@@ -51,7 +51,7 @@ def main(args):
     rs = np.random.RandomState(0)
     X, y = synth(args.num_examples, rs)
     it = mx.io.NDArrayIter(X, y, batch_size=args.num_examples)
-    mod = mx.mod.Module(get_symbol(), context=mx.tpu(0))
+    mod = mx.mod.Module(get_symbol(), context=mx.current_context())
     mod.bind(data_shapes=it.provide_data,
              label_shapes=it.provide_label, inputs_need_grad=True)
     mod.init_params(mx.init.Xavier())

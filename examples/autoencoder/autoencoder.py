@@ -52,7 +52,7 @@ def main(args):
     it = mx.io.NDArrayIter({"data": X}, {"recon_label": X},
                            batch_size=64)
     mod = mx.mod.Module(get_symbol(), label_names=("recon_label",),
-                        context=mx.tpu(0))
+                        context=mx.current_context())
     mod.fit(it, num_epoch=args.num_epochs, optimizer="adam",
             optimizer_params={"learning_rate": 3e-3},
             initializer=mx.init.Xavier(),

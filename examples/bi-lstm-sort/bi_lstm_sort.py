@@ -52,7 +52,8 @@ def main(args):
     data, label = synth(args.num_examples, args.vocab, args.seq_len, rs)
     it = mx.io.NDArrayIter(data, label, batch_size=args.batch_size)
     mod = mx.mod.Module(get_symbol(args.vocab, args.seq_len,
-                                   args.num_hidden), context=mx.tpu(0))
+                                   args.num_hidden),
+                        context=mx.current_context())
     mod.fit(it, num_epoch=args.num_epochs, optimizer="adam",
             optimizer_params={"learning_rate": 0.01},
             initializer=mx.init.Xavier(),

@@ -61,7 +61,7 @@ def main(args):
     data, y = synth(args.num_examples, args.vocab, args.seq_len, rs)
     it = mx.io.NDArrayIter(data, y, batch_size=args.batch_size)
     mod = mx.mod.Module(get_symbol(args.vocab, args.seq_len),
-                        context=mx.tpu(0))
+                        context=mx.current_context())
     mod.fit(it, num_epoch=args.num_epochs, optimizer="adam",
             optimizer_params={"learning_rate": 5e-3},
             initializer=mx.init.Xavier(),

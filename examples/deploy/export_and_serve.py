@@ -40,7 +40,7 @@ def main():
                 act_type="relu"),
             num_hidden=4, name="fc2"), name="softmax")
     it = mx.io.NDArrayIter(X, y, batch_size=64, shuffle=True)
-    mod = mx.mod.Module(net, context=mx.tpu())
+    mod = mx.mod.Module(net, context=mx.current_context())
     mod.fit(it, num_epoch=30, initializer=mx.init.Xavier(),
             optimizer_params={"learning_rate": 0.5})
 

@@ -85,7 +85,7 @@ def main(args):
     imgs, labels = synth_batch(args.num_examples, args.size, rs)
     it = mx.io.NDArrayIter(imgs, labels, batch_size=args.batch_size)
     net = get_symbol()
-    mod = mx.mod.Module(net, context=mx.tpu(0))
+    mod = mx.mod.Module(net, context=mx.current_context())
     mod.fit(it, num_epoch=args.num_epochs, optimizer="adam",
             optimizer_params={"learning_rate": 5e-3},
             initializer=mx.init.Xavier(),

@@ -73,7 +73,7 @@ def main(args):
     # (seed-sensitive by nature) reproduce
     mx.random.seed(3)
     batch, z_dim = args.batch_size, 16
-    ctx = mx.tpu(0)
+    ctx = mx.current_context()
 
     symG = make_generator(ngf=16, z_dim=z_dim)
     symD = make_discriminator(ndf=16)

@@ -70,7 +70,7 @@ def fit(args, network, data_loader):
             network, arg_params, aux_params = mx.model.load_checkpoint(
                 args.model_prefix, args.load_epoch)
 
-    mod = mx.mod.Module(network, context=mx.tpu())
+    mod = mx.mod.Module(network, context=mx.current_context())
     optimizer_params = {"learning_rate": lr, "wd": args.wd}
     if args.optimizer in ("sgd", "nag"):
         optimizer_params["momentum"] = args.mom

@@ -56,7 +56,7 @@ def main(args):
 
     mesh = create_mesh({"pipe": n_dev}, devices=jax.devices()[:n_dev])
     with mesh_scope(mesh):
-        mod = mx.mod.Module(sym, context=mx.tpu(0),
+        mod = mx.mod.Module(sym, context=mx.current_context(),
                             pipeline_stages=n_dev,
                             pipeline_microbatches=args.microbatches,
                             pipeline_schedule=args.schedule)

@@ -69,7 +69,7 @@ def main(args):
                             "model": model_axis})
         scope = mesh_scope(mesh)
 
-    mod = mx.mod.Module(build_lm(args), context=mx.tpu())
+    mod = mx.mod.Module(build_lm(args), context=mx.current_context())
     with scope:
         mod.fit(it, num_epoch=args.num_epochs,
                 eval_metric=mx.metric.Perplexity(ignore_label=None),

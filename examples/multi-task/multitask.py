@@ -65,7 +65,7 @@ def main(args):
         batch_size=args.batch_size)
     mod = mx.mod.Module(get_symbol(),
                         label_names=("quad_label", "size_label"),
-                        context=mx.tpu(0))
+                        context=mx.current_context())
     mod.fit(it, num_epoch=args.num_epochs, optimizer="adam",
             optimizer_params={"learning_rate": 5e-3},
             initializer=mx.init.Xavier(),

@@ -81,7 +81,7 @@ def main(args):
     net = get_symbol(vocab, vocab, h, num_label)
     mod = mx.mod.Module(net, data_names=("data", "label"),
                         label_names=("label_weight",),
-                        context=mx.tpu(0))
+                        context=mx.current_context())
     mod.fit(it, num_epoch=args.num_epochs, optimizer="adam",
             optimizer_params={"learning_rate": 0.02},
             initializer=mx.init.Xavier(),

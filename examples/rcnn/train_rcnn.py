@@ -278,7 +278,7 @@ def main(args):
                            shuffle=True)
 
     sym = rcnn_symbol(args.batch_size)
-    mod = mx.mod.Module(sym, context=mx.tpu(),
+    mod = mx.mod.Module(sym, context=mx.current_context(),
                         data_names=("data", "im_info"),
                         label_names=("label",))
     mod.bind(data_shapes=it.provide_data, label_shapes=it.provide_label)

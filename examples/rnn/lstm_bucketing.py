@@ -73,7 +73,7 @@ def main(args):
     model = mx.mod.BucketingModule(
         sym_gen=sym_gen_factory(args),
         default_bucket_key=train.default_bucket_key,
-        context=mx.tpu())
+        context=mx.current_context())
 
     metric = mx.metric.Perplexity(ignore_label=None)
     model.fit(train, eval_data=val, eval_metric=metric,

@@ -104,8 +104,8 @@ def main(args):
                            label_name="label")
 
     sym = ssd_symbol()
-    mod = mx.mod.Module(sym, context=mx.tpu(), label_names=("label",),
-                        data_names=("data",))
+    mod = mx.mod.Module(sym, context=mx.current_context(),
+                        label_names=("label",), data_names=("data",))
     mod.bind(data_shapes=it.provide_data, label_shapes=it.provide_label)
     mod.init_params(mx.init.Xavier())
     mod.init_optimizer(optimizer="adam",

@@ -85,7 +85,7 @@ def main(args):
     rates = [args.death_rate * (i + 1) / args.units
              for i in range(args.units)]
     train_sym = get_symbol(args.units, 16, rates, args.batch_size, True)
-    mod = mx.mod.Module(train_sym, context=mx.tpu(0))
+    mod = mx.mod.Module(train_sym, context=mx.current_context())
     mod.fit(it, num_epoch=args.num_epochs, optimizer="adam",
             optimizer_params={"learning_rate": 5e-3},
             initializer=mx.init.Xavier())
@@ -93,7 +93,7 @@ def main(args):
     # inference graph: same parameters, gates replaced by expectation
     arg_params, aux_params = mod.get_params()
     infer_sym = get_symbol(args.units, 16, rates, args.batch_size, False)
-    imod = mx.mod.Module(infer_sym, context=mx.tpu(0))
+    imod = mx.mod.Module(infer_sym, context=mx.current_context())
     it.reset()
     imod.bind(data_shapes=it.provide_data,
               label_shapes=it.provide_label, for_training=False)

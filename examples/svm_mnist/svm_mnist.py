@@ -43,7 +43,7 @@ def synth(n, rs, num_classes=4, dim=32):
 def train(head, X, y, epochs):
     label_name = "svm_label" if head == "svm" else "softmax_label"
     it = mx.io.NDArrayIter(X, y, batch_size=64, label_name=label_name)
-    mod = mx.mod.Module(get_symbol(head, 4), context=mx.tpu(0),
+    mod = mx.mod.Module(get_symbol(head, 4), context=mx.current_context(),
                         label_names=(label_name,))
     lr = 0.1
     mod.fit(it, num_epoch=epochs, optimizer="sgd",

@@ -68,7 +68,7 @@ def main(args):
                            batch_size=args.batch_size)
     net = get_symbol(args.batch_size, args.latent, 256, args.kl_weight)
     mod = mx.mod.Module(net, label_names=("recon_label",),
-                        context=mx.tpu(0))
+                        context=mx.current_context())
     mod.fit(it, num_epoch=args.num_epochs, optimizer="adam",
             optimizer_params={"learning_rate": 3e-3},
             initializer=mx.init.Xavier(),
@@ -86,7 +86,7 @@ def main(args):
     z = mx.sym.Variable("z")
     gen_sym = decoder(z, 256)
     gen = mx.mod.Module(gen_sym, data_names=("z",), label_names=(),
-                        context=mx.tpu(0))
+                        context=mx.current_context())
     gen.bind(data_shapes=[("z", (args.batch_size, args.latent))],
              for_training=False)
     arg_params, aux_params = mod.get_params()

@@ -84,7 +84,7 @@ def main(args):
                            batch_size=args.batch_size)
     mod = mx.mod.Module(get_symbol(args.seq_len, args.num_hidden, 10),
                         data_names=("data",), label_names=("label",),
-                        context=mx.tpu(0))
+                        context=mx.current_context())
     mod.fit(it, num_epoch=args.num_epochs, optimizer="adam",
             optimizer_params={"learning_rate": 0.01},
             initializer=mx.init.Xavier(),

@@ -7,21 +7,6 @@ dispatch to cached XLA executables, bound Symbol graphs compile to a single
 XLA computation, distribution is jax.sharding meshes + XLA collectives over
 ICI/DCN, and Gluon-style blocks hybridize into jitted programs.
 """
-import os as _os
-
-if _os.environ.get("JAX_PLATFORMS") == "cpu":
-    # honor an explicit CPU request: TPU plugin env exports can override
-    # the env var after it is read, so the documented JAX_PLATFORMS=cpu
-    # contract silently lands on the accelerator without this pin (the
-    # same pin tests/conftest.py applies for pytest).  No-op when the
-    # jax backend is already initialized.
-    try:
-        import jax as _jax
-
-        _jax.config.update("jax_platforms", "cpu")
-    except Exception:
-        pass
-
 from . import base
 from . import compile_cache
 from . import attribute

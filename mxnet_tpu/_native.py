@@ -5,17 +5,22 @@ IO — SURVEY.md §2.1); on TPU the engine/storage layers are PJRT/XLA, and
 the native layer that remains worthwhile is host-side IO.  This module
 compiles ``src/*.cc`` with the system ``g++`` on first use (no pybind11
 in this image; the ABI is plain C for ctypes) and caches the shared
-object under ``mxnet_tpu/_build/``.
+object under the git-ignored ``mxnet_tpu/_build/``, keyed by a digest
+of the sources it was built from (a copied tree need not keep mtimes).
 
-Degrades gracefully: if no compiler is available the callers fall back
-to their pure-Python paths (``native_recordio() is None``).
+If the build fails the callers use their pure-Python paths
+(``native_recordio() is None``); the compiler's error is logged once
+per library, so the slower path is never taken in silence.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
+
+from .base import logger
 
 _LOCK = threading.Lock()
 _LIB = {}
@@ -46,6 +51,15 @@ _EXTRA_FLAGS = {
 }
 
 
+def _sources_digest(paths):
+    h = hashlib.sha256()
+    for path in sorted(paths):
+        h.update(os.path.basename(path).encode())
+        with open(path, "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
+
+
 def _load(name):
     """Compile (if stale) and dlopen src/<name>.cc; returns CDLL or
     None."""
@@ -54,27 +68,46 @@ def _load(name):
             return _LIB[name]
         src = os.path.join(_SRC_DIR, name + ".cc")
         so = os.path.join(_BUILD_DIR, name + ".so")
+        stamp = so + ".sha256"
         lib = None
         try:
             if os.path.exists(src):
-                # stale if older than the source OR any src/*.h it may
-                # include (embed_common.h is shared by the ABI libs)
-                deps = [src] + [os.path.join(_SRC_DIR, f)
-                                for f in os.listdir(_SRC_DIR)
-                                if f.endswith(".h")]
-                if not os.path.exists(so) or \
-                        os.path.getmtime(so) < max(
-                            os.path.getmtime(d) for d in deps):
+                # stale unless built from exactly these bytes: the
+                # source AND any src/*.h it may include (embed_common.h
+                # is shared by the ABI libs)
+                digest = _sources_digest(
+                    [src] + [os.path.join(_SRC_DIR, f)
+                             for f in os.listdir(_SRC_DIR)
+                             if f.endswith(".h")])
+                built = None
+                if os.path.exists(so) and os.path.exists(stamp):
+                    with open(stamp) as f:
+                        built = f.read().strip()
+                if built != digest:
                     os.makedirs(_BUILD_DIR, exist_ok=True)
                     cflags, ldflags = ([], [])
                     if name in _EXTRA_FLAGS:
                         cflags, ldflags = _EXTRA_FLAGS[name]()
+                    # build beside, then rename: another process of
+                    # this checkout never dlopens a half-written file
+                    tmp = "%s.%d.tmp" % (so, os.getpid())
                     subprocess.run(
                         ["g++", "-O2", "-shared", "-fPIC", "-std=c++17"]
-                        + cflags + ["-o", so, src] + ldflags,
+                        + cflags + ["-o", tmp, src] + ldflags,
                         check=True, capture_output=True, timeout=120)
+                    os.replace(tmp, so)
+                    with open(stamp, "w") as f:
+                        f.write(digest)
                 lib = ctypes.CDLL(so)
-        except (OSError, subprocess.SubprocessError):
+        except (OSError, subprocess.SubprocessError) as e:
+            # once per library (_LIB caches the None)
+            detail = getattr(e, "stderr", None)
+            if isinstance(detail, bytes):
+                detail = detail.decode("utf-8", "replace")
+            logger.warning(
+                "native library %r unavailable, using the pure-Python "
+                "path: %s%s", name, e,
+                ("\n" + detail.strip()[-2000:]) if detail else "")
             lib = None
         _LIB[name] = lib
         return lib

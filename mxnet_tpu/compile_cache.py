@@ -3,14 +3,17 @@ guardrails.
 
 Three legs (docs/compilation.md):
 
-1. **Persistent compilation cache** — every bench artifact of rounds
-   1-5 died inside XLA compilation before the first measured step; this
-   wires JAX's persistent compilation cache behind
-   ``MXNET_COMPILE_CACHE_DIR`` (default ``~/.cache/mxnet_tpu/xla``,
-   empty string opts out) so a second process running the same model
-   deserializes the executable instead of re-running XLA.  The cache
-   directory is bounded by ``MXNET_COMPILE_CACHE_MAX_BYTES`` with an
-   LRU eviction sweep, and :func:`cache_stats` reports hits / misses /
+1. **Persistent compilation cache** — a second process running the
+   same model deserializes its executables instead of re-running XLA.
+   Where the directory comes from, in order: ``JAX_COMPILATION_CACHE_DIR``
+   (JAX reads it itself; this module then writes no directory into
+   ``jax.config`` and never deletes a file there), else
+   ``MXNET_COMPILE_CACHE_DIR`` (empty string opts out), else the fixed
+   ``<checkout>/.cache/xla`` beside the package — the path is part of
+   the cache key's usefulness, so it never depends on ``$HOME``, a
+   temporary name, a pid or the time.  A directory this module chose
+   is bounded by ``MXNET_COMPILE_CACHE_MAX_BYTES`` with an LRU eviction
+   sweep, and :func:`cache_stats` reports directory / hits / misses /
    bytes / evictions for the current process.  Initialization is lazy:
    the first jit owner (``TrainStep``, ``Executor``, ``CachedOp``, a
    ``Context`` device lookup) calls :func:`ensure_initialized`.
@@ -54,7 +57,11 @@ __all__ = ["ensure_initialized", "cache_stats", "sweep_cache",
            "RecompileRegistry", "RecompileStorm", "registry",
            "write_artifact", "track_lru"]
 
-DEFAULT_CACHE_DIR = os.path.join("~", ".cache", "mxnet_tpu", "xla")
+# one fixed place inside the checkout (git-ignored): derived from where
+# the package lives, so every process of this checkout shares it
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".cache", "xla")
 # cap chosen for a shared dev box: ~40 ResNet-class executables
 DEFAULT_MAX_BYTES = 2 << 30
 
@@ -63,6 +70,9 @@ _state = {
     "initialized": False,
     "enabled": False,
     "dir": None,
+    # False when the directory came from JAX_COMPILATION_CACHE_DIR: a
+    # directory handed in from outside is never swept
+    "owned": False,
     "max_bytes": None,
     "hits": 0,
     "requests": 0,
@@ -88,11 +98,16 @@ def ensure_initialized():
     """Wire the JAX persistent compilation cache (idempotent, lazy).
 
     Called by every jit owner right before its first trace; the fast
-    path is one boolean check.  Honors:
+    path is one boolean check.  Honors, in this order:
 
-    * ``MXNET_COMPILE_CACHE_DIR`` — cache directory; default
-      ``~/.cache/mxnet_tpu/xla``, empty string disables persistence.
-    * ``MXNET_COMPILE_CACHE_MAX_BYTES`` — LRU size cap for the sweep.
+    * ``JAX_COMPILATION_CACHE_DIR`` — JAX's own variable.  When set,
+      JAX's reading of it stands: no directory is written into
+      ``jax.config`` here and the directory is never swept.
+    * ``MXNET_COMPILE_CACHE_DIR`` — cache directory; empty string
+      disables persistence.
+    * neither: the fixed ``<checkout>/.cache/xla`` beside the package.
+    * ``MXNET_COMPILE_CACHE_MAX_BYTES`` — LRU size cap for the sweep of
+      a directory this module chose.
     * ``MXNET_COMPILE_CACHE_MIN_COMPILE_S`` — only executables whose
       XLA compile took at least this long are persisted (default 0.5;
       set 0 to persist everything, as the round-trip tests do).
@@ -102,22 +117,26 @@ def ensure_initialized():
     with _lock:
         if _state["initialized"]:
             return _state["enabled"]
-        cache_dir = get_env("MXNET_COMPILE_CACHE_DIR", DEFAULT_CACHE_DIR,
-                            str)
+        handed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+        cache_dir = handed or get_env("MXNET_COMPILE_CACHE_DIR",
+                                      DEFAULT_CACHE_DIR, str)
         _state["max_bytes"] = get_env("MXNET_COMPILE_CACHE_MAX_BYTES",
                                       DEFAULT_MAX_BYTES, int)
         if not cache_dir:
             _state["initialized"] = True
             _state["enabled"] = False
             return False
-        cache_dir = os.path.abspath(os.path.expanduser(cache_dir))
         try:
             import jax
 
-            from jax._src import monitoring as _monitoring
-
-            os.makedirs(cache_dir, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
+            if handed:
+                # the directory was placed from outside: report what
+                # JAX itself read, set nothing, delete nothing
+                cache_dir = jax.config.jax_compilation_cache_dir
+            else:
+                cache_dir = os.path.abspath(os.path.expanduser(cache_dir))
+                os.makedirs(cache_dir, exist_ok=True)
+                jax.config.update("jax_compilation_cache_dir", cache_dir)
             jax.config.update("jax_enable_compilation_cache", True)
             jax.config.update(
                 "jax_persistent_cache_min_compile_time_secs",
@@ -126,13 +145,15 @@ def ensure_initialized():
             # executables — the LRU sweep is the size policy here
             jax.config.update("jax_persistent_cache_min_entry_size_bytes",
                               0)
-            _monitoring.register_event_listener(_on_monitoring_event)
+            jax.monitoring.register_event_listener(_on_monitoring_event)
             _state["dir"] = cache_dir
+            _state["owned"] = not handed
             _state["enabled"] = True
-            # bound the directory NOW (a previous run may have blown the
-            # cap) and again at exit (this run's own entries)
-            sweep_cache()
-            atexit.register(sweep_cache)
+            if not handed:
+                # bound the directory NOW (a previous run may have blown
+                # the cap) and again at exit (this run's own entries)
+                sweep_cache()
+                atexit.register(sweep_cache)
         except Exception as e:  # cache is an optimization, never fatal
             logger.warning("persistent compilation cache unavailable "
                            "(%s); compiles will not be reused across "
@@ -167,8 +188,10 @@ def sweep_cache(cache_dir=None, max_bytes=None):
     """LRU eviction sweep: delete least-recently-used cache entries
     until the directory fits ``max_bytes``.  Returns (entries, bytes)
     remaining.  Safe to call concurrently with running processes — an
-    evicted entry just recompiles on its next use."""
-    cache_dir = cache_dir or _state["dir"]
+    evicted entry just recompiles on its next use.  With no explicit
+    ``cache_dir`` only a directory this module chose is swept."""
+    if cache_dir is None and _state["owned"]:
+        cache_dir = _state["dir"]
     if max_bytes is None:
         max_bytes = _state["max_bytes"]
         if max_bytes is None:

@@ -14,6 +14,8 @@ from __future__ import annotations
 
 import threading
 
+from .base import MXNetError
+
 __all__ = ["Context", "cpu", "gpu", "tpu", "current_context"]
 
 
@@ -82,12 +84,33 @@ class Context:
         ensure_initialized()
         kind = self.device_type
         if kind in ("cpu", "cpu_pinned"):
+            # every cpu(i) is the same host memory in the reference, so
+            # the id wraps over whatever host devices exist
             devs = jax.local_devices(backend="cpu") if _has_platform("cpu") \
                 else jax.local_devices()
-        else:
-            # tpu (and gpu, aliased to the accelerator) → default platform
-            devs = jax.local_devices()
-        return devs[self.device_id % len(devs)]
+            return devs[self.device_id % len(devs)]
+        # tpu (and the gpu alias): the accelerator or nothing — a run
+        # that names the chip must not pass on another device
+        devs = [d for d in jax.local_devices() if d.platform == "tpu"]
+        if not 0 <= self.device_id < len(devs):
+            raise MXNetError(
+                "%s names TPU device %d but this process has %d TPU "
+                "device(s); jax.devices() holds %s"
+                % (self, self.device_id, len(devs), describe_devices()))
+        return devs[self.device_id]
+
+
+def describe_devices():
+    """What ``jax.devices()`` holds, as error messages and the chip
+    scripts name it: e.g. ``8 x cpu (cpu)`` or ``1 x tpu (TPU v5 lite)``."""
+    import collections
+
+    import jax
+
+    kinds = collections.Counter(
+        (d.platform, d.device_kind) for d in jax.devices())
+    return ", ".join("%d x %s (%s)" % (n, platform, kind)
+                     for (platform, kind), n in sorted(kinds.items()))
 
 
 def _has_platform(name):
@@ -106,18 +129,25 @@ def cpu(device_id=0):
 
 def tpu(device_id=0):
     """A TPU context — the accelerator context of this framework
-    (the ``mx.tpu()`` from the north star in BASELINE.json)."""
+    (the ``mx.tpu()`` from the north star in BASELINE.json).  Resolving
+    it (``.jax_device``) raises a typed ``MXNetError`` when device
+    ``device_id`` is not a TPU of this process: it never lands on a CPU
+    and never wraps onto another chip.  Code that means "the default
+    device" says ``mx.current_context()``."""
     return Context("tpu", device_id)
 
 
 def gpu(device_id=0):
     """Compatibility alias: reference scripts that say ``mx.gpu(i)`` get the
-    accelerator (TPU) so `--gpus` scripts run unmodified."""
+    accelerator (TPU) so `--gpus` scripts run unmodified on a TPU host.
+    Like ``mx.tpu(i)`` it raises when resolved on a host with no TPU."""
     return Context("tpu", device_id)
 
 
 def current_context():
-    """The thread-local default context (reference ``current_context()``)."""
+    """The thread-local default context (reference ``current_context()``):
+    ``tpu(0)`` when the default backend is a TPU, ``cpu(0)`` on a CPU
+    host (the documented CPU mode for tests and rehearsals)."""
     ctx = getattr(Context._default_ctx, "value", None)
     if ctx is None:
         ctx = Context("tpu", 0) if _accelerator_present() else Context("cpu", 0)
@@ -128,4 +158,4 @@ def current_context():
 def _accelerator_present():
     import jax
 
-    return jax.default_backend() not in ("cpu",)
+    return jax.default_backend() == "tpu"

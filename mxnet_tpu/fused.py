@@ -61,7 +61,9 @@ Extra TPU-first knobs the reference exposes differently:
 """
 from __future__ import annotations
 
-from .base import MXNetError
+import functools
+
+from .base import MXNetError, logger
 from .compile_cache import signature_of as _signature_of
 
 __all__ = ["compile_train_step", "TrainStep"]
@@ -412,7 +414,14 @@ class TrainStep:
                             full[q] = _z3_tag(fp)
                     return base_loss_fn(full, b, r)
 
-                policy = _z3_remat_policy()
+                # The re-gather policy applies to fp32 compute only.
+                # Under a compute dtype the matmuls consume the CAST copy,
+                # which carries no tag: the policy saved it whole (on the
+                # chip the compiled bf16 step holds one gather per
+                # parameter either way), and that program stopped
+                # learning on four v5e chips while this plain form tracks
+                # one chip exactly (PERF.md, Findings PR 21).
+                policy = _z3_remat_policy() if cdtype is None else None
                 loss_fn = (jax.checkpoint(z3_loss_fn, policy=policy)
                            if policy is not None else z3_loss_fn)
             vag = None
@@ -890,9 +899,10 @@ class TrainStep:
                 pass
         return out
 
-    def _abstract_inputs(self, shapes, dtype="float32"):
+    def _abstract_inputs(self, shapes, dtype="float32", device=None):
         """Abstract (params, aux, states, batch, rng, lr, t[, hstate])
-        matching what ``__call__`` dispatches for per-step ``shapes``:
+        matching what ``__call__`` dispatches for per-step ``shapes``
+        (placed on ``device`` when given, else wherever jit defaults):
         parameter/aux avals from the shape-inference pass, optimizer
         states via ``eval_shape``, the super-batch leading K axis when
         ``steps_per_call > 1``, a concrete rng key, the python-float lr
@@ -905,6 +915,9 @@ class TrainStep:
         shapes = {k: tuple(v) for k, v in dict(shapes).items()}
         all_shapes = _infer_param_shapes(self.symbol, dict(shapes))
         S = jax.ShapeDtypeStruct
+        if device is not None:
+            S = functools.partial(
+                S, sharding=jax.sharding.SingleDeviceSharding(device))
         params = {n: S(tuple(all_shapes[n]), jnp.dtype(dtype))
                   for n in self.param_names}
         aux = {n: S(tuple(all_shapes[n]), jnp.dtype("float32"))
@@ -937,8 +950,11 @@ class TrainStep:
             args = args + (self._init_hstate(),)
         return args
 
-    def compile(self, shapes, dtype="float32"):
+    def compile(self, shapes, dtype="float32", device=None):
         """AOT warmup: lower and compile the step for ``shapes`` NOW.
+        ``device``: the single device the live arrays sit on when it is
+        not jit's default (a cpu-context module on a TPU host) — the
+        executable is compiled for it, not for the default device.
 
         ``shapes`` maps each data/label name to its per-step shape (the
         same dict ``init_state`` takes); the leading ``steps_per_call``
@@ -960,7 +976,7 @@ class TrainStep:
                 "param_sharding=%r: the sharded jit resolves against "
                 "concrete parameters on the first call"
                 % (self._param_sharding,))
-        args = self._abstract_inputs(shapes, dtype=dtype)
+        args = self._abstract_inputs(shapes, dtype=dtype, device=device)
         if self._jit_step is None:
             if self.zero_axis is not None:
                 # ZeRO: the abstract states carry the flat layout, which
@@ -1077,12 +1093,19 @@ class TrainStep:
             if self._aot is not None and sig == self._aot_sig:
                 try:
                     out = self._aot(*call_args)
-                except Exception:
-                    # Compiled executables validate avals/shardings before
-                    # running (donation has not happened yet), so falling
-                    # back to the lazy jit is safe; drop the AOT
+                except (TypeError, ValueError) as e:
+                    # A compiled executable validates avals, layouts and
+                    # shardings before it runs (donation has not happened
+                    # yet) and refuses with one of these; the lazy jit
+                    # re-specializes for what arrived.  Drop the AOT
                     # executable for good rather than re-failing every
-                    # step.
+                    # step, and say so.  A runtime error of the step
+                    # itself (device fault, out of memory) is neither
+                    # type and raises.
+                    logger.warning(
+                        "%s: AOT executable refused the live arguments "
+                        "(%s); using the lazily-jitted step from here",
+                        self._recompile_guard.name, e)
                     self._aot = None
                     out = None
             if out is None:

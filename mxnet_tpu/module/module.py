@@ -439,8 +439,8 @@ class Module(BaseModule):
         (``InitOpSegs``, env ``MXNET_EXEC_BULK_EXEC_TRAIN``) taken to its
         limit: the whole train step — including the optimizer and, under a
         mesh, the gradient all-reduce — is a single device call per batch,
-        which removes the per-op host round-trips that dominate when the
-        device is behind a network tunnel.  Works for every optimizer with
+        which removes the per-op dispatches and host round-trips of the
+        split path.  Works for every optimizer with
         a ``fused_update`` (the whole built-in family); per-param lr/wd
         multipliers and fixed params are honored.  Set MXNET_FUSED_STEP=0
         to disable (falls back to forward/backward/update calls)."""
@@ -565,68 +565,32 @@ class Module(BaseModule):
         if not ok:
             _bail("grad_req %r is not fusable" % (req,))
             return
-        try:
-            from ..fused import TrainStep
-            from ..health import StepHealth
+        # every declared reason to use the split path bailed above; from
+        # here an exception building the fused step is a fault and
+        # raises (it would otherwise silently change what trains)
+        from ..fused import TrainStep
+        from ..health import StepHealth
 
-            remat = "full" if get_env("MXNET_BACKWARD_DO_MIRROR", False,
-                                      bool) else None
-            scaler = getattr(self, "_loss_scaler", None)
-            step_health = None
-            if scaler is not None or \
-                    getattr(self, "_health_monitor", None) is not None:
-                step_health = StepHealth(scaler=scaler)
-            self._fused = TrainStep(
-                self._symbol, optimizer=o, mesh=self._mesh,
-                data_names=self._data_names, label_names=self._label_names,
-                fixed_param_names=self._fixed_param_names, remat=remat,
-                param_sharding=getattr(self, "_param_sharding", None),
-                compute_dtype=getattr(self, "_compute_dtype", None),
-                steps_per_call=getattr(self, "_steps_per_call", 1),
-                health=step_health,
-                zero=getattr(self, "_zero", None),
-                plan=getattr(self, "_plan", None))
-            # the sharded-update dispatch attaches the kvstore's peer
-            # diagnosis to bounded-collective timeouts
-            self._fused._kvstore = self._kvstore
-        except Exception as e:  # fall back to the split path
-            if getattr(self, "_compute_dtype", None) is not None:
-                raise MXNetError(
-                    "compute_dtype=%r was requested but the fused step "
-                    "could not be built: %s"
-                    % (self._compute_dtype, e)) from e
-            if getattr(self, "_loss_scaler", None) is not None:
-                raise MXNetError(
-                    "loss_scale was requested but the fused step could "
-                    "not be built: %s" % (e,)) from e
-            if getattr(self, "_steps_per_call", 1) > 1:
-                raise MXNetError(
-                    "steps_per_call=%d was requested but the fused step "
-                    "could not be built: %s"
-                    % (self._steps_per_call, e)) from e
-            if getattr(self, "_param_sharding", None) not in (
-                    None, "replicated"):
-                # an EXPLICIT sharding request must not silently train
-                # replicated single-device
-                raise MXNetError(
-                    "param_sharding=%r was requested but the fused step "
-                    "could not be built: %s"
-                    % (self._param_sharding, e)) from e
-            if getattr(self, "_zero", None) in ("on", "3"):
-                raise MXNetError(
-                    "zero=%s was requested but the fused step could not "
-                    "be built: %s" % (self._zero, e)) from e
-            if getattr(self, "_plan", None) is not None:
-                raise MXNetError(
-                    "plan=%r was requested but the fused step could not "
-                    "be built: %s" % (self._plan, e)) from e
-            self.logger.debug("fused step unavailable: %s", e)
-            self._fused = None
-        if self._fused is None and self._mesh is not None and \
-                max(self._mesh.shape.values()) > 1:
-            self.logger.warning(
-                "dist kvstore requested but the fused SPMD step is "
-                "unavailable; training runs single-device (full batch)")
+        remat = "full" if get_env("MXNET_BACKWARD_DO_MIRROR", False,
+                                  bool) else None
+        scaler = getattr(self, "_loss_scaler", None)
+        step_health = None
+        if scaler is not None or \
+                getattr(self, "_health_monitor", None) is not None:
+            step_health = StepHealth(scaler=scaler)
+        self._fused = TrainStep(
+            self._symbol, optimizer=o, mesh=self._mesh,
+            data_names=self._data_names, label_names=self._label_names,
+            fixed_param_names=self._fixed_param_names, remat=remat,
+            param_sharding=getattr(self, "_param_sharding", None),
+            compute_dtype=getattr(self, "_compute_dtype", None),
+            steps_per_call=getattr(self, "_steps_per_call", 1),
+            health=step_health,
+            zero=getattr(self, "_zero", None),
+            plan=getattr(self, "_plan", None))
+        # the sharded-update dispatch attaches the kvstore's peer
+        # diagnosis to bounded-collective timeouts
+        self._fused._kvstore = self._kvstore
 
     def _init_fused_states(self):
         """Seed fused optimizer states, honoring any states preloaded into
@@ -755,7 +719,12 @@ class Module(BaseModule):
         shapes = {d.name: d.shape for d in self._data_shapes}
         shapes.update({l.name: l.shape
                        for l in (self._label_shapes or [])})
-        stats = fused.compile(shapes, dtype=dtype)
+        # single-device modules compile for THEIR device: jit's default
+        # is the accelerator, which a cpu-context module on a TPU host
+        # would then refuse at the first step
+        device = self._context[0].jax_device if self._mesh is None \
+            else None
+        stats = fused.compile(shapes, dtype=dtype, device=device)
         self.logger.debug("AOT compile %s: %.2fs%s", stats.get("name"),
                           stats.get("duration_s", 0.0),
                           " (persistent-cache hit)"

@@ -500,11 +500,12 @@ def waitall():
     handler contract, ``threaded_engine.h:347``)."""
     import jax
 
-    barrier = getattr(jax, "effects_barrier", None)
-    if barrier is not None:
-        barrier()
-    else:  # older jax: synchronize via a device round-trip
-        jax.device_put(0.0).block_until_ready()
+    # effects_barrier waits for effectful computations only; an ordinary
+    # async-dispatched step is awaited through the arrays it produced
+    jax.effects_barrier()
+    for a in jax.live_arrays():
+        if not a.is_deleted():
+            a.block_until_ready()
 
 
 # -- save/load: the reference's binary NDArray dict format is replaced by

@@ -50,7 +50,8 @@ from ..base import MXNetError, get_env
 
 __all__ = ["attention_impl", "attention_block_size", "dot_product_attention",
            "flash_attention", "reference_attention", "attend_block",
-           "online_block_merge", "finalize_attention", "decode_attention"]
+           "online_block_merge", "finalize_attention", "decode_attention",
+           "pallas_eligible"]
 
 _IMPLS = ("auto", "flash", "reference")
 
@@ -494,15 +495,38 @@ def decode_attention(q, k_ctx, v_ctx, lengths, scale=None, block=None,
 # Pallas fused kernel (TPU) + dispatcher
 # ---------------------------------------------------------------------------
 
+# the library kernel's default BlockSizes are 128 on every axis and it
+# asserts T % block == 0; Mosaic tiles the head dim in lanes of 128 and
+# accepts a half lane
+_PALLAS_T_BLOCK = 128
+_PALLAS_D_MULTIPLE = 64
+
+
+def pallas_eligible(q, k, v, window=0):
+    """Whether ``auto`` sends this call to the library Pallas flash
+    kernel on TPU — a decision from what is visible at trace time
+    (rank, shapes, dtype, ``window``), never from whether a trial call
+    raised.  The kernel takes ``(n, h, T, d)`` floating inputs of one
+    shape family, ``T`` a multiple of its 128 block on both sides,
+    ``d`` a multiple of 64, and has no sliding-window mask."""
+    if window or q.ndim != 4 or k.shape != v.shape:
+        return False
+    if q.shape[:2] != k.shape[:2] or q.shape[-1] != k.shape[-1]:
+        return False
+    if not jnp.issubdtype(q.dtype, jnp.floating):
+        return False
+    return (q.shape[-2] % _PALLAS_T_BLOCK == 0
+            and k.shape[-2] % _PALLAS_T_BLOCK == 0
+            and q.shape[-1] % _PALLAS_D_MULTIPLE == 0)
+
+
 def _pallas_attention(q, k, v, causal, scale):
-    """TPU fused flash kernel (Mosaic).  Raises when unavailable or the
-    shape does not meet the kernel's block constraints — callers fall
-    back to the ``lax`` blockwise path."""
+    """TPU fused flash kernel (Mosaic).  Callers check
+    :func:`pallas_eligible` first; whatever the kernel or its compiler
+    then raises is an error of the step, not a reason to change path."""
     from jax.experimental.pallas.ops.tpu.flash_attention import (
         flash_attention as pl_flash)
 
-    if q.ndim != 4:
-        raise MXNetError("pallas flash kernel needs (n, h, T, d) inputs")
     return pl_flash(q, k, v, causal=causal, sm_scale=scale)
 
 
@@ -511,12 +535,12 @@ def dot_product_attention(q, k, v, causal=True, scale=None, impl=None,
     """Dispatch attention to the implementation ``MXNET_ATTN_IMPL`` (or
     the explicit ``impl`` argument) selects.
 
-    ``auto`` tries the Pallas fused kernel when tracing for TPU and
-    falls back to the portable ``lax`` blockwise kernel — which is also
-    what ``flash`` forces, so the CPU tier-1 rig and the TPU fallback
-    run identical code.  ``window > 0`` (sliding-window attention) is
-    not expressible in the Pallas kernel's mask, so it always takes the
-    blockwise path.
+    ``auto`` takes the Pallas fused kernel when tracing for TPU and
+    :func:`pallas_eligible` says the call fits it, and the portable
+    ``lax`` blockwise kernel otherwise — which is also what ``flash``
+    forces, so the CPU tier-1 rig and ineligible TPU calls run identical
+    code.  The choice is made before the kernel is called; an error
+    from either kernel propagates.
     """
     impl = (impl or attention_impl()).strip().lower()
     if impl not in _IMPLS:
@@ -524,14 +548,10 @@ def dot_product_attention(q, k, v, causal=True, scale=None, impl=None,
     if impl == "reference":
         return reference_attention(q, k, v, causal=causal, scale=scale,
                                    window=window)
-    if impl == "auto" and jax.default_backend() == "tpu" and not window:
+    if impl == "auto" and jax.default_backend() == "tpu" \
+            and pallas_eligible(q, k, v, window):
         if scale is None:
             scale = 1.0 / (q.shape[-1] ** 0.5)
-        try:
-            return _pallas_attention(q, k, v, causal, scale)
-        except MXNetError:  # typed contract violation, not a kernel gap
-            raise
-        except Exception:  # unsupported shape/kernel -> portable path
-            pass
+        return _pallas_attention(q, k, v, causal, scale)
     return flash_attention(q, k, v, causal=causal, scale=scale,
                            block=block, window=window)

@@ -59,11 +59,6 @@ def _moe_fn(mesh, axis, top_k):
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    try:
-        shard_map = jax.shard_map
-    except AttributeError:  # older jax
-        from jax.experimental.shard_map import shard_map
-
     n_dev = mesh.shape[axis]
 
     def body(x, gate_w, w1, w2):
@@ -84,14 +79,9 @@ def _moe_fn(mesh, axis, top_k):
         out = jnp.einsum("ebd,be->bd", y, local_probs)
         return lax.psum(out, axis)
 
-    try:
-        fn = shard_map(body, mesh=mesh,
+    fn = jax.shard_map(body, mesh=mesh,
                        in_specs=(P(), P(), P(axis), P(axis)),
                        out_specs=P(), check_vma=False)
-    except TypeError:
-        fn = shard_map(body, mesh=mesh,
-                       in_specs=(P(), P(), P(axis), P(axis)),
-                       out_specs=P(), check_rep=False)
     return jax.jit(fn)
 
 
@@ -244,11 +234,6 @@ def _routed_fn(mesh, axis, top_k, capacity):
     import jax
     from jax.sharding import PartitionSpec as P
 
-    try:
-        shard_map = jax.shard_map
-    except AttributeError:  # older jax
-        from jax.experimental.shard_map import shard_map
-
     n_dev = mesh.shape[axis]
 
     def body(x, gate_w, w1, w2):
@@ -258,8 +243,5 @@ def _routed_fn(mesh, axis, top_k, capacity):
 
     specs = dict(in_specs=(P(axis), P(), P(axis), P(axis)),
                  out_specs=(P(axis), P()))
-    try:
-        fn = shard_map(body, mesh=mesh, check_vma=False, **specs)
-    except TypeError:
-        fn = shard_map(body, mesh=mesh, check_rep=False, **specs)
+    fn = jax.shard_map(body, mesh=mesh, check_vma=False, **specs)
     return jax.jit(fn)

@@ -118,13 +118,8 @@ def init_distributed(coordinator=None, num_processes=None,
     # already joined (e.g. the worker called jax.distributed.initialize
     # itself before any mxnet_tpu entry): a second initialize would
     # raise "must be called before any JAX calls", not "already"
-    try:
-        from jax._src import distributed as _jdist
-
-        if getattr(_jdist.global_state, "client", None) is not None:
-            return True
-    except ImportError:  # pragma: no cover - jax internals moved
-        pass
+    if jax.distributed.is_initialized():
+        return True
     if num_processes is None:
         num_processes = int(os.environ.get("MXNET_NUM_WORKERS", "1"))
     if process_id is None:

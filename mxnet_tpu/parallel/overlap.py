@@ -40,11 +40,12 @@ import importlib.util
 import os
 import warnings
 
-from ..base import get_env
+from ..base import get_env, logger
 
 __all__ = ["arm_latency_hiding", "bucket_partition", "ddp_axis",
            "ddp_batch_factor", "ddp_pmean", "ddp_psum",
-           "ddp_value_and_grad", "grad_bucket_bytes", "overlap_mode"]
+           "ddp_value_and_grad", "grad_bucket_bytes", "lhs_flags_present",
+           "overlap_mode"]
 
 # the MaxText-standard trio: latency-hiding scheduler + async collective
 # fusion.  Delivered via LIBTPU_INIT_ARGS, NOT XLA_FLAGS: only libtpu
@@ -138,17 +139,38 @@ def grad_bucket_bytes():
     return max(0, int(mb * (1 << 20)))
 
 
+_late_logged = False
+
+
+def _backend_initialized():
+    # jax has no public name for "has a backend client been created
+    # yet"; this private one is what jax.distributed.initialize itself
+    # checks to refuse a late call
+    from jax._src import xla_bridge
+
+    return xla_bridge.backends_are_initialized()
+
+
+def lhs_flags_present():
+    """Whether every scheduler flag is in ``LIBTPU_INIT_ARGS`` now."""
+    flags = os.environ.get("LIBTPU_INIT_ARGS", "")
+    return all(f.split("=")[0] in flags for f in _LHS_FLAGS)
+
+
 def arm_latency_hiding():
     """Append the latency-hiding-scheduler flags to ``LIBTPU_INIT_ARGS``
-    (idempotent).
+    (idempotent) — while that can still take effect.
 
-    Best-effort: the flags only take effect when set before the TPU
-    client initializes, so the first ``TrainStep`` construction in a
-    process arms them.  ``auto`` (default) arms only when a TPU is
+    libtpu reads the variable once, when the TPU client initializes.
+    So the flags are appended only if no backend exists yet; a call
+    that arrives later (anything resolved a device first — a
+    ``Context.jax_device`` lookup, ``jax.devices()``, an array) leaves
+    the environment alone, logs once that the flags are NOT armed, and
+    returns False.  ``auto`` (default) arms only when a TPU is
     plausibly present (``JAX_PLATFORMS`` mentions tpu, or libtpu is
-    importable) — CPU/GPU backends never read ``LIBTPU_INIT_ARGS``, so
-    arming is inert there; ``MXNET_XLA_LHS=1`` forces, ``0`` disables.
-    Returns True when the flags are present after the call.
+    importable) — CPU/GPU backends never read ``LIBTPU_INIT_ARGS``;
+    ``MXNET_XLA_LHS=1`` forces, ``0`` disables.  Returns True when the
+    flags were in the environment before the backend initialized.
     """
     mode = str(get_env("MXNET_XLA_LHS", "auto")).strip().lower()
     if mode in ("0", "off", "false", "no"):
@@ -157,6 +179,20 @@ def arm_latency_hiding():
                 or importlib.util.find_spec("libtpu") is not None)
     if mode == "auto" and not tpu_hint:
         return False
+    if _backend_initialized():
+        # nothing is appended from here on, so flags present now were
+        # present when libtpu read the variable
+        global _late_logged
+        armed = lhs_flags_present()
+        if not armed and not _late_logged:
+            _late_logged = True
+            logger.info(
+                "latency-hiding scheduler flags not armed: the backend "
+                "initialized before the first TrainStep was built, and "
+                "libtpu reads LIBTPU_INIT_ARGS only at start-up (set "
+                "them in the environment to arm: %s)",
+                " ".join(_LHS_FLAGS))
+        return armed
     flags = os.environ.get("LIBTPU_INIT_ARGS", "")
     missing = [f for f in _LHS_FLAGS if f.split("=")[0] not in flags]
     if missing:
@@ -227,16 +263,8 @@ def bucket_partition(order, sizes, bucket_bytes):
 def _shard_map(fn, mesh, in_specs, out_specs):
     import jax
 
-    try:
-        smap = jax.shard_map
-    except AttributeError:  # older jax
-        from jax.experimental.shard_map import shard_map as smap
-    try:
-        return smap(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                    check_vma=False)
-    except TypeError:  # older jax spells the flag check_rep
-        return smap(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                    check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def ddp_value_and_grad(loss_fn, params, batch, rng, mesh, axis,

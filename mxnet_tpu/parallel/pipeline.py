@@ -91,11 +91,6 @@ def _pipeline_fn(mesh, axis, stage_fn, params_treedef):
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    try:
-        shard_map = jax.shard_map
-    except AttributeError:  # older jax
-        from jax.experimental.shard_map import shard_map
-
     n_stages = mesh.shape[axis]
 
     def body(params, micro):
@@ -134,12 +129,8 @@ def _pipeline_fn(mesh, axis, stage_fn, params_treedef):
     pspec = jax.tree.unflatten(
         params_treedef,
         [P(axis)] * params_treedef.num_leaves)
-    try:
-        fn = shard_map(body, mesh=mesh, in_specs=(pspec, P()),
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(pspec, P()),
                        out_specs=P(), check_vma=False)
-    except TypeError:
-        fn = shard_map(body, mesh=mesh, in_specs=(pspec, P()),
-                       out_specs=P(), check_rep=False)
     return jax.jit(fn)
 
 
@@ -686,11 +677,6 @@ class PipelineTrainStep:
         from jax import lax
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        try:
-            shard_map = jax.shard_map
-        except AttributeError:  # pragma: no cover - older jax
-            from jax.experimental.shard_map import shard_map
-
         S, M, axis = self.n_stages, self.n_micro, self.axis
         R = 2 * S
         mesh = self.mesh
@@ -966,10 +952,7 @@ class PipelineTrainStep:
         specs = dict(
             in_specs=(pspec, pspec, pspec, P(), P(), P(), P()),
             out_specs=(pspec, pspec, pspec, P()))
-        try:
-            fn = shard_map(body, mesh=mesh, check_vma=False, **specs)
-        except TypeError:
-            fn = shard_map(body, mesh=mesh, check_rep=False, **specs)
+        fn = jax.shard_map(body, mesh=mesh, check_vma=False, **specs)
         row_sh = NamedSharding(mesh, P(axis))
         repl = NamedSharding(mesh, P())
         return jax.jit(

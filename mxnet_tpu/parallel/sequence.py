@@ -62,9 +62,7 @@ def ring_attention(q, k, v, axis_name, causal=False, scale=None):
 
     from ..ops.attention import attend_block, finalize_attention
 
-    # psum of a constant folds to the static axis size on every jax
-    # version; lax.axis_size only exists on newer releases
-    n = lax.psum(1, axis_name)
+    n = lax.axis_size(axis_name)
     rank = lax.axis_index(axis_name)
     t_local = q.shape[-2]
     d = q.shape[-1]
@@ -141,18 +139,9 @@ def _sp_attention_fn(mesh, axis, causal, batch_axes=(), heads_axis=None):
     import jax
     from jax.sharding import PartitionSpec as P
 
-    try:
-        shard_map = jax.shard_map
-    except AttributeError:  # older jax
-        from jax.experimental.shard_map import shard_map
-
     spec = P(batch_axes or None, heads_axis, axis, None)
     body = functools.partial(ring_attention, axis_name=axis,
                              causal=causal)
-    try:
-        fn = shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
                        out_specs=spec, check_vma=False)
-    except TypeError:  # older jax spells the flag check_rep
-        fn = shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                       out_specs=spec, check_rep=False)
     return jax.jit(fn)

@@ -25,6 +25,9 @@ class PallasKernel:
     output refs.  ``out_shapes``: list of (shape, dtype) for outputs.
     Optional ``grid``/``in_specs``/``out_specs`` pass through to
     ``pl.pallas_call``; by default whole arrays land in VMEM.
+    ``interpret=True`` runs the body in the Pallas interpreter — a
+    test mode the caller asks for by name; the default compiles with
+    Mosaic, which needs a TPU.
 
     Example::
 
@@ -36,19 +39,18 @@ class PallasKernel:
     """
 
     def __init__(self, kernel, out_shapes, grid=None, in_specs=None,
-                 out_specs=None, interpret="auto"):
-        import jax
-
+                 out_specs=None, interpret=False):
         self._kernel = kernel
         self._out_shapes = [(tuple(s), d) for (s, d) in out_shapes]
         self._grid = grid
         self._in_specs = in_specs
         self._out_specs = out_specs
-        if interpret == "auto":
-            # Mosaic compiles only on real TPU backends; everywhere else
-            # (CPU tests) the interpreter runs the same kernel
-            interpret = jax.default_backend() != "tpu"
-        self._interpret = bool(interpret)
+        if not isinstance(interpret, bool):
+            raise MXNetError(
+                "PallasKernel(interpret=%r): pass True (Pallas "
+                "interpreter, for tests off-TPU) or False (Mosaic)"
+                % (interpret,))
+        self._interpret = interpret
         self._compiled = None
 
     def _build(self):

@@ -700,13 +700,14 @@ class InferenceSession(object):
         rec = self._exes[name]
         sig = signature_of(args)
         rec.guard.observe(sig)
-        try:
-            return rec.compiled(*args)
-        except Exception:
-            # Shape/dtype drift from the compiled avals (guarded above)
-            # falls back to the lazy jit rather than failing the request.
+        if sig != rec.aval_sig:
+            # Shape/dtype drift from the compiled avals (reported by the
+            # guard above) runs through the lazy jit rather than failing
+            # the request.  Nothing else does: an error of the compiled
+            # executable — a device fault, out of memory — raises.
             rec.fallbacks += 1
             return rec.jitted(*args)
+        return rec.compiled(*args)
 
     def _pool_args(self, cache):
         """The pool arguments a dispatch appends, in the canonical

@@ -46,12 +46,9 @@ def main():
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    # recent jax CPU clients reject cross-process programs unless a
+    # jax CPU clients reject cross-process programs unless a
     # collectives implementation is chosen before backend creation
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # older jax: no flag, multiprocess just works
-        pass
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(coordinator_address=coordinator,
                                num_processes=num_procs,
                                process_id=rank)

@@ -59,12 +59,9 @@ def main():
             sys.argv[3], int(sys.argv[4]), int(sys.argv[5])
         # the split path is the multi-process contract under test
         os.environ["MXNET_FUSED_STEP"] = "0"
-        # recent jax CPU clients reject cross-process programs unless a
+        # jax CPU clients reject cross-process programs unless a
         # collectives implementation is chosen before backend creation
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:  # older jax: no flag, multiprocess just works
-            pass
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
         jax.distributed.initialize(coordinator_address=coordinator,
                                    num_processes=num_procs,
                                    process_id=rank)

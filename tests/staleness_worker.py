@@ -68,12 +68,9 @@ def main():
     import jax
 
     jax.config.update("jax_platforms", "cpu")
-    # recent jax CPU clients reject cross-process programs unless a
+    # jax CPU clients reject cross-process programs unless a
     # collectives implementation is chosen before backend creation
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # older jax: no flag, multiprocess just works
-        pass
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(coordinator_address=coordinator,
                                num_processes=nprocs, process_id=rank)
     import numpy as np

@@ -307,6 +307,8 @@ def test_persistent_cache_roundtrip_across_processes(tmp_path):
     """Second process compiling the same program must be served from the
     persistent cache: hits > 0 and a (much) smaller compile_s."""
     env = dict(os.environ)
+    # this test places its own cache: JAX's variable would outrank it
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     env.update(JAX_PLATFORMS="cpu",
                MXNET_COMPILE_CACHE_DIR=str(tmp_path / "xla"),
                MXNET_COMPILE_CACHE_MIN_COMPILE_S="0")
@@ -329,6 +331,8 @@ def test_persistent_cache_roundtrip_across_processes(tmp_path):
 
 def test_cache_opt_out_via_empty_dir(tmp_path):
     env = dict(os.environ)
+    # this test places its own cache: JAX's variable would outrank it
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     env.update(JAX_PLATFORMS="cpu", MXNET_COMPILE_CACHE_DIR="")
     proc = subprocess.run(
         [sys.executable, "-c",
@@ -361,8 +365,10 @@ def test_write_artifact_and_report_tool(tmp_path):
 
 def test_bench_budget_emits_partial_json(tmp_path):
     """A budget-expired bench run must still print one parseable JSON
-    line (the BENCH_r05 'parsed: null' regression)."""
+    line, not die at the harness timeout with no output."""
     env = dict(os.environ)
+    # this test places its own cache: JAX's variable would outrank it
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     env.update(JAX_PLATFORMS="cpu",
                MXNET_COMPILE_CACHE_DIR=str(tmp_path / "xla"),
                MXNET_BENCH_BUDGET_S="3")

@@ -126,7 +126,8 @@ def test_launcher_local_sets_env(tmp_path):
 
 def test_rtc_pallas_kernel():
     """The MXRtc analogue: user-defined Pallas kernels run over NDArrays
-    (interpret mode on CPU; Mosaic on real TPU)."""
+    (interpret mode here, asked for by name; Mosaic is the default and
+    needs a TPU)."""
     from mxnet_tpu.rtc import PallasKernel
 
     def body(x_ref, y_ref, o_ref):
@@ -134,7 +135,7 @@ def test_rtc_pallas_kernel():
 
     x = np.random.RandomState(0).randn(16, 128).astype("float32")
     y = np.random.RandomState(1).randn(16, 128).astype("float32")
-    k = PallasKernel(body, [((16, 128), "float32")])
+    k = PallasKernel(body, [((16, 128), "float32")], interpret=True)
     (out,) = k(mx.nd.array(x), mx.nd.array(y))
     np.testing.assert_allclose(out.asnumpy(), x * 2 + y, rtol=1e-6)
 

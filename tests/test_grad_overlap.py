@@ -59,16 +59,25 @@ def test_arm_latency_hiding_uses_libtpu_args(monkeypatch):
     monkeypatch.setenv("LIBTPU_INIT_ARGS", "--preexisting=1")
     monkeypatch.setenv("XLA_FLAGS", "")
     monkeypatch.setenv("MXNET_XLA_LHS", "1")
-    assert overlap.arm_latency_hiding()
+    # a late call (this process has a backend by now) must not claim to
+    # have armed anything, nor touch the environment
+    monkeypatch.setattr(overlap, "_backend_initialized", lambda: True)
+    assert not overlap.arm_latency_hiding()
     import os
+
+    assert os.environ["LIBTPU_INIT_ARGS"] == "--preexisting=1"
+    monkeypatch.setattr(overlap, "_backend_initialized", lambda: False)
+    assert overlap.arm_latency_hiding()
 
     armed = os.environ["LIBTPU_INIT_ARGS"]
     assert "--preexisting=1" in armed
     assert "--xla_tpu_enable_latency_hiding_scheduler=true" in armed
     assert os.environ["XLA_FLAGS"] == ""
-    # idempotent
+    # idempotent, and a later call sees flags that were there in time
     assert overlap.arm_latency_hiding()
     assert os.environ["LIBTPU_INIT_ARGS"] == armed
+    monkeypatch.setattr(overlap, "_backend_initialized", lambda: True)
+    assert overlap.arm_latency_hiding()
     monkeypatch.setenv("MXNET_XLA_LHS", "0")
     assert not overlap.arm_latency_hiding()
 

@@ -385,8 +385,8 @@ def test_pipeline_module_fit_trains_lm():
     it = mx.io.NDArrayIter(data, label, batch_size=16)
     mesh = create_mesh({"pipe": 4}, devices=jax.devices()[:4])
     with mesh_scope(mesh):
-        mod = mx.mod.Module(sym, context=mx.tpu(0), pipeline_stages=4,
-                            pipeline_microbatches=4)
+        mod = mx.mod.Module(sym, context=mx.current_context(),
+                            pipeline_stages=4, pipeline_microbatches=4)
         mod.fit(it, num_epoch=15, optimizer="adam",
                 kvstore="dist_tpu_sync",
                 optimizer_params={"learning_rate": 0.02},
@@ -606,8 +606,8 @@ def test_pipeline_module_fit_trains_bn_dropout_resnet():
     it = mx.io.NDArrayIter(data, label, batch_size=16)
     mesh = create_mesh({"pipe": 4}, devices=jax.devices()[:4])
     with mesh_scope(mesh):
-        mod = mx.mod.Module(sym, context=mx.tpu(0), pipeline_stages=4,
-                            pipeline_microbatches=4)
+        mod = mx.mod.Module(sym, context=mx.current_context(),
+                            pipeline_stages=4, pipeline_microbatches=4)
         mod.fit(it, num_epoch=30, optimizer="adam",
                 kvstore="dist_tpu_sync",
                 optimizer_params={"learning_rate": 0.01},
@@ -641,7 +641,7 @@ def test_moe_transformer_trains_expert_parallel():
     it = mx.io.NDArrayIter(toks, labels, batch_size=n)
     mesh = create_mesh({"expert": 4}, devices=jax.devices()[:4])
     with mesh_scope(mesh):
-        mod = mx.mod.Module(sym, context=mx.tpu(0))
+        mod = mx.mod.Module(sym, context=mx.current_context())
         mod.fit(it, num_epoch=12, optimizer="adam",
                 kvstore="dist_tpu_sync",
                 optimizer_params={"learning_rate": 0.02},
@@ -667,8 +667,8 @@ def test_pipeline_checkpoint_roundtrip(tmp_path):
     mesh = create_mesh({"pipe": 4}, devices=jax.devices()[:4])
     prefix = str(tmp_path / "pipe_ckpt")
     with mesh_scope(mesh):
-        mod = mx.mod.Module(sym, context=mx.tpu(0), pipeline_stages=4,
-                            pipeline_microbatches=4)
+        mod = mx.mod.Module(sym, context=mx.current_context(),
+                            pipeline_stages=4, pipeline_microbatches=4)
         mod.fit(it, num_epoch=2, optimizer="adam",
                 kvstore="dist_tpu_sync",
                 optimizer_params={"learning_rate": 0.02},

@@ -414,6 +414,8 @@ def test_bench_serve_budget_emits_partial_json(tmp_path):
     subprocess plus the 2s budget costs ~10s of wall clock."""
     env = dict(os.environ)
     env.pop("MXNET_FAULT_INJECT", None)
+    # this test places its own cache: JAX's variable would outrank it
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     env.update(JAX_PLATFORMS="cpu",
                MXNET_COMPILE_CACHE_DIR=str(tmp_path / "xla"),
                MXNET_BENCH_BUDGET_S="2")

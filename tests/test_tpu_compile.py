@@ -1,0 +1,91 @@
+"""The main path's kernels compile for the chip — without the chip.
+
+The TPU compiler is installed next to the CPU backend and compiles for a
+topology that is described, not attached (``v5e:2x2``, device kind "TPU
+v5 lite").  Interpret-mode tests cannot see what Mosaic refuses (tiling,
+VMEM budget); these can, at the real shapes: the two BatchNorm reduction
+kernels at ResNet-50 shapes and the library flash-attention kernel that
+``MXNET_ATTN_IMPL=auto`` dispatches to on TPU, forward and backward, at
+the transformer bench shape.  Nothing runs, so nothing here is a result
+or a time — a compile that passes is not a chip run.
+
+All of it lives in this one file, and the topology is described inside a
+module-scoped fixture: only the xdist worker that is handed this file
+loads the TPU library, and only once a test of it has started.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+BN_SHAPES = [(64, 256, 56, 56), (512, 64, 112, 112)]
+FLASH_SHAPE = (8, 16, 1024, 128)  # B, H, T, D of the 8L-d2048 config
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A sharding on one described v5e chip, with the persistent
+    compilation cache off around the module: an executable compiled for
+    a described chip can be written to it but not read back here."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # mxlint: disable=MX008 — whatever the describe call raises means "cannot be described here"
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *avals):
+    # conftest asks for "highest" matmul precision so CPU results match
+    # numpy; the programs the chip runs are traced at the default, and
+    # Mosaic refuses an fp32-precision matmul over bf16 operands ("Bad
+    # lhs type") — so trace these as production does
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(fn).lower(*avals).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+@pytest.mark.parametrize("shape", BN_SHAPES, ids=str)
+def test_bn_stats_compiles_for_v5e(one_chip, shape):
+    from mxnet_tpu.ops.pallas_bn import bn_stats
+
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    _compile(bn_stats, x)
+
+
+def test_bn_grad_sums_compiles_for_v5e(one_chip):
+    from mxnet_tpu.ops.pallas_bn import bn_grad_sums
+
+    shape = BN_SHAPES[0]
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+    stat = jax.ShapeDtypeStruct((shape[1],), jnp.float32,
+                                sharding=one_chip)
+    _compile(bn_grad_sums, x, x, stat, stat)
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "bwd"])
+def test_flash_attention_compiles_for_v5e(one_chip, backward):
+    from mxnet_tpu.ops import attention
+
+    q = jax.ShapeDtypeStruct(FLASH_SHAPE, jnp.bfloat16, sharding=one_chip)
+    # the shape the dispatcher must judge fit for the kernel
+    assert attention.pallas_eligible(q, q, q)
+    scale = FLASH_SHAPE[-1] ** -0.5
+
+    def fwd(q, k, v):
+        return attention._pallas_attention(q, k, v, True, scale)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if backward else fwd
+    _compile(fn, q, q, q)

@@ -97,11 +97,7 @@ def main():
     if DIST:
         coordinator, num_procs, rank = \
             sys.argv[3], int(sys.argv[4]), int(sys.argv[5])
-        try:
-            jax.config.update("jax_cpu_collectives_implementation",
-                              "gloo")
-        except Exception:  # older jax: no flag, multiprocess just works
-            pass
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
         jax.distributed.initialize(coordinator_address=coordinator,
                                    num_processes=num_procs,
                                    process_id=rank)

@@ -96,6 +96,15 @@ def _worker_env(env, coordinator, num_workers, rank, elastic_dir=None):
 
 
 def launch_local(num_workers, command, env, elastic_dir=None):
+    """Start ``num_workers`` copies of ``command`` on THIS host, joined
+    through one ``jax.distributed`` coordinator.
+
+    For CPU workers (the multi-process test tier).  It gives no worker
+    a chip of its own: every worker inherits the same environment, so
+    on a TPU host each one reaches for ALL chips, and a chip belongs to
+    one process at a time — the second worker fails or hangs.  One
+    process drives all chips of a host (``python chip_smoke.py --chips
+    4`` does); do not use this launcher for that."""
     coordinator = "127.0.0.1:%d" % _free_port()
     procs = [subprocess.Popen(
         command, env=_worker_env(env, coordinator, num_workers, rank,

@@ -23,7 +23,8 @@ def main(argv=None):
                     "(docs/static_analysis.md).")
     ap.add_argument("paths", nargs="*",
                     help="files/dirs to scan (default: mxnet_tpu tools "
-                         "bench*.py __graft_entry__.py under the repo "
+                         "bench*.py __graft_entry__.py chip_smoke.py "
+                         "tests/test_tpu_compile.py under the repo "
                          "root)")
     ap.add_argument("--select", default="",
                     help="comma-separated codes to run (default: all)")
@@ -74,7 +75,10 @@ def main(argv=None):
     if not paths:
         paths = [os.path.join(root, "mxnet_tpu"),
                  os.path.join(root, "tools"),
-                 os.path.join(root, "__graft_entry__.py")]
+                 os.path.join(root, "__graft_entry__.py"),
+                 os.path.join(root, "chip_smoke.py"),
+                 # the one test file that compiles for the chip
+                 os.path.join(root, "tests", "test_tpu_compile.py")]
         import glob as _glob
         paths += sorted(_glob.glob(os.path.join(root, "bench*.py")))
         paths = [p for p in paths if os.path.exists(p)]

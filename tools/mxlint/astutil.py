@@ -113,7 +113,7 @@ def qualname(node, parents):
 # callables whose function argument is traced by jax
 TRACING_CALLS = (
     "jax.jit", "jit", "pjit", "jax.pmap", "pmap",
-    "shard_map", "jax.experimental.shard_map.shard_map",
+    "shard_map", "jax.shard_map",
     "jax.checkpoint", "jax.remat", "remat", "checkpoint",
     "lax.scan", "scan", "lax.cond", "cond", "lax.while_loop",
     "while_loop", "lax.fori_loop", "fori_loop", "lax.switch",

@@ -114,8 +114,9 @@ class ProjectContext(object):
 
     ``files`` is the list of scanned FileContexts; ``root`` the repo
     root.  ``library_files()`` parses the *canonical* code set
-    (mxnet_tpu/, tools/, bench*.py, __graft_entry__.py) even when the
-    CLI was pointed at a subset, so registry comparisons are stable.
+    (mxnet_tpu/, tools/, bench*.py, __graft_entry__.py, chip_smoke.py)
+    even when the CLI was pointed at a subset, so registry comparisons
+    are stable.
     """
 
     def __init__(self, root, files):
@@ -141,7 +142,7 @@ class ProjectContext(object):
                     canon_rel.add(os.path.relpath(p, self.root))
         for name in sorted(os.listdir(self.root)):
             if fnmatch.fnmatch(name, "bench*.py") or \
-                    name == "__graft_entry__.py":
+                    name in ("__graft_entry__.py", "chip_smoke.py"):
                 canon_rel.add(name)
         by_rel = {f.relpath: f for f in self.files}
         out = []
