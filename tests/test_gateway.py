@@ -33,6 +33,8 @@ from mxnet_tpu.serve import gateway as gw_mod
 from mxnet_tpu.serve import model as serve_model
 from mxnet_tpu.testing import faults
 
+from serve_util import lend
+
 CFG = serve.ModelConfig(vocab_size=61, num_layers=2, d_model=32,
                         num_heads=2, max_len=64)
 SCONF = serve.ServeConfig(slots=3, page_size=8, buckets=(8, 16),
@@ -65,9 +67,7 @@ def _pool(params):
 
 @pytest.fixture
 def pool(_pool):
-    yield _pool
-    for sess in _pool:
-        sess.reset_cold()
+    yield from lend(*_pool)
 
 
 @pytest.fixture
@@ -210,7 +210,9 @@ def test_stream_matches_in_process_oracle(pool, oracle):
             "stream": False})
         assert status == 200
         assert json.loads(body)["tokens"] == oracle[0]
-        assert gw.counters["streams_completed"] == 4
+        # the whole-body reply is counted after it is written: the
+        # client can hold its answer before the loop thread has counted
+        assert _wait(lambda: gw.counters["streams_completed"] == 4)
     assert gw.incident_path is None  # clean runs write no artifact
 
 

@@ -2,7 +2,8 @@
 bounds per storage dtype, cross-process bit-stability, the ZeRO-3
 flat-tile interchange (topology-independent codes, gather-path
 dequantization, quantized elastic checkpoint restore), and quantized
-serving sessions with the per-precision bit-exactness contract.
+serving sessions held to the per-precision reference
+(tests/closeness.py).
 
 Also the fp8 TRAINING surface that module grew: delayed-scaling
 helpers (amax history, realized scales, the fp8_trace site registry),
@@ -23,6 +24,9 @@ from mxnet_tpu import quantize, serve
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.parallel import create_mesh, zero
 from mxnet_tpu.serve import model as serve_model
+
+from closeness import LIMIT_SPACINGS
+from serve_util import worst_gap_vs_reference
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -339,14 +343,20 @@ def test_quantized_tile_save_restores_on_any_topology(params, tmp_path):
 # quantized serving sessions
 # ---------------------------------------------------------------------------
 
+def _probe(seed):
+    return [np.random.RandomState(seed).randint(
+        1, CFG.vocab_size, size=6).tolist()]
+
+
 @pytest.mark.parametrize("mode", ["int8", "fp8"])
 def test_quantized_session_bitexact_per_precision(params, mode,
                                                   monkeypatch):
-    """The serving bit-exactness oracle survives quantization: paged
-    decode over the quantized tree == the jitted full-context reference
-    over the SAME quantized tree, the executable count stays frozen
-    under MXNET_RECOMPILE_ERROR=1, and the guard prefix carries the
-    quant tag so precisions never alias."""
+    """The serving oracle survives quantization: paged decode over the
+    quantized tree matches the jitted full-context reference over the
+    SAME quantized tree as closely as two executables can (sound: at
+    most 4 spacings over 12 seeds, jax 0.9.0), the executable count
+    stays frozen under MXNET_RECOMPILE_ERROR=1, and the guard prefix
+    carries the quant tag so precisions never alias."""
     monkeypatch.setenv("MXNET_RECOMPILE_ERROR", "1")
     sess = serve.InferenceSession(params, num_heads=CFG.num_heads,
                                   config=_sconf(quant=mode))
@@ -355,21 +365,8 @@ def test_quantized_session_bitexact_per_precision(params, mode,
     assert "-q%s" % mode in sess._guard_prefix
     assert quantize.is_quantized(sess.params["blk0_ffn1_weight"])
 
-    def ref_row(seq):
-        return np.asarray(serve_model.reference_last_logits(
-            sess.params, seq, CFG, PAGE, exact=True))
-
-    probe = list(np.random.RandomState(5).randint(1, CFG.vocab_size,
-                                                  size=6))
-    slot = sess.try_alloc(len(probe), 6)
-    first, logits = sess.prefill(slot, probe)
-    np.testing.assert_array_equal(logits, ref_row(probe))
-    seq = list(probe) + [first]
-    for _ in range(5):
-        toks, step_logits = sess.step()
-        np.testing.assert_array_equal(step_logits[slot], ref_row(seq))
-        seq.append(toks[slot])
-    sess.release(slot)
+    assert worst_gap_vs_reference(sess, _probe(5), steps=5,
+                                  max_new=6) <= LIMIT_SPACINGS
     assert len(sess.executables) == len(sess.config.buckets) + 1
 
     # at-rest accounting: the quantized tree really is ~4x smaller on
@@ -590,10 +587,62 @@ def test_fp8_off_keeps_legacy_hstate_free_path(monkeypatch):
         np.testing.assert_array_equal(ref[k], again[k], err_msg=k)
 
 
+def _np_fp8_mlp(p0, x, y, n, lr=0.1):
+    """The delayed-scaling fp8 recipe of ``_fp8_train_step``'s net in
+    plain numpy, independent of the program: e4m3 fake-casts of both
+    operands at max(history) / FP8_MAX (1.0 while the history is
+    empty), e5m2 fake-cast of the cotangent at its own amax, SGD."""
+    import ml_dtypes
+
+    def fake(v, scale, qmax, dtype):
+        scale = np.float32(scale)
+        return (np.clip(v / scale, -qmax, qmax).astype(dtype)
+                .astype(np.float32) * scale)
+
+    def fwd(v, w, scales):
+        vq = fake(v, scales[0], quantize.FP8_MAX, ml_dtypes.float8_e4m3fn)
+        wq = fake(w, scales[1], quantize.FP8_MAX, ml_dtypes.float8_e4m3fn)
+        return vq @ wq.T, (vq, wq)
+
+    def bwd(saved, g):
+        vq, wq = saved
+        amax = np.abs(g).max()
+        g = fake(g, amax / quantize.FP8_E5M2_MAX if amax > 0 else 1.0,
+                 quantize.FP8_E5M2_MAX, ml_dtypes.float8_e5m2)
+        return g @ wq, g.T @ vq
+
+    p = {k: v.copy() for k, v in p0.items()}
+    hist = np.zeros((2, 2, quantize.FP8_AMAX_HISTORY), np.float32)
+    for _ in range(n):
+        hmax = hist.max(-1)
+        scales = np.where(hmax > 0, hmax / quantize.FP8_MAX, 1.0)
+        h, saved1 = fwd(x, p["fc1_weight"], scales[0])
+        h = h + p["fc1_bias"]
+        act = np.maximum(h, 0)
+        z, saved2 = fwd(act, p["fc2_weight"], scales[1])
+        z = z + p["fc2_bias"]
+        e = np.exp(z - z.max(1, keepdims=True))
+        g = e / e.sum(1, keepdims=True)
+        g[np.arange(len(y)), y.astype(int)] -= 1
+        amax = [[np.abs(x).max(), np.abs(p["fc1_weight"]).max()],
+                [np.abs(act).max(), np.abs(p["fc2_weight"]).max()]]
+        dact, dw2 = bwd(saved2, g)
+        dh = dact * (h > 0)
+        _, dw1 = bwd(saved1, dh)
+        for k, d in (("fc1_weight", dw1), ("fc1_bias", dh.sum(0)),
+                     ("fc2_weight", dw2), ("fc2_bias", g.sum(0))):
+            p[k] = (p[k] - lr * d).astype(np.float32)
+        hist = np.concatenate(
+            [np.asarray(amax, np.float32)[..., None], hist[..., :-1]], -1)
+    return p
+
+
 def test_fp8_on_trains_and_rolls_amax_history(monkeypatch):
     """MXNET_FP8=on: both FC matmuls claim fp8 sites, the (sites, 2,
-    HISTORY) amax history advances every step, and the fp8 trajectory
-    lands near the full-precision one."""
+    HISTORY) amax history advances every step, and the trajectory is
+    the fp8 recipe's own: it equals an independent numpy emulation of
+    the recipe (``_np_fp8_mlp``) to float32 rounding, and lands as near
+    the full-precision trajectory as that recipe does."""
     monkeypatch.setenv("MXNET_FP8", "off")
     step, params, aux, states, batch, rng = _fp8_train_step()
     ref = _run_params(step, params, aux, states, batch, rng)
@@ -601,18 +650,27 @@ def test_fp8_on_trains_and_rolls_amax_history(monkeypatch):
     monkeypatch.setenv("MXNET_FP8", "on")
     fstep, params, aux, states, batch, rng = _fp8_train_step()
     assert fstep._fp8 and fstep._use_hstate
-    p0 = np.asarray(params["fc1_weight"]).copy()  # before donation
+    p0 = {k: np.asarray(v).copy() for k, v in params.items()}  # donated
     got = _run_params(fstep, params, aux, states, batch, rng)
     assert fstep._fp8_sites == 2  # fc1 + fc2
     hist = np.asarray(fstep._hstate["fp8_hist"])
     assert hist.shape == (2, 2, quantize.FP8_AMAX_HISTORY)
     assert (hist[:, :, :5] > 0).all()  # 5 steps: 5 fresh amax columns
     assert (hist[:, :, 5:] == 0).all()  # older slots still virgin
+    want = _np_fp8_mlp(p0, batch["data"], batch["softmax_label"], n=5)
     for k in ref:
         assert np.isfinite(got[k]).all(), k
+        # reading: 1.2e-7 here, at most 3.6e-7 over six seeds
+        np.testing.assert_allclose(got[k], want[k], rtol=0, atol=1e-5,
+                                   err_msg=k)
+        # The recipe's own distance from float32 after five steps of
+        # lr 0.1 that move the weights by 0.79: fc1_weight 0.184 here
+        # (0.077-0.415 over six seeds, the emulation reading the same
+        # to 1e-6), so 0.184 is the arithmetic's level under jax 0.9.0's
+        # random stream, not a fault of the fp8 path.  Limit: 3x.
         drift = np.max(np.abs(got[k] - ref[k]))
-        assert drift <= 0.1, (k, drift)
-    assert not np.array_equal(got["fc1_weight"], p0)  # it really trained
+        assert drift <= 0.6, (k, drift)
+    assert not np.array_equal(got["fc1_weight"], p0["fc1_weight"])
 
 
 def test_fp8_layers_filters_sites(monkeypatch):
@@ -706,31 +764,60 @@ def test_kv_quant_config_from_env(monkeypatch):
 def test_kv_quant_session_bitexact_per_precision(params, mode,
                                                  monkeypatch):
     """Quantized KV pages keep the serving oracle: paged decode over
-    int8/e4m3 pages == the jitted full-context reference running the
-    SAME per-row fake quantization, the executable count stays frozen
-    under MXNET_RECOMPILE_ERROR=1, and the guard prefix carries the kv
-    tag so precisions never alias an f32 session's executables."""
+    int8/e4m3 pages matches the jitted full-context reference running
+    the SAME per-row fake quantization as closely as two executables
+    can (sound: at most 4 spacings over 12 seeds), the executable count
+    stays frozen under MXNET_RECOMPILE_ERROR=1, and the guard prefix
+    carries the kv tag so precisions never alias an f32 session's
+    executables."""
     monkeypatch.setenv("MXNET_RECOMPILE_ERROR", "1")
     sess = serve.InferenceSession(params, num_heads=CFG.num_heads,
                                   config=_sconf(kv_quant=mode))
     assert "-kv%s" % mode in sess._guard_prefix
-
-    def ref_row(seq):
-        return np.asarray(serve_model.reference_last_logits(
-            sess.params, seq, CFG, PAGE, exact=True, kv_quant=mode))
-
-    probe = list(np.random.RandomState(6).randint(1, CFG.vocab_size,
-                                                  size=6))
-    slot = sess.try_alloc(len(probe), 6)
-    first, logits = sess.prefill(slot, probe)
-    np.testing.assert_array_equal(logits, ref_row(probe))
-    seq = list(probe) + [first]
-    for _ in range(5):
-        toks, step_logits = sess.step()
-        np.testing.assert_array_equal(step_logits[slot], ref_row(seq))
-        seq.append(toks[slot])
-    sess.release(slot)
+    assert worst_gap_vs_reference(sess, _probe(6), steps=5,
+                                  max_new=6) <= LIMIT_SPACINGS
     assert len(sess.executables) == len(sess.config.buckets) + 1
+
+
+def _wrong_channel_scale(sess, slots):
+    """Weight quantization's smallest fault: two output channels of one
+    weight swap their scales."""
+    rec = sess.params["blk0_ffn1_weight"]
+    scale = np.array(rec["s"])
+    scale[[0, 1]] = scale[[1, 0]]
+    sess.params = dict(sess.params,
+                       blk0_ffn1_weight={"q": rec["q"], "s": scale})
+
+
+def _wrong_row_scale(sess, slots):
+    """KV quantization's smallest fault: two token rows of one page swap
+    their key scales."""
+    import jax.numpy as jnp
+
+    cache = sess.cache
+    page = int(cache._tables[slots[0], 0])
+    scale = np.array(cache.k_scale)
+    scale[:, page, [0, 1]] = scale[:, page, [1, 0]]
+    cache.k_scale = jnp.asarray(scale)
+
+
+@pytest.mark.parametrize("kw,plant", [
+    (dict(quant="int8"), _wrong_channel_scale),
+    (dict(kv_quant="int8"), _wrong_row_scale),
+    (dict(kv_quant="fp8"), _wrong_row_scale)],
+    ids=["quant-int8", "kv-int8", "kv-fp8"])
+def test_quantized_session_comparison_sees_planted_fault(params, kw,
+                                                         plant):
+    """The control of the two comparisons above, where the limit is 32:
+    swapped channel scales read 88 773 spacings (74 862-273 549 over 5
+    seeds, int8 and fp8), a KV scale of the wrong row 6 374 (int8) and
+    13 651 (fp8; 1 501-13 651 over 5 seeds).  The reference keeps the
+    sound weights."""
+    sess = serve.InferenceSession(params, num_heads=CFG.num_heads,
+                                  config=_sconf(**kw))
+    assert worst_gap_vs_reference(
+        sess, _probe(6), steps=5, max_new=6, plant=plant,
+        ref_params=sess.params) > 30 * LIMIT_SPACINGS
 
 
 def test_spec_decoding_composes_with_kv_quant(params):
@@ -762,32 +849,18 @@ def test_spec_decoding_composes_with_kv_quant(params):
 
 def test_prefix_hit_bitexact_on_quantized_pages(params):
     """A prefix hit that maps an already-quantized page prefills only
-    the suffix, and both streams stay bit-exact against the
-    per-precision reference — the mapped codes and scale rows ARE the
-    cold-miss ones, byte for byte."""
+    the suffix, and both streams stay on the per-precision reference —
+    the mapped codes and scale rows ARE the cold-miss ones."""
     sess = serve.InferenceSession(
         params, num_heads=CFG.num_heads,
         config=_sconf(kv_quant="int8", prefix_pages=-1))
-
-    def ref_row(seq):
-        return np.asarray(serve_model.reference_last_logits(
-            sess.params, seq, CFG, PAGE, exact=True, kv_quant="int8"))
-
     shared = [5, 9, 2, 11, 3, 7, 8, 4]  # one full page
     p_cold = shared + [1, 6]
     p_hit = shared + [2, 9, 14]
-    s_cold = sess.try_alloc(len(p_cold), 4, tokens=p_cold)
-    first_c, logits_c = sess.prefill(s_cold, p_cold)
-    s_hit = sess.try_alloc(len(p_hit), 4, tokens=p_hit)
-    assert sess.cache.cached_len(s_hit) == PAGE  # mapped, not recomputed
-    first_h, logits_h = sess.prefill(s_hit, p_hit)
-    np.testing.assert_array_equal(logits_c, ref_row(p_cold))
-    np.testing.assert_array_equal(logits_h, ref_row(p_hit))
-    seqs = {s_cold: p_cold + [first_c], s_hit: p_hit + [first_h]}
-    for _ in range(3):
-        toks, logits = sess.step()
-        for slot, seq in seqs.items():
-            np.testing.assert_array_equal(logits[slot], ref_row(seq))
-            seq.append(toks[slot])
-    sess.release(s_cold)
-    sess.release(s_hit)
+
+    def mapped(sess, slots):
+        assert sess.cache.cached_len(slots[0]) == 0
+        assert sess.cache.cached_len(slots[1]) == PAGE  # not recomputed
+
+    assert worst_gap_vs_reference(sess, [p_cold, p_hit], steps=3,
+                                  max_new=4, plant=mapped) <= LIMIT_SPACINGS

@@ -14,6 +14,8 @@ from mxnet_tpu.base import MXNetError
 from mxnet_tpu.serve import model as serve_model
 from mxnet_tpu.testing import faults
 
+from serve_util import lend
+
 CFG = serve.ModelConfig(vocab_size=61, num_layers=2, d_model=32,
                         num_heads=2, max_len=64)
 SCONF = serve.ServeConfig(slots=3, page_size=8, buckets=(8, 16),
@@ -41,9 +43,7 @@ def _pool(params):
 
 @pytest.fixture
 def pool(_pool):
-    yield _pool
-    for sess in _pool:
-        sess.reset_cold()
+    yield from lend(*_pool)
 
 
 def _mk(n=8, max_new=6):

@@ -14,6 +14,9 @@ from mxnet_tpu.serve.kv_cache import PagedKVCache
 from mxnet_tpu.serve.scheduler import Request, Scheduler, summarize
 from mxnet_tpu.testing import faults
 
+from closeness import assert_close_across_executables
+from serve_util import lend
+
 CFG = serve.ModelConfig(vocab_size=61, num_layers=2, d_model=32,
                         num_heads=2, max_len=64)
 PAGE = 8
@@ -33,7 +36,7 @@ def params():
 
 
 @pytest.fixture(scope="module")
-def prefix_session(params):
+def _prefix_session(params):
     """Reservation admission + prefix cache (the hit/CoW tests)."""
     sconf = serve.ServeConfig(slots=3, page_size=PAGE, buckets=(8, 16),
                               max_new=8, exact=True, prefix_pages=-1)
@@ -41,8 +44,13 @@ def prefix_session(params):
                                   config=sconf)
 
 
+@pytest.fixture
+def prefix_session(_prefix_session):
+    yield from lend(_prefix_session)
+
+
 @pytest.fixture(scope="module")
-def oversub_session(params):
+def _oversub_session(params):
     """Oversubscribed 5-page pool: 3 one-page prompts admit, growth at
     decode boundaries forces watermark preemption."""
     sconf = serve.ServeConfig(slots=3, page_size=PAGE, buckets=(8, 16),
@@ -50,6 +58,11 @@ def oversub_session(params):
                               oversub=True, prefix_pages=-1)
     return serve.InferenceSession(params, num_heads=CFG.num_heads,
                                   config=sconf)
+
+
+@pytest.fixture
+def oversub_session(_oversub_session):
+    yield from lend(_oversub_session)
 
 
 def _greedy_oracle(sess, prompt, max_new):
@@ -177,8 +190,9 @@ def test_oversub_alloc_admits_by_current_need():
 def test_prefix_hit_bitexact_vs_cold_miss(prefix_session):
     """Two prompts sharing a full first page: the second admission maps
     the published page, prefills only the suffix, and its logits (and
-    every decode step after) are bit-identical to the full-context
-    reference — i.e. to what a cold prefill computes."""
+    every decode step after) match the full-context reference — i.e.
+    what a cold prefill computes — as closely as two executables can
+    (tests/closeness.py)."""
     sess = prefix_session
     lookups0 = sess.cache.prefix_stats["lookups"]
     shared = [5, 9, 2, 11, 3, 7, 8, 4]  # one full page
@@ -193,28 +207,26 @@ def test_prefix_hit_bitexact_vs_cold_miss(prefix_session):
     for seq, logits in ((p_cold, logits_c), (p_hit, logits_h)):
         ref = np.asarray(serve_model.reference_last_logits(
             sess.params, seq, CFG, PAGE, exact=True))
-        np.testing.assert_array_equal(logits, ref)
+        assert_close_across_executables(logits, ref)
     stats = sess.cache.prefix_stats
     assert stats["lookups"] - lookups0 == 2
     assert stats["hit_tokens"] >= PAGE
-    # decode both: streams stay bit-exact with a shared mapped page
+    # decode both: streams stay on the reference with a shared mapped page
     seqs = {s_cold: p_cold + [first_c], s_hit: p_hit + [first_h]}
     for _ in range(3):
         toks, logits = sess.step()
         for slot, seq in seqs.items():
             ref = np.asarray(serve_model.reference_last_logits(
                 sess.params, seq, CFG, PAGE, exact=True))
-            np.testing.assert_array_equal(logits[slot], ref)
+            assert_close_across_executables(logits[slot], ref)
             seq.append(toks[slot])
-    sess.release(s_cold)
-    sess.release(s_hit)
 
 
 def test_cow_divergence_never_mutates_shared_page(prefix_session):
     """Force the copy-on-write guard on a page two slots share: the
     writer gets a bit-identical private copy, the original page (and
     the other holder's table entry) are untouched, and both streams
-    keep decoding bit-exactly."""
+    keep decoding on the reference."""
     sess = prefix_session
     shared = [4, 4, 9, 1, 13, 2, 6, 10]
     pa = shared + [3]
@@ -249,10 +261,8 @@ def test_cow_divergence_never_mutates_shared_page(prefix_session):
         for slot, seq in seqs.items():
             ref = np.asarray(serve_model.reference_last_logits(
                 sess.params, seq, CFG, PAGE, exact=True))
-            np.testing.assert_array_equal(logits[slot], ref)
+            assert_close_across_executables(logits[slot], ref)
             seq.append(toks[slot])
-    sess.release(sa)
-    sess.release(sb)
 
 
 # ---------------------------------------------------------------------------
@@ -389,7 +399,7 @@ def test_chaos_evict_fault_isolates_victim(oversub_session, monkeypatch):
         assert r.tokens == oracle[r.rid]
     assert sess.cache.free_slots == sess.config.slots
     # the shared prefix page survived the faulted eviction: a new
-    # request over the same prefix still hits and decodes bit-exactly
+    # request over the same prefix still hits and decodes on the reference
     faults.reset()
     monkeypatch.delenv("MXNET_FAULT_INJECT")
     probe = shared + [11]
@@ -398,8 +408,7 @@ def test_chaos_evict_fault_isolates_victim(oversub_session, monkeypatch):
     _, logits = sess.prefill(slot, probe)
     ref = np.asarray(serve_model.reference_last_logits(
         sess.params, probe, CFG, PAGE, exact=True))
-    np.testing.assert_array_equal(logits, ref)
-    sess.release(slot)
+    assert_close_across_executables(logits, ref)
 
 
 @pytest.mark.chaos

@@ -1,8 +1,8 @@
 """ZeRO-style sharded optimizer update (``parallel/zero.py`` + the
 fused step's ``zero=`` branch): layout/eligibility units, the
 checkpoint interchange descriptors, end-to-end training equivalence
-against the replicated update (bit-exact in fp32 with a power-of-two
-lr), composition with the multi-step scan + dynamic loss scaling +
+against the replicated update (tests/closeness.py: two executables,
+fp32 with a power-of-two lr), composition with the multi-step scan + dynamic loss scaling +
 global-norm clipping, the 1/N state-memory claim, AOT compilation,
 the bounded-dispatch fault site, and the elastic-checkpoint resume
 matrix (same mesh, zero=off, and a different device count)."""
@@ -17,6 +17,9 @@ import pytest
 import mxnet_tpu as mx
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.parallel import create_mesh, mesh_scope, zero
+
+from closeness import (LIMIT_SPACINGS, assert_close_across_executables,
+                       spacings_apart)
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -147,11 +150,14 @@ def _mlp_sym():
 
 def _train(monkeypatch, zero_mode, optimizer="sgd", overlap_env="off",
            steps=3, steps_per_call=1, scaled=False, clip=None,
-           batch=16, feat=8):
+           batch=16, feat=8, stale_tile=False):
     """TrainStep on an 8-way DP mesh; returns (params, last outs, step).
 
-    Power-of-two lr/rescale so zero on/off is bit-exact in fp32 (XLA
-    reassociates the lr*rescale constant chain identically)."""
+    Power-of-two lr/rescale so zero on/off differ by nothing but the
+    order of their sums (XLA reassociates the lr*rescale constant chain
+    identically).  ``stale_tile`` plants the smallest realistic ZeRO
+    fault before the last step: device 0's 1/N tile of every optimizer
+    state still holds the step before's values."""
     import jax
 
     from mxnet_tpu.fused import TrainStep
@@ -186,7 +192,12 @@ def _train(monkeypatch, zero_mode, optimizer="sgd", overlap_env="off",
     rs = np.random.RandomState(42)
     rng = jax.random.PRNGKey(7)
     out = None
-    for _ in range(steps):
+    for i in range(steps):
+        if stale_tile and i == steps - 1:
+            states = jax.tree.map(
+                lambda new, old: new.at[:new.shape[0] // 8].set(
+                    old[:new.shape[0] // 8]), states, before)
+        before = jax.tree.map(np.asarray, states)  # the step donates
         if steps_per_call > 1:
             bd = {"data": rs.randn(steps_per_call, batch, feat)
                   .astype("float32"),
@@ -211,7 +222,10 @@ def _train(monkeypatch, zero_mode, optimizer="sgd", overlap_env="off",
 def test_zero_matches_replicated_bit_exact(monkeypatch, optimizer,
                                            overlap_env):
     """The acceptance equivalence: 3 fp32 steps with the sharded update
-    produce bit-identical parameters to the replicated update."""
+    produce the replicated update's parameters — two executables whose
+    sums run in different orders: sgd reads 0 spacings apart, adam at
+    most 4 (``fc2_bias``; jax 0.9.0), the limit is
+    tests/closeness.py's."""
     with warnings.catch_warnings():
         warnings.simplefilter("error", RuntimeWarning)  # no declines
         p_on, o_on, _, _ = _train(monkeypatch, "on", optimizer=optimizer,
@@ -220,8 +234,19 @@ def test_zero_matches_replicated_bit_exact(monkeypatch, optimizer,
                                 overlap_env=overlap_env)
     assert set(p_on) == set(p_off)
     for k in p_on:
-        np.testing.assert_array_equal(p_on[k], p_off[k], err_msg=k)
-    np.testing.assert_array_equal(o_on, o_off)
+        assert_close_across_executables(p_on[k], p_off[k], err_msg=k)
+    assert_close_across_executables(o_on, o_off)
+
+
+def test_zero_comparison_sees_planted_fault(monkeypatch):
+    """The control of the comparison above and of its ZeRO-3 twin: one
+    device's tile of the Adam moments a step stale reads tens of
+    thousands of spacings on the parameters that tile updates."""
+    p_bad, _, _, _ = _train(monkeypatch, "on", optimizer="adam",
+                            stale_tile=True)
+    p_off, _, _, _ = _train(monkeypatch, "off", optimizer="adam")
+    assert max(spacings_apart(p_bad[k], p_off[k])
+               for k in p_off) > 30 * LIMIT_SPACINGS
 
 
 def test_zero_composes_scan_clip_and_loss_scale(monkeypatch):
@@ -309,8 +334,8 @@ def test_zero3_matches_replicated_bit_exact(monkeypatch, optimizer,
                                             overlap_env):
     """The ZeRO-3 acceptance equivalence: 3 fp32 steps with params at
     rest as flat 1/N tiles (bucketed in-step gathers, backward
-    re-gather via remat) produce bit-identical parameters to the
-    replicated update."""
+    re-gather via remat) produce the replicated update's parameters
+    (two executables; 0 spacings apart read on jax 0.9.0)."""
     with warnings.catch_warnings():
         warnings.simplefilter("error", RuntimeWarning)  # no declines
         p3, o3, _, _ = _train(monkeypatch, "3", optimizer=optimizer,
@@ -319,8 +344,8 @@ def test_zero3_matches_replicated_bit_exact(monkeypatch, optimizer,
                                 overlap_env=overlap_env)
     assert set(p3) == set(p_off)
     for k in p3:
-        np.testing.assert_array_equal(p3[k], p_off[k], err_msg=k)
-    np.testing.assert_array_equal(o3, o_off)
+        assert_close_across_executables(p3[k], p_off[k], err_msg=k)
+    assert_close_across_executables(o3, o_off)
 
 
 def test_zero3_composes_scan_clip_and_loss_scale(monkeypatch):
@@ -529,19 +554,20 @@ def _mlp_resume_sym():
     return mx.sym.SoftmaxOutput(fc2, name="softmax")
 
 
-@pytest.mark.parametrize("szero,rzero,rdev,exact", [
-    ("on", "on", 8, True),   # same topology: bit-exact continuation
-    ("on", "off", 8, True),  # sharded save seeds the replicated update
-    ("on", "on", 4, False),  # different N re-tiles; order differs
-    ("3", "3", 8, True),     # ZeRO-3 save -> ZeRO-3 continuation
-    ("3", "off", 8, True),   # ZeRO-3 save seeds the replicated update
-    ("3", "on", 4, False),   # ZeRO-3 save, stage-1 resume on fewer devs
+@pytest.mark.parametrize("szero,rzero,rdev", [
+    ("on", "on", 8),   # same topology, same executable: the same bits
+    ("on", "off", 8),  # sharded save seeds the replicated update
+    ("on", "on", 4),   # different N re-tiles; order differs
+    ("3", "3", 8),     # ZeRO-3 save -> ZeRO-3 continuation
+    ("3", "off", 8),   # ZeRO-3 save seeds the replicated update
+    ("3", "on", 4),    # ZeRO-3 save, stage-1 resume on fewer devs
 ])
 def test_zero_ckpt_resume_matrix(monkeypatch, tmp_path, szero, rzero,
-                                 rdev, exact):
+                                 rdev):
     """A zero=on or zero=3 save (sharded Adam moments — and under
     ZeRO-3 the at-rest param tiles — through the v2 piece windows)
-    resumes into the same mesh bit-exactly, into zero=off bit-exactly
+    resumes into the same mesh bit for bit (the same executable over
+    the same state), into zero=off as closely as two executables agree
     (unsharded seeding), and into a different device count within
     reduction-order tolerance — all matching the straight 3-epoch
     run."""
@@ -561,9 +587,12 @@ def test_zero_ckpt_resume_matrix(monkeypatch, tmp_path, szero, rzero,
     resumed = _fit(tmp_path, 3, rzero, rdev,
                    resume=ckpt.CheckpointManager(d, prefix="m"))
     for k in straight:
-        if exact:
+        if (szero, 8) == (rzero, rdev):
             np.testing.assert_array_equal(straight[k], resumed[k],
                                           err_msg=k)
+        elif rdev == 8:
+            assert_close_across_executables(straight[k], resumed[k],
+                                            err_msg=k)
         else:
             np.testing.assert_allclose(straight[k], resumed[k],
                                        rtol=1e-5, atol=1e-6, err_msg=k)
