@@ -213,6 +213,8 @@ class BaseModule:
           and fold them into the metric every N batches instead of every
           batch (``MXNET_METRIC_SYNC_PERIOD``); a ``Speedometer`` reading
           the metric still sees up-to-date values (reads force a flush).
+          Only ``F1``, ``CustomMetric`` and user metrics gain from it: the
+          other built-in metrics reduce on the device (``metric.py``).
         * ``steps_per_call`` — dispatch K optimizer steps as one device
           call (``lax.scan`` over a packed super-batch staged by the
           prefetcher); requires the fused step (``MXNET_STEPS_PER_CALL``).

@@ -6,8 +6,10 @@ v5 lite").  Interpret-mode tests cannot see what Mosaic refuses (tiling,
 VMEM budget); these can, at the real shapes: the two BatchNorm reduction
 kernels at ResNet-50 shapes and the library flash-attention kernel that
 ``MXNET_ATTN_IMPL=auto`` dispatches to on TPU, forward and backward, at
-the transformer bench shape.  Nothing runs, so nothing here is a result
-or a time — a compile that passes is not a chip run.
+the transformer bench shape; and the metrics' device reductions over the
+Cerebras-GPT head's (8192, 50257) bfloat16 softmax, which must read the
+prediction in place.  Nothing runs, so nothing here is a result or a
+time — a compile that passes is not a chip run.
 
 All of it lives in this one file, and the topology is described inside a
 module-scoped fixture: only the xdist worker that is handed this file
@@ -20,6 +22,7 @@ from jax.sharding import SingleDeviceSharding
 
 BN_SHAPES = [(64, 256, 56, 56), (512, 64, 112, 112)]
 FLASH_SHAPE = (8, 16, 1024, 128)  # B, H, T, D of the 8L-d2048 config
+LM_HEAD_SHAPE = (8192, 50257)  # batch 4 x 2048 tokens, Cerebras-GPT vocabulary
 
 
 @pytest.fixture(scope="module")
@@ -89,3 +92,27 @@ def test_flash_attention_compiles_for_v5e(one_chip, backward):
 
     fn = jax.grad(loss, argnums=(0, 1, 2)) if backward else fwd
     _compile(fn, q, q, q)
+
+
+@pytest.mark.parametrize("name, static", [
+    ("metric_cross_entropy", {"eps": 1e-12}),
+    ("metric_accuracy", {"axis": 1}),
+    ("metric_top_k_accuracy", {"top_k": 5}),
+    ("metric_perplexity", {"ignore_label": 0}),
+    ("metric_loss", {}),
+], ids=lambda v: v if isinstance(v, str) else "")
+def test_metric_reduction_reads_the_lm_head_in_place(one_chip, name, static):
+    """The 0.82 GB softmax is an argument and nothing like it is a
+    temporary: no cast or re-laid-out copy of it beside the training
+    step's own buffers (a float32 copy would be 1.65 GB)."""
+    from mxnet_tpu import metric
+
+    pred = jax.ShapeDtypeStruct(LM_HEAD_SHAPE, jnp.bfloat16,
+                                sharding=one_chip)
+    label = jax.ShapeDtypeStruct(LM_HEAD_SHAPE[:1], jnp.float32,
+                                 sharding=one_chip)
+    args = (pred,) if name == "metric_loss" else (label, pred)
+    compiled = getattr(metric, name).lower(*args, **static).compile()
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes >= 2 * 8192 * 50257
+    assert memory.temp_size_in_bytes < 1 << 20
