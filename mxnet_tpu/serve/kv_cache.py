@@ -11,6 +11,15 @@ no per-request allocation, no growing tensors, no recompiles.  Requests
 own *pages* (rows of the pool), recorded in a per-slot page table the
 executables consume as a plain (slots, max_pages) int32 array.
 
+A latent-attention model (``latent_dim > 0``, ``serve/latent_moe.py``)
+keeps one row of ``latent_dim`` values a token a layer instead of
+per-head keys and values: ONE pool
+
+    k_pool : (num_layers, num_pages + 1, page_size, latent_dim)
+
+and no ``v_pool``.  Pages, tables, reference counts, the prefix index,
+oversubscription and copy-on-write do not know the difference.
+
 Two admission modes (vs the original reservation-only pager):
 
 * **Reservation admission** (default) — a request is admitted only when
@@ -84,7 +93,7 @@ class PagedKVCache:
     def __init__(self, num_layers, num_heads, head_dim, page_size,
                  num_pages, slots, max_pages_per_slot, dtype=None,
                  table_pad=0, prefix_pages=0, kv_quant="",
-                 layer_kinds=(), window=0, ring_pages=0):
+                 layer_kinds=(), window=0, ring_pages=0, latent_dim=0):
         import jax.numpy as jnp
         import numpy as np
 
@@ -153,14 +162,22 @@ class PagedKVCache:
         # (page, offset) the codes are, so the page tables, COW, and
         # preempt/resume machinery never know quantization exists
         self.kv_quant = _quantize.quant_mode(kv_quant)
+        self.latent_dim = int(latent_dim)
+        if self.latent_dim and (self.kv_quant or self.hybrid):
+            raise MXNetError(
+                "PagedKVCache: a latent pool has no kv_quant (no per-head "
+                "row to scale) and no windowed or SSM layers yet")
         if self.kv_quant:
             dtype = jnp.dtype(_quantize.quant_dtype(self.kv_quant))
         else:
             dtype = dtype or jnp.float32
         pool_shape = (max(self.n_full, 1), self.num_pages + 1,
                       self.page_size, self.num_heads, self.head_dim)
+        if self.latent_dim:
+            pool_shape = pool_shape[:3] + (self.latent_dim,)
         self.k_pool = jnp.zeros(pool_shape, dtype)
-        self.v_pool = jnp.zeros(pool_shape, dtype)
+        self.v_pool = None if self.latent_dim else jnp.zeros(pool_shape,
+                                                             dtype)
         if self.kv_quant:
             scale_shape = pool_shape[:3]
             self.k_scale = jnp.ones(scale_shape, jnp.float32)
@@ -522,7 +539,9 @@ class PagedKVCache:
             # copy, so the private page is bit-identical to the shared
             # one and the stream stays exact
             self.k_pool = self.k_pool.at[:, new].set(self.k_pool[:, page])
-            self.v_pool = self.v_pool.at[:, new].set(self.v_pool[:, page])
+            if self.v_pool is not None:
+                self.v_pool = self.v_pool.at[:, new].set(
+                    self.v_pool[:, page])
             if self.kv_quant:  # scale rows travel with their codes
                 self.k_scale = self.k_scale.at[:, new].set(
                     self.k_scale[:, page])
@@ -638,7 +657,9 @@ class PagedKVCache:
         """Total device bytes held by the pools (scale pools included
         for quantized caches) — constant for the session's lifetime,
         which IS the O(1) decode-memory story."""
-        total = int(self.k_pool.nbytes) + int(self.v_pool.nbytes)
+        total = int(self.k_pool.nbytes)
+        if self.v_pool is not None:
+            total += int(self.v_pool.nbytes)
         if self.kv_quant:
             total += int(self.k_scale.nbytes) + int(self.v_scale.nbytes)
         if self.kw_pool is not None:

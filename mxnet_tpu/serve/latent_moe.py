@@ -1,0 +1,559 @@
+"""The DeepSeek-V3 decoder block for the serving runtime: latent
+attention (MLA) over a latent page pool, and a dropless routed expert
+layer with shared experts.
+
+The second block beside ``model.py``'s GPT-2 one, selected by
+``ModelConfig(block="deepseek_v3", ...)``: ``model.full_forward`` hands
+over to :func:`full_forward`, and the session compiles
+:func:`prefill_forward` and :func:`decode_step` in the GPT-2 ones' place.
+The equations (``benchmark/references/deepseek_v3_lm.py`` is their plain
+form, and the tests hold this module to it):
+
+* ``h = x + Attn(RMSNorm(x))``, ``y = h + FFN(RMSNorm(h))``, no position
+  embedding, no bias, an untied head after a final RMSNorm.
+* Attn: ``q = W_q u`` as (H, nope + rope); ``[c | r] = W_kva u``;
+  ``c <- RMSNorm(c)``; the rope part of ``q`` and the one shared ``r`` get
+  RoPE on interleaved pairs.  **The cache holds ``[c | r]``**, one row of
+  ``kv_lora_rank + qk_rope_head_dim`` values a token a layer, in the pages
+  ``PagedKVCache`` hands out, and nothing per head.
+* Prefill is *materialised*: ``[k_nope_h | v_h] = W_kvb,h c`` for the
+  slot's gathered rows (the chunk being fed and whatever it attends to
+  from earlier chunks or prefix hits), then attention over heads of
+  ``nope + rope`` against values of ``v_head_dim``.
+* Decode is *absorbed*: ``q_lat_h = W_k,h^T q_nope_h``, scores
+  ``q_lat_h . c_j + q_rope_h . r_j``, ``a_h = sum_j p_j c_j``,
+  ``o = W_o concat_h(W_v,h a_h)``: one shared key/value head of the
+  latent width, no K or V ever built for the cached context.
+* FFN: one SwiGLU in the first ``first_k_dense`` layers; after them
+  ``s = sigmoid(W_r u)`` in float32 at highest precision (one bfloat16
+  pass flips a token's last expert often enough to show in the logits),
+  the ``num_experts_per_tok`` largest ``s + b`` taken,
+  ``w = routed_scaling_factor * s / sum_taken(s)``, and
+  ``sum_taken w_e SwiGLU_e(u) + SwiGLU_shared(u)``.
+
+The expert layer sorts the tokens x k assignments by expert, pads each
+expert's group to whole tiles and loops over the tiles in use, indexing
+the stacked expert matrices by the tile's expert: every assignment is
+computed whatever the imbalance (dropless), a decode step reads only the
+experts its tokens reach, and prefill does tokens x k expert FLOPs (plus
+tile padding), never tokens x experts.
+
+``exact`` selects the M-invariant ``_mm`` as for the GPT-2 block, but
+the bit-identity contract does not extend here: the absorbed and the
+materialised forms associate differently, so decode agrees with a full
+forward to rounding, not to the bit.
+
+Counters: every executable folds what its routers did into a small
+device array it is handed and returns (``moe_stats``, see
+:func:`stats_size`); nothing reads it but
+``InferenceSession.moe_report()``.
+"""
+from __future__ import annotations
+
+from ..base import MXNetError
+from ..ops.attention import decode_attention
+from .model import _mm, _resolve_params
+
+BLOCK = "deepseek_v3"
+
+# moe_stats columns before the per-(expert layer, expert) load
+DECODE_STEPS, PREFILL_CHUNKS, ASKED, COMPUTED, DISTINCT, _HEADER = range(6)
+_LO_BITS = 30  # moe_stats[0] holds 30 bits, moe_stats[1] the carries
+
+
+def validate(cfg):
+    sizes = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim,
+             cfg.kv_lora_rank, cfg.d_ff, cfg.max_len)
+    if min(sizes) < 1:
+        raise MXNetError(
+            "ModelConfig(block=%r) needs qk_nope_head_dim, qk_rope_head_dim,"
+            " v_head_dim, kv_lora_rank, d_ff and max_len (got %r)"
+            % (BLOCK, sizes))
+    if cfg.qk_rope_head_dim % 2:
+        raise MXNetError("qk_rope_head_dim %d is not even"
+                         % cfg.qk_rope_head_dim)
+    if cfg.layer_kinds or cfg.window:
+        raise MXNetError("block %r has no windowed or SSM layers" % BLOCK)
+    if not 0 <= cfg.first_k_dense <= cfg.num_layers:
+        raise MXNetError("first_k_dense %d outside 0..%d layers"
+                         % (cfg.first_k_dense, cfg.num_layers))
+    if cfg.first_k_dense < cfg.num_layers:
+        if min(cfg.moe_d_ff, cfg.n_routed_experts,
+               cfg.num_experts_per_tok) < 1 or cfg.n_shared_experts < 0:
+            raise MXNetError(
+                "expert layers need moe_d_ff, n_routed_experts and "
+                "num_experts_per_tok")
+        if cfg.num_experts_per_tok > cfg.n_routed_experts:
+            raise MXNetError("num_experts_per_tok %d > n_routed_experts %d"
+                             % (cfg.num_experts_per_tok,
+                                cfg.n_routed_experts))
+    return cfg
+
+
+def param_shapes(cfg):
+    """{parameter name: shape}: matrices (out, in) as ``_mm`` takes them,
+    a layer's routed experts stacked on a leading axis."""
+    d, h = cfg.d_model, cfg.num_heads
+    nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    rank, v = cfg.kv_lora_rank, cfg.vocab_size
+    e, fe = cfg.n_routed_experts, cfg.moe_d_ff
+    fs = cfg.n_shared_experts * fe
+    out = {"tok_embed_weight": (v, d), "final_norm_gamma": (d,),
+           "lm_head_weight": (v, d)}
+    for i in range(cfg.num_layers):
+        p = "blk%d_" % i
+        out.update({
+            p + "attn_norm_gamma": (d,),
+            p + "q_weight": (h * (nope + rope), d),
+            p + "kv_a_weight": (rank + rope, d),
+            p + "kv_norm_gamma": (rank,),
+            p + "kv_b_weight": (h * (nope + vd), rank),
+            p + "o_weight": (d, h * vd),
+            p + "ffn_norm_gamma": (d,),
+        })
+        if i < cfg.first_k_dense:
+            out.update({p + "gate_weight": (cfg.d_ff, d),
+                        p + "up_weight": (cfg.d_ff, d),
+                        p + "down_weight": (d, cfg.d_ff)})
+            continue
+        out.update({
+            p + "router_weight": (e, d), p + "router_bias": (e,),
+            p + "experts_gate_weight": (e, fe, d),
+            p + "experts_up_weight": (e, fe, d),
+            p + "experts_down_weight": (e, d, fe),
+        })
+        if fs:
+            out.update({p + "shared_gate_weight": (fs, d),
+                        p + "shared_up_weight": (fs, d),
+                        p + "shared_down_weight": (d, fs)})
+    return out
+
+
+def init_params(cfg, seed=0, scale=0.02):
+    """Fresh float32 parameters (tests and benches): normal matrices,
+    norm scales one, the router's selection bias zero."""
+    import jax
+    import jax.numpy as jnp
+
+    shapes = param_shapes(cfg)
+    keys = jax.random.split(jax.random.PRNGKey(seed), len(shapes))
+    params = {}
+    for key, (name, shape) in zip(keys, sorted(shapes.items())):
+        if name.endswith("_gamma"):
+            params[name] = jnp.ones(shape, jnp.float32)
+        elif name.endswith("_bias"):
+            params[name] = jnp.zeros(shape, jnp.float32)
+        else:
+            params[name] = (scale * jax.random.normal(key, shape)
+                            ).astype(jnp.float32)
+    return params
+
+
+def check_params(params, cfg):
+    """The parameter dict has exactly the architecture's shapes."""
+    def _shape(v):
+        return tuple(v["q"].shape if isinstance(v, dict) else v.shape)
+
+    for name, shape in param_shapes(cfg).items():
+        if name not in params:
+            raise MXNetError("ModelConfig(block=%r) needs parameter %s %r"
+                             % (BLOCK, name, shape))
+        if _shape(params[name]) != tuple(shape):
+            raise MXNetError("parameter %s is %r, the architecture says %r"
+                             % (name, _shape(params[name]), tuple(shape)))
+
+
+def n_moe_layers(cfg):
+    return cfg.num_layers - cfg.first_k_dense
+
+
+def stats_size(cfg):
+    """Columns of ``moe_stats`` (2, n) int32: decode steps, prefill
+    chunks, assignments asked, assignments computed, the sum over decode
+    steps and expert layers of the distinct experts reached, then the
+    cumulative load of every (expert layer, expert).  Row 0 holds the low
+    30 bits of each count and row 1 the carries, so a session that is
+    never asked for its report does not wrap."""
+    return _HEADER + n_moe_layers(cfg) * cfg.n_routed_experts
+
+
+def _fold(stats, inc):
+    """``stats + inc`` (inc < 2**30 a column), carries moved to row 1."""
+    import jax.numpy as jnp
+
+    lo = stats[0] + inc
+    return jnp.stack([lo & ((1 << _LO_BITS) - 1),
+                      stats[1] + (lo >> _LO_BITS)])
+
+
+def report(stats, cfg):
+    """Host side: ``moe_stats`` as exact Python ints under their names
+    (``InferenceSession.moe_report`` documents them)."""
+    import numpy as np
+
+    counts = [int(lo) + (int(hi) << _LO_BITS)
+              for lo, hi in zip(*np.asarray(stats))]
+    layers, experts = n_moe_layers(cfg), cfg.n_routed_experts
+    return {
+        "decode_steps": counts[DECODE_STEPS],
+        "prefill_chunks": counts[PREFILL_CHUNKS],
+        "assignments_asked": counts[ASKED],
+        "assignments_computed": counts[COMPUTED],
+        "distinct_experts": counts[DISTINCT],
+        "expert_layers": layers,
+        "expert_load": np.asarray(counts[_HEADER:], np.int64).reshape(
+            layers, experts),
+    }
+
+
+def _rms_norm(x, gamma, eps):
+    import jax.numpy as jnp
+    from jax import lax
+
+    var = jnp.mean(x * x, axis=-1, keepdims=True)
+    return x * lax.rsqrt(var + eps) * gamma
+
+
+def _rope(x, positions, theta):
+    """Rotate the interleaved pairs (2j, 2j + 1) of ``x`` (N, ..., rope)
+    by ``positions`` (N,) x theta ** (-2j / rope)."""
+    import jax.numpy as jnp
+
+    rope = x.shape[-1]
+    inv_freq = theta ** (-jnp.arange(0, rope, 2, dtype=jnp.float32) / rope)
+    angle = positions.astype(jnp.float32)[:, None] * inv_freq
+    angle = angle.reshape((x.shape[0],) + (1,) * (x.ndim - 2) + (rope // 2,))
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    even, odd = x[..., 0::2], x[..., 1::2]
+    return jnp.stack([even * cos - odd * sin, odd * cos + even * sin],
+                     axis=-1).reshape(x.shape)
+
+
+def _swiglu(u, gate, up, down, exact):
+    import jax
+
+    return _mm(jax.nn.silu(_mm(u, gate, exact)) * _mm(u, up, exact), down,
+               exact)
+
+
+def _query_and_row(params, pre, u, positions, cfg, exact):
+    """u (N, d) -> rotated queries (N, H, nope + rope) and the rows the
+    cache holds, ``[RMSNorm(c) | RoPE(r)]`` (N, rank + rope)."""
+    import jax.numpy as jnp
+
+    n, nope, rank = u.shape[0], cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    q = _mm(u, params[pre + "q_weight"], exact).reshape(
+        n, cfg.num_heads, nope + cfg.qk_rope_head_dim)
+    q = jnp.concatenate(
+        [q[..., :nope], _rope(q[..., nope:], positions, cfg.rope_theta)],
+        axis=-1)
+    kva = _mm(u, params[pre + "kv_a_weight"], exact)
+    c = _rms_norm(kva[:, :rank], params[pre + "kv_norm_gamma"],
+                  cfg.rms_norm_eps)
+    r = _rope(kva[:, rank:], positions, cfg.rope_theta)
+    return q, jnp.concatenate([c, r], axis=-1)
+
+
+def _scale(cfg):
+    return 1.0 / (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** 0.5
+
+
+def _attend_materialised(params, pre, q, ctx, horizons, cfg, exact, block):
+    """Prefill's form.  q (N, H, nope + rope); ctx (Tc, rank + rope) latent
+    rows in position order; horizons (N,): row j sees ``ctx[:horizons[j]]``.
+    K and V are built from the rows for all heads.  -> (N, H * vd)."""
+    import jax.numpy as jnp
+
+    h, nope, rank = cfg.num_heads, cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    tc = ctx.shape[0]
+    kv = _mm(ctx[:, :rank], params[pre + "kv_b_weight"], exact).reshape(
+        tc, h, nope + cfg.v_head_dim)
+    k = jnp.concatenate(
+        [kv[..., :nope],
+         jnp.broadcast_to(ctx[:, None, rank:],
+                          (tc, h, cfg.qk_rope_head_dim))], axis=-1)
+    att = decode_attention(
+        q.transpose(1, 0, 2)[None], k.transpose(1, 0, 2)[None],
+        kv[..., nope:].transpose(1, 0, 2)[None], horizons[None],
+        scale=_scale(cfg), block=block, mi=exact)
+    return att[0].transpose(1, 0, 2).reshape(q.shape[0], h * cfg.v_head_dim)
+
+
+def _attend_absorbed(params, pre, q, ctx, lengths, cfg, exact, block):
+    """Decode's form.  q (S, H, nope + rope), one query a slot; ctx
+    (S, Tc, rank + rope) each slot's gathered rows; lengths (S,) valid
+    rows.  The heads are the query rows of ONE shared key/value head of
+    the latent width.  -> (S, H * vd)."""
+    import jax.numpy as jnp
+
+    h, nope, rank = cfg.num_heads, cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    w = params[pre + "kv_b_weight"].reshape(h, nope + cfg.v_head_dim, rank)
+    q_lat = jnp.einsum("shn,hnc->shc", q[..., :nope], w[:, :nope])
+    q_abs = jnp.concatenate([q_lat, q[..., nope:]], axis=-1)
+    att = decode_attention(q_abs[:, None], ctx[:, None],
+                           ctx[:, None, :, :rank], lengths,
+                           scale=_scale(cfg), block=block, mi=exact)
+    out = jnp.einsum("shc,hvc->shv", att[:, 0], w[:, nope:])
+    return out.reshape(q.shape[0], h * cfg.v_head_dim)
+
+
+def _route(u, params, pre, cfg):
+    """-> (taken (N, k) expert ids, w (N, k) combine weights)."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    with jax.named_scope("moe_route"):
+        scores = jax.nn.sigmoid(jnp.einsum(
+            "nc,ec->ne", u.astype(jnp.float32),
+            params[pre + "router_weight"].astype(jnp.float32),
+            precision=lax.Precision.HIGHEST))
+        _, taken = lax.top_k(scores + params[pre + "router_bias"],
+                             cfg.num_experts_per_tok)
+        w = jnp.take_along_axis(scores, taken, axis=-1)
+        if cfg.norm_topk_prob:
+            w = w / (w.sum(axis=-1, keepdims=True) + 1e-20)
+        return taken, w * cfg.routed_scaling_factor
+
+
+def _tile_rows(assignments, experts):
+    """Rows of one tile of the grouped matmul: the mean group, rounded up
+    to a power of two, between 8 (a sublane) and 128 (an MXU pass)."""
+    mean = max(assignments // experts, 1)
+    return min(128, max(8, 1 << (mean - 1).bit_length()))
+
+
+def _routed_experts(u, taken, w, params, pre, cfg, exact):
+    """sum_k w[:, k] * SwiGLU_{taken[:, k]}(u), dropless.  -> (out (N, d),
+    assignments whose tile was computed (N, k) bool)."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    n, d = u.shape
+    k, e = taken.shape[1], cfg.n_routed_experts
+    a = n * k
+    tile = _tile_rows(a, e)
+    max_tiles = a // tile + min(e, a)
+    with jax.named_scope("moe_experts"):
+        flat = taken.reshape(a)               # assignment = token * k + j
+        order = jnp.argsort(flat, stable=True)
+        by_expert = flat[order]
+        counts = jnp.zeros((e,), jnp.int32).at[flat].add(1)
+        tiles_of = (counts + tile - 1) // tile
+        tile_end = jnp.cumsum(tiles_of)
+        group_start = jnp.cumsum(counts) - counts
+        # padded row of each assignment: its expert's first tile, then
+        # its rank inside the group
+        row = (tile_end - tiles_of)[by_expert] * tile \
+            + jnp.arange(a, dtype=jnp.int32) - group_start[by_expert]
+        token_of_row = jnp.full((max_tiles * tile,), n, jnp.int32
+                                ).at[row].set((order // k).astype(jnp.int32))
+        x = jnp.concatenate([u, jnp.zeros((1, d), u.dtype)])[token_of_row]
+        expert_of_tile = jnp.clip(jnp.searchsorted(
+            tile_end, jnp.arange(max_tiles, dtype=jnp.int32), side="right"),
+            0, e - 1)
+        in_use = tile_end[-1]
+        gate, up, down = (params[pre + "experts_%s_weight" % m]
+                          for m in ("gate", "up", "down"))
+
+        def one_tile(t, y):
+            idx = expert_of_tile[t]
+            xt = lax.dynamic_slice_in_dim(x, t * tile, tile)
+            yt = _swiglu(xt, lax.dynamic_index_in_dim(gate, idx, 0, False),
+                         lax.dynamic_index_in_dim(up, idx, 0, False),
+                         lax.dynamic_index_in_dim(down, idx, 0, False), exact)
+            return lax.dynamic_update_slice_in_dim(y, yt, t * tile, 0)
+
+        y = lax.fori_loop(0, in_use, one_tile,
+                          jnp.zeros((max_tiles * tile, d), u.dtype))
+        row_of = jnp.zeros((a,), jnp.int32).at[order].set(row)
+        out = (y[row_of].reshape(n, k, d) * w[..., None].astype(u.dtype)
+               ).sum(axis=1)
+        computed = (row_of < in_use * tile).reshape(n, k)
+    return out, computed
+
+
+def _ffn(params, i, x, cfg, exact, valid):
+    """The block's second half on (N, d).  ``valid`` (N,) bool marks the
+    rows that are real tokens (bucket padding is routed and computed like
+    any row, and not counted).  -> (x + FFN, counter increments or None)."""
+    import jax
+    import jax.numpy as jnp
+
+    pre = "blk%d_" % i
+    u = _rms_norm(x, params[pre + "ffn_norm_gamma"], cfg.rms_norm_eps)
+    if i < cfg.first_k_dense:
+        return x + _swiglu(u, params[pre + "gate_weight"],
+                           params[pre + "up_weight"],
+                           params[pre + "down_weight"], exact), None
+    taken, w = _route(u, params, pre, cfg)
+    out, computed = _routed_experts(u, taken, w, params, pre, cfg, exact)
+    if cfg.n_shared_experts:
+        with jax.named_scope("moe_shared"):
+            out = out + _swiglu(u, params[pre + "shared_gate_weight"],
+                                params[pre + "shared_up_weight"],
+                                params[pre + "shared_down_weight"], exact)
+    real = jnp.broadcast_to(valid[:, None], taken.shape)
+    load = jnp.zeros((cfg.n_routed_experts,), jnp.int32).at[
+        taken.reshape(-1)].add(real.reshape(-1).astype(jnp.int32))
+    inc = (real.sum().astype(jnp.int32),
+           (real & computed).sum().astype(jnp.int32), load)
+    return x + out, inc
+
+
+def _stats_after(moe_stats, incs, decode):
+    """Fold one executable's routers into ``moe_stats``."""
+    import jax.numpy as jnp
+
+    head = jnp.zeros((_HEADER,), jnp.int32).at[
+        DECODE_STEPS if decode else PREFILL_CHUNKS].set(1)
+    loads = []
+    for asked, computed, load in incs:
+        head = head.at[ASKED].add(asked).at[COMPUTED].add(computed)
+        if decode:
+            head = head.at[DISTINCT].add((load > 0).sum().astype(jnp.int32))
+        loads.append(load)
+    return _fold(moe_stats, jnp.concatenate([head] + loads))
+
+
+def _head(params, x, cfg, exact):
+    x = _rms_norm(x, params["final_norm_gamma"], cfg.rms_norm_eps)
+    return _mm(x, params["lm_head_weight"], exact)
+
+
+def full_forward(params, tokens, cfg, exact, block=None):
+    """(n, T) int tokens -> (n, T, V) logits, materialised attention over
+    the sequence's own rows: the O(T^2) forward the paged paths are held
+    against.  ``block`` is the attention's key block (T by default)."""
+    import jax
+    import jax.numpy as jnp
+
+    params = _resolve_params(params)
+    t = tokens.shape[-1]
+    if t > cfg.max_len:
+        raise MXNetError("sequence length %d > model max_len %d"
+                         % (t, cfg.max_len))
+    positions = jnp.arange(t, dtype=jnp.int32)
+    valid = jnp.ones((t,), bool)
+
+    def one(seq):
+        x = jnp.take(params["tok_embed_weight"], seq.astype(jnp.int32),
+                     axis=0)
+        for i in range(cfg.num_layers):
+            pre = "blk%d_" % i
+            u = _rms_norm(x, params[pre + "attn_norm_gamma"],
+                          cfg.rms_norm_eps)
+            q, rows = _query_and_row(params, pre, u, positions, cfg, exact)
+            att = _attend_materialised(params, pre, q, rows, positions + 1,
+                                       cfg, exact, block or t)
+            x = x + _mm(att, params[pre + "o_weight"], exact)
+            x, _ = _ffn(params, i, x, cfg, exact, valid)
+        return _head(params, x, cfg, exact)
+
+    return jax.vmap(one)(tokens)
+
+
+def _prefill_block(max_pages, page_size, exact):
+    """Key block of prefill's attention scan: a page under ``exact`` (the
+    GPT-2 block's geometry), else the largest whole number of pages that
+    divides the table and stays within 512 keys."""
+    if exact:
+        return page_size
+    pages = max(p for p in range(1, max_pages + 1)
+                if max_pages % p == 0 and p * page_size <= max(512, page_size))
+    return pages * page_size
+
+
+def prefill_forward(params, tokens, length, offset, table_row, latent_pool,
+                    moe_stats, cfg, page_size, exact):
+    """Bucketed prefill of one chunk (``model.prefill_forward``'s
+    contract: page-aligned ``offset``, ``length`` real tokens, rows past
+    the table on the trash page).  Writes the chunk's latent rows, gathers
+    the slot's pages and attends in the materialised form with per-row
+    horizons ``offset + j + 1``, so a chunk at an offset reads what
+    earlier chunks or prefix hits left.  The head runs on the last real
+    row only.  -> (first_token, last_logits, latent_pool, moe_stats)."""
+    import jax
+    import jax.numpy as jnp
+
+    params = _resolve_params(params)
+    _, t_b = tokens.shape
+    if t_b % page_size:
+        raise MXNetError("bucket length %d not a multiple of page size %d"
+                         % (t_b, page_size))
+    max_pages = table_row.shape[0]
+    trash = latent_pool.shape[1] - 1
+    offs = jnp.arange(t_b, dtype=jnp.int32)
+    abs_pos = offset + offs
+    idx = abs_pos // page_size
+    pages = jnp.where(idx < max_pages,
+                      table_row[jnp.clip(idx, 0, max_pages - 1)], trash)
+    offsets = abs_pos % page_size
+    valid = offs < length
+    block = _prefill_block(max_pages, page_size, exact)
+    x = jnp.take(params["tok_embed_weight"], tokens[0].astype(jnp.int32),
+                 axis=0)
+    incs = []
+    for i in range(cfg.num_layers):
+        pre = "blk%d_" % i
+        with jax.named_scope("mla_prefill"):
+            u = _rms_norm(x, params[pre + "attn_norm_gamma"],
+                          cfg.rms_norm_eps)
+            q, rows = _query_and_row(params, pre, u, abs_pos, cfg, exact)
+            latent_pool = latent_pool.at[i, pages, offsets].set(
+                rows.astype(latent_pool.dtype))
+            ctx = latent_pool[i][table_row].reshape(
+                max_pages * page_size, rows.shape[-1])
+            att = _attend_materialised(params, pre, q, ctx, abs_pos + 1,
+                                       cfg, exact, block)
+            x = x + _mm(att, params[pre + "o_weight"], exact)
+        x, inc = _ffn(params, i, x, cfg, exact, valid)
+        if inc is not None:
+            incs.append(inc)
+    last = _head(params, jnp.take(x, length - 1, axis=0), cfg, exact)
+    first_token = jnp.argmax(last, axis=-1).astype(jnp.int32)
+    return (first_token, last, latent_pool,
+            _stats_after(moe_stats, incs, decode=False))
+
+
+def decode_step(params, tokens, lengths, tables, latent_pool, moe_stats,
+                cfg, page_size, exact):
+    """One decode step for every slot (``model.decode_step``'s contract).
+    Appends each slot's latent row at ``lengths`` and attends in the
+    absorbed form over the slot's gathered pages: in one block without
+    ``exact``, page by page with it.
+    -> (next_tokens, logits, latent_pool, moe_stats)."""
+    import jax
+    import jax.numpy as jnp
+
+    params = _resolve_params(params)
+    s = tokens.shape[0]
+    max_pages = tables.shape[1]
+    t_cap = max_pages * page_size
+    x = jnp.take(params["tok_embed_weight"], tokens.astype(jnp.int32),
+                 axis=0)
+    page_slot = jnp.clip(lengths // page_size, 0, max_pages - 1)
+    page = jnp.take_along_axis(tables, page_slot[:, None], axis=1)[:, 0]
+    offset = lengths % page_size
+    valid = jnp.ones((s,), bool)
+    incs = []
+    for i in range(cfg.num_layers):
+        pre = "blk%d_" % i
+        with jax.named_scope("mla_decode"):
+            u = _rms_norm(x, params[pre + "attn_norm_gamma"],
+                          cfg.rms_norm_eps)
+            q, rows = _query_and_row(params, pre, u, lengths, cfg, exact)
+            latent_pool = latent_pool.at[i, page, offset].set(
+                rows.astype(latent_pool.dtype))
+            ctx = latent_pool[i][tables].reshape(s, t_cap, rows.shape[-1])
+            att = _attend_absorbed(params, pre, q, ctx, lengths + 1, cfg,
+                                   exact, page_size if exact else t_cap)
+            x = x + _mm(att, params[pre + "o_weight"], exact)
+        x, inc = _ffn(params, i, x, cfg, exact, valid)
+        if inc is not None:
+            incs.append(inc)
+    logits = _head(params, x, cfg, exact)
+    next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    return (next_tokens, logits, latent_pool,
+            _stats_after(moe_stats, incs, decode=True))

@@ -63,18 +63,52 @@ class ModelConfig:
     ``ops/ssm_ops.py``).  All kinds reuse the block's existing
     ``attn_in``/``attn_out`` weights, so any attention checkpoint hosts
     any stack.  The empty tuple means all-full (the classic decoder).
+
+    ``block`` names the architecture.  ``"gpt2"`` (learned positions,
+    LayerNorm, GELU, biased fused-QKV heads) is what
+    :func:`config_from_params` infers from a parameter dict;
+    ``"deepseek_v3"`` (``latent_moe.py``: RMSNorm, RoPE, latent
+    attention, SwiGLU, routed and shared experts, no bias, no position
+    table) cannot be inferred from shapes, so it is stated: the fields
+    after ``block`` are its head split, its latent rank, its norms and
+    RoPE, and its expert layer, under the names of the published
+    ``config.json`` where the repo had none.
     """
     vocab_size: int
     num_layers: int
     d_model: int
     num_heads: int
-    max_len: int          # pos_embed rows == the context ceiling
+    max_len: int          # the context ceiling (gpt2: pos_embed rows)
     window: int = 0       # sliding-window length for "window" layers
     layer_kinds: tuple = ()  # per-layer kind; () = all "full"
+    block: str = "gpt2"
+    qk_nope_head_dim: int = 0   # per-head query/key width without RoPE
+    qk_rope_head_dim: int = 0   # rotated width; one shared key a token
+    v_head_dim: int = 0
+    kv_lora_rank: int = 0       # the latent c's width; the cache holds
+    #                             kv_lora_rank + qk_rope_head_dim a token
+    rope_theta: float = 10000.0
+    rms_norm_eps: float = 1e-6
+    d_ff: int = 0               # the dense layers' SwiGLU width
+    first_k_dense: int = 0      # leading layers with a dense FFN
+    moe_d_ff: int = 0           # one expert's SwiGLU width
+    n_routed_experts: int = 0
+    num_experts_per_tok: int = 0
+    n_shared_experts: int = 0   # one SwiGLU of n_shared * moe_d_ff
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
 
     @property
     def head_dim(self):
         return self.d_model // self.num_heads
+
+    @property
+    def latent_dim(self):
+        """Values the cache holds a token a layer in ONE pool (the latent
+        block), or 0 for per-head K and V pools."""
+        if self.block == "gpt2":
+            return 0
+        return self.kv_lora_rank + self.qk_rope_head_dim
 
     @property
     def kinds(self):
@@ -86,6 +120,13 @@ class ModelConfig:
         return any(k != "full" for k in self.kinds)
 
     def validate(self):
+        if self.block != "gpt2":
+            from . import latent_moe
+
+            if self.block != latent_moe.BLOCK:
+                raise MXNetError("unknown block %r (gpt2 or %s)"
+                                 % (self.block, latent_moe.BLOCK))
+            return latent_moe.validate(self)
         if self.d_model % self.num_heads:
             raise MXNetError("d_model %d not divisible by num_heads %d"
                              % (self.d_model, self.num_heads))
@@ -150,6 +191,10 @@ def init_params(cfg, seed=0, scale=0.02):
     import jax.numpy as jnp
 
     cfg.validate()
+    if cfg.block != "gpt2":
+        from . import latent_moe
+
+        return latent_moe.init_params(cfg, seed=seed, scale=scale)
     keys = iter(jax.random.split(jax.random.PRNGKey(seed),
                                  4 * cfg.num_layers + 4))
 
@@ -437,6 +482,14 @@ def full_forward(params, tokens, cfg, exact=None, block=None,
 
     if exact is None:
         exact = exact_mode()
+    if cfg.block != "gpt2":
+        from . import latent_moe
+
+        if return_kv or kv_quant:
+            raise MXNetError("block %r: full_forward has no return_kv and "
+                             "no kv_quant" % cfg.block)
+        return latent_moe.full_forward(params, tokens, cfg, exact,
+                                       block=block)
     params = _resolve_params(params)
     t = tokens.shape[-1]
     if t > cfg.max_len:

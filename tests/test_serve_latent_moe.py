@@ -1,0 +1,409 @@
+"""The DeepSeek-V3 block in the serving runtime (``serve/latent_moe.py``:
+latent attention over one latent page pool, dropless routed experts with
+shared experts), held to the plain reference the benchmark keeps,
+``benchmark/references/deepseek_v3_lm.py``, loaded from its path: one
+reference in the repo.  Toy widths, seeded weights, logits compared.
+
+Tolerances, each with its reason:
+
+* ``LIMIT_SPACINGS`` (tests/closeness.py, 32 float32 spacings at the
+  row's largest logit) wherever two programs compute the same sums in
+  another order: the session's executables against the reference, chunked
+  against one-piece prefill, the absorbed decode against the materialised
+  prefill (which associate ``q_nope . (W_k c)`` as ``(W_k^T q_nope) . c``).
+  tests/conftest.py sets full-precision matmuls, so what is left is
+  float32 rounding: the largest reading over the cases below and 12 seeds
+  was 6.0; a position off by one, a stale page or an expert left out
+  reads in the thousands (``test_the_comparison_can_fail``).
+* The router works in float32 at highest precision on both sides, so a
+  token's experts do not flip between programs at these sizes; a flipped
+  expert would read in the thousands as well.
+* Scheduler runs return tokens only, and an argmax over random weights
+  may turn on a last bit: a served token's logit has to lie within 1e-5
+  of the row's spread below the reference's best (the benchmark's
+  ``served_token_gap``), which an equal logit meets and a wrong row
+  misses by four orders.
+"""
+import functools
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from mxnet_tpu import serve
+from mxnet_tpu.base import MXNetError
+from mxnet_tpu.compile_cache import signature_of
+from mxnet_tpu.serve import latent_moe
+from mxnet_tpu.serve import model as serve_model
+from mxnet_tpu.serve.scheduler import Request, Scheduler
+
+from closeness import assert_close_across_executables, spacings_apart
+from serve_util import lend
+
+_REF = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
+    __file__))), "benchmark", "references", "deepseek_v3_lm.py")
+_spec = importlib.util.spec_from_file_location("deepseek_v3_lm_reference",
+                                               _REF)
+reference = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(reference)
+
+PAGE = 8
+# the reference's configuration: the published config.json's keys
+HF = dict(hidden_size=64, num_attention_heads=4, qk_nope_head_dim=16,
+          qk_rope_head_dim=8, v_head_dim=16, kv_lora_rank=32, vocab_size=97,
+          intermediate_size=96, moe_intermediate_size=24,
+          n_routed_experts=8, n_shared_experts=2, num_experts_per_tok=2,
+          num_hidden_layers=3, first_k_dense_replace=1, rope_theta=1e6,
+          rms_norm_eps=1e-6, routed_scaling_factor=2.448,
+          norm_topk_prob=True, max_position_embeddings=128)
+
+
+def model_config(hf):
+    return serve.ModelConfig(
+        block="deepseek_v3", vocab_size=hf["vocab_size"],
+        num_layers=hf["num_hidden_layers"], d_model=hf["hidden_size"],
+        num_heads=hf["num_attention_heads"],
+        max_len=hf["max_position_embeddings"],
+        qk_nope_head_dim=hf["qk_nope_head_dim"],
+        qk_rope_head_dim=hf["qk_rope_head_dim"],
+        v_head_dim=hf["v_head_dim"], kv_lora_rank=hf["kv_lora_rank"],
+        rope_theta=hf["rope_theta"], rms_norm_eps=hf["rms_norm_eps"],
+        d_ff=hf["intermediate_size"],
+        first_k_dense=hf["first_k_dense_replace"],
+        moe_d_ff=hf["moe_intermediate_size"],
+        n_routed_experts=hf["n_routed_experts"],
+        num_experts_per_tok=hf["num_experts_per_tok"],
+        n_shared_experts=hf["n_shared_experts"],
+        routed_scaling_factor=hf["routed_scaling_factor"],
+        norm_topk_prob=hf["norm_topk_prob"])
+
+
+CFG = model_config(HF)
+ROW = HF["kv_lora_rank"] + HF["qk_rope_head_dim"]
+
+
+@functools.lru_cache(maxsize=None)
+def _jitted_reference(hf_items):
+    hf = dict(hf_items)
+    return jax.jit(lambda params, seq: reference.logits(params, seq, hf))
+
+
+def ref_logits(params, seq, hf=HF):
+    """The reference's (len(seq), vocab) logits.  One compilation a
+    configuration: the sequence is padded to 64 tokens, which a causal
+    model's earlier rows cannot see."""
+    padded = jnp.asarray(list(seq) + [0] * (64 - len(seq)), jnp.int32)
+    return np.asarray(_jitted_reference(tuple(sorted(hf.items())))(
+        params, padded))[:len(seq)]
+
+
+def tokens(seed, n):
+    return np.random.default_rng(seed).integers(
+        0, HF["vocab_size"], n).tolist()
+
+
+@pytest.fixture(scope="module")
+def params():
+    return serve_model.init_params(CFG, seed=3)
+
+
+def session(params, **over):
+    conf = dict(slots=3, page_size=PAGE, buckets=(16, 32), max_new=16,
+                exact=False)
+    conf.update(over)
+    return serve.InferenceSession(params, model=CFG,
+                                  config=serve.ServeConfig(**conf))
+
+
+@pytest.fixture(scope="module")
+def _plain(params):
+    return session(params)
+
+
+@pytest.fixture
+def plain(_plain):
+    yield from lend(_plain)
+
+
+@pytest.fixture(scope="module")
+def _prefix(params):
+    return session(params, prefix_pages=-1)
+
+
+@pytest.fixture
+def prefix(_prefix):
+    yield from lend(_prefix)
+
+
+def test_params_are_the_references_spec(params):
+    assert {k: tuple(v.shape) for k, v in params.items()} \
+        == {k: tuple(v) for k, v in reference.spec(HF).items()}
+    assert latent_moe.param_shapes(CFG) == {
+        k: tuple(v) for k, v in reference.spec(HF).items()}
+
+
+@pytest.mark.parametrize("exact", [False, True])
+@pytest.mark.parametrize("seed", [0, 1])
+def test_full_forward_matches_reference(params, exact, seed):
+    seq = tokens(seed, 40)
+    got = np.asarray(serve_model.full_forward(
+        params, jnp.asarray([seq], jnp.int32), CFG, exact=exact))[0]
+    assert_close_across_executables(got, ref_logits(params, seq))
+
+
+@pytest.mark.parametrize("exact", [False, True])
+def test_prefill_then_decode_through_the_latent_cache(params, exact):
+    """Three prompts of different lengths share the decode batch; every
+    logits row the session returns is the reference's row."""
+    sess = session(params, exact=exact)
+    assert sorted(sess.executables) == ["decode", "prefill_16", "prefill_32"]
+    seqs, slots = [], []
+    for i, n in enumerate((5, 16, 27)):
+        p = tokens(10 + i, n)
+        slot = sess.try_alloc(n, 8, tokens=p)
+        first, logits = sess.prefill(slot, p)
+        assert_close_across_executables(logits, ref_logits(params, p)[-1])
+        seqs.append(p + [first])
+        slots.append(slot)
+    for _ in range(6):
+        toks, logits = sess.step()
+        for slot, seq in zip(slots, seqs):
+            assert_close_across_executables(
+                logits[slot], ref_logits(params, seq)[-1])
+            seq.append(toks[slot])
+    assert sess.fallback_count() == 0
+
+
+def test_chunked_prefill_matches_one_piece(params, plain):
+    """A resumed transcript longer than the largest bucket runs as
+    max-bucket chunks at page-aligned offsets; the same tokens in one
+    piece (a session with a bucket that holds them) and the reference give
+    the same last row."""
+    seq = tokens(21, 45)                         # 32 + 13: two chunks
+    slot = plain.try_alloc(len(seq), 3, tokens=seq, resume=True)
+    _, chunked = plain.prefill(slot, seq)
+    whole = session(params, buckets=(48,), max_new=16)
+    wslot = whole.try_alloc(len(seq), 3, tokens=seq)
+    _, one_piece = whole.prefill(wslot, seq)
+    assert_close_across_executables(chunked, one_piece)
+    assert_close_across_executables(chunked, ref_logits(params, seq)[-1])
+    assert plain.moe_report()["prefill_chunks"] \
+        - whole.moe_report()["prefill_chunks"] >= 1
+
+
+def test_absorbed_decode_matches_materialised_prefill(plain, params):
+    """Position n's logits two ways: prefill of n + 1 tokens (K and V
+    built from the latent rows) and prefill of n tokens followed by one
+    decode step fed token n (absorbed projections, no K or V)."""
+    seq = tokens(22, 20)
+    a = plain.try_alloc(len(seq), 4, tokens=seq)
+    _, materialised = plain.prefill(a, seq)
+    b = plain.try_alloc(len(seq) - 1, 4, tokens=seq[:-1])
+    plain.prefill(b, seq[:-1])
+    plain._slot_tokens[b] = seq[-1]
+    _, logits = plain.step()
+    assert_close_across_executables(logits[b], materialised)
+
+
+def test_prefix_hit_on_latent_pages_gives_cold_logits(prefix, params):
+    """The second request maps the first one's published latent pages
+    read-only and prefills only its suffix; its rows are a cold run's."""
+    shared = tokens(23, 2 * PAGE)
+    pa, pb = shared + tokens(24, 3), shared + tokens(25, 5)
+    sa = prefix.try_alloc(len(pa), 6, tokens=pa)
+    first_a, _ = prefix.prefill(sa, pa)
+    sb = prefix.try_alloc(len(pb), 6, tokens=pb)
+    assert prefix.cache.cached_len(sb) == 2 * PAGE
+    assert int(prefix.cache._tables[sb, 0]) == int(prefix.cache._tables[sa, 0])
+    first_b, logits_b = prefix.prefill(sb, pb)
+    assert_close_across_executables(logits_b, ref_logits(params, pb)[-1])
+    seqs = {sa: pa + [first_a], sb: pb + [first_b]}
+    for _ in range(3):
+        toks, logits = prefix.step()
+        for slot, seq in seqs.items():
+            assert_close_across_executables(
+                logits[slot], ref_logits(params, seq)[-1])
+            seq.append(toks[slot])
+    assert prefix.cache.prefix_stats["hit_pages"] >= 2
+
+
+def served_gap(params, prompt, served):
+    """How far a served token's logit lies below the reference's best, as
+    a share of the row's spread; the widest over the stream."""
+    rows = ref_logits(params, prompt + served[:-1])[len(prompt) - 1:]
+    picked = rows[np.arange(len(served)), served]
+    return float(((rows.max(-1) - picked)
+                  / (rows.max(-1) - rows.min(-1))).max())
+
+
+def test_preempt_and_reprefill_on_latent_pages(params):
+    """A 5-page pool under three growing requests has to preempt; every
+    resumed request re-prefills its transcript (chunked where it outgrew
+    the bucket) and its stream is still the reference's."""
+    sess = session(params, buckets=(8, 16), max_new=8, num_pages=5,
+                   oversub=True, prefix_pages=-1)
+    reqs = [Request(rid=i, prompt=tokens(30 + i, 8), max_new=6,
+                    arrival_s=0.0) for i in range(3)]
+    sched = Scheduler(sess, policy="continuous")
+    done, _ = sched.run(reqs)
+    assert sched.stats["preemptions"] > 0
+    assert sched.stats["resumes"] == sched.stats["preemptions"]
+    for r in done:
+        assert not r.failed, r.error
+        assert len(r.tokens) == r.max_new
+        assert served_gap(params, list(r.prompt), list(r.tokens)) <= 1e-5
+    report = sess.moe_report()
+    assert report["assignments_asked"] == report["assignments_computed"] > 0
+    assert sess.cache.free_slots == sess.config.slots
+
+
+@pytest.mark.parametrize("top_k, hot", [(1, (3,)), (6, (0, 2, 3, 5, 6, 7))])
+def test_skewed_routing_is_dropless(top_k, hot):
+    """A crafted router (a selection bias no score can outweigh) sends
+    every token to the same expert, then to the same six: the tiles of one
+    group outnumber every other's, nothing is dropped, the load is where
+    the bias put it, and the logits are the reference's."""
+    hf = dict(HF, num_experts_per_tok=top_k)
+    cfg = model_config(hf)
+    params = dict(serve_model.init_params(cfg, seed=5))
+    bias = np.zeros((hf["n_routed_experts"],), np.float32)
+    bias[list(hot)] = 10.0
+    for i in range(hf["first_k_dense_replace"], hf["num_hidden_layers"]):
+        params["blk%d_router_bias" % i] = jnp.asarray(bias)
+    seq = tokens(40, 30)
+    got = np.asarray(serve_model.full_forward(
+        params, jnp.asarray([seq], jnp.int32), cfg, exact=False))[0]
+    assert_close_across_executables(got, ref_logits(params, seq, hf))
+    sess = serve.InferenceSession(
+        params, model=cfg, config=serve.ServeConfig(
+            slots=2, page_size=PAGE, buckets=(32,), max_new=8, exact=False))
+    slot = sess.try_alloc(len(seq), 4, tokens=seq)
+    first, logits = sess.prefill(slot, seq)
+    assert_close_across_executables(logits, ref_logits(params, seq, hf)[-1])
+    _, logits = sess.step()
+    assert_close_across_executables(
+        logits[slot], ref_logits(params, seq + [first], hf)[-1])
+    report = sess.moe_report()
+    moe_layers = hf["num_hidden_layers"] - hf["first_k_dense_replace"]
+    # 30 prompt tokens, then one decode step over both slots' rows
+    asked = (len(seq) + 2) * top_k * moe_layers
+    assert report["assignments_asked"] == report["assignments_computed"] \
+        == asked
+    assert report["decode_steps"] == 1 and report["prefill_chunks"] == 1
+    assert report["distinct_experts"] == top_k * moe_layers
+    load = report["expert_load"]
+    assert load.shape == (moe_layers, hf["n_routed_experts"])
+    assert load[:, list(hot)].sum() == asked and load.sum() == asked
+
+
+def test_moe_report_counts_every_router(plain):
+    seq = tokens(41, 11)
+    slot = plain.try_alloc(len(seq), 4, tokens=seq)
+    before = plain.moe_report()
+    plain.prefill(slot, seq)
+    plain.step()
+    after = plain.moe_report()
+    k, layers = HF["num_experts_per_tok"], 2
+    # a decode step routes every slot's row, the idle slots' too
+    asked = (len(seq) + plain.config.slots) * k * layers
+    assert after["assignments_asked"] - before["assignments_asked"] == asked
+    assert after["assignments_computed"] - before["assignments_computed"] \
+        == asked
+    assert after["decode_steps"] - before["decode_steps"] == 1
+    assert (after["expert_load"] - before["expert_load"]).sum() == asked
+    assert after["expert_layers"] == layers
+
+
+def test_counters_carry_past_thirty_bits():
+    stats = jnp.asarray([[(1 << 30) - 3, 5], [2, 0]], jnp.int32)
+    folded = np.asarray(latent_moe._fold(stats, jnp.asarray([7, 1])))
+    assert [int(lo) + (int(hi) << 30) for lo, hi in zip(*folded)] \
+        == [(3 << 30) + 4, 6]
+
+
+def test_pool_is_one_latent_pool(plain):
+    cache, conf = plain.cache, plain.config
+    pages = conf.slots * conf.max_pages_per_slot
+    assert cache.v_pool is None
+    assert cache.k_pool.shape == (HF["num_hidden_layers"], pages + 1, PAGE,
+                                  ROW)
+    assert cache.pool_bytes() == plain.state_report()["pool_bytes"] \
+        == HF["num_hidden_layers"] * (pages + 1) * PAGE * ROW * 4
+    assert plain.moe_report()["expert_load"].shape == (2, 8)
+
+
+@pytest.mark.parametrize("conf", [
+    dict(spec_k=2), dict(kv_quant="int8"),
+    dict(layers="full,window", window=8), dict(window=8)])
+def test_unsupported_combinations_are_refused(params, conf):
+    with pytest.raises(MXNetError, match="does not support"):
+        session(params, **conf)
+
+
+def test_a_wrong_or_missing_architecture_is_refused(params):
+    with pytest.raises(MXNetError, match="num_heads= .* or model="):
+        serve.InferenceSession(params, config=serve.ServeConfig())
+    with pytest.raises(MXNetError, match="architecture says"):
+        serve.InferenceSession(
+            params, model=model_config(dict(HF, kv_lora_rank=16)),
+            config=serve.ServeConfig(page_size=PAGE, buckets=(16,)))
+    with pytest.raises(MXNetError, match="unknown block"):
+        serve.ModelConfig(vocab_size=8, num_layers=1, d_model=8,
+                          num_heads=1, max_len=8, block="other").validate()
+
+
+def test_weight_only_int8_serves_the_block(params):
+    """The cell's control: the quantized session runs, and lands where a
+    lower precision lands, off the float32 reference by more than
+    rounding and by less than a wrong model."""
+    sess = session(params, quant="int8")
+    seq = tokens(42, 20)
+    slot = sess.try_alloc(len(seq), 4, tokens=seq)
+    _, logits = sess.prefill(slot, seq)
+    gap = spacings_apart(logits, ref_logits(params, seq)[-1])
+    assert 1e3 < gap < 1e6
+
+
+def test_the_comparison_can_fail(plain, params):
+    """The planted faults the limit has to catch: a position off by one,
+    and an expert's contribution left out."""
+    seq = tokens(43, 20)
+    slot = plain.try_alloc(len(seq), 4, tokens=seq)
+    first, _ = plain.prefill(slot, seq)
+    plain.cache.lengths[slot] -= 1            # decode at the wrong position
+    _, logits = plain.step()
+    want = ref_logits(params, seq + [first])[-1]
+    assert spacings_apart(logits[slot], want) > 1e3
+    starved = dict(params)
+    starved["blk1_experts_down_weight"] = \
+        params["blk1_experts_down_weight"].at[2].set(0.0)
+    got = np.asarray(serve_model.full_forward(
+        starved, jnp.asarray([seq], jnp.int32), CFG, exact=False))[0]
+    assert spacings_apart(got, ref_logits(params, seq)) > 1e3
+
+
+def test_gpt2_session_executables_are_unchanged():
+    """The GPT-2 call infers as before: the same executables over the same
+    arguments, two pools and no counters."""
+    cfg = serve.ModelConfig(vocab_size=61, num_layers=2, d_model=32,
+                            num_heads=2, max_len=64)
+    assert cfg.block == "gpt2" and cfg.latent_dim == 0
+    params = serve_model.init_params(cfg, seed=3)
+    sess = serve.InferenceSession(
+        params, num_heads=2, config=serve.ServeConfig(
+            slots=3, page_size=PAGE, buckets=(8, 16), max_new=8))
+    assert sorted(sess.executables) == ["decode", "prefill_16", "prefill_8"]
+    assert sess.moe_report() is None and sess._compiler_options() is None
+    sds, i32 = jax.ShapeDtypeStruct, jnp.int32
+    pool = sds((2, 3 * 3 + 1, PAGE, 2, 16), jnp.float32)
+    param_avals = jax.tree.map(lambda v: sds(v.shape, v.dtype), sess.params)
+    assert sess._exes["decode"].aval_sig == signature_of(
+        (param_avals, sds((3,), i32), sds((3,), i32), sds((3, 3), i32),
+         pool, pool))
+    assert sess._exes["prefill_16"].aval_sig == signature_of(
+        (param_avals, sds((1, 16), i32), sds((), i32), sds((), i32),
+         sds((3,), i32), pool, pool))
+    assert sess.cache.pool_bytes() == 2 * 2 * 10 * PAGE * 2 * 16 * 4
