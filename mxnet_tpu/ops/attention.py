@@ -51,7 +51,7 @@ from ..base import MXNetError, get_env
 __all__ = ["attention_impl", "attention_block_size", "dot_product_attention",
            "flash_attention", "reference_attention", "attend_block",
            "online_block_merge", "finalize_attention", "decode_attention",
-           "pallas_eligible"]
+           "paged_decode_attention", "pallas_eligible"]
 
 _IMPLS = ("auto", "flash", "reference")
 
@@ -488,6 +488,82 @@ def decode_attention(q, k_ctx, v_ctx, lengths, scale=None, block=None,
         return body(carry, tuple(next(it) if p else None for p in present))
 
     (acc, _, l), _ = lax.scan(step, (acc0, m0, l0), packed)
+    return finalize_attention(acc, l).astype(q.dtype)
+
+
+# keys one iteration of the paged reader's loop takes.  On a v5e, at 16
+# slots x 16 heads x 128, pages of 16: 8 pages an iteration served 5.6 %
+# more tokens/s than 1 (4 pages 4.9 %), 16 nothing more and short
+# contexts pay for the rounding up (PERF.md, PR 27)
+_PAGED_KEYS_PER_ITERATION = 128
+
+
+def paged_decode_attention(q, k_pool, v_pool, layer, tables, lengths,
+                           page_size, mi=False, k_scale=None, v_scale=None):
+    """The decode step's attention, reading KV pages where they lie.
+
+    :func:`decode_attention` over ``pool[layer][tables]`` without the
+    ``(S, Tcap, H, D)`` copy of every slot's whole page table, and without
+    the blocks past the longest live context: each iteration gathers the
+    next few pages of every slot from the pool and merges them, page by
+    page in ascending order, with the same :func:`attend_block` and the
+    same validity mask.  A block that every slot masks is an exact no-op
+    of the online merge (correction 1, p 0), so stopping after the last
+    block any slot can see gives :func:`decode_attention`'s result bit for
+    bit, ``mi`` or not.  Its cost follows the longest live context, not
+    the table's capacity.
+
+    q: (S, H, 1, D), one query row a slot; k_pool/v_pool:
+    (L, pages + 1, page_size, H, D) as ``PagedKVCache`` lays them out;
+    ``layer`` the pool's layer to read; tables: (S, max_pages) int32 (rows
+    past a slot's reservation name the trash page, which is in bounds);
+    lengths: (S,) int, valid rows INCLUDING the current token, which the
+    caller has appended; ``k_scale``/``v_scale``: (L, pages + 1, page_size)
+    float32 scale pools of quantized pages, dequantized per page inside
+    the loop.  Traced bound, so not differentiable: decode never is.
+    """
+    s, max_pages = tables.shape
+    if q.shape[-2] != 1 or lengths.ndim != 1:
+        raise MXNetError(
+            "paged_decode_attention takes one query row and one length a "
+            "slot, got q %r, lengths %r" % (q.shape, lengths.shape))
+    group = max(1, min(_PAGED_KEYS_PER_ITERATION // page_size, max_pages))
+    # columns that complete the last group lie past every horizon
+    # (position >= max_pages * page_size >= lengths): any page in bounds
+    pad = -max_pages % group
+    if pad:
+        tables = jnp.concatenate(
+            [tables, jnp.broadcast_to(tables[:, -1:], (s, pad))], axis=1)
+    q32 = q.astype(jnp.float32) * (1.0 / (q.shape[-1] ** 0.5))
+    valid_len = lengths.reshape(lengths.shape + (1,) * (q.ndim - 1))
+    live_pages = jnp.clip((jnp.max(lengths) + page_size - 1) // page_size,
+                          0, max_pages)
+
+    def body(it, carry):
+        cols = lax.dynamic_slice_in_dim(tables, it * group, group, axis=1)
+        k_grp = k_pool[layer, cols]          # (S, group, page, H, D)
+        v_grp = v_pool[layer, cols]
+        if k_scale is not None:
+            ks_grp, vs_grp = k_scale[layer, cols], v_scale[layer, cols]
+        for g in range(group):
+            # (S, page, H, D) -> (S, H, page, D), this page alone
+            kblk = k_grp[:, g].transpose(0, 2, 1, 3)
+            vblk = v_grp[:, g].transpose(0, 2, 1, 3)
+            if k_scale is not None:  # in-kernel dequant of quantized pages
+                kblk = kblk.astype(jnp.float32) \
+                    * ks_grp[:, g][:, None, :, None]
+                vblk = vblk.astype(jnp.float32) \
+                    * vs_grp[:, g][:, None, :, None]
+            k_pos = (it * group + g) * page_size + jnp.arange(page_size)
+            carry = attend_block(q32, kblk, vblk, *carry,
+                                 kv_valid=k_pos < valid_len, mi=mi)
+        return carry
+
+    acc0 = jnp.zeros(q.shape[:-1] + (v_pool.shape[-1],), jnp.float32)
+    m0 = jnp.full(q.shape[:-1] + (1,), -jnp.inf, jnp.float32)
+    l0 = jnp.zeros(q.shape[:-1] + (1,), jnp.float32)
+    acc, _, l = lax.fori_loop(0, (live_pages + group - 1) // group, body,
+                              (acc0, m0, l0))
     return finalize_attention(acc, l).astype(q.dtype)
 
 

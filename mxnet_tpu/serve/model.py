@@ -38,7 +38,8 @@ import dataclasses
 import functools
 
 from ..base import MXNetError, get_env
-from ..ops.attention import decode_attention, flash_attention
+from ..ops.attention import (decode_attention, flash_attention,
+                             paged_decode_attention)
 
 __all__ = ["ModelConfig", "exact_mode", "init_params", "config_from_params",
            "full_forward", "prefill_forward", "decode_step", "verify_step",
@@ -676,16 +677,19 @@ def decode_step(params, tokens, lengths, tables, k_pool, v_pool, cfg,
     (S,) int32 — KV rows already cached per slot (the new token's
     position); tables: (S, max_pages) int32 page tables (inactive slots:
     all-trash rows, length 0).  Appends each slot's new KV at
-    ``lengths``, attends over the gathered pages with the shared
+    ``lengths``, attends over the slot's pages with the shared
     online-softmax kernel, and returns
     (next_tokens (S,), logits (S, V), *pools).
 
-    Per-token cost is constant in the generated length: fixed-shape
-    gather/scatter over the page pool plus ``Tcap/page_size`` block
-    visits — there is no tensor here whose size depends on how many
-    tokens any request has generated.
+    Full-attention layers read their pages from the pool in place
+    (:func:`~mxnet_tpu.ops.attention.paged_decode_attention`): no copy of
+    a slot's page table is gathered, and the loop over pages ends at the
+    longest live context, so a step's cost follows what the slots hold
+    and not the table's capacity.  Every shape is fixed all the same:
+    there is no tensor here whose size depends on how many tokens any
+    request has generated.
 
-    Hybrid stacks tighten that constant further: windowed layers write
+    Hybrid stacks bound it further: windowed layers write
     the token's KV at ``lengths % ring_tokens`` in the slot's ring and
     attend over only ``ring_tokens`` rows (the rotated position-labeled
     gather); SSM layers advance the (H, D, D) recurrence one step.
@@ -767,19 +771,11 @@ def decode_step(params, tokens, lengths, tables, k_pool, v_pool, cfg,
             v_pool, v_scale = _kv_append(v_pool, v_scale, fi, page,
                                          offset, v.reshape(s, h, d),
                                          kv_quant)
-            # gather the slot's full page set: (S, P, page, H, D) ->
-            # (S, H, P*page, D)
-            ctx_k = k_pool[fi][tables].reshape(
-                s, max_pages * page_size, h, d).transpose(0, 2, 1, 3)
-            ctx_v = v_pool[fi][tables].reshape(
-                s, max_pages * page_size, h, d).transpose(0, 2, 1, 3)
-            ks = vs = None
-            if kv_quant:
-                ks = k_scale[fi][tables].reshape(s, max_pages * page_size)
-                vs = v_scale[fi][tables].reshape(s, max_pages * page_size)
-            att = decode_attention(q.reshape(s, h, 1, d), ctx_k, ctx_v,
-                                   lengths + 1, block=page_size, mi=exact,
-                                   k_scale=ks, v_scale=vs)
+            # read the pages where they lie, up to the longest context
+            att = paged_decode_attention(
+                q.reshape(s, h, 1, d), k_pool, v_pool, fi, tables,
+                lengths + 1, page_size, mi=exact, k_scale=k_scale,
+                v_scale=v_scale)
             fi += 1
         ctx = att.transpose(0, 2, 1, 3).reshape(s, cfg.d_model)
         out = _mm(ctx, params["blk%d_attn_out_weight" % i], exact) \

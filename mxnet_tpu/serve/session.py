@@ -411,6 +411,7 @@ class InferenceSession(object):
         self._slot_history = {}  # slot -> prompt + committed tokens
         self._spec_stats = {"verify_steps": 0, "slot_steps": 0,
                             "proposed": 0, "accepted": 0, "committed": 0}
+        self._decode_stats = {"steps": 0, "blocks_visited": 0}
         self._resolve_draft(draft_params, draft_num_heads)
         if cfg.quant:
             # weight-only quantization of the at-rest params (the draft
@@ -1011,6 +1012,12 @@ class InferenceSession(object):
         args = (self.params, jnp.asarray(tokens),
                 self.cache.device_lengths(), self.cache.device_tables()) \
             + self._pool_args(self.cache)
+        # the page blocks this step's attention has to visit: those of
+        # the longest context, its new row included
+        longest = int(self.cache.lengths.max()) + 1
+        self._decode_stats["steps"] += 1
+        self._decode_stats["blocks_visited"] += min(
+            -(-longest // cfg.page_size), self.cache.table_width)
         out = self._dispatch("decode", args)
         next_toks, logits = out[0], out[1]
         self._store_pools(self.cache, out[2:])
@@ -1152,6 +1159,27 @@ class InferenceSession(object):
         rep["tokens_per_verify_step"] = (
             rep["committed"] / float(rep["slot_steps"])
             if rep["slot_steps"] else 0.0)
+        return rep
+
+    def decode_report(self):
+        """How much of the page tables the decode steps had to read,
+        counted on the host from ``cache.lengths`` (no device read, no
+        step pays for it): ``steps`` decode steps since the session was
+        built, ``blocks_visited`` the sum over them of the page blocks up
+        to the longest live context (where the loop of
+        :func:`~mxnet_tpu.ops.attention.paged_decode_attention`, which
+        every full-attention layer runs, ends, to within the few pages
+        that complete its last iteration), ``blocks_capacity`` = steps x
+        the table's width, what a reader that ignores the lengths would
+        visit, and ``visited_share`` their ratio.  ``None`` for the
+        latent block, whose decode step has a reader of its own."""
+        if self.model.latent_dim:
+            return None
+        rep = dict(self._decode_stats)
+        rep["blocks_capacity"] = rep["steps"] * self.cache.table_width
+        rep["visited_share"] = (
+            rep["blocks_visited"] / float(rep["blocks_capacity"])
+            if rep["blocks_capacity"] else 0.0)
         return rep
 
     def moe_report(self):

@@ -1,0 +1,258 @@
+"""The dense decode step's attention reads KV pages in place
+(``ops/attention.py:paged_decode_attention``): bit for bit
+``decode_attention`` over the gathered context, with no gathered context
+in the program, stopping at the longest live context; and
+``InferenceSession.decode_report()`` says how far that was."""
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from mxnet_tpu import quantize, serve
+from mxnet_tpu.ops import attention
+from mxnet_tpu.ops.attention import decode_attention, paged_decode_attention
+from mxnet_tpu.serve import model as serve_model
+
+from serve_util import lend
+
+S, H, D, PAGE, MAX_PAGES, LAYERS, LAYER = 3, 2, 8, 4, 5, 2, 1
+CAP = PAGE * MAX_PAGES
+TRASH = S * MAX_PAGES
+
+# valid rows a slot (the current token included) -> what the case is for
+LENGTHS = {
+    "mid_page": (6, 3, 10),
+    "page_boundary": (8, 4, 12),
+    "one_row": (1, 1, 1),
+    "at_capacity": (CAP, CAP, CAP),
+    "idle_beside_full": (1, CAP, 7),   # an idle slot attends length 0 + 1
+    "one_long": (CAP - 3, 2, 5),
+    "two_iterations": (2 * PAGE + 1, 3, PAGE),
+}
+
+
+def _pools(rs, kv_quant=""):
+    """K and V pools of LAYERS layers whose trash page is NaN-free
+    garbage, page tables that map only the pages a length needs (the
+    rest name the trash page, as the cache does), and one query row."""
+    shape = (LAYERS, TRASH + 1, PAGE, H, D)
+    k = rs.randn(*shape).astype(np.float32)
+    v = rs.randn(*shape).astype(np.float32)
+    q = rs.randn(S, H, 1, D).astype(np.float32)
+    if not kv_quant:
+        return jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), None, None
+    kq, ks = quantize.kv_quantize_rows(jnp.asarray(k), kv_quant)
+    vq, vs = quantize.kv_quantize_rows(jnp.asarray(v), kv_quant)
+    return jnp.asarray(q), kq, vq, ks, vs
+
+
+def _tables(rs, lengths):
+    perm = rs.permutation(TRASH)
+    tables = np.full((S, MAX_PAGES), TRASH, np.int32)
+    for s, n in enumerate(lengths):
+        pages = -(-n // PAGE)
+        tables[s, :pages] = perm[s * MAX_PAGES:s * MAX_PAGES + pages]
+    return jnp.asarray(tables)
+
+
+def _gathered(q, k, v, ks, vs, tables, lengths, mi):
+    """What ``decode_step`` did before: gather every slot's whole table,
+    then ``decode_attention`` over all ``MAX_PAGES`` blocks."""
+    ctx_k = k[LAYER][tables].reshape(S, CAP, H, D).transpose(0, 2, 1, 3)
+    ctx_v = v[LAYER][tables].reshape(S, CAP, H, D).transpose(0, 2, 1, 3)
+    if ks is not None:
+        ks = ks[LAYER][tables].reshape(S, CAP)
+        vs = vs[LAYER][tables].reshape(S, CAP)
+    return decode_attention(q, ctx_k, ctx_v, lengths, block=PAGE, mi=mi,
+                            k_scale=ks, v_scale=vs)
+
+
+def _paged(q, k, v, ks, vs, tables, lengths, mi):
+    return paged_decode_attention(q, k, v, LAYER, tables, lengths, PAGE,
+                                  mi=mi, k_scale=ks, v_scale=vs)
+
+
+@pytest.mark.parametrize("kv_quant", ["", "int8"])
+@pytest.mark.parametrize("mi", [True, False])
+@pytest.mark.parametrize("case", sorted(LENGTHS))
+def test_paged_reader_equals_gathered_reader_bit_for_bit(case, mi, kv_quant):
+    """Skipping the blocks every slot masks, and reading the rest from
+    the pool, changes no bit: lengths that end mid-page, on a page
+    boundary, at 1, at the table's capacity, an idle slot beside a full
+    one, float32 pages and quantized ones with their scales."""
+    rs = np.random.RandomState(7)
+    q, k, v, ks, vs = _pools(rs, kv_quant)
+    lengths = jnp.asarray(LENGTHS[case], jnp.int32)
+    tables = _tables(rs, LENGTHS[case])
+    want = jax.jit(_gathered, static_argnames="mi")(
+        q, k, v, ks, vs, tables, lengths, mi=mi)
+    got = jax.jit(_paged, static_argnames="mi")(
+        q, k, v, ks, vs, tables, lengths, mi=mi)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.fixture(autouse=True)
+def _two_pages_an_iteration(monkeypatch):
+    """At these toy tables the reader's own group would swallow the whole
+    table in one iteration: two pages an iteration (five columns, so the
+    last iteration is completed with a masked one) unless a test says
+    otherwise."""
+    monkeypatch.setattr(attention, "_PAGED_KEYS_PER_ITERATION", 2 * PAGE)
+
+
+@pytest.mark.parametrize("kv_quant", ["", "int8"])
+@pytest.mark.parametrize("keys", [PAGE, 3 * PAGE, 64 * PAGE])
+def test_pages_an_iteration_do_not_change_the_result(monkeypatch, keys,
+                                                     kv_quant):
+    """One page an iteration, three (the table's five columns completed
+    with a masked sixth), or the whole table in one: the same bits."""
+    rs = np.random.RandomState(8)
+    q, k, v, ks, vs = _pools(rs, kv_quant)
+    lengths = jnp.asarray(LENGTHS["one_long"], jnp.int32)
+    tables = _tables(rs, LENGTHS["one_long"])
+    want = _gathered(q, k, v, ks, vs, tables, lengths, True)
+    monkeypatch.setattr(attention, "_PAGED_KEYS_PER_ITERATION", keys)
+    got = _paged(q, k, v, ks, vs, tables, lengths, True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("pool", ["k", "v"])
+def test_planted_fault_in_a_live_page_is_seen(pool):
+    """The control of the comparison above: two rows of one live page
+    swapped in the pool change the paged reader's result and leave the
+    other slots' alone; the same swap in a page no table maps changes
+    nothing."""
+    rs = np.random.RandomState(9)
+    q, k, v, ks, vs = _pools(rs)
+    lengths = jnp.asarray(LENGTHS["mid_page"], jnp.int32)
+    tables = _tables(rs, LENGTHS["mid_page"])
+    sound = np.asarray(_paged(q, k, v, ks, vs, tables, lengths, True))
+
+    def swapped(page):
+        arr = np.array(k if pool == "k" else v)
+        arr[LAYER, page, [0, 1]] = arr[LAYER, page, [1, 0]]
+        kk, vv = (arr, v) if pool == "k" else (k, arr)
+        return np.asarray(_paged(q, jnp.asarray(kk), jnp.asarray(vv), ks,
+                                 vs, tables, lengths, True))
+
+    live = int(tables[2, 1])     # slot 2 holds 10 rows: its second page
+    faulty = swapped(live)
+    assert np.abs(faulty[2] - sound[2]).max() > 1e-3
+    np.testing.assert_array_equal(faulty[:2], sound[:2])
+    unmapped = sorted(set(range(TRASH)) - set(np.asarray(tables).ravel()))
+    np.testing.assert_array_equal(swapped(unmapped[0]), sound)
+
+
+def test_reader_refuses_more_than_one_query_row():
+    rs = np.random.RandomState(10)
+    q, k, v, ks, vs = _pools(rs)
+    tables = _tables(rs, LENGTHS["mid_page"])
+    from mxnet_tpu.base import MXNetError
+
+    with pytest.raises(MXNetError, match="one query row"):
+        paged_decode_attention(jnp.concatenate([q, q], axis=2), k, v, LAYER,
+                               tables, jnp.asarray([6, 3, 10]), PAGE)
+
+
+# ---------------------------------------------------------------------------
+# the decode program and the session's counter
+# ---------------------------------------------------------------------------
+
+CFG = serve.ModelConfig(vocab_size=61, num_layers=2, d_model=32,
+                        num_heads=2, max_len=64)
+SLOTS, SPAGE = 3, 8
+
+
+@pytest.fixture(scope="module")
+def _session():
+    sconf = serve.ServeConfig(slots=SLOTS, page_size=SPAGE, buckets=(8, 16),
+                              max_new=8, exact=True)
+    return serve.InferenceSession(serve_model.init_params(CFG, seed=3),
+                                  num_heads=CFG.num_heads, config=sconf)
+
+
+@pytest.fixture
+def session(_session):
+    yield from lend(_session)
+
+
+@pytest.mark.parametrize("kv_quant", ["", "int8"])
+def test_decode_program_holds_no_copy_of_a_slots_whole_table(kv_quant):
+    """The gathered context cannot come back unnoticed: no array of the
+    jitted decode program, before or after XLA's passes, has the shape
+    (S, max_pages * page, H, D), its transpose, or the gather's own
+    (S, max_pages, page, H, D).  The same search finds the copy in the
+    program that gathers (the control)."""
+    s, page, max_pages = 3, 4, 5
+    h, d = CFG.num_heads, CFG.head_dim
+    params = serve_model.init_params(CFG, seed=3)
+    store = jnp.int8 if kv_quant else jnp.float32
+    pool = jnp.zeros((CFG.num_layers, s * max_pages + 1, page, h, d), store)
+    scale = jnp.ones(pool.shape[:3], jnp.float32) if kv_quant else None
+    tables = jnp.zeros((s, max_pages), jnp.int32)
+    ints = jnp.zeros((s,), jnp.int32)
+
+    def shapes_of(fn, *args):
+        lowered = jax.jit(fn).lower(*args)
+        text = lowered.as_text() + lowered.compile().as_text()
+        dims = set(re.findall(r"(?:tensor<|[a-z]\d+\[)([\dx,]+)", text))
+        return {tuple(int(n) for n in re.split("[x,]", dim) if n)
+                for dim in dims}
+
+    def decode(params, tokens, lengths, tables, k_pool, v_pool, ks, vs):
+        return serve_model.decode_step(
+            params, tokens, lengths, tables, k_pool, v_pool, CFG, page,
+            exact=False, kv_quant=kv_quant, k_scale=ks, v_scale=vs)
+
+    def gathers(k_pool, tables):
+        return k_pool[0][tables].reshape(
+            s, max_pages * page, h, d).transpose(0, 2, 1, 3) * 2
+
+    cap = max_pages * page
+    banned = {(s, cap, h, d), (s, h, cap, d), (s, max_pages, page, h, d)}
+    assert banned & shapes_of(gathers, pool.astype(jnp.float32), tables)
+    found = shapes_of(decode, params, ints, ints, tables, pool, pool,
+                      scale, scale)
+    assert (s, page, h, d) in found     # the search reads this program
+    assert not banned & found
+
+
+def test_decode_report_counts_blocks_to_the_longest_context(session):
+    """``blocks_visited`` grows each step by the page blocks of the
+    longest live context, its new row included, and ``blocks_capacity``
+    by the table's width; idle slots count as one block."""
+    width = session.cache.table_width
+    assert width == (16 + 8) // SPAGE
+    before = session.decode_report()
+    rs = np.random.RandomState(5)
+    for n in (3, 13):
+        slot = session.try_alloc(n, 8)
+        session.prefill(slot, rs.randint(1, CFG.vocab_size, size=n).tolist())
+    want = 0
+    for step in range(5):
+        # the longest context holds 13 + step rows and appends one
+        want += -(-(13 + step + 1) // SPAGE)
+        session.step()
+    rep = session.decode_report()
+    assert rep["steps"] - before["steps"] == 5
+    assert rep["blocks_visited"] - before["blocks_visited"] == want == 12
+    assert rep["blocks_capacity"] - before["blocks_capacity"] == 5 * width
+    assert rep["visited_share"] == rep["blocks_visited"] / rep[
+        "blocks_capacity"]
+    session.reset_cold()
+    session.step()          # no live slot: every row attends its one row
+    rep2 = session.decode_report()
+    assert rep2["blocks_visited"] - rep["blocks_visited"] == 1
+
+
+def test_decode_report_of_a_fresh_session_is_zero():
+    sconf = serve.ServeConfig(slots=2, page_size=SPAGE, buckets=(8,),
+                              max_new=8, exact=True)
+    sess = serve.InferenceSession(serve_model.init_params(CFG, seed=3),
+                                  num_heads=CFG.num_heads, config=sconf)
+    assert sess.decode_report() == {"steps": 0, "blocks_visited": 0,
+                                    "blocks_capacity": 0,
+                                    "visited_share": 0.0}
