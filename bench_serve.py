@@ -475,7 +475,8 @@ def measure(argv=None):
         config=_dc.replace(sconf, kv_quant=kvq))
     assert len(kvsess.executables) == len(sconf.buckets) + 1
     _RESULT["kv_quant"] = kvq
-    _RESULT["kv_code_dtype"] = str(np.dtype(kvsess.cache.k_pool.dtype))
+    _RESULT["kv_code_dtype"] = str(np.dtype(
+        kvsess.cache.pools["k_pool"].dtype))
 
     # the M-invariant oracle holds PER PRECISION: quantized paged decode
     # must match the jitted reference forward at the SAME kv precision

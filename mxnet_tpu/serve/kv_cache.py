@@ -13,12 +13,21 @@ executables consume as a plain (slots, max_pages) int32 array.
 
 A latent-attention model (``latent_dim > 0``, ``serve/latent_moe.py``)
 keeps one row of ``latent_dim`` values a token a layer instead of
-per-head keys and values: ONE pool
+per-head keys and values: ONE pool and no other
 
-    k_pool : (num_layers, num_pages + 1, page_size, latent_dim)
+    latent_pool : (num_layers, num_pages + 1, page_size, latent_dim)
 
-and no ``v_pool``.  Pages, tables, reference counts, the prefix index,
-oversubscription and copy-on-write do not know the difference.
+Pages, tables, reference counts, the prefix index, oversubscription and
+copy-on-write do not know the difference.
+
+**One owner.**  Every device array the cache holds lives in
+:attr:`PagedKVCache.pools`, one mapping from name to array that is built
+once in ``__init__`` and holds only what this cache has.  The serve
+executables take the mapping as one pytree argument and return it as
+one (a dict flattens in sorted key order); the session stores what came
+back.  THIS file is where the names are written; the step functions read
+them by name, and nothing else enumerates them.  A new kind of state is
+one more entry here (plus its name in ``paged`` if pages index it).
 
 Two admission modes (vs the original reservation-only pager):
 
@@ -173,42 +182,40 @@ class PagedKVCache:
             dtype = dtype or jnp.float32
         pool_shape = (max(self.n_full, 1), self.num_pages + 1,
                       self.page_size, self.num_heads, self.head_dim)
+        # name -> device array: ALL the cache's device state, and the one
+        # pytree every serve executable takes and returns
+        self.pools = {}
         if self.latent_dim:
-            pool_shape = pool_shape[:3] + (self.latent_dim,)
-        self.k_pool = jnp.zeros(pool_shape, dtype)
-        self.v_pool = None if self.latent_dim else jnp.zeros(pool_shape,
-                                                             dtype)
-        if self.kv_quant:
-            scale_shape = pool_shape[:3]
-            self.k_scale = jnp.ones(scale_shape, jnp.float32)
-            self.v_scale = jnp.ones(scale_shape, jnp.float32)
+            self.pools["latent_pool"] = jnp.zeros(
+                pool_shape[:3] + (self.latent_dim,), dtype)
         else:
-            self.k_scale = self.v_scale = None
+            self.pools["k_pool"] = jnp.zeros(pool_shape, dtype)
+            self.pools["v_pool"] = jnp.zeros(pool_shape, dtype)
+        if self.kv_quant:
+            self.pools["k_scale"] = jnp.ones(pool_shape[:3], jnp.float32)
+            self.pools["v_scale"] = jnp.ones(pool_shape[:3], jnp.float32)
+        # the pools whose axis 1 is the page: what copy-on-write copies
+        self.paged = tuple(self.pools)
         # windowed-layer rings: slot-indexed, no page table — every slot
         # owns exactly ring_pages pages for each windowed layer, for the
         # session's whole lifetime (that is the O(1)-per-slot story)
         if self.n_window:
             ring_shape = (self.n_window, self.slots, self.ring_tokens,
                           self.num_heads, self.head_dim)
-            self.kw_pool = jnp.zeros(ring_shape, dtype)
-            self.vw_pool = jnp.zeros(ring_shape, dtype)
+            self.pools["kw_pool"] = jnp.zeros(ring_shape, dtype)
+            self.pools["vw_pool"] = jnp.zeros(ring_shape, dtype)
             if self.kv_quant:
-                self.kw_scale = jnp.ones(ring_shape[:3], jnp.float32)
-                self.vw_scale = jnp.ones(ring_shape[:3], jnp.float32)
-            else:
-                self.kw_scale = self.vw_scale = None
-        else:
-            self.kw_pool = self.vw_pool = None
-            self.kw_scale = self.vw_scale = None
+                self.pools["kw_scale"] = jnp.ones(ring_shape[:3],
+                                                  jnp.float32)
+                self.pools["vw_scale"] = jnp.ones(ring_shape[:3],
+                                                  jnp.float32)
         # SSM state pool: fp32 regardless of kv_quant — the state is a
         # running accumulator, not content-addressed KV rows; quantizing
         # it would break the chunked-prefill == serial-decode contract
         if self.n_ssm:
-            self.ssm_state = jnp.zeros(
+            self.pools["ssm_state"] = jnp.zeros(
                 (self.n_ssm, self.slots, self.num_heads, self.head_dim,
                  self.head_dim), jnp.float32)
-        else:
-            self.ssm_state = None
         # min-heaps: heappop yields the lowest free id, preserving the
         # deterministic lowest-first reuse contract (a sorted range is
         # already a valid heap)
@@ -451,8 +458,9 @@ class PagedKVCache:
         # rows need no scrub — the position labels the windowed gather
         # computes for a fresh request exclude every row the request has
         # not itself written (stale rows label as position < 0)
-        if self.ssm_state is not None:
-            self.ssm_state = self.ssm_state.at[:, slot].set(0.0)
+        if "ssm_state" in self.pools:
+            self.pools["ssm_state"] = \
+                self.pools["ssm_state"].at[:, slot].set(0.0)
         if tokens is not None and self.prefix_pages:
             self.prefix_stats["lookups"] += 1
             if hit:
@@ -535,18 +543,13 @@ class PagedKVCache:
                 self._retained.pop(page, None)
                 continue
             new = self._take_page()
-            # device-side page copy across all layers in one op; pure
-            # copy, so the private page is bit-identical to the shared
-            # one and the stream stays exact
-            self.k_pool = self.k_pool.at[:, new].set(self.k_pool[:, page])
-            if self.v_pool is not None:
-                self.v_pool = self.v_pool.at[:, new].set(
-                    self.v_pool[:, page])
-            if self.kv_quant:  # scale rows travel with their codes
-                self.k_scale = self.k_scale.at[:, new].set(
-                    self.k_scale[:, page])
-                self.v_scale = self.v_scale.at[:, new].set(
-                    self.v_scale[:, page])
+            # device-side page copy across all layers, one op a paged
+            # pool (scale rows travel with their codes); pure copy, so
+            # the private page is bit-identical to the shared one and
+            # the stream stays exact
+            for name in self.paged:
+                pool = self.pools[name]
+                self.pools[name] = pool.at[:, new].set(pool[:, page])
             self._refcount[new] = 1
             pages[idx] = new
             self._tables[slot, idx] = new
@@ -657,19 +660,7 @@ class PagedKVCache:
         """Total device bytes held by the pools (scale pools included
         for quantized caches) — constant for the session's lifetime,
         which IS the O(1) decode-memory story."""
-        total = int(self.k_pool.nbytes)
-        if self.v_pool is not None:
-            total += int(self.v_pool.nbytes)
-        if self.kv_quant:
-            total += int(self.k_scale.nbytes) + int(self.v_scale.nbytes)
-        if self.kw_pool is not None:
-            total += int(self.kw_pool.nbytes) + int(self.vw_pool.nbytes)
-            if self.kv_quant:
-                total += (int(self.kw_scale.nbytes)
-                          + int(self.vw_scale.nbytes))
-        if self.ssm_state is not None:
-            total += int(self.ssm_state.nbytes)
-        return total
+        return sum(int(pool.nbytes) for pool in self.pools.values())
 
     @classmethod
     def page_bytes(cls, num_layers, num_heads, head_dim, page_size,

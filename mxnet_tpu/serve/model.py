@@ -36,14 +36,34 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import importlib
 
 from ..base import MXNetError, get_env
 from ..ops.attention import (decode_attention, flash_attention,
                              paged_decode_attention)
 
-__all__ = ["ModelConfig", "exact_mode", "init_params", "config_from_params",
-           "full_forward", "prefill_forward", "decode_step", "verify_step",
-           "draft_propose", "reference_last_logits"]
+__all__ = ["ModelConfig", "BLOCKS", "block_of", "exact_mode", "init_params",
+           "config_from_params", "full_forward", "prefill_forward",
+           "decode_step", "verify_step", "draft_propose",
+           "reference_last_logits"]
+
+# ``ModelConfig.block`` -> the module of this package that provides the
+# block.  Every such module has the same surface (docs/serving.md, "Adding
+# a block", names it; tests/test_serve_blocks.py holds the modules to
+# it).  This module is the GPT-2 block.
+BLOCKS = {"gpt2": "model", "deepseek_v3": "latent_moe"}
+
+
+def block_of(cfg):
+    """The module that provides ``cfg.block``, imported on first use."""
+    mod = BLOCKS.get(cfg.block)
+    if mod is None:
+        raise MXNetError("unknown block %r (%s)"
+                         % (cfg.block, " or ".join(BLOCKS)))
+    if isinstance(mod, str):
+        mod = BLOCKS[cfg.block] = importlib.import_module("." + mod,
+                                                          __package__)
+    return mod
 
 
 def exact_mode():
@@ -65,8 +85,8 @@ class ModelConfig:
     ``attn_in``/``attn_out`` weights, so any attention checkpoint hosts
     any stack.  The empty tuple means all-full (the classic decoder).
 
-    ``block`` names the architecture.  ``"gpt2"`` (learned positions,
-    LayerNorm, GELU, biased fused-QKV heads) is what
+    ``block`` names the architecture, a key of :data:`BLOCKS`.  ``"gpt2"``
+    (learned positions, LayerNorm, GELU, biased fused-QKV heads) is what
     :func:`config_from_params` infers from a parameter dict;
     ``"deepseek_v3"`` (``latent_moe.py``: RMSNorm, RoPE, latent
     attention, SwiGLU, routed and shared experts, no bias, no position
@@ -104,14 +124,6 @@ class ModelConfig:
         return self.d_model // self.num_heads
 
     @property
-    def latent_dim(self):
-        """Values the cache holds a token a layer in ONE pool (the latent
-        block), or 0 for per-head K and V pools."""
-        if self.block == "gpt2":
-            return 0
-        return self.kv_lora_rank + self.qk_rope_head_dim
-
-    @property
     def kinds(self):
         """Per-layer kinds, expanded to ``num_layers`` entries."""
         return self.layer_kinds or ("full",) * self.num_layers
@@ -121,29 +133,84 @@ class ModelConfig:
         return any(k != "full" for k in self.kinds)
 
     def validate(self):
-        if self.block != "gpt2":
-            from . import latent_moe
+        return block_of(self).validate(self)
 
-            if self.block != latent_moe.BLOCK:
-                raise MXNetError("unknown block %r (gpt2 or %s)"
-                                 % (self.block, latent_moe.BLOCK))
-            return latent_moe.validate(self)
-        if self.d_model % self.num_heads:
-            raise MXNetError("d_model %d not divisible by num_heads %d"
-                             % (self.d_model, self.num_heads))
-        if self.layer_kinds:
-            if len(self.layer_kinds) != self.num_layers:
-                raise MXNetError(
-                    "layer_kinds %r does not cover %d layers"
-                    % (self.layer_kinds, self.num_layers))
-            bad = set(self.layer_kinds) - {"full", "window", "ssm"}
-            if bad:
-                raise MXNetError("unknown layer kinds %r" % sorted(bad))
-            if "window" in self.layer_kinds and self.window < 1:
-                raise MXNetError(
-                    "windowed layers need window >= 1 (got %d)"
-                    % self.window)
-        return self
+
+# -- the GPT-2 block's surface, beside the step functions below ----------
+REFUSES = ()      # ServeConfig features a session over this block refuses
+REFUSES_WHY = ""
+
+
+def validate(cfg):
+    if cfg.d_model % cfg.num_heads:
+        raise MXNetError("d_model %d not divisible by num_heads %d"
+                         % (cfg.d_model, cfg.num_heads))
+    if cfg.layer_kinds:
+        if len(cfg.layer_kinds) != cfg.num_layers:
+            raise MXNetError("layer_kinds %r does not cover %d layers"
+                             % (cfg.layer_kinds, cfg.num_layers))
+        bad = set(cfg.layer_kinds) - {"full", "window", "ssm"}
+        if bad:
+            raise MXNetError("unknown layer kinds %r" % sorted(bad))
+        if "window" in cfg.layer_kinds and cfg.window < 1:
+            raise MXNetError("windowed layers need window >= 1 (got %d)"
+                             % cfg.window)
+    return cfg
+
+
+def check_params(params, cfg):
+    """The parameter dict has the architecture's shapes: all of it that
+    shapes can tell (``num_heads`` appears in none)."""
+    got = config_from_params(params, cfg.num_heads)
+    for name in ("vocab_size", "num_layers", "d_model", "max_len"):
+        if getattr(got, name) != getattr(cfg, name):
+            raise MXNetError("the parameters say %s %d, the architecture "
+                             "says %d" % (name, getattr(got, name),
+                                          getattr(cfg, name)))
+
+
+def latent_dim(cfg):
+    """Values the cache holds a token a layer in ONE latent pool; 0 for
+    this block's per-head K and V pools."""
+    return 0
+
+
+def init_counters(cfg):
+    """The block's own device state, which every executable takes and
+    returns beside the cache's pools: none."""
+    return {}
+
+
+def compiler_options(backend):
+    return None
+
+
+def report(counters, cfg):
+    """``InferenceSession.moe_report()``: this block has no routers."""
+    return None
+
+
+def decode_report(stats, table_width):
+    """``InferenceSession.decode_report()`` from the session's host-side
+    counts: every full-attention layer of :func:`decode_step` runs
+    :func:`~mxnet_tpu.ops.attention.paged_decode_attention`, whose loop
+    ends at the longest live context."""
+    rep = dict(stats)
+    rep["blocks_capacity"] = rep["steps"] * table_width
+    rep["visited_share"] = (
+        rep["blocks_visited"] / float(rep["blocks_capacity"])
+        if rep["blocks_capacity"] else 0.0)
+    return rep
+
+
+def guard_tag(cfg):
+    """What the recompile guard's name must tell apart beyond the widths:
+    a hybrid stack adds ring/state pool avals (and a window length baked
+    into every trace), so it must never share a guard with the classic
+    stack — window length plus the per-layer kind initials (f/w/s)."""
+    if not cfg.hybrid:
+        return ""
+    return "-w%d%s" % (cfg.window, "".join(k[0] for k in cfg.kinds))
 
 
 def _resolve_params(params):
@@ -192,10 +259,9 @@ def init_params(cfg, seed=0, scale=0.02):
     import jax.numpy as jnp
 
     cfg.validate()
-    if cfg.block != "gpt2":
-        from . import latent_moe
-
-        return latent_moe.init_params(cfg, seed=seed, scale=scale)
+    theirs = block_of(cfg).init_params
+    if theirs is not init_params:
+        return theirs(cfg, seed=seed, scale=scale)
     keys = iter(jax.random.split(jax.random.PRNGKey(seed),
                                  4 * cfg.num_layers + 4))
 
@@ -259,24 +325,30 @@ def _attn_heads(x, n, t, h, d):
     return x.reshape(n, t, h, d).transpose(0, 2, 1, 3)
 
 
-def _kv_append(pool, scale_pool, i, pages, offsets, rows, kv_quant):
-    """Scatter a batch of KV rows into layer ``i`` of the page pool.
+def _append(pools, which, i, major, minor, rows, kv_quant):
+    """Scatter a batch of KV rows into layer ``i`` of ``pools[which +
+    "_pool"]``, in place in the mapping.  ``which`` is ``"k"`` / ``"v"``
+    (the page pools: ``major`` the pages, ``minor`` the offsets in them)
+    or ``"kw"`` / ``"vw"`` (a windowed layer's per-slot ring, (Lw, S, R,
+    H, D): ``major`` the slots, ``minor`` the ring rows; broadcastable).
 
-    ``rows`` is (N, H, D) — one row per token.  With ``kv_quant`` each
+    ``rows`` is (..., H, D) — one row per token.  With ``kv_quant`` each
     row quantizes independently (codes into the storage pool, one
-    float32 scale per row into the parallel scale pool), so a page's
-    bytes are a pure function of the tokens written to it — the property
-    that keeps prefill scatter, serial decode append, batched verify
-    append, prefix-hit replay and preempt/re-prefill byte-identical.
+    float32 scale per row into the parallel ``which + "_scale"`` pool),
+    so a page's or ring's bytes are a pure function of the tokens
+    written to it — the property that keeps prefill scatter, serial
+    decode append, batched verify append, prefix-hit replay, COW and
+    preempt/re-prefill byte-identical.
     """
+    name = which + "_pool"
     if kv_quant:
         from .. import quantize as _q
 
-        codes, scales = _q.kv_quantize_rows(rows, kv_quant)
-        pool = pool.at[i, pages, offsets].set(codes)
-        scale_pool = scale_pool.at[i, pages, offsets].set(scales)
-        return pool, scale_pool
-    return pool.at[i, pages, offsets].set(rows.astype(pool.dtype)), scale_pool
+        rows, scales = _q.kv_quantize_rows(rows, kv_quant)
+        pools[which + "_scale"] = \
+            pools[which + "_scale"].at[i, major, minor].set(scales)
+    pools[name] = pools[name].at[i, major, minor].set(
+        rows.astype(pools[name].dtype))
 
 
 def _kv_fake_quant(k, v, kv_quant):
@@ -312,42 +384,6 @@ def _qkv_heads(params, i, x, cfg, exact):
     q, k, v = jnp.split(qkv, 3, axis=-1)
     return (_attn_heads(q, n, t, h, d), _attn_heads(k, n, t, h, d),
             _attn_heads(v, n, t, h, d))
-
-
-def _pool_pack(k_pool, v_pool, k_scale, v_scale, kw_pool, vw_pool,
-               kw_scale, vw_scale, ssm_state, kv_quant):
-    """Canonical pool ordering every serve executable returns (and the
-    session's ``_pool_args``/``_store_pools`` mirror): paged pools, the
-    paged scales (kv_quant), the window rings, the ring scales
-    (kv_quant), then the SSM state pool.  Absent pools are simply
-    omitted, so the classic all-full stack keeps its historical
-    signature byte-for-byte."""
-    pools = [k_pool, v_pool]
-    if kv_quant:
-        pools += [k_scale, v_scale]
-    if kw_pool is not None:
-        pools += [kw_pool, vw_pool]
-        if kv_quant:
-            pools += [kw_scale, vw_scale]
-    if ssm_state is not None:
-        pools.append(ssm_state)
-    return tuple(pools)
-
-
-def _pool_names(kv_quant, has_window, has_ssm):
-    """Keyword names matching :func:`_pool_pack`'s ordering — lets a
-    caller re-bind a packed pool tuple onto the executables' signatures
-    without hand-maintaining the order in two places."""
-    names = ["k_pool", "v_pool"]
-    if kv_quant:
-        names += ["k_scale", "v_scale"]
-    if has_window:
-        names += ["kw_pool", "vw_pool"]
-        if kv_quant:
-            names += ["kw_scale", "vw_scale"]
-    if has_ssm:
-        names.append("ssm_state")
-    return tuple(names)
 
 
 def _block_attention(params, i, x, cfg, exact, block, kv_quant="",
@@ -401,29 +437,10 @@ def _block_ssm(params, i, x, cfg, exact, state0=None, row_valid=None,
     return (x + out,) + res[1:]
 
 
-def _ring_append(pool, scale_pool, i, slot_ids, rows_idx, rows, kv_quant):
-    """Scatter KV rows into windowed layer ``i``'s per-slot ring.
-
-    pool: (Lw, S, R, H, D); ``slot_ids``/``rows_idx`` broadcastable int
-    arrays selecting (slot, ring row) per written token; ``rows`` the
-    matching (..., H, D) values.  Quantization is per row with the same
-    helper the paged pools use, so ring bytes are a pure function of
-    the token written — the preempt/re-prefill and COW arguments carry
-    over to rings unchanged."""
-    if kv_quant:
-        from .. import quantize as _q
-
-        codes, scales = _q.kv_quantize_rows(rows, kv_quant)
-        pool = pool.at[i, slot_ids, rows_idx].set(codes)
-        scale_pool = scale_pool.at[i, slot_ids, rows_idx].set(scales)
-        return pool, scale_pool
-    return (pool.at[i, slot_ids, rows_idx].set(rows.astype(pool.dtype)),
-            scale_pool)
-
-
-def _ring_gather(pool, scale_pool, i, pb_max, page_size, kv_quant,
-                 slot=None):
-    """Gather a ring in ascending-absolute-position order.
+def _ring_gather(pools, which, i, pb_max, page_size, slot=None):
+    """Gather layer ``i`` of the ring ``pools[which + "_pool"]`` (and its
+    scales, where the mapping holds them) in ascending-absolute-position
+    order.
 
     ``pb_max``: (S,) int32 — the highest absolute PAGE index written
     (the newest page).  The ring's pages are rotated so the gathered
@@ -436,6 +453,7 @@ def _ring_gather(pool, scale_pool, i, pb_max, page_size, kv_quant,
     (ctx (S, R, H, D), scales (S, R) or None, k_positions (S, R))."""
     import jax.numpy as jnp
 
+    pool, scale_pool = pools[which + "_pool"], pools.get(which + "_scale")
     ring = pool[i] if slot is None else \
         jnp.take(pool[i], slot, axis=0)[None]
     s, ring_tokens = ring.shape[0], ring.shape[1]
@@ -451,7 +469,7 @@ def _ring_gather(pool, scale_pool, i, pb_max, page_size, kv_quant,
                    + in_page[None, None, :]).reshape(s, ring_tokens)
     ctx = jnp.take_along_axis(ring, row_idx[:, :, None, None], axis=1)
     scales = None
-    if kv_quant:
+    if scale_pool is not None:
         sc = scale_pool[i] if slot is None else \
             jnp.take(scale_pool[i], slot, axis=0)[None]
         scales = jnp.take_along_axis(sc, row_idx, axis=1)
@@ -483,14 +501,12 @@ def full_forward(params, tokens, cfg, exact=None, block=None,
 
     if exact is None:
         exact = exact_mode()
-    if cfg.block != "gpt2":
-        from . import latent_moe
-
+    theirs = block_of(cfg).full_forward
+    if theirs is not full_forward:
         if return_kv or kv_quant:
             raise MXNetError("block %r: full_forward has no return_kv and "
                              "no kv_quant" % cfg.block)
-        return latent_moe.full_forward(params, tokens, cfg, exact,
-                                       block=block)
+        return theirs(params, tokens, cfg, exact, block=block)
     params = _resolve_params(params)
     t = tokens.shape[-1]
     if t > cfg.max_len:
@@ -521,10 +537,8 @@ def full_forward(params, tokens, cfg, exact=None, block=None,
     return logits
 
 
-def prefill_forward(params, tokens, length, offset, table_row, k_pool,
-                    v_pool, cfg, page_size, exact=None, k_scale=None,
-                    v_scale=None, kv_quant="", kw_pool=None, vw_pool=None,
-                    kw_scale=None, vw_scale=None, ssm_state=None,
+def prefill_forward(params, tokens, length, offset, table_row, pools,
+                    counters, cfg, page_size, exact=None, kv_quant="",
                     slot=None):
     """Bucketed prefill over one suffix chunk: write the chunk's KV into
     the slot's pages and attend each row over everything at or before
@@ -538,9 +552,11 @@ def prefill_forward(params, tokens, length, offset, table_row, k_pool,
     ``page_size`` multiple — chunks are page-aligned; 0 reproduces the
     classic whole-prompt prefill); table_row: (max_pages,) int32 page
     ids — entries beyond the slot's mapped pages point at the trash
-    page.  Returns (first_token, last_logits, k_pool, v_pool) where
-    ``last_logits`` is the logits at chunk position ``length - 1``
-    (absolute position ``offset + length - 1``); the pools are
+    page; pools: the cache's device state (``PagedKVCache.pools``, read
+    here by name); counters: the block's own device state, none for
+    this block.  Returns (first_token, last_logits, pools, counters)
+    where ``last_logits`` is the logits at chunk position ``length - 1``
+    (absolute position ``offset + length - 1``); pools and counters are
     donate-safe.
 
     The body is :func:`verify_step` for one slot: per-row absolute
@@ -564,8 +580,7 @@ def prefill_forward(params, tokens, length, offset, table_row, k_pool,
     position-labeled rotated ring gather; SSM layers advance the slot's
     recurrence state (``ssm_state``) across the chunk in one
     ``lax.scan`` — chunk padding passes the state through untouched.
-    The updated ring/state pools ride the return tuple after the paged
-    pools (and their scales).
+    The updated ring/state pools come back in the same mapping.
     """
     import jax.numpy as jnp
 
@@ -578,7 +593,8 @@ def prefill_forward(params, tokens, length, offset, table_row, k_pool,
                          % (t_b, page_size))
     h, d = cfg.num_heads, cfg.head_dim
     max_pages = table_row.shape[0]
-    trash = k_pool.shape[1] - 1  # pool row num_pages, static in-graph
+    pools = dict(pools)
+    trash = pools["k_pool"].shape[1] - 1  # pool row num_pages, static
     offs = jnp.arange(t_b, dtype=jnp.int32)
     abs_pos = offset + offs                               # (Tb,)
     pos = jnp.clip(abs_pos, 0, cfg.max_len - 1)
@@ -593,11 +609,12 @@ def prefill_forward(params, tokens, length, offset, table_row, k_pool,
     fi = wi = si = 0  # per-kind pool indices (static)
     for i, kind in enumerate(cfg.kinds):
         if kind == "ssm":
-            state0 = jnp.take(ssm_state[si], slot, axis=0)[None]
+            state0 = jnp.take(pools["ssm_state"][si], slot, axis=0)[None]
             rv = (offs < length).reshape(1, t_b)  # padding: state no-op
             x, state = _block_ssm(params, i, x, cfg, exact, state0=state0,
                                   row_valid=rv)
-            ssm_state = ssm_state.at[si, slot].set(state[0])
+            pools["ssm_state"] = \
+                pools["ssm_state"].at[si, slot].set(state[0])
             si += 1
             x = _block_mlp(params, i, x, exact)
             continue
@@ -607,21 +624,16 @@ def prefill_forward(params, tokens, length, offset, table_row, k_pool,
             + params["blk%d_attn_in_bias" % i]
         q, k, v = jnp.split(qkv, 3, axis=-1)
         if kind == "window":
-            ring_tokens = kw_pool.shape[2]
-            ring_rows = abs_pos % ring_tokens
-            kw_pool, kw_scale = _ring_append(
-                kw_pool, kw_scale, wi, slot, ring_rows,
-                k.reshape(t_b, h, d), kv_quant)
-            vw_pool, vw_scale = _ring_append(
-                vw_pool, vw_scale, wi, slot, ring_rows,
-                v.reshape(t_b, h, d), kv_quant)
-            pb_max = (offset + t_b - 1) // page_size
-            ctx_k, ks, kp = _ring_gather(kw_pool, kw_scale, wi,
-                                         jnp.atleast_1d(pb_max),
-                                         page_size, kv_quant, slot=slot)
-            ctx_v, vs, _ = _ring_gather(vw_pool, vw_scale, wi,
-                                        jnp.atleast_1d(pb_max),
-                                        page_size, kv_quant, slot=slot)
+            ring_rows = abs_pos % pools["kw_pool"].shape[2]
+            _append(pools, "kw", wi, slot, ring_rows,
+                    k.reshape(t_b, h, d), kv_quant)
+            _append(pools, "vw", wi, slot, ring_rows,
+                    v.reshape(t_b, h, d), kv_quant)
+            pb_max = jnp.atleast_1d((offset + t_b - 1) // page_size)
+            ctx_k, ks, kp = _ring_gather(pools, "kw", wi, pb_max,
+                                         page_size, slot=slot)
+            ctx_v, vs, _ = _ring_gather(pools, "vw", wi, pb_max,
+                                        page_size, slot=slot)
             att = decode_attention(
                 q.reshape(1, t_b, h, d).transpose(0, 2, 1, 3),
                 ctx_k.transpose(0, 2, 1, 3), ctx_v.transpose(0, 2, 1, 3),
@@ -631,21 +643,19 @@ def prefill_forward(params, tokens, length, offset, table_row, k_pool,
         else:
             # append the chunk's KV at its absolute rows (one vectorized
             # scatter; only trash rows can collide, nothing reads them)
-            k_pool, k_scale = _kv_append(k_pool, k_scale, fi, pages,
-                                         offsets, k.reshape(t_b, h, d),
-                                         kv_quant)
-            v_pool, v_scale = _kv_append(v_pool, v_scale, fi, pages,
-                                         offsets, v.reshape(t_b, h, d),
-                                         kv_quant)
-            ctx_k = k_pool[fi][table_row].reshape(
+            _append(pools, "k", fi, pages, offsets, k.reshape(t_b, h, d),
+                    kv_quant)
+            _append(pools, "v", fi, pages, offsets, v.reshape(t_b, h, d),
+                    kv_quant)
+            ctx_k = pools["k_pool"][fi][table_row].reshape(
                 1, max_pages * page_size, h, d).transpose(0, 2, 1, 3)
-            ctx_v = v_pool[fi][table_row].reshape(
+            ctx_v = pools["v_pool"][fi][table_row].reshape(
                 1, max_pages * page_size, h, d).transpose(0, 2, 1, 3)
             ks = vs = None
             if kv_quant:
-                ks = k_scale[fi][table_row].reshape(
+                ks = pools["k_scale"][fi][table_row].reshape(
                     1, max_pages * page_size)
-                vs = v_scale[fi][table_row].reshape(
+                vs = pools["v_scale"][fi][table_row].reshape(
                     1, max_pages * page_size)
             att = decode_attention(
                 q.reshape(1, t_b, h, d).transpose(0, 2, 1, 3),
@@ -662,24 +672,20 @@ def prefill_forward(params, tokens, length, offset, table_row, k_pool,
         + params["lm_head_bias"]
     last = jnp.take(logits[0], length - 1, axis=0)
     first_token = jnp.argmax(last, axis=-1).astype(jnp.int32)
-    return (first_token, last) + _pool_pack(
-        k_pool, v_pool, k_scale, v_scale, kw_pool, vw_pool, kw_scale,
-        vw_scale, ssm_state, kv_quant)
+    return first_token, last, pools, counters
 
 
-def decode_step(params, tokens, lengths, tables, k_pool, v_pool, cfg,
-                page_size, exact=None, k_scale=None, v_scale=None,
-                kv_quant="", kw_pool=None, vw_pool=None, kw_scale=None,
-                vw_scale=None, ssm_state=None):
+def decode_step(params, tokens, lengths, tables, pools, counters, cfg,
+                page_size, exact=None, kv_quant=""):
     """One continuous-batching decode step for every slot at once.
 
     tokens: (S,) int32 — each slot's previous output token; lengths:
     (S,) int32 — KV rows already cached per slot (the new token's
     position); tables: (S, max_pages) int32 page tables (inactive slots:
-    all-trash rows, length 0).  Appends each slot's new KV at
-    ``lengths``, attends over the slot's pages with the shared
-    online-softmax kernel, and returns
-    (next_tokens (S,), logits (S, V), *pools).
+    all-trash rows, length 0); pools and counters as in
+    :func:`prefill_forward`.  Appends each slot's new KV at ``lengths``,
+    attends over the slot's pages with the shared online-softmax kernel,
+    and returns (next_tokens (S,), logits (S, V), pools, counters).
 
     Full-attention layers read their pages from the pool in place
     (:func:`~mxnet_tpu.ops.attention.paged_decode_attention`): no copy of
@@ -705,6 +711,7 @@ def decode_step(params, tokens, lengths, tables, k_pool, v_pool, cfg,
     s = tokens.shape[0]
     h, d = cfg.num_heads, cfg.head_dim
     max_pages = tables.shape[1]
+    pools = dict(pools)
     x = jnp.take(params["tok_embed_weight"], tokens.astype(jnp.int32),
                  axis=0)
     pos = jnp.clip(lengths, 0, cfg.max_len - 1)
@@ -727,8 +734,8 @@ def decode_step(params, tokens, lengths, tables, k_pool, v_pool, cfg,
             y, state = ssm_scan(q.reshape(s, 1, h, d),
                                 k.reshape(s, 1, h, d),
                                 v.reshape(s, 1, h, d),
-                                ssm_state[si], ssm_decay(h))
-            ssm_state = ssm_state.at[si].set(state)
+                                pools["ssm_state"][si], ssm_decay(h))
+            pools["ssm_state"] = pools["ssm_state"].at[si].set(state)
             ctx = y.astype(x.dtype).reshape(s, cfg.d_model)
             out = _mm(ctx, params["blk%d_attn_out_weight" % i], exact) \
                 + params["blk%d_attn_out_bias" % i]
@@ -742,19 +749,15 @@ def decode_step(params, tokens, lengths, tables, k_pool, v_pool, cfg,
             + params["blk%d_attn_in_bias" % i]
         q, k, v = jnp.split(qkv, 3, axis=-1)
         if kind == "window":
-            ring_tokens = kw_pool.shape[2]
-            ring_rows = lengths % ring_tokens
-            kw_pool, kw_scale = _ring_append(
-                kw_pool, kw_scale, wi, slot_ids, ring_rows,
-                k.reshape(s, h, d), kv_quant)
-            vw_pool, vw_scale = _ring_append(
-                vw_pool, vw_scale, wi, slot_ids, ring_rows,
-                v.reshape(s, h, d), kv_quant)
+            ring_rows = lengths % pools["kw_pool"].shape[2]
+            _append(pools, "kw", wi, slot_ids, ring_rows,
+                    k.reshape(s, h, d), kv_quant)
+            _append(pools, "vw", wi, slot_ids, ring_rows,
+                    v.reshape(s, h, d), kv_quant)
             pb_max = lengths // page_size
-            ctx_k, ks, kp = _ring_gather(kw_pool, kw_scale, wi, pb_max,
-                                         page_size, kv_quant)
-            ctx_v, vs, _ = _ring_gather(vw_pool, vw_scale, wi, pb_max,
-                                        page_size, kv_quant)
+            ctx_k, ks, kp = _ring_gather(pools, "kw", wi, pb_max,
+                                         page_size)
+            ctx_v, vs, _ = _ring_gather(pools, "vw", wi, pb_max, page_size)
             att = decode_attention(q.reshape(s, h, 1, d),
                                    ctx_k.transpose(0, 2, 1, 3),
                                    ctx_v.transpose(0, 2, 1, 3),
@@ -765,17 +768,15 @@ def decode_step(params, tokens, lengths, tables, k_pool, v_pool, cfg,
         else:
             # append this token's KV at (page, offset); inactive slots
             # write the trash page (their table rows are all-trash)
-            k_pool, k_scale = _kv_append(k_pool, k_scale, fi, page,
-                                         offset, k.reshape(s, h, d),
-                                         kv_quant)
-            v_pool, v_scale = _kv_append(v_pool, v_scale, fi, page,
-                                         offset, v.reshape(s, h, d),
-                                         kv_quant)
+            _append(pools, "k", fi, page, offset, k.reshape(s, h, d),
+                    kv_quant)
+            _append(pools, "v", fi, page, offset, v.reshape(s, h, d),
+                    kv_quant)
             # read the pages where they lie, up to the longest context
             att = paged_decode_attention(
-                q.reshape(s, h, 1, d), k_pool, v_pool, fi, tables,
-                lengths + 1, page_size, mi=exact, k_scale=k_scale,
-                v_scale=v_scale)
+                q.reshape(s, h, 1, d), pools["k_pool"], pools["v_pool"],
+                fi, tables, lengths + 1, page_size, mi=exact,
+                k_scale=pools.get("k_scale"), v_scale=pools.get("v_scale"))
             fi += 1
         ctx = att.transpose(0, 2, 1, 3).reshape(s, cfg.d_model)
         out = _mm(ctx, params["blk%d_attn_out_weight" % i], exact) \
@@ -786,15 +787,11 @@ def decode_step(params, tokens, lengths, tables, k_pool, v_pool, cfg,
     logits = _mm(x, params["lm_head_weight"], exact) \
         + params["lm_head_bias"]
     next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return (next_tokens, logits) + _pool_pack(
-        k_pool, v_pool, k_scale, v_scale, kw_pool, vw_pool, kw_scale,
-        vw_scale, ssm_state, kv_quant)
+    return next_tokens, logits, pools, counters
 
 
-def verify_step(params, tokens, lengths, tables, k_pool, v_pool, cfg,
-                page_size, exact=None, k_scale=None, v_scale=None,
-                kv_quant="", kw_pool=None, vw_pool=None, kw_scale=None,
-                vw_scale=None, ssm_state=None, limits=None):
+def verify_step(params, tokens, lengths, tables, pools, counters, cfg,
+                page_size, exact=None, kv_quant="", limits=None):
     """Speculative-decoding verify: advance every slot ``W = K + 1``
     teacher-forced positions in ONE fixed-shape step.
 
@@ -804,7 +801,7 @@ def verify_step(params, tokens, lengths, tables, k_pool, v_pool, cfg,
     Writes all W rows' KV at positions ``lengths .. lengths + W - 1``
     and attends row ``j`` over exactly ``lengths + j + 1`` keys (the
     causal horizon expressed as a per-row validity length), then
-    returns (greedy (S, W), logits (S, W, V), *pools).
+    returns (greedy (S, W), logits (S, W, V), pools, counters).
 
     Bit-exactness contract: with ``exact=True`` every op here is the
     M-invariant form of the matching :func:`decode_step` op, and the
@@ -841,6 +838,7 @@ def verify_step(params, tokens, lengths, tables, k_pool, v_pool, cfg,
     s, w = tokens.shape
     h, d = cfg.num_heads, cfg.head_dim
     max_pages = tables.shape[1]
+    pools = dict(pools)
     x = jnp.take(params["tok_embed_weight"], tokens.astype(jnp.int32),
                  axis=0)
     offs = jnp.arange(w, dtype=lengths.dtype)
@@ -866,7 +864,7 @@ def verify_step(params, tokens, lengths, tables, k_pool, v_pool, cfg,
             from ..ops.ssm_ops import ssm_decay, ssm_scan
 
             y, _, snaps = ssm_scan(q.reshape(s, w, h, d), k, v,
-                                   ssm_state[si], ssm_decay(h),
+                                   pools["ssm_state"][si], ssm_decay(h),
                                    collect=True)
             ssm_snaps.append((si, snaps))
             si += 1
@@ -880,38 +878,35 @@ def verify_step(params, tokens, lengths, tables, k_pool, v_pool, cfg,
         # j only ever reads rows <= j of this very step plus committed
         # context, so write-then-attend reproduces the serial interleave
         if kind == "window":
-            ring_tokens = kw_pool.shape[2]
+            ring_tokens = pools["kw_pool"].shape[2]
             for j in range(w):
                 rr = abs_pos[:, j] % ring_tokens
-                kw_pool, kw_scale = _ring_append(
-                    kw_pool, kw_scale, wi, slot_ids, rr, k[:, j], kv_quant)
-                vw_pool, vw_scale = _ring_append(
-                    vw_pool, vw_scale, wi, slot_ids, rr, v[:, j], kv_quant)
+                _append(pools, "kw", wi, slot_ids, rr, k[:, j], kv_quant)
+                _append(pools, "vw", wi, slot_ids, rr, v[:, j], kv_quant)
             pb_max = (lengths + w - 1) // page_size
-            ctx_k, ks, kp = _ring_gather(kw_pool, kw_scale, wi, pb_max,
-                                         page_size, kv_quant)
-            ctx_v, vs, _ = _ring_gather(vw_pool, vw_scale, wi, pb_max,
-                                        page_size, kv_quant)
+            ctx_k, ks, kp = _ring_gather(pools, "kw", wi, pb_max,
+                                         page_size)
+            ctx_v, vs, _ = _ring_gather(pools, "vw", wi, pb_max, page_size)
             ctx_k = ctx_k.transpose(0, 2, 1, 3)
             ctx_v = ctx_v.transpose(0, 2, 1, 3)
             win = cfg.window
             wi += 1
         else:
             for j in range(w):
-                k_pool, k_scale = _kv_append(k_pool, k_scale, fi,
-                                             pages[:, j], offsets[:, j],
-                                             k[:, j], kv_quant)
-                v_pool, v_scale = _kv_append(v_pool, v_scale, fi,
-                                             pages[:, j], offsets[:, j],
-                                             v[:, j], kv_quant)
-            ctx_k = k_pool[fi][tables].reshape(
+                _append(pools, "k", fi, pages[:, j], offsets[:, j],
+                        k[:, j], kv_quant)
+                _append(pools, "v", fi, pages[:, j], offsets[:, j],
+                        v[:, j], kv_quant)
+            ctx_k = pools["k_pool"][fi][tables].reshape(
                 s, max_pages * page_size, h, d).transpose(0, 2, 1, 3)
-            ctx_v = v_pool[fi][tables].reshape(
+            ctx_v = pools["v_pool"][fi][tables].reshape(
                 s, max_pages * page_size, h, d).transpose(0, 2, 1, 3)
             ks = vs = kp = None
             if kv_quant:
-                ks = k_scale[fi][tables].reshape(s, max_pages * page_size)
-                vs = v_scale[fi][tables].reshape(s, max_pages * page_size)
+                ks = pools["k_scale"][fi][tables].reshape(
+                    s, max_pages * page_size)
+                vs = pools["v_scale"][fi][tables].reshape(
+                    s, max_pages * page_size)
             win = 0
             fi += 1
         att = decode_attention(q.reshape(s, w, h, d).transpose(0, 2, 1, 3),
@@ -943,16 +938,12 @@ def verify_step(params, tokens, lengths, tables, k_pool, v_pool, cfg,
             per_slot = jnp.moveaxis(snaps, 0, 1)
             sel = jnp.take_along_axis(
                 per_slot, idx[:, None, None, None, None], axis=1)[:, 0]
-            ssm_state = ssm_state.at[si].set(sel)
-    return (greedy, logits) + _pool_pack(
-        k_pool, v_pool, k_scale, v_scale, kw_pool, vw_pool, kw_scale,
-        vw_scale, ssm_state, kv_quant)
+            pools["ssm_state"] = pools["ssm_state"].at[si].set(sel)
+    return greedy, logits, pools, counters
 
 
-def draft_propose(params, tokens, n_feed, lengths, tables, k_pool, v_pool,
-                  cfg, page_size, exact=None, k_scale=None, v_scale=None,
-                  kv_quant="", kw_pool=None, vw_pool=None, kw_scale=None,
-                  vw_scale=None):
+def draft_propose(params, tokens, n_feed, lengths, tables, pools, counters,
+                  cfg, page_size, exact=None, kv_quant=""):
     """Draft-model K+1-step scan: one dispatch that both *ingests*
     committed tokens and *proposes* speculative continuations.
 
@@ -963,9 +954,9 @@ def draft_propose(params, tokens, n_feed, lengths, tables, k_pool, v_pool,
     ``n_feed = W`` is pure teacher forcing (prompt ingestion in W-token
     chunks).  Every step appends its token's KV at ``lengths + j``, so
     the draft cache tracks exactly the positions the target cache holds.
-    Returns (outs (S, W), *pools) where ``outs[:, j]`` is the greedy
-    token after feeding position ``lengths + j`` — propose mode uses
-    ``outs[:, :W-1]`` as its K proposals.
+    Returns (outs (S, W), pools, counters) where ``outs[:, j]`` is the
+    greedy token after feeding position ``lengths + j`` — propose mode
+    uses ``outs[:, :W-1]`` as its K proposals.
 
     Draft stacks may mix full and windowed layers (the ring append /
     rotated gather is scan-compatible and rollback is lengths-only) but
@@ -985,25 +976,21 @@ def draft_propose(params, tokens, n_feed, lengths, tables, k_pool, v_pool,
     # resolve once, outside the scan body, so the dequantized weights
     # are loop invariants XLA hoists rather than per-step work
     params = _resolve_params(params)
-    pools0 = _pool_pack(k_pool, v_pool, k_scale, v_scale, kw_pool,
-                        vw_pool, kw_scale, vw_scale, None, kv_quant)
-    names = _pool_names(kv_quant, kw_pool is not None, False)
 
     def body(carry, xs):
-        prev, pools = carry
+        prev, state = carry
         teach, j = xs
         tok = jnp.where(j < n_feed, teach, prev)
-        out = decode_step(params, tok, lengths + j, tables,
+        out = decode_step(params, tok, lengths + j, tables, *state,
                           cfg=cfg, page_size=page_size, exact=exact,
-                          kv_quant=kv_quant,
-                          **dict(zip(names, pools)))
+                          kv_quant=kv_quant)
         return (out[0], out[2:]), out[0]
 
     w = tokens.shape[1]
     xs = (tokens.T, jnp.arange(w, dtype=lengths.dtype))
-    carry0 = (tokens[:, 0].astype(jnp.int32), pools0)
-    (_, pools), outs = lax.scan(body, carry0, xs)
-    return (outs.T,) + pools
+    carry0 = (tokens[:, 0].astype(jnp.int32), (pools, counters))
+    (_, state), outs = lax.scan(body, carry0, xs)
+    return (outs.T,) + state
 
 
 @functools.lru_cache(maxsize=None)

@@ -202,10 +202,10 @@ def test_decode_program_holds_no_copy_of_a_slots_whole_table(kv_quant):
         return {tuple(int(n) for n in re.split("[x,]", dim) if n)
                 for dim in dims}
 
-    def decode(params, tokens, lengths, tables, k_pool, v_pool, ks, vs):
+    def decode(params, tokens, lengths, tables, pools):
         return serve_model.decode_step(
-            params, tokens, lengths, tables, k_pool, v_pool, CFG, page,
-            exact=False, kv_quant=kv_quant, k_scale=ks, v_scale=vs)
+            params, tokens, lengths, tables, pools, {}, CFG, page,
+            exact=False, kv_quant=kv_quant)
 
     def gathers(k_pool, tables):
         return k_pool[0][tables].reshape(
@@ -214,8 +214,10 @@ def test_decode_program_holds_no_copy_of_a_slots_whole_table(kv_quant):
     cap = max_pages * page
     banned = {(s, cap, h, d), (s, h, cap, d), (s, max_pages, page, h, d)}
     assert banned & shapes_of(gathers, pool.astype(jnp.float32), tables)
-    found = shapes_of(decode, params, ints, ints, tables, pool, pool,
-                      scale, scale)
+    pools = {"k_pool": pool, "v_pool": pool}
+    if kv_quant:
+        pools.update(k_scale=scale, v_scale=scale)
+    found = shapes_of(decode, params, ints, ints, tables, pools)
     assert (s, page, h, d) in found     # the search reads this program
     assert not banned & found
 

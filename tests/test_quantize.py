@@ -796,9 +796,9 @@ def _wrong_row_scale(sess, slots):
 
     cache = sess.cache
     page = int(cache._tables[slots[0], 0])
-    scale = np.array(cache.k_scale)
+    scale = np.array(cache.pools["k_scale"])
     scale[:, page, [0, 1]] = scale[:, page, [1, 0]]
-    cache.k_scale = jnp.asarray(scale)
+    cache.pools["k_scale"] = jnp.asarray(scale)
 
 
 @pytest.mark.parametrize("kw,plant", [

@@ -84,7 +84,7 @@ def test_kv_cache_alloc_release_exhaustion():
         cache.can_admit(100, 100)  # can never fit a slot
     # unreserved table entries point at the write-only trash page
     assert cache._tables[s2, -1] == cache.trash_page
-    assert cache.pool_bytes() == 2 * cache.k_pool.nbytes
+    assert cache.pool_bytes() == 2 * cache.pools["k_pool"].nbytes
 
 
 def test_serve_config_validation():

@@ -1,0 +1,162 @@
+"""The two seams of the serving path.
+
+* A block is a module with a fixed surface (``serve/model.py``'s
+  ``BLOCKS`` table; docs/serving.md, "Adding a block"), and
+  ``InferenceSession`` reaches the architecture through nothing else: a
+  third block registered here, out of the GPT-2 functions under another
+  name and holding ONLY the surface's names, is served end to end by an
+  unedited session.
+* ``PagedKVCache.pools`` is the one owner of the cache's device state:
+  for every session variant that exists, what the executables return is
+  what the cache holds, ``pool_bytes()`` is its sum, and a copy-on-write
+  copies the page in every pool that pages index and in no other.
+"""
+import dataclasses
+import types
+
+import numpy as np
+import pytest
+
+from mxnet_tpu import serve
+from mxnet_tpu.base import MXNetError
+from mxnet_tpu.serve import latent_moe
+from mxnet_tpu.serve import model as serve_model
+from mxnet_tpu.serve.scheduler import Request, Scheduler
+
+# what the session may ask of a block, and no more (docs/serving.md)
+SURFACE = ("validate", "check_params", "init_params", "full_forward",
+           "latent_dim", "init_counters", "prefill_forward", "decode_step",
+           "REFUSES", "REFUSES_WHY", "compiler_options", "report",
+           "decode_report", "guard_tag")
+SPECULATIVE = ("verify_step", "draft_propose")
+
+GPT2 = serve.ModelConfig(vocab_size=61, num_layers=3, d_model=32,
+                         num_heads=2, max_len=64)
+LATENT = serve.ModelConfig(
+    block="deepseek_v3", vocab_size=61, num_layers=2, d_model=32,
+    num_heads=2, max_len=64, qk_nope_head_dim=8, qk_rope_head_dim=4,
+    v_head_dim=8, kv_lora_rank=12, d_ff=48, first_k_dense=1, moe_d_ff=16,
+    n_routed_experts=4, num_experts_per_tok=2, n_shared_experts=1)
+CONF = dict(slots=3, page_size=8, buckets=(8, 16), max_new=8)
+
+
+@pytest.mark.parametrize("name", sorted(serve_model.BLOCKS))
+def test_every_block_provides_the_surface(name):
+    block = serve_model.block_of(dataclasses.replace(GPT2, block=name))
+    assert [n for n in SURFACE if not hasattr(block, n)] == []
+    # the speculative steps, unless the block says it refuses spec_k
+    if "spec_k" not in block.REFUSES:
+        assert [n for n in SPECULATIVE if not hasattr(block, n)] == []
+
+
+def served(sess):
+    reqs = [Request(rid=i, prompt=np.random.default_rng(i).integers(
+        0, GPT2.vocab_size, 5 + 3 * i).tolist(), max_new=6, arrival_s=0.0)
+        for i in range(4)]
+    done, _ = Scheduler(sess, policy="continuous").run(reqs)
+    assert not any(r.failed for r in done), [r.error for r in done]
+    assert sess.fallback_count() == 0
+    return {r.rid: list(r.tokens) for r in done}
+
+
+def test_a_third_block_is_served_by_an_unedited_session(monkeypatch):
+    twin = types.SimpleNamespace(**{
+        n: getattr(serve_model, n) for n in SURFACE + SPECULATIVE})
+    monkeypatch.setitem(serve_model.BLOCKS, "gpt2_twin", twin)
+    cfg = dataclasses.replace(GPT2, block="gpt2_twin").validate()
+    params = serve.init_params(cfg, seed=3)
+    conf = serve.ServeConfig(spec_k=2, draft="layers:1", **CONF)
+    want = served(serve.InferenceSession(params, num_heads=2, config=conf))
+    sess = serve.InferenceSession(params, model=cfg, config=conf)
+    assert sess.block is twin and sess.model.block == "gpt2_twin"
+    assert sorted(sess.executables) == [
+        "decode", "draft", "prefill_16", "prefill_8", "verify"]
+    assert served(sess) == want
+    assert all(len(toks) == 6 for toks in want.values())
+    assert sess.moe_report() is None
+    assert sess.decode_report() is not None
+    with pytest.raises(MXNetError, match="the architecture"):
+        serve.InferenceSession(
+            params, model=dataclasses.replace(cfg, max_len=32), config=conf)
+
+
+VARIANTS = {
+    "classic": (GPT2, dict()),
+    "kv_int8": (GPT2, dict(kv_quant="int8")),
+    "hybrid": (GPT2, dict(layers="full,window,ssm", window=8)),
+    "hybrid_kv_int8": (GPT2, dict(layers="full,window", window=8,
+                                  kv_quant="int8")),
+    "spec": (GPT2, dict(spec_k=2, draft="layers:1")),
+    "latent": (LATENT, dict()),
+}
+
+
+def avals(pools):
+    return {name: (tuple(p.shape), str(p.dtype))
+            for name, p in pools.items()}
+
+
+@pytest.mark.parametrize("variant", sorted(VARIANTS))
+def test_the_cache_owns_its_device_state(variant):
+    cfg, over = VARIANTS[variant]
+    params = serve.init_params(cfg, seed=5)
+    sess = serve.InferenceSession(
+        params, model=cfg, config=serve.ServeConfig(**dict(CONF, **over)))
+    caches = [c for c in (sess.cache, sess.draft_cache) if c is not None]
+    built = [avals(c.pools) for c in caches]
+    assert all(built)
+
+    # what every executable returns is what the cache was built with
+    prompt = list(range(1, 12))
+    slot = sess.try_alloc(len(prompt), 4, tokens=prompt)
+    sess.prefill(slot, prompt)
+    assert [avals(c.pools) for c in caches] == built
+    sess.spec_step() if sess.config.spec_k else sess.step()
+    assert [avals(c.pools) for c in caches] == built
+    assert sess.fallback_count() == 0
+    assert set(sess.counters) == set(sess.block.init_counters(sess.model))
+
+    for cache in caches:
+        pools = cache.pools
+        assert cache.pool_bytes() == sum(p.nbytes for p in pools.values())
+        assert sess.state_report()["pool_bytes"] == sess.cache.pool_bytes()
+        # the paged pools are those whose second axis is the page
+        assert set(cache.paged) == {
+            n for n, p in pools.items()
+            if p.shape[1] == cache.num_pages + 1}
+        assert cache.paged
+
+        # copy-on-write: a second holder of the slot's first page, then a
+        # write into it; every paged pool gets the page copied, bit for
+        # bit, and every other pool is left as it was
+        page = cache._pages_of[slot][0]
+        before = {n: np.array(p) for n, p in pools.items()}
+        assert all(np.any(before[n][:, page] != 0) for n in cache.paged)
+        cache._refcount[page] += 1
+        assert cache.ensure_writable(slot, 0, 1) == 1
+        new = cache._pages_of[slot][0]
+        assert new != page and cache._tables[slot, 0] == new
+        for name, pool in cache.pools.items():
+            after = np.array(pool)
+            if name in cache.paged:
+                np.testing.assert_array_equal(after[:, new],
+                                              before[name][:, page])
+                after[:, new] = before[name][:, new]
+            np.testing.assert_array_equal(after, before[name])
+        cache._drop_ref(page)           # the second holder lets go
+    sess.release(slot)
+    assert sess.cache.free_pages == sess.cache.num_pages
+
+
+def test_pool_names_are_the_caches_alone():
+    """A latent cache holds one pool under its own name and no ``k_pool``;
+    the latent block's counters are not among the pools."""
+    sess = serve.InferenceSession(
+        serve.init_params(LATENT, seed=5), model=LATENT,
+        config=serve.ServeConfig(**CONF))
+    assert list(sess.cache.pools) == ["latent_pool"]
+    assert list(sess.counters) == ["moe_stats"]
+    assert sess.counters["moe_stats"].shape == (
+        2, latent_moe.stats_size(LATENT))
+    assert sess.decode_report() is None
+    assert sess.moe_report()["decode_steps"] == 0

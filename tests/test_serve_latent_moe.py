@@ -327,9 +327,9 @@ def test_counters_carry_past_thirty_bits():
 def test_pool_is_one_latent_pool(plain):
     cache, conf = plain.cache, plain.config
     pages = conf.slots * conf.max_pages_per_slot
-    assert cache.v_pool is None
-    assert cache.k_pool.shape == (HF["num_hidden_layers"], pages + 1, PAGE,
-                                  ROW)
+    assert list(cache.pools) == ["latent_pool"]
+    assert cache.pools["latent_pool"].shape == (
+        HF["num_hidden_layers"], pages + 1, PAGE, ROW)
     assert cache.pool_bytes() == plain.state_report()["pool_bytes"] \
         == HF["num_hidden_layers"] * (pages + 1) * PAGE * ROW * 4
     assert plain.moe_report()["expert_load"].shape == (2, 8)
@@ -385,25 +385,48 @@ def test_the_comparison_can_fail(plain, params):
     assert spacings_apart(got, ref_logits(params, seq)) > 1e3
 
 
-def test_gpt2_session_executables_are_unchanged():
-    """The GPT-2 call infers as before: the same executables over the same
-    arguments, two pools and no counters."""
+def _gpt2_session():
     cfg = serve.ModelConfig(vocab_size=61, num_layers=2, d_model=32,
                             num_heads=2, max_len=64)
-    assert cfg.block == "gpt2" and cfg.latent_dim == 0
-    params = serve_model.init_params(cfg, seed=3)
+    assert cfg.block == "gpt2"
     sess = serve.InferenceSession(
-        params, num_heads=2, config=serve.ServeConfig(
-            slots=3, page_size=PAGE, buckets=(8, 16), max_new=8))
-    assert sorted(sess.executables) == ["decode", "prefill_16", "prefill_8"]
-    assert sess.moe_report() is None and sess._compiler_options() is None
-    sds, i32 = jax.ShapeDtypeStruct, jnp.int32
-    pool = sds((2, 3 * 3 + 1, PAGE, 2, 16), jnp.float32)
-    param_avals = jax.tree.map(lambda v: sds(v.shape, v.dtype), sess.params)
-    assert sess._exes["decode"].aval_sig == signature_of(
-        (param_avals, sds((3,), i32), sds((3,), i32), sds((3, 3), i32),
-         pool, pool))
-    assert sess._exes["prefill_16"].aval_sig == signature_of(
-        (param_avals, sds((1, 16), i32), sds((), i32), sds((), i32),
-         sds((3,), i32), pool, pool))
+        serve_model.init_params(cfg, seed=3), num_heads=2,
+        config=serve.ServeConfig(slots=3, page_size=PAGE, buckets=(8, 16),
+                                 max_new=8))
+    assert sess.moe_report() is None and sess.counters == {}
     assert sess.cache.pool_bytes() == 2 * 2 * 10 * PAGE * 2 * 16 * 4
+    pool = jax.ShapeDtypeStruct((2, 3 * 3 + 1, PAGE, 2, 16), jnp.float32)
+    return sess, 3, ["decode", "prefill_16", "prefill_8"], (pool, pool)
+
+
+def _latent_session(params):
+    sess = session(params)
+    f32, i32 = jnp.float32, jnp.int32
+    return sess, 6, ["decode", "prefill_16", "prefill_32"], (
+        jax.ShapeDtypeStruct((3, 3 * 6 + 1, PAGE, ROW), f32),
+        jax.ShapeDtypeStruct((2, 5 + 2 * 8), i32))
+
+
+@pytest.mark.parametrize("block", ["gpt2", "deepseek_v3"])
+def test_flattened_executable_inputs_are_pinned(block, params):
+    """What an executable is compiled over, leaf by leaf in the order the
+    device sees them: the parameters, the step's tokens, lengths and
+    tables, then the cache's pools and the block's counters, and nothing
+    else.  The GPT-2 call infers as before (two pools, no counters); the
+    latent call has its one pool and its routers' counts.  How the
+    session groups them into arguments is free to change; this is not."""
+    sess, width, names, state = (
+        _gpt2_session() if block == "gpt2" else _latent_session(params))
+    assert sorted(sess.executables) == names
+    sds, i32 = jax.ShapeDtypeStruct, jnp.int32
+    param_avals = jax.tree.map(lambda v: sds(v.shape, v.dtype), sess.params)
+
+    def leaves(sig):
+        return [leaf for _, leaf in sig]
+
+    assert leaves(sess._exes["decode"].aval_sig) == leaves(signature_of(
+        (param_avals, sds((3,), i32), sds((3,), i32), sds((3, width), i32))
+        + state))
+    assert leaves(sess._exes["prefill_16"].aval_sig) == leaves(signature_of(
+        (param_avals, sds((1, 16), i32), sds((), i32), sds((), i32),
+         sds((width,), i32)) + state))

@@ -238,22 +238,22 @@ def test_cow_divergence_never_mutates_shared_page(prefix_session):
     first_b, _ = sess.prefill(sb, pb)
     page = int(sess.cache._tables[sa, 0])
     assert int(sess.cache._tables[sb, 0]) == page  # genuinely shared
-    before_k = np.asarray(sess.cache.k_pool[:, page])
-    before_v = np.asarray(sess.cache.v_pool[:, page])
+    before_k = np.asarray(sess.cache.pools["k_pool"][:, page])
+    before_v = np.asarray(sess.cache.pools["v_pool"][:, page])
     copied = sess.cache.ensure_writable(sb, 0, 1)
     assert copied == 1
     new_page = int(sess.cache._tables[sb, 0])
     assert new_page != page
     assert int(sess.cache._tables[sa, 0]) == page  # holder unaffected
     np.testing.assert_array_equal(
-        np.asarray(sess.cache.k_pool[:, page]), before_k)
+        np.asarray(sess.cache.pools["k_pool"][:, page]), before_k)
     np.testing.assert_array_equal(
-        np.asarray(sess.cache.v_pool[:, page]), before_v)
+        np.asarray(sess.cache.pools["v_pool"][:, page]), before_v)
     # the private copy is bit-identical, so attention through it is too
     np.testing.assert_array_equal(
-        np.asarray(sess.cache.k_pool[:, new_page]), before_k)
+        np.asarray(sess.cache.pools["k_pool"][:, new_page]), before_k)
     np.testing.assert_array_equal(
-        np.asarray(sess.cache.v_pool[:, new_page]), before_v)
+        np.asarray(sess.cache.pools["v_pool"][:, new_page]), before_v)
     assert sess.cache.prefix_stats["cow_copies"] >= 1
     seqs = {sa: pa + [first_a], sb: pb + [first_b]}
     for _ in range(2):

@@ -107,16 +107,17 @@ def test_ring_cache_bookkeeping():
     assert (cache.n_full, cache.n_window, cache.n_ssm) == (1, 1, 1)
     assert cache.hybrid
     # pools only carry FULL layers; rings and state live beside them
-    assert cache.k_pool.shape[0] == 1
-    assert cache.kw_pool.shape == (1, 2, 24, 2, 4)
-    assert cache.ssm_state.shape == (1, 2, 2, 4, 4)
-    assert cache.pool_bytes() > 2 * cache.k_pool.nbytes
+    pools = cache.pools
+    assert pools["k_pool"].shape[0] == 1
+    assert pools["kw_pool"].shape == (1, 2, 24, 2, 4)
+    assert pools["ssm_state"].shape == (1, 2, 2, 4, 4)
+    assert cache.pool_bytes() > 2 * pools["k_pool"].nbytes
     # alloc re-zeroes the slot's recurrence state (rings need no zeroing:
     # stale rows carry out-of-window position labels and mask out)
     import jax.numpy as jnp
-    cache.ssm_state = jnp.ones_like(cache.ssm_state)
+    pools["ssm_state"] = jnp.ones_like(pools["ssm_state"])
     slot = cache.alloc(5, 8)
-    assert float(jnp.abs(cache.ssm_state[:, slot]).max()) == 0.0
+    assert float(jnp.abs(pools["ssm_state"][:, slot]).max()) == 0.0
 
     # a stack with NO full layers needs no pages at all: admission is
     # bounded by slots alone (the O(1)-per-slot capacity story)
@@ -168,9 +169,9 @@ def _stale_ring_row(sess, slots):
 
     cache = sess.cache
     row = (int(cache.lengths[slots[0]]) - 1) % cache.ring_tokens
-    ring = np.array(cache.kw_pool)
+    ring = np.array(cache.pools["kw_pool"])
     ring[:, slots[0], row] = ring[:, slots[0], row - 1]
-    cache.kw_pool = jnp.asarray(ring, cache.kw_pool.dtype)
+    cache.pools["kw_pool"] = jnp.asarray(ring, ring.dtype)
 
 
 @pytest.mark.parametrize("kv_quant", ["", "int8"])

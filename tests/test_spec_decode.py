@@ -200,10 +200,16 @@ def _verify_vs_serial_decode(pool_dtype, position_off=0):
                   cfg.head_dim)
     tables = jnp.asarray([[0, 1, 2, pages], [3, 4, 5, pages]], jnp.int32)
 
-    decode = jax.jit(lambda p, t, l, kp, vp: serve_model.decode_step(
-        p, t, l, tables, kp, vp, cfg, page, exact=True))
-    verify = jax.jit(lambda p, t, l, kp, vp: serve_model.verify_step(
-        p, t, l, tables, kp, vp, cfg, page, exact=True))
+    def on_pools(step):
+        def run(p, t, l, kp, vp):
+            toks, logits, pools, _ = step(
+                p, t, l, tables, {"k_pool": kp, "v_pool": vp}, {}, cfg,
+                page, exact=True)
+            return toks, logits, pools["k_pool"], pools["v_pool"]
+        return jax.jit(run)
+
+    decode = on_pools(serve_model.decode_step)
+    verify = on_pools(serve_model.verify_step)
 
     rs = np.random.RandomState(11)
     k_pool = jnp.zeros(pool_shape, dtype)
