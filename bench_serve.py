@@ -230,10 +230,12 @@ def measure(argv=None):
     probe = list(np.random.RandomState(1).randint(1, 127, size=9))
     slot = sess.try_alloc(len(probe), 8)
     first, last_logits = sess.prefill(slot, probe)
+    last_logits = np.asarray(last_logits)
     np.testing.assert_array_equal(last_logits, ref_row(probe))
     seq = list(probe) + [first]
     for _ in range(7):
         toks, logits = sess.step()
+        logits = np.asarray(logits)
         np.testing.assert_array_equal(logits[slot], ref_row(seq))
         seq.append(toks[slot])
     sess.release(slot)
@@ -392,6 +394,7 @@ def measure(argv=None):
     # match the jitted reference forward over its own quantized tree
     qslot = qsess.try_alloc(len(probe), 8)
     qfirst, qlogits = qsess.prefill(qslot, probe)
+    qlogits = np.asarray(qlogits)
     np.testing.assert_array_equal(
         qlogits, np.asarray(serve_model.reference_last_logits(
             qsess.params, probe, cfg, sconf.page_size, exact=True)))
@@ -404,12 +407,16 @@ def measure(argv=None):
     bslot = sess.try_alloc(len(probe), 8)
     qslot = qsess.try_alloc(len(probe), 8)
     bfirst, blog = sess.prefill(bslot, probe)
+    blog = np.asarray(blog)
     _, qlog = qsess.prefill(qslot, probe)
+    qlog = np.asarray(qlog)
     drift = max(drift, float(np.max(np.abs(qlog - blog))))
     for _ in range(6):
         qsess._slot_tokens[qslot] = sess._slot_tokens[bslot]
         btoks, blogs = sess.step()
+        blogs = np.asarray(blogs)
         qtoks, qlogs = qsess.step()
+        qlogs = np.asarray(qlogs)
         drift = max(drift, float(np.max(np.abs(qlogs[qslot]
                                                - blogs[bslot]))))
     sess.release(bslot)
@@ -482,6 +489,7 @@ def measure(argv=None):
     # must match the jitted reference forward at the SAME kv precision
     kslot = kvsess.try_alloc(len(probe), 8)
     kfirst, klogits = kvsess.prefill(kslot, probe)
+    klogits = np.asarray(klogits)
     np.testing.assert_array_equal(
         klogits, np.asarray(serve_model.reference_last_logits(
             kvsess.params, probe, cfg, sconf.page_size, exact=True,
@@ -489,6 +497,7 @@ def measure(argv=None):
     kseq = list(probe) + [kfirst]
     for _ in range(4):
         ktoks, klogs = kvsess.step()
+        klogs = np.asarray(klogs)
         np.testing.assert_array_equal(
             klogs[kslot], np.asarray(serve_model.reference_last_logits(
                 kvsess.params, kseq, cfg, sconf.page_size, exact=True,
@@ -503,12 +512,16 @@ def measure(argv=None):
     bslot = sess.try_alloc(len(probe), 8)
     kslot = kvsess.try_alloc(len(probe), 8)
     _, blog = sess.prefill(bslot, probe)
+    blog = np.asarray(blog)
     _, klog = kvsess.prefill(kslot, probe)
+    klog = np.asarray(klog)
     kv_drift = max(kv_drift, float(np.max(np.abs(klog - blog))))
     for _ in range(6):
         kvsess._slot_tokens[kslot] = sess._slot_tokens[bslot]
         btoks, blogs = sess.step()
+        blogs = np.asarray(blogs)
         ktoks, klogs = kvsess.step()
+        klogs = np.asarray(klogs)
         kv_drift = max(kv_drift, float(np.max(np.abs(klogs[kslot]
                                                      - blogs[bslot]))))
     sess.release(bslot)

@@ -645,15 +645,15 @@ class PagedKVCache:
             self._tables_dev = jnp.asarray(self._tables.copy())
         return self._tables_dev
 
-    def device_lengths(self):
-        import jax.numpy as jnp
-
-        return jnp.asarray(self.lengths.copy())
+    def lengths_arg(self):
+        """The (slots,) int32 lengths as an executable takes them: a host
+        copy (the live array moves on while a launch may still read its
+        argument), which the launch itself uploads."""
+        return self.lengths.copy()
 
     def table_row(self, slot):
-        import jax.numpy as jnp
-
-        return jnp.asarray(self._tables[slot].copy())
+        """One slot's page-table row, likewise."""
+        return self._tables[slot].copy()
 
     # -- accounting -------------------------------------------------------
     def pool_bytes(self):

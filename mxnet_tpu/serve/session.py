@@ -315,12 +315,24 @@ class ServeConfig:
 
 
 class _Executable(object):
-    """One AOT-compiled entry point + its recompile guard."""
+    """One AOT-compiled entry point + its recompile guard.
+
+    An executable's first argument is the session's parameters (the
+    draft's, for ``draft``): some hundreds of leaves, assigned once.
+    Their part of the call signature is described when the executable is
+    built and kept here beside the tree it describes (``params``); a
+    call describes only the arguments it makes anew.
+    ``leaves_described`` counts the leaves described so far, at the
+    build and by every call since."""
 
     __slots__ = ("name", "compiled", "jitted", "guard", "aval_sig",
-                 "memory", "fallbacks")
+                 "params", "params_sig", "memory", "fallbacks",
+                 "leaves_described")
 
-    def __init__(self, name, compiled, jitted, guard, aval_sig, memory):
+    def __init__(self, name, compiled, jitted, guard, params, aval_sig,
+                 memory):
+        import jax
+
         self.name = name
         self.compiled = compiled
         self.jitted = jitted
@@ -328,6 +340,26 @@ class _Executable(object):
         self.aval_sig = aval_sig
         self.memory = memory  # dict from memory_analysis(), at compile time
         self.fallbacks = 0
+        # the parameters lead the signature, as they lead the arguments
+        self.params = params
+        self.params_sig = aval_sig[:len(jax.tree.leaves(params))]
+        self.leaves_described = len(aval_sig)
+
+    def signature(self, args):
+        """``signature_of(args)``, of which only what follows the
+        parameters is described anew.  Parameters assigned since the
+        last call are another tree: described here, once."""
+        from ..compile_cache import signature_of
+
+        if args[0] is not self.params:
+            self.params = args[0]
+            self.params_sig = signature_of((args[0],))
+            self.leaves_described += len(self.params_sig)
+        # None has no leaves, and keeps the others' paths what they are
+        # in the whole tuple
+        rest = signature_of((None,) + args[1:])
+        self.leaves_described += len(rest)
+        return self.params_sig + rest
 
 
 class InferenceSession(object):
@@ -562,8 +594,9 @@ class InferenceSession(object):
                         if "window" in self.draft_model.kinds else 0))
 
     # -- compilation ------------------------------------------------------
-    def _aot(self, name, fn, avals, donate_argnums):
-        """``TrainStep.compile``-style AOT build of one executable."""
+    def _aot(self, name, fn, params, avals, donate_argnums):
+        """``TrainStep.compile``-style AOT build of one executable over
+        ``avals``, the first of which stands for ``params``."""
         import jax
 
         from .. import compile_cache, profiler
@@ -606,7 +639,7 @@ class InferenceSession(object):
         sig = signature_of(avals)
         guard.observe(sig)
         self._exes[name] = _Executable(name, compiled, jitted, guard,
-                                       sig, memory)
+                                       params, sig, memory)
 
     def _compile_all(self):
         import jax
@@ -639,7 +672,7 @@ class InferenceSession(object):
                                      counters, **static)
 
         self._aot(
-            "decode", decode_fn,
+            "decode", decode_fn, self.params,
             (param_avals, sds((cfg.slots,), i32), sds((cfg.slots,), i32),
              sds((cfg.slots, max_pages), i32), pools, counters),
             donate_argnums=(4, 5))
@@ -655,7 +688,7 @@ class InferenceSession(object):
                     counters, slot=slot, **static)
 
             self._aot(
-                "prefill_%d" % bucket, prefill_fn,
+                "prefill_%d" % bucket, prefill_fn, self.params,
                 (param_avals, sds((1, bucket), i32), sds((), i32),
                  sds((), i32), sds((max_pages,), i32), pools, counters,
                  slot),
@@ -675,7 +708,7 @@ class InferenceSession(object):
                                          **static)
 
             self._aot(
-                "verify", verify_fn,
+                "verify", verify_fn, self.params,
                 (param_avals, sds((cfg.slots, w), i32),
                  sds((cfg.slots,), i32), sds((cfg.slots, max_pages), i32),
                  pools, counters, limits),
@@ -693,7 +726,7 @@ class InferenceSession(object):
                                             **dstatic)
 
             self._aot(
-                "draft", draft_fn,
+                "draft", draft_fn, self.draft_params,
                 (avals_of(self.draft_params), sds((cfg.slots, w), i32),
                  sds((cfg.slots,), i32), sds((cfg.slots,), i32),
                  sds((cfg.slots, max_pages), i32),
@@ -722,10 +755,8 @@ class InferenceSession(object):
 
     # -- dispatch ---------------------------------------------------------
     def _dispatch(self, name, args):
-        from ..compile_cache import signature_of
-
         rec = self._exes[name]
-        sig = signature_of(args)
+        sig = rec.signature(args)
         rec.guard.observe(sig)
         if sig != rec.aval_sig:
             # Shape/dtype drift from the compiled avals (reported by the
@@ -804,7 +835,9 @@ class InferenceSession(object):
 
     def prefill(self, slot, prompt_tokens):
         """Run the bucketed prefill for ``slot``; returns
-        ``(first_token, last_logits)``.
+        ``(first_token, last_logits)``: the token a host integer, the
+        ``(vocab,)`` logits the executable's output as the device array
+        it is (``np.asarray`` reads it; who does pays the transfer).
 
         Only the *uncached suffix* is computed: prompt positions covered
         by prefix-cache hit pages (``cache.cached_len``) are skipped,
@@ -815,7 +848,6 @@ class InferenceSession(object):
         prompt pages are published into the prefix index for future
         admissions."""
         import numpy as np
-        import jax.numpy as jnp
 
         prompt = np.asarray(prompt_tokens, np.int32).reshape(-1)
         p = int(prompt.shape[0])
@@ -846,13 +878,11 @@ class InferenceSession(object):
             toks = np.zeros((1, bucket), np.int32)
             toks[0, :n] = prompt[off:off + n]
             self.cache.ensure_writable(slot, off, n)
-            args = (self.params, jnp.asarray(toks),
-                    jnp.asarray(n, jnp.int32),
-                    jnp.asarray(off, jnp.int32),
+            # host arrays: the launch uploads them, no call of its own each
+            args = (self.params, toks, np.int32(n), np.int32(off),
                     self.cache.table_row(slot), self.cache.pools,
                     self.counters,
-                    jnp.asarray(slot, jnp.int32)
-                    if self.cache.hybrid else None)
+                    np.int32(slot) if self.cache.hybrid else None)
             first, last_logits, self.cache.pools, self.counters = \
                 self._dispatch("prefill_%d" % bucket, args)
             off += n
@@ -865,7 +895,7 @@ class InferenceSession(object):
         if self._draft_mode == "model":
             self._draft_ingest(slot, prompt)
             self.draft_cache.register_prefix(slot, prompt_list)
-        return first, np.asarray(last_logits)
+        return first, last_logits
 
     def _draft_ingest(self, slot, prompt):
         """Teacher-force the prompt through the draft executable in
@@ -878,7 +908,6 @@ class InferenceSession(object):
         the next draft call overwrites those exact positions before any
         validity mask admits them."""
         import numpy as np
-        import jax.numpy as jnp
 
         cfg = self.config
         w = cfg.spec_window
@@ -900,11 +929,9 @@ class InferenceSession(object):
     def _dispatch_draft(self, tokens, n_feed):
         """One draft dispatch (ingest or propose); re-adopts the draft
         cache's donated pools and returns the (slots, W) greedy tokens."""
-        import jax.numpy as jnp
-
         outs, self.draft_cache.pools, _ = self._dispatch("draft", (
-            self.draft_params, jnp.asarray(tokens), jnp.asarray(n_feed),
-            self.draft_cache.device_lengths(),
+            self.draft_params, tokens, n_feed,
+            self.draft_cache.lengths_arg(),
             self.draft_cache.device_tables(), self.draft_cache.pools, {}))
         return outs
 
@@ -912,17 +939,18 @@ class InferenceSession(object):
         """Advance every active slot one token with the single decode
         executable; returns ``(tokens, logits)`` where ``tokens`` maps
         slot -> emitted token id and ``logits`` is the (slots, vocab)
-        array (inactive rows are garbage by design)."""
+        array (inactive rows are garbage by design), left on the device:
+        the step reads the ``(slots,)`` token vector and nothing else,
+        and a caller that wants numbers converts (``np.asarray``)."""
         import numpy as np
-        import jax.numpy as jnp
 
         cfg = self.config
         self._pre_dispatch(1)
         tokens = np.zeros((cfg.slots,), np.int32)
         for slot, tok in self._slot_tokens.items():
             tokens[slot] = tok
-        args = (self.params, jnp.asarray(tokens),
-                self.cache.device_lengths(), self.cache.device_tables(),
+        args = (self.params, tokens,
+                self.cache.lengths_arg(), self.cache.device_tables(),
                 self.cache.pools, self.counters)
         # the page blocks this step's attention has to visit: those of
         # the longest context, its new row included
@@ -941,7 +969,7 @@ class InferenceSession(object):
             if slot in self._slot_history:
                 self._slot_history[slot].append(tok)
             out[slot] = tok
-        return out, np.asarray(logits)
+        return out, logits
 
     def spec_step(self, limits=None):
         """One speculative step for every active slot: draft proposes K
@@ -963,7 +991,6 @@ class InferenceSession(object):
         committed token.
         """
         import numpy as np
-        import jax.numpy as jnp
 
         cfg = self.config
         if not cfg.spec_k:
@@ -1003,10 +1030,9 @@ class InferenceSession(object):
             lim_arr = np.ones((cfg.slots,), np.int32)
             for slot, limit in lims.items():
                 lim_arr[slot] = limit
-            lim_arr = jnp.asarray(lim_arr)
         greedy, _, self.cache.pools, self.counters = self._dispatch(
-            "verify", (self.params, jnp.asarray(tokens),
-                       self.cache.device_lengths(),
+            "verify", (self.params, tokens,
+                       self.cache.lengths_arg(),
                        self.cache.device_tables(), self.cache.pools,
                        self.counters, lim_arr))
         greedy = np.asarray(greedy)
@@ -1215,8 +1241,12 @@ class InferenceSession(object):
         return dequantize_params(self.params)
 
     def guard_report(self):
-        return {name: rec.guard.snapshot() for name, rec in
-                self._exes.items()}
+        """name -> the executable's recompile guard (calls, traces,
+        signatures) and ``leaves_described``: the parameters' leaves
+        once, then a dozen at most for every call, whatever the depth."""
+        return {name: dict(rec.guard.snapshot(),
+                           leaves_described=rec.leaves_described)
+                for name, rec in self._exes.items()}
 
     def fallback_count(self):
         return sum(rec.fallbacks for rec in self._exes.values())

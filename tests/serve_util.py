@@ -54,6 +54,7 @@ def worst_gap_vs_reference(sess, prompts, steps, max_new=8, plant=None,
         slot = sess.try_alloc(len(p), max_new, tokens=p)
         assert slot is not None
         first, logits = sess.prefill(slot, p)
+        logits = np.asarray(logits)
         worst = max(worst, spacings_apart(
             logits, reference_row(sess, p, ref_params)))
         slots.append(slot)
@@ -62,6 +63,7 @@ def worst_gap_vs_reference(sess, prompts, steps, max_new=8, plant=None,
         plant(sess, slots)
     for _ in range(steps):
         toks, logits = sess.step()
+        logits = np.asarray(logits)
         for slot, seq in zip(slots, seqs):
             worst = max(worst, spacings_apart(
                 logits[slot], reference_row(sess, seq, ref_params)))

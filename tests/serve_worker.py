@@ -118,6 +118,7 @@ def main():
         slot = sess.try_alloc(len(prompt), 6)
         assert slot is not None
         first, last_logits = sess.prefill(slot, prompt)
+        last_logits = np.asarray(last_logits)
         assert_close_across_executables(
             last_logits,
             np.asarray(reference_last_logits(sess.params, prompt,
@@ -125,6 +126,7 @@ def main():
         seq = list(prompt) + [first]
         for _ in range(5):
             toks, logits = sess.step()
+            logits = np.asarray(logits)
             assert_close_across_executables(
                 logits[slot],
                 np.asarray(reference_last_logits(sess.params, seq,

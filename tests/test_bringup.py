@@ -86,11 +86,11 @@ def _fake_session(compiled):
     from mxnet_tpu.compile_cache import RecompileGuard, signature_of
     from mxnet_tpu.serve.session import InferenceSession, _Executable
 
-    args = (jnp.zeros((2, 3)),)
+    args = ({"w": jnp.ones((3,))}, jnp.zeros((2, 3)))
     sess = InferenceSession.__new__(InferenceSession)
     sess._exes = {"decode": _Executable(
-        "decode", compiled, lambda x: x + 1, RecompileGuard("t.decode"),
-        signature_of(args), {})}
+        "decode", compiled, lambda p, x: x + 1, RecompileGuard("t.decode"),
+        args[0], signature_of(args), {})}
     return sess, args
 
 
@@ -105,10 +105,10 @@ def test_dispatch_reraises_a_device_error():
 
 
 def test_dispatch_falls_back_on_signature_drift_only():
-    sess, args = _fake_session(lambda x: x)
-    assert sess._dispatch("decode", args) is args[0]
+    sess, args = _fake_session(lambda p, x: x)
+    assert sess._dispatch("decode", args) is args[1]
     assert sess.fallback_count() == 0
-    out = sess._dispatch("decode", (jnp.zeros((4, 3)),))  # drifted shape
+    out = sess._dispatch("decode", (args[0], jnp.zeros((4, 3))))  # drifted
     assert out.shape == (4, 3) and float(out[0, 0]) == 1.0
     assert sess.fallback_count() == 1
 

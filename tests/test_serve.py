@@ -187,10 +187,12 @@ def test_from_checkpoint_roundtrip(tmp_path, params):
     p = list(range(1, 8))
     slot = sess.try_alloc(len(p), 4)
     first, last_logits = sess.prefill(slot, p)
+    last_logits = np.asarray(last_logits)
     assert_close_across_executables(last_logits, _ref_row(sess, p))
     seq = list(p) + [first]
     for _ in range(3):
         toks, logits = sess.step()
+        logits = np.asarray(logits)
         assert_close_across_executables(logits[slot], _ref_row(sess, seq))
         seq.append(toks[slot])
 

@@ -165,11 +165,13 @@ def test_prefill_then_decode_through_the_latent_cache(params, exact):
         p = tokens(10 + i, n)
         slot = sess.try_alloc(n, 8, tokens=p)
         first, logits = sess.prefill(slot, p)
+        logits = np.asarray(logits)
         assert_close_across_executables(logits, ref_logits(params, p)[-1])
         seqs.append(p + [first])
         slots.append(slot)
     for _ in range(6):
         toks, logits = sess.step()
+        logits = np.asarray(logits)
         for slot, seq in zip(slots, seqs):
             assert_close_across_executables(
                 logits[slot], ref_logits(params, seq)[-1])
@@ -185,9 +187,11 @@ def test_chunked_prefill_matches_one_piece(params, plain):
     seq = tokens(21, 45)                         # 32 + 13: two chunks
     slot = plain.try_alloc(len(seq), 3, tokens=seq, resume=True)
     _, chunked = plain.prefill(slot, seq)
+    chunked = np.asarray(chunked)
     whole = session(params, buckets=(48,), max_new=16)
     wslot = whole.try_alloc(len(seq), 3, tokens=seq)
     _, one_piece = whole.prefill(wslot, seq)
+    one_piece = np.asarray(one_piece)
     assert_close_across_executables(chunked, one_piece)
     assert_close_across_executables(chunked, ref_logits(params, seq)[-1])
     assert plain.moe_report()["prefill_chunks"] \
@@ -201,10 +205,12 @@ def test_absorbed_decode_matches_materialised_prefill(plain, params):
     seq = tokens(22, 20)
     a = plain.try_alloc(len(seq), 4, tokens=seq)
     _, materialised = plain.prefill(a, seq)
+    materialised = np.asarray(materialised)
     b = plain.try_alloc(len(seq) - 1, 4, tokens=seq[:-1])
     plain.prefill(b, seq[:-1])
     plain._slot_tokens[b] = seq[-1]
     _, logits = plain.step()
+    logits = np.asarray(logits)
     assert_close_across_executables(logits[b], materialised)
 
 
@@ -219,10 +225,12 @@ def test_prefix_hit_on_latent_pages_gives_cold_logits(prefix, params):
     assert prefix.cache.cached_len(sb) == 2 * PAGE
     assert int(prefix.cache._tables[sb, 0]) == int(prefix.cache._tables[sa, 0])
     first_b, logits_b = prefix.prefill(sb, pb)
+    logits_b = np.asarray(logits_b)
     assert_close_across_executables(logits_b, ref_logits(params, pb)[-1])
     seqs = {sa: pa + [first_a], sb: pb + [first_b]}
     for _ in range(3):
         toks, logits = prefix.step()
+        logits = np.asarray(logits)
         for slot, seq in seqs.items():
             assert_close_across_executables(
                 logits[slot], ref_logits(params, seq)[-1])
@@ -282,8 +290,10 @@ def test_skewed_routing_is_dropless(top_k, hot):
             slots=2, page_size=PAGE, buckets=(32,), max_new=8, exact=False))
     slot = sess.try_alloc(len(seq), 4, tokens=seq)
     first, logits = sess.prefill(slot, seq)
+    logits = np.asarray(logits)
     assert_close_across_executables(logits, ref_logits(params, seq, hf)[-1])
     _, logits = sess.step()
+    logits = np.asarray(logits)
     assert_close_across_executables(
         logits[slot], ref_logits(params, seq + [first], hf)[-1])
     report = sess.moe_report()
@@ -363,6 +373,7 @@ def test_weight_only_int8_serves_the_block(params):
     seq = tokens(42, 20)
     slot = sess.try_alloc(len(seq), 4, tokens=seq)
     _, logits = sess.prefill(slot, seq)
+    logits = np.asarray(logits)
     gap = spacings_apart(logits, ref_logits(params, seq)[-1])
     assert 1e3 < gap < 1e6
 
@@ -375,6 +386,7 @@ def test_the_comparison_can_fail(plain, params):
     first, _ = plain.prefill(slot, seq)
     plain.cache.lengths[slot] -= 1            # decode at the wrong position
     _, logits = plain.step()
+    logits = np.asarray(logits)
     want = ref_logits(params, seq + [first])[-1]
     assert spacings_apart(logits[slot], want) > 1e3
     starved = dict(params)

@@ -200,10 +200,12 @@ def test_prefix_hit_bitexact_vs_cold_miss(prefix_session):
     p_hit = shared + [2, 9, 14]
     s_cold = sess.try_alloc(len(p_cold), 6, tokens=p_cold)
     first_c, logits_c = sess.prefill(s_cold, p_cold)
+    logits_c = np.asarray(logits_c)
     assert sess.cache.cached_len(s_cold) == 0
     s_hit = sess.try_alloc(len(p_hit), 6, tokens=p_hit)
     assert sess.cache.cached_len(s_hit) == PAGE  # mapped, not recomputed
     first_h, logits_h = sess.prefill(s_hit, p_hit)
+    logits_h = np.asarray(logits_h)
     for seq, logits in ((p_cold, logits_c), (p_hit, logits_h)):
         ref = np.asarray(serve_model.reference_last_logits(
             sess.params, seq, CFG, PAGE, exact=True))
@@ -215,6 +217,7 @@ def test_prefix_hit_bitexact_vs_cold_miss(prefix_session):
     seqs = {s_cold: p_cold + [first_c], s_hit: p_hit + [first_h]}
     for _ in range(3):
         toks, logits = sess.step()
+        logits = np.asarray(logits)
         for slot, seq in seqs.items():
             ref = np.asarray(serve_model.reference_last_logits(
                 sess.params, seq, CFG, PAGE, exact=True))
@@ -258,6 +261,7 @@ def test_cow_divergence_never_mutates_shared_page(prefix_session):
     seqs = {sa: pa + [first_a], sb: pb + [first_b]}
     for _ in range(2):
         toks, logits = sess.step()
+        logits = np.asarray(logits)
         for slot, seq in seqs.items():
             ref = np.asarray(serve_model.reference_last_logits(
                 sess.params, seq, CFG, PAGE, exact=True))
@@ -406,6 +410,7 @@ def test_chaos_evict_fault_isolates_victim(oversub_session, monkeypatch):
     slot = sess.try_alloc(len(probe), 2, tokens=probe)
     assert sess.cache.cached_len(slot) == PAGE
     _, logits = sess.prefill(slot, probe)
+    logits = np.asarray(logits)
     ref = np.asarray(serve_model.reference_last_logits(
         sess.params, probe, CFG, PAGE, exact=True))
     assert_close_across_executables(logits, ref)
