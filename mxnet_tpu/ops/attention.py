@@ -499,7 +499,8 @@ _PAGED_KEYS_PER_ITERATION = 128
 
 
 def paged_decode_attention(q, k_pool, v_pool, layer, tables, lengths,
-                           page_size, mi=False, k_scale=None, v_scale=None):
+                           page_size, mi=False, k_scale=None, v_scale=None,
+                           scale=None):
     """The decode step's attention, reading KV pages where they lie.
 
     :func:`decode_attention` over ``pool[layer][tables]`` without the
@@ -513,20 +514,24 @@ def paged_decode_attention(q, k_pool, v_pool, layer, tables, lengths,
     bit, ``mi`` or not.  Its cost follows the longest live context, not
     the table's capacity.
 
-    q: (S, H, 1, D), one query row a slot; k_pool/v_pool:
+    q: (S, H, R, D): one query row a slot and head (R = 1), or the R
+    query heads that share key/value head H as its rows (grouped-query
+    attention; they share the slot's length too); k_pool/v_pool:
     (L, pages + 1, page_size, H, D) as ``PagedKVCache`` lays them out;
     ``layer`` the pool's layer to read; tables: (S, max_pages) int32 (rows
     past a slot's reservation name the trash page, which is in bounds);
     lengths: (S,) int, valid rows INCLUDING the current token, which the
     caller has appended; ``k_scale``/``v_scale``: (L, pages + 1, page_size)
     float32 scale pools of quantized pages, dequantized per page inside
-    the loop.  Traced bound, so not differentiable: decode never is.
+    the loop; ``scale`` multiplies the scores (1 / sqrt(D) by default).
+    Traced bound, so not differentiable: decode never is.
     """
     s, max_pages = tables.shape
-    if q.shape[-2] != 1 or lengths.ndim != 1:
+    if q.shape[1] != k_pool.shape[-2] or lengths.ndim != 1:
         raise MXNetError(
-            "paged_decode_attention takes one query row and one length a "
-            "slot, got q %r, lengths %r" % (q.shape, lengths.shape))
+            "paged_decode_attention takes the pool's %d heads and one "
+            "length a slot, got q %r, lengths %r"
+            % (k_pool.shape[-2], q.shape, lengths.shape))
     group = max(1, min(_PAGED_KEYS_PER_ITERATION // page_size, max_pages))
     # columns that complete the last group lie past every horizon
     # (position >= max_pages * page_size >= lengths): any page in bounds
@@ -534,7 +539,9 @@ def paged_decode_attention(q, k_pool, v_pool, layer, tables, lengths,
     if pad:
         tables = jnp.concatenate(
             [tables, jnp.broadcast_to(tables[:, -1:], (s, pad))], axis=1)
-    q32 = q.astype(jnp.float32) * (1.0 / (q.shape[-1] ** 0.5))
+    if scale is None:
+        scale = 1.0 / (q.shape[-1] ** 0.5)
+    q32 = q.astype(jnp.float32) * scale
     valid_len = lengths.reshape(lengths.shape + (1,) * (q.ndim - 1))
     live_pages = jnp.clip((jnp.max(lengths) + page_size - 1) // page_size,
                           0, max_pages)

@@ -20,6 +20,21 @@ per-head keys and values: ONE pool and no other
 Pages, tables, reference counts, the prefix index, oversubscription and
 copy-on-write do not know the difference.
 
+**Slot-private recurrent state** (``state``): what a layer keeps a slot
+besides pages, as the block's ``state_shapes(cfg)`` names it: name ->
+(layers, one slot's shape a layer, dtype), built here as
+
+    <name> : (layers, slots) + shape
+
+(the Mamba-2 block's ``ssm_state`` of (heads, head width, state size) and
+``conv_state`` of (taps - 1, channels); the GPT-2 block's ``"ssm"`` kind's
+(H, D, D) ``ssm_state``).  No page indexes it: a slot's rows are zeroed
+at :meth:`alloc`, a request's chunks carry them from one prefill dispatch
+to the next, and a cache that has any is ``hybrid``: the prefix index is
+off and prefill takes the slot.  Only ``"full"`` layers own pages, so the
+page pools' layer axis is their count and their head axis the key/value
+head count.
+
 **One owner.**  Every device array the cache holds lives in
 :attr:`PagedKVCache.pools`, one mapping from name to array that is built
 once in ``__init__`` and holds only what this cache has.  The serve
@@ -76,6 +91,7 @@ O(log n) per op).
 """
 from __future__ import annotations
 
+import functools
 import hashlib
 import heapq
 from collections import OrderedDict
@@ -96,13 +112,26 @@ def _chain_key(prev_key, page_tokens):
     return h.digest()
 
 
+@functools.lru_cache(maxsize=None)
+def _zero_slot():
+    """-> jitted f(pools, slot) = pools with axis 1's row ``slot`` of
+    every pool zeroed; ``pools`` is donated."""
+    import jax
+
+    return jax.jit(
+        lambda pools, slot: {name: pool.at[:, slot].set(0)
+                             for name, pool in pools.items()},
+        donate_argnums=0)
+
+
 class PagedKVCache:
     """Fixed-pool paged KV cache for ``slots`` concurrent requests."""
 
     def __init__(self, num_layers, num_heads, head_dim, page_size,
                  num_pages, slots, max_pages_per_slot, dtype=None,
                  table_pad=0, prefix_pages=0, kv_quant="",
-                 layer_kinds=(), window=0, ring_pages=0, latent_dim=0):
+                 layer_kinds=(), window=0, ring_pages=0, latent_dim=0,
+                 state=None):
         import jax.numpy as jnp
         import numpy as np
 
@@ -132,9 +161,9 @@ class PagedKVCache:
         # ring of ``ring_pages`` pages per slot (``kw_pool``/``vw_pool``,
         # slot-indexed: ring append overwrites the oldest page's rows in
         # place, and the attention mask saturates visibility at the
-        # window).  SSM layers get one (H, D, D) fp32 recurrence state
-        # per slot (``ssm_state``), the per-layer state pool beside the
-        # KV pools.
+        # window).  SSM layers own neither: what they keep a slot is in
+        # ``state`` (below), the per-layer state pools beside the KV
+        # pools.
         self.layer_kinds = tuple(layer_kinds) or ("full",) * self.num_layers
         if len(self.layer_kinds) != self.num_layers:
             raise MXNetError(
@@ -155,6 +184,7 @@ class PagedKVCache:
                 "ring_pages >= 1 (got window=%d, ring_pages=%d)"
                 % (self.window, self.ring_pages))
         self.ring_tokens = self.ring_pages * self.page_size
+        self.state = tuple(state or ())   # names of the state pools
         # extra always-trash table columns past the reservable range, so
         # executables that clip a past-the-reservation write position
         # (the speculative verify's overflow rows) land on the trash
@@ -209,13 +239,11 @@ class PagedKVCache:
                                                   jnp.float32)
                 self.pools["vw_scale"] = jnp.ones(ring_shape[:3],
                                                   jnp.float32)
-        # SSM state pool: fp32 regardless of kv_quant — the state is a
-        # running accumulator, not content-addressed KV rows; quantizing
-        # it would break the chunked-prefill == serial-decode contract
-        if self.n_ssm:
-            self.pools["ssm_state"] = jnp.zeros(
-                (self.n_ssm, self.slots, self.num_heads, self.head_dim,
-                 self.head_dim), jnp.float32)
+        # slot-private recurrent state, as the block names it: built
+        # zero, a slot's rows zeroed again at every alloc
+        for name, (layers, shape, state_dtype) in (state or {}).items():
+            self.pools[name] = jnp.zeros(
+                (int(layers), self.slots) + tuple(shape), state_dtype)
         # min-heaps: heappop yields the lowest free id, preserving the
         # deterministic lowest-first reuse contract (a sorted range is
         # already a valid heap)
@@ -264,8 +292,9 @@ class PagedKVCache:
 
     @property
     def hybrid(self):
-        """True when the stack holds any windowed or SSM layer."""
-        return bool(self.n_window or self.n_ssm)
+        """True when a slot owns more than pages: a windowed layer's
+        ring, or recurrent state of any kind."""
+        return bool(self.n_window or self.state)
 
     def pages_needed(self, prompt_len, max_new):
         """Worst-case page reservation for one request.  Pool pages hold
@@ -454,13 +483,12 @@ class PagedKVCache:
         # page
         self.lengths[slot] = self._cached_len[slot]
         self._tables_dev = None
-        # SSM recurrence starts from a zero state at offset 0; ring
-        # rows need no scrub — the position labels the windowed gather
+        # a recurrence starts from zero state at offset 0; ring rows
+        # need no scrub — the position labels the windowed gather
         # computes for a fresh request exclude every row the request has
         # not itself written (stale rows label as position < 0)
-        if "ssm_state" in self.pools:
-            self.pools["ssm_state"] = \
-                self.pools["ssm_state"].at[:, slot].set(0.0)
+        if self.state:
+            self._scrub_state(slot)
         if tokens is not None and self.prefix_pages:
             self.prefix_stats["lookups"] += 1
             if hit:
@@ -469,6 +497,17 @@ class PagedKVCache:
                 self.prefix_stats["hit_tokens"] += \
                     len(hit) * self.page_size
         return slot
+
+    def _scrub_state(self, slot):
+        """Zero ``slot``'s rows of every state pool, in place: the state
+        pools go to one jitted call donated and the slot as an argument,
+        so it compiles once a set of shapes and writes one slot's rows,
+        not a copy of the pools."""
+        import numpy as np
+
+        self.pools.update(_zero_slot()(
+            {name: self.pools[name] for name in self.state},
+            np.int32(slot)))
 
     def append_pages(self, slot, new_len):
         """Grow the slot's mapped pages to cover ``new_len`` token

@@ -53,7 +53,7 @@ from __future__ import annotations
 
 from ..base import MXNetError
 from ..ops.attention import decode_attention
-from .model import _mm, _resolve_params
+from .model import _mm, _resolve_params, check_param_shapes
 
 BLOCK = "deepseek_v3"
 
@@ -157,21 +157,17 @@ def init_params(cfg, seed=0, scale=0.02):
 
 def check_params(params, cfg):
     """The parameter dict has exactly the architecture's shapes."""
-    def _shape(v):
-        return tuple(v["q"].shape if isinstance(v, dict) else v.shape)
-
-    for name, shape in param_shapes(cfg).items():
-        if name not in params:
-            raise MXNetError("ModelConfig(block=%r) needs parameter %s %r"
-                             % (BLOCK, name, shape))
-        if _shape(params[name]) != tuple(shape):
-            raise MXNetError("parameter %s is %r, the architecture says %r"
-                             % (name, _shape(params[name]), tuple(shape)))
+    check_param_shapes(params, param_shapes(cfg), BLOCK)
 
 
 def latent_dim(cfg):
     """Values the cache holds a token a layer, in ONE latent pool."""
     return cfg.kv_lora_rank + cfg.qk_rope_head_dim
+
+
+def state_shapes(cfg):
+    """Slot-private recurrent state beside the pages: none."""
+    return {}
 
 
 def init_counters(cfg):
@@ -232,7 +228,7 @@ def _fold(stats, inc):
 
 def report(counters, cfg):
     """Host side: ``moe_stats`` as exact Python ints under their names
-    (``InferenceSession.moe_report`` documents them)."""
+    (``InferenceSession.block_report`` documents them)."""
     import numpy as np
 
     counts = [int(lo) + (int(hi) << _LO_BITS)

@@ -51,7 +51,8 @@ __all__ = ["ModelConfig", "BLOCKS", "block_of", "exact_mode", "init_params",
 # block.  Every such module has the same surface (docs/serving.md, "Adding
 # a block", names it; tests/test_serve_blocks.py holds the modules to
 # it).  This module is the GPT-2 block.
-BLOCKS = {"gpt2": "model", "deepseek_v3": "latent_moe"}
+BLOCKS = {"gpt2": "model", "deepseek_v3": "latent_moe",
+          "granitemoehybrid": "granite_hybrid"}
 
 
 def block_of(cfg):
@@ -93,7 +94,11 @@ class ModelConfig:
     table) cannot be inferred from shapes, so it is stated: the fields
     after ``block`` are its head split, its latent rank, its norms and
     RoPE, and its expert layer, under the names of the published
-    ``config.json`` where the repo had none.
+    ``config.json`` where the repo had none.  ``"granitemoehybrid"``
+    (``granite_hybrid.py``: Mamba-2 and grouped-query attention layers in
+    the published ``layer_types`` order, no positions, a shared SwiGLU of
+    ``d_ff``, four multipliers, a tied head) is stated the same way, by
+    the last group of fields.
     """
     vocab_size: int
     num_layers: int
@@ -118,14 +123,36 @@ class ModelConfig:
     n_shared_experts: int = 0   # one SwiGLU of n_shared * moe_d_ff
     routed_scaling_factor: float = 1.0
     norm_topk_prob: bool = True
+    num_key_value_heads: int = 0    # 0: as many as query heads
+    layer_types: tuple = ()     # per layer "mamba" | "attention"
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_n_groups: int = 1     # heads that share one B and one C
+    mamba_d_conv: int = 4       # taps of the causal depthwise convolution
+    mamba_chunk_size: int = 256     # rows a chunk of the prefill scan
+    embedding_multiplier: float = 1.0
+    attention_multiplier: float = 0.0   # the score scale, stated
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    tie_word_embeddings: bool = False
 
     @property
     def head_dim(self):
         return self.d_model // self.num_heads
 
     @property
+    def kv_heads(self):
+        return self.num_key_value_heads or self.num_heads
+
+    @property
     def kinds(self):
-        """Per-layer kinds, expanded to ``num_layers`` entries."""
+        """Per-layer kinds in the cache's words, ``num_layers`` entries:
+        ``"full"`` owns pages, ``"window"`` a ring, ``"ssm"`` nothing but
+        the slot-private state its block's ``state_shapes`` names."""
+        if self.layer_types:
+            return tuple({"attention": "full", "mamba": "ssm"}.get(t, t)
+                         for t in self.layer_types)
         return self.layer_kinds or ("full",) * self.num_layers
 
     @property
@@ -169,10 +196,38 @@ def check_params(params, cfg):
                                           getattr(cfg, name)))
 
 
+def check_param_shapes(params, shapes, block):
+    """``params`` has exactly ``shapes`` ({name: shape}; a quantized
+    entry keeps the canonical shape on its codes): what a block whose
+    architecture is stated, not inferred, asks of its parameter dict."""
+    def _shape(v):
+        return tuple(v["q"].shape if isinstance(v, dict) else v.shape)
+
+    for name, shape in shapes.items():
+        if name not in params:
+            raise MXNetError("ModelConfig(block=%r) needs parameter %s %r"
+                             % (block, name, shape))
+        if _shape(params[name]) != tuple(shape):
+            raise MXNetError("parameter %s is %r, the architecture says %r"
+                             % (name, _shape(params[name]), tuple(shape)))
+
+
 def latent_dim(cfg):
     """Values the cache holds a token a layer in ONE latent pool; 0 for
     this block's per-head K and V pools."""
     return 0
+
+
+def state_shapes(cfg):
+    """Slot-private recurrent state beside the pages, as the cache
+    builds it: name -> (layers, one slot's shape a layer, dtype).  The
+    ``"ssm"`` kind's (H, D, D) float32 recurrence state, fp32 whatever
+    ``kv_quant`` says: it is a running accumulator, not KV rows."""
+    n_ssm = cfg.kinds.count("ssm")
+    if not n_ssm:
+        return {}
+    return {"ssm_state": (n_ssm, (cfg.num_heads, cfg.head_dim,
+                                  cfg.head_dim), "float32")}
 
 
 def init_counters(cfg):
@@ -186,7 +241,8 @@ def compiler_options(backend):
 
 
 def report(counters, cfg):
-    """``InferenceSession.moe_report()``: this block has no routers."""
+    """``InferenceSession.block_report()``: this block counts nothing
+    on the device."""
     return None
 
 

@@ -109,8 +109,12 @@ shaped :class:`ModelConfig` from the parameter dict and ``num_heads``.
 An architecture that shapes cannot tell (``ModelConfig(block=
 "deepseek_v3", ...)``: latent attention over one latent page pool,
 routed and shared experts, ``serve/latent_moe.py``) is passed as
-``model=`` and used as given.  It is the model's, not the deployment's:
-no ``ServeConfig`` field and no environment variable names it.  The
+``model=`` and used as given; so is ``ModelConfig(block=
+"granitemoehybrid", ...)`` (``serve/granite_hybrid.py``: Mamba-2 layers,
+whose slot-private state and convolution context the cache keeps beside
+the pages of the few grouped-query attention layers).  It is the
+model's, not the deployment's: no ``ServeConfig`` field and no
+environment variable names it.  The
 session looks the block's module up once (``model.BLOCKS``,
 ``self.block``) and asks it for everything that depends on the
 architecture: what the cache must hold, the step functions, what it
@@ -122,11 +126,14 @@ the parameters, the step's own arrays, then ``pools`` (the cache's
 device state, :attr:`PagedKVCache.pools`: one name -> array mapping, one
 pytree argument, donated) and ``counters`` (the block's own device
 state, likewise: the latent block's router counts, read by
-:meth:`InferenceSession.moe_report`; empty for GPT-2).  After a dispatch
+:meth:`InferenceSession.block_report`; empty for GPT-2).  After a dispatch
 the session stores the two mappings that came back, and knows no pool by
-name.  Not supported for the latent block yet, and refused at
+name: a block with recurrent state names it in ``state_shapes(cfg)``,
+the cache builds it, and ``alloc`` zeroes a slot's rows.  Not supported
+for the latent block and the Mamba-2 block yet, and refused at
 construction: ``spec_k``, ``kv_quant``, ``layers`` / ``window``.
-Weight-only ``quant``, ``prefix_pages`` and ``oversub`` work.
+Weight-only ``quant`` and ``oversub`` work for both, ``prefix_pages`` for
+the latent block (a cache with recurrent state keeps no prefix index).
 
 Env knobs (see docs/env_vars.md): ``MXNET_SERVE_SLOTS``,
 ``MXNET_SERVE_PAGE``, ``MXNET_SERVE_BUCKETS``, ``MXNET_SERVE_MAX_NEW``,
@@ -432,7 +439,7 @@ class InferenceSession(object):
                    cfg.max_new, self.model.max_len))
         self.cache = PagedKVCache(
             num_layers=self.model.num_layers,
-            num_heads=self.model.num_heads,
+            num_heads=self.model.kv_heads,
             head_dim=self.model.head_dim,
             page_size=cfg.page_size,
             num_pages=cfg.pool_pages,
@@ -441,10 +448,11 @@ class InferenceSession(object):
             table_pad=cfg.spec_pad_pages,
             prefix_pages=cfg.prefix_pages,
             kv_quant=cfg.kv_quant,
-            layer_kinds=self.model.layer_kinds,
+            layer_kinds=self.model.kinds,
             window=self.model.window,
             ring_pages=cfg.ring_pages if "window" in kinds else 0,
-            latent_dim=self.block.latent_dim(self.model))
+            latent_dim=self.block.latent_dim(self.model),
+            state=self.block.state_shapes(self.model))
         # the block's own device state, taken and returned by every
         # executable beside the cache's pools
         self.counters = self.block.init_counters(self.model)
@@ -460,7 +468,12 @@ class InferenceSession(object):
             # scale records that every executable dequantizes in-graph
             from .. import quantize as _quant
 
-            self.params = _quant.quantize_params(self.params, cfg.quant)
+            # leaf by leaf, in place: a float32 leaf that nobody else
+            # holds goes when its codes are there, so quantizing never
+            # needs room for both copies of the whole model
+            for name in list(self.params):
+                self.params.update(_quant.quantize_params(
+                    {name: self.params.pop(name)}, cfg.quant))
             if self.draft_params is not None:
                 self.draft_params = _quant.quantize_params(
                     self.draft_params, cfg.quant)
@@ -1109,20 +1122,31 @@ class InferenceSession(object):
         return self.block.decode_report(self._decode_stats,
                                         self.cache.table_width)
 
-    def moe_report(self):
-        """What the latent block's routers did since the session was
-        built, counted on the device by the executables and copied to the
-        host only here (one small array; no step pays for it).
-        ``assignments_asked`` = real tokens x experts per token over
-        every expert layer of every prefill chunk and decode step (a
-        decode step routes every slot's row, idle slots too);
-        ``assignments_computed`` = those whose tile the expert loop
+    def block_report(self):
+        """What the block's executables counted on the device since the
+        session was built (its ``report(counters, cfg)``), copied to the
+        host only here (one small array; no step pays for it).  ``None``
+        for a block that counts nothing (GPT-2).
+
+        The latent block: ``assignments_asked`` = real tokens x experts
+        per token over every expert layer of every prefill chunk and
+        decode step (a decode step routes every slot's row, idle slots
+        too); ``assignments_computed`` = those whose tile the expert loop
         reached: equal, or tokens were dropped.  ``distinct_experts`` is
         the sum over decode steps and expert layers of the experts at
         least one row reached (what a step had to read), ``expert_load``
-        the (expert layers, experts) cumulative assignments.  ``None``
-        for a block without routers."""
+        the (expert layers, experts) cumulative assignments.
+
+        The Mamba-2 / grouped-query block: ``decode_steps``,
+        ``prefill_chunks``, ``rows_valid`` and ``rows_padded`` (the rows
+        its prefill chunks scanned, real and bucket padding),
+        ``prefills_from_zero`` and ``prefills_carried`` (chunks that began
+        a request on the zero state ``alloc`` left, and chunks that took
+        up the state and the convolution context an earlier chunk wrote),
+        and ``state_bytes_per_slot``."""
         return self.block.report(self.counters, self.model)
+
+    moe_report = block_report   # the name it had while only routers counted
 
     def _pre_dispatch(self, rows):
         """Per-boundary page upkeep before a decode/verify/draft

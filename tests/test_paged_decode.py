@@ -15,6 +15,7 @@ from mxnet_tpu.ops import attention
 from mxnet_tpu.ops.attention import decode_attention, paged_decode_attention
 from mxnet_tpu.serve import model as serve_model
 
+from closeness import assert_close_across_executables
 from serve_util import lend
 
 S, H, D, PAGE, MAX_PAGES, LAYERS, LAYER = 3, 2, 8, 4, 5, 2, 1
@@ -146,15 +147,28 @@ def test_planted_fault_in_a_live_page_is_seen(pool):
     np.testing.assert_array_equal(swapped(unmapped[0]), sound)
 
 
-def test_reader_refuses_more_than_one_query_row():
+def test_reader_takes_a_groups_query_heads_as_rows_and_no_other_head_count():
+    """Grouped-query attention: the query heads that share a key/value
+    head are that head's rows, each answered as it is alone; a head count
+    that is not the pool's is refused; ``scale`` multiplies the scores."""
     rs = np.random.RandomState(10)
     q, k, v, ks, vs = _pools(rs)
+    lengths = jnp.asarray(LENGTHS["mid_page"])
     tables = _tables(rs, LENGTHS["mid_page"])
     from mxnet_tpu.base import MXNetError
 
-    with pytest.raises(MXNetError, match="one query row"):
-        paged_decode_attention(jnp.concatenate([q, q], axis=2), k, v, LAYER,
-                               tables, jnp.asarray([6, 3, 10]), PAGE)
+    alone = [paged_decode_attention(row, k, v, LAYER, tables, lengths, PAGE)
+             for row in (q, 2.0 * q)]
+    both = paged_decode_attention(jnp.concatenate([q, 2.0 * q], axis=2), k,
+                                  v, LAYER, tables, lengths, PAGE)
+    assert_close_across_executables(both[:, :, :1], alone[0])
+    assert_close_across_executables(both[:, :, 1:], alone[1])
+    scaled = paged_decode_attention(q, k, v, LAYER, tables, lengths, PAGE,
+                                    scale=2.0 / q.shape[-1] ** 0.5)
+    assert_close_across_executables(scaled, alone[1])
+    with pytest.raises(MXNetError, match="the pool's %d heads" % k.shape[-2]):
+        paged_decode_attention(jnp.concatenate([q, q], axis=1), k, v, LAYER,
+                               tables, lengths, PAGE)
 
 
 # ---------------------------------------------------------------------------

@@ -25,7 +25,8 @@ from mxnet_tpu.serve.scheduler import Request, Scheduler
 
 # what the session may ask of a block, and no more (docs/serving.md)
 SURFACE = ("validate", "check_params", "init_params", "full_forward",
-           "latent_dim", "init_counters", "prefill_forward", "decode_step",
+           "latent_dim", "state_shapes", "init_counters", "prefill_forward",
+           "decode_step",
            "REFUSES", "REFUSES_WHY", "compiler_options", "report",
            "decode_report", "guard_tag")
 SPECULATIVE = ("verify_step", "draft_propose")
@@ -37,6 +38,13 @@ LATENT = serve.ModelConfig(
     num_heads=2, max_len=64, qk_nope_head_dim=8, qk_rope_head_dim=4,
     v_head_dim=8, kv_lora_rank=12, d_ff=48, first_k_dense=1, moe_d_ff=16,
     n_routed_experts=4, num_experts_per_tok=2, n_shared_experts=1)
+GRANITE = serve.ModelConfig(
+    block="granitemoehybrid", vocab_size=61, num_layers=3, d_model=32,
+    num_heads=4, num_key_value_heads=2, max_len=64, d_ff=48,
+    rms_norm_eps=1e-5, layer_types=("mamba", "attention", "mamba"),
+    mamba_n_heads=4, mamba_d_head=16, mamba_d_state=8, mamba_chunk_size=8,
+    embedding_multiplier=12.0, attention_multiplier=0.125,
+    residual_multiplier=0.22, logits_scaling=8.0, tie_word_embeddings=True)
 CONF = dict(slots=3, page_size=8, buckets=(8, 16), max_new=8)
 
 
@@ -88,6 +96,7 @@ VARIANTS = {
                                   kv_quant="int8")),
     "spec": (GPT2, dict(spec_k=2, draft="layers:1")),
     "latent": (LATENT, dict()),
+    "granite": (GRANITE, dict()),
 }
 
 
@@ -120,11 +129,17 @@ def test_the_cache_owns_its_device_state(variant):
         pools = cache.pools
         assert cache.pool_bytes() == sum(p.nbytes for p in pools.values())
         assert sess.state_report()["pool_bytes"] == sess.cache.pool_bytes()
-        # the paged pools are those whose second axis is the page
+        # the paged pools are those whose second axis is the page; the
+        # slot-private state pools are what the block says a slot holds
         assert set(cache.paged) == {
             n for n, p in pools.items()
             if p.shape[1] == cache.num_pages + 1}
         assert cache.paged
+        assert {n: (pools[n].shape[0], tuple(pools[n].shape[2:]),
+                    str(pools[n].dtype)) for n in cache.state} \
+            == {n: (layers, tuple(shape), dtype) for n, (layers, shape, dtype)
+                in sess.block.state_shapes(sess.model).items()}
+        assert cache.hybrid == bool(cache.state or cache.n_window)
 
         # copy-on-write: a second holder of the slot's first page, then a
         # write into it; every paged pool gets the page copied, bit for

@@ -103,7 +103,8 @@ def test_ring_cache_bookkeeping():
                          page_size=8, num_pages=4, slots=2,
                          max_pages_per_slot=2,
                          layer_kinds=("full", "window", "ssm"),
-                         window=8, ring_pages=3)
+                         window=8, ring_pages=3,
+                         state={"ssm_state": (1, (2, 4, 4), "float32")})
     assert (cache.n_full, cache.n_window, cache.n_ssm) == (1, 1, 1)
     assert cache.hybrid
     # pools only carry FULL layers; rings and state live beside them

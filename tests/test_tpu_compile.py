@@ -116,3 +116,45 @@ def test_metric_reduction_reads_the_lm_head_in_place(one_chip, name, static):
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes >= 2 * 8192 * 50257
     assert memory.temp_size_in_bytes < 1 << 20
+
+
+# granite-4.0-h-micro's Mamba-2 layer: heads, head width, state size,
+# groups; 16 slots, the largest prefill bucket, the published chunk
+MAMBA = dict(heads=64, width=64, state=128, groups=1)
+
+
+def _mamba_avals(rows, one_chip, carried):
+    def aval(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    h, p, n, g = (MAMBA[k] for k in ("heads", "width", "state", "groups"))
+    return (aval(rows, h, p), aval(rows, h), aval(h), aval(rows, g, n),
+            aval(rows, g, n), aval(*carried, h, p, n))
+
+
+def test_mamba2_chunked_scan_compiles_for_v5e_without_a_token_loop(one_chip):
+    """A bucket of 512 rows at chunk 256: the compiled program's only
+    loop is the pass between its two chunks."""
+    from mxnet_tpu.ops.mamba2 import ssd_chunked_scan
+
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(ssd_chunked_scan).lower(
+            *_mamba_avals(512, one_chip, ())).compile()
+    text = compiled.as_text()
+    assert text.count(" while(") <= 1
+    assert "trip_count\":{\"n\":\"512\"" not in text
+    # the decay matrices of one chunk pair are what it holds at most
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
+
+
+def test_mamba2_decode_step_updates_the_donated_state_in_place(one_chip):
+    """16 slots' state, 134 MB a layer, is an argument that the result
+    aliases: no second copy of it beside the 14 GB the cell holds."""
+    from mxnet_tpu.ops.mamba2 import ssd_step
+
+    compiled = jax.jit(ssd_step, donate_argnums=5).lower(
+        *_mamba_avals(16, one_chip, (16,))).compile()
+    memory = compiled.memory_analysis()
+    state_bytes = 16 * 64 * 64 * 128 * 4
+    assert memory.alias_size_in_bytes >= state_bytes
+    assert memory.temp_size_in_bytes < state_bytes // 8
