@@ -578,11 +578,24 @@ def paged_decode_attention(q, k_pool, v_pool, layer, tables, lengths,
 # Pallas fused kernel (TPU) + dispatcher
 # ---------------------------------------------------------------------------
 
-# the library kernel's default BlockSizes are 128 on every axis and it
-# asserts T % block == 0; Mosaic tiles the head dim in lanes of 128 and
-# accepts a half lane
+# the library kernel tiles the sequence in multiples of 128 and asserts
+# that a block divides its axis; Mosaic tiles the head dim in lanes of 128
+# and accepts a half lane
 _PALLAS_T_BLOCK = 128
 _PALLAS_D_MULTIPLE = 64
+# block lengths, from a sweep of the three kernels alone on a v5e chip
+# (PERF.md, Findings PR 31): an outer block of 1024 rows and inner steps
+# of 512 are within a few per cent of the best at every shape read; the
+# library's 128 on every axis pays the grid's per-step overhead 4-5 times
+# over.  The dq kernel's ``di`` operand is broadcast in HBM to its outer k
+# block's width, so that one stays at 512.
+_PALLAS_MAJOR_ROWS = 1024
+_PALLAS_MINOR_ROWS = 512
+# a row of a tile is d * itemsize bytes as an operand and d * 4 in the
+# kernels' float32 accumulators; 2 MiB of rows is what the widest shape
+# compiled for the chip holds (float32, d 256: 1024 rows;
+# tests/test_tpu_compile.py), and wider heads get shorter blocks
+_PALLAS_TILE_BYTES = 2 << 20
 
 
 def pallas_eligible(q, k, v, window=0):
@@ -603,14 +616,49 @@ def pallas_eligible(q, k, v, window=0):
             and q.shape[-1] % _PALLAS_D_MULTIPLE == 0)
 
 
+def _largest_block(axis, limit):
+    """The largest multiple of 128 that divides ``axis`` and is at most
+    ``limit`` (itself at least 128, which divides every axis
+    :func:`pallas_eligible` admits)."""
+    return max(b for b in range(_PALLAS_T_BLOCK, limit + 1, _PALLAS_T_BLOCK)
+               if axis % b == 0)
+
+
+def pallas_block_sizes(q, k):
+    """The library kernel's ``BlockSizes`` for an eligible call, forward
+    and both backward kernels, from what is visible at trace time: the
+    two sequence lengths, the head dim and the dtype's itemsize.  Every
+    block divides its axis (1152 gets 384, 128 gets 128), an inner block
+    divides its outer block, and a wide head shortens the blocks so that
+    the tiles fit VMEM."""
+    from jax.experimental.pallas.ops.tpu.flash_attention import BlockSizes
+
+    q_len, kv_len, d = q.shape[-2], k.shape[-2], q.shape[-1]
+    rows = max(_PALLAS_T_BLOCK, _PALLAS_TILE_BYTES
+               // (d * (jnp.dtype(q.dtype).itemsize + 4)))
+    major, minor = min(_PALLAS_MAJOR_ROWS, rows), min(_PALLAS_MINOR_ROWS, rows)
+    q_major = _largest_block(q_len, major)
+    k_major = _largest_block(kv_len, major)
+    q_minor = _largest_block(q_major, minor)
+    k_minor = _largest_block(k_major, minor)
+    k_dq = _largest_block(kv_len, minor)
+    return BlockSizes(
+        block_q=q_major, block_k_major=k_major, block_k=k_minor, block_b=1,
+        block_q_major_dkv=q_major, block_q_dkv=q_minor,
+        block_k_major_dkv=k_major, block_k_dkv=k_minor,
+        block_q_dq=q_major, block_k_major_dq=k_dq, block_k_dq=k_dq)
+
+
 def _pallas_attention(q, k, v, causal, scale):
-    """TPU fused flash kernel (Mosaic).  Callers check
-    :func:`pallas_eligible` first; whatever the kernel or its compiler
-    then raises is an error of the step, not a reason to change path."""
+    """TPU fused flash kernel (Mosaic), tiled by
+    :func:`pallas_block_sizes`.  Callers check :func:`pallas_eligible`
+    first; whatever the kernel or its compiler then raises is an error of
+    the step, not a reason to change path."""
     from jax.experimental.pallas.ops.tpu.flash_attention import (
         flash_attention as pl_flash)
 
-    return pl_flash(q, k, v, causal=causal, sm_scale=scale)
+    return pl_flash(q, k, v, causal=causal, sm_scale=scale,
+                    block_sizes=pallas_block_sizes(q, k))
 
 
 def dot_product_attention(q, k, v, causal=True, scale=None, impl=None,

@@ -70,6 +70,58 @@ def test_pallas_eligibility_is_decided_from_the_call(shape, dtype, window,
     assert attention.pallas_eligible(q, q, q, window) is fits
 
 
+# (q shape, kv length, dtype): every eligible row of the table above, the
+# benchmark's training shape, and the rule's edges: one block an axis, an
+# axis that 256 and 512 do not divide, the widest head, unequal lengths
+BLOCK_SIZE_CASES = [
+    ((8, 16, 1024, 128), 1024, "bfloat16"),
+    ((8, 16, 1024, 64), 1024, "float32"),
+    ((4, 16, 2048, 128), 2048, "bfloat16"),
+    ((2, 4, 128, 128), 128, "bfloat16"),
+    ((2, 4, 1152, 128), 1152, "bfloat16"),
+    ((1, 2, 2048, 256), 2048, "float32"),
+    ((2, 4, 256, 64), 1152, "float32"),
+    ((1, 2, 4096, 128), 4096, "bfloat16"),
+]
+
+
+@pytest.mark.parametrize("shape,kv_len,dtype", BLOCK_SIZE_CASES, ids=[
+    "bench", "d64-f32", "cell", "T128", "T1152", "d256-f32", "q256-k1152",
+    "T4096"])
+def test_pallas_block_sizes_are_a_function_of_the_call(shape, kv_len, dtype,
+                                                       monkeypatch):
+    from mxnet_tpu.ops import attention
+
+    q = jax.ShapeDtypeStruct(shape, dtype)
+    k = jax.ShapeDtypeStruct(shape[:2] + (kv_len, shape[3]), dtype)
+    assert attention.pallas_eligible(q, k, k)
+    sizes = attention.pallas_block_sizes(q, k)
+    assert sizes.has_backward_blocks and sizes.block_b == 1
+    q_blocks = [sizes.block_q, sizes.block_q_major_dkv, sizes.block_q_dkv,
+                sizes.block_q_dq]
+    k_blocks = [sizes.block_k_major, sizes.block_k, sizes.block_k_major_dkv,
+                sizes.block_k_dkv, sizes.block_k_major_dq, sizes.block_k_dq]
+    for axis, blocks in ((shape[2], q_blocks), (kv_len, k_blocks)):
+        for block in blocks:
+            assert block % 128 == 0 and axis % block == 0, (axis, block)
+        if axis == 128:
+            assert set(blocks) == {128}
+    # minor divides major (BlockSizes checked it too, or raised)
+    assert sizes.block_k_major % sizes.block_k == 0
+    assert sizes.block_q_major_dkv % sizes.block_q_dkv == 0
+    assert sizes.block_k_major_dkv % sizes.block_k_dkv == 0
+    assert sizes.block_k_major_dq % sizes.block_k_dq == 0
+    # the avals alone decide: the lax kernel's knob is not read
+    monkeypatch.setenv("MXNET_ATTN_BLOCK", "64")
+    assert attention.pallas_block_sizes(q, k) == sizes
+    # and they are worth having: no axis a block divides is left at the
+    # library's 128 where a larger block divides it
+    if shape[2] % 256 == 0:
+        assert min(q_blocks) > 128
+    if kv_len % 256 == 0:
+        assert min(k_blocks) > 128
+
+
 def test_rtc_interpret_is_asked_for_by_name():
     from mxnet_tpu.rtc import PallasKernel
 

@@ -5,11 +5,12 @@ topology that is described, not attached (``v5e:2x2``, device kind "TPU
 v5 lite").  Interpret-mode tests cannot see what Mosaic refuses (tiling,
 VMEM budget); these can, at the real shapes: the two BatchNorm reduction
 kernels at ResNet-50 shapes and the library flash-attention kernel that
-``MXNET_ATTN_IMPL=auto`` dispatches to on TPU, forward and backward, at
-the transformer bench shape; and the metrics' device reductions over the
-Cerebras-GPT head's (8192, 50257) bfloat16 softmax, which must read the
-prediction in place.  Nothing runs, so nothing here is a result or a
-time — a compile that passes is not a chip run.
+``MXNET_ATTN_IMPL=auto`` dispatches to on TPU, forward and backward, with
+the blocks ``pallas_block_sizes`` gives each shape (the benchmark's, the
+transformer bench's, the rule's edges); and the metrics' device
+reductions over the Cerebras-GPT head's (8192, 50257) bfloat16 softmax,
+which must read the prediction in place.  Nothing runs, so nothing here
+is a result or a time — a compile that passes is not a chip run.
 
 All of it lives in this one file, and the topology is described inside a
 module-scoped fixture: only the xdist worker that is handed this file
@@ -21,7 +22,6 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 BN_SHAPES = [(64, 256, 56, 56), (512, 64, 112, 112)]
-FLASH_SHAPE = (8, 16, 1024, 128)  # B, H, T, D of the 8L-d2048 config
 LM_HEAD_SHAPE = (8192, 50257)  # batch 4 x 2048 tokens, Cerebras-GPT vocabulary
 
 
@@ -75,14 +75,37 @@ def test_bn_grad_sums_compiles_for_v5e(one_chip):
     _compile(bn_grad_sums, x, x, stat, stat)
 
 
+# (B, H, T, D), dtype: the transformer bench shape, the benchmark's
+# training shape, a float32 half-lane head, and the edges of
+# ``pallas_block_sizes``: one block an axis, an axis only 128 and 384
+# divide, the single-step forward kernel (T 512), the widest tiles the
+# rule lets stand (float32 d 256) and a head wide enough to shorten them
+FLASH_CASES = [
+    ((8, 16, 1024, 128), "bfloat16"),
+    ((4, 16, 2048, 128), "bfloat16"),
+    ((8, 16, 1024, 64), "float32"),
+    ((2, 4, 128, 128), "bfloat16"),
+    ((2, 4, 1152, 128), "bfloat16"),
+    ((2, 4, 512, 128), "bfloat16"),
+    ((1, 2, 2048, 256), "float32"),
+    ((1, 2, 2048, 512), "float32"),
+]
+
+
 @pytest.mark.parametrize("backward", [False, True], ids=["fwd", "bwd"])
-def test_flash_attention_compiles_for_v5e(one_chip, backward):
+@pytest.mark.parametrize("shape,dtype", FLASH_CASES, ids=[
+    "bench", "cell", "d64-f32", "T128", "T1152", "T512", "d256-f32",
+    "d512-f32"])
+def test_flash_attention_compiles_for_v5e(one_chip, shape, dtype, backward):
+    """Through ``_pallas_attention``, so with the blocks
+    ``pallas_block_sizes`` gives the call: Mosaic takes every tile (VMEM,
+    tiling), and the backward kernels are the ones named for them."""
     from mxnet_tpu.ops import attention
 
-    q = jax.ShapeDtypeStruct(FLASH_SHAPE, jnp.bfloat16, sharding=one_chip)
+    q = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     # the shape the dispatcher must judge fit for the kernel
     assert attention.pallas_eligible(q, q, q)
-    scale = FLASH_SHAPE[-1] ** -0.5
+    scale = shape[-1] ** -0.5
 
     def fwd(q, k, v):
         return attention._pallas_attention(q, k, v, True, scale)
@@ -91,7 +114,19 @@ def test_flash_attention_compiles_for_v5e(one_chip, backward):
         return fwd(q, k, v).astype(jnp.float32).sum()
 
     fn = jax.grad(loss, argnums=(0, 1, 2)) if backward else fwd
-    _compile(fn, q, q, q)
+    text = _compile(fn, q, q, q).as_text()
+    # forward alone; with the backward: forward, dkv, dq
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == (
+        3 if backward else 1)
+    if backward:
+        sizes = attention.pallas_block_sizes(q, q)
+        assert ("flash_mha_bwd_dkv_block_q_major=%d_block_q=%d_"
+                "block_k_major=%d_block_k=%d/" % (
+                    sizes.block_q_major_dkv, sizes.block_q_dkv,
+                    sizes.block_k_major_dkv, sizes.block_k_dkv)) in text
+        assert ("flash_mha_bwd_dq_block_q_major=%d_block_k_major=%d_"
+                "block_k=%d/" % (sizes.block_q_dq, sizes.block_k_major_dq,
+                                 sizes.block_k_dq)) in text
 
 
 @pytest.mark.parametrize("name, static", [
