@@ -15,10 +15,16 @@ A latent-attention model (``latent_dim > 0``, ``serve/latent_moe.py``)
 keeps one row of ``latent_dim`` values a token a layer instead of
 per-head keys and values: ONE pool and no other
 
-    latent_pool : (num_layers, num_pages + 1, page_size, latent_dim)
+    latent_pool : (full layers, num_pages + 1, page_size, latent_dim)
 
 Pages, tables, reference counts, the prefix index, oversubscription and
-copy-on-write do not know the difference.
+copy-on-write do not know the difference.  Its layer axis counts the
+layers of kind ``"full"`` only, as the K/V pools' does: a stack whose
+other layers keep slot-private state (``serve/bailing_hybrid.py``: one
+latent-attention layer in six, linear-attention state in the rest) has
+the latent pool for the few and the ``state`` pools below for the many,
+in one cache.  ``kv_quant`` and windowed layers' rings beside a latent
+pool are still refused.
 
 **Slot-private recurrent state** (``state``): what a layer keeps a slot
 besides pages, as the block's ``state_shapes(cfg)`` names it: name ->
@@ -27,8 +33,9 @@ besides pages, as the block's ``state_shapes(cfg)`` names it: name ->
     <name> : (layers, slots) + shape
 
 (the Mamba-2 block's ``ssm_state`` of (heads, head width, state size) and
-``conv_state`` of (taps - 1, channels); the GPT-2 block's ``"ssm"`` kind's
-(H, D, D) ``ssm_state``).  No page indexes it: a slot's rows are zeroed
+``conv_state`` of (taps - 1, channels); the KDA block's ``kda_state`` of
+(heads, key width, value width) and ``conv_state``; the GPT-2 block's
+``"ssm"`` kind's (H, D, D) ``ssm_state``).  No page indexes it: a slot's rows are zeroed
 at :meth:`alloc`, a request's chunks carry them from one prefill dispatch
 to the next, and a cache that has any is ``hybrid``: the prefix index is
 off and prefill takes the slot.  Only ``"full"`` layers own pages, so the
@@ -202,10 +209,11 @@ class PagedKVCache:
         # preempt/resume machinery never know quantization exists
         self.kv_quant = _quantize.quant_mode(kv_quant)
         self.latent_dim = int(latent_dim)
-        if self.latent_dim and (self.kv_quant or self.hybrid):
+        if self.latent_dim and (self.kv_quant or self.n_window):
             raise MXNetError(
                 "PagedKVCache: a latent pool has no kv_quant (no per-head "
-                "row to scale) and no windowed or SSM layers yet")
+                "row to scale) and no windowed layers' rings yet; "
+                "slot-private state pools beside it are served")
         if self.kv_quant:
             dtype = jnp.dtype(_quantize.quant_dtype(self.kv_quant))
         else:

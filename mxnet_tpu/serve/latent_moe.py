@@ -30,7 +30,11 @@ form, and the tests hold this module to it):
   pass flips a token's last expert often enough to show in the logits),
   the ``num_experts_per_tok`` largest ``s + b`` taken,
   ``w = routed_scaling_factor * s / sum_taken(s)``, and
-  ``sum_taken w_e SwiGLU_e(u) + SwiGLU_shared(u)``.
+  ``sum_taken w_e SwiGLU_e(u) + SwiGLU_shared(u)``.  With ``n_group > 1``
+  the choice is group-limited (``noaux_tc``): the experts lie in
+  ``n_group`` equal groups, a group's score is the sum of its two largest
+  ``s + b``, and the experts are taken from the ``topk_group`` best groups
+  only.
 
 The expert layer sorts the tokens x k assignments by expert, pads each
 expert's group to whole tiles and loops over the tiles in use, indexing
@@ -38,6 +42,16 @@ the stacked expert matrices by the tile's expert: every assignment is
 computed whatever the imbalance (dropless), a decode step reads only the
 experts its tokens reach, and prefill does tokens x k expert FLOPs (plus
 tile padding), never tokens x experts.
+
+**A share of the experts** (``experts_held = (first, count)``, one chip
+of an expert-parallel deployment; ``serve/bailing_hybrid.py`` runs it):
+the router keeps all ``n_routed_experts`` outputs and the weights are
+normalised over everything a token took, the stacked matrices hold the
+``count`` experts from ``first`` on, and the loop computes the
+assignments that fall on them.  The others add nothing here (their
+chips would add them) and are told apart from dropped ones: ``computed``
+is false for both, and :func:`held` says which were asked of this chip.
+Nothing stands in for the absent chips or their exchange.
 
 ``exact`` selects the M-invariant ``_mm`` as for the GPT-2 block, but
 the bit-identity contract does not extend here: the absorbed and the
@@ -80,20 +94,49 @@ def validate(cfg):
                          % cfg.qk_rope_head_dim)
     if cfg.layer_kinds or cfg.window:
         raise MXNetError("block %r has no windowed or SSM layers" % BLOCK)
+    validate_ffn(cfg)
+    return cfg
+
+
+def validate_ffn(cfg):
+    """The dense layers' count, the expert layers' sizes, the group limit
+    and the held range fit each other."""
     if not 0 <= cfg.first_k_dense <= cfg.num_layers:
         raise MXNetError("first_k_dense %d outside 0..%d layers"
                          % (cfg.first_k_dense, cfg.num_layers))
-    if cfg.first_k_dense < cfg.num_layers:
-        if min(cfg.moe_d_ff, cfg.n_routed_experts,
-               cfg.num_experts_per_tok) < 1 or cfg.n_shared_experts < 0:
-            raise MXNetError(
-                "expert layers need moe_d_ff, n_routed_experts and "
-                "num_experts_per_tok")
-        if cfg.num_experts_per_tok > cfg.n_routed_experts:
-            raise MXNetError("num_experts_per_tok %d > n_routed_experts %d"
-                             % (cfg.num_experts_per_tok,
-                                cfg.n_routed_experts))
-    return cfg
+    if cfg.first_k_dense == cfg.num_layers:
+        return
+    if min(cfg.moe_d_ff, cfg.n_routed_experts,
+           cfg.num_experts_per_tok) < 1 or cfg.n_shared_experts < 0:
+        raise MXNetError(
+            "expert layers need moe_d_ff, n_routed_experts and "
+            "num_experts_per_tok")
+    if cfg.num_experts_per_tok > cfg.n_routed_experts:
+        raise MXNetError("num_experts_per_tok %d > n_routed_experts %d"
+                         % (cfg.num_experts_per_tok, cfg.n_routed_experts))
+    e, groups = cfg.n_routed_experts, cfg.n_group
+    if groups < 1 or e % groups or not 1 <= cfg.topk_group <= groups \
+            or cfg.num_experts_per_tok > cfg.topk_group * (e // groups) \
+            or (groups > 1 and e // groups < 2):
+        raise MXNetError(
+            "%d experts in n_group %d, topk_group %d, %d a token"
+            % (e, groups, cfg.topk_group, cfg.num_experts_per_tok))
+    first, count = held_range(cfg)
+    if first < 0 or count < 1 or first + count > e:
+        raise MXNetError("experts_held %r outside the router's %d experts"
+                         % (cfg.experts_held, e))
+
+
+def held_range(cfg):
+    """-> (first, count): the routed experts whose matrices are here."""
+    return tuple(cfg.experts_held) or (0, cfg.n_routed_experts)
+
+
+def held(taken, cfg):
+    """taken (N, k) expert ids -> bool, the assignments asked of the
+    experts held here."""
+    first, count = held_range(cfg)
+    return (taken >= first) & (taken < first + count)
 
 
 def param_shapes(cfg):
@@ -104,6 +147,7 @@ def param_shapes(cfg):
     rank, v = cfg.kv_lora_rank, cfg.vocab_size
     e, fe = cfg.n_routed_experts, cfg.moe_d_ff
     fs = cfg.n_shared_experts * fe
+    held_e = held_range(cfg)[1]
     out = {"tok_embed_weight": (v, d), "final_norm_gamma": (d,),
            "lm_head_weight": (v, d)}
     for i in range(cfg.num_layers):
@@ -124,9 +168,9 @@ def param_shapes(cfg):
             continue
         out.update({
             p + "router_weight": (e, d), p + "router_bias": (e,),
-            p + "experts_gate_weight": (e, fe, d),
-            p + "experts_up_weight": (e, fe, d),
-            p + "experts_down_weight": (e, d, fe),
+            p + "experts_gate_weight": (held_e, fe, d),
+            p + "experts_up_weight": (held_e, fe, d),
+            p + "experts_down_weight": (held_e, d, fe),
         })
         if fs:
             out.update({p + "shared_gate_weight": (fs, d),
@@ -348,8 +392,16 @@ def _route(u, params, pre, cfg):
             "nc,ec->ne", u.astype(jnp.float32),
             params[pre + "router_weight"].astype(jnp.float32),
             precision=lax.Precision.HIGHEST))
-        _, taken = lax.top_k(scores + params[pre + "router_bias"],
-                             cfg.num_experts_per_tok)
+        choice = scores + params[pre + "router_bias"]
+        if cfg.n_group > 1:
+            grouped = choice.reshape(u.shape[0], cfg.n_group, -1)
+            _, best = lax.top_k(lax.top_k(grouped, 2)[0].sum(axis=-1),
+                                cfg.topk_group)
+            kept = jnp.zeros(grouped.shape[:2], bool).at[
+                jnp.arange(u.shape[0])[:, None], best].set(True)
+            choice = jnp.where(kept[..., None], grouped, -jnp.inf
+                               ).reshape(choice.shape)
+        _, taken = lax.top_k(choice, cfg.num_experts_per_tok)
         w = jnp.take_along_axis(scores, taken, axis=-1)
         if cfg.norm_topk_prob:
             w = w / (w.sum(axis=-1, keepdims=True) + 1e-20)
@@ -364,31 +416,42 @@ def _tile_rows(assignments, experts):
 
 
 def _routed_experts(u, taken, w, params, pre, cfg, exact):
-    """sum_k w[:, k] * SwiGLU_{taken[:, k]}(u), dropless.  -> (out (N, d),
-    assignments whose tile was computed (N, k) bool)."""
+    """sum_k w[:, k] * SwiGLU_{taken[:, k]}(u) over the experts held
+    here, dropless.  -> (out (N, d), assignments whose tile was computed
+    (N, k) bool: false for one that belongs to an expert held elsewhere,
+    see :func:`held`)."""
     import jax
     import jax.numpy as jnp
     from jax import lax
 
     n, d = u.shape
-    k, e = taken.shape[1], cfg.n_routed_experts
+    k = taken.shape[1]
+    first, e = held_range(cfg)
+    share = e < cfg.n_routed_experts
     a = n * k
-    tile = _tile_rows(a, e)
+    tile = _tile_rows(a, cfg.n_routed_experts)
     max_tiles = a // tile + min(e, a)
     with jax.named_scope("moe_experts"):
         flat = taken.reshape(a)               # assignment = token * k + j
+        if share:   # held elsewhere: group e, behind every group computed
+            flat = jnp.where(held(flat, cfg), flat - first, e)
         order = jnp.argsort(flat, stable=True)
         by_expert = flat[order]
-        counts = jnp.zeros((e,), jnp.int32).at[flat].add(1)
+        counts = jnp.zeros((e + share,), jnp.int32).at[flat].add(1)
         tiles_of = (counts + tile - 1) // tile
+        if share:
+            tiles_of = tiles_of.at[e].set(0)
         tile_end = jnp.cumsum(tiles_of)
         group_start = jnp.cumsum(counts) - counts
         # padded row of each assignment: its expert's first tile, then
         # its rank inside the group
         row = (tile_end - tiles_of)[by_expert] * tile \
             + jnp.arange(a, dtype=jnp.int32) - group_start[by_expert]
-        token_of_row = jnp.full((max_tiles * tile,), n, jnp.int32
-                                ).at[row].set((order // k).astype(jnp.int32))
+        if share:   # no row: past the last tile, where scatters drop
+            row = jnp.where(by_expert < e, row, max_tiles * tile)
+            tile_end = tile_end[:e]
+        token_of_row = jnp.full((max_tiles * tile,), n, jnp.int32).at[row].set(
+            (order // k).astype(jnp.int32), mode="drop" if share else None)
         x = jnp.concatenate([u, jnp.zeros((1, d), u.dtype)])[token_of_row]
         expert_of_tile = jnp.clip(jnp.searchsorted(
             tile_end, jnp.arange(max_tiles, dtype=jnp.int32), side="right"),
@@ -408,25 +471,26 @@ def _routed_experts(u, taken, w, params, pre, cfg, exact):
         y = lax.fori_loop(0, in_use, one_tile,
                           jnp.zeros((max_tiles * tile, d), u.dtype))
         row_of = jnp.zeros((a,), jnp.int32).at[order].set(row)
+        if share:   # what is held elsewhere adds nothing here
+            y = jnp.concatenate([y, jnp.zeros((1, d), y.dtype)])
         out = (y[row_of].reshape(n, k, d) * w[..., None].astype(u.dtype)
                ).sum(axis=1)
         computed = (row_of < in_use * tile).reshape(n, k)
     return out, computed
 
 
-def _ffn(params, i, x, cfg, exact, valid):
-    """The block's second half on (N, d).  ``valid`` (N,) bool marks the
-    rows that are real tokens (bucket padding is routed and computed like
-    any row, and not counted).  -> (x + FFN, counter increments or None)."""
+def _ffn_out(params, i, x, cfg, exact):
+    """FFN(RMSNorm(x)) on (N, d), what layer ``i`` adds to ``x``.
+    -> (out, taken (N, k) expert ids, computed (N, k) bool); the last two
+    ``None`` in a dense layer."""
     import jax
-    import jax.numpy as jnp
 
     pre = "blk%d_" % i
     u = _rms_norm(x, params[pre + "ffn_norm_gamma"], cfg.rms_norm_eps)
     if i < cfg.first_k_dense:
-        return x + _swiglu(u, params[pre + "gate_weight"],
-                           params[pre + "up_weight"],
-                           params[pre + "down_weight"], exact), None
+        return _swiglu(u, params[pre + "gate_weight"],
+                       params[pre + "up_weight"],
+                       params[pre + "down_weight"], exact), None, None
     taken, w = _route(u, params, pre, cfg)
     out, computed = _routed_experts(u, taken, w, params, pre, cfg, exact)
     if cfg.n_shared_experts:
@@ -434,6 +498,18 @@ def _ffn(params, i, x, cfg, exact, valid):
             out = out + _swiglu(u, params[pre + "shared_gate_weight"],
                                 params[pre + "shared_up_weight"],
                                 params[pre + "shared_down_weight"], exact)
+    return out, taken, computed
+
+
+def _ffn(params, i, x, cfg, exact, valid):
+    """The block's second half on (N, d).  ``valid`` (N,) bool marks the
+    rows that are real tokens (bucket padding is routed and computed like
+    any row, and not counted).  -> (x + FFN, counter increments or None)."""
+    import jax.numpy as jnp
+
+    out, taken, computed = _ffn_out(params, i, x, cfg, exact)
+    if taken is None:
+        return x + out, None
     real = jnp.broadcast_to(valid[:, None], taken.shape)
     load = jnp.zeros((cfg.n_routed_experts,), jnp.int32).at[
         taken.reshape(-1)].add(real.reshape(-1).astype(jnp.int32))

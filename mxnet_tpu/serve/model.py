@@ -52,7 +52,8 @@ __all__ = ["ModelConfig", "BLOCKS", "block_of", "exact_mode", "init_params",
 # a block", names it; tests/test_serve_blocks.py holds the modules to
 # it).  This module is the GPT-2 block.
 BLOCKS = {"gpt2": "model", "deepseek_v3": "latent_moe",
-          "granitemoehybrid": "granite_hybrid"}
+          "granitemoehybrid": "granite_hybrid",
+          "bailing_hybrid": "bailing_hybrid"}
 
 
 def block_of(cfg):
@@ -98,7 +99,17 @@ class ModelConfig:
     (``granite_hybrid.py``: Mamba-2 and grouped-query attention layers in
     the published ``layer_types`` order, no positions, a shared SwiGLU of
     ``d_ff``, four multipliers, a tied head) is stated the same way, by
-    the last group of fields.
+    the group of fields from ``num_key_value_heads`` on.
+    ``"bailing_hybrid"`` (``bailing_hybrid.py``: KDA linear-attention
+    layers and gated latent-attention layers, a dense SwiGLU then
+    group-limited routed experts of which this chip may hold a share)
+    takes the latent block's fields, ``layer_types`` of ``"kda"`` |
+    ``"mla"`` (the published stack's layer ``i`` is ``"mla"`` where
+    ``(i + 1) % layer_group_size == 0``; the tuple states the layers
+    kept) and the last group: the router's group limit under its
+    published names, the contiguous range of experts held here, and the
+    KDA mixer's head count, head width, convolution taps, gate bound and
+    prefill chunk.
     """
     vocab_size: int
     num_layers: int
@@ -124,7 +135,8 @@ class ModelConfig:
     routed_scaling_factor: float = 1.0
     norm_topk_prob: bool = True
     num_key_value_heads: int = 0    # 0: as many as query heads
-    layer_types: tuple = ()     # per layer "mamba" | "attention"
+    layer_types: tuple = ()     # per layer "mamba" | "attention", or
+    #                             "kda" | "mla" (bailing_hybrid)
     mamba_n_heads: int = 0
     mamba_d_head: int = 0
     mamba_d_state: int = 0
@@ -136,6 +148,16 @@ class ModelConfig:
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0
     tie_word_embeddings: bool = False
+    n_group: int = 1            # the router's experts, in equal groups
+    topk_group: int = 1         # groups a token may choose experts from
+    experts_held: tuple = ()    # (first, count): the routed experts this
+    #                             chip holds of n_routed_experts; () = all
+    kda_n_heads: int = 0        # 0: as many as query heads
+    kda_head_dim: int = 0       # key and value width of a KDA head
+    kda_d_conv: int = 4         # taps of the short causal convolution
+    kda_lower_bound: float = -5.0   # the safe gate: log-decay a token in
+    #                                 (kda_lower_bound, 0)
+    kda_chunk_size: int = 32    # rows a chunk of the prefill form
 
     @property
     def head_dim(self):
@@ -151,8 +173,8 @@ class ModelConfig:
         ``"full"`` owns pages, ``"window"`` a ring, ``"ssm"`` nothing but
         the slot-private state its block's ``state_shapes`` names."""
         if self.layer_types:
-            return tuple({"attention": "full", "mamba": "ssm"}.get(t, t)
-                         for t in self.layer_types)
+            return tuple({"attention": "full", "mamba": "ssm", "mla": "full",
+                          "kda": "ssm"}.get(t, t) for t in self.layer_types)
         return self.layer_kinds or ("full",) * self.num_layers
 
     @property

@@ -3,7 +3,7 @@
 * A block is a module with a fixed surface (``serve/model.py``'s
   ``BLOCKS`` table; docs/serving.md, "Adding a block"), and
   ``InferenceSession`` reaches the architecture through nothing else: a
-  third block registered here, out of the GPT-2 functions under another
+  further block registered here, out of the GPT-2 functions under another
   name and holding ONLY the surface's names, is served end to end by an
   unedited session.
 * ``PagedKVCache.pools`` is the one owner of the cache's device state:
@@ -45,6 +45,14 @@ GRANITE = serve.ModelConfig(
     mamba_n_heads=4, mamba_d_head=16, mamba_d_state=8, mamba_chunk_size=8,
     embedding_multiplier=12.0, attention_multiplier=0.125,
     residual_multiplier=0.22, logits_scaling=8.0, tie_word_embeddings=True)
+BAILING = serve.ModelConfig(
+    block="bailing_hybrid", vocab_size=61, num_layers=4, d_model=32,
+    num_heads=2, max_len=64, qk_nope_head_dim=8, qk_rope_head_dim=4,
+    v_head_dim=8, kv_lora_rank=12, d_ff=48, first_k_dense=1, moe_d_ff=16,
+    n_routed_experts=16, num_experts_per_tok=4, n_shared_experts=1,
+    routed_scaling_factor=2.5, n_group=4, topk_group=2, experts_held=(4, 4),
+    layer_types=("kda", "kda", "mla", "kda"), kda_head_dim=8,
+    kda_chunk_size=8, rope_theta=6e6)
 CONF = dict(slots=3, page_size=8, buckets=(8, 16), max_new=8)
 
 
@@ -97,6 +105,7 @@ VARIANTS = {
     "spec": (GPT2, dict(spec_k=2, draft="layers:1")),
     "latent": (LATENT, dict()),
     "granite": (GRANITE, dict()),
+    "bailing": (BAILING, dict()),
 }
 
 
@@ -175,3 +184,20 @@ def test_pool_names_are_the_caches_alone():
         2, latent_moe.stats_size(LATENT))
     assert sess.decode_report() is None
     assert sess.moe_report()["decode_steps"] == 0
+
+
+def test_a_latent_pool_and_state_pools_in_one_session():
+    """The fourth block's cache: one latent pool for its one ``"mla"``
+    layer, two state pools for its three ``"kda"`` layers, no ``k_pool``;
+    its counters are not among the pools."""
+    sess = serve.InferenceSession(
+        serve.init_params(BAILING, seed=5), model=BAILING,
+        config=serve.ServeConfig(**CONF))
+    assert sorted(sess.cache.pools) == ["conv_state", "kda_state",
+                                        "latent_pool"]
+    assert sess.cache.pools["latent_pool"].shape[0] == 1
+    assert sess.cache.pools["kda_state"].shape[:2] == (3, CONF["slots"])
+    assert sess.cache.paged == ("latent_pool",) and sess.cache.hybrid
+    assert list(sess.counters) == ["moe_stats"]
+    assert sess.decode_report() is None
+    assert sess.block_report()["experts_held"] == 4

@@ -9,7 +9,9 @@ kernels at ResNet-50 shapes and the library flash-attention kernel that
 the blocks ``pallas_block_sizes`` gives each shape (the benchmark's, the
 transformer bench's, the rule's edges); and the metrics' device
 reductions over the Cerebras-GPT head's (8192, 50257) bfloat16 softmax,
-which must read the prediction in place.  Nothing runs, so nothing here
+which must read the prediction in place; and the KDA layer's two forms
+at Ling-3.0-flash's widths (plain XLA: the solve's lowering, the
+temporaries, the state updated in place).  Nothing runs, so nothing here
 is a result or a time — a compile that passes is not a chip run.
 
 All of it lives in this one file, and the topology is described inside a
@@ -193,3 +195,39 @@ def test_mamba2_decode_step_updates_the_donated_state_in_place(one_chip):
     state_bytes = 16 * 64 * 64 * 128 * 4
     assert memory.alias_size_in_bytes >= state_bytes
     assert memory.temp_size_in_bytes < state_bytes // 8
+
+
+# Ling-3.0-flash's KDA layer at its published widths: 32 heads of 128 x
+# 128 float32 state; a prefill bucket of 1024 rows in chunks of 32, a
+# decode step over 64 slots.  Plain XLA, no Mosaic kernel: what could be
+# refused is the triangular solve's lowering and the temporaries' size.
+def _kda_avals(lead, one_chip):
+    sds = lambda *shape: jax.ShapeDtypeStruct(lead + shape, jnp.float32,
+                                              sharding=one_chip)
+    return (sds(32, 128), sds(32, 128), sds(32, 128), sds(32, 128), sds(32),
+            sds(32, 128, 128))
+
+
+def test_kda_chunked_form_compiles_for_v5e(one_chip):
+    from mxnet_tpu.ops.kda import kda_chunked
+
+    q, k, v, g, beta, _ = _kda_avals((1024,), one_chip)
+    state = jax.ShapeDtypeStruct((32, 128, 128), jnp.float32,
+                                 sharding=one_chip)
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(lambda *a: kda_chunked(*a, chunk=32)).lower(
+            q, k, v, g, beta, state).compile()
+    # the chunk pass is the one loop over rows' chunks; temporaries stay
+    # far under what the cell leaves free beside 12.7 GB (~3 GB)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+def test_kda_decode_step_updates_the_state_in_place_on_v5e(one_chip):
+    from mxnet_tpu.ops.kda import kda_step
+
+    avals = _kda_avals((64,), one_chip)
+    compiled = jax.jit(kda_step, donate_argnums=5).lower(*avals).compile()
+    mem = compiled.memory_analysis()
+    # 64 slots' state is 134 MB a layer: donated, it is updated in place
+    assert mem.alias_size_in_bytes >= 64 * 32 * 128 * 128 * 4
+    assert mem.temp_size_in_bytes < 64 * 32 * 128 * 128 * 4
