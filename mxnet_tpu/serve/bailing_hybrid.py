@@ -48,10 +48,11 @@ from ..ops.mamba2 import causal_conv, conv_step
 from . import latent_moe
 from .latent_moe import (_LO_BITS, _attend_absorbed, _attend_materialised,
                          _ffn_out, _fold, _head, _prefill_block,
-                         _query_and_row, _rms_norm, held, held_range)
-# the expert loop is the latent block's, and so is what it asks of XLA
+                         _query_and_row, _resolve, _rms_norm, held,
+                         held_range)
+# the expert layer is the latent block's, and so is what it asks of XLA
 from .latent_moe import compiler_options  # noqa: F401
-from .model import _mm, _resolve_params, check_param_shapes
+from .model import _mm, check_param_shapes
 
 BLOCK = "bailing_hybrid"
 
@@ -354,13 +355,13 @@ def _head_gate(params, pre, att, u, cfg, exact):
                 * gate[..., None].astype(att.dtype)).reshape(att.shape)
 
 
-def _ffn(params, i, x, cfg, exact, valid):
+def _ffn(params, i, x, cfg, exact, valid, dequantized):
     """The block's second half on (N, d): the latent block's, with this
     block's counts by name.  ``valid`` (N,) bool marks the rows that are
     real tokens.  -> (x + FFN, counter increments or None)."""
     import jax.numpy as jnp
 
-    out, taken, computed = _ffn_out(params, i, x, cfg, exact)
+    out, taken, computed = _ffn_out(params, i, x, cfg, exact, dequantized)
     if taken is None:
         return x + out, None
     first, count = held_range(cfg)
@@ -385,7 +386,7 @@ def full_forward(params, tokens, cfg, exact, block=None):
     import jax
     import jax.numpy as jnp
 
-    params = _resolve_params(params)
+    params, dequantized = _resolve(params)
     t = tokens.shape[-1]
     if t > cfg.max_len:
         raise MXNetError("sequence length %d > model max_len %d"
@@ -415,7 +416,8 @@ def full_forward(params, tokens, cfg, exact, block=None):
                                            block or t)
                 out = _mm(_head_gate(params, pre, att, u, cfg, exact),
                           params[pre + "o_weight"], exact)
-            x, _ = _ffn(params, i, x + out, cfg, exact, valid)
+            x, _ = _ffn(params, i, x + out, cfg, exact, valid,
+                        dequantized)
         return _head(params, x, cfg, exact)
 
     return jax.vmap(one)(tokens)
@@ -437,7 +439,7 @@ def prefill_forward(params, tokens, length, offset, table_row, pools,
     import jax
     import jax.numpy as jnp
 
-    params = _resolve_params(params)
+    params, dequantized = _resolve(params)
     _, t_b = tokens.shape
     if t_b % page_size:
         raise MXNetError("bucket length %d not a multiple of page size %d"
@@ -481,7 +483,7 @@ def prefill_forward(params, tokens, length, offset, table_row, pools,
             out = _mm(_head_gate(params, pre, att, u, cfg, exact),
                       params[pre + "o_weight"], exact)
             li += 1
-        x, inc = _ffn(params, i, x + out, cfg, exact, valid)
+        x, inc = _ffn(params, i, x + out, cfg, exact, valid, dequantized)
         if inc is not None:
             incs.append(inc)
     last = _head(params, jnp.take(x, length - 1, axis=0), cfg, exact)
@@ -502,7 +504,7 @@ def decode_step(params, tokens, lengths, tables, pools, counters, cfg,
     import jax
     import jax.numpy as jnp
 
-    params = _resolve_params(params)
+    params, dequantized = _resolve(params)
     s = tokens.shape[0]
     max_pages = tables.shape[1]
     pools = dict(pools)
@@ -544,7 +546,7 @@ def decode_step(params, tokens, lengths, tables, pools, counters, cfg,
             out = _mm(_head_gate(params, pre, att, u, cfg, exact),
                       params[pre + "o_weight"], exact)
             li += 1
-        x, inc = _ffn(params, i, x + out, cfg, exact, valid)
+        x, inc = _ffn(params, i, x + out, cfg, exact, valid, dequantized)
         if inc is not None:
             incs.append(inc)
     logits = _head(params, x, cfg, exact)

@@ -34,6 +34,8 @@ masking makes the pad positions exact no-ops).
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import dataclasses
 import functools
 import importlib
@@ -216,6 +218,32 @@ def check_params(params, cfg):
             raise MXNetError("the parameters say %s %d, the architecture "
                              "says %d" % (name, getattr(got, name),
                                           getattr(cfg, name)))
+
+
+_TRACE_NOTES = contextvars.ContextVar("serve_trace_notes", default=None)
+
+
+@contextlib.contextmanager
+def trace_notes():
+    """-> a dict of what a block's functions note (:func:`note_traced`)
+    while they are traced inside the ``with``: facts of the program that
+    was built, such as which kernel a layer was traced with, that no
+    device counter can hold.  The session builds each executable under
+    one and keeps the dict beside it."""
+    notes = {}
+    token = _TRACE_NOTES.set(notes)
+    try:
+        yield notes
+    finally:
+        _TRACE_NOTES.reset(token)
+
+
+def note_traced(name, count):
+    """Add ``count`` under ``name`` to the notes of the trace under way;
+    nothing outside :func:`trace_notes`."""
+    notes = _TRACE_NOTES.get()
+    if notes is not None:
+        notes[name] = notes.get(name, 0) + count
 
 
 def check_param_shapes(params, shapes, block):

@@ -153,7 +153,8 @@ import time
 from ..base import MXNetError, get_env
 from ..quantize import quant_mode
 from .kv_cache import PagedKVCache
-from .model import ModelConfig, block_of, config_from_params, exact_mode
+from .model import (ModelConfig, block_of, config_from_params, exact_mode,
+                    trace_notes)
 
 __all__ = ["ServeConfig", "InferenceSession"]
 
@@ -333,11 +334,11 @@ class _Executable(object):
     build and by every call since."""
 
     __slots__ = ("name", "compiled", "jitted", "guard", "aval_sig",
-                 "params", "params_sig", "memory", "fallbacks",
+                 "params", "params_sig", "memory", "traced", "fallbacks",
                  "leaves_described")
 
     def __init__(self, name, compiled, jitted, guard, params, aval_sig,
-                 memory):
+                 memory, traced=None):
         import jax
 
         self.name = name
@@ -346,6 +347,8 @@ class _Executable(object):
         self.guard = guard
         self.aval_sig = aval_sig
         self.memory = memory  # dict from memory_analysis(), at compile time
+        # what the block noted while it was traced (model.trace_notes)
+        self.traced = traced or {}
         self.fallbacks = 0
         # the parameters lead the signature, as they lead the arguments
         self.params = params
@@ -618,7 +621,9 @@ class InferenceSession(object):
         jitted = jax.jit(fn, donate_argnums=donate_argnums)
         hits_before = compile_cache.cache_stats()["hits"]
         t0 = time.perf_counter()
-        compiled = jitted.lower(*avals).compile(
+        with trace_notes() as traced:
+            lowered = jitted.lower(*avals)
+        compiled = lowered.compile(
             compiler_options=self.block.compiler_options(
                 jax.default_backend()))
         dt = time.perf_counter() - t0
@@ -652,7 +657,7 @@ class InferenceSession(object):
         sig = signature_of(avals)
         guard.observe(sig)
         self._exes[name] = _Executable(name, compiled, jitted, guard,
-                                       params, sig, memory)
+                                       params, sig, memory, traced)
 
     def _compile_all(self):
         import jax
@@ -1136,6 +1141,10 @@ class InferenceSession(object):
         the sum over decode steps and expert layers of the experts at
         least one row reached (what a step had to read), ``expert_load``
         the (expert layers, experts) cumulative assignments.
+        ``expert_kernel_layers`` (this block's and the KDA block's) is not
+        a device count: the expert layers of the decode executable that
+        were traced with the grouped-matmul kernel
+        (``ops/grouped_matmul.py``), 0 where the ``fori_loop`` runs.
 
         The Mamba-2 / grouped-query block: ``decode_steps``,
         ``prefill_chunks``, ``rows_valid`` and ``rows_padded`` (the rows
@@ -1144,7 +1153,10 @@ class InferenceSession(object):
         a request on the zero state ``alloc`` left, and chunks that took
         up the state and the convolution context an earlier chunk wrote),
         and ``state_bytes_per_slot``."""
-        return self.block.report(self.counters, self.model)
+        rep = self.block.report(self.counters, self.model)
+        if rep is not None:
+            rep.update(self._exes["decode"].traced)
+        return rep
 
     moe_report = block_report   # the name it had while only routers counted
 

@@ -47,7 +47,7 @@ from mxnet_tpu.serve.scheduler import Request, Scheduler
 
 from closeness import (LIMIT_SPACINGS, assert_close_across_executables,
                        spacings_apart)
-from serve_util import lend
+from serve_util import assert_the_cpu_runs_the_expert_loop, lend
 
 _REF = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
     __file__))), "benchmark", "references", "bailing_hybrid_lm.py")
@@ -103,6 +103,7 @@ def model_config(hf):
 
 CFG = model_config(HF)
 KDA_LAYERS = CFG.layer_types.count("kda")
+EXPERT_LAYERS = HF["num_hidden_layers"] - HF["first_k_dense_replace"]
 
 
 def test_the_layer_pattern_follows_the_published_period():
@@ -504,6 +505,19 @@ def test_scheduler_serves_and_the_block_counts(params):
             rep["experts_held"]) == (3, 1, 3, 4)
     assert rep["state_bytes_per_slot"] == 3 * 4 * (4 * 16 * 16 + 3 * 192)
     assert sess.decode_report() is None and sess.fallback_count() == 0
+
+
+def test_on_the_cpu_the_expert_layers_run_the_loop(plain, params,
+                                                   monkeypatch):
+    """The predicate beside the kernel says "loop" here (the backend, and
+    these widths): both traced programs hold the ``while`` and no
+    ``pallas_call``, and the report says so with an integer.  Asked to say
+    "kernel", it is answered by a ``pallas_call`` an expert layer, which
+    the executables note while they are traced; and what it is told of a
+    weight-only-quantized tree is that its stacks were made inside the
+    trace."""
+    assert_the_cpu_runs_the_expert_loop(
+        plain, session(params, quant="int8"), EXPERT_LAYERS, monkeypatch)
 
 
 def test_what_the_block_refuses(params):

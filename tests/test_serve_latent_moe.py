@@ -41,7 +41,7 @@ from mxnet_tpu.serve import model as serve_model
 from mxnet_tpu.serve.scheduler import Request, Scheduler
 
 from closeness import assert_close_across_executables, spacings_apart
-from serve_util import lend
+from serve_util import assert_the_cpu_runs_the_expert_loop, lend
 
 _REF = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
     __file__))), "benchmark", "references", "deepseek_v3_lm.py")
@@ -82,6 +82,7 @@ def model_config(hf):
 
 
 CFG = model_config(HF)
+EXPERT_LAYERS = HF["num_hidden_layers"] - HF["first_k_dense_replace"]
 ROW = HF["kv_lora_rank"] + HF["qk_rope_head_dim"]
 
 
@@ -395,6 +396,19 @@ def test_the_comparison_can_fail(plain, params):
     got = np.asarray(serve_model.full_forward(
         starved, jnp.asarray([seq], jnp.int32), CFG, exact=False))[0]
     assert spacings_apart(got, ref_logits(params, seq)) > 1e3
+
+
+def test_on_the_cpu_the_expert_layers_run_the_loop(plain, params,
+                                                   monkeypatch):
+    """The predicate beside the kernel says "loop" here (the backend, and
+    these widths): both traced programs hold the ``while`` and no
+    ``pallas_call``, and the report says so with an integer.  Asked to say
+    "kernel", it is answered by a ``pallas_call`` an expert layer, which
+    the executables note while they are traced; and what it is told of a
+    weight-only-quantized tree is that its stacks were made inside the
+    trace."""
+    assert_the_cpu_runs_the_expert_loop(
+        plain, session(params, quant="int8"), EXPERT_LAYERS, monkeypatch)
 
 
 def _gpt2_session():

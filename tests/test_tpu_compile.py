@@ -11,8 +11,10 @@ transformer bench's, the rule's edges); and the metrics' device
 reductions over the Cerebras-GPT head's (8192, 50257) bfloat16 softmax,
 which must read the prediction in place; and the KDA layer's two forms
 at Ling-3.0-flash's widths (plain XLA: the solve's lowering, the
-temporaries, the state updated in place).  Nothing runs, so nothing here
-is a result or a time — a compile that passes is not a chip run.
+temporaries, the state updated in place); and the routed-expert layer's
+grouped-matmul kernel over kanana-2's and Ling-3.0-flash's stacks, a
+whole float32 expert a block.  Nothing runs, so nothing here is a result
+or a time — a compile that passes is not a chip run.
 
 All of it lives in this one file, and the topology is described inside a
 module-scoped fixture: only the xdist worker that is handed this file
@@ -231,3 +233,59 @@ def test_kda_decode_step_updates_the_state_in_place_on_v5e(one_chip):
     # 64 slots' state is 134 MB a layer: donated, it is updated in place
     assert mem.alias_size_in_bytes >= 64 * 32 * 128 * 128 * 4
     assert mem.temp_size_in_bytes < 64 * 32 * 128 * 128 * 4
+
+
+# The routed-expert layer of the two expert cells at their real shapes
+# (rows of the call, d, routed experts, experts a token, experts held):
+# kanana-2's decode step over 16 slots (108 tiles of 8 rows) and its
+# bucket of 2048 (224 tiles of 128) over (128, 768, 2048) stacks;
+# Ling-3.0-flash's decode step over 64 slots (128 tiles of 8) and its
+# bucket of 1024 (576 tiles of 16) over the (64, 768, 2560) stacks of the
+# share this chip holds.
+EXPERT_CASES = {
+    "kanana-decode": (16, 2048, 128, 6, (), 8, 108),
+    "kanana-bucket-2048": (2048, 2048, 128, 6, (), 128, 224),
+    "ling-decode": (64, 2560, 512, 8, (0, 64), 8, 128),
+    "ling-bucket-1024": (1024, 2560, 512, 8, (0, 64), 16, 576),
+}
+
+
+@pytest.mark.parametrize("name", sorted(EXPERT_CASES))
+def test_grouped_swiglu_compiles_for_v5e(one_chip, name, monkeypatch):
+    """Through ``_routed_experts``, so with the tiles it builds and the
+    predicate's own answer for a TPU: Mosaic takes a whole expert a block
+    (``vmem_limit_bytes``), the kernel carries its tile in its name, and
+    no stack is copied or converted on its way in: the kernel reads the
+    float32 matrices where the parameters lie."""
+    import re
+
+    from mxnet_tpu.ops.grouped_matmul import kernel_name
+    from mxnet_tpu.serve import latent_moe
+    from serve_util import expert_layer_config
+
+    rows, d, experts, top_k, held, tile, tiles = EXPERT_CASES[name]
+    cfg = expert_layer_config(d, 768, experts, top_k, held)
+    e = latent_moe.held_range(cfg)[1]
+    assert latent_moe._tile_rows(rows * top_k, experts) == tile
+    assert rows * top_k // tile + min(e, rows * top_k) == tiles
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def layer(u, taken, w, gate, up, down):
+        params = {"blk1_experts_gate_weight": gate,
+                  "blk1_experts_up_weight": up,
+                  "blk1_experts_down_weight": down}
+        return latent_moe._routed_experts(u, taken, w, params, "blk1_", cfg,
+                                          False)
+
+    text = _compile(layer, sds((rows, d)), sds((rows, top_k), jnp.int32),
+                    sds((rows, top_k)), sds((e, 768, d)), sds((e, 768, d)),
+                    sds((e, d, 768))).as_text()
+    assert len(re.findall(r"%%%s[.\d]* = " % kernel_name(tile), text)) == 1
+    stack = r"(f32|bf16)\[%d,(768,%d|%d,768)\]" % (e, d, d)
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if re.search(r"= %s\S* (copy|convert|fusion|transpose)\("
+                          % stack, line)]
+    assert not moved, moved
