@@ -65,6 +65,7 @@ import functools
 
 from .base import MXNetError, logger
 from .compile_cache import signature_of as _signature_of
+from .profiler import span as _span
 
 __all__ = ["compile_train_step", "TrainStep"]
 
@@ -1028,65 +1029,66 @@ class TrainStep:
             t = self._t - K + 1  # first inner step's post-increment count
         else:
             self._t = int(t) + K - 1
-        # Two input hygiene passes before the donated call:
-        # 1. commit uncommitted arrays (jnp.zeros products) so the jit
-        #    signature is identical on every step — no recompiles;
-        # 2. donated pytrees must not alias each other (some optimizers
-        #    seed state from the weight buffer; XLA may also alias
-        #    identical outputs) — copy duplicates.
-        seen = set()
+        with _span("trainstep.hygiene"):
+            # Two input hygiene passes before the donated call:
+            # 1. commit uncommitted arrays (jnp.zeros products) so the jit
+            #    signature is identical on every step — no recompiles;
+            # 2. donated pytrees must not alias each other (some optimizers
+            #    seed state from the weight buffer; XLA may also alias
+            #    identical outputs) — copy duplicates.
+            seen = set()
 
-        def dedupe(x):
-            if not getattr(x, "committed", True):
-                x = jax.device_put(x, next(iter(x.devices())))
-            k = _buffer_key(x)
-            if k in seen:
-                return jnp.copy(x)
-            seen.add(k)
-            return x
+            def dedupe(x):
+                if not getattr(x, "committed", True):
+                    x = jax.device_put(x, next(iter(x.devices())))
+                k = _buffer_key(x)
+                if k in seen:
+                    return jnp.copy(x)
+                seen.add(k)
+                return x
 
-        params, aux, states = jax.tree.map(
-            dedupe, (params, aux, states))
-        if self._jit_step is None:
-            if self.zero_axis is not None:
-                self._jit_step = self._build_zero_jit(params, states)
+            params, aux, states = jax.tree.map(
+                dedupe, (params, aux, states))
+            if self._jit_step is None:
+                if self.zero_axis is not None:
+                    self._jit_step = self._build_zero_jit(params, states)
+                else:
+                    self._jit_step = self._build_sharded_jit(params, states)
+            if getattr(self, "_in_pshard", None) is not None:
+                # committed single-device arrays cannot be auto-resharded to
+                # a non-trivial layout by jit; place them explicitly (no-op
+                # once the donated outputs carry the sharding)
+                params = _place(params, self._in_pshard)
+                states = _place(states, self._in_sshard)
+            lr = self.lr if lr is None else lr
+            t = jnp.asarray(t, "int32")
+            if self._spans_processes():
+                # pod run: EVERY array argument must be a global jax.Array —
+                # jit cannot place host batches/rng/scalars across processes
+                # itself.  The host batch is read as the GLOBAL batch (each
+                # rank materializes its own rows), matching the
+                # single-process semantics bit for bit.
+                repl = self._in_repl
+                aux = _place(aux, repl)
+                batch = _place(dict(batch), self._in_bshard)
+                rng = _place(rng, repl)
+                lr = _place(jnp.asarray(lr, "float32"), repl)
+                t = _place(t, repl)
+                if self._use_hstate and self._hstate is None:
+                    self._fp8_site_count(params, aux, batch)
+                    self._hstate = self._init_hstate()
+                if self._hstate is not None:
+                    self._hstate = _place(self._hstate, repl)
+            if not self._use_hstate:
+                call_args = (params, aux, states, batch, rng, lr, t)
             else:
-                self._jit_step = self._build_sharded_jit(params, states)
-        if getattr(self, "_in_pshard", None) is not None:
-            # committed single-device arrays cannot be auto-resharded to
-            # a non-trivial layout by jit; place them explicitly (no-op
-            # once the donated outputs carry the sharding)
-            params = _place(params, self._in_pshard)
-            states = _place(states, self._in_sshard)
-        lr = self.lr if lr is None else lr
-        t = jnp.asarray(t, "int32")
-        if self._spans_processes():
-            # pod run: EVERY array argument must be a global jax.Array —
-            # jit cannot place host batches/rng/scalars across processes
-            # itself.  The host batch is read as the GLOBAL batch (each
-            # rank materializes its own rows), matching the
-            # single-process semantics bit for bit.
-            repl = self._in_repl
-            aux = _place(aux, repl)
-            batch = _place(dict(batch), self._in_bshard)
-            rng = _place(rng, repl)
-            lr = _place(jnp.asarray(lr, "float32"), repl)
-            t = _place(t, repl)
-            if self._use_hstate and self._hstate is None:
-                self._fp8_site_count(params, aux, batch)
-                self._hstate = self._init_hstate()
-            if self._hstate is not None:
-                self._hstate = _place(self._hstate, repl)
-        if not self._use_hstate:
-            call_args = (params, aux, states, batch, rng, lr, t)
-        else:
-            if self._hstate is None:
-                self._fp8_site_count(params, aux, batch)
-                self._hstate = self._init_hstate()
-            call_args = (params, aux, states, batch, rng, lr, t,
-                         self._hstate)
-        sig = _signature_of(*call_args)
-        self._recompile_guard.observe(sig)
+                if self._hstate is None:
+                    self._fp8_site_count(params, aux, batch)
+                    self._hstate = self._init_hstate()
+                call_args = (params, aux, states, batch, rng, lr, t,
+                             self._hstate)
+            sig = _signature_of(*call_args)
+            self._recompile_guard.observe(sig)
 
         def dispatch():
             out = None
@@ -1112,33 +1114,34 @@ class TrainStep:
                 out = self._jit_step(*call_args)
             return out
 
-        if self.zero_axis is not None:
-            from .parallel import zero as _zero
-            from .testing import faults
+        with _span("trainstep.launch"):
+            if self.zero_axis is not None:
+                from .parallel import zero as _zero
+                from .testing import faults
 
-            def dispatch_zero():
-                # host-side boundaries of the in-program collectives:
-                # before dispatch = the gradient reduce-scatter (and,
-                # under ZeRO-3, the forward bucket all-gathers), after
-                # the result = the stage-1 fresh-param all-gather
-                faults.inject("zero_update")
-                if self.zero3:
-                    faults.inject("zero_gather")
-                res = dispatch()
-                faults.inject("zero_update")
-                return res
+                def dispatch_zero():
+                    # host-side boundaries of the in-program collectives:
+                    # before dispatch = the gradient reduce-scatter (and,
+                    # under ZeRO-3, the forward bucket all-gathers), after
+                    # the result = the stage-1 fresh-param all-gather
+                    faults.inject("zero_update")
+                    if self.zero3:
+                        faults.inject("zero_gather")
+                    res = dispatch()
+                    faults.inject("zero_update")
+                    return res
 
-            what = None
-            active = None
-            if self.zero3 and faults.active("zero_gather"):
-                active = True
-                what = ("ZeRO-3 bucketed parameter all-gather (forward "
-                        "bucket gathers + backward re-gather)")
-            out = _zero.bounded_dispatch(dispatch_zero,
-                                         kvstore=self._kvstore,
-                                         active=active, what=what)
-        else:
-            out = dispatch()
+                what = None
+                active = None
+                if self.zero3 and faults.active("zero_gather"):
+                    active = True
+                    what = ("ZeRO-3 bucketed parameter all-gather (forward "
+                            "bucket gathers + backward re-gather)")
+                out = _zero.bounded_dispatch(dispatch_zero,
+                                             kvstore=self._kvstore,
+                                             active=active, what=what)
+            else:
+                out = dispatch()
         if not self._use_hstate:
             return out
         (params, aux, states, outs, self._hstate,

@@ -17,6 +17,7 @@ from ..base import MXNetError, StepHung, TrainingDiverged, TrainingPreempted
 from .. import metric as metric_mod
 from .. import io as io_mod
 from ..ndarray import NDArray
+from ..profiler import span as _span
 
 __all__ = ["BaseModule"]
 
@@ -501,63 +502,76 @@ class BaseModule:
                     # run the epoch tail and move on
                     end_of_batch = True
                 while not end_of_batch:
-                    data_batch = next_data_batch
-                    if watchdog is not None:
-                        watchdog.kick("epoch %d batch %d" % (epoch, nbatch))
-                    faults.inject("step")
-                    if monitor is not None:
-                        monitor.tic()
-                    self.forward_backward(data_batch)
-                    self.update()
-                    if hmon is not None:
-                        # dispatch boundary: feed the monitor this step's
-                        # device stats refs; it realizes LAGGED entries
-                        # (already finished on device — free reads) and
-                        # may request a rollback
-                        self._health_tick(hmon, mgr, epoch, nbatch)
-                    # lookahead next() AFTER dispatch: pulling batch n+1 off
-                    # the staging queue (and refilling it) overlaps the step
-                    # that is still executing asynchronously on device
-                    try:
-                        next_data_batch = next(data_iter)
-                    except StopIteration:
-                        end_of_batch = True
-                    if K > 1:
-                        outs = self.get_outputs()
-                        labels = data_batch.label or []
-                        for k in range(K):
-                            self.update_metric(eval_metric,
-                                               [l[k] for l in labels],
-                                               outputs=[o[k] for o in outs])
-                    else:
-                        self.update_metric(eval_metric, data_batch.label)
-                    if monitor is not None:
-                        monitor.toc_print()
-                    if batch_end_callback is not None:
-                        for cb in _as_list(batch_end_callback):
-                            cb(BatchEndParam(epoch=epoch, nbatch=nbatch,
-                                             eval_metric=eval_metric,
-                                             locals=locals()))
-                    nbatch += K
-                    if guard.fired is not None:
-                        # batch boundary: params/optimizer state consistent
-                        self._preempt(guard.fired, fit_data, mgr,
-                                      epoch, nbatch)
-                    if elastic is not None:
-                        event = elastic.poll()
-                        if event is not None:
-                            self._elastic_migrate(elastic, event, mgr,
-                                                  fit_data, epoch, nbatch)
-                            # the stream was re-seeked to this boundary
-                            # (migration) or left in place (fallback);
-                            # either way the lookahead batch fetched
-                            # above predates the move — refetch
-                            data_iter = iter(fit_data)
-                            end_of_batch = False
+                    with _span("fit.batch", epoch=epoch, nbatch=nbatch):
+                        data_batch = next_data_batch
+                        if watchdog is not None:
+                            watchdog.kick("epoch %d batch %d"
+                                          % (epoch, nbatch))
+                        faults.inject("step")
+                        if monitor is not None:
+                            monitor.tic()
+                        with _span("fit.forward_backward"):
+                            self.forward_backward(data_batch)
+                        with _span("fit.update"):
+                            self.update()
+                        if hmon is not None:
+                            # dispatch boundary: feed the monitor this
+                            # step's device stats refs; it realizes LAGGED
+                            # entries (already finished on device — free
+                            # reads) and may request a rollback
+                            self._health_tick(hmon, mgr, epoch, nbatch)
+                        # lookahead next() AFTER dispatch: pulling batch
+                        # n+1 off the staging queue (and refilling it)
+                        # overlaps the step that is still executing
+                        # asynchronously on device
+                        with _span("fit.next_batch"):
                             try:
                                 next_data_batch = next(data_iter)
                             except StopIteration:
                                 end_of_batch = True
+                        with _span("fit.update_metric"):
+                            if K > 1:
+                                outs = self.get_outputs()
+                                labels = data_batch.label or []
+                                for k in range(K):
+                                    self.update_metric(
+                                        eval_metric, [l[k] for l in labels],
+                                        outputs=[o[k] for o in outs])
+                            else:
+                                self.update_metric(eval_metric,
+                                                   data_batch.label)
+                        if monitor is not None:
+                            monitor.toc_print()
+                        if batch_end_callback is not None:
+                            with _span("fit.callbacks"):
+                                for cb in _as_list(batch_end_callback):
+                                    cb(BatchEndParam(
+                                        epoch=epoch, nbatch=nbatch,
+                                        eval_metric=eval_metric,
+                                        locals=locals()))
+                        nbatch += K
+                        if guard.fired is not None:
+                            # batch boundary: params/optimizer state
+                            # consistent
+                            self._preempt(guard.fired, fit_data, mgr,
+                                          epoch, nbatch)
+                        if elastic is not None:
+                            event = elastic.poll()
+                            if event is not None:
+                                self._elastic_migrate(
+                                    elastic, event, mgr, fit_data, epoch,
+                                    nbatch)
+                                # the stream was re-seeked to this
+                                # boundary (migration) or left in place
+                                # (fallback); either way the lookahead
+                                # batch fetched above predates the move —
+                                # refetch
+                                data_iter = iter(fit_data)
+                                end_of_batch = False
+                                try:
+                                    next_data_batch = next(data_iter)
+                                except StopIteration:
+                                    end_of_batch = True
 
                 if watchdog is not None:
                     # the epoch tail (eval pass, checkpoint write,

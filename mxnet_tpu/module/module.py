@@ -21,6 +21,7 @@ from .. import optimizer as opt
 from .. import kvstore as kvs
 from ..initializer import InitDesc
 from ..ndarray import NDArray, zeros
+from ..profiler import span as _span
 from .base_module import BaseModule
 
 __all__ = ["Module"]
@@ -756,34 +757,36 @@ class Module(BaseModule):
         aux = {n: self._exec.aux_dict[n]._data for n in self._aux_names}
         if self._fused_states is None:
             self._fused_states = self._init_fused_states()
-        batch = {}
-        for name, arr in zip(self._data_names, data_batch.data):
-            batch[name] = arr._data if isinstance(arr, NDArray) else \
-                jnp.asarray(arr)
-        for name, arr in zip(self._label_names, data_batch.label or []):
-            batch[name] = arr._data if isinstance(arr, NDArray) else \
-                jnp.asarray(arr)
         K = getattr(self._fused, "_steps_per_call", 1)
-        if getattr(data_batch, "staged", False):
-            # the DevicePrefetchIter staging thread already placed this
-            # batch (device or NamedSharding) — re-placing would be a
-            # synchronous no-op at best and an axis-0 re-shard at worst
-            # for packed super-batches
-            pass
-        elif self._mesh is not None:
-            from ..parallel.sharding import shard_batch
+        with _span("trainstep.stage"):
+            batch = {}
+            for name, arr in zip(self._data_names, data_batch.data):
+                batch[name] = arr._data if isinstance(arr, NDArray) else \
+                    jnp.asarray(arr)
+            for name, arr in zip(self._label_names, data_batch.label or []):
+                batch[name] = arr._data if isinstance(arr, NDArray) else \
+                    jnp.asarray(arr)
+            if getattr(data_batch, "staged", False):
+                # the DevicePrefetchIter staging thread already placed this
+                # batch (device or NamedSharding) — re-placing would be a
+                # synchronous no-op at best and an axis-0 re-shard at worst
+                # for packed super-batches
+                pass
+            elif self._mesh is not None:
+                from ..parallel.sharding import shard_batch
 
-            lead = 1 if K > 1 else 0
-            batch = {k: shard_batch(self._mesh, v, leading=lead)
-                     for k, v in batch.items()}
-        else:
-            # load_data semantics: batches follow the module's device, not
-            # the default platform (a cpu-context module on a TPU host gets
-            # NDArrayIter batches materialized on the accelerator)
-            import jax
+                lead = 1 if K > 1 else 0
+                batch = {k: shard_batch(self._mesh, v, leading=lead)
+                         for k, v in batch.items()}
+            else:
+                # load_data semantics: batches follow the module's device,
+                # not the default platform (a cpu-context module on a TPU
+                # host gets NDArrayIter batches materialized on the
+                # accelerator)
+                import jax
 
-            dev = self._context[0].jax_device
-            batch = {k: jax.device_put(v, dev) for k, v in batch.items()}
+                dev = self._context[0].jax_device
+                batch = {k: jax.device_put(v, dev) for k, v in batch.items()}
         from ..testing import faults
 
         poison = faults.inject("numerics")
@@ -812,24 +815,25 @@ class Module(BaseModule):
         self._last_health_stats = getattr(self._fused, "last_health", None)
         from ..parallel.pipeline import PipelineTrainStep
 
-        if isinstance(self._fused, PipelineTrainStep):
-            # params/states live as packed stage-sharded buffers inside
-            # the step; arg_dict is synced lazily (_sync_pipeline) when
-            # something reads it (eval forward, get_params, checkpoint)
-            self._pipeline_stale = True
-        elif z3:
-            # at-rest tiles stay step-side; aux (batchnorm stats) are
-            # canonical-shaped and land in aux_dict as usual
-            self._zero3_params = new_params
-            self._zero3_stale = True
-            for n, v in new_aux.items():
-                self._exec.aux_dict[n]._set_data(v)
-        else:
-            for n, v in new_params.items():
-                self._exec.arg_dict[n]._set_data(v)
-            for n, v in new_aux.items():
-                self._exec.aux_dict[n]._set_data(v)
-        self._exec.outputs = [NDArray(o, self._context[0]) for o in outs]
+        with _span("fit.adopt"):
+            if isinstance(self._fused, PipelineTrainStep):
+                # params/states live as packed stage-sharded buffers inside
+                # the step; arg_dict is synced lazily (_sync_pipeline) when
+                # something reads it (eval forward, get_params, checkpoint)
+                self._pipeline_stale = True
+            elif z3:
+                # at-rest tiles stay step-side; aux (batchnorm stats) are
+                # canonical-shaped and land in aux_dict as usual
+                self._zero3_params = new_params
+                self._zero3_stale = True
+                for n, v in new_aux.items():
+                    self._exec.aux_dict[n]._set_data(v)
+            else:
+                for n, v in new_params.items():
+                    self._exec.arg_dict[n]._set_data(v)
+                for n, v in new_aux.items():
+                    self._exec.aux_dict[n]._set_data(v)
+            self._exec.outputs = [NDArray(o, self._context[0]) for o in outs]
         self._fused_ran = True
 
     # -- compute --------------------------------------------------------

@@ -20,22 +20,37 @@ FLOPs estimate, executable size) and retrievable with
 :func:`compile_events` / summed with :func:`total_compile_s` — the
 numbers ``TrainStep.compile_stats`` and the bench scripts' ``compile_s``
 field surface (see docs/compilation.md).
+
+Host spans (:func:`span`): the program's own record of what the host did
+on its three hot paths (``Scheduler.tick``, a session call, ``fit``'s
+batch), under the names ``docs/performance.md`` ("Spans") lists.  A span
+is live while a profiler session runs, whoever started it, or after
+``record_spans(True)``; it is then written into the running trace as
+``mx:<name>`` (on the device trace's clock) and kept in memory on
+``time.perf_counter()`` for :func:`spans`.  Otherwise a span site costs
+one ``is_enabled()`` check.
 """
 from __future__ import annotations
 
+import collections
 import glob
 import gzip
+import itertools
 import os
 import shutil
 import tempfile
 import threading
 import time as _time
 
+from jax.profiler import TraceAnnotation   # jax is loaded by now (base)
+
 from .base import MXNetError, get_env
 
 __all__ = ["profiler_set_config", "profiler_set_state", "set_config",
            "set_state", "dump", "dump_profile", "state",
-           "compile_event", "compile_events", "total_compile_s"]
+           "compile_event", "compile_events", "total_compile_s",
+           "span", "spans", "clear_spans", "spans_dropped",
+           "record_spans", "SpanRecord", "SPAN_PREFIX", "SPAN_CAPACITY"]
 
 _config = {"filename": "profile.json", "profile_all": False}
 _state = {"running": False, "tmpdir": None, "dumped": False}
@@ -136,6 +151,128 @@ def total_compile_s():
     """Total wall seconds this process spent in recorded compilations."""
     with _compile_lock:
         return sum(e["duration_s"] for e in _compile_events)
+
+
+# -- host spans -------------------------------------------------------------
+
+SPAN_PREFIX = "mx:"
+SPAN_CAPACITY = 1 << 18
+
+SpanRecord = collections.namedtuple(
+    "SpanRecord", "id parent name start_s end_s attrs")
+
+_perf_counter = _time.perf_counter   # the clock of Scheduler.now
+_span_records = collections.deque(maxlen=SPAN_CAPACITY)
+_span_lock = threading.Lock()
+_span_ids = itertools.count(1)
+_span_local = threading.local()
+_span_state = {"record": False, "dropped": 0}
+
+
+class _NoSpan(object):
+    """What :func:`span` hands out while spans are off."""
+
+    __slots__ = ()
+    on = False
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set(self, **attrs):
+        pass
+
+
+_NO_SPAN = _NoSpan()
+
+
+class _Span(object):
+    __slots__ = ("id", "parent", "name", "start_s", "attrs", "_annotation")
+    on = True
+
+    def __init__(self, name, attrs, annotation):
+        self.name = name
+        self.attrs = attrs
+        self._annotation = annotation
+
+    def __enter__(self):
+        try:
+            stack = _span_local.stack
+        except AttributeError:
+            stack = _span_local.stack = []
+        self.id = next(_span_ids)
+        self.parent = stack[-1] if stack else None
+        stack.append(self.id)
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        self.start_s = _perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        end_s = _perf_counter()
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        _span_local.stack.pop()
+        record = (self.id, self.parent, self.name, self.start_s, end_s,
+                  self.attrs)
+        with _span_lock:
+            if len(_span_records) == SPAN_CAPACITY:
+                _span_state["dropped"] += 1
+            _span_records.append(record)
+        return False
+
+    def set(self, **attrs):
+        """Add what was not known at entry (small scalars)."""
+        self.attrs.update(attrs)
+        if self._annotation is not None:
+            self._annotation.set_metadata(**attrs)
+
+
+def span(name, **attrs):
+    """A context manager around one stretch of host work.  On or off is
+    decided here, at entry.  The ``with`` yields a handle: ``on``,
+    ``start_s`` (when on) and ``set(**attrs)``."""
+    if TraceAnnotation.is_enabled():
+        return _Span(name, attrs, TraceAnnotation(SPAN_PREFIX + name, **attrs))
+    if _span_state["record"]:
+        return _Span(name, attrs, None)
+    return _NO_SPAN
+
+
+def record_spans(on=True):
+    """Keep spans in memory with no profiler session running (about two
+    microseconds a span); returns what the switch was."""
+    was, _span_state["record"] = _span_state["record"], bool(on)
+    return was
+
+
+def spans(name=None, since=None, until=None):
+    """The finished spans in the order they ended (a child before its
+    parent), as :class:`SpanRecord` copies: those called ``name``, begun
+    at or after ``since`` and ended at or before ``until``
+    (``time.perf_counter()`` values)."""
+    with _span_lock:
+        records = list(_span_records)
+    return [SpanRecord(i, parent, n, start_s, end_s, dict(attrs))
+            for i, parent, n, start_s, end_s, attrs in records
+            if (name is None or n == name)
+            and (since is None or start_s >= since)
+            and (until is None or end_s <= until)]
+
+
+def clear_spans():
+    with _span_lock:
+        _span_records.clear()
+        _span_state["dropped"] = 0
+
+
+def spans_dropped():
+    """Spans the bounded record (``SPAN_CAPACITY``) has let go, oldest
+    first, since the last :func:`clear_spans`."""
+    return _span_state["dropped"]
+
 
 if get_env("MXNET_PROFILER_AUTOSTART", False, bool):
     profiler_set_state("run")

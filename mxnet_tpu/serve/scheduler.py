@@ -89,6 +89,7 @@ import dataclasses
 import time
 
 from ..base import MXNetError
+from ..profiler import span as _span
 from ..testing import faults
 
 __all__ = ["Request", "Scheduler", "ServeCancelled", "summarize"]
@@ -96,7 +97,10 @@ __all__ = ["Request", "Scheduler", "ServeCancelled", "summarize"]
 _POLICIES = ("serial", "static", "continuous")
 
 _FRESH_STATS = {"preemptions": 0, "resumes": 0, "peak_active": 0,
-                "faulted": 0, "cancelled": 0}
+                "faulted": 0, "cancelled": 0,
+                # a ``serve.tick`` span's ``admitted`` / ``finished`` are
+                # these two, counted over the tick
+                "admitted": 0, "finished": 0}
 
 
 class ServeCancelled(MXNetError):
@@ -327,14 +331,46 @@ class Scheduler(object):
         and run one fixed-shape step.  Returns :attr:`outstanding`.
         ``wait=False`` skips the idle open-loop sleep (a supervisor
         interleaving many schedulers owns the clock)."""
+        if not self.outstanding:
+            return False
+        with _span("serve.tick", live=len(self._active)) as sp:
+            if not sp.on:
+                return self._tick(wait)
+            stats = self.stats
+            admitted, finished = stats["admitted"], stats["finished"]
+            more = self._tick(wait)
+            sp.set(admitted=stats["admitted"] - admitted,
+                   finished=stats["finished"] - finished)
+            return more
+
+    def _admit(self, req, seq, budget, resume):
+        """``try_alloc`` and prefill of one arrival, or of one parked
+        request's transcript, as one ``serve.admit`` span -> (slot,
+        first token): the slot ``None`` where the session has no room, the
+        token ``None`` where the request failed (and is released)."""
+        with _span("serve.admit", rid=req.rid, prompt=len(seq),
+                   resume=int(resume)) as sp:
+            if sp.on:
+                sp.set(queued_ms=(sp.start_s - self._t0 - req.arrival_s)
+                       * 1e3)
+            slot = self.session.try_alloc(len(seq), budget, tokens=seq,
+                                          resume=resume)
+            sp.set(slot=-1 if slot is None else slot)
+            if slot is None:
+                return None, None
+            (self._parked if resume else self._pending).remove(req)
+            self.stats["admitted"] += 1
+            return slot, self._prefill(req, slot, seq)
+
+    def _tick(self, wait):
+        """The body of :meth:`tick`, inside its ``serve.tick`` span (it
+        leaves at four places; the span's end-of-tick attributes are set
+        at one, in :meth:`tick`)."""
         sess = self.session
         pending, parked, active = self._pending, self._parked, self._active
         now = self.now
-        if not self.outstanding:
-            return False
-
-        slo_s = float(getattr(sess.config, "ttft_slo_ms", 0.0)) / 1000.0
-        oversub = bool(getattr(sess.config, "oversub", False))
+        config = sess.config
+        slo_s = config.ttft_slo_ms / 1000.0
 
         # 0) resume parked requests first — they hold queue
         # seniority over fresh arrivals, and their transcript pages
@@ -345,8 +381,7 @@ class Scheduler(object):
                 continue
             seq = list(req.prompt) + req.tokens[:-1]
             budget = req.max_new - len(req.tokens) + 1
-            slot = sess.try_alloc(len(seq), budget, tokens=seq,
-                                  resume=True)
+            slot, first = self._admit(req, seq, budget, resume=True)
             if slot is None:
                 if not active and not pending:
                     raise MXNetError(
@@ -354,8 +389,6 @@ class Scheduler(object):
                         "idle session — pool smaller than one "
                         "request's worst case" % req.rid)
                 break
-            parked.remove(req)
-            first = self._prefill(req, slot, seq)
             if first is None:
                 continue
             if first != req.tokens[-1]:
@@ -380,15 +413,15 @@ class Scheduler(object):
         if self.policy == "serial":
             admit_cap = 1 if not active else 0
         elif self.policy == "static":
-            admit_cap = sess.config.slots if not active else 0
+            admit_cap = config.slots if not active else 0
         else:
-            admit_cap = sess.config.slots - len(active)
+            admit_cap = config.slots - len(active)
         for req in arrived[:max(admit_cap, 0)]:
             if not self._boundary(req, None, "serve_admit"):
                 pending.remove(req)
                 continue
-            slot = sess.try_alloc(len(req.prompt), req.max_new,
-                                  tokens=req.prompt)
+            slot, first = self._admit(req, req.prompt, req.max_new,
+                                      resume=False)
             if slot is None:
                 if not active:
                     # nothing of this scheduler's is running, so nothing
@@ -402,13 +435,11 @@ class Scheduler(object):
                         "request needs %d — another caller holds the "
                         "session's slots, or the pool is smaller than "
                         "one request's worst case"
-                        % (req.rid, cache.free_slots, sess.config.slots,
+                        % (req.rid, cache.free_slots, config.slots,
                            cache.reclaimable_pages,
                            cache.pages_needed(len(req.prompt),
                                               req.max_new)))
                 break  # pool full: stays queued for a later boundary
-            pending.remove(req)
-            first = self._prefill(req, slot, req.prompt)
             if first is None:
                 continue
             req.ttft_s = now() - req.arrival_s
@@ -428,7 +459,7 @@ class Scheduler(object):
             return self.outstanding
 
         # 2) per-request step boundaries (deterministic slot order)
-        spec = getattr(sess.config, "spec_k", 0) > 0
+        spec = config.spec_k > 0
         site = "serve_verify" if spec else "serve_decode"
         for slot in sorted(active):
             req = active[slot]
@@ -444,9 +475,9 @@ class Scheduler(object):
         # — park them, and let the survivors step.  The last active
         # request is never evicted (it can always finish: one
         # request's worst case fits the pool by construction).
-        if oversub:
-            rows = sess.config.spec_window if spec else 1
-            wm = max(int(getattr(sess.config, "watermark", 0)), 0)
+        if config.oversub:
+            rows = config.spec_window if spec else 1
+            wm = config.watermark
             while (len(active) > 1
                    and sess.pages_short(rows) + wm
                    > sess.cache.reclaimable_pages):
@@ -494,16 +525,20 @@ class Scheduler(object):
         return self.outstanding
 
     def _finish(self, req, slot, active, now):
-        active.pop(slot, None)
-        if self._boundary(req, slot, "serve_respond"):
-            req.done_s = now()
-            self.session.release(slot)
-        if self._followup is not None:
-            nxt = self._followup(req, now())
-            if nxt is not None:
-                for r in (nxt if isinstance(nxt, (list, tuple)) else [nxt]):
-                    self._pending.append(r)
-                    self._queue.append(r)
+        self.stats["finished"] += 1
+        with _span("serve.finish", rid=req.rid, slot=slot,
+                   tokens=len(req.tokens)):
+            active.pop(slot, None)
+            if self._boundary(req, slot, "serve_respond"):
+                req.done_s = now()
+                self.session.release(slot)
+            if self._followup is not None:
+                nxt = self._followup(req, now())
+                if nxt is not None:
+                    for r in (nxt if isinstance(nxt, (list, tuple))
+                              else [nxt]):
+                        self._pending.append(r)
+                        self._queue.append(r)
 
 
 def _percentile(values, pct):

@@ -151,6 +151,7 @@ import json
 import time
 
 from ..base import MXNetError, get_env
+from ..profiler import span as _span
 from ..quantize import quant_mode
 from .kv_cache import PagedKVCache
 from .model import (ModelConfig, block_of, config_from_params, exact_mode,
@@ -867,52 +868,60 @@ class InferenceSession(object):
         admissions."""
         import numpy as np
 
-        prompt = np.asarray(prompt_tokens, np.int32).reshape(-1)
-        p = int(prompt.shape[0])
-        cached = self.cache.cached_len(slot)
-        if not 0 <= cached < p:
-            raise MXNetError("prefill: cached prefix %d outside prompt "
-                             "of %d tokens" % (cached, p))
-        if self.config.kv_quant:
-            # chaos site: a fault here fails THIS request before any of
-            # its quantized pages/scales are written, so survivors'
-            # pages and scale rows stay consistent
-            from ..testing import faults
+        with _span("session.prefill", slot=slot) as sp:
+            prompt = np.asarray(prompt_tokens, np.int32).reshape(-1)
+            p = int(prompt.shape[0])
+            cached = self.cache.cached_len(slot)
+            sp.set(prompt=p, cached=cached)
+            if not 0 <= cached < p:
+                raise MXNetError("prefill: cached prefix %d outside prompt "
+                                 "of %d tokens" % (cached, p))
+            if self.config.kv_quant:
+                # chaos site: a fault here fails THIS request before any
+                # of its quantized pages/scales are written, so survivors'
+                # pages and scale rows stay consistent
+                from ..testing import faults
 
-            faults.inject("kv_quant")
-        if self.cache.n_window:
-            # chaos site: fail before any ring row is written — the
-            # slot's ring still holds only rows whose gather labels fall
-            # outside every future mask, so survivors (and this slot's
-            # re-admission) see a consistent ring
-            from ..testing import faults
+                faults.inject("kv_quant")
+            if self.cache.n_window:
+                # chaos site: fail before any ring row is written — the
+                # slot's ring still holds only rows whose gather labels
+                # fall outside every future mask, so survivors (and this
+                # slot's re-admission) see a consistent ring
+                from ..testing import faults
 
-            faults.inject("kv_window")
-        first = last_logits = None
-        off = cached
-        while off < p:
-            bucket = self._chunk_bucket(p - off)
-            n = min(p - off, bucket)
-            toks = np.zeros((1, bucket), np.int32)
-            toks[0, :n] = prompt[off:off + n]
-            self.cache.ensure_writable(slot, off, n)
-            # host arrays: the launch uploads them, no call of its own each
-            args = (self.params, toks, np.int32(n), np.int32(off),
-                    self.cache.table_row(slot), self.cache.pools,
-                    self.counters,
-                    np.int32(slot) if self.cache.hybrid else None)
-            first, last_logits, self.cache.pools, self.counters = \
-                self._dispatch("prefill_%d" % bucket, args)
-            off += n
-            self.cache.lengths[slot] = off
-        first = int(first)
-        self._slot_tokens[slot] = first
-        self._slot_history[slot] = [int(t) for t in prompt] + [first]
-        prompt_list = [int(t) for t in prompt]
-        self.cache.register_prefix(slot, prompt_list)
-        if self._draft_mode == "model":
-            self._draft_ingest(slot, prompt)
-            self.draft_cache.register_prefix(slot, prompt_list)
+                faults.inject("kv_window")
+            first = last_logits = None
+            off, chunks = cached, 0
+            while off < p:
+                with _span("prefill.launch"):
+                    bucket = self._chunk_bucket(p - off)
+                    n = min(p - off, bucket)
+                    toks = np.zeros((1, bucket), np.int32)
+                    toks[0, :n] = prompt[off:off + n]
+                    self.cache.ensure_writable(slot, off, n)
+                    # host arrays: the launch uploads them, no call of its
+                    # own each
+                    args = (self.params, toks, np.int32(n), np.int32(off),
+                            self.cache.table_row(slot), self.cache.pools,
+                            self.counters,
+                            np.int32(slot) if self.cache.hybrid else None)
+                    first, last_logits, self.cache.pools, self.counters = \
+                        self._dispatch("prefill_%d" % bucket, args)
+                off += n
+                chunks += 1
+                self.cache.lengths[slot] = off
+            sp.set(bucket=bucket, chunks=chunks)
+            with _span("prefill.wait"):
+                first = int(first)
+            with _span("prefill.publish"):
+                self._slot_tokens[slot] = first
+                self._slot_history[slot] = [int(t) for t in prompt] + [first]
+                prompt_list = [int(t) for t in prompt]
+                self.cache.register_prefix(slot, prompt_list)
+                if self._draft_mode == "model":
+                    self._draft_ingest(slot, prompt)
+                    self.draft_cache.register_prefix(slot, prompt_list)
         return first, last_logits
 
     def _draft_ingest(self, slot, prompt):
@@ -963,30 +972,35 @@ class InferenceSession(object):
         import numpy as np
 
         cfg = self.config
-        self._pre_dispatch(1)
-        tokens = np.zeros((cfg.slots,), np.int32)
-        for slot, tok in self._slot_tokens.items():
-            tokens[slot] = tok
-        args = (self.params, tokens,
-                self.cache.lengths_arg(), self.cache.device_tables(),
-                self.cache.pools, self.counters)
-        # the page blocks this step's attention has to visit: those of
-        # the longest context, its new row included
-        longest = int(self.cache.lengths.max()) + 1
-        self._decode_stats["steps"] += 1
-        self._decode_stats["blocks_visited"] += min(
-            -(-longest // cfg.page_size), self.cache.table_width)
-        next_toks, logits, self.cache.pools, self.counters = \
-            self._dispatch("decode", args)
-        next_np = np.asarray(next_toks)
-        out = {}
-        for slot in list(self._slot_tokens):
-            self.cache.lengths[slot] += 1
-            tok = int(next_np[slot])
-            self._slot_tokens[slot] = tok
-            if slot in self._slot_history:
-                self._slot_history[slot].append(tok)
-            out[slot] = tok
+        with _span("session.step", live=len(self._slot_tokens)):
+            with _span("step.prepare"):
+                self._pre_dispatch(1)
+                tokens = np.zeros((cfg.slots,), np.int32)
+                for slot, tok in self._slot_tokens.items():
+                    tokens[slot] = tok
+                args = (self.params, tokens,
+                        self.cache.lengths_arg(), self.cache.device_tables(),
+                        self.cache.pools, self.counters)
+                # the page blocks this step's attention has to visit:
+                # those of the longest context, its new row included
+                longest = int(self.cache.lengths.max()) + 1
+                self._decode_stats["steps"] += 1
+                self._decode_stats["blocks_visited"] += min(
+                    -(-longest // cfg.page_size), self.cache.table_width)
+            with _span("step.launch"):
+                next_toks, logits, self.cache.pools, self.counters = \
+                    self._dispatch("decode", args)
+            with _span("step.wait"):
+                next_np = np.asarray(next_toks)
+            with _span("step.commit"):
+                out = {}
+                for slot in list(self._slot_tokens):
+                    self.cache.lengths[slot] += 1
+                    tok = int(next_np[slot])
+                    self._slot_tokens[slot] = tok
+                    if slot in self._slot_history:
+                        self._slot_history[slot].append(tok)
+                    out[slot] = tok
         return out, logits
 
     def spec_step(self, limits=None):
