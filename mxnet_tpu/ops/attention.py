@@ -516,8 +516,10 @@ def paged_decode_attention(q, k_pool, v_pool, layer, tables, lengths,
 
     q: (S, H, R, D): one query row a slot and head (R = 1), or the R
     query heads that share key/value head H as its rows (grouped-query
-    attention; they share the slot's length too); k_pool/v_pool:
-    (L, pages + 1, page_size, H, D) as ``PagedKVCache`` lays them out;
+    attention; they share the slot's length too); k_pool/v_pool: as
+    ``serve/kv_cache.py`` lays them out at rest (its ``kv_pool_shape``),
+    read through its ``read_pages``, which hands back the gathered pages
+    as (..., page_size, H, D) whatever that layout is;
     ``layer`` the pool's layer to read; tables: (S, max_pages) int32 (rows
     past a slot's reservation name the trash page, which is in bounds);
     lengths: (S,) int, valid rows INCLUDING the current token, which the
@@ -526,12 +528,18 @@ def paged_decode_attention(q, k_pool, v_pool, layer, tables, lengths,
     the loop; ``scale`` multiplies the scores (1 / sqrt(D) by default).
     Traced bound, so not differentiable: decode never is.
     """
+    # called from a traced step, long after both packages are loaded
+    # (``serve`` imports this module while it is itself imported)
+    from ..serve.kv_cache import pool_heads, read_pages
+
     s, max_pages = tables.shape
-    if q.shape[1] != k_pool.shape[-2] or lengths.ndim != 1:
+    d = q.shape[-1]
+    heads = pool_heads(k_pool, d)
+    if q.shape[1] != heads or lengths.ndim != 1:
         raise MXNetError(
             "paged_decode_attention takes the pool's %d heads and one "
             "length a slot, got q %r, lengths %r"
-            % (k_pool.shape[-2], q.shape, lengths.shape))
+            % (heads, q.shape, lengths.shape))
     group = max(1, min(_PAGED_KEYS_PER_ITERATION // page_size, max_pages))
     # columns that complete the last group lie past every horizon
     # (position >= max_pages * page_size >= lengths): any page in bounds
@@ -548,8 +556,8 @@ def paged_decode_attention(q, k_pool, v_pool, layer, tables, lengths,
 
     def body(it, carry):
         cols = lax.dynamic_slice_in_dim(tables, it * group, group, axis=1)
-        k_grp = k_pool[layer, cols]          # (S, group, page, H, D)
-        v_grp = v_pool[layer, cols]
+        k_grp = read_pages(k_pool, layer, cols, d)  # (S, group, page, H, D)
+        v_grp = read_pages(v_pool, layer, cols, d)
         if k_scale is not None:
             ks_grp, vs_grp = k_scale[layer, cols], v_scale[layer, cols]
         for g in range(group):
@@ -566,7 +574,7 @@ def paged_decode_attention(q, k_pool, v_pool, layer, tables, lengths,
                                  kv_valid=k_pos < valid_len, mi=mi)
         return carry
 
-    acc0 = jnp.zeros(q.shape[:-1] + (v_pool.shape[-1],), jnp.float32)
+    acc0 = jnp.zeros(q.shape, jnp.float32)
     m0 = jnp.full(q.shape[:-1] + (1,), -jnp.inf, jnp.float32)
     l0 = jnp.zeros(q.shape[:-1] + (1,), jnp.float32)
     acc, _, l = lax.fori_loop(0, (live_pages + group - 1) // group, body,

@@ -49,8 +49,9 @@ from ..base import MXNetError
 from ..ops.attention import (decode_attention, flash_attention,
                              paged_decode_attention)
 from ..ops.mamba2 import causal_conv, conv_step, ssd_chunked_scan, ssd_step
+from .kv_cache import append_rows, read_context
 from .latent_moe import _LO_BITS, _fold, _prefill_block, _rms_norm
-from .model import _append, _mm, _resolve_params, check_param_shapes
+from .model import _mm, _resolve_params, check_param_shapes
 # the attention layers run the GPT-2 block's paged reader: its report
 from .model import decode_report  # noqa: F401
 
@@ -429,12 +430,10 @@ def prefill_forward(params, tokens, length, offset, table_row, pools,
         else:
             with jax.named_scope("gqa_prefill"):
                 q, k, v = _qkv(params, pre, u, cfg, exact)
-                _append(pools, "k", ai, pages, offsets, k, "")
-                _append(pools, "v", ai, pages, offsets, v, "")
-                ctx_k, ctx_v = (
-                    pools[name][ai][table_row].reshape(
-                        1, max_pages * page_size, kv, hd).transpose(0, 2, 1, 3)
-                    for name in ("k_pool", "v_pool"))
+                append_rows(pools, "k", ai, pages, offsets, k, "")
+                append_rows(pools, "v", ai, pages, offsets, v, "")
+                ctx_k = read_context(pools["k_pool"], ai, table_row, hd)
+                ctx_v = read_context(pools["v_pool"], ai, table_row, hd)
                 # a key/value head's query heads are its rows: row
                 # t * group + g sees the keys row t sees
                 att = decode_attention(
@@ -495,8 +494,8 @@ def decode_step(params, tokens, lengths, tables, pools, counters, cfg,
         else:
             with jax.named_scope("gqa_decode"):
                 q, k, v = _qkv(params, pre, u, cfg, exact)
-                _append(pools, "k", ai, page, offset, k, "")
-                _append(pools, "v", ai, page, offset, v, "")
+                append_rows(pools, "k", ai, page, offset, k, "")
+                append_rows(pools, "v", ai, page, offset, v, "")
                 att = paged_decode_attention(
                     q, pools["k_pool"], pools["v_pool"], ai, tables,
                     lengths + 1, page_size, mi=exact,

@@ -5,11 +5,35 @@ Duality and Portable O(1) Autoregressive Caching"): all KV state lives
 in two fixed-shape device pools
 
     k_pool, v_pool : (num_layers, num_pages + 1, page_size, H, D)
+                     when D is a multiple of 128, and otherwise
+                     (num_layers, num_pages + 1, page_size, H * D)
 
 so every prefill/decode executable sees one unchanging buffer shape —
 no per-request allocation, no growing tensors, no recompiles.  Requests
 own *pages* (rows of the pool), recorded in a per-slot page table the
 executables consume as a plain (slots, max_pages) int32 array.
+
+**The K/V pools' layout at rest** (:func:`kv_pool_shape`; THIS file is
+the only place that knows it).  A TPU keeps an array's last two axes in
+tiles of (8, 128): 8 sublanes by 128 lanes.  With heads of 128 the
+(H, D) axes fill whole tiles and a 16-row append is an in-place update
+of 16 rows.  With heads of 64 (granite-4.0-h-micro: 8 x 64) the lane
+axis is half a tile, the pool at rest is padded to twice its bytes, and
+the compiler unpacks and repacks the WHOLE pool around each append: for
+a donated float32 pool of 100.8 MB, 117.5 MB of argument and 201.6 MB of
+temporaries, four whole-pool copies a layer for K and V, 6-7 ms of a
+32 ms decode step for under 1 % of its bytes (PERF.md, PRs 30 and 38).
+So a head narrower than a lane tile is not given the lane axis to
+itself: the heads fold into it, (..., H * D), 512 lanes with no padding,
+and the same append compiles to the in-place update alone.  Axes 0-2
+(layer, page, row in the page) are the same in both layouts, so pages,
+tables, the trash page, copy-on-write and the scale pools of
+``kv_quant`` do not know the difference.  The step functions never
+index the trailing axes: they write rows of (..., H, D) through
+:func:`append_rows` and read pages back as (..., H, D) through
+:func:`read_pages` / :func:`read_context`, which reshape what they have
+*gathered* (a few pages a slot), never the pool.
+:attr:`PagedKVCache.kv_lanes` says which layout a cache took.
 
 A latent-attention model (``latent_dim > 0``, ``serve/latent_moe.py``)
 keeps one row of ``latent_dim`` values a token a layer instead of
@@ -101,11 +125,77 @@ from __future__ import annotations
 import functools
 import hashlib
 import heapq
+import math
 from collections import OrderedDict
 
 from ..base import MXNetError
 
-__all__ = ["PagedKVCache"]
+__all__ = ["PagedKVCache", "kv_pool_shape", "append_rows", "pool_heads",
+           "read_pages", "read_context"]
+
+# a TPU tile's lane count: the last axis of an array at rest is padded to
+# a multiple of it
+_LANES = 128
+
+
+def kv_pool_shape(layers, rows, page_size, num_heads, head_dim):
+    """Shape at rest of a paged K or V pool of ``rows`` pages (the trash
+    page included): heads of whole lane tiles keep their own axis, narrower
+    heads fold into the last one (the module docstring has why)."""
+    lead = (int(layers), int(rows), int(page_size))
+    if head_dim % _LANES == 0:
+        return lead + (int(num_heads), int(head_dim))
+    return lead + (int(num_heads) * int(head_dim),)
+
+
+def append_rows(pools, which, layer, major, minor, rows, kv_quant=""):
+    """Scatter a batch of KV rows into layer ``layer`` of ``pools[which +
+    "_pool"]``, in place in the mapping.  ``which`` is ``"k"`` / ``"v"``
+    (the page pools: ``major`` the pages, ``minor`` the offsets in them)
+    or ``"kw"`` / ``"vw"`` (a windowed layer's per-slot ring, (Lw, S, R,
+    H, D): ``major`` the slots, ``minor`` the ring rows; broadcastable).
+
+    ``rows`` is (..., H, D), one row per token, whatever the pool's
+    layout at rest: they take the shape of the pool's axes past the
+    third.  With ``kv_quant`` each row quantizes independently (codes
+    into the storage pool, one float32 scale per row into the parallel
+    ``which + "_scale"`` pool), so a page's or ring's bytes are a pure
+    function of the tokens written to it — the property that keeps
+    prefill scatter, serial decode append, batched verify append,
+    prefix-hit replay, COW and preempt/re-prefill byte-identical.
+    """
+    name = which + "_pool"
+    pool = pools[name]
+    if kv_quant:
+        from .. import quantize as _q
+
+        rows, scales = _q.kv_quantize_rows(rows, kv_quant)
+        pools[which + "_scale"] = \
+            pools[which + "_scale"].at[layer, major, minor].set(scales)
+    rows = rows.reshape(rows.shape[:-2] + pool.shape[3:])
+    pools[name] = pool.at[layer, major, minor].set(rows.astype(pool.dtype))
+
+
+def pool_heads(pool, head_dim):
+    """Heads of ``head_dim`` a row of a K or V pool holds."""
+    return math.prod(pool.shape[3:]) // head_dim
+
+
+def read_pages(pool, layer, pages, head_dim):
+    """Pages ``pages`` (an int array of any shape) of layer ``layer`` of a
+    K or V pool, read where they lie -> pages.shape + (page_size, H, D)."""
+    return pool[layer, pages].reshape(
+        pages.shape + (pool.shape[2], -1, head_dim))
+
+
+def read_context(pool, layer, tables, head_dim):
+    """A slot's whole page table as one context for
+    ``ops.attention.decode_attention``: ``tables`` (max_pages,) or
+    (S, max_pages) -> (1 or S, H, max_pages * page_size, D)."""
+    n = 1 if tables.ndim == 1 else tables.shape[0]
+    return pool[layer][tables].reshape(
+        n, tables.shape[-1] * pool.shape[2], -1, head_dim
+    ).transpose(0, 2, 1, 3)
 
 
 def _chain_key(prev_key, page_tokens):
@@ -218,8 +308,9 @@ class PagedKVCache:
             dtype = jnp.dtype(_quantize.quant_dtype(self.kv_quant))
         else:
             dtype = dtype or jnp.float32
-        pool_shape = (max(self.n_full, 1), self.num_pages + 1,
-                      self.page_size, self.num_heads, self.head_dim)
+        pool_shape = kv_pool_shape(max(self.n_full, 1), self.num_pages + 1,
+                                   self.page_size, self.num_heads,
+                                   self.head_dim)
         # name -> device array: ALL the cache's device state, and the one
         # pytree every serve executable takes and returns
         self.pools = {}
@@ -276,6 +367,15 @@ class PagedKVCache:
     def table_width(self):
         """Page-table columns: reservable pages + the all-trash pad."""
         return self.max_pages_per_slot + self.table_pad
+
+    @property
+    def kv_lanes(self):
+        """Width of the K/V pools' last axis at rest: ``head_dim`` where a
+        head fills whole lane tiles, ``num_heads * head_dim`` where the
+        heads fold into it (:func:`kv_pool_shape`); ``None`` for a cache
+        with no K/V pool (a latent one)."""
+        pool = self.pools.get("k_pool")
+        return None if pool is None else int(pool.shape[-1])
 
     # -- capacity ---------------------------------------------------------
     @property

@@ -43,6 +43,7 @@ import importlib
 from ..base import MXNetError, get_env
 from ..ops.attention import (decode_attention, flash_attention,
                              paged_decode_attention)
+from .kv_cache import append_rows, read_context
 
 __all__ = ["ModelConfig", "BLOCKS", "block_of", "exact_mode", "init_params",
            "config_from_params", "full_forward", "prefill_forward",
@@ -431,32 +432,6 @@ def _attn_heads(x, n, t, h, d):
     return x.reshape(n, t, h, d).transpose(0, 2, 1, 3)
 
 
-def _append(pools, which, i, major, minor, rows, kv_quant):
-    """Scatter a batch of KV rows into layer ``i`` of ``pools[which +
-    "_pool"]``, in place in the mapping.  ``which`` is ``"k"`` / ``"v"``
-    (the page pools: ``major`` the pages, ``minor`` the offsets in them)
-    or ``"kw"`` / ``"vw"`` (a windowed layer's per-slot ring, (Lw, S, R,
-    H, D): ``major`` the slots, ``minor`` the ring rows; broadcastable).
-
-    ``rows`` is (..., H, D) — one row per token.  With ``kv_quant`` each
-    row quantizes independently (codes into the storage pool, one
-    float32 scale per row into the parallel ``which + "_scale"`` pool),
-    so a page's or ring's bytes are a pure function of the tokens
-    written to it — the property that keeps prefill scatter, serial
-    decode append, batched verify append, prefix-hit replay, COW and
-    preempt/re-prefill byte-identical.
-    """
-    name = which + "_pool"
-    if kv_quant:
-        from .. import quantize as _q
-
-        rows, scales = _q.kv_quantize_rows(rows, kv_quant)
-        pools[which + "_scale"] = \
-            pools[which + "_scale"].at[i, major, minor].set(scales)
-    pools[name] = pools[name].at[i, major, minor].set(
-        rows.astype(pools[name].dtype))
-
-
 def _kv_fake_quant(k, v, kv_quant):
     """Reference-side half of the per-precision bit-exactness oracle:
     quantize-dequantize the (n, H, T, D) head tensors per token with the
@@ -731,10 +706,10 @@ def prefill_forward(params, tokens, length, offset, table_row, pools,
         q, k, v = jnp.split(qkv, 3, axis=-1)
         if kind == "window":
             ring_rows = abs_pos % pools["kw_pool"].shape[2]
-            _append(pools, "kw", wi, slot, ring_rows,
-                    k.reshape(t_b, h, d), kv_quant)
-            _append(pools, "vw", wi, slot, ring_rows,
-                    v.reshape(t_b, h, d), kv_quant)
+            append_rows(pools, "kw", wi, slot, ring_rows, k.reshape(t_b, h, d),
+                        kv_quant)
+            append_rows(pools, "vw", wi, slot, ring_rows, v.reshape(t_b, h, d),
+                        kv_quant)
             pb_max = jnp.atleast_1d((offset + t_b - 1) // page_size)
             ctx_k, ks, kp = _ring_gather(pools, "kw", wi, pb_max,
                                          page_size, slot=slot)
@@ -749,14 +724,12 @@ def prefill_forward(params, tokens, length, offset, table_row, pools,
         else:
             # append the chunk's KV at its absolute rows (one vectorized
             # scatter; only trash rows can collide, nothing reads them)
-            _append(pools, "k", fi, pages, offsets, k.reshape(t_b, h, d),
-                    kv_quant)
-            _append(pools, "v", fi, pages, offsets, v.reshape(t_b, h, d),
-                    kv_quant)
-            ctx_k = pools["k_pool"][fi][table_row].reshape(
-                1, max_pages * page_size, h, d).transpose(0, 2, 1, 3)
-            ctx_v = pools["v_pool"][fi][table_row].reshape(
-                1, max_pages * page_size, h, d).transpose(0, 2, 1, 3)
+            append_rows(pools, "k", fi, pages, offsets, k.reshape(t_b, h, d),
+                        kv_quant)
+            append_rows(pools, "v", fi, pages, offsets, v.reshape(t_b, h, d),
+                        kv_quant)
+            ctx_k = read_context(pools["k_pool"], fi, table_row, d)
+            ctx_v = read_context(pools["v_pool"], fi, table_row, d)
             ks = vs = None
             if kv_quant:
                 ks = pools["k_scale"][fi][table_row].reshape(
@@ -856,10 +829,10 @@ def decode_step(params, tokens, lengths, tables, pools, counters, cfg,
         q, k, v = jnp.split(qkv, 3, axis=-1)
         if kind == "window":
             ring_rows = lengths % pools["kw_pool"].shape[2]
-            _append(pools, "kw", wi, slot_ids, ring_rows,
-                    k.reshape(s, h, d), kv_quant)
-            _append(pools, "vw", wi, slot_ids, ring_rows,
-                    v.reshape(s, h, d), kv_quant)
+            append_rows(pools, "kw", wi, slot_ids, ring_rows,
+                        k.reshape(s, h, d), kv_quant)
+            append_rows(pools, "vw", wi, slot_ids, ring_rows,
+                        v.reshape(s, h, d), kv_quant)
             pb_max = lengths // page_size
             ctx_k, ks, kp = _ring_gather(pools, "kw", wi, pb_max,
                                          page_size)
@@ -874,10 +847,10 @@ def decode_step(params, tokens, lengths, tables, pools, counters, cfg,
         else:
             # append this token's KV at (page, offset); inactive slots
             # write the trash page (their table rows are all-trash)
-            _append(pools, "k", fi, page, offset, k.reshape(s, h, d),
-                    kv_quant)
-            _append(pools, "v", fi, page, offset, v.reshape(s, h, d),
-                    kv_quant)
+            append_rows(pools, "k", fi, page, offset, k.reshape(s, h, d),
+                        kv_quant)
+            append_rows(pools, "v", fi, page, offset, v.reshape(s, h, d),
+                        kv_quant)
             # read the pages where they lie, up to the longest context
             att = paged_decode_attention(
                 q.reshape(s, h, 1, d), pools["k_pool"], pools["v_pool"],
@@ -987,8 +960,8 @@ def verify_step(params, tokens, lengths, tables, pools, counters, cfg,
             ring_tokens = pools["kw_pool"].shape[2]
             for j in range(w):
                 rr = abs_pos[:, j] % ring_tokens
-                _append(pools, "kw", wi, slot_ids, rr, k[:, j], kv_quant)
-                _append(pools, "vw", wi, slot_ids, rr, v[:, j], kv_quant)
+                append_rows(pools, "kw", wi, slot_ids, rr, k[:, j], kv_quant)
+                append_rows(pools, "vw", wi, slot_ids, rr, v[:, j], kv_quant)
             pb_max = (lengths + w - 1) // page_size
             ctx_k, ks, kp = _ring_gather(pools, "kw", wi, pb_max,
                                          page_size)
@@ -999,14 +972,12 @@ def verify_step(params, tokens, lengths, tables, pools, counters, cfg,
             wi += 1
         else:
             for j in range(w):
-                _append(pools, "k", fi, pages[:, j], offsets[:, j],
-                        k[:, j], kv_quant)
-                _append(pools, "v", fi, pages[:, j], offsets[:, j],
-                        v[:, j], kv_quant)
-            ctx_k = pools["k_pool"][fi][tables].reshape(
-                s, max_pages * page_size, h, d).transpose(0, 2, 1, 3)
-            ctx_v = pools["v_pool"][fi][tables].reshape(
-                s, max_pages * page_size, h, d).transpose(0, 2, 1, 3)
+                append_rows(pools, "k", fi, pages[:, j], offsets[:, j],
+                            k[:, j], kv_quant)
+                append_rows(pools, "v", fi, pages[:, j], offsets[:, j],
+                            v[:, j], kv_quant)
+            ctx_k = read_context(pools["k_pool"], fi, tables, d)
+            ctx_v = read_context(pools["v_pool"], fi, tables, d)
             ks = vs = kp = None
             if kv_quant:
                 ks = pools["k_scale"][fi][tables].reshape(

@@ -1136,10 +1136,16 @@ class InferenceSession(object):
         every full-attention layer runs, ends, to within the few pages
         that complete its last iteration), ``blocks_capacity`` = steps x
         the table's width, what a reader that ignores the lengths would
-        visit, and ``visited_share`` their ratio.  ``None`` for a block
-        whose decode step has no such reader (the latent block)."""
-        return self.block.decode_report(self._decode_stats,
-                                        self.cache.table_width)
+        visit, and ``visited_share`` their ratio; ``kv_lanes`` the width
+        of the K/V pools' last axis at rest, which says whether the cache
+        folded the heads into it (:attr:`PagedKVCache.kv_lanes`).
+        ``None`` for a block whose decode step has no such reader (the
+        latent block)."""
+        rep = self.block.decode_report(self._decode_stats,
+                                       self.cache.table_width)
+        if rep is not None:
+            rep["kv_lanes"] = self.cache.kv_lanes
+        return rep
 
     def block_report(self):
         """What the block's executables counted on the device since the

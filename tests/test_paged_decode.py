@@ -13,6 +13,7 @@ import pytest
 from mxnet_tpu import quantize, serve
 from mxnet_tpu.ops import attention
 from mxnet_tpu.ops.attention import decode_attention, paged_decode_attention
+from mxnet_tpu.serve import kv_cache
 from mxnet_tpu.serve import model as serve_model
 
 from closeness import assert_close_across_executables
@@ -34,19 +35,25 @@ LENGTHS = {
 }
 
 
-def _pools(rs, kv_quant=""):
+def _pools(rs, kv_quant="", layout="heads"):
     """K and V pools of LAYERS layers whose trash page is NaN-free
     garbage, page tables that map only the pages a length needs (the
-    rest name the trash page, as the cache does), and one query row."""
+    rest name the trash page, as the cache does), and one query row.
+    ``layout``: the heads on an axis of their own, or ``"folded"`` into
+    the last one, as the cache lays out heads of 8 at rest."""
     shape = (LAYERS, TRASH + 1, PAGE, H, D)
-    k = rs.randn(*shape).astype(np.float32)
-    v = rs.randn(*shape).astype(np.float32)
-    q = rs.randn(S, H, 1, D).astype(np.float32)
-    if not kv_quant:
-        return jnp.asarray(q), jnp.asarray(k), jnp.asarray(v), None, None
-    kq, ks = quantize.kv_quantize_rows(jnp.asarray(k), kv_quant)
-    vq, vs = quantize.kv_quantize_rows(jnp.asarray(v), kv_quant)
-    return jnp.asarray(q), kq, vq, ks, vs
+    k = jnp.asarray(rs.randn(*shape).astype(np.float32))
+    v = jnp.asarray(rs.randn(*shape).astype(np.float32))
+    q = jnp.asarray(rs.randn(S, H, 1, D).astype(np.float32))
+    ks = vs = None
+    if kv_quant:
+        k, ks = quantize.kv_quantize_rows(k, kv_quant)
+        v, vs = quantize.kv_quantize_rows(v, kv_quant)
+    if layout == "folded":
+        at_rest = kv_cache.kv_pool_shape(*shape)
+        assert at_rest == shape[:3] + (H * D,)
+        k, v = k.reshape(at_rest), v.reshape(at_rest)
+    return q, k, v, ks, vs
 
 
 def _tables(rs, lengths):
@@ -75,16 +82,19 @@ def _paged(q, k, v, ks, vs, tables, lengths, mi):
                                   mi=mi, k_scale=ks, v_scale=vs)
 
 
+@pytest.mark.parametrize("layout", ["heads", "folded"])
 @pytest.mark.parametrize("kv_quant", ["", "int8"])
 @pytest.mark.parametrize("mi", [True, False])
 @pytest.mark.parametrize("case", sorted(LENGTHS))
-def test_paged_reader_equals_gathered_reader_bit_for_bit(case, mi, kv_quant):
+def test_paged_reader_equals_gathered_reader_bit_for_bit(case, mi, kv_quant,
+                                                         layout):
     """Skipping the blocks every slot masks, and reading the rest from
     the pool, changes no bit: lengths that end mid-page, on a page
     boundary, at 1, at the table's capacity, an idle slot beside a full
-    one, float32 pages and quantized ones with their scales."""
+    one, float32 pages and quantized ones with their scales, the heads on
+    their own axis or folded into the last."""
     rs = np.random.RandomState(7)
-    q, k, v, ks, vs = _pools(rs, kv_quant)
+    q, k, v, ks, vs = _pools(rs, kv_quant, layout)
     lengths = jnp.asarray(LENGTHS[case], jnp.int32)
     tables = _tables(rs, LENGTHS[case])
     want = jax.jit(_gathered, static_argnames="mi")(
@@ -169,6 +179,91 @@ def test_reader_takes_a_groups_query_heads_as_rows_and_no_other_head_count():
     with pytest.raises(MXNetError, match="the pool's %d heads" % k.shape[-2]):
         paged_decode_attention(jnp.concatenate([q, q], axis=1), k, v, LAYER,
                                tables, lengths, PAGE)
+
+
+# ---------------------------------------------------------------------------
+# the pools' layout at rest: the cache's, and no result knows it
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("head_dim, folds", [(32, True), (64, True),
+                                             (128, False), (256, False)])
+def test_heads_narrower_than_a_lane_tile_fold_into_the_last_axis(head_dim,
+                                                                 folds):
+    shape = kv_cache.kv_pool_shape(4, 769, 16, 8, head_dim)
+    assert shape == ((4, 769, 16, 8 * head_dim) if folds
+                     else (4, 769, 16, 8, head_dim))
+    cache = kv_cache.PagedKVCache(4, 8, head_dim, 16, 6, 2, 3)
+    assert cache.pools["k_pool"].shape == cache.pools["v_pool"].shape \
+        == kv_cache.kv_pool_shape(4, 7, 16, 8, head_dim)
+    assert cache.kv_lanes == (8 * head_dim if folds else head_dim)
+    assert kv_cache.pool_heads(cache.pools["k_pool"], head_dim) == 8
+
+
+def test_a_cache_without_a_kv_pool_names_no_lane_width():
+    cache = kv_cache.PagedKVCache(4, 8, 64, 16, 6, 2, 3, latent_dim=576)
+    assert cache.kv_lanes is None and "k_pool" not in cache.pools
+
+
+@pytest.mark.parametrize("kv_quant", ["", "int8"])
+@pytest.mark.parametrize("rows", [1, 4], ids=["ungrouped", "grouped"])
+@pytest.mark.parametrize("head_dim", [32, 64])
+def test_append_and_read_over_a_folded_pool_equal_the_heads_layout(
+        head_dim, rows, kv_quant):
+    """The decode step's two halves, ``append_rows`` at (page, offset)
+    and ``paged_decode_attention``, over the cache's folded pools and
+    over the same pools with the heads on their own axis: the same pools
+    afterwards and the same attention, bit for bit; one query row a head
+    or a group's four, float32 pages or quantized ones with their
+    scales."""
+    heads = 2
+    rs = np.random.RandomState(11)
+    shape = (LAYERS, TRASH + 1, PAGE, heads, head_dim)
+    folded = kv_cache.kv_pool_shape(*shape)
+    assert folded == shape[:3] + (heads * head_dim,)
+    lengths = np.asarray(LENGTHS["mid_page"], np.int32)
+    tables = _tables(rs, LENGTHS["mid_page"])
+    new_k, new_v = (jnp.asarray(rs.randn(S, heads, head_dim), jnp.float32)
+                    for _ in "kv")
+    q = jnp.asarray(rs.randn(S, heads, rows, head_dim), jnp.float32)
+    filled = {}
+    for which in "kv":
+        pool = jnp.asarray(rs.randn(*shape).astype(np.float32))
+        if kv_quant:
+            pool, filled[which + "_scale"] = quantize.kv_quantize_rows(
+                pool, kv_quant)
+        filled[which + "_pool"] = pool
+    # the row each slot appends: its last valid one
+    page = jnp.take_along_axis(
+        tables, jnp.asarray((lengths - 1) // PAGE)[:, None], axis=1)[:, 0]
+    offset = jnp.asarray((lengths - 1) % PAGE)
+
+    @jax.jit
+    def step(pools):
+        pools = dict(pools)
+        kv_cache.append_rows(pools, "k", LAYER, page, offset, new_k,
+                             kv_quant)
+        kv_cache.append_rows(pools, "v", LAYER, page, offset, new_v,
+                             kv_quant)
+        return pools, paged_decode_attention(
+            q, pools["k_pool"], pools["v_pool"], LAYER, tables,
+            jnp.asarray(lengths), PAGE, mi=True,
+            k_scale=pools.get("k_scale"), v_scale=pools.get("v_scale"))
+
+    want_pools, want = step(filled)
+    got_pools, got = step({
+        name: pool.reshape(folded) if name.endswith("_pool") else pool
+        for name, pool in filled.items()})
+    assert got_pools["k_pool"].shape == folded
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    for name, pool in want_pools.items():
+        np.testing.assert_array_equal(
+            np.asarray(got_pools[name]).reshape(pool.shape),
+            np.asarray(pool))
+    if not kv_quant:   # and the appended rows are the rows handed in
+        np.testing.assert_array_equal(
+            np.asarray(got_pools["k_pool"][LAYER, page, offset]).reshape(
+                new_k.shape), np.asarray(new_k))
 
 
 # ---------------------------------------------------------------------------
@@ -269,6 +364,10 @@ def test_decode_report_of_a_fresh_session_is_zero():
                               max_new=8, exact=True)
     sess = serve.InferenceSession(serve_model.init_params(CFG, seed=3),
                                   num_heads=CFG.num_heads, config=sconf)
-    assert sess.decode_report() == {"steps": 0, "blocks_visited": 0,
-                                    "blocks_capacity": 0,
-                                    "visited_share": 0.0}
+    assert sess.decode_report() == {
+        "steps": 0, "blocks_visited": 0, "blocks_capacity": 0,
+        "visited_share": 0.0,
+        # two heads of 16 fold into the pools' last axis
+        "kv_lanes": CFG.num_heads * CFG.head_dim}
+    assert sess.cache.kv_lanes == 32
+    assert sess.cache.pools["k_pool"].ndim == 4

@@ -45,6 +45,7 @@ from mxnet_tpu.base import MXNetError
 from mxnet_tpu.ops import mamba2
 from mxnet_tpu.serve import granite_hybrid
 from mxnet_tpu.serve import model as serve_model
+from mxnet_tpu.serve.kv_cache import kv_pool_shape
 from mxnet_tpu.serve.scheduler import Request, Scheduler
 
 from closeness import (LIMIT_SPACINGS, assert_close_across_executables,
@@ -405,7 +406,11 @@ def test_sixteen_slots_turn_over_under_the_scheduler(params):
 def test_pools_are_pages_for_attention_and_state_for_mamba(plain):
     cache, conf = plain.cache, plain.config
     pages = conf.slots * conf.max_pages_per_slot
-    kv = (1, pages + 1, PAGE, HF["num_key_value_heads"], 16)
+    # key/value heads of 16 are narrower than a lane tile: the cache
+    # folds them into the pools' last axis
+    kv = kv_pool_shape(1, pages + 1, PAGE, HF["num_key_value_heads"], 16)
+    assert kv == (1, pages + 1, PAGE, HF["num_key_value_heads"] * 16)
+    assert cache.kv_lanes == plain.decode_report()["kv_lanes"] == kv[-1]
     assert {n: tuple(p.shape) for n, p in cache.pools.items()} == {
         "k_pool": kv, "v_pool": kv,
         "ssm_state": (MAMBA_LAYERS, conf.slots, 8, 16, 16),

@@ -421,7 +421,8 @@ def _gpt2_session():
                                  max_new=8))
     assert sess.moe_report() is None and sess.counters == {}
     assert sess.cache.pool_bytes() == 2 * 2 * 10 * PAGE * 2 * 16 * 4
-    pool = jax.ShapeDtypeStruct((2, 3 * 3 + 1, PAGE, 2, 16), jnp.float32)
+    # two heads of 16 fold into the pools' last axis (kv_cache.py)
+    pool = jax.ShapeDtypeStruct((2, 3 * 3 + 1, PAGE, 2 * 16), jnp.float32)
     return sess, 3, ["decode", "prefill_16", "prefill_8"], (pool, pool)
 
 

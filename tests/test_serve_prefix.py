@@ -10,6 +10,7 @@ import pytest
 from mxnet_tpu import serve
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.serve import model as serve_model
+from mxnet_tpu.serve import kv_cache
 from mxnet_tpu.serve.kv_cache import PagedKVCache
 from mxnet_tpu.serve.scheduler import Request, Scheduler, summarize
 from mxnet_tpu.testing import faults
@@ -267,6 +268,51 @@ def test_cow_divergence_never_mutates_shared_page(prefix_session):
                 sess.params, seq, CFG, PAGE, exact=True))
             assert_close_across_executables(logits[slot], ref)
             seq.append(toks[slot])
+
+
+@pytest.mark.parametrize("head_dim, lanes", [(16, 32), (128, 128)],
+                         ids=["folded", "heads"])
+def test_cow_copies_a_page_whatever_the_pools_layout(head_dim, lanes):
+    """Copy-on-write copies axis 1, the page, which both layouts of the
+    K/V pools share: heads of 16 folded into a last axis of 32, heads of
+    128 on an axis of their own.  The private page reads back, as
+    (page_size, heads, head_dim) rows, what was appended to the shared
+    one, and the shared one is untouched."""
+    import jax.numpy as jnp
+
+    heads, layers = 2, 2
+    cache = PagedKVCache(layers, heads, head_dim, PAGE, 6, 2, 3,
+                         prefix_pages=-1)
+    assert cache.kv_lanes == lanes
+    assert cache.pools["k_pool"].ndim == (4 if lanes != head_dim else 5)
+    tokens = list(range(1, PAGE + 2))
+    sa = cache.alloc(len(tokens), 4, tokens=tokens)
+    page = int(cache.table_row(sa)[0])
+    rs = np.random.RandomState(5)
+    rows = {w: rs.randn(layers, PAGE, heads, head_dim).astype(np.float32)
+            for w in "kv"}
+    pools = dict(cache.pools)
+    for layer in range(layers):
+        for w in "kv":
+            kv_cache.append_rows(pools, w, layer, page, jnp.arange(PAGE),
+                                 jnp.asarray(rows[w][layer]))
+    cache.pools.update(pools)
+    assert cache.register_prefix(sa, tokens) == 1
+    sb = cache.alloc(len(tokens), 4, tokens=tokens)
+    assert cache.cached_len(sb) == PAGE
+    assert int(cache.table_row(sb)[0]) == page       # genuinely shared
+    assert cache.ensure_writable(sb, 0, 1) == 1
+    new_page = int(cache.table_row(sb)[0])
+    assert new_page != page and int(cache.table_row(sa)[0]) == page
+    for w in "kv":
+        for layer in range(layers):
+            for at in (page, new_page):
+                np.testing.assert_array_equal(
+                    np.asarray(kv_cache.read_pages(
+                        cache.pools[w + "_pool"], layer,
+                        jnp.asarray([at]), head_dim))[0],
+                    rows[w][layer])
+    assert cache.prefix_stats["cow_copies"] == 1
 
 
 # ---------------------------------------------------------------------------

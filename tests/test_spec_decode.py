@@ -16,7 +16,7 @@ import pytest
 from mxnet_tpu import serve
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.serve import model as serve_model
-from mxnet_tpu.serve.kv_cache import PagedKVCache
+from mxnet_tpu.serve.kv_cache import PagedKVCache, kv_pool_shape
 from mxnet_tpu.testing import faults
 
 from closeness import LIMIT_SPACINGS, spacings_apart
@@ -182,12 +182,14 @@ def test_spec_pad_pages_config():
 # verify_step exactness: one W-row verify == W serial decode steps
 # ---------------------------------------------------------------------------
 
-def _verify_vs_serial_decode(pool_dtype, position_off=0):
+def _verify_vs_serial_decode(pool_dtype, position_off=0, layout="folded"):
     """Gaps, in spacings, between one batched verify and the serial
     decode trajectory fed the same tokens: (logits, k pool, v pool),
     plus whether verify's greedy row is its own logits' argmax.
     ``position_off`` plants the fault: verify is told a history one
-    row longer than the pools hold."""
+    row longer than the pools hold.  ``layout``: the pools as the cache
+    lays out heads of 8 (``"folded"`` into the last axis), or with the
+    heads on an axis of their own; the step functions take either."""
     import jax
     import jax.numpy as jnp
 
@@ -198,6 +200,9 @@ def _verify_vs_serial_decode(pool_dtype, position_off=0):
     dtype = jnp.dtype(pool_dtype)
     pool_shape = (cfg.num_layers, pages + 1, page, cfg.num_heads,
                   cfg.head_dim)
+    if layout == "folded":
+        pool_shape = kv_pool_shape(*pool_shape)
+        assert len(pool_shape) == 4
     tables = jnp.asarray([[0, 1, 2, pages], [3, 4, 5, pages]], jnp.int32)
 
     def on_pools(step):
@@ -245,14 +250,17 @@ def _verify_vs_serial_decode(pool_dtype, position_off=0):
             spacings_apart(f32(bv), f32(sv), pool_dtype), own_argmax)
 
 
+@pytest.mark.parametrize("layout", ["folded", "heads"])
 @pytest.mark.parametrize("pool_dtype", ["float32", "bfloat16"])
-def test_verify_bitexact_vs_serial_decode(pool_dtype):
-    """The kernel-level contract under both pool precisions: logits AND
+def test_verify_bitexact_vs_serial_decode(pool_dtype, layout):
+    """The kernel-level contract under both pool precisions and both
+    layouts of the pools at rest: logits AND
     the written KV pools from one batched verify match the serial decode
     trajectory fed the same tokens (two executables: 0 spacings read on
     jax 0.9.0, the limit is tests/closeness.py's), and the greedy row is
     the verify logits' own argmax, exactly."""
-    logits, k_gap, v_gap, own_argmax = _verify_vs_serial_decode(pool_dtype)
+    logits, k_gap, v_gap, own_argmax = _verify_vs_serial_decode(
+        pool_dtype, layout=layout)
     assert max(logits, k_gap, v_gap) <= LIMIT_SPACINGS
     assert own_argmax
 

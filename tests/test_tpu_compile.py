@@ -13,13 +13,19 @@ which must read the prediction in place; and the KDA layer's two forms
 at Ling-3.0-flash's widths (plain XLA: the solve's lowering, the
 temporaries, the state updated in place); and the routed-expert layer's
 grouped-matmul kernel over kanana-2's and Ling-3.0-flash's stacks, a
-whole float32 expert a block.  Nothing runs, so nothing here is a result
-or a time — a compile that passes is not a chip run.
+whole float32 expert a block; and a grouped-query attention layer's
+append to and read of the paged K/V pools at granite-4.0-h-micro's pool
+size, which must leave the pools where they lie.  Nothing runs, so
+nothing here is a result or a time — a compile that passes is not a
+chip run.
 
 All of it lives in this one file, and the topology is described inside a
 module-scoped fixture: only the xdist worker that is handed this file
 loads the TPU library, and only once a test of it has started.
 """
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -257,8 +263,6 @@ def test_grouped_swiglu_compiles_for_v5e(one_chip, name, monkeypatch):
     (``vmem_limit_bytes``), the kernel carries its tile in its name, and
     no stack is copied or converted on its way in: the kernel reads the
     float32 matrices where the parameters lie."""
-    import re
-
     from mxnet_tpu.ops.grouped_matmul import kernel_name
     from mxnet_tpu.serve import latent_moe
     from serve_util import expert_layer_config
@@ -289,3 +293,111 @@ def test_grouped_swiglu_compiles_for_v5e(one_chip, name, monkeypatch):
              if re.search(r"= %s\S* (copy|convert|fusion|transpose)\("
                           % stack, line)]
     assert not moved, moved
+
+
+# An attention layer's decode half at granite-4.0-h-micro's pool size (4
+# attention layers, 16 slots x 48 pages + the trash page, pages of 16, 8
+# key/value heads of 64, 32 query heads) and, as the control that always
+# passed, at the dense cell's heads of 128: 16 rows appended to the
+# donated K and V pools, then the paged read.  What is compiled is what
+# ``granite_hybrid.decode_step`` runs a layer, without its weights.
+KV_CASES = {
+    # name: (key/value heads, head width, query heads a key/value head)
+    "granite_heads_of_64": (8, 64, 4),
+    "dense_heads_of_128": (16, 128, 1),
+}
+
+
+def _kv_layer_program(one_chip, pool_shape, heads, head_dim, group):
+    """-> the compiled append + paged read over two donated float32 pools
+    of ``pool_shape``, and one pool's logical bytes."""
+    from mxnet_tpu.ops.attention import paged_decode_attention
+    from mxnet_tpu.serve.kv_cache import append_rows
+
+    slots, max_pages, page = 16, 48, 16
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def layer(pools, q, k, v, tables, lengths):
+        pools = dict(pools)
+        page_slot = jnp.clip(lengths // page, 0, max_pages - 1)
+        at = jnp.take_along_axis(tables, page_slot[:, None], axis=1)[:, 0]
+        append_rows(pools, "k", 1, at, lengths % page, k)
+        append_rows(pools, "v", 1, at, lengths % page, v)
+        return pools, paged_decode_attention(
+            q, pools["k_pool"], pools["v_pool"], 1, tables, lengths + 1,
+            page)
+
+    pool = sds(pool_shape)
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(layer, donate_argnums=0).lower(
+            {"k_pool": pool, "v_pool": pool},
+            sds((slots, heads, group, head_dim)),
+            sds((slots, heads, head_dim)), sds((slots, heads, head_dim)),
+            sds((slots, max_pages), jnp.int32),
+            sds((slots,), jnp.int32)).compile()
+    return compiled, 4 * math.prod(pool_shape)
+
+
+def _whole_pool_copies(text, pool_shape):
+    """-> (copies, prefetches): the lines of a compiled text whose result
+    is a whole pool made by a ``copy`` (or by a fusion the compiler named
+    for one: ``copy_fusion``, ``*.remat_compressed``), and those that
+    only move a pool as it lies into the chip's fast memory
+    (``copy-done`` into ``S(1)``, the same layout on both sides)."""
+    dims = ",".join(str(n) for n in pool_shape)
+    whole = r"\(?f32\[%s\]" % dims
+    copies, prefetches = [], []
+    for line in text.splitlines():
+        if re.search(r"= %s\S* copy\(" % whole, line) or re.search(
+                r"%%\S*(copy|remat_\w*compressed)\S* = %s\S* fusion\("
+                % whole, line):
+            copies.append(line.strip()[:140])
+        elif re.search(r"= %s\S*S\(1\)\} copy-done\(" % whole, line):
+            prefetches.append(line.strip()[:140])
+    return copies, prefetches
+
+
+@pytest.mark.parametrize("name", sorted(KV_CASES))
+def test_kv_append_and_paged_read_leave_the_pools_where_they_lie(one_chip,
+                                                                 name):
+    """The pools' layout at rest is the cache's rule (``kv_pool_shape``):
+    under it the two pools are arguments of their logical size, the result
+    aliases them and no operation of the compiled text copies a whole
+    pool into another layout.  With nothing else in memory the compiler
+    may still park one 100.8 MB pool, as it lies, in the 128 MiB of fast
+    memory for the read loop (the folded V pool here: a ``copy-start`` /
+    ``copy-done`` into ``S(1)``, 101.6 MB of temporaries); the cell's
+    executables, with 12.8 GB of weights to stream, have no such move
+    (read in their text at PR 38: PERF.md), so one is allowed and no
+    more."""
+    from mxnet_tpu.serve.kv_cache import kv_pool_shape
+
+    heads, head_dim, group = KV_CASES[name]
+    shape = kv_pool_shape(4, 16 * 48 + 1, 16, heads, head_dim)
+    compiled, logical = _kv_layer_program(one_chip, shape, heads, head_dim,
+                                          group)
+    memory = compiled.memory_analysis()
+    small = 1 << 20     # q, k, v, tables, lengths and their padding
+    assert 2 * logical <= memory.argument_size_in_bytes < 2 * logical + small
+    assert memory.alias_size_in_bytes >= 2 * logical
+    copies, prefetches = _whole_pool_copies(compiled.as_text(), shape)
+    assert not copies, copies
+    assert len(prefetches) <= 1, prefetches
+    assert memory.temp_size_in_bytes < len(prefetches) * logical + small
+
+
+def test_unfolded_heads_of_64_cost_the_whole_pool(one_chip):
+    """The control of the test above, and why the rule exists: the same
+    program over pools that keep heads of 64 on an axis of their own
+    (the layout before PR 38) pads the pools at rest (117.5 MB for 100.8)
+    and copies them whole around the 16-row append."""
+    heads, head_dim, group = KV_CASES["granite_heads_of_64"]
+    shape = (4, 16 * 48 + 1, 16, heads, head_dim)
+    compiled, logical = _kv_layer_program(one_chip, shape, heads, head_dim,
+                                          group)
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes > 2 * logical + (16 << 20)
+    assert memory.temp_size_in_bytes > 2 * logical
+    assert len(_whole_pool_copies(compiled.as_text(), shape)[0]) >= 4
