@@ -46,6 +46,7 @@ from ..base import MXNetError
 from ..ops.kda import MAX_EXPONENT, kda_chunked, kda_step
 from ..ops.mamba2 import causal_conv, conv_step
 from . import latent_moe
+from .kv_cache import append_latent_rows, read_latent_context
 from .latent_moe import (_LO_BITS, _attend_absorbed, _attend_materialised,
                          _ffn_out, _fold, _head, _prefill_block,
                          _query_and_row, _resolve, _rms_norm, held,
@@ -473,11 +474,9 @@ def prefill_forward(params, tokens, length, offset, table_row, pools,
         else:
             with jax.named_scope("mla_prefill"):
                 q, rows = _query_and_row(params, pre, u, abs_pos, cfg, exact)
-                pool = pools["latent_pool"].at[li, pages, offsets].set(
-                    rows.astype(pools["latent_pool"].dtype))
-                pools["latent_pool"] = pool
-                ctx = pool[li][table_row].reshape(max_pages * page_size,
-                                                  rows.shape[-1])
+                append_latent_rows(pools, li, pages, offsets, rows)
+                ctx = read_latent_context(pools["latent_pool"], li,
+                                          table_row)
                 att = _attend_materialised(params, pre, q, ctx, abs_pos + 1,
                                            cfg, exact, block)
             out = _mm(_head_gate(params, pre, att, u, cfg, exact),
@@ -537,10 +536,8 @@ def decode_step(params, tokens, lengths, tables, pools, counters, cfg,
         else:
             with jax.named_scope("mla_decode"):
                 q, rows = _query_and_row(params, pre, u, lengths, cfg, exact)
-                pool = pools["latent_pool"].at[li, page, offset].set(
-                    rows.astype(pools["latent_pool"].dtype))
-                pools["latent_pool"] = pool
-                ctx = pool[li][tables].reshape(s, t_cap, rows.shape[-1])
+                append_latent_rows(pools, li, page, offset, rows)
+                ctx = read_latent_context(pools["latent_pool"], li, tables)
                 att = _attend_absorbed(params, pre, q, ctx, lengths + 1, cfg,
                                        exact, page_size if exact else t_cap)
             out = _mm(_head_gate(params, pre, att, u, cfg, exact),

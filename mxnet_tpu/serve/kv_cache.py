@@ -39,11 +39,41 @@ A latent-attention model (``latent_dim > 0``, ``serve/latent_moe.py``)
 keeps one row of ``latent_dim`` values a token a layer instead of
 per-head keys and values: ONE pool and no other
 
-    latent_pool : (full layers, num_pages + 1, page_size, latent_dim)
+    latent_pool : (full layers, num_pages + 1, page_size, lanes)
+                  lanes = latent_dim rounded up to a multiple of 128
+
+**The latent pool's layout at rest** (:func:`latent_pool_shape`; THIS
+file is the only place that knows it).  A row of 512 + 64 = 576 values
+(kanana-2, Ling-3.0-flash) is four and a half lane tiles.  Given a pool
+of (..., 16, 576) the compiler does not pad the rows to 640 (11 %): it
+puts the PAGE axis on the lanes (2305 -> 2432 pages pad 5.5 %), and a
+pool that lies page-minor cannot take a row: every executable that
+touches it transposes the WHOLE pool to rows in front of its first
+append and back behind its last, 1.75 GB moved for 16 rows of 2 304
+bytes, ~2.9 ms of every kanana-2 call and ~2.2 ms of every Ling call
+(read in the compiled text and in the traces' ``copy``: PERF.md, PR 41).
+So the row is laid in whole lane tiles here, 640 lanes, the lanes past
+``latent_dim`` zero: the same executables then take the pool row-major,
+update 16 rows in place and hold no whole-pool copy; the pool is 11 %
+larger at rest (+24 MB at kanana-2's size) and a call's temporaries
+0.5 GB smaller.  A width that already fills lane tiles keeps its shape
+letter for letter.  The step functions never index the pool's trailing
+axis: they write rows of ``latent_dim`` values through
+:func:`append_latent_rows` (which writes the pad lanes zero, so they are
+zero wherever a row was ever written, and copy-on-write copies them as
+they are) and read a slot's or every slot's table through
+:func:`read_latent_context`, which gathers the table's pages from the
+pool where it lies (``pool[layer, tables]``: a ``pool[layer][tables]``
+first materialises the layer, 85 MB read and written a layer a call)
+and hands the rows back as wide as the pool keeps them.  The absorbed
+attention gives its query as many zero lanes, which add exactly 0 to a
+score; the materialised one slices what it *gathered*.
+:attr:`PagedKVCache.latent_lanes` says how wide the rows lie.
 
 Pages, tables, reference counts, the prefix index, oversubscription and
-copy-on-write do not know the difference.  Its layer axis counts the
-layers of kind ``"full"`` only, as the K/V pools' does: a stack whose
+copy-on-write index axes 0-2 and do not know the difference.  Its layer
+axis counts the layers of kind ``"full"`` only, as the K/V pools' does: a
+stack whose
 other layers keep slot-private state (``serve/bailing_hybrid.py``: one
 latent-attention layer in six, linear-attention state in the rest) has
 the latent pool for the few and the ``state`` pools below for the many,
@@ -131,7 +161,8 @@ from collections import OrderedDict
 from ..base import MXNetError
 
 __all__ = ["PagedKVCache", "kv_pool_shape", "append_rows", "pool_heads",
-           "read_pages", "read_context"]
+           "read_pages", "read_context", "latent_pool_shape",
+           "append_latent_rows", "read_latent_context"]
 
 # a TPU tile's lane count: the last axis of an array at rest is padded to
 # a multiple of it
@@ -196,6 +227,39 @@ def read_context(pool, layer, tables, head_dim):
     return pool[layer][tables].reshape(
         n, tables.shape[-1] * pool.shape[2], -1, head_dim
     ).transpose(0, 2, 1, 3)
+
+
+def latent_pool_shape(layers, rows, page_size, latent_dim):
+    """Shape at rest of a paged latent pool of ``rows`` pages (the trash
+    page included): a row of ``latent_dim`` values in whole lane tiles,
+    the lanes past ``latent_dim`` zero (the module docstring has why)."""
+    lanes = -(-int(latent_dim) // _LANES) * _LANES
+    return (int(layers), int(rows), int(page_size), lanes)
+
+
+def append_latent_rows(pools, layer, pages, offsets, rows):
+    """Scatter a batch of latent rows (N, latent_dim), one a token, into
+    layer ``layer`` of ``pools["latent_pool"]`` at (``pages``,
+    ``offsets``), in place in the mapping.  The pool's lanes past the
+    row's width are written zero."""
+    import jax.numpy as jnp
+
+    pool = pools["latent_pool"]
+    rows = jnp.pad(rows, ((0, 0), (0, pool.shape[-1] - rows.shape[-1])))
+    pools["latent_pool"] = pool.at[layer, pages, offsets].set(
+        rows.astype(pool.dtype))
+
+
+def read_latent_context(pool, layer, tables):
+    """A slot's whole page table, or every slot's, of layer ``layer`` of
+    a latent pool as rows in position order, gathered from the pool where
+    it lies: ``tables`` (max_pages,) or (S, max_pages) -> (max_pages *
+    page_size, lanes) or (S, max_pages * page_size, lanes).  The rows are
+    as wide as the pool's (the lanes past ``latent_dim`` zero, which add
+    exactly 0 to a score against a query given as many zero lanes)."""
+    return pool[layer, tables].reshape(
+        tables.shape[:-1] + (tables.shape[-1] * pool.shape[2],
+                             pool.shape[3]))
 
 
 def _chain_key(prev_key, page_tokens):
@@ -315,8 +379,8 @@ class PagedKVCache:
         # pytree every serve executable takes and returns
         self.pools = {}
         if self.latent_dim:
-            self.pools["latent_pool"] = jnp.zeros(
-                pool_shape[:3] + (self.latent_dim,), dtype)
+            self.pools["latent_pool"] = jnp.zeros(latent_pool_shape(
+                *pool_shape[:3], self.latent_dim), dtype)
         else:
             self.pools["k_pool"] = jnp.zeros(pool_shape, dtype)
             self.pools["v_pool"] = jnp.zeros(pool_shape, dtype)
@@ -375,6 +439,14 @@ class PagedKVCache:
         heads fold into it (:func:`kv_pool_shape`); ``None`` for a cache
         with no K/V pool (a latent one)."""
         pool = self.pools.get("k_pool")
+        return None if pool is None else int(pool.shape[-1])
+
+    @property
+    def latent_lanes(self):
+        """Width of the latent pool's last axis at rest: ``latent_dim``
+        rounded up to whole lane tiles (:func:`latent_pool_shape`);
+        ``None`` for a cache with no latent pool."""
+        pool = self.pools.get("latent_pool")
         return None if pool is None else int(pool.shape[-1])
 
     # -- capacity ---------------------------------------------------------

@@ -16,7 +16,11 @@ form, and the tests hold this module to it):
   ``c <- RMSNorm(c)``; the rope part of ``q`` and the one shared ``r`` get
   RoPE on interleaved pairs.  **The cache holds ``[c | r]``**, one row of
   ``kv_lora_rank + qk_rope_head_dim`` values a token a layer, in the pages
-  ``PagedKVCache`` hands out, and nothing per head.
+  ``PagedKVCache`` hands out, and nothing per head.  How the rows lie in
+  the pool is ``serve/kv_cache.py``'s (whole lane tiles, the pad lanes
+  zero): this module writes them through ``append_latent_rows`` and reads
+  a table through ``read_latent_context``, and indexes no trailing axis
+  of the pool.
 * Prefill is *materialised*: ``[k_nope_h | v_h] = W_kvb,h c`` for the
   slot's gathered rows (the chunk being fed and whatever it attends to
   from earlier chunks or prefix hits), then attention over heads of
@@ -76,6 +80,7 @@ from __future__ import annotations
 from ..base import MXNetError
 from ..ops.attention import decode_attention
 from ..ops.grouped_matmul import grouped_swiglu, grouped_swiglu_eligible
+from .kv_cache import append_latent_rows, read_latent_context
 from .model import _mm, _resolve_params, check_param_shapes, note_traced
 
 BLOCK = "deepseek_v3"
@@ -357,19 +362,21 @@ def _scale(cfg):
 
 
 def _attend_materialised(params, pre, q, ctx, horizons, cfg, exact, block):
-    """Prefill's form.  q (N, H, nope + rope); ctx (Tc, rank + rope) latent
-    rows in position order; horizons (N,): row j sees ``ctx[:horizons[j]]``.
+    """Prefill's form.  q (N, H, nope + rope); ctx (Tc, rank + rope or
+    wider: what lies past is not read) latent rows in position order;
+    horizons (N,): row j sees ``ctx[:horizons[j]]``.
     K and V are built from the rows for all heads.  -> (N, H * vd)."""
     import jax.numpy as jnp
 
     h, nope, rank = cfg.num_heads, cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    rope = cfg.qk_rope_head_dim
     tc = ctx.shape[0]
     kv = _mm(ctx[:, :rank], params[pre + "kv_b_weight"], exact).reshape(
         tc, h, nope + cfg.v_head_dim)
     k = jnp.concatenate(
         [kv[..., :nope],
-         jnp.broadcast_to(ctx[:, None, rank:],
-                          (tc, h, cfg.qk_rope_head_dim))], axis=-1)
+         jnp.broadcast_to(ctx[:, None, rank:rank + rope], (tc, h, rope))],
+        axis=-1)
     att = decode_attention(
         q.transpose(1, 0, 2)[None], k.transpose(1, 0, 2)[None],
         kv[..., nope:].transpose(1, 0, 2)[None], horizons[None],
@@ -379,15 +386,19 @@ def _attend_materialised(params, pre, q, ctx, horizons, cfg, exact, block):
 
 def _attend_absorbed(params, pre, q, ctx, lengths, cfg, exact, block):
     """Decode's form.  q (S, H, nope + rope), one query a slot; ctx
-    (S, Tc, rank + rope) each slot's gathered rows; lengths (S,) valid
-    rows.  The heads are the query rows of ONE shared key/value head of
-    the latent width.  -> (S, H * vd)."""
+    (S, Tc, rank + rope or wider) each slot's gathered rows, as the pool
+    keeps them: what lies past rank + rope is zero, and the query is
+    given as many zero lanes, which add exactly 0 to a score; lengths
+    (S,) valid rows.  The heads are the query rows of ONE shared
+    key/value head of the latent width.  -> (S, H * vd)."""
     import jax.numpy as jnp
 
     h, nope, rank = cfg.num_heads, cfg.qk_nope_head_dim, cfg.kv_lora_rank
     w = params[pre + "kv_b_weight"].reshape(h, nope + cfg.v_head_dim, rank)
     q_lat = jnp.einsum("shn,hnc->shc", q[..., :nope], w[:, :nope])
     q_abs = jnp.concatenate([q_lat, q[..., nope:]], axis=-1)
+    q_abs = jnp.pad(q_abs, ((0, 0), (0, 0),
+                            (0, ctx.shape[-1] - q_abs.shape[-1])))
     att = decode_attention(q_abs[:, None], ctx[:, None],
                            ctx[:, None, :, :rank], lengths,
                            scale=_scale(cfg), block=block, mi=exact)
@@ -636,8 +647,8 @@ def prefill_forward(params, tokens, length, offset, table_row, pools,
         raise MXNetError("bucket length %d not a multiple of page size %d"
                          % (t_b, page_size))
     max_pages = table_row.shape[0]
-    latent_pool = pools["latent_pool"]
-    trash = latent_pool.shape[1] - 1
+    pools = dict(pools)
+    trash = pools["latent_pool"].shape[1] - 1
     offs = jnp.arange(t_b, dtype=jnp.int32)
     abs_pos = offset + offs
     idx = abs_pos // page_size
@@ -655,10 +666,8 @@ def prefill_forward(params, tokens, length, offset, table_row, pools,
             u = _rms_norm(x, params[pre + "attn_norm_gamma"],
                           cfg.rms_norm_eps)
             q, rows = _query_and_row(params, pre, u, abs_pos, cfg, exact)
-            latent_pool = latent_pool.at[i, pages, offsets].set(
-                rows.astype(latent_pool.dtype))
-            ctx = latent_pool[i][table_row].reshape(
-                max_pages * page_size, rows.shape[-1])
+            append_latent_rows(pools, i, pages, offsets, rows)
+            ctx = read_latent_context(pools["latent_pool"], i, table_row)
             att = _attend_materialised(params, pre, q, ctx, abs_pos + 1,
                                        cfg, exact, block)
             x = x + _mm(att, params[pre + "o_weight"], exact)
@@ -667,8 +676,8 @@ def prefill_forward(params, tokens, length, offset, table_row, pools,
             incs.append(inc)
     last = _head(params, jnp.take(x, length - 1, axis=0), cfg, exact)
     first_token = jnp.argmax(last, axis=-1).astype(jnp.int32)
-    return (first_token, last, dict(pools, latent_pool=latent_pool),
-            _stats_after(counters, incs, decode=False))
+    return first_token, last, pools, _stats_after(counters, incs,
+                                                  decode=False)
 
 
 def decode_step(params, tokens, lengths, tables, pools, counters, cfg,
@@ -684,7 +693,7 @@ def decode_step(params, tokens, lengths, tables, pools, counters, cfg,
     params, dequantized = _resolve(params)
     s = tokens.shape[0]
     max_pages = tables.shape[1]
-    latent_pool = pools["latent_pool"]
+    pools = dict(pools)
     t_cap = max_pages * page_size
     x = jnp.take(params["tok_embed_weight"], tokens.astype(jnp.int32),
                  axis=0)
@@ -699,9 +708,8 @@ def decode_step(params, tokens, lengths, tables, pools, counters, cfg,
             u = _rms_norm(x, params[pre + "attn_norm_gamma"],
                           cfg.rms_norm_eps)
             q, rows = _query_and_row(params, pre, u, lengths, cfg, exact)
-            latent_pool = latent_pool.at[i, page, offset].set(
-                rows.astype(latent_pool.dtype))
-            ctx = latent_pool[i][tables].reshape(s, t_cap, rows.shape[-1])
+            append_latent_rows(pools, i, page, offset, rows)
+            ctx = read_latent_context(pools["latent_pool"], i, tables)
             att = _attend_absorbed(params, pre, q, ctx, lengths + 1, cfg,
                                    exact, page_size if exact else t_cap)
             x = x + _mm(att, params[pre + "o_weight"], exact)
@@ -710,5 +718,5 @@ def decode_step(params, tokens, lengths, tables, pools, counters, cfg,
             incs.append(inc)
     logits = _head(params, x, cfg, exact)
     next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return (next_tokens, logits, dict(pools, latent_pool=latent_pool),
-            _stats_after(counters, incs, decode=True))
+    return next_tokens, logits, pools, _stats_after(counters, incs,
+                                                    decode=True)

@@ -1165,6 +1165,9 @@ class InferenceSession(object):
         a device count: the expert layers of the decode executable that
         were traced with the grouped-matmul kernel
         (``ops/grouped_matmul.py``), 0 where the ``fori_loop`` runs.
+        ``latent_lanes`` (both blocks with a latent pool) is not one
+        either: the width of the pool's rows at rest, whole lane tiles
+        (:attr:`PagedKVCache.latent_lanes`).
 
         The Mamba-2 / grouped-query block: ``decode_steps``,
         ``prefill_chunks``, ``rows_valid`` and ``rows_padded`` (the rows
@@ -1176,6 +1179,8 @@ class InferenceSession(object):
         rep = self.block.report(self.counters, self.model)
         if rep is not None:
             rep.update(self._exes["decode"].traced)
+            if self.cache.latent_lanes is not None:
+                rep["latent_lanes"] = self.cache.latent_lanes
         return rep
 
     moe_report = block_report   # the name it had while only routers counted

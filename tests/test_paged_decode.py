@@ -202,6 +202,13 @@ def test_heads_narrower_than_a_lane_tile_fold_into_the_last_axis(head_dim,
 def test_a_cache_without_a_kv_pool_names_no_lane_width():
     cache = kv_cache.PagedKVCache(4, 8, 64, 16, 6, 2, 3, latent_dim=576)
     assert cache.kv_lanes is None and "k_pool" not in cache.pools
+    assert cache.latent_lanes == 640
+
+
+@pytest.mark.parametrize("head_dim", [64, 128])
+def test_a_cache_without_a_latent_pool_names_no_latent_width(head_dim):
+    cache = kv_cache.PagedKVCache(4, 8, head_dim, 16, 6, 2, 3)
+    assert cache.latent_lanes is None and "latent_pool" not in cache.pools
 
 
 @pytest.mark.parametrize("kv_quant", ["", "int8"])

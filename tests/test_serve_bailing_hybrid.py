@@ -42,7 +42,7 @@ from mxnet_tpu.base import MXNetError
 from mxnet_tpu.ops import kda
 from mxnet_tpu.serve import bailing_hybrid, latent_moe
 from mxnet_tpu.serve import model as serve_model
-from mxnet_tpu.serve.kv_cache import PagedKVCache
+from mxnet_tpu.serve.kv_cache import PagedKVCache, latent_pool_shape
 from mxnet_tpu.serve.scheduler import Request, Scheduler
 
 from closeness import (LIMIT_SPACINGS, assert_close_across_executables,
@@ -103,6 +103,7 @@ def model_config(hf):
 
 CFG = model_config(HF)
 KDA_LAYERS = CFG.layer_types.count("kda")
+ROW = bailing_hybrid.latent_dim(CFG)     # a latent row: rank + rope
 EXPERT_LAYERS = HF["num_hidden_layers"] - HF["first_k_dense_replace"]
 
 
@@ -507,6 +508,26 @@ def test_scheduler_serves_and_the_block_counts(params):
     assert sess.decode_report() is None and sess.fallback_count() == 0
 
 
+@pytest.mark.parametrize("upto", ["prefill", "decode", "release"])
+def test_the_latent_pools_pad_lanes_stay_zero(plain, upto):
+    """The one latent layer's rows are ROW values in a lane tile of 128:
+    what lies past them is zero after a prefill, after decode steps (all
+    slots' rows, idle ones too) and after the slot's release, and the
+    block reports the width the pool took."""
+    slot = plain.try_alloc(19, 8, tokens=tokens(90, 19))
+    plain.prefill(slot, tokens(90, 19))
+    if upto != "prefill":
+        for _ in range(3):
+            plain.step()
+    if upto == "release":
+        plain.release(slot)
+    pool = np.asarray(plain.cache.pools["latent_pool"])
+    assert pool.shape[-1] == plain.block_report()["latent_lanes"] \
+        == plain.cache.latent_lanes == 128 > ROW
+    assert float(np.abs(pool[..., ROW:]).max()) == 0.0
+    assert float(np.abs(pool[..., :ROW]).max()) > 0.0
+
+
 def test_on_the_cpu_the_expert_layers_run_the_loop(plain, params,
                                                    monkeypatch):
     """The predicate beside the kernel says "loop" here (the backend, and
@@ -557,8 +578,11 @@ def _cache(**over):
 def test_a_latent_pool_beside_state_pools():
     cache = _cache()
     assert {n: tuple(p.shape) for n, p in cache.pools.items()} == {
-        "latent_pool": (1, 13, 8, 32),          # the one "full" layer
+        # the one "full" layer; a row of 32 values in one lane tile
+        "latent_pool": latent_pool_shape(1, 13, 8, 32),
         "kda_state": (3, 3, 4, 16, 16), "conv_state": (3, 3, 3, 192)}
+    assert cache.pools["latent_pool"].shape[-1] == cache.latent_lanes == 128
+    assert cache.kv_lanes is None
     assert cache.paged == ("latent_pool",)
     assert cache.state == ("kda_state", "conv_state") and cache.hybrid
     assert cache.pages_needed(20, 8) == 4

@@ -19,7 +19,7 @@ import pytest
 
 from mxnet_tpu import serve
 from mxnet_tpu.base import MXNetError
-from mxnet_tpu.serve import latent_moe
+from mxnet_tpu.serve import kv_cache, latent_moe
 from mxnet_tpu.serve import model as serve_model
 from mxnet_tpu.serve.scheduler import Request, Scheduler
 
@@ -179,6 +179,12 @@ def test_pool_names_are_the_caches_alone():
         serve.init_params(LATENT, seed=5), model=LATENT,
         config=serve.ServeConfig(**CONF))
     assert list(sess.cache.pools) == ["latent_pool"]
+    # 12 + 4 values a row, in one lane tile (kv_cache.latent_pool_shape)
+    assert sess.cache.pools["latent_pool"].shape \
+        == kv_cache.latent_pool_shape(2, 3 * 3 + 1, 8, 16) \
+        == (2, 10, 8, 128)
+    assert sess.block_report()["latent_lanes"] == sess.cache.latent_lanes \
+        == 128
     assert list(sess.counters) == ["moe_stats"]
     assert sess.counters["moe_stats"].shape == (
         2, latent_moe.stats_size(LATENT))
@@ -195,7 +201,10 @@ def test_a_latent_pool_and_state_pools_in_one_session():
         config=serve.ServeConfig(**CONF))
     assert sorted(sess.cache.pools) == ["conv_state", "kda_state",
                                         "latent_pool"]
-    assert sess.cache.pools["latent_pool"].shape[0] == 1
+    assert sess.cache.pools["latent_pool"].shape \
+        == kv_cache.latent_pool_shape(1, 3 * 3 + 1, 8, 16)
+    assert sess.block_report()["latent_lanes"] == sess.cache.latent_lanes \
+        == 128
     assert sess.cache.pools["kda_state"].shape[:2] == (3, CONF["slots"])
     assert sess.cache.paged == ("latent_pool",) and sess.cache.hybrid
     assert list(sess.counters) == ["moe_stats"]

@@ -36,7 +36,7 @@ import pytest
 from mxnet_tpu import serve
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.compile_cache import signature_of
-from mxnet_tpu.serve import latent_moe
+from mxnet_tpu.serve import kv_cache, latent_moe
 from mxnet_tpu.serve import model as serve_model
 from mxnet_tpu.serve.scheduler import Request, Scheduler
 
@@ -84,6 +84,7 @@ def model_config(hf):
 CFG = model_config(HF)
 EXPERT_LAYERS = HF["num_hidden_layers"] - HF["first_k_dense_replace"]
 ROW = HF["kv_lora_rank"] + HF["qk_rope_head_dim"]
+LANES = 128     # what a row of ROW values occupies in the pool at rest
 
 
 @functools.lru_cache(maxsize=None)
@@ -339,11 +340,110 @@ def test_pool_is_one_latent_pool(plain):
     cache, conf = plain.cache, plain.config
     pages = conf.slots * conf.max_pages_per_slot
     assert list(cache.pools) == ["latent_pool"]
-    assert cache.pools["latent_pool"].shape == (
-        HF["num_hidden_layers"], pages + 1, PAGE, ROW)
+    # a row of 40 values lies in one whole lane tile (kv_cache.py)
+    assert cache.pools["latent_pool"].shape == kv_cache.latent_pool_shape(
+        HF["num_hidden_layers"], pages + 1, PAGE, ROW) == (
+        HF["num_hidden_layers"], pages + 1, PAGE, LANES)
+    assert cache.latent_lanes == plain.block_report()["latent_lanes"] \
+        == LANES
+    assert cache.kv_lanes is None and plain.decode_report() is None
     assert cache.pool_bytes() == plain.state_report()["pool_bytes"] \
-        == HF["num_hidden_layers"] * (pages + 1) * PAGE * ROW * 4
+        == HF["num_hidden_layers"] * (pages + 1) * PAGE * LANES * 4
     assert plain.moe_report()["expert_load"].shape == (2, 8)
+
+
+@pytest.mark.parametrize("width, lanes", [
+    (576, 640), (40, 128), (1, 128), (128, 128), (512, 512), (640, 640)])
+def test_a_latent_row_lies_in_whole_lane_tiles(width, lanes):
+    """The rule reads the row's width alone: rounded up to whole tiles of
+    128 lanes (kanana-2 and Ling-3.0-flash: 512 + 64 -> 640), and a width
+    that already fills them keeps its shape letter for letter."""
+    assert kv_cache.latent_pool_shape(5, 2305, 16, width) \
+        == (5, 2305, 16, lanes)
+    cache = kv_cache.PagedKVCache(2, 4, 16, 8, 6, 2, 3, latent_dim=width)
+    assert cache.pools["latent_pool"].shape == (2, 7, 8, lanes)
+    assert cache.latent_lanes == lanes and cache.kv_lanes is None
+
+
+def _pad_lanes(sess):
+    """(the largest magnitude in the pool's lanes past the row's width,
+    that among the lanes a row fills)."""
+    pool = np.asarray(sess.cache.pools["latent_pool"])
+    return float(np.abs(pool[..., ROW:]).max()), \
+        float(np.abs(pool[..., :ROW]).max())
+
+
+@pytest.mark.parametrize("upto", ["prefill", "decode", "copy_on_write",
+                                  "release"])
+def test_the_pad_lanes_stay_zero(prefix, upto):
+    """What lies past a row's width is written zero by every append and
+    copied as zero: after a prefill (a bucket's padding rows on the trash
+    page too), after decode steps (idle slots' rows too), after a
+    copy-on-write of a shared page and after the slot's release."""
+    assert _pad_lanes(prefix)[0] == 0.0
+    prompt = tokens(61, 2 * PAGE + 3)
+    slot = prefix.try_alloc(len(prompt), 6, tokens=prompt)
+    prefix.prefill(slot, prompt)
+    if upto != "prefill":
+        for _ in range(3):
+            prefix.step()
+    if upto in ("copy_on_write", "release"):
+        cache = prefix.cache
+        page = cache._pages_of[slot][0]
+        cache._refcount[page] += 1
+        assert cache.ensure_writable(slot, 0, 1) == 1
+        new = cache._pages_of[slot][0]
+        pool = np.asarray(cache.pools["latent_pool"])
+        np.testing.assert_array_equal(pool[:, new], pool[:, page])
+        cache._drop_ref(page)
+    if upto == "release":
+        prefix.release(slot)
+    pad, row = _pad_lanes(prefix)
+    assert pad == 0.0 and row > 0.0
+
+
+# rank + rope: the suite's 32 + 8 = 40 (a third of a lane tile) and a row
+# that fills one whole tile, which keeps its shape
+WIDTHS = {"a_third_of_a_tile": (32, 8), "one_whole_tile": (96, 32)}
+
+
+@pytest.mark.parametrize("exact", [False, True])
+@pytest.mark.parametrize("name", sorted(WIDTHS))
+def test_paged_prefill_and_decode_equal_full_forward_at_both_widths(name,
+                                                                    exact):
+    """The paged paths over a pool whose rows are padded (40 -> 128
+    lanes) and over one whose rows are not (128): a prefill's last row
+    and four decode steps' rows are ``full_forward``'s rows of the same
+    tokens."""
+    rank, rope = WIDTHS[name]
+    cfg = model_config(dict(HF, kv_lora_rank=rank, qk_rope_head_dim=rope))
+    params = serve_model.init_params(cfg, seed=4)
+    sess = serve.InferenceSession(params, model=cfg, config=serve.ServeConfig(
+        slots=3, page_size=PAGE, buckets=(16, 32), max_new=16, exact=exact))
+    assert sess.cache.latent_lanes == 128
+    assert (rank + rope) % 128 == (0 if name == "one_whole_tile" else 40)
+
+    forward = jax.jit(lambda seq: serve_model.full_forward(
+        params, seq, cfg, exact=exact))
+
+    def full(seq):      # one compilation: a causal model's earlier rows
+        padded = jnp.asarray([seq + [0] * (32 - len(seq))], jnp.int32)
+        return np.asarray(forward(padded))[0, len(seq) - 1]
+
+    seqs = {}
+    for i, n in enumerate((7, 21)):
+        p = tokens(70 + i, n)
+        slot = sess.try_alloc(n, 8, tokens=p)
+        first, logits = sess.prefill(slot, p)
+        assert_close_across_executables(np.asarray(logits), full(p))
+        seqs[slot] = p + [first]
+    for _ in range(4):
+        toks, logits = sess.step()
+        logits = np.asarray(logits)
+        for slot, seq in seqs.items():
+            assert_close_across_executables(logits[slot], full(seq))
+            seq.append(toks[slot])
+    assert sess.fallback_count() == 0
 
 
 @pytest.mark.parametrize("conf", [
@@ -430,7 +530,8 @@ def _latent_session(params):
     sess = session(params)
     f32, i32 = jnp.float32, jnp.int32
     return sess, 6, ["decode", "prefill_16", "prefill_32"], (
-        jax.ShapeDtypeStruct((3, 3 * 6 + 1, PAGE, ROW), f32),
+        jax.ShapeDtypeStruct(
+            kv_cache.latent_pool_shape(3, 3 * 6 + 1, PAGE, ROW), f32),
         jax.ShapeDtypeStruct((2, 5 + 2 * 8), i32))
 
 

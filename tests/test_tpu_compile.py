@@ -15,7 +15,9 @@ temporaries, the state updated in place); and the routed-expert layer's
 grouped-matmul kernel over kanana-2's and Ling-3.0-flash's stacks, a
 whole float32 expert a block; and a grouped-query attention layer's
 append to and read of the paged K/V pools at granite-4.0-h-micro's pool
-size, which must leave the pools where they lie.  Nothing runs, so
+size, which must leave the pools where they lie; and a latent-attention
+layer's append to and read of the latent pool at kanana-2's and
+Ling-3.0-flash's pool sizes, likewise.  Nothing runs, so
 nothing here is a result or a time — a compile that passes is not a
 chip run.
 
@@ -401,3 +403,109 @@ def test_unfolded_heads_of_64_cost_the_whole_pool(one_chip):
     assert memory.argument_size_in_bytes > 2 * logical + (16 << 20)
     assert memory.temp_size_in_bytes > 2 * logical
     assert len(_whole_pool_copies(compiled.as_text(), shape)[0]) >= 4
+
+
+# A latent-attention layer's decode half at the two latent cells' pool
+# sizes (kanana-2: 5 latent layers, 16 slots x 144 pages + the trash page;
+# Ling-3.0-flash: 1 latent layer, 64 slots x 128 pages + 1; pages of 16,
+# rows of 512 + 64 values, 32 heads): 16 or 64 rows appended to the donated
+# pool, every slot's table gathered, the absorbed attention over it.  What
+# is compiled is what ``latent_moe.decode_step`` runs a layer, without its
+# weights.
+LATENT_CASES = {
+    # name: (latent layers, slots, pages a slot, the layer compiled)
+    "kanana_5_layers_2305_pages": (5, 16, 144, 3),
+    "ling_1_layer_8193_pages": (1, 64, 128, 0),
+}
+LATENT_ROW, LATENT_RANK, LATENT_HEADS = 576, 512, 32
+
+
+def _latent_layer_program(one_chip, pool_shape, slots, max_pages, layer):
+    """-> the compiled append + whole-table read + absorbed attention over
+    one donated float32 latent pool of ``pool_shape``, through the cache's
+    own two functions, and the pool's logical bytes."""
+    from mxnet_tpu.ops.attention import decode_attention
+    from mxnet_tpu.serve.kv_cache import (append_latent_rows,
+                                          read_latent_context)
+
+    page = pool_shape[2]
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def step(pools, q, rows, tables, lengths):
+        pools = dict(pools)
+        page_slot = jnp.clip(lengths // page, 0, max_pages - 1)
+        at = jnp.take_along_axis(tables, page_slot[:, None], axis=1)[:, 0]
+        append_latent_rows(pools, layer, at, lengths % page, rows)
+        ctx = read_latent_context(pools["latent_pool"], layer, tables)
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, ctx.shape[-1] - q.shape[-1])))
+        return pools, decode_attention(
+            q[:, None], ctx[:, None], ctx[:, None, :, :LATENT_RANK],
+            lengths + 1, scale=0.07, block=max_pages * page)
+
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(step, donate_argnums=0).lower(
+            {"latent_pool": sds(pool_shape)},
+            sds((slots, LATENT_HEADS, LATENT_ROW)), sds((slots, LATENT_ROW)),
+            sds((slots, max_pages), jnp.int32),
+            sds((slots,), jnp.int32)).compile()
+    return compiled, 4 * math.prod(pool_shape)
+
+
+def _pool_parameter_layout(text, pool_shape):
+    """-> the minor-to-major order of the entry parameter that is the
+    pool, as the compiled text writes it (``3,2,1,0`` = row-major)."""
+    dims = ",".join(str(n) for n in pool_shape)
+    found = re.search(
+        r"%%pools\S* = f32\[%s\]\{([0-9,]+)[:}]\S* parameter\(" % dims, text)
+    assert found, "no entry parameter of f32[%s]" % dims
+    return found.group(1)
+
+
+@pytest.mark.parametrize("name", sorted(LATENT_CASES))
+def test_latent_append_and_read_leave_the_pool_where_it_lies(one_chip, name):
+    """The latent pool's layout at rest is the cache's rule
+    (``latent_pool_shape``: rows of 576 values in five whole lane tiles):
+    under it the pool is a row-major argument of its logical size, the
+    result aliases it, no operation of the compiled text copies the whole
+    pool into another layout or slices a layer out of it in front of the
+    gather, and the temporaries stay under two gathered contexts."""
+    from mxnet_tpu.serve.kv_cache import latent_pool_shape
+
+    layers, slots, max_pages, layer = LATENT_CASES[name]
+    shape = latent_pool_shape(layers, slots * max_pages + 1, 16, LATENT_ROW)
+    assert shape[-1] == 640
+    compiled, logical = _latent_layer_program(one_chip, shape, slots,
+                                              max_pages, layer)
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    # the queries in whole lane tiles; rows, tables, lengths, padding
+    others = 4 * slots * LATENT_HEADS * shape[-1] + (1 << 20)
+    assert _pool_parameter_layout(text, shape) == "3,2,1,0"
+    assert logical <= memory.argument_size_in_bytes < logical + others
+    assert memory.alias_size_in_bytes >= logical
+    copies, prefetches = _whole_pool_copies(text, shape)
+    assert not copies and not prefetches, copies + prefetches
+    sliced = [line.strip()[:140] for line in text.splitlines() if re.search(
+        r"%%\S*slice\S* = f32\[%d,%d,%d\]\S* fusion\(" % shape[1:], line)]
+    assert not sliced, sliced
+    context = 4 * slots * max_pages * 16 * shape[-1]
+    assert memory.temp_size_in_bytes < 2 * context
+
+
+@pytest.mark.parametrize("name", sorted(LATENT_CASES))
+def test_latent_rows_of_576_cost_the_whole_pool(one_chip, name):
+    """The control of the test above, and why the rule exists: the same
+    program, through the same two functions, over a pool that keeps rows
+    of 576 values (the layout before PR 41).  The compiler then puts the
+    PAGE axis on the lanes at rest (2305 -> 2432 pads less than 576 ->
+    640), and transposes the whole pool to rows in front of the append
+    and back behind it: two whole-pool copies a call."""
+    layers, slots, max_pages, layer = LATENT_CASES[name]
+    shape = (layers, slots * max_pages + 1, 16, LATENT_ROW)
+    compiled, logical = _latent_layer_program(one_chip, shape, slots,
+                                              max_pages, layer)
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    assert _pool_parameter_layout(text, shape) == "1,3,2,0"
+    assert len(_whole_pool_copies(text, shape)[0]) >= 2
+    assert memory.temp_size_in_bytes > logical      # a pool of temporaries
