@@ -47,10 +47,10 @@ from ..ops.kda import MAX_EXPONENT, kda_chunked, kda_step
 from ..ops.mamba2 import causal_conv, conv_step
 from . import latent_moe
 from .kv_cache import append_latent_rows, read_latent_context
-from .latent_moe import (_LO_BITS, _attend_absorbed, _attend_materialised,
-                         _ffn_out, _fold, _head, _prefill_block,
-                         _query_and_row, _resolve, _rms_norm, held,
-                         held_range)
+from .latent_moe import (_attend_absorbed, _attend_materialised,
+                         _ffn_held as _ffn, _head, _head_gate,
+                         _prefill_block, _query_and_row, _resolve, _rms_norm,
+                         fold_named, held_range, read_named)
 # the expert layer is the latent block's, and so is what it asks of XLA
 from .latent_moe import compiler_options  # noqa: F401
 from .model import _mm, check_param_shapes
@@ -247,8 +247,7 @@ def report(counters, cfg):
 
     import numpy as np
 
-    out = {name: int(lo) + (int(hi) << _LO_BITS) for name, lo, hi
-           in zip(COLUMNS, *np.asarray(counters["moe_stats"]))}
+    out = read_named(counters["moe_stats"], COLUMNS)
     out["kda_layers"] = cfg.layer_types.count("kda")
     out["mla_layers"] = cfg.layer_types.count("mla")
     out["expert_layers"] = cfg.num_layers - cfg.first_k_dense
@@ -262,16 +261,13 @@ def report(counters, cfg):
 def _count(counters, incs, **inc):
     """Fold one executable's routers (``incs``, a dict a layer) and its
     own counts into ``counters["moe_stats"]``."""
-    import jax.numpy as jnp
-
     for layer in incs:
         for name, value in layer.items():
             inc[name] = inc.get(name, 0) + value
     if "decode_steps" not in inc:
         inc["distinct_held_experts"] = 0
-    row = jnp.stack([jnp.asarray(inc.get(name, 0), jnp.int32)
-                     for name in COLUMNS])
-    return dict(counters, moe_stats=_fold(counters["moe_stats"], row))
+    return dict(counters, moe_stats=fold_named(counters["moe_stats"],
+                                               COLUMNS, inc))
 
 
 def _kda_inputs(params, pre, u, cfg, exact):
@@ -345,40 +341,6 @@ def _kda_rows(params, pre, u, state, context, length, cfg, exact):
     return _kda_out(params, pre, o, gate, cfg, exact), state, context
 
 
-def _head_gate(params, pre, att, u, cfg, exact):
-    """att (N, H * vd) with each head scaled by its sigmoid gate of u."""
-    import jax
-
-    with jax.named_scope("mla_gate"):
-        gate = jax.nn.sigmoid(_mm(u, params[pre + "attn_gate_weight"],
-                                  exact))
-        return (att.reshape(att.shape[0], cfg.num_heads, -1)
-                * gate[..., None].astype(att.dtype)).reshape(att.shape)
-
-
-def _ffn(params, i, x, cfg, exact, valid, dequantized):
-    """The block's second half on (N, d): the latent block's, with this
-    block's counts by name.  ``valid`` (N,) bool marks the rows that are
-    real tokens.  -> (x + FFN, counter increments or None)."""
-    import jax.numpy as jnp
-
-    out, taken, computed = _ffn_out(params, i, x, cfg, exact, dequantized)
-    if taken is None:
-        return x + out, None
-    first, count = held_range(cfg)
-    here = held(taken, cfg) & valid[:, None]
-    reached = jnp.zeros((count + 1,), bool).at[
-        jnp.where(here, taken - first, count).reshape(-1)].set(True)
-    return x + out, {
-        name: mask.sum().astype(jnp.int32) for name, mask in (
-            ("assignments_asked", jnp.broadcast_to(valid[:, None],
-                                                   taken.shape)),
-            ("assignments_held", here),
-            ("assignments_computed", here & computed),
-            ("distinct_held_experts", reached[:count]),
-            ("rows_without_held_expert", valid & ~here.any(axis=1)))}
-
-
 def full_forward(params, tokens, cfg, exact, block=None):
     """(n, T) int tokens -> (n, T, V) logits from zero state, materialised
     attention over the sequence's own rows: the forward the cached paths
@@ -415,8 +377,8 @@ def full_forward(params, tokens, cfg, exact, block=None):
                 att = _attend_materialised(params, pre, q, rows,
                                            positions + 1, cfg, exact,
                                            block or t)
-                out = _mm(_head_gate(params, pre, att, u, cfg, exact),
-                          params[pre + "o_weight"], exact)
+                out = _mm(_head_gate(params, pre, att, u, cfg.num_heads,
+                                     exact), params[pre + "o_weight"], exact)
             x, _ = _ffn(params, i, x + out, cfg, exact, valid,
                         dequantized)
         return _head(params, x, cfg, exact)
@@ -479,7 +441,7 @@ def prefill_forward(params, tokens, length, offset, table_row, pools,
                                           table_row)
                 att = _attend_materialised(params, pre, q, ctx, abs_pos + 1,
                                            cfg, exact, block)
-            out = _mm(_head_gate(params, pre, att, u, cfg, exact),
+            out = _mm(_head_gate(params, pre, att, u, cfg.num_heads, exact),
                       params[pre + "o_weight"], exact)
             li += 1
         x, inc = _ffn(params, i, x + out, cfg, exact, valid, dequantized)
@@ -540,7 +502,7 @@ def decode_step(params, tokens, lengths, tables, pools, counters, cfg,
                 ctx = read_latent_context(pools["latent_pool"], li, tables)
                 att = _attend_absorbed(params, pre, q, ctx, lengths + 1, cfg,
                                        exact, page_size if exact else t_cap)
-            out = _mm(_head_gate(params, pre, att, u, cfg, exact),
+            out = _mm(_head_gate(params, pre, att, u, cfg.num_heads, exact),
                       params[pre + "o_weight"], exact)
             li += 1
         x, inc = _ffn(params, i, x + out, cfg, exact, valid, dequantized)

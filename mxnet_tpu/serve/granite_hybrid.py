@@ -50,7 +50,7 @@ from ..ops.attention import (decode_attention, flash_attention,
                              paged_decode_attention)
 from ..ops.mamba2 import causal_conv, conv_step, ssd_chunked_scan, ssd_step
 from .kv_cache import append_rows, read_context
-from .latent_moe import _LO_BITS, _fold, _prefill_block, _rms_norm
+from .latent_moe import _prefill_block, _rms_norm, fold_named, read_named
 from .model import _mm, _resolve_params, check_param_shapes
 # the attention layers run the GPT-2 block's paged reader: its report
 from .model import decode_report  # noqa: F401
@@ -222,8 +222,7 @@ def report(counters, cfg):
 
     import numpy as np
 
-    out = {name: int(lo) + (int(hi) << _LO_BITS) for name, lo, hi
-           in zip(COLUMNS, *np.asarray(counters["ssm_stats"]))}
+    out = read_named(counters["ssm_stats"], COLUMNS)
     out["mamba_layers"] = cfg.layer_types.count("mamba")
     out["attention_layers"] = cfg.layer_types.count("attention")
     out["state_bytes_per_slot"] = sum(
@@ -234,11 +233,8 @@ def report(counters, cfg):
 
 def _count(counters, **inc):
     """Fold one executable's counts into ``counters["ssm_stats"]``."""
-    import jax.numpy as jnp
-
-    row = jnp.stack([jnp.asarray(inc.get(name, 0), jnp.int32)
-                     for name in COLUMNS])
-    return dict(counters, ssm_stats=_fold(counters["ssm_stats"], row))
+    return dict(counters, ssm_stats=fold_named(counters["ssm_stats"],
+                                               COLUMNS, inc))
 
 
 def _mlp(params, pre, x, cfg, exact):

@@ -96,6 +96,30 @@ off and prefill takes the slot.  Only ``"full"`` layers own pages, so the
 page pools' layer axis is their count and their head axis the key/value
 head count.
 
+**A windowed layer's ring** (``kw_pool`` / ``vw_pool``; THIS file is the
+only place that knows its layout):
+
+    kw_pool, vw_pool : (window layers, slots, ring rows, H, D)
+
+No page indexes it: every slot owns ``ring_pages * page_size`` rows a
+windowed layer for the session's life, and position ``p`` of a slot's
+request lies in row ``p % rows``.  Who sizes it: the GPT-2 block's
+``layers`` / ``window`` path by ``ServeConfig.ring_pages`` (the window
+plus the largest write span, because its dispatches write a whole bucket
+into the ring before they read); a stated block whose model has a window
+(``serve/laguna.py``) by the model's window alone, rounded up to whole
+pages, whatever the buckets are: its prefill reads the rows from before a
+chunk, attends over them and the chunk's own rows, and only then folds
+the chunk's last rows in.  The step functions never compute a row's index
+or a row's position themselves: one token a slot is written through
+:func:`append_rows` at ``lengths % rows``, a chunk's last rows through
+:func:`fold_into_ring` (one dense rewrite of the slot's ring: bucket
+padding writes nothing), and what a row holds is read off
+:func:`ring_positions`, the latest position at most ``newest`` that maps
+to the row, negative where the request has not written it.  A row that is
+stale (the request before, an idle slot's junk token in row 0) therefore
+labels outside every band, and ``alloc`` scrubs nothing.
+
 **One owner.**  Every device array the cache holds lives in
 :attr:`PagedKVCache.pools`, one mapping from name to array that is built
 once in ``__init__`` and holds only what this cache has.  The serve
@@ -162,7 +186,8 @@ from ..base import MXNetError
 
 __all__ = ["PagedKVCache", "kv_pool_shape", "append_rows", "pool_heads",
            "read_pages", "read_context", "latent_pool_shape",
-           "append_latent_rows", "read_latent_context"]
+           "append_latent_rows", "read_latent_context", "ring_positions",
+           "fold_into_ring"]
 
 # a TPU tile's lane count: the last axis of an array at rest is padded to
 # a multiple of it
@@ -260,6 +285,38 @@ def read_latent_context(pool, layer, tables):
     return pool[layer, tables].reshape(
         tables.shape[:-1] + (tables.shape[-1] * pool.shape[2],
                              pool.shape[3]))
+
+
+def ring_positions(rows, newest):
+    """The absolute position each of a ring's ``rows`` rows holds once the
+    slot's request has written every position up to ``newest`` (an int
+    array of any shape; -1 for nothing yet) -> newest.shape + (rows,):
+    row ``r`` holds the latest ``p <= newest`` with ``p % rows == r``,
+    which is negative where the request has not written the row."""
+    import jax.numpy as jnp
+
+    newest = jnp.asarray(newest)[..., None]
+    return newest - (newest - jnp.arange(rows, dtype=newest.dtype)) % rows
+
+
+def fold_into_ring(pools, which, layer, slot, rows, first, count):
+    """Write a prefill chunk into ``slot``'s ring of windowed layer
+    ``layer`` of ``pools[which + "_pool"]`` (``"kw"`` / ``"vw"``), in
+    place in the mapping: ``rows`` (T, H, D) are the chunk's rows at
+    positions ``first`` on, of which the first ``count`` are real.  The
+    ring then holds the last positions up to ``first + count - 1``: a
+    row whose position lies in the chunk takes the chunk's row, every
+    other row keeps what it held, and bucket padding is not written."""
+    import jax.numpy as jnp
+
+    name = which + "_pool"
+    pool = pools[name]
+    held = ring_positions(pool.shape[2], first + count - 1)
+    from_chunk = held >= first
+    taken = rows[jnp.clip(held - first, 0, rows.shape[0] - 1)]
+    pools[name] = pool.at[layer, slot].set(jnp.where(
+        from_chunk[:, None, None], taken.astype(pool.dtype),
+        pool[layer, slot]))
 
 
 def _chain_key(prev_key, page_tokens):

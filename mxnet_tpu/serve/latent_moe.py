@@ -153,15 +153,42 @@ def held(taken, cfg):
     return (taken >= first) & (taken < first + count)
 
 
+def ffn_param_shapes(cfg, i):
+    """{parameter name: shape} of layer ``i``'s second half: its norm,
+    then one SwiGLU in a dense layer, else the router (with its selection
+    bias where the scores are sigmoids), the held experts stacked on a
+    leading axis and the shared expert."""
+    d, e, fe = cfg.d_model, cfg.n_routed_experts, cfg.moe_d_ff
+    fs = cfg.n_shared_experts * fe
+    held_e = held_range(cfg)[1]
+    p = "blk%d_" % i
+    out = {p + "ffn_norm_gamma": (d,)}
+    if i < cfg.first_k_dense:
+        out.update({p + "gate_weight": (cfg.d_ff, d),
+                    p + "up_weight": (cfg.d_ff, d),
+                    p + "down_weight": (d, cfg.d_ff)})
+        return out
+    out.update({
+        p + "router_weight": (e, d),
+        p + "experts_gate_weight": (held_e, fe, d),
+        p + "experts_up_weight": (held_e, fe, d),
+        p + "experts_down_weight": (held_e, d, fe),
+    })
+    if cfg.scoring_func != "softmax":
+        out[p + "router_bias"] = (e,)
+    if fs:
+        out.update({p + "shared_gate_weight": (fs, d),
+                    p + "shared_up_weight": (fs, d),
+                    p + "shared_down_weight": (d, fs)})
+    return out
+
+
 def param_shapes(cfg):
     """{parameter name: shape}: matrices (out, in) as ``_mm`` takes them,
     a layer's routed experts stacked on a leading axis."""
     d, h = cfg.d_model, cfg.num_heads
     nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     rank, v = cfg.kv_lora_rank, cfg.vocab_size
-    e, fe = cfg.n_routed_experts, cfg.moe_d_ff
-    fs = cfg.n_shared_experts * fe
-    held_e = held_range(cfg)[1]
     out = {"tok_embed_weight": (v, d), "final_norm_gamma": (d,),
            "lm_head_weight": (v, d)}
     for i in range(cfg.num_layers):
@@ -173,33 +200,18 @@ def param_shapes(cfg):
             p + "kv_norm_gamma": (rank,),
             p + "kv_b_weight": (h * (nope + vd), rank),
             p + "o_weight": (d, h * vd),
-            p + "ffn_norm_gamma": (d,),
         })
-        if i < cfg.first_k_dense:
-            out.update({p + "gate_weight": (cfg.d_ff, d),
-                        p + "up_weight": (cfg.d_ff, d),
-                        p + "down_weight": (d, cfg.d_ff)})
-            continue
-        out.update({
-            p + "router_weight": (e, d), p + "router_bias": (e,),
-            p + "experts_gate_weight": (held_e, fe, d),
-            p + "experts_up_weight": (held_e, fe, d),
-            p + "experts_down_weight": (held_e, d, fe),
-        })
-        if fs:
-            out.update({p + "shared_gate_weight": (fs, d),
-                        p + "shared_up_weight": (fs, d),
-                        p + "shared_down_weight": (d, fs)})
+        out.update(ffn_param_shapes(cfg, i))
     return out
 
 
-def init_params(cfg, seed=0, scale=0.02):
-    """Fresh float32 parameters (tests and benches): normal matrices,
-    norm scales one, the router's selection bias zero."""
+def init_from_shapes(shapes, seed, scale):
+    """{name: shape} -> fresh float32 parameters: ``*_gamma`` ones,
+    ``*_bias`` zeros, everything else normal at ``scale``; a leaf's key is
+    its place among the sorted names."""
     import jax
     import jax.numpy as jnp
 
-    shapes = param_shapes(cfg)
     keys = jax.random.split(jax.random.PRNGKey(seed), len(shapes))
     params = {}
     for key, (name, shape) in zip(keys, sorted(shapes.items())):
@@ -211,6 +223,12 @@ def init_params(cfg, seed=0, scale=0.02):
             params[name] = (scale * jax.random.normal(key, shape)
                             ).astype(jnp.float32)
     return params
+
+
+def init_params(cfg, seed=0, scale=0.02):
+    """Fresh float32 parameters (tests and benches): normal matrices,
+    norm scales one, the router's selection bias zero."""
+    return init_from_shapes(param_shapes(cfg), seed, scale)
 
 
 def check_params(params, cfg):
@@ -287,6 +305,24 @@ def _fold(stats, inc):
     lo = stats[0] + inc
     return jnp.stack([lo & ((1 << _LO_BITS) - 1),
                       stats[1] + (lo >> _LO_BITS)])
+
+
+def fold_named(stats, columns, inc):
+    """``stats`` (2, len(columns)) after one executable's counts ``inc``
+    ({column name: count}, a name left out counts 0) were folded in."""
+    import jax.numpy as jnp
+
+    return _fold(stats, jnp.stack([jnp.asarray(inc.get(name, 0), jnp.int32)
+                                   for name in columns]))
+
+
+def read_named(stats, columns):
+    """Host side: ``stats`` (2, len(columns)) as exact Python ints under
+    the columns' names."""
+    import numpy as np
+
+    return {name: int(lo) + (int(hi) << _LO_BITS)
+            for name, lo, hi in zip(columns, *np.asarray(stats))}
 
 
 def report(counters, cfg):
@@ -413,11 +449,15 @@ def _route(u, params, pre, cfg):
     from jax import lax
 
     with jax.named_scope("moe_route"):
-        scores = jax.nn.sigmoid(jnp.einsum(
+        logits = jnp.einsum(
             "nc,ec->ne", u.astype(jnp.float32),
             params[pre + "router_weight"].astype(jnp.float32),
-            precision=lax.Precision.HIGHEST))
-        choice = scores + params[pre + "router_bias"]
+            precision=lax.Precision.HIGHEST)
+        if cfg.scoring_func == "softmax":   # chosen by the scores alone
+            choice = scores = jax.nn.softmax(logits, axis=-1)
+        else:
+            scores = jax.nn.sigmoid(logits)
+            choice = scores + params[pre + "router_bias"]
         if cfg.n_group > 1:
             grouped = choice.reshape(u.shape[0], cfg.n_group, -1)
             _, best = lax.top_k(lax.top_k(grouped, 2)[0].sum(axis=-1),
@@ -561,6 +601,45 @@ def _ffn(params, i, x, cfg, exact, valid, dequantized):
     inc = (real.sum().astype(jnp.int32),
            (real & computed).sum().astype(jnp.int32), load)
     return x + out, inc
+
+
+def _ffn_held(params, i, x, cfg, exact, valid, dequantized):
+    """:func:`_ffn` for a block that may hold a share of its experts, its
+    counts by name: ``assignments_asked`` real rows x experts a token,
+    ``assignments_held`` those that fell on experts held here,
+    ``assignments_computed`` those of them whose tile the loop reached,
+    ``distinct_held_experts`` the held experts at least one real row
+    reached, ``rows_without_held_expert`` real rows that reached none.
+    -> (x + FFN, {name: count} or None in a dense layer)."""
+    import jax.numpy as jnp
+
+    out, taken, computed = _ffn_out(params, i, x, cfg, exact, dequantized)
+    if taken is None:
+        return x + out, None
+    first, count = held_range(cfg)
+    here = held(taken, cfg) & valid[:, None]
+    reached = jnp.zeros((count + 1,), bool).at[
+        jnp.where(here, taken - first, count).reshape(-1)].set(True)
+    return x + out, {
+        name: mask.sum().astype(jnp.int32) for name, mask in (
+            ("assignments_asked", jnp.broadcast_to(valid[:, None],
+                                                   taken.shape)),
+            ("assignments_held", here),
+            ("assignments_computed", here & computed),
+            ("distinct_held_experts", reached[:count]),
+            ("rows_without_held_expert", valid & ~here.any(axis=1)))}
+
+
+def _head_gate(params, pre, att, u, heads, exact, scope="mla_gate"):
+    """att (N, heads * width) with each head scaled by its sigmoid gate
+    of u, one row of ``attn_gate_weight`` a head."""
+    import jax
+
+    with jax.named_scope(scope):
+        gate = jax.nn.sigmoid(_mm(u, params[pre + "attn_gate_weight"],
+                                  exact))
+        return (att.reshape(att.shape[0], heads, -1)
+                * gate[..., None].astype(att.dtype)).reshape(att.shape)
 
 
 def _stats_after(counters, incs, decode):

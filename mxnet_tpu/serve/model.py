@@ -56,7 +56,7 @@ __all__ = ["ModelConfig", "BLOCKS", "block_of", "exact_mode", "init_params",
 # it).  This module is the GPT-2 block.
 BLOCKS = {"gpt2": "model", "deepseek_v3": "latent_moe",
           "granitemoehybrid": "granite_hybrid",
-          "bailing_hybrid": "bailing_hybrid"}
+          "bailing_hybrid": "bailing_hybrid", "laguna": "laguna"}
 
 
 def block_of(cfg):
@@ -112,7 +112,16 @@ class ModelConfig:
     kept) and the last group: the router's group limit under its
     published names, the contiguous range of experts held here, and the
     KDA mixer's head count, head width, convolution taps, gate bound and
-    prefill chunk.
+    prefill chunk.  ``"laguna"`` (``laguna.py``: sliding-window and full
+    grouped-query attention layers with a query-head count a layer, a
+    rotary embedding of two kinds, a sigmoid gate a head, softmax-routed
+    experts of which this chip may hold a share) takes the expert fields,
+    ``num_key_value_heads``, ``layer_types`` of ``"full_attention"`` |
+    ``"sliding_attention"`` and the last group, under the published
+    ``config.json``'s names: the head width, the query heads of each
+    layer, the window, the two ``rope_parameters`` groups (a mapping a
+    kind of layer; kept as sorted tuples, which hash), the leading dense
+    layers, the shared expert's width and the router's score function.
     """
     vocab_size: int
     num_layers: int
@@ -139,7 +148,8 @@ class ModelConfig:
     norm_topk_prob: bool = True
     num_key_value_heads: int = 0    # 0: as many as query heads
     layer_types: tuple = ()     # per layer "mamba" | "attention", or
-    #                             "kda" | "mla" (bailing_hybrid)
+    #                             "kda" | "mla" (bailing_hybrid), or
+    #                             "full_attention" | "sliding_attention"
     mamba_n_heads: int = 0
     mamba_d_head: int = 0
     mamba_d_state: int = 0
@@ -161,10 +171,27 @@ class ModelConfig:
     kda_lower_bound: float = -5.0   # the safe gate: log-decay a token in
     #                                 (kda_lower_bound, 0)
     kda_chunk_size: int = 32    # rows a chunk of the prefill form
+    attn_head_dim: int = 0      # a head's width, stated (the published
+    #                             head_dim); 0: d_model // num_heads
+    num_attention_heads_per_layer: tuple = ()   # (): num_heads in each
+    sliding_window: int = 0     # keys a "sliding_attention" layer's query
+    #                             sees, itself included
+    rope_parameters: tuple = ()  # {layer type: {rope_theta, rope_type,
+    #                              partial_rotary_factor, YaRN's keys}}
+    mlp_only_layers: tuple = ()  # the layers with a dense FFN: leading ones
+    shared_expert_intermediate_size: int = 0    # one shared SwiGLU's width
+    scoring_func: str = "sigmoid"   # the router's scores: "sigmoid" (+ a
+    #                                 selection bias) | "softmax"
+
+    def __post_init__(self):
+        if isinstance(self.rope_parameters, dict):
+            object.__setattr__(self, "rope_parameters", tuple(sorted(
+                (kind, tuple(sorted(group.items())))
+                for kind, group in self.rope_parameters.items())))
 
     @property
     def head_dim(self):
-        return self.d_model // self.num_heads
+        return self.attn_head_dim or self.d_model // self.num_heads
 
     @property
     def kv_heads(self):
@@ -177,7 +204,9 @@ class ModelConfig:
         the slot-private state its block's ``state_shapes`` names."""
         if self.layer_types:
             return tuple({"attention": "full", "mamba": "ssm", "mla": "full",
-                          "kda": "ssm"}.get(t, t) for t in self.layer_types)
+                          "kda": "ssm", "full_attention": "full",
+                          "sliding_attention": "window"}.get(t, t)
+                         for t in self.layer_types)
         return self.layer_kinds or ("full",) * self.num_layers
 
     @property

@@ -114,7 +114,11 @@ routed and shared experts, ``serve/latent_moe.py``) is passed as
 whose slot-private state and convolution context the cache keeps beside
 the pages of the few grouped-query attention layers).  It is the
 model's, not the deployment's: no ``ServeConfig`` field and no
-environment variable names it.  The
+environment variable names it.  ``ModelConfig(block="laguna", ...)``
+(``serve/laguna.py``) brings windowed layers of the model's own: the
+session sizes their rings by the block's ``ring_pages(cfg, page_size)``
+(the model's window in whole pages) and not by ``ServeConfig.ring_pages``,
+which stays the ``layers`` / ``window`` path's rule.  The
 session looks the block's module up once (``model.BLOCKS``,
 ``self.block``) and asks it for everything that depends on the
 architecture: what the cache must hold, the step functions, what it
@@ -134,6 +138,14 @@ for the latent block and the Mamba-2 block yet, and refused at
 construction: ``spec_k``, ``kv_quant``, ``layers`` / ``window``.
 Weight-only ``quant`` and ``oversub`` work for both, ``prefix_pages`` for
 the latent block (a cache with recurrent state keeps no prefix index).
+
+Fresh prompts in chunks (``ServeConfig.max_prompt``, a field only): by
+default a fresh prompt may be as long as the largest bucket.  With
+``max_prompt`` above it a fresh prompt up to ``max_prompt`` is admitted
+and prefilled by the chunk loop above (full chunks at the largest bucket,
+the rest at the smallest bucket that fits: the same ``len(buckets) + 1``
+executables), and a slot's page reservation and the ``max_len`` check
+follow ``max_prompt + max_new``.
 
 Env knobs (see docs/env_vars.md): ``MXNET_SERVE_SLOTS``,
 ``MXNET_SERVE_PAGE``, ``MXNET_SERVE_BUCKETS``, ``MXNET_SERVE_MAX_NEW``,
@@ -200,12 +212,17 @@ class ServeConfig:
     ttft_slo_ms: float = 0.0  # 0 = no TTFT budget (SLO admission off)
     window: int = 0  # sliding-window length for "window" layers
     layers: str = ""  # layer-kind pattern, e.g. "full,window,ssm"
+    max_prompt: int = 0  # longest admissible fresh prompt; 0 = max(buckets)
     # ``layers`` is cycled over the model's depth ("full,window" on a
     # 4-layer model -> full,window,full,window); ``window`` sizes every
     # "window" layer's attention span (its KV lives in a fixed ring of
     # pages per slot, so per-slot bytes stop scaling with context).
     # Empty layers = the classic all-full-attention stack; window is
     # ignored then.
+    # ``max_prompt`` above the largest bucket admits fresh prompts up to
+    # it: prefill feeds such a prompt in chunks of the largest bucket (the
+    # loop a resumed transcript runs), and a slot's page reservation and
+    # the model's ``max_len`` are held to ``max_prompt + max_new``.
 
     @classmethod
     def from_env(cls, **overrides):
@@ -246,6 +263,10 @@ class ServeConfig:
             raise MXNetError("ServeConfig: watermark must be >= 0")
         if self.ttft_slo_ms < 0:
             raise MXNetError("ServeConfig: ttft_slo_ms must be >= 0")
+        if self.max_prompt and self.max_prompt < max(self.buckets):
+            raise MXNetError(
+                "ServeConfig: max_prompt %d is under the largest bucket %d"
+                % (self.max_prompt, max(self.buckets)))
         for b in self.buckets:
             if b % self.page_size:
                 raise MXNetError(
@@ -298,8 +319,15 @@ class ServeConfig:
         return -(-(self.window + span - 1) // self.page_size) + 1
 
     @property
+    def longest_prompt(self):
+        """The longest admissible fresh prompt: ``max_prompt`` where it is
+        stated, else the largest bucket (so ``dataclasses.replace`` of the
+        buckets moves it with them)."""
+        return self.max_prompt or max(self.buckets)
+
+    @property
     def max_pages_per_slot(self):
-        worst = max(self.buckets) + self.max_new
+        worst = self.longest_prompt + self.max_new
         return -(-worst // self.page_size)
 
     @property
@@ -435,12 +463,20 @@ class InferenceSession(object):
             # any checkpoint hosts any stack
             self.model = dataclasses.replace(
                 self.model, window=cfg.window, layer_kinds=kinds).validate()
-        if max(cfg.buckets) + cfg.max_new > self.model.max_len:
+        if cfg.longest_prompt + cfg.max_new > self.model.max_len:
             raise MXNetError(
-                "ServeConfig worst case %d (bucket %d + max_new %d) exceeds "
+                "ServeConfig worst case %d (prompt %d + max_new %d) exceeds "
                 "the model's max_len %d"
-                % (max(cfg.buckets) + cfg.max_new, max(cfg.buckets),
+                % (cfg.longest_prompt + cfg.max_new, cfg.longest_prompt,
                    cfg.max_new, self.model.max_len))
+        if self.model.sliding_window:
+            # the model's own windowed layers: the ring follows the
+            # model's window (the block says how), not the buckets
+            window = self.model.sliding_window
+            ring_pages = self.block.ring_pages(self.model, cfg.page_size)
+        else:
+            window = self.model.window
+            ring_pages = cfg.ring_pages if "window" in kinds else 0
         self.cache = PagedKVCache(
             num_layers=self.model.num_layers,
             num_heads=self.model.kv_heads,
@@ -453,8 +489,8 @@ class InferenceSession(object):
             prefix_pages=cfg.prefix_pages,
             kv_quant=cfg.kv_quant,
             layer_kinds=self.model.kinds,
-            window=self.model.window,
-            ring_pages=cfg.ring_pages if "window" in kinds else 0,
+            window=window,
+            ring_pages=ring_pages,
             latent_dim=self.block.latent_dim(self.model),
             state=self.block.state_shapes(self.model))
         # the block's own device state, taken and returned by every
@@ -589,11 +625,11 @@ class InferenceSession(object):
                 "draft vocab %d != target vocab %d — a draft must share "
                 "the target's token space"
                 % (self.draft_model.vocab_size, self.model.vocab_size))
-        if max(cfg.buckets) + cfg.max_new > self.draft_model.max_len:
+        if cfg.longest_prompt + cfg.max_new > self.draft_model.max_len:
             raise MXNetError(
                 "draft max_len %d cannot cover the serve worst case %d"
                 % (self.draft_model.max_len,
-                   max(cfg.buckets) + cfg.max_new))
+                   cfg.longest_prompt + cfg.max_new))
         self.draft_cache = PagedKVCache(
             num_layers=self.draft_model.num_layers,
             num_heads=self.draft_model.num_heads,
@@ -800,18 +836,25 @@ class InferenceSession(object):
         """Reserve a slot for a request, or return ``None`` when the
         cache can't admit it right now.
 
-        ``tokens`` (the prompt's token ids) enables the prefix-cache
-        lookup: published pages whose chain matches are mapped into the
-        slot and :meth:`PagedKVCache.cached_len` reports the positions
-        prefill may skip.  ``resume=True`` lifts the bucket-length check
+        A fresh prompt may be as long as ``config.max_prompt`` (by
+        default the largest bucket).  ``tokens`` (the prompt's token ids)
+        enables the prefix-cache lookup: published pages whose chain
+        matches are mapped into the slot and
+        :meth:`PagedKVCache.cached_len` reports the positions prefill may
+        skip.  ``resume=True`` lifts the bucket-length check
         (a preempted request's re-prefill sequence — prompt plus already
         committed tokens — may exceed the largest bucket; chunked
         prefill covers it, and page capacity is still enforced because
         the resumed worst case equals the original one)."""
         if prompt_len < 1:
             raise MXNetError("empty prompt")
-        if not resume:
-            self.bucket_for(prompt_len)  # validates length
+        if not resume and prompt_len > self.config.longest_prompt:
+            # up to ``max_prompt`` a fresh prompt past the largest bucket
+            # goes in chunks, as a resumed transcript does
+            raise MXNetError(
+                "prompt of %d tokens exceeds the longest admissible prompt "
+                "%d (ServeConfig.max_prompt; the largest prefill bucket "
+                "unless stated)" % (prompt_len, self.config.longest_prompt))
         max_new = self.config.max_new if max_new is None else int(max_new)
         if max_new > self.config.max_new:
             raise MXNetError("max_new %d exceeds the session cap %d"
@@ -862,10 +905,10 @@ class InferenceSession(object):
         by prefix-cache hit pages (``cache.cached_len``) are skipped,
         and the rest runs in page-aligned chunks through the per-bucket
         offset-taking executables — one chunk for a classic in-bucket
-        prompt, several max-bucket chunks for a resumed transcript
-        longer than the largest bucket.  Afterwards the slot's full
-        prompt pages are published into the prefix index for future
-        admissions."""
+        prompt, several max-bucket chunks for a resumed transcript or a
+        fresh prompt (``config.max_prompt``) longer than the largest
+        bucket.  Afterwards the slot's full prompt pages are published
+        into the prefix index for future admissions."""
         import numpy as np
 
         with _span("session.prefill", slot=slot) as sp:
@@ -1175,12 +1218,29 @@ class InferenceSession(object):
         ``prefills_from_zero`` and ``prefills_carried`` (chunks that began
         a request on the zero state ``alloc`` left, and chunks that took
         up the state and the convolution context an earlier chunk wrote),
-        and ``state_bytes_per_slot``."""
+        and ``state_bytes_per_slot``.
+
+        The window / full grouped-query block (``serve/laguna.py``): the
+        share's router counts as the KDA block names them
+        (``assignments_asked`` / ``_held`` / ``_computed``,
+        ``distinct_held_experts``, ``rows_without_held_expert``);
+        ``decode_steps``, ``prefill_chunks`` and
+        ``prefill_chunks_continued`` (chunks at an offset past 0: a prompt
+        longer than the largest bucket, or a resumed transcript); of the
+        decode steps, summed over the window layers,
+        ``window_rows_visited`` (every slot's whole ring is read) and
+        ``window_rows_in_band`` (those of live slots inside the band), and
+        summed over the full layers ``full_rows_live`` (the rows of live
+        slots' contexts; what the paged reader visits is
+        ``decode_report()``'s ``blocks_visited``); ``ring_rows``, the rows
+        a slot's ring holds in a window layer, and ``kv_lanes``."""
         rep = self.block.report(self.counters, self.model)
         if rep is not None:
             rep.update(self._exes["decode"].traced)
             if self.cache.latent_lanes is not None:
                 rep["latent_lanes"] = self.cache.latent_lanes
+            if self.cache.n_window:
+                rep["ring_rows"] = self.cache.ring_tokens
         return rep
 
     moe_report = block_report   # the name it had while only routers counted

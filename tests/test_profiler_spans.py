@@ -206,6 +206,32 @@ def test_serve_spans_are_the_vocabulary_whole_and_nested(session):
         assert queued_ms / 1e3 <= req.ttft_s
 
 
+def test_a_fresh_prompt_in_chunks_says_so_in_its_spans(params):
+    """With ``ServeConfig.max_prompt`` above the largest bucket a fresh
+    prompt of 37 tokens is admitted as one ``serve.admit`` whose ``prompt``
+    is the whole prompt, and its ``session.prefill`` says three chunks, the
+    last one's bucket, and one ``prefill.launch`` a chunk."""
+    sess = serve.InferenceSession(
+        params, num_heads=CFG.num_heads, config=serve.ServeConfig(
+            slots=2, page_size=PAGE, buckets=(8, 16), max_new=8,
+            max_prompt=40, exact=True))
+    prompt = np.random.default_rng(7).integers(0, CFG.vocab_size,
+                                               37).tolist()
+    profiler.record_spans(True)
+    done, _ = serve.Scheduler(sess).run([serve.Request(
+        rid=0, prompt=prompt, max_new=3, arrival_s=0.0)])
+    profiler.record_spans(False)
+    assert not done[0].failed and len(done[0].tokens) == 3
+    admit, = profiler.spans("serve.admit")
+    prefill, = profiler.spans("session.prefill")
+    assert (admit.attrs["prompt"], admit.attrs["resume"]) == (37, 0)
+    assert prefill.parent == admit.id
+    assert prefill.attrs == {"slot": admit.attrs["slot"], "prompt": 37,
+                             "cached": 0, "chunks": 3, "bucket": 8}
+    assert len([r for r in profiler.spans("prefill.launch")
+                if r.parent == prefill.id]) == 3
+
+
 def test_a_step_is_its_four_segments_in_order(session):
     profiler.record_spans(True)
     serve.Scheduler(session).run(requests())

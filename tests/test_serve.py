@@ -2,6 +2,7 @@
 continuous-batching scheduler, paged decode against the full-context
 reference (tests/closeness.py says how close), and the Predictor
 recompile guardrails (mxnet_tpu/serve/, docs/serving.md)."""
+import dataclasses
 import json
 import os
 import subprocess
@@ -229,6 +230,64 @@ def test_admission_limits(session):
         session.try_alloc(4, max_new=99)  # beyond session cap
     with pytest.raises(MXNetError):
         session.try_alloc(0)
+
+
+def test_max_prompt_defaults_to_the_largest_bucket(session):
+    """``ServeConfig.max_prompt`` unset is today's behaviour: the largest
+    bucket bounds a fresh prompt, a slot's pages and the ``max_len``
+    check; under the largest bucket it is refused."""
+    conf = serve.ServeConfig(slots=3, page_size=PAGE, buckets=(8, 16),
+                             max_new=8)
+    assert conf.max_prompt == 0 and conf.longest_prompt == 16
+    assert conf.max_pages_per_slot == 3
+    assert session.config.longest_prompt == 16
+    # unset it follows the buckets through ``dataclasses.replace`` (the
+    # autotuner's and bench_serve.py's way to a new config)
+    assert dataclasses.replace(conf, buckets=(8,)).longest_prompt == 8
+    assert session.cache.max_pages_per_slot == 3
+    with pytest.raises(MXNetError, match="longest admissible prompt 16"):
+        session.try_alloc(17)
+    assert serve.ServeConfig.from_env(buckets=(16, 32)).longest_prompt == 32
+    with pytest.raises(MXNetError, match="under the largest bucket"):
+        serve.ServeConfig(buckets=(16, 32), max_prompt=24)
+    with pytest.raises(MXNetError, match="exceeds the model's max_len 64"):
+        serve.InferenceSession(
+            serve_model.init_params(CFG, seed=3), num_heads=CFG.num_heads,
+            config=serve.ServeConfig(slots=2, page_size=PAGE,
+                                     buckets=(8, 16), max_new=8,
+                                     max_prompt=60))
+
+
+@pytest.mark.parametrize("over", [
+    dict(), dict(layers="full,window", window=8)],
+    ids=["full", "full,window"])
+def test_a_fresh_long_prompt_is_the_same_prompt_resumed(params, over):
+    """With ``max_prompt`` above the largest bucket a fresh prompt of 37
+    tokens is admitted and goes in chunks of 16, 16 and 5 (bucket 8)
+    through the same ``buckets + 1`` executables: bit for bit what a
+    resumed transcript of those tokens gets, the reference's row, and a
+    slot reserves pages for ``max_prompt + max_new``."""
+    sess = serve.InferenceSession(
+        params, num_heads=CFG.num_heads, config=serve.ServeConfig(
+            slots=3, page_size=PAGE, buckets=(8, 16), max_new=8,
+            max_prompt=40, exact=True, **over))
+    assert sorted(sess.executables) == ["decode", "prefill_16", "prefill_8"]
+    assert sess.cache.max_pages_per_slot == 6
+    seq = np.random.default_rng(5).integers(0, CFG.vocab_size, 37).tolist()
+    fresh = sess.try_alloc(len(seq), 4, tokens=seq)
+    first, logits = sess.prefill(fresh, seq)
+    resumed = sess.try_alloc(len(seq), 4, tokens=seq, resume=True)
+    again, logits2 = sess.prefill(resumed, seq)
+    assert fresh != resumed and first == again
+    np.testing.assert_array_equal(np.asarray(logits), np.asarray(logits2))
+    assert_close_across_executables(
+        np.asarray(logits), np.asarray(serve_model.reference_last_logits(
+            sess.params, seq, sess.model, PAGE, exact=True)))
+    toks, _ = sess.step()
+    assert toks[fresh] == toks[resumed]
+    with pytest.raises(MXNetError, match="longest admissible prompt 40"):
+        sess.try_alloc(41)
+    assert sess.fallback_count() == 0
 
 
 # ---------------------------------------------------------------------------

@@ -53,6 +53,20 @@ BAILING = serve.ModelConfig(
     routed_scaling_factor=2.5, n_group=4, topk_group=2, experts_held=(4, 4),
     layer_types=("kda", "kda", "mla", "kda"), kda_head_dim=8,
     kda_chunk_size=8, rope_theta=6e6)
+LAGUNA = serve.ModelConfig(
+    block="laguna", vocab_size=61, num_layers=3, d_model=32, num_heads=2,
+    num_key_value_heads=2, max_len=64, attn_head_dim=8,
+    num_attention_heads_per_layer=(2, 4, 4), sliding_window=8,
+    layer_types=("full_attention", "sliding_attention", "sliding_attention"),
+    rope_parameters={
+        "full_attention": dict(
+            rope_theta=5e5, rope_type="yarn", factor=128,
+            original_max_position_embeddings=8192, beta_slow=1, beta_fast=32,
+            attention_factor=1.485, partial_rotary_factor=0.5),
+        "sliding_attention": dict(rope_theta=1e4)},
+    mlp_only_layers=(0,), d_ff=48, moe_d_ff=16, n_routed_experts=16,
+    num_experts_per_tok=4, shared_expert_intermediate_size=16,
+    routed_scaling_factor=2.5, scoring_func="softmax", experts_held=(4, 4))
 CONF = dict(slots=3, page_size=8, buckets=(8, 16), max_new=8)
 
 
@@ -106,6 +120,8 @@ VARIANTS = {
     "latent": (LATENT, dict()),
     "granite": (GRANITE, dict()),
     "bailing": (BAILING, dict()),
+    "laguna": (LAGUNA, dict()),
+    "laguna_long_prompts": (LAGUNA, dict(max_prompt=40)),
 }
 
 
@@ -210,3 +226,27 @@ def test_a_latent_pool_and_state_pools_in_one_session():
     assert list(sess.counters) == ["moe_stats"]
     assert sess.decode_report() is None
     assert sess.block_report()["experts_held"] == 4
+
+
+def test_rings_by_the_models_window_beside_pages():
+    """The fifth block's cache: K/V pages for its one full layer, a ring
+    of the model's window (8 rows: one page here) a slot for each of its
+    two window layers, whatever the buckets; two counters, neither among
+    the pools; the paged reader's report, since its full layers run it."""
+    sess = serve.InferenceSession(
+        serve.init_params(LAGUNA, seed=5), model=LAGUNA,
+        config=serve.ServeConfig(**dict(CONF, max_prompt=40)))
+    assert sorted(sess.cache.pools) == ["k_pool", "kw_pool", "v_pool",
+                                        "vw_pool"]
+    assert sess.cache.pools["k_pool"].shape \
+        == kv_cache.kv_pool_shape(1, 3 * 6 + 1, 8, 2, 8) == (1, 19, 8, 16)
+    assert sess.cache.pools["kw_pool"].shape == (2, 3, 8, 2, 8)
+    assert sess.cache.paged == ("k_pool", "v_pool") and sess.cache.hybrid
+    assert sess.cache.ring_tokens == sess.block_report()["ring_rows"] == 8
+    # the GPT-2 block's rule, which follows the buckets, is not asked
+    assert sess.config.ring_pages == 3
+    assert sorted(sess.counters) == ["attn_stats", "moe_stats"]
+    assert sess.decode_report()["kv_lanes"] \
+        == sess.block_report()["kv_lanes"] == 16
+    assert sess.block_report()["experts_held"] == 4
+    assert sorted(sess.executables) == ["decode", "prefill_16", "prefill_8"]

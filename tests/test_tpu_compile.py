@@ -17,7 +17,10 @@ whole float32 expert a block; and a grouped-query attention layer's
 append to and read of the paged K/V pools at granite-4.0-h-micro's pool
 size, which must leave the pools where they lie; and a latent-attention
 layer's append to and read of the latent pool at kanana-2's and
-Ling-3.0-flash's pool sizes, likewise.  Nothing runs, so
+Ling-3.0-flash's pool sizes, likewise; and the window / full
+grouped-query block's whole decode step and its 2048-row prefill chunk at
+Laguna-S-2.1's published widths and the cell's cache, pages and rings
+updated in place.  Nothing runs, so
 nothing here is a result or a time — a compile that passes is not a
 chip run.
 
@@ -509,3 +512,113 @@ def test_latent_rows_of_576_cost_the_whole_pool(one_chip, name):
     assert _pool_parameter_layout(text, shape) == "1,3,2,0"
     assert len(_whole_pool_copies(text, shape)[0]) >= 2
     assert memory.temp_size_in_bytes > logical      # a pool of temporaries
+
+
+# The window / full grouped-query block's own executables at
+# Laguna-S-2.1's published widths, the share the cell holds and the cell's
+# cache: 5 layers (full | window x 3 | full, 48 | 72 | 48 query heads over
+# 8 key/value heads of 128, window 512), experts 0-31 of 256 held, 1/8 of
+# the vocabulary; 16 slots x 832 pages of 16 + the trash page in the two
+# full layers' K/V pools (1.745 GB each), rings of 512 rows a slot in the
+# three window layers (0.101 GB each).  What is compiled is
+# ``laguna.decode_step`` / ``laguna.prefill_forward`` with the TPU's
+# branches taken (the expert layers' grouped-matmul kernel).
+LAGUNA_SLOTS, LAGUNA_TABLE = 16, (12288 + 1024) // 16
+
+
+def _laguna_program(one_chip, monkeypatch, bucket):
+    """-> the compiled decode step (``bucket`` 0) or prefill chunk of
+    ``bucket`` rows, the cache's pool shapes, and the notes of the trace."""
+    from mxnet_tpu import serve
+    from mxnet_tpu.serve import kv_cache, laguna
+    from mxnet_tpu.serve import model as serve_model
+
+    cfg = serve.ModelConfig(
+        block="laguna", vocab_size=12544, num_layers=5, d_model=3072,
+        num_heads=48, num_key_value_heads=8, max_len=1048576,
+        attn_head_dim=128, num_attention_heads_per_layer=(48, 72, 72, 72, 48),
+        layer_types=("full_attention",) + ("sliding_attention",) * 3
+        + ("full_attention",), sliding_window=512,
+        rope_parameters={
+            "full_attention": dict(
+                rope_theta=500000, rope_type="yarn", factor=128,
+                original_max_position_embeddings=8192, beta_slow=1,
+                beta_fast=32, attention_factor=1.4852030263919618,
+                partial_rotary_factor=0.5),
+            "sliding_attention": dict(rope_type="default", rope_theta=10000,
+                                      partial_rotary_factor=1)},
+        mlp_only_layers=(0,), d_ff=12288, moe_d_ff=1024,
+        n_routed_experts=256, num_experts_per_tok=10,
+        shared_expert_intermediate_size=1024, routed_scaling_factor=2.5,
+        scoring_func="softmax", experts_held=(0, 32)).validate()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    i32 = jnp.int32
+    params = {k: sds(v) for k, v in laguna.param_shapes(cfg).items()}
+    assert abs(sum(math.prod(v.shape) for v in params.values())
+               - 1717e6) < 1e6
+    page = 16
+    rings = laguna.ring_pages(cfg, page) * page
+    shapes = {
+        "k_pool": kv_cache.kv_pool_shape(
+            2, LAGUNA_SLOTS * LAGUNA_TABLE + 1, page, 8, 128),
+        "kw_pool": (3, LAGUNA_SLOTS, rings, 8, 128)}
+    shapes.update(v_pool=shapes["k_pool"], vw_pool=shapes["kw_pool"])
+    pools = {name: sds(shape) for name, shape in shapes.items()}
+    counters = {"moe_stats": sds((2, len(laguna.MOE_COLUMNS)), i32),
+                "attn_stats": sds((2, len(laguna.ATTN_COLUMNS)), i32)}
+    static = dict(cfg=cfg, page_size=page, exact=False, kv_quant="")
+    if bucket:
+        def step(params, tokens, length, offset, table_row, pools, counters,
+                 slot):
+            return laguna.prefill_forward(
+                params, tokens, length, offset, table_row, pools, counters,
+                slot=slot, **static)
+
+        avals = (params, sds((1, bucket), i32), sds((), i32), sds((), i32),
+                 sds((LAGUNA_TABLE,), i32), pools, counters, sds((), i32))
+        donate = (5, 6)
+    else:
+        def step(params, tokens, lengths, tables, pools, counters):
+            return laguna.decode_step(params, tokens, lengths, tables, pools,
+                                      counters, **static)
+
+        avals = (params, sds((LAGUNA_SLOTS,), i32), sds((LAGUNA_SLOTS,), i32),
+                 sds((LAGUNA_SLOTS, LAGUNA_TABLE), i32), pools, counters)
+        donate = (4, 5)
+    with jax.default_matmul_precision("default"), \
+            serve_model.trace_notes() as notes:
+        lowered = jax.jit(step, donate_argnums=donate).lower(*avals)
+    return lowered.compile(
+        compiler_options=laguna.compiler_options("tpu")), shapes, notes
+
+
+@pytest.mark.parametrize("bucket, tile, temporaries", [
+    (0, 8, 64 << 20), (2048, 128, 3 << 29)],
+    ids=["decode", "prefill-2048"])
+def test_laguna_executables_compile_for_v5e_at_the_published_widths(
+        one_chip, monkeypatch, bucket, tile, temporaries):
+    """The whole step fits the chip beside its arguments (10.56 GB: 6.87
+    of weights, 3.49 of pages, 0.20 of rings), Mosaic takes a whole
+    float32 expert of 1024 x 3072 a block in all four expert layers, and
+    the donated pools and rings are updated where they lie: the result
+    aliases all four, and no operation copies a whole pool or a whole
+    ring into another layout."""
+    from mxnet_tpu.ops.grouped_matmul import kernel_name
+
+    compiled, shapes, notes = _laguna_program(one_chip, monkeypatch, bucket)
+    assert notes == {"expert_kernel_layers": 4}
+    text = compiled.as_text()
+    assert len(re.findall(r"%%%s[.\d]* = " % kernel_name(tile), text)) == 4
+    memory = compiled.memory_analysis()
+    held = 4 * sum(math.prod(shape) for shape in shapes.values())
+    assert shapes["kw_pool"][2] == 512
+    assert memory.alias_size_in_bytes >= held
+    assert 10.5e9 < memory.argument_size_in_bytes < 10.6e9
+    assert memory.temp_size_in_bytes < temporaries
+    for shape in (shapes["k_pool"], shapes["kw_pool"]):
+        copies, _ = _whole_pool_copies(text, shape)
+        assert not copies, copies
