@@ -385,9 +385,16 @@ def decode_attention(q, k_ctx, v_ctx, lengths, scale=None, block=None,
     ``L + j + 1`` keys, which is the causal mask expressed as raggedness).
     ``Tcap`` must be a multiple of ``block`` (the page size, for the
     paged cache).  Fully-masked blocks are exact no-ops in the online
-    merge (correction 1, p 0), so visiting all ``Tcap/block`` blocks
-    with the validity mask reproduces the reference forward's merge
-    sequence bit-for-bit when ``mi=True``.
+    merge (correction 1, p 0), so visiting the blocks with the validity
+    mask reproduces the reference forward's merge sequence bit-for-bit
+    when ``mi=True``, and the loop stops after the last block any query
+    row of the call can see: ``ceil(max(lengths) / block)`` visits of
+    the ``Tcap / block``, a traced trip count (one executable whatever
+    the lengths; not differentiable, as serving never is), which gives
+    every row, padding rows included, what the whole walk gives, bit for
+    bit.  A prefill chunk at ``offset`` of ``t_b`` rows passes horizons
+    up to ``offset + t_b`` and pays for that many keys, not for the
+    slot's whole page table.
 
     ``k_scale``/``v_scale``: optional (S, Tcap) float32 per-position
     scales of a quantized KV context (``quantize.kv_quantize_rows``
@@ -407,7 +414,9 @@ def decode_attention(q, k_ctx, v_ctx, lengths, scale=None, block=None,
     blocks are page-aligned at the same absolute boundaries the
     reference forward uses, the online merge visits visible blocks in
     the same order with the same masks — windowed decode stays
-    bit-exact against the windowed reference under ``mi=True``.
+    bit-exact against the windowed reference under ``mi=True``.  With
+    ``k_positions`` a row's index says nothing of its horizon, so such
+    a call walks every block.
     """
     d = q.shape[-1]
     t_cap = k_ctx.shape[-2]
@@ -421,26 +430,6 @@ def decode_attention(q, k_ctx, v_ctx, lengths, scale=None, block=None,
             "decode_attention: context capacity %d not a multiple of "
             "block %d" % (t_cap, block))
     nblk = t_cap // block
-    kb = _kv_blocks(k_ctx, t_cap, block)
-    vb = _kv_blocks(v_ctx, t_cap, block)
-
-    def _scale_blocks(s):
-        # (S, Tcap) -> (nblk, S, 1, block, 1): broadcast-ready against
-        # the (nblk, S, H, block, D) code blocks
-        s = s.reshape(s.shape[0], nblk, block)
-        return jnp.moveaxis(s, 1, 0)[:, :, None, :, None]
-
-    ksb = _scale_blocks(k_scale) if k_scale is not None else None
-    vsb = _scale_blocks(v_scale) if v_scale is not None else None
-
-    def _pos_blocks(p):
-        # (S, Tcap) -> (nblk, S, 1, 1, block): broadcast-ready against
-        # the (S, H, Q, block) mask
-        p = p.reshape(p.shape[0], nblk, block)
-        return jnp.moveaxis(p, 1, 0)[:, :, None, None, :]
-
-    kpb = _pos_blocks(k_positions) if k_positions is not None else None
-    starts = jnp.arange(nblk) * block
     q32 = q.astype(jnp.float32) * scale
     acc0 = jnp.zeros(q.shape[:-1] + (v_ctx.shape[-1],), jnp.float32)
     m0 = jnp.full(q.shape[:-1] + (1,), -jnp.inf, jnp.float32)
@@ -455,39 +444,44 @@ def decode_attention(q, k_ctx, v_ctx, lengths, scale=None, block=None,
     else:
         # (S, 1, 1, 1) so the mask broadcasts against (S, H, Q, block)
         valid_len = lengths.reshape(lengths.shape + (1,) * (q.ndim - 1))
+    if k_positions is not None or nblk == 1:
+        # explicit positions: a row's index says nothing of its horizon;
+        # one block: nothing to skip, and a constant bound of 1 is no loop
+        live_blocks = nblk
+    else:
+        # rows at index >= max(lengths) are masked for every query row
+        live_blocks = jnp.clip((jnp.max(lengths) + block - 1) // block,
+                               0, nblk)
 
-    def body(carry, xs):
-        acc, m, l = carry
-        kblk, vblk, start, ks, vs, kp = xs
-        if ks is not None:  # in-kernel dequant of quantized pages
-            kblk = kblk.astype(jnp.float32) * ks
-            vblk = vblk.astype(jnp.float32) * vs
-        if kp is not None:
+    def rows(x, it, axis):
+        return lax.dynamic_slice_in_dim(x, it * block, block, axis=axis)
+
+    def body(it, carry):
+        kblk, vblk = rows(k_ctx, it, -2), rows(v_ctx, it, -2)
+        if k_scale is not None:  # in-kernel dequant of quantized pages
+            # (S, block) -> (S, 1, block, 1) against (S, H, block, D)
+            kblk = kblk.astype(jnp.float32) \
+                * rows(k_scale, it, 1)[:, None, :, None]
+            vblk = vblk.astype(jnp.float32) \
+                * rows(v_scale, it, 1)[:, None, :, None]
+        if k_positions is not None:
             # explicit per-row absolute positions (ring gather): rows
             # that wrapped or were never written carry positions outside
             # [0, valid_len) and mask out exactly
+            kp = rows(k_positions, it, 1)[:, None, None, :]
             pos = valid_len - 1  # query row's absolute position
             kv_valid = (kp >= 0) & (kp <= pos)
             if window:
                 kv_valid = kv_valid & (kp > pos - window)
         else:
-            k_pos = start + jnp.arange(block)
+            k_pos = it * block + jnp.arange(block)
             kv_valid = k_pos < valid_len
             if window:
                 kv_valid = kv_valid & (k_pos >= valid_len - window)
-        acc, m, l = attend_block(q32, kblk, vblk, acc, m, l,
-                                 kv_valid=kv_valid, mi=mi)
-        return (acc, m, l), None
+        return attend_block(q32, kblk, vblk, *carry, kv_valid=kv_valid,
+                            mi=mi)
 
-    slots = [kb, vb, starts, ksb, vsb, kpb]
-    present = [x is not None for x in slots]
-    packed = tuple(x for x in slots if x is not None)
-
-    def step(carry, xs):
-        it = iter(xs)
-        return body(carry, tuple(next(it) if p else None for p in present))
-
-    (acc, _, l), _ = lax.scan(step, (acc0, m0, l0), packed)
+    acc, _, l = lax.fori_loop(0, live_blocks, body, (acc0, m0, l0))
     return finalize_attention(acc, l).astype(q.dtype)
 
 

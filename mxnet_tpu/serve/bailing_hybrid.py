@@ -49,8 +49,8 @@ from . import latent_moe
 from .kv_cache import append_latent_rows, read_latent_context
 from .latent_moe import (_attend_absorbed, _attend_materialised,
                          _ffn_held as _ffn, _head, _head_gate,
-                         _prefill_block, _query_and_row, _resolve, _rms_norm,
-                         fold_named, held_range, read_named)
+                         _query_and_row, _resolve, _rms_norm, fold_named,
+                         held_range, prefill_block, read_named)
 # the expert layer is the latent block's, and so is what it asks of XLA
 from .latent_moe import compiler_options  # noqa: F401
 from .model import _mm, check_param_shapes
@@ -417,7 +417,7 @@ def prefill_forward(params, tokens, length, offset, table_row, pools,
                       table_row[jnp.clip(idx, 0, max_pages - 1)], trash)
     offsets = abs_pos % page_size
     valid = offs < length
-    block = _prefill_block(max_pages, page_size, exact)
+    block = prefill_block(max_pages, page_size, exact)
     x = jnp.take(params["tok_embed_weight"], tokens[0].astype(jnp.int32),
                  axis=0)
     incs = []

@@ -50,7 +50,7 @@ from ..ops.attention import (decode_attention, flash_attention,
                              paged_decode_attention)
 from ..ops.mamba2 import causal_conv, conv_step, ssd_chunked_scan, ssd_step
 from .kv_cache import append_rows, read_context
-from .latent_moe import _prefill_block, _rms_norm, fold_named, read_named
+from .latent_moe import _rms_norm, fold_named, prefill_block, read_named
 from .model import _mm, _resolve_params, check_param_shapes
 # the attention layers run the GPT-2 block's paged reader: its report
 from .model import decode_report  # noqa: F401
@@ -409,7 +409,7 @@ def prefill_forward(params, tokens, length, offset, table_row, pools,
     offsets = abs_pos % page_size
     kv, hd = cfg.kv_heads, cfg.head_dim
     group = cfg.num_heads // kv
-    block = _prefill_block(max_pages, page_size, exact)
+    block = prefill_block(max_pages, page_size, exact)
     x = _embed(params, tokens[0], cfg)
     ai = mi = 0
     for i, kind in enumerate(cfg.layer_types):

@@ -66,9 +66,8 @@ from ..ops.attention import (attend_block, decode_attention,
 from . import latent_moe
 from .kv_cache import (append_rows, fold_into_ring, kv_pool_shape,
                        read_context, ring_positions)
-from .latent_moe import (_ffn_held, _head, _head_gate, _prefill_block,
-                         _resolve, _rms_norm, fold_named, held_range,
-                         read_named)
+from .latent_moe import (_ffn_held, _head, _head_gate, _resolve, _rms_norm,
+                         fold_named, held_range, prefill_block, read_named)
 # the expert layer is the latent block's, and so is what it asks of XLA
 # (the same pass would carry the K/V pools and the rings as bfloat16)
 from .latent_moe import compiler_options  # noqa: F401
@@ -484,7 +483,7 @@ def prefill_forward(params, tokens, length, offset, table_row, pools,
     offsets = abs_pos % page_size
     valid = offs < length
     kv, hd = cfg.kv_heads, cfg.attn_head_dim
-    block = _prefill_block(max_pages, page_size, exact)
+    block = prefill_block(max_pages, page_size, exact)
     x = jnp.take(params["tok_embed_weight"], tokens[0].astype(jnp.int32),
                  axis=0)
     incs = []

@@ -695,10 +695,12 @@ def full_forward(params, tokens, cfg, exact, block=None):
     return jax.vmap(one)(tokens)
 
 
-def _prefill_block(max_pages, page_size, exact):
+def prefill_block(max_pages, page_size, exact):
     """Key block of prefill's attention scan: a page under ``exact`` (the
     GPT-2 block's geometry), else the largest whole number of pages that
-    divides the table and stays within 512 keys."""
+    divides the table and stays within 512 keys.  The scan visits the
+    blocks up to the chunk's furthest horizon, ``offset + bucket``
+    (``InferenceSession.prefill_report()`` counts them from this)."""
     if exact:
         return page_size
     pages = max(p for p in range(1, max_pages + 1)
@@ -735,7 +737,7 @@ def prefill_forward(params, tokens, length, offset, table_row, pools,
                       table_row[jnp.clip(idx, 0, max_pages - 1)], trash)
     offsets = abs_pos % page_size
     valid = offs < length
-    block = _prefill_block(max_pages, page_size, exact)
+    block = prefill_block(max_pages, page_size, exact)
     x = jnp.take(params["tok_embed_weight"], tokens[0].astype(jnp.int32),
                  axis=0)
     incs = []

@@ -339,6 +339,15 @@ def decode_report(stats, table_width):
     return rep
 
 
+def prefill_block(max_pages, page_size, exact):
+    """Key block of :func:`prefill_forward`'s attention scan over the
+    slot's gathered table: a page, whatever the table's width.  The scan
+    visits the blocks up to the chunk's furthest horizon, ``offset +
+    bucket`` (``InferenceSession.prefill_report()`` counts them from
+    this)."""
+    return page_size
+
+
 def guard_tag(cfg):
     """What the recompile guard's name must tell apart beyond the widths:
     a hybrid stack adds ring/state pool avals (and a window length baked

@@ -501,6 +501,11 @@ class InferenceSession(object):
         self._spec_stats = {"verify_steps": 0, "slot_steps": 0,
                             "proposed": 0, "accepted": 0, "committed": 0}
         self._decode_stats = {"steps": 0, "blocks_visited": 0}
+        self._prefill_stats = {"chunks": 0, "rows_visited": 0,
+                               "rows_capacity": 0}
+        # the key block of a prefill chunk's attention scan over the table
+        self._scan_block = self.block.prefill_block(
+            self.cache.table_width, cfg.page_size, bool(cfg.exact))
         self._resolve_draft(draft_params, draft_num_heads)
         if cfg.quant:
             # weight-only quantization of the at-rest params (the draft
@@ -949,6 +954,7 @@ class InferenceSession(object):
                             self.cache.table_row(slot), self.cache.pools,
                             self.counters,
                             np.int32(slot) if self.cache.hybrid else None)
+                    self._count_chunk(off, bucket)
                     first, last_logits, self.cache.pools, self.counters = \
                         self._dispatch("prefill_%d" % bucket, args)
                 off += n
@@ -966,6 +972,19 @@ class InferenceSession(object):
                     self._draft_ingest(slot, prompt)
                     self.draft_cache.register_prefix(slot, prompt_list)
         return first, last_logits
+
+    def _count_chunk(self, offset, bucket):
+        """One prefill chunk's share of ``prefill_report()``: in every
+        layer that reads the slot's page table the attention scan
+        (:func:`~mxnet_tpu.ops.attention.decode_attention`) visits whole
+        key blocks up to the chunk's furthest horizon, bucket padding
+        included, of the table's capacity."""
+        stats, block = self._prefill_stats, self._scan_block
+        capacity = self.cache.table_width * self.config.page_size
+        stats["chunks"] += 1
+        stats["rows_visited"] += self.cache.n_full * min(
+            -(-(offset + bucket) // block) * block, capacity)
+        stats["rows_capacity"] += self.cache.n_full * capacity
 
     def _draft_ingest(self, slot, prompt):
         """Teacher-force the prompt through the draft executable in
@@ -1188,6 +1207,27 @@ class InferenceSession(object):
                                        self.cache.table_width)
         if rep is not None:
             rep["kv_lanes"] = self.cache.kv_lanes
+        return rep
+
+    def prefill_report(self):
+        """How much of the slots' page tables the prefill chunks' attention
+        had to read, counted on the host where a chunk is launched (no
+        device read, no chunk pays for it): ``chunks`` since the session
+        was built; ``rows_visited`` the sum, over them and over the layers
+        that read a page table (full attention, latent), of the key rows
+        up to the chunk's furthest horizon ``offset + bucket``, rounded up
+        to the block's scan block (its ``prefill_block``) and clipped to
+        the table, which is where the loop of
+        :func:`~mxnet_tpu.ops.attention.decode_attention` ends;
+        ``rows_capacity`` = chunks x layers x the table's rows, what a
+        scan of the whole table visits, and ``visited_share`` their
+        ratio.  (The whole table is still *gathered* in front of the
+        scan; a window layer's ring and a recurrent layer's state are
+        not tables and count nothing.)"""
+        rep = dict(self._prefill_stats)
+        rep["visited_share"] = (
+            rep["rows_visited"] / float(rep["rows_capacity"])
+            if rep["rows_capacity"] else 0.0)
         return rep
 
     def block_report(self):

@@ -28,7 +28,7 @@ SURFACE = ("validate", "check_params", "init_params", "full_forward",
            "latent_dim", "state_shapes", "init_counters", "prefill_forward",
            "decode_step",
            "REFUSES", "REFUSES_WHY", "compiler_options", "report",
-           "decode_report", "guard_tag")
+           "decode_report", "prefill_block", "guard_tag")
 SPECULATIVE = ("verify_step", "draft_propose")
 
 GPT2 = serve.ModelConfig(vocab_size=61, num_layers=3, d_model=32,
@@ -108,6 +108,49 @@ def test_a_third_block_is_served_by_an_unedited_session(monkeypatch):
     with pytest.raises(MXNetError, match="the architecture"):
         serve.InferenceSession(
             params, model=dataclasses.replace(cfg, max_len=32), config=conf)
+
+
+# block -> (model, ServeConfig settings, layers that read a page table,
+# the scan's key block); the table is (16 + 8) / 8 = 3 pages = 24 rows
+PREFILL_SCANS = {
+    "dense": (GPT2, dict(), 3, 8),
+    "dense_one_full_layer": (GPT2, dict(layers="full,window,ssm", window=8),
+                             1, 8),
+    "latent": (LATENT, dict(), 2, 8),
+    # not exact, a table within 512 keys is one block: nothing to skip
+    "latent_one_block": (LATENT, dict(exact=False), 2, 24),
+    "granite": (GRANITE, dict(), 1, 8),
+    "bailing": (BAILING, dict(), 1, 8),
+}
+
+
+@pytest.mark.parametrize("name", sorted(PREFILL_SCANS))
+def test_prefill_report_counts_rows_to_the_chunks_horizon(name):
+    """``prefill_report()`` is host arithmetic where a chunk is launched:
+    a prompt of 5 goes in bucket 8 and its scan ends at row 8, one of 13
+    in bucket 16 and ends at row 16 (bucket padding sees that far), in
+    whole key blocks, of a table of 24 rows, once for each layer that
+    reads a page table."""
+    model, settings, layers, block = PREFILL_SCANS[name]
+    sess = serve.InferenceSession(
+        serve.init_params(model, seed=5), model=model,
+        config=serve.ServeConfig(**dict(CONF, **settings)))
+    assert sess.block.prefill_block(3, 8, sess.config.exact) == block
+    assert sess.prefill_report() == {
+        "chunks": 0, "rows_visited": 0, "rows_capacity": 0,
+        "visited_share": 0.0}
+    for seed, n in enumerate((5, 13)):
+        slot = sess.try_alloc(n, 4)
+        sess.prefill(slot, np.random.default_rng(seed).integers(
+            0, model.vocab_size, n).tolist())
+    visited = layers * sum(-(-bucket // block) * block for bucket in (8, 16))
+    assert sess.prefill_report() == {
+        "chunks": 2, "rows_visited": visited,
+        "rows_capacity": layers * 2 * 24,
+        "visited_share": visited / (layers * 2 * 24.0)}
+    assert visited == (layers * 24 if block == 8 else layers * 48)
+    sess.step()         # a decode step is no chunk
+    assert sess.prefill_report()["chunks"] == 2
 
 
 VARIANTS = {

@@ -406,6 +406,81 @@ def test_a_fresh_prompt_in_chunks_is_the_prompt_in_one_bucket(params, plain):
         plain.try_alloc(65, 3)
 
 
+def _whole_walk(q, k_ctx, v_ctx, lengths, **kw):
+    """``decode_attention`` as it was before the scan had a bound: rows
+    labelled with their own index carry the same mask, and a call with
+    ``k_positions`` walks every block of the table."""
+    from mxnet_tpu.ops.attention import decode_attention
+
+    rows = jnp.arange(k_ctx.shape[-2], dtype=jnp.int32)
+    return decode_attention(
+        q, k_ctx, v_ctx, lengths,
+        k_positions=jnp.broadcast_to(rows, (q.shape[0], rows.shape[0])),
+        **kw)
+
+
+# exact -> (max_len, ServeConfig sizes, prompt, the full layers' key block
+# and table rows): three chunks at offsets 0, b, 2b of a table that is
+# several key blocks wide (not exact, a table within 512 keys is one block)
+CHUNKED = {
+    True: (256, dict(CONF, exact=True), 43, PAGE, 80),
+    False: (1024, dict(slots=2, page_size=16, buckets=(128,), max_new=16,
+                       max_prompt=624, exact=False), 300, 320, 640),
+}
+
+
+@pytest.mark.parametrize("exact", [True, False])
+def test_a_chunks_scan_ends_at_its_horizon_and_changes_no_bit(
+        params, monkeypatch, exact):
+    """A prompt of three chunks (offsets 0, 2048, 4096 at the cell's
+    sizes; 0, 16, 32 and 0, 128, 256 here): each full layer's scan ends
+    at the block that holds ``offset + bucket``, ``prefill_report()`` says
+    so, and the first token, the logits and every byte the chunks left
+    in the pages and the rings are those of a session whose scan walks
+    the whole table; the logits are ``full_forward``'s row."""
+    max_len, conf, n, block, rows = CHUNKED[exact]
+    cfg = model_config(dict(HF, max_position_embeddings=max_len))
+    bucket = max(conf["buckets"])
+    assert laguna.prefill_block(rows // conf["page_size"],
+                                conf["page_size"], exact) == block
+    seq = tokens(77, n)
+    sess = session(params, cfg, **conf)
+    with monkeypatch.context() as patch:
+        patch.setattr(laguna, "decode_attention", _whole_walk)
+        whole = session(params, cfg, **conf)
+    got, want = [], []
+    for s, out in ((sess, got), (whole, want)):
+        slot = s.try_alloc(n, 4, tokens=seq)
+        first, logits = s.prefill(slot, seq)
+        toks, after = s.step()
+        out.extend([first, np.asarray(logits), toks[slot],
+                    np.asarray(after)[slot]]
+                   + [np.asarray(s.cache.pools[name]) for name in
+                      ("k_pool", "v_pool", "kw_pool", "vw_pool")])
+    for a, b in zip(got, want):
+        np.testing.assert_array_equal(a, b)
+    assert_close_across_executables(got[1], np.asarray(
+        serve_model.full_forward(params, jnp.asarray([seq], jnp.int32), cfg,
+                                 exact=exact))[0, -1])
+    rep = sess.prefill_report()
+    offsets = (0, bucket, 2 * bucket)
+    visited = [min(-(-(off + bucket) // block) * block, rows)
+               for off in offsets]
+    assert visited == ([16, 32, 48] if exact else [320, 320, 640])
+    # 2 full layers; the 3 window layers' rings are no tables
+    assert rep == {"chunks": 3, "rows_visited": 2 * sum(visited),
+                   "rows_capacity": 2 * 3 * rows,
+                   "visited_share": sum(visited) / (3.0 * rows)}
+    assert rep["rows_visited"] < rep["rows_capacity"]
+    assert whole.prefill_report() == rep    # the host counts the same
+    assert sorted(sess.executables) == sorted(whole.executables)
+    assert len(sess.executables) == len(conf["buckets"]) + 1
+    # the twin is another program: its loops' trip counts are constants
+    name = "prefill_%d" % bucket
+    assert sess.executables[name].as_text() \
+        != whole.executables[name].as_text()
+
+
 def test_a_slot_admitted_again_sees_no_stale_ring_row(params, plain):
     """A slot that served a request of 50 tokens (its rings full of that
     request's rows) and is admitted again gives a shorter request the rows

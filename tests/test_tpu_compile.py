@@ -596,6 +596,20 @@ def _laguna_program(one_chip, monkeypatch, bucket):
         compiler_options=laguna.compiler_options("tpu")), shapes, notes
 
 
+def _loop_conditions(text, scope):
+    """The condition computations of the ``while`` operations that the
+    trace put under ``scope``, as the compiled text writes them."""
+    found = []
+    for name in re.findall(
+            r" while\(\S+ condition=%%(\S+), body=\S+ "
+            r"metadata=\{op_name=\"[^\"]*/%s/while\"" % scope, text):
+        body = re.search(r"^%%%s \(.*?^\}" % re.escape(name), text,
+                         re.M | re.S)
+        assert body, "no computation %s" % name
+        found.append(body.group(0))
+    return found
+
+
 @pytest.mark.parametrize("bucket, tile, temporaries", [
     (0, 8, 64 << 20), (2048, 128, 3 << 29)],
     ids=["decode", "prefill-2048"])
@@ -622,3 +636,18 @@ def test_laguna_executables_compile_for_v5e_at_the_published_widths(
     for shape in (shapes["k_pool"], shapes["kw_pool"]):
         copies, _ = _whole_pool_copies(text, shape)
         assert not copies, copies
+    if bucket:
+        # one loop a full layer over the gathered table's 26 blocks of 512
+        # keys, and its trip count is data (the chunk's furthest horizon):
+        # the condition compares the counter with an element of the carry,
+        # where a walk of every block compared it with constant(26); and
+        # the blocks are sliced out of the context, not stacked beside it
+        conditions = _loop_conditions(text, "gqa_prefill")
+        assert len(conditions) == 2
+        for cond in conditions:
+            assert "constant(" not in cond, cond
+            assert re.search(r"ROOT \S+ = pred\S* compare\(%get-tuple-element"
+                             r"\S+ %get-tuple-element", cond), cond
+        assert LAGUNA_TABLE * 16 // 512 == 26
+        assert "f32[26,1,8,512,128]" not in text
+        assert "f32[1,8,13312,128]" in text
