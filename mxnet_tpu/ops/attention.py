@@ -47,6 +47,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..base import MXNetError, get_env
+from .paged_attention import paged_attention, paged_attention_eligible
 
 __all__ = ["attention_impl", "attention_block_size", "dot_product_attention",
            "flash_attention", "reference_attention", "attend_block",
@@ -508,6 +509,15 @@ def paged_decode_attention(q, k_pool, v_pool, layer, tables, lengths,
     bit, ``mi`` or not.  Its cost follows the longest live context, not
     the table's capacity.
 
+    That is the ``fori_loop``, on every backend and for every call.  On a
+    TPU a call that :func:`~.paged_attention.paged_attention_eligible`
+    accepts (not ``mi``, no scales, float32 pools that keep their heads'
+    axis) is instead ONE Pallas kernel (``ops/paged_attention.py``) that
+    walks each slot's own pages and stops at that slot's length, at the
+    loop's precision; the loop is its fallback and its oracle.  Which ran
+    is noted in the trace under way (``paged_kernel_layers``, which
+    ``InferenceSession.decode_report()`` hands on).
+
     q: (S, H, R, D): one query row a slot and head (R = 1), or the R
     query heads that share key/value head H as its rows (grouped-query
     attention; they share the slot's length too); k_pool/v_pool: as
@@ -525,6 +535,7 @@ def paged_decode_attention(q, k_pool, v_pool, layer, tables, lengths,
     # called from a traced step, long after both packages are loaded
     # (``serve`` imports this module while it is itself imported)
     from ..serve.kv_cache import pool_heads, read_pages
+    from ..serve.model import note_traced
 
     s, max_pages = tables.shape
     d = q.shape[-1]
@@ -534,6 +545,12 @@ def paged_decode_attention(q, k_pool, v_pool, layer, tables, lengths,
             "paged_decode_attention takes the pool's %d heads and one "
             "length a slot, got q %r, lengths %r"
             % (heads, q.shape, lengths.shape))
+    if scale is None:
+        scale = 1.0 / (q.shape[-1] ** 0.5)
+    if paged_attention_eligible(q, k_pool, v_pool, mi, k_scale, v_scale):
+        note_traced("paged_kernel_layers", 1)
+        return paged_attention(q, k_pool, v_pool, layer, tables, lengths,
+                               page_size, scale)
     group = max(1, min(_PAGED_KEYS_PER_ITERATION // page_size, max_pages))
     # columns that complete the last group lie past every horizon
     # (position >= max_pages * page_size >= lengths): any page in bounds
@@ -541,8 +558,6 @@ def paged_decode_attention(q, k_pool, v_pool, layer, tables, lengths,
     if pad:
         tables = jnp.concatenate(
             [tables, jnp.broadcast_to(tables[:, -1:], (s, pad))], axis=1)
-    if scale is None:
-        scale = 1.0 / (q.shape[-1] ** 0.5)
     q32 = q.astype(jnp.float32) * scale
     valid_len = lengths.reshape(lengths.shape + (1,) * (q.ndim - 1))
     live_pages = jnp.clip((jnp.max(lengths) + page_size - 1) // page_size,

@@ -326,11 +326,26 @@ def report(counters, cfg):
     return None
 
 
+def decode_pages_visited(lengths, page_size, table_width, per_slot):
+    """Pages of a full layer's pools that one decode step's
+    :func:`~mxnet_tpu.ops.attention.paged_decode_attention` visits, from
+    the host's ``lengths`` (every slot's rows before the step, which
+    appends one; 0 for an idle slot): ``per_slot`` (the kernel) the sum of
+    the slots' own pages; else (the loop) the longest context's pages for
+    every slot."""
+    import numpy as np
+
+    pages = np.minimum(-(-(np.asarray(lengths) + 1) // page_size),
+                       table_width)
+    return int(pages.sum() if per_slot else pages.max() * pages.size)
+
+
 def decode_report(stats, table_width):
     """``InferenceSession.decode_report()`` from the session's host-side
     counts: every full-attention layer of :func:`decode_step` runs
     :func:`~mxnet_tpu.ops.attention.paged_decode_attention`, whose loop
-    ends at the longest live context."""
+    ends at the longest live context and whose kernel at each slot's
+    own."""
     rep = dict(stats)
     rep["blocks_capacity"] = rep["steps"] * table_width
     rep["visited_share"] = (

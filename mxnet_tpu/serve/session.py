@@ -166,8 +166,8 @@ from ..base import MXNetError, get_env
 from ..profiler import span as _span
 from ..quantize import quant_mode
 from .kv_cache import PagedKVCache
-from .model import (ModelConfig, block_of, config_from_params, exact_mode,
-                    trace_notes)
+from .model import (ModelConfig, block_of, config_from_params,
+                    decode_pages_visited, exact_mode, trace_notes)
 
 __all__ = ["ServeConfig", "InferenceSession"]
 
@@ -500,7 +500,7 @@ class InferenceSession(object):
         self._slot_history = {}  # slot -> prompt + committed tokens
         self._spec_stats = {"verify_steps": 0, "slot_steps": 0,
                             "proposed": 0, "accepted": 0, "committed": 0}
-        self._decode_stats = {"steps": 0, "blocks_visited": 0}
+        self._decode_stats = {"steps": 0, "pages_visited": 0}
         self._prefill_stats = {"chunks": 0, "rows_visited": 0,
                                "rows_capacity": 0}
         # the key block of a prefill chunk's attention scan over the table
@@ -1043,12 +1043,14 @@ class InferenceSession(object):
                 args = (self.params, tokens,
                         self.cache.lengths_arg(), self.cache.device_tables(),
                         self.cache.pools, self.counters)
-                # the page blocks this step's attention has to visit:
-                # those of the longest context, its new row included
-                longest = int(self.cache.lengths.max()) + 1
+                # the pages this step's reader visits a full layer, each
+                # context's new row included: the kernel every slot's own,
+                # the loop the longest context's for every slot
+                pages = decode_pages_visited(
+                    self.cache.lengths, cfg.page_size,
+                    self.cache.table_width, self._paged_kernel_layers() > 0)
                 self._decode_stats["steps"] += 1
-                self._decode_stats["blocks_visited"] += min(
-                    -(-longest // cfg.page_size), self.cache.table_width)
+                self._decode_stats["pages_visited"] += pages
             with _span("step.launch"):
                 next_toks, logits, self.cache.pools, self.counters = \
                     self._dispatch("decode", args)
@@ -1188,25 +1190,47 @@ class InferenceSession(object):
             if rep["slot_steps"] else 0.0)
         return rep
 
+    def _paged_kernel_layers(self):
+        """Layers of the decode executable that were traced with the
+        paged-attention kernel (``ops/paged_attention.py``); 0 where
+        every full-attention layer runs the loop."""
+        return self._exes["decode"].traced.get("paged_kernel_layers", 0)
+
     def decode_report(self):
         """How much of the page tables the decode steps had to read,
         counted on the host from ``cache.lengths`` (no device read, no
-        step pays for it): ``steps`` decode steps since the session was
-        built, ``blocks_visited`` the sum over them of the page blocks up
-        to the longest live context (where the loop of
-        :func:`~mxnet_tpu.ops.attention.paged_decode_attention`, which
-        every full-attention layer runs, ends, to within the few pages
-        that complete its last iteration), ``blocks_capacity`` = steps x
-        the table's width, what a reader that ignores the lengths would
-        visit, and ``visited_share`` their ratio; ``kv_lanes`` the width
-        of the K/V pools' last axis at rest, which says whether the cache
-        folded the heads into it (:attr:`PagedKVCache.kv_lanes`).
-        ``None`` for a block whose decode step has no such reader (the
-        latent block)."""
-        rep = self.block.decode_report(self._decode_stats,
-                                       self.cache.table_width)
+        step pays for it), for the reader that was traced:
+        ``paged_kernel_layers`` says which, the layers of the decode
+        executable whose
+        :func:`~mxnet_tpu.ops.attention.paged_decode_attention` is the
+        Pallas kernel (every full-attention layer on a TPU where the call
+        is eligible: ``ops/paged_attention.py:paged_attention_eligible``;
+        0 on the CPU, under ``exact`` / ``kv_quant`` and for pools that
+        fold their heads, where the ``fori_loop`` runs).  ``steps`` decode
+        steps since the session was built; ``pages_visited`` the sum over
+        them of the pages a full layer's reader visits, each context's new
+        row included: with the kernel every slot's own
+        ``ceil((length + 1) / page_size)``, an idle slot's one; with the
+        loop the longest live context's pages for every slot (where the
+        loop ends, to within the few pages that complete its last
+        iteration).  ``blocks_visited`` = ``pages_visited / slots``, the
+        page blocks a slot: an ``int`` under the loop (the longest
+        context's), a ``float`` under the kernel (the slots' mean);
+        ``blocks_capacity`` = steps x the table's width, what a reader
+        that ignores the lengths would visit, and ``visited_share`` their
+        ratio; ``kv_lanes`` the width of the K/V pools' last axis at rest,
+        which says whether the cache folded the heads into it
+        (:attr:`PagedKVCache.kv_lanes`).  ``None`` for a block whose
+        decode step has no such reader (the latent block)."""
+        by_kernel = self._paged_kernel_layers()
+        pages, slots = self._decode_stats["pages_visited"], self.config.slots
+        rep = self.block.decode_report(
+            dict(self._decode_stats, blocks_visited=(
+                pages / slots if by_kernel else pages // slots)),
+            self.cache.table_width)
         if rep is not None:
             rep["kv_lanes"] = self.cache.kv_lanes
+            rep["paged_kernel_layers"] = by_kernel
         return rep
 
     def prefill_report(self):
@@ -1273,7 +1297,11 @@ class InferenceSession(object):
         summed over the full layers ``full_rows_live`` (the rows of live
         slots' contexts; what the paged reader visits is
         ``decode_report()``'s ``blocks_visited``); ``ring_rows``, the rows
-        a slot's ring holds in a window layer, and ``kv_lanes``."""
+        a slot's ring holds in a window layer, and ``kv_lanes``.
+
+        Every note of the decode executable's trace is copied in, so
+        where the paged-attention kernel was traced its
+        ``paged_kernel_layers`` shows here as in ``decode_report()``."""
         rep = self.block.report(self.counters, self.model)
         if rep is not None:
             rep.update(self._exes["decode"].traced)

@@ -2,7 +2,11 @@
 (``ops/attention.py:paged_decode_attention``): bit for bit
 ``decode_attention`` over the gathered context, with no gathered context
 in the program, stopping at the longest live context; and
-``InferenceSession.decode_report()`` says how far that was."""
+``InferenceSession.decode_report()`` says how far that was.  On a TPU
+the eligible calls are one Pallas kernel (``ops/paged_attention.py``)
+that stops at each slot's own length: here it runs in Pallas's TPU
+interpreter against the loop, which stays as the fallback and the
+oracle."""
 import re
 
 import jax
@@ -10,8 +14,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from jax.experimental.pallas import tpu as pltpu
+
 from mxnet_tpu import quantize, serve
-from mxnet_tpu.ops import attention
+from mxnet_tpu.ops import attention, paged_attention
 from mxnet_tpu.ops.attention import decode_attention, paged_decode_attention
 from mxnet_tpu.serve import kv_cache
 from mxnet_tpu.serve import model as serve_model
@@ -179,6 +185,209 @@ def test_reader_takes_a_groups_query_heads_as_rows_and_no_other_head_count():
     with pytest.raises(MXNetError, match="the pool's %d heads" % k.shape[-2]):
         paged_decode_attention(jnp.concatenate([q, q], axis=1), k, v, LAYER,
                                tables, lengths, PAGE)
+
+
+# ---------------------------------------------------------------------------
+# the kernel that reads each slot's own pages (Pallas, TPU interpreter)
+# ---------------------------------------------------------------------------
+
+KD = 128    # a head of whole lane tiles: the pools keep the heads' axis
+
+
+def _kernel_case(rs, lengths, rows):
+    """Pools of heads of 128 whose every row is finite garbage where no
+    slot can see it (the pages past a length, the rows of a last page
+    past it, the trash page, the other layer): 1e3, so that one such row
+    reaching a result moves it by far more than any tolerance."""
+    shape = (LAYERS, TRASH + 1, PAGE, H, KD)
+    k, v = (rs.randn(*shape).astype(np.float32) for _ in "kv")
+    tables = np.asarray(_tables(rs, lengths))
+    seen = np.zeros(shape[:3], bool)
+    for s, n in enumerate(lengths):
+        for pos in range(n):
+            seen[LAYER, tables[s, pos // PAGE], pos % PAGE] = True
+    k[~seen], v[~seen] = 1e3, -1e3
+    q = jnp.asarray(rs.randn(S, H, rows, KD).astype(np.float32))
+    return q, jnp.asarray(k), jnp.asarray(v), jnp.asarray(tables)
+
+
+def _by_kernel(q, k, v, tables, lengths, pages=2, **more):
+    """The kernel as the chip would run it but for the interpreter, whose
+    memory starts as NaN: what was never copied must not be read."""
+    with pltpu.force_tpu_interpret_mode():
+        return paged_attention.paged_attention(
+            q, k, v, LAYER, tables, lengths, PAGE,
+            more.pop("scale", 1.0 / KD ** 0.5), pages=pages, **more)
+
+
+@pytest.mark.parametrize("rows", [1, 6], ids=["R1", "R6"])
+@pytest.mark.parametrize("case", sorted(LENGTHS))
+def test_kernel_equals_the_loop(case, rows):
+    """Each slot's own pages and no others, two pages a block (so a slot
+    is one to three blocks and the next slot's first block is fetched
+    behind the last): the loop's result to the tolerance of two
+    executables of one computation, one query row a head or a group's
+    six, for every case of ``LENGTHS``; nothing a slot cannot see reaches
+    its result, and nothing is NaN."""
+    rs = np.random.RandomState(12)
+    lengths = jnp.asarray(LENGTHS[case], jnp.int32)
+    q, k, v, tables = _kernel_case(rs, LENGTHS[case], rows)
+    want = _paged(q, k, v, None, None, tables, lengths, False)
+    got = _by_kernel(q, k, v, tables, lengths)
+    assert got.shape == q.shape and got.dtype == q.dtype
+    assert np.isfinite(np.asarray(got)).all()
+    assert np.abs(np.asarray(want)).max() < 10     # no garbage in the oracle
+    assert_close_across_executables(got, want)
+
+
+@pytest.mark.parametrize("pages", [1, 3, 8])
+def test_kernel_pages_a_block_do_not_change_the_result(pages):
+    """One page a block, three (the table's five columns completed with a
+    sixth that no block reads) or the whole table in one."""
+    rs = np.random.RandomState(13)
+    lengths = jnp.asarray(LENGTHS["one_long"], jnp.int32)
+    q, k, v, tables = _kernel_case(rs, LENGTHS["one_long"], 6)
+    want = _paged(q, k, v, None, None, tables, lengths, False)
+    assert_close_across_executables(
+        _by_kernel(q, k, v, tables, lengths, pages=pages), want)
+
+
+def test_kernel_gives_a_slot_of_length_zero_zero_and_takes_a_scale():
+    """No row to see: 0, as the loop gives; ``scale`` multiplies the
+    scores; a bfloat16 query gets a bfloat16 answer."""
+    rs = np.random.RandomState(14)
+    lengths = jnp.asarray((0, CAP, 7), jnp.int32)
+    q, k, v, tables = _kernel_case(rs, (0, CAP, 7), 1)
+    got = _by_kernel(q, k, v, tables, lengths, scale=0.2)
+    want = paged_decode_attention(q, k, v, LAYER, tables, lengths, PAGE,
+                                  scale=0.2)
+    assert not np.asarray(got[0]).any()
+    assert_close_across_executables(got, want)
+    half = _by_kernel(q.astype(jnp.bfloat16), k, v, tables, lengths,
+                      scale=0.2)
+    assert half.dtype == jnp.bfloat16
+    assert_close_across_executables(
+        half, paged_decode_attention(q.astype(jnp.bfloat16), k, v, LAYER,
+                                     tables, lengths, PAGE, scale=0.2),
+        limit=2, dtype="bfloat16")
+
+
+@pytest.mark.parametrize("pool", ["k", "v"])
+def test_kernel_sees_a_planted_fault_in_a_live_page(pool):
+    """The control: two rows of one live page swapped move that slot's
+    result and no other's."""
+    rs = np.random.RandomState(15)
+    lengths = jnp.asarray(LENGTHS["mid_page"], jnp.int32)
+    q, k, v, tables = _kernel_case(rs, LENGTHS["mid_page"], 6)
+    sound = np.asarray(_by_kernel(q, k, v, tables, lengths))
+    arr = np.array(k if pool == "k" else v)
+    live = int(tables[2, 1])     # slot 2 holds 10 rows: its second page
+    arr[LAYER, live, [0, 1]] = arr[LAYER, live, [1, 0]]
+    kk, vv = (jnp.asarray(arr), v) if pool == "k" else (k, jnp.asarray(arr))
+    faulty = np.asarray(_by_kernel(q, kk, vv, tables, lengths))
+    assert np.abs(faulty[2] - sound[2]).max() > 1e-3
+    np.testing.assert_array_equal(faulty[:2], sound[:2])
+
+
+def test_kernel_rounds_its_operands_as_the_chip_does_at_default_precision():
+    """What runs on the chip: at the default matmul precision the
+    operands are rounded to bfloat16 where they are read and the sums are
+    float32, as XLA's einsum does there with the loop's.  The CPU's loop
+    multiplies in float32, so the two differ by the roundings: the
+    largest read over six seeds was 0.36 % of the result's largest
+    magnitude; the limit is 4 eps of bfloat16, 1.6 %."""
+    rs = np.random.RandomState(16)
+    lengths = jnp.asarray(LENGTHS["one_long"], jnp.int32)
+    q, k, v, tables = _kernel_case(rs, LENGTHS["one_long"], 6)
+    want = np.asarray(_paged(q, k, v, None, None, tables, lengths, False))
+    with jax.default_matmul_precision("default"):
+        got = np.asarray(_by_kernel(q, k, v, tables, lengths))
+    gap = np.abs(got - want).max() / np.abs(want).max()
+    assert 1e-5 < gap < 4 * 2.0 ** -8, gap
+
+
+def _loop_text(q, k, v, ks, vs, tables, lengths, mi):
+    # a function of its own each time: a trace is cached by the function
+    return jax.jit(lambda *args: _paged(*args, mi)).lower(
+        q, k, v, ks, vs, tables, lengths).as_text()
+
+
+def test_only_an_eligible_call_on_a_tpu_takes_the_kernel(monkeypatch):
+    """``paged_attention_eligible`` is read off the call while it is
+    traced: the backend is a TPU, ``mi`` is not asked, the pages carry no
+    scales, the pools keep their heads' axis in whole sublane tiles of
+    whole lane tiles, in float32.  Every other call lowers to the loop's
+    text, letter for letter what it lowered to with no kernel to ask."""
+    rs = np.random.RandomState(17)
+    shape = (LAYERS, TRASH + 1, PAGE, 8, KD)
+    k = v = jnp.asarray(rs.randn(*shape).astype(np.float32))
+    q = jnp.asarray(rs.randn(S, 8, 6, KD).astype(np.float32))
+    scales = jnp.ones(shape[:3], jnp.float32)
+    lengths = jnp.asarray(LENGTHS["mid_page"], jnp.int32)
+    tables = _tables(rs, LENGTHS["mid_page"])
+    eligible = paged_attention.paged_attention_eligible
+    folded = k.reshape(shape[:3] + (-1,))
+    two_heads = k[:, :, :, :2]
+    refused = {
+        "mi": (q, k, v, True, None, None),
+        "scales": (q, k.astype(jnp.int8), v.astype(jnp.int8), False, scales,
+                   scales),
+        "folded": (q, folded, folded, False, None, None),
+        "heads_of_part_of_a_tile": (q[:, :2], two_heads, two_heads, False,
+                                    None, None),
+        "bfloat16_pools": (q, k.astype(jnp.bfloat16), v.astype(jnp.bfloat16),
+                           False, None, None),
+        "heads_of_two_lane_tiles": (
+            jnp.tile(q, 2), jnp.tile(k, 2), jnp.tile(v, 2), False, None,
+            None),
+    }
+    assert not eligible(q, k, v, False, None, None)         # the CPU
+    texts = {name: _loop_text(q_, k_, v_, ks, vs, tables, lengths, mi)
+             for name, (q_, k_, v_, mi, ks, vs) in refused.items()}
+    texts["the_cpu"] = _loop_text(q, k, v, None, None, tables, lengths,
+                                  False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert eligible(q, k, v, False, None, None)
+    assert eligible(q.astype(jnp.bfloat16), k, v, False, None, None)
+    for name, (q_, k_, v_, mi, ks, vs) in refused.items():
+        assert not eligible(q_, k_, v_, mi, ks, vs), name
+        text = _loop_text(q_, k_, v_, ks, vs, tables, lengths, mi)
+        assert text == texts[name] and "while" in text, name
+        assert "tpu_custom_call" not in text, name
+    # the eligible call: traced only, this backend cannot lower the kernel
+    with serve_model.trace_notes() as notes:
+        traced = jax.make_jaxpr(lambda *args: _paged(*args, False))(
+            q, k, v, None, None, tables, lengths)
+    assert notes == {"paged_kernel_layers": 1}
+    name = paged_attention.kernel_name(
+        paged_attention.pages_per_block(PAGE, MAX_PAGES))
+    assert name == "paged_decode_attention_p5" and name in str(traced)
+    # the kernel in a jitted body of its own, and no loop beside it
+    steps = [eqn.primitive.name for eqn in traced.jaxpr.eqns]
+    assert str(traced).count("pallas_call") == 1 and "while" not in steps
+
+
+def test_a_steps_layers_share_one_trace_of_the_kernel(monkeypatch):
+    """The layer's number is data to the kernel's jitted body, so two
+    layers of one step are two calls of ONE traced body (lowered once):
+    the dense model's 24 lowerings added 23 s to a session's start."""
+    rs = np.random.RandomState(18)
+    shape = (LAYERS, TRASH + 1, PAGE, 8, KD)
+    k = v = jnp.asarray(rs.randn(*shape).astype(np.float32))
+    q = jnp.asarray(rs.randn(S, 8, 1, KD).astype(np.float32))
+    lengths = jnp.asarray(LENGTHS["mid_page"], jnp.int32)
+    tables = _tables(rs, LENGTHS["mid_page"])
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def two_layers(q, k, v, tables, lengths):
+        return sum(paged_decode_attention(q, k, v, layer, tables, lengths,
+                                          PAGE) for layer in range(LAYERS))
+
+    traced = jax.make_jaxpr(two_layers)(q, k, v, tables, lengths)
+    bodies = [eqn.params["jaxpr"] for eqn in traced.jaxpr.eqns
+              if eqn.params.get("name") == "_paged_attention"]
+    assert len(bodies) == LAYERS == 2 and bodies[0] is bodies[1]
+    assert str(bodies[0]).count("pallas_call") == 1
 
 
 # ---------------------------------------------------------------------------
@@ -366,14 +575,69 @@ def test_decode_report_counts_blocks_to_the_longest_context(session):
     assert rep2["blocks_visited"] - rep["blocks_visited"] == 1
 
 
+@pytest.mark.parametrize("lengths, page, width, per_slot, per_loop", [
+    # 16-row pages: the contexts' own pages | the longest's for every slot
+    ((0, 0, 0), 16, 8, 3, 3),                 # idle slots attend one row
+    ((15, 16, 31), 16, 8, 1 + 2 + 2, 6),      # the new row opens a page
+    ((100, 0, 7, 7), 8, 13, 13 + 1 + 1 + 1, 52),
+    ((300, 5), 16, 4, 4 + 1, 8),              # no further than the table
+])
+def test_pages_a_step_visits_under_both_readers(lengths, page, width,
+                                                per_slot, per_loop):
+    """The host arithmetic alone: the kernel visits every slot's own
+    ``ceil((length + 1) / page)`` pages, the loop the longest context's
+    for every slot; neither past the table's width."""
+    visits = serve_model.decode_pages_visited
+    assert visits(np.asarray(lengths), page, width, True) == per_slot
+    assert visits(np.asarray(lengths), page, width, False) == per_loop
+    assert type(visits(lengths, page, width, True)) is int
+
+
+@pytest.mark.parametrize("kernel_layers", [0, 2], ids=["loop", "kernel"])
+def test_decode_report_counts_for_the_reader_that_was_traced(
+        session, monkeypatch, kernel_layers):
+    """``paged_kernel_layers`` is what the decode executable's trace
+    noted (0 on the CPU); with it the session counts each slot's own
+    pages and ``blocks_visited`` is their mean a slot, a float; without
+    it the longest context's, an int.  The counts are host arithmetic:
+    the note alone switches them, the executable is the CPU's loop."""
+    rep = session.decode_report()
+    assert rep["paged_kernel_layers"] == 0
+    monkeypatch.setattr(session._exes["decode"], "traced",
+                        {"paged_kernel_layers": kernel_layers}
+                        if kernel_layers else {})
+    rs = np.random.RandomState(6)
+    for n in (3, 13):
+        slot = session.try_alloc(n, 8)
+        session.prefill(slot, rs.randint(1, CFG.vocab_size, size=n).tolist())
+    before = session.decode_report()
+    pages = 0
+    for step in range(4):
+        # contexts of 3 + step and 13 + step rows and an idle slot, each
+        # appending one row
+        own = [-(-(3 + step + 1) // SPAGE), -(-(13 + step + 1) // SPAGE), 1]
+        pages += sum(own) if kernel_layers else max(own) * SLOTS
+        session.step()
+    rep = session.decode_report()
+    assert rep["paged_kernel_layers"] == kernel_layers
+    assert rep["pages_visited"] - before["pages_visited"] == pages \
+        == (17 if kernel_layers else 27)
+    visited = rep["blocks_visited"] - before["blocks_visited"]
+    assert visited == pytest.approx(pages / SLOTS, rel=1e-12)
+    assert type(rep["blocks_visited"]) is (float if kernel_layers else int)
+    assert rep["visited_share"] == rep["blocks_visited"] / rep[
+        "blocks_capacity"]
+
+
 def test_decode_report_of_a_fresh_session_is_zero():
     sconf = serve.ServeConfig(slots=2, page_size=SPAGE, buckets=(8,),
                               max_new=8, exact=True)
     sess = serve.InferenceSession(serve_model.init_params(CFG, seed=3),
                                   num_heads=CFG.num_heads, config=sconf)
     assert sess.decode_report() == {
-        "steps": 0, "blocks_visited": 0, "blocks_capacity": 0,
-        "visited_share": 0.0,
+        "steps": 0, "blocks_visited": 0, "pages_visited": 0,
+        "blocks_capacity": 0, "visited_share": 0.0,
+        "paged_kernel_layers": 0,
         # two heads of 16 fold into the pools' last axis
         "kv_lanes": CFG.num_heads * CFG.head_dim}
     assert sess.cache.kv_lanes == 32

@@ -15,7 +15,11 @@ temporaries, the state updated in place); and the routed-expert layer's
 grouped-matmul kernel over kanana-2's and Ling-3.0-flash's stacks, a
 whole float32 expert a block; and a grouped-query attention layer's
 append to and read of the paged K/V pools at granite-4.0-h-micro's pool
-size, which must leave the pools where they lie; and a latent-attention
+size (the loop over folded pools) and at the dense cell's and
+Laguna-S-2.1's (the paged-attention kernel over pools that keep their
+heads' axis), which must leave the pools where they lie; and the dense
+block's whole decode step at Cerebras-GPT-1.3B's widths with the kernel
+in; and a latent-attention
 layer's append to and read of the latent pool at kanana-2's and
 Ling-3.0-flash's pool sizes, likewise; and the window / full
 grouped-query block's whole decode step and its 2048-row prefill chunk at
@@ -292,7 +296,7 @@ def test_grouped_swiglu_compiles_for_v5e(one_chip, name, monkeypatch):
     text = _compile(layer, sds((rows, d)), sds((rows, top_k), jnp.int32),
                     sds((rows, top_k)), sds((e, 768, d)), sds((e, 768, d)),
                     sds((e, d, 768))).as_text()
-    assert len(re.findall(r"%%%s[.\d]* = " % kernel_name(tile), text)) == 1
+    assert len(_kernel_calls(text, kernel_name(tile))) == 1
     stack = r"(f32|bf16)\[%d,(768,%d|%d,768)\]" % (e, d, d)
     moved = [line.strip()[:160] for line in text.splitlines()
              if re.search(r"= %s\S* (copy|convert|fusion|transpose)\("
@@ -302,24 +306,34 @@ def test_grouped_swiglu_compiles_for_v5e(one_chip, name, monkeypatch):
 
 # An attention layer's decode half at granite-4.0-h-micro's pool size (4
 # attention layers, 16 slots x 48 pages + the trash page, pages of 16, 8
-# key/value heads of 64, 32 query heads) and, as the control that always
-# passed, at the dense cell's heads of 128: 16 rows appended to the
-# donated K and V pools, then the paged read.  What is compiled is what
-# ``granite_hybrid.decode_step`` runs a layer, without its weights.
+# key/value heads of 64, 32 query heads), at the dense cell's heads of 128
+# over as many pages, and at Laguna-S-2.1's two full layers (16 slots x 832
+# pages + 1, 8 key/value heads of 128, 48 query heads): 16 rows appended
+# to the donated K and V pools, then the paged read, with the TPU's
+# branches taken: the ``fori_loop`` over granite's folded pools, the
+# paged-attention kernel (``ops/paged_attention.py``) over the two that
+# keep their heads' axis.  What is compiled is what the blocks'
+# ``decode_step`` runs a layer, without its weights.
 KV_CASES = {
-    # name: (key/value heads, head width, query heads a key/value head)
-    "granite_heads_of_64": (8, 64, 4),
-    "dense_heads_of_128": (16, 128, 1),
+    # name: (key/value heads, head width, query heads a key/value head,
+    #        layers, pages a slot, the kernel's pages a block or 0: loop)
+    "granite_heads_of_64": (8, 64, 4, 4, 48, 0),
+    "dense_heads_of_128": (16, 128, 1, 4, 48, 8),
+    "laguna_heads_of_128": (8, 128, 6, 2, 832, 8),
 }
 
 
-def _kv_layer_program(one_chip, pool_shape, heads, head_dim, group):
+def _kv_layer_program(one_chip, monkeypatch, pool_shape, heads, head_dim,
+                      group):
     """-> the compiled append + paged read over two donated float32 pools
-    of ``pool_shape``, and one pool's logical bytes."""
+    of ``pool_shape`` as a TPU traces them, and one pool's logical
+    bytes."""
     from mxnet_tpu.ops.attention import paged_decode_attention
     from mxnet_tpu.serve.kv_cache import append_rows
 
-    slots, max_pages, page = 16, 48, 16
+    slots, page = 16, 16
+    max_pages = (pool_shape[1] - 1) // slots
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
     def sds(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -364,9 +378,22 @@ def _whole_pool_copies(text, pool_shape):
     return copies, prefetches
 
 
+def _layer_slices(text, pool_shape):
+    """The lines of a compiled text whose result is one layer of a pool
+    (what ``pool[layer]`` in front of a custom call materialises)."""
+    layer = ",".join(str(n) for n in pool_shape[1:])
+    return [line.strip()[:140] for line in text.splitlines()
+            if re.search(r"= f32\[(1,)?%s\]" % layer, line)]
+
+
+def _kernel_calls(text, name):
+    """The ``pallas_call``s named ``name`` in a compiled text."""
+    return re.findall(r"%%%s[.\d]* = " % name, text)
+
+
 @pytest.mark.parametrize("name", sorted(KV_CASES))
-def test_kv_append_and_paged_read_leave_the_pools_where_they_lie(one_chip,
-                                                                 name):
+def test_kv_append_and_paged_read_leave_the_pools_where_they_lie(
+        one_chip, monkeypatch, name):
     """The pools' layout at rest is the cache's rule (``kv_pool_shape``):
     under it the two pools are arguments of their logical size, the result
     aliases them and no operation of the compiled text copies a whole
@@ -376,36 +403,80 @@ def test_kv_append_and_paged_read_leave_the_pools_where_they_lie(one_chip,
     ``copy-done`` into ``S(1)``, 101.6 MB of temporaries); the cell's
     executables, with 12.8 GB of weights to stream, have no such move
     (read in their text at PR 38: PERF.md), so one is allowed and no
-    more."""
+    more.  **Which reader**: pools that keep their heads' axis are read by
+    the paged-attention kernel, which carries its block in its name, takes
+    the pools whole (no slice of a layer in front of it, no temporaries)
+    and leaves no loop; Mosaic takes its double buffer within the
+    ``vmem_limit_bytes`` it states (the compile is the check), well under
+    the chip's 128 MiB.  Folded pools keep the loop."""
+    from mxnet_tpu.ops import paged_attention
     from mxnet_tpu.serve.kv_cache import kv_pool_shape
 
-    heads, head_dim, group = KV_CASES[name]
-    shape = kv_pool_shape(4, 16 * 48 + 1, 16, heads, head_dim)
-    compiled, logical = _kv_layer_program(one_chip, shape, heads, head_dim,
-                                          group)
+    heads, head_dim, group, layers, max_pages, pages = KV_CASES[name]
+    shape = kv_pool_shape(layers, 16 * max_pages + 1, 16, heads, head_dim)
+    compiled, logical = _kv_layer_program(one_chip, monkeypatch, shape,
+                                          heads, head_dim, group)
     memory = compiled.memory_analysis()
     small = 1 << 20     # q, k, v, tables, lengths and their padding
     assert 2 * logical <= memory.argument_size_in_bytes < 2 * logical + small
     assert memory.alias_size_in_bytes >= 2 * logical
-    copies, prefetches = _whole_pool_copies(compiled.as_text(), shape)
+    text = compiled.as_text()
+    copies, prefetches = _whole_pool_copies(text, shape)
     assert not copies, copies
-    assert len(prefetches) <= 1, prefetches
-    assert memory.temp_size_in_bytes < len(prefetches) * logical + small
+    loops = len(re.findall(r" while\(", text))
+    if not pages:
+        assert len(shape) == 4 and loops == 1
+        assert "tpu_custom_call" not in text
+        assert len(prefetches) <= 1, prefetches
+        assert memory.temp_size_in_bytes < len(prefetches) * logical + small
+        return
+    assert paged_attention.pages_per_block(16, max_pages) == pages
+    assert len(_kernel_calls(text, paged_attention.kernel_name(pages))) == 1
+    assert not loops and not prefetches
+    assert not _layer_slices(text, shape), _layer_slices(text, shape)
+    assert memory.temp_size_in_bytes < small
+    assert paged_attention._vmem_bytes(
+        pages, 16, heads, 8, head_dim) < 16 << 20
 
 
-def test_unfolded_heads_of_64_cost_the_whole_pool(one_chip):
+def test_unfolded_heads_of_64_cost_the_whole_pool(one_chip, monkeypatch):
     """The control of the test above, and why the rule exists: the same
     program over pools that keep heads of 64 on an axis of their own
     (the layout before PR 38) pads the pools at rest (117.5 MB for 100.8)
     and copies them whole around the 16-row append."""
-    heads, head_dim, group = KV_CASES["granite_heads_of_64"]
+    heads, head_dim, group = KV_CASES["granite_heads_of_64"][:3]
     shape = (4, 16 * 48 + 1, 16, heads, head_dim)
-    compiled, logical = _kv_layer_program(one_chip, shape, heads, head_dim,
-                                          group)
+    compiled, logical = _kv_layer_program(one_chip, monkeypatch, shape,
+                                          heads, head_dim, group)
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes > 2 * logical + (16 << 20)
     assert memory.temp_size_in_bytes > 2 * logical
     assert len(_whole_pool_copies(compiled.as_text(), shape)[0]) >= 4
+
+
+def test_a_layer_sliced_in_front_of_the_kernel_is_found(one_chip,
+                                                        monkeypatch):
+    """The control of ``_layer_slices``: the same append and read with
+    the kernel handed ``pool[layer]`` (as a pool of one layer) instead of
+    the pool and the layer's number holds a slice of each pool's layer,
+    the kernel's operand, and a layer's bytes of temporaries."""
+    from mxnet_tpu.ops import attention
+    from mxnet_tpu.serve.kv_cache import kv_pool_shape
+
+    heads, head_dim, group, layers, max_pages, _ = KV_CASES[
+        "dense_heads_of_128"]
+    shape = kv_pool_shape(layers, 16 * max_pages + 1, 16, heads, head_dim)
+    whole = attention.paged_attention
+
+    def sliced(q, k_pool, v_pool, layer, *rest):
+        return whole(q, k_pool[layer][None], v_pool[layer][None], 0, *rest)
+
+    monkeypatch.setattr(attention, "paged_attention", sliced)
+    compiled, logical = _kv_layer_program(one_chip, monkeypatch, shape,
+                                          heads, head_dim, group)
+    assert len(_layer_slices(compiled.as_text(), shape)) >= 2
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        >= logical // layers
 
 
 # A latent-attention layer's decode half at the two latent cells' pool
@@ -596,6 +667,65 @@ def _laguna_program(one_chip, monkeypatch, bucket):
         compiler_options=laguna.compiler_options("tpu")), shapes, notes
 
 
+def _decode_reads_by_kernel(text, pool_shape, scope, layers):
+    """A decode executable's compiled text holds ``layers``
+    paged-attention kernels of 8 pages a block, no ``while`` that the
+    trace put under ``scope`` (the attention's; the expert layers'
+    ``searchsorted`` loops are elsewhere) and no slice of a pool's
+    layer."""
+    from mxnet_tpu.ops import paged_attention
+
+    assert len(_kernel_calls(text, paged_attention.kernel_name(8))) == layers
+    assert not re.findall(r" while\([^\n]*op_name=\"[^\"]*%s[^\"]*\""
+                          % scope, text)
+    assert not _layer_slices(text, pool_shape), _layer_slices(text,
+                                                              pool_shape)
+
+
+def test_dense_decode_step_compiles_for_v5e_with_the_kernel_in(one_chip,
+                                                               monkeypatch):
+    """``cgpt1.3b-chat``'s decode executable at Cerebras-GPT-1.3B's
+    published widths (24 layers, d 2048, 16 heads of 128, ffn 8192,
+    vocabulary 50257) over the cell's cache (16 slots x 48 pages of 16 +
+    the trash page: two pools of 2.42 GB): all 24 layers' attention is the
+    paged-attention kernel, the program holds no loop at all, no slice of
+    a pool's layer and no whole-pool copy, and the donated pools are
+    updated where they lie."""
+    from mxnet_tpu import serve
+    from mxnet_tpu.serve import kv_cache
+    from mxnet_tpu.serve import model as serve_model
+
+    cfg = serve.ModelConfig(vocab_size=50257, num_layers=24, d_model=2048,
+                            num_heads=16, max_len=2048)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    slots, table, page, i32 = 16, 48, 16, jnp.int32
+    params = {k: sds(v.shape) for k, v in jax.eval_shape(
+        lambda: serve_model.init_params(cfg)).items()}
+    shape = kv_cache.kv_pool_shape(24, slots * table + 1, page, 16, 128)
+    pools = {"k_pool": sds(shape), "v_pool": sds(shape)}
+
+    def step(params, tokens, lengths, tables, pools):
+        return serve_model.decode_step(params, tokens, lengths, tables,
+                                       pools, {}, cfg, page, exact=False)
+
+    with jax.default_matmul_precision("default"), \
+            serve_model.trace_notes() as notes:
+        lowered = jax.jit(step, donate_argnums=4).lower(
+            params, sds((slots,), i32), sds((slots,), i32),
+            sds((slots, table), i32), pools)
+    assert notes == {"paged_kernel_layers": 24}
+    compiled = lowered.compile()
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    _decode_reads_by_kernel(text, shape, "", 24)
+    assert not _whole_pool_copies(text, shape)[0]
+    assert memory.alias_size_in_bytes >= 2 * 4 * math.prod(shape)
+    assert memory.temp_size_in_bytes < 64 << 20
+
+
 def _loop_conditions(text, scope):
     """The condition computations of the ``while`` operations that the
     trace put under ``scope``, as the compiled text writes them."""
@@ -624,9 +754,10 @@ def test_laguna_executables_compile_for_v5e_at_the_published_widths(
     from mxnet_tpu.ops.grouped_matmul import kernel_name
 
     compiled, shapes, notes = _laguna_program(one_chip, monkeypatch, bucket)
-    assert notes == {"expert_kernel_layers": 4}
+    assert notes == dict({"expert_kernel_layers": 4},
+                         **({} if bucket else {"paged_kernel_layers": 2}))
     text = compiled.as_text()
-    assert len(re.findall(r"%%%s[.\d]* = " % kernel_name(tile), text)) == 4
+    assert len(_kernel_calls(text, kernel_name(tile))) == 4
     memory = compiled.memory_analysis()
     held = 4 * sum(math.prod(shape) for shape in shapes.values())
     assert shapes["kw_pool"][2] == 512
@@ -636,6 +767,10 @@ def test_laguna_executables_compile_for_v5e_at_the_published_widths(
     for shape in (shapes["k_pool"], shapes["kw_pool"]):
         copies, _ = _whole_pool_copies(text, shape)
         assert not copies, copies
+    if not bucket:
+        # the two full layers' reads are the paged-attention kernel over
+        # the whole pools: no loop under their scope, no layer sliced out
+        _decode_reads_by_kernel(text, shapes["k_pool"], "gqa_decode", 2)
     if bucket:
         # one loop a full layer over the gathered table's 26 blocks of 512
         # keys, and its trip count is data (the chunk's furthest horizon):
