@@ -889,13 +889,13 @@ def measure(argv=None):
     _RESULT["gw_counters"] = dict(gw.counters)
 
     # -- hybrid long-context A/B: O(1) per-slot serving memory -----------
-    # Windowed-ring + SSM stacks against full attention at a FIXED
-    # pool-byte budget.  Two acceptance bars: the hybrid stack reserves
-    # no pages (admission is slot-bounded), so peak concurrency at the
-    # same pool bytes must be >= 2x; and its per-slot state is constant
-    # in context length, so per-token decode latency must stay flat as
-    # the context jumps 4k -> 32k (the full-attention pool could not
-    # even HOLD those contexts).
+    # An all-window stack (the model states its layers) against full
+    # attention at a FIXED pool-byte budget.  Two acceptance bars: it
+    # reserves no pages (admission is slot-bounded), so peak concurrency
+    # at the same pool bytes must be >= 2x; and its per-slot state is
+    # constant in context length, so per-token decode latency must stay
+    # flat as the context jumps 4k -> 32k (the full-attention pool could
+    # not even HOLD those contexts).
     hyb_window = 16
     ab_max_new = 112  # 144-token requests: context >> window
     long_cfg = serve.ModelConfig(vocab_size=128, num_layers=2,
@@ -903,12 +903,13 @@ def measure(argv=None):
     long_params = serve_model.init_params(long_cfg, seed=0)
     ab_base = _dc.replace(sconf, slots=8, buckets=(32,),
                           max_new=ab_max_new)
-    hyb_conf = _dc.replace(ab_base, num_pages=1, layers="window,ssm",
-                           window=hyb_window)
-    hyb_ab = serve.InferenceSession(long_params, num_heads=2,
-                                    config=hyb_conf)
-    # executable count frozen: hybrid changes executable ARGUMENTS
-    # (ring/state pools), never the executable set
+    hyb_conf = _dc.replace(ab_base, num_pages=1)
+    hyb_ab = serve.InferenceSession(
+        long_params, config=hyb_conf, model=_dc.replace(
+            long_cfg, layer_types=("sliding_attention",) * 2,
+            sliding_window=hyb_window))
+    # executable count frozen: windowed layers change executable
+    # ARGUMENTS (ring pools), never the executable set
     assert len(hyb_ab.executables) == len(hyb_conf.buckets) + 1
     # the full-attention side gets the hybrid footprint as its page
     # budget — the fixed-pool-bytes framing of the capacity claim

@@ -368,13 +368,11 @@ def apply_serve(config, params, store=None):
     ``ServeConfig`` (called by ``InferenceSession`` only when the
     caller did NOT pass an explicit config).  Applies ``quant``,
     ``kv_quant`` (int8/fp8 KV-cache pages), ``buckets``,
-    ``prefix_pages`` (prefix-cache retention size), ``watermark``
+    ``prefix_pages`` (prefix-cache retention size) and ``watermark``
     (preemption free-pool floor; inert until the caller turns
-    ``oversub`` on), and the hybrid-stack pair ``layers`` /
-    ``window`` (per-layer kind pattern + sliding-window length — a
-    tuner that found windowed/SSM layers hold quality can pin the O(1)
-    memory stack); anything the record doesn't carry
-    keeps the env/default value.  No-op unless ``MXNET_AUTOTUNE`` is on
+    ``oversub`` on): capacity and precision, never what the model
+    computes layer by layer.  Anything the record doesn't carry keeps
+    the env/default value.  No-op unless ``MXNET_AUTOTUNE`` is on
     and a record exists for this (model-fingerprint, backend)."""
     if not autotune_enabled():
         return config
@@ -398,10 +396,6 @@ def apply_serve(config, params, store=None):
         updates["prefix_pages"] = int(knobs["prefix_pages"])
     if "watermark" in knobs:
         updates["watermark"] = int(knobs["watermark"])
-    if "layers" in knobs:
-        updates["layers"] = str(knobs["layers"])
-    if "window" in knobs:
-        updates["window"] = int(knobs["window"])
     if not updates:
         return config
     note_applied(rec, where="InferenceSession",

@@ -8,7 +8,6 @@ from .registry import OpDef, register, get, list_ops, invoke, FrozenAttrs
 
 # register all built-in op families
 from . import attention     # noqa: F401  (kernel library, no op names)
-from . import ssm_ops       # noqa: F401  (kernel library, no op names)
 from . import math_ops      # noqa: F401
 from . import matrix_ops    # noqa: F401
 from . import nn_ops        # noqa: F401

@@ -58,11 +58,10 @@ from .model import _mm, check_param_shapes
 BLOCK = "bailing_hybrid"
 
 # ServeConfig features a session over this block refuses at construction
-REFUSES = ("spec_k", "kv_quant", "layers / window")
+REFUSES = ("spec_k", "kv_quant")
 REFUSES_WHY = ("a rejected draft would need the state before it, and "
                "nothing snapshots a slot's state; neither the state nor a "
-               "latent row has a row to scale; the layer pattern is the "
-               "model's: ROADMAP M3, M4")
+               "latent row has a row to scale: ROADMAP M3, M4")
 
 # moe_stats columns: what the routers and the recurrent layers did.
 # assignments_asked: real rows x experts a token, of all the experts;
@@ -109,9 +108,6 @@ def validate(cfg):
             or set(cfg.layer_types) - {"kda", "mla"}:
         raise MXNetError("layer_types %r: %d layers, each \"kda\" or "
                          "\"mla\"" % (cfg.layer_types, cfg.num_layers))
-    if cfg.layer_kinds or cfg.window:
-        raise MXNetError("block %r takes its layer pattern from layer_types"
-                         % BLOCK)
     latent_moe.validate_ffn(cfg)
     return cfg
 

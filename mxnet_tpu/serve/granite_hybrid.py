@@ -58,11 +58,10 @@ from .model import decode_report  # noqa: F401
 BLOCK = "granitemoehybrid"
 
 # ServeConfig features a session over this block refuses at construction
-REFUSES = ("spec_k", "kv_quant", "layers / window")
+REFUSES = ("spec_k", "kv_quant")
 REFUSES_WHY = ("a rejected draft would need the state before it, and "
                "nothing snapshots a slot's state; the state is a float32 "
-               "accumulator with no row to scale; the layer pattern is "
-               "the model's: ROADMAP M4")
+               "accumulator with no row to scale: ROADMAP M4")
 
 # ssm_stats columns
 COLUMNS = ("decode_steps", "prefill_chunks", "rows_valid", "rows_padded",
@@ -96,9 +95,6 @@ def validate(cfg):
     if cfg.mamba_n_heads % cfg.mamba_n_groups:
         raise MXNetError("mamba_n_heads %d over mamba_n_groups %d"
                          % (cfg.mamba_n_heads, cfg.mamba_n_groups))
-    if cfg.layer_kinds or cfg.window:
-        raise MXNetError("block %r takes its layer pattern from layer_types"
-                         % BLOCK)
     if not cfg.tie_word_embeddings:
         raise MXNetError("block %r has no untied head" % BLOCK)
     return cfg

@@ -88,9 +88,8 @@ besides pages, as the block's ``state_shapes(cfg)`` names it: name ->
 
 (the Mamba-2 block's ``ssm_state`` of (heads, head width, state size) and
 ``conv_state`` of (taps - 1, channels); the KDA block's ``kda_state`` of
-(heads, key width, value width) and ``conv_state``; the GPT-2 block's
-``"ssm"`` kind's (H, D, D) ``ssm_state``).  No page indexes it: a slot's rows are zeroed
-at :meth:`alloc`, a request's chunks carry them from one prefill dispatch
+(heads, key width, value width) and ``conv_state``).  No page indexes it:
+a slot's rows are zeroed at :meth:`alloc`, a request's chunks carry them from one prefill dispatch
 to the next, and a cache that has any is ``hybrid``: the prefix index is
 off and prefill takes the slot.  Only ``"full"`` layers own pages, so the
 page pools' layer axis is their count and their head axis the key/value
@@ -103,11 +102,11 @@ only place that knows its layout):
 
 No page indexes it: every slot owns ``ring_pages * page_size`` rows a
 windowed layer for the session's life, and position ``p`` of a slot's
-request lies in row ``p % rows``.  Who sizes it: the GPT-2 block's
-``layers`` / ``window`` path by ``ServeConfig.ring_pages`` (the window
-plus the largest write span, because its dispatches write a whole bucket
-into the ring before they read); a stated block whose model has a window
-(``serve/laguna.py``) by the model's window alone, rounded up to whole
+request lies in row ``p % rows``.  Who sizes it: the block, whose
+``ring_pages(model, config)`` the session asks.  The GPT-2 block's rule
+is the model's window plus the largest write span, because its
+dispatches write a whole bucket into the ring before they read;
+``serve/laguna.py``'s is the model's window alone, rounded up to whole
 pages, whatever the buckets are: its prefill reads the rows from before a
 chunk, attends over them and the chunk's own rows, and only then folds
 the chunk's last rows in.  The step functions never compute a row's index
@@ -865,15 +864,13 @@ class PagedKVCache:
         on-table-mutation contract holds): tables do not change here,
         and lengths re-upload every step anyway.
 
-        Hybrid stacks stay O(1) too.  Window rings: the position -> ring
-        row map is deterministic, so the rejected rows' ring slots are
-        exactly the ones the re-issued positions overwrite next step,
-        and the windowed mask (driven by the rolled-back length) never
-        reads them in between — rolling back ``lengths`` IS rolling back
-        the ring position.  SSM state: the verify executable selects the
-        committed snapshot in-graph before returning (see
-        ``model.verify_step``), so by the time the host truncates, the
-        state pool already holds the post-commit state."""
+        Window rings stay O(1) too: the position -> ring row map is
+        deterministic, so the rejected rows' ring slots are exactly the
+        ones the re-issued positions overwrite next step, and the
+        windowed mask (driven by the rolled-back length) never reads
+        them in between — rolling back ``lengths`` IS rolling back the
+        ring position.  Slot-private state has no row to roll back: the
+        blocks that keep any refuse ``spec_k``."""
         if slot not in self._pages_of:
             raise MXNetError("truncate of unallocated slot %r" % (slot,))
         n = int(n_tokens)

@@ -79,11 +79,10 @@ BLOCK = "laguna"
 KINDS = ("full_attention", "sliding_attention")
 
 # ServeConfig features a session over this block refuses at construction
-REFUSES = ("spec_k", "kv_quant", "layers / window")
+REFUSES = ("spec_k", "kv_quant")
 REFUSES_WHY = ("a draft's rejected rows would already have overwritten "
                "ring rows that the committed stream still sees; the rings "
-               "here have no scale pool; the layer pattern is the model's: "
-               "ROADMAP M2")
+               "here have no scale pool: ROADMAP M2")
 
 # moe_stats columns: latent_moe._ffn_held's counts
 MOE_COLUMNS = ("assignments_asked", "assignments_held",
@@ -152,12 +151,13 @@ def rope_frequencies(group, head_dim):
         float(group["attention_factor"])
 
 
-def ring_pages(cfg, page_size):
-    """Pages of a slot's ring in every window layer: the model's window
-    in whole pages.  Decode overwrites the one row that has just left the
-    band, and prefill reads the ring before it writes, so no row more is
-    needed, whatever the buckets."""
-    return -(-cfg.sliding_window // page_size)
+def ring_pages(cfg, serve):
+    """Pages of a slot's ring in every window layer, under the
+    ``ServeConfig`` ``serve``: the model's window in whole pages.  Decode
+    overwrites the one row that has just left the band, and prefill reads
+    the ring before it writes, so no row more is needed, whatever the
+    buckets."""
+    return -(-cfg.sliding_window // serve.page_size)
 
 
 def validate(cfg):
@@ -200,9 +200,6 @@ def validate(cfg):
         raise MXNetError(
             "the shared expert (%d wide) is a whole number of experts of %d"
             % (cfg.shared_expert_intermediate_size, cfg.moe_d_ff))
-    if cfg.layer_kinds or cfg.window:
-        raise MXNetError("block %r takes its layer pattern from layer_types "
-                         "and its window from sliding_window" % BLOCK)
     if cfg.tie_word_embeddings:
         raise MXNetError("block %r has no tied head" % BLOCK)
     latent_moe.validate_ffn(_ffn_cfg(cfg))

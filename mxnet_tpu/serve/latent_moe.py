@@ -86,9 +86,9 @@ from .model import _mm, _resolve_params, check_param_shapes, note_traced
 BLOCK = "deepseek_v3"
 
 # ServeConfig features a session over this block refuses at construction
-REFUSES = ("spec_k", "kv_quant", "layers / window")
+REFUSES = ("spec_k", "kv_quant")
 REFUSES_WHY = ("speculative rows in a latent pool, a scale for a latent "
-               "row, windowed latent layers: ROADMAP M3")
+               "row: ROADMAP M3")
 
 # moe_stats columns before the per-(expert layer, expert) load
 DECODE_STEPS, PREFILL_CHUNKS, ASKED, COMPUTED, DISTINCT, _HEADER = range(6)
@@ -106,8 +106,6 @@ def validate(cfg):
     if cfg.qk_rope_head_dim % 2:
         raise MXNetError("qk_rope_head_dim %d is not even"
                          % cfg.qk_rope_head_dim)
-    if cfg.layer_kinds or cfg.window:
-        raise MXNetError("block %r has no windowed or SSM layers" % BLOCK)
     validate_ffn(cfg)
     return cfg
 

@@ -82,17 +82,20 @@ class ModelConfig:
     """Static decoder geometry (everything the traced functions close
     over).
 
-    ``layer_kinds``/``window`` describe a hybrid stack: per layer,
-    ``"full"`` (paged full-context attention), ``"window"`` (sliding-
-    window attention over the last ``window`` keys, ring-buffered KV),
-    or ``"ssm"`` (linear-attention recurrence, O(1) state — see
-    ``ops/ssm_ops.py``).  All kinds reuse the block's existing
-    ``attn_in``/``attn_out`` weights, so any attention checkpoint hosts
-    any stack.  The empty tuple means all-full (the classic decoder).
+    A layer's kind is stated once, here: ``layer_types`` names every
+    layer in the block's own words and :attr:`kinds` maps them to the
+    cache's (``"full"`` owns pages, ``"window"`` a ring, ``"ssm"`` only
+    slot-private state).  No ``ServeConfig`` field and no environment
+    variable says what a layer is.
 
     ``block`` names the architecture, a key of :data:`BLOCKS`.  ``"gpt2"``
     (learned positions, LayerNorm, GELU, biased fused-QKV heads) is what
-    :func:`config_from_params` infers from a parameter dict;
+    :func:`config_from_params` infers from a parameter dict, every layer
+    full attention; a stack with sliding-window layers states
+    ``layer_types`` of ``"full_attention"`` | ``"sliding_attention"`` and
+    ``sliding_window`` (ring-buffered K/V, the last ``sliding_window``
+    keys; every layer keeps the ``attn_in`` / ``attn_out`` weights, so any
+    such checkpoint hosts any such stack);
     ``"deepseek_v3"`` (``latent_moe.py``: RMSNorm, RoPE, latent
     attention, SwiGLU, routed and shared experts, no bias, no position
     table) cannot be inferred from shapes, so it is stated: the fields
@@ -128,8 +131,6 @@ class ModelConfig:
     d_model: int
     num_heads: int
     max_len: int          # the context ceiling (gpt2: pos_embed rows)
-    window: int = 0       # sliding-window length for "window" layers
-    layer_kinds: tuple = ()  # per-layer kind; () = all "full"
     block: str = "gpt2"
     qk_nope_head_dim: int = 0   # per-head query/key width without RoPE
     qk_rope_head_dim: int = 0   # rotated width; one shared key a token
@@ -150,6 +151,7 @@ class ModelConfig:
     layer_types: tuple = ()     # per layer "mamba" | "attention", or
     #                             "kda" | "mla" (bailing_hybrid), or
     #                             "full_attention" | "sliding_attention"
+    #                             (laguna, gpt2; gpt2: () = all full)
     mamba_n_heads: int = 0
     mamba_d_head: int = 0
     mamba_d_state: int = 0
@@ -207,7 +209,7 @@ class ModelConfig:
                           "kda": "ssm", "full_attention": "full",
                           "sliding_attention": "window"}.get(t, t)
                          for t in self.layer_types)
-        return self.layer_kinds or ("full",) * self.num_layers
+        return ("full",) * self.num_layers
 
     @property
     def hybrid(self):
@@ -226,16 +228,17 @@ def validate(cfg):
     if cfg.d_model % cfg.num_heads:
         raise MXNetError("d_model %d not divisible by num_heads %d"
                          % (cfg.d_model, cfg.num_heads))
-    if cfg.layer_kinds:
-        if len(cfg.layer_kinds) != cfg.num_layers:
-            raise MXNetError("layer_kinds %r does not cover %d layers"
-                             % (cfg.layer_kinds, cfg.num_layers))
-        bad = set(cfg.layer_kinds) - {"full", "window", "ssm"}
+    if cfg.layer_types:
+        bad = set(cfg.layer_types) - {"full_attention", "sliding_attention"}
         if bad:
-            raise MXNetError("unknown layer kinds %r" % sorted(bad))
-        if "window" in cfg.layer_kinds and cfg.window < 1:
-            raise MXNetError("windowed layers need window >= 1 (got %d)"
-                             % cfg.window)
+            raise MXNetError("block 'gpt2' runs full_attention and "
+                             "sliding_attention layers, not %r" % sorted(bad))
+        if len(cfg.layer_types) != cfg.num_layers:
+            raise MXNetError("layer_types %r does not cover %d layers"
+                             % (cfg.layer_types, cfg.num_layers))
+        if "sliding_attention" in cfg.layer_types and cfg.sliding_window < 1:
+            raise MXNetError("sliding_attention layers need sliding_window "
+                             ">= 1 (got %d)" % cfg.sliding_window)
     return cfg
 
 
@@ -300,14 +303,9 @@ def latent_dim(cfg):
 
 def state_shapes(cfg):
     """Slot-private recurrent state beside the pages, as the cache
-    builds it: name -> (layers, one slot's shape a layer, dtype).  The
-    ``"ssm"`` kind's (H, D, D) float32 recurrence state, fp32 whatever
-    ``kv_quant`` says: it is a running accumulator, not KV rows."""
-    n_ssm = cfg.kinds.count("ssm")
-    if not n_ssm:
-        return {}
-    return {"ssm_state": (n_ssm, (cfg.num_heads, cfg.head_dim,
-                                  cfg.head_dim), "float32")}
+    builds it: name -> (layers, one slot's shape a layer, dtype).  None:
+    this block's layers are attention, over pages or over a ring."""
+    return {}
 
 
 def init_counters(cfg):
@@ -363,14 +361,32 @@ def prefill_block(max_pages, page_size, exact):
     return page_size
 
 
+def ring_pages(cfg, serve):
+    """Pages of a slot's ring in every windowed layer, under the
+    ``ServeConfig`` ``serve``.
+
+    A dispatch writes up to ``span`` rows (the largest prefill chunk, or
+    the speculative window) before its queries read, so a ring must hold
+    the window plus the whole span minus the row that overlaps
+    (``sliding_window + span - 1`` rows) for no visible key to be
+    overwritten mid-dispatch — plus one extra page because the rotated
+    gather (:func:`_ring_gather`) is page-granular: the newest page may
+    be only one row full, yet the gather must still reach
+    ``sliding_window + span - 1`` rows below that row."""
+    span = max(max(serve.buckets), serve.spec_window if serve.spec_k else 1)
+    return -(-(cfg.sliding_window + span - 1) // serve.page_size) + 1
+
+
 def guard_tag(cfg):
     """What the recompile guard's name must tell apart beyond the widths:
-    a hybrid stack adds ring/state pool avals (and a window length baked
-    into every trace), so it must never share a guard with the classic
-    stack — window length plus the per-layer kind initials (f/w/s)."""
+    a stack with windowed layers adds ring pool avals (and a window
+    length baked into every trace), so it must never share a guard with
+    the classic stack — window length plus the per-layer kind initials
+    (f/w)."""
     if not cfg.hybrid:
         return ""
-    return "-w%d%s" % (cfg.window, "".join(k[0] for k in cfg.kinds))
+    return "-w%d%s" % (cfg.sliding_window,
+                       "".join(k[0] for k in cfg.kinds))
 
 
 def _resolve_params(params):
@@ -503,23 +519,6 @@ def _kv_fake_quant(k, v, kv_quant):
     return _fq(k), _fq(v)
 
 
-def _qkv_heads(params, i, x, cfg, exact):
-    """Shared sublayer head: pre-norm + in-projection + head split.
-    Returns (q, k, v) as (n, H, T, D) — identical ops for every layer
-    kind, so hybrid stacks share the projection's bit pattern."""
-    import jax.numpy as jnp
-
-    n, t, _ = x.shape
-    h, d = cfg.num_heads, cfg.head_dim
-    hdn = _layer_norm(x, params["blk%d_ln1_gamma" % i],
-                      params["blk%d_ln1_beta" % i])
-    qkv = _mm(hdn, params["blk%d_attn_in_weight" % i], exact) \
-        + params["blk%d_attn_in_bias" % i]
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-    return (_attn_heads(q, n, t, h, d), _attn_heads(k, n, t, h, d),
-            _attn_heads(v, n, t, h, d))
-
-
 def _block_attention(params, i, x, cfg, exact, block, kv_quant="",
                      window=0):
     """One pre-norm attention sublayer on (n, T, C); returns the
@@ -529,8 +528,16 @@ def _block_attention(params, i, x, cfg, exact, block, kv_quant="",
     attention, mirroring what a paged reader reconstructs.  ``window``
     restricts attention to the last ``window`` keys (the windowed-layer
     reference path)."""
+    import jax.numpy as jnp
+
     n, t, c = x.shape
-    q, k, v = _qkv_heads(params, i, x, cfg, exact)
+    h, d = cfg.num_heads, cfg.head_dim
+    hdn = _layer_norm(x, params["blk%d_ln1_gamma" % i],
+                      params["blk%d_ln1_beta" % i])
+    qkv = _mm(hdn, params["blk%d_attn_in_weight" % i], exact) \
+        + params["blk%d_attn_in_bias" % i]
+    q, k, v = (_attn_heads(part, n, t, h, d)
+               for part in jnp.split(qkv, 3, axis=-1))
     k, v = _kv_fake_quant(k, v, kv_quant)
     ctx = flash_attention(q, k, v, causal=True, block=block, mi=exact,
                           window=window)
@@ -538,37 +545,6 @@ def _block_attention(params, i, x, cfg, exact, block, kv_quant="",
     out = _mm(ctx, params["blk%d_attn_out_weight" % i], exact) \
         + params["blk%d_attn_out_bias" % i]
     return x + out, (k, v)
-
-
-def _block_ssm(params, i, x, cfg, exact, state0=None, row_valid=None,
-               collect=False):
-    """One SSM (linear-attention) sublayer on (n, T, C): the recurrence
-    of ``ops/ssm_ops.py`` fed by the block's own q/k/v projections.
-    ``state0`` (n, H, D, D) fp32 is the pre-scan state (zeros for a
-    from-scratch forward); ``row_valid`` masks bucket padding out of the
-    state.  Returns (x + out, state[, states]) — ``states`` (T, n, H, D,
-    D) per-row snapshots when ``collect`` (the verify step's O(1)
-    rollback source).  K/V are consumed in-register and never stored,
-    so ``kv_quant`` does not apply (the state pool is fp32)."""
-    import jax.numpy as jnp
-
-    from ..ops.ssm_ops import ssm_decay, ssm_scan
-
-    n, t, c = x.shape
-    q, k, v = _qkv_heads(params, i, x, cfg, exact)
-    # scan wants rows-major (n, T, H, D)
-    q, k, v = (q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-               v.transpose(0, 2, 1, 3))
-    if state0 is None:
-        state0 = jnp.zeros((n, cfg.num_heads, cfg.head_dim, cfg.head_dim),
-                           jnp.float32)
-    res = ssm_scan(q, k, v, state0, ssm_decay(cfg.num_heads),
-                   row_valid=row_valid, collect=collect)
-    y = res[0].astype(x.dtype)
-    ctx = y.reshape(n, t, c)
-    out = _mm(ctx, params["blk%d_attn_out_weight" % i], exact) \
-        + params["blk%d_attn_out_bias" % i]
-    return (x + out,) + res[1:]
 
 
 def _ring_gather(pools, which, i, pb_max, page_size, slot=None):
@@ -651,17 +627,10 @@ def full_forward(params, tokens, cfg, exact=None, block=None,
     x = x + params["pos_embed"][:, :t]
     kvs = []
     for i, kind in enumerate(cfg.kinds):
-        if kind == "ssm":
-            # serial scan from a zero state: the same per-row op
-            # sequence chunked prefill and recurrent decode run, so this
-            # forward stays the bit-exactness oracle for hybrid stacks
-            x, _ = _block_ssm(params, i, x, cfg, exact)
-            kvs.append(None)
-        else:
-            x, kv = _block_attention(
-                params, i, x, cfg, exact, block, kv_quant=kv_quant,
-                window=cfg.window if kind == "window" else 0)
-            kvs.append(kv)
+        x, kv = _block_attention(
+            params, i, x, cfg, exact, block, kv_quant=kv_quant,
+            window=cfg.sliding_window if kind == "window" else 0)
+        kvs.append(kv)
         x = _block_mlp(params, i, x, exact)
     x = _layer_norm(x, params["final_ln_gamma"], params["final_ln_beta"])
     logits = _mm(x, params["lm_head_weight"], exact) \
@@ -708,13 +677,11 @@ def prefill_forward(params, tokens, length, offset, table_row, pools,
     ``offset > 0``); their positions exceed every row's horizon, so
     nothing reads them.
 
-    Hybrid stacks: windowed layers scatter the chunk's rows into the
-    slot's ring (``kw_pool``/``vw_pool``, selected by the ``slot``
-    scalar) at ``abs_pos % ring_tokens`` and attend over the
-    position-labeled rotated ring gather; SSM layers advance the slot's
-    recurrence state (``ssm_state``) across the chunk in one
-    ``lax.scan`` — chunk padding passes the state through untouched.
-    The updated ring/state pools come back in the same mapping.
+    Windowed layers scatter the chunk's rows into the slot's ring
+    (``kw_pool``/``vw_pool``, selected by the ``slot`` scalar) at
+    ``abs_pos % ring_tokens`` and attend over the position-labeled
+    rotated ring gather.  The updated ring pools come back in the same
+    mapping.
     """
     import jax.numpy as jnp
 
@@ -740,18 +707,8 @@ def prefill_forward(params, tokens, length, offset, table_row, pools,
     pages = jnp.where(idx < max_pages,
                       table_row[jnp.clip(idx, 0, max_pages - 1)], trash)
     offsets = abs_pos % page_size
-    fi = wi = si = 0  # per-kind pool indices (static)
+    fi = wi = 0  # per-kind pool indices (static)
     for i, kind in enumerate(cfg.kinds):
-        if kind == "ssm":
-            state0 = jnp.take(pools["ssm_state"][si], slot, axis=0)[None]
-            rv = (offs < length).reshape(1, t_b)  # padding: state no-op
-            x, state = _block_ssm(params, i, x, cfg, exact, state0=state0,
-                                  row_valid=rv)
-            pools["ssm_state"] = \
-                pools["ssm_state"].at[si, slot].set(state[0])
-            si += 1
-            x = _block_mlp(params, i, x, exact)
-            continue
         hdn = _layer_norm(x, params["blk%d_ln1_gamma" % i],
                           params["blk%d_ln1_beta" % i])
         qkv = _mm(hdn, params["blk%d_attn_in_weight" % i], exact) \
@@ -772,7 +729,7 @@ def prefill_forward(params, tokens, length, offset, table_row, pools,
                 q.reshape(1, t_b, h, d).transpose(0, 2, 1, 3),
                 ctx_k.transpose(0, 2, 1, 3), ctx_v.transpose(0, 2, 1, 3),
                 row_valid, block=page_size, mi=exact, k_scale=ks,
-                v_scale=vs, window=cfg.window, k_positions=kp)
+                v_scale=vs, window=cfg.sliding_window, k_positions=kp)
             wi += 1
         else:
             # append the chunk's KV at its absolute rows (one vectorized
@@ -827,13 +784,12 @@ def decode_step(params, tokens, lengths, tables, pools, counters, cfg,
     there is no tensor here whose size depends on how many tokens any
     request has generated.
 
-    Hybrid stacks bound it further: windowed layers write
-    the token's KV at ``lengths % ring_tokens`` in the slot's ring and
-    attend over only ``ring_tokens`` rows (the rotated position-labeled
-    gather); SSM layers advance the (H, D, D) recurrence one step.
-    Idle slots harmlessly re-write their own ring row 0 / state (both
-    are re-initialized on alloc/prefill before anything reads them) —
-    the hybrid analog of idle slots writing the trash page.
+    Windowed layers bound it further: they write the token's KV at
+    ``lengths % ring_tokens`` in the slot's ring and attend over only
+    ``ring_tokens`` rows (the rotated position-labeled gather).  Idle
+    slots harmlessly re-write their own ring row 0 (prefill rewrites it
+    before anything reads it) — the ring's analog of idle slots writing
+    the trash page.
     """
     import jax.numpy as jnp
 
@@ -852,29 +808,8 @@ def decode_step(params, tokens, lengths, tables, pools, counters, cfg,
     page = jnp.take_along_axis(tables, page_slot[:, None], axis=1)[:, 0]
     offset = lengths % page_size
     slot_ids = jnp.arange(s)
-    fi = wi = si = 0
+    fi = wi = 0
     for i, kind in enumerate(cfg.kinds):
-        if kind == "ssm":
-            hdn = _layer_norm(x[:, None, :],
-                              params["blk%d_ln1_gamma" % i],
-                              params["blk%d_ln1_beta" % i])
-            qkv = _mm(hdn, params["blk%d_attn_in_weight" % i], exact) \
-                + params["blk%d_attn_in_bias" % i]
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-            from ..ops.ssm_ops import ssm_decay, ssm_scan
-
-            y, state = ssm_scan(q.reshape(s, 1, h, d),
-                                k.reshape(s, 1, h, d),
-                                v.reshape(s, 1, h, d),
-                                pools["ssm_state"][si], ssm_decay(h))
-            pools["ssm_state"] = pools["ssm_state"].at[si].set(state)
-            ctx = y.astype(x.dtype).reshape(s, cfg.d_model)
-            out = _mm(ctx, params["blk%d_attn_out_weight" % i], exact) \
-                + params["blk%d_attn_out_bias" % i]
-            x = x + out
-            x = _block_mlp(params, i, x, exact)
-            si += 1
-            continue
         hdn = _layer_norm(x, params["blk%d_ln1_gamma" % i],
                           params["blk%d_ln1_beta" % i])
         qkv = _mm(hdn, params["blk%d_attn_in_weight" % i], exact) \
@@ -895,7 +830,8 @@ def decode_step(params, tokens, lengths, tables, pools, counters, cfg,
                                    ctx_v.transpose(0, 2, 1, 3),
                                    lengths + 1, block=page_size, mi=exact,
                                    k_scale=ks, v_scale=vs,
-                                   window=cfg.window, k_positions=kp)
+                                   window=cfg.sliding_window,
+                                   k_positions=kp)
             wi += 1
         else:
             # append this token's KV at (page, offset); inactive slots
@@ -923,7 +859,7 @@ def decode_step(params, tokens, lengths, tables, pools, counters, cfg,
 
 
 def verify_step(params, tokens, lengths, tables, pools, counters, cfg,
-                page_size, exact=None, kv_quant="", limits=None):
+                page_size, exact=None, kv_quant=""):
     """Speculative-decoding verify: advance every slot ``W = K + 1``
     teacher-forced positions in ONE fixed-shape step.
 
@@ -950,17 +886,11 @@ def verify_step(params, tokens, lengths, tables, pools, counters, cfg,
     never alias a real page); such rows are never committed, so their
     garbage logits are dead by construction.
 
-    Hybrid-stack rollback is O(1) by construction.  Windowed layers
-    write all W rows into the ring at their deterministic slots
-    ``abs_pos % ring_tokens``; rejected rows need no undo — after the
-    host rolls ``lengths`` back, their ring rows label as positions
-    outside every future mask until the committed stream rewrites them.
-    SSM layers scan with ``collect=True`` and, because a recurrence has
-    no per-row storage to mask, the acceptance count is recomputed
-    IN-GRAPH (``limits``: (S,) int32 per-slot commit cap — the same
-    integer comparison the host's commit loop runs) to select each
-    slot's state snapshot at its commit point; only that snapshot is
-    written back, so a rejected suffix never touches committed state.
+    A windowed layer's rollback is O(1) by construction: it writes all W
+    rows into the ring at their deterministic slots ``abs_pos %
+    ring_tokens``, and rejected rows need no undo — after the host rolls
+    ``lengths`` back, their ring rows label as positions outside every
+    future mask until the committed stream rewrites them.
     """
     import jax.numpy as jnp
 
@@ -982,8 +912,7 @@ def verify_step(params, tokens, lengths, tables, pools, counters, cfg,
     pages = jnp.take_along_axis(tables, page_slot, axis=1)  # (S, W)
     offsets = abs_pos % page_size
     slot_ids = jnp.arange(s)
-    ssm_snaps = []          # (pool index, (W, S, H, D, D) snapshots)
-    fi = wi = si = 0
+    fi = wi = 0
     for i, kind in enumerate(cfg.kinds):
         hdn = _layer_norm(x, params["blk%d_ln1_gamma" % i],
                           params["blk%d_ln1_beta" % i])
@@ -992,20 +921,6 @@ def verify_step(params, tokens, lengths, tables, pools, counters, cfg,
         q, k, v = jnp.split(qkv, 3, axis=-1)
         k = k.reshape(s, w, h, d)
         v = v.reshape(s, w, h, d)
-        if kind == "ssm":
-            from ..ops.ssm_ops import ssm_decay, ssm_scan
-
-            y, _, snaps = ssm_scan(q.reshape(s, w, h, d), k, v,
-                                   pools["ssm_state"][si], ssm_decay(h),
-                                   collect=True)
-            ssm_snaps.append((si, snaps))
-            si += 1
-            ctx = y.astype(x.dtype).reshape(s, w, cfg.d_model)
-            out = _mm(ctx, params["blk%d_attn_out_weight" % i], exact) \
-                + params["blk%d_attn_out_bias" % i]
-            x = x + out
-            x = _block_mlp(params, i, x, exact)
-            continue
         # append all W rows' KV, then attend with per-row horizons: row
         # j only ever reads rows <= j of this very step plus committed
         # context, so write-then-attend reproduces the serial interleave
@@ -1021,7 +936,7 @@ def verify_step(params, tokens, lengths, tables, pools, counters, cfg,
             ctx_v, vs, _ = _ring_gather(pools, "vw", wi, pb_max, page_size)
             ctx_k = ctx_k.transpose(0, 2, 1, 3)
             ctx_v = ctx_v.transpose(0, 2, 1, 3)
-            win = cfg.window
+            win = cfg.sliding_window
             wi += 1
         else:
             for j in range(w):
@@ -1052,23 +967,6 @@ def verify_step(params, tokens, lengths, tables, pools, counters, cfg,
     logits = _mm(x, params["lm_head_weight"], exact) \
         + params["lm_head_bias"]
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    if ssm_snaps:
-        # in-graph acceptance: leading run of draft tokens that match
-        # greedy — integer-exact, so it reproduces the host commit loop
-        agree = (tokens[:, 1:].astype(jnp.int32) == greedy[:, :-1])
-        run = jnp.sum(jnp.cumprod(agree.astype(jnp.int32), axis=1),
-                      axis=1)
-        c = 1 + run
-        if limits is not None:
-            c = jnp.minimum(c, limits.astype(jnp.int32))
-        idx = jnp.clip(c - 1, 0, w - 1)
-        for si, snaps in ssm_snaps:
-            # (W, S, H, D, D) -> (S, W, H, D, D), pick each slot's
-            # commit-point snapshot
-            per_slot = jnp.moveaxis(snaps, 0, 1)
-            sel = jnp.take_along_axis(
-                per_slot, idx[:, None, None, None, None], axis=1)[:, 0]
-            pools["ssm_state"] = pools["ssm_state"].at[si].set(sel)
     return greedy, logits, pools, counters
 
 
@@ -1089,20 +987,13 @@ def draft_propose(params, tokens, n_feed, lengths, tables, pools, counters,
     uses ``outs[:, :W-1]`` as its K proposals.
 
     Draft stacks may mix full and windowed layers (the ring append /
-    rotated gather is scan-compatible and rollback is lengths-only) but
-    never SSM layers — see the guard below.
+    rotated gather is scan-compatible and rollback is lengths-only).
     """
     import jax.numpy as jnp
     from jax import lax
 
     if exact is None:
         exact = exact_mode()
-    if "ssm" in cfg.kinds:
-        # an SSM draft would need its own state pool threaded through the
-        # scan AND verify-synchronized rollback; nothing needs it, so the
-        # session rejects the configuration up front
-        raise MXNetError("draft_propose: SSM layers are not supported in "
-                         "draft models")
     # resolve once, outside the scan body, so the dequantized weights
     # are loop invariants XLA hoists rather than per-step work
     params = _resolve_params(params)

@@ -81,28 +81,18 @@ active slots on demand (:meth:`InferenceSession.pages_short` is the
 scheduler's shortfall probe, and the scheduler preempts requests when
 the pool runs below its watermark before the growth would fail).
 
-Hybrid stacks (``layers`` / ``MXNET_SERVE_LAYERS`` +
-``window`` / ``MXNET_SERVE_WINDOW``): a per-layer kind pattern
-(``full`` | ``window`` | ``ssm``, cycled over the model's depth) turns
-the decoder into a hybrid stack whose per-slot memory is O(1) in
-context length.  Windowed layers keep a fixed ring of pages per slot
-(ring append overwrites the oldest rows in place; the rotated,
-position-labeled gather keeps attention bit-exact against the windowed
-reference); SSM layers keep one (H, D, D) fp32 recurrence state per
-slot in the cache's state pool, prefill advances it with a chunked
-in-dispatch scan and decode with the same scan at T=1 — identical op
-sequences, so chunked and serial execution are bit-identical.  The
-executable count stays frozen (hybrid adds entries to the cache's pool
-mapping and a prefill ``slot`` scalar, not executables), speculative
-decoding composes (verify recomputes
-acceptance in-graph to commit SSM state snapshots at each slot's
-commit point; rings roll back lengths-only), and preempt/resume uses
-the same deterministic re-prefill oracle — re-running prefill
-reconstructs ring contents and SSM state exactly.  Prefix caching is
-the one subsystem hybrid stacks opt out of: rings and states are
-slot-private, so the only window-aligned boundary at which every layer
-kind's state is reconstructible from published pages is offset 0 —
-lookups miss and nothing is published.
+Windowed layers (``ModelConfig.layer_types`` + ``sliding_window``: the
+model's, like the rest of the architecture below) keep a fixed ring of
+pages per slot, sized by the block's ``ring_pages(model, config)``, so a
+stack of them holds a slot in O(window) memory whatever its context
+length.  The executable count stays frozen (rings add entries to the
+cache's pool mapping and a prefill ``slot`` scalar, not executables),
+speculative decoding composes (rings roll back lengths-only), and
+preempt/resume re-runs prefill, which reconstructs ring contents exactly.
+Prefix caching is the one subsystem such stacks opt out of: rings are
+slot-private, so the only boundary at which every layer's state is
+reconstructible from published pages is offset 0 — lookups miss and
+nothing is published.
 
 The architecture (``model=``): by default the session infers a GPT-2
 shaped :class:`ModelConfig` from the parameter dict and ``num_heads``.
@@ -115,10 +105,8 @@ whose slot-private state and convolution context the cache keeps beside
 the pages of the few grouped-query attention layers).  It is the
 model's, not the deployment's: no ``ServeConfig`` field and no
 environment variable names it.  ``ModelConfig(block="laguna", ...)``
-(``serve/laguna.py``) brings windowed layers of the model's own: the
-session sizes their rings by the block's ``ring_pages(cfg, page_size)``
-(the model's window in whole pages) and not by ``ServeConfig.ring_pages``,
-which stays the ``layers`` / ``window`` path's rule.  The
+(``serve/laguna.py``) states windowed layers the same way, and its
+``ring_pages`` is the model's window in whole pages.  The
 session looks the block's module up once (``model.BLOCKS``,
 ``self.block``) and asks it for everything that depends on the
 architecture: what the cache must hold, the step functions, what it
@@ -135,7 +123,7 @@ the session stores the two mappings that came back, and knows no pool by
 name: a block with recurrent state names it in ``state_shapes(cfg)``,
 the cache builds it, and ``alloc`` zeroes a slot's rows.  Not supported
 for the latent block and the Mamba-2 block yet, and refused at
-construction: ``spec_k``, ``kv_quant``, ``layers`` / ``window``.
+construction: ``spec_k``, ``kv_quant``.
 Weight-only ``quant`` and ``oversub`` work for both, ``prefix_pages`` for
 the latent block (a cache with recurrent state keeps no prefix index).
 
@@ -153,8 +141,7 @@ Env knobs (see docs/env_vars.md): ``MXNET_SERVE_SLOTS``,
 ``MXNET_SERVE_DRAFT``, ``MXNET_SERVE_QUANT``,
 ``MXNET_SERVE_KV_QUANT``, ``MXNET_SERVE_PREFIX_PAGES``,
 ``MXNET_SERVE_OVERSUB``,
-``MXNET_SERVE_WATERMARK``, ``MXNET_SERVE_TTFT_SLO_MS``,
-``MXNET_SERVE_WINDOW``, ``MXNET_SERVE_LAYERS``.
+``MXNET_SERVE_WATERMARK``, ``MXNET_SERVE_TTFT_SLO_MS``.
 """
 from __future__ import annotations
 
@@ -210,15 +197,7 @@ class ServeConfig:
     oversub: bool = False  # admit by current need, grow on demand
     watermark: int = 0  # free-pool floor that triggers preemption
     ttft_slo_ms: float = 0.0  # 0 = no TTFT budget (SLO admission off)
-    window: int = 0  # sliding-window length for "window" layers
-    layers: str = ""  # layer-kind pattern, e.g. "full,window,ssm"
     max_prompt: int = 0  # longest admissible fresh prompt; 0 = max(buckets)
-    # ``layers`` is cycled over the model's depth ("full,window" on a
-    # 4-layer model -> full,window,full,window); ``window`` sizes every
-    # "window" layer's attention span (its KV lives in a fixed ring of
-    # pages per slot, so per-slot bytes stop scaling with context).
-    # Empty layers = the classic all-full-attention stack; window is
-    # ignored then.
     # ``max_prompt`` above the largest bucket admits fresh prompts up to
     # it: prefill feeds such a prompt in chunks of the largest bucket (the
     # loop a resumed transcript runs), and a slot's page reservation and
@@ -242,8 +221,6 @@ class ServeConfig:
             oversub=get_env("MXNET_SERVE_OVERSUB", False, bool),
             watermark=get_env("MXNET_SERVE_WATERMARK", 0, int),
             ttft_slo_ms=get_env("MXNET_SERVE_TTFT_SLO_MS", 0.0, float),
-            window=get_env("MXNET_SERVE_WINDOW", 0, int),
-            layers=get_env("MXNET_SERVE_LAYERS", "", str),
         )
         vals.update(overrides)
         return cls(**vals)
@@ -272,52 +249,6 @@ class ServeConfig:
                 raise MXNetError(
                     "ServeConfig: bucket %d is not a multiple of page_size "
                     "%d (prefill writes whole pages)" % (b, self.page_size))
-        if self.window < 0:
-            raise MXNetError("ServeConfig: window must be >= 0")
-        pat = self.layer_pattern
-        bad = set(pat) - {"full", "window", "ssm"}
-        if bad:
-            raise MXNetError("ServeConfig: unknown layer kinds %r in "
-                             "layers=%r" % (sorted(bad), self.layers))
-        if "window" in pat and self.window < 1:
-            raise MXNetError(
-                "ServeConfig: layers=%r has windowed layers but window "
-                "is unset (MXNET_SERVE_WINDOW)" % (self.layers,))
-
-    @property
-    def layer_pattern(self):
-        """``layers`` parsed into a kind tuple (may be shorter than the
-        model — :meth:`kinds_for` cycles it over the real depth)."""
-        return tuple(t.strip() for t in self.layers.replace(";", ",")
-                     .split(",") if t.strip())
-
-    def kinds_for(self, num_layers):
-        """Per-layer kinds for an ``num_layers``-deep model: the
-        ``layers`` pattern repeated to cover the stack.  All-full
-        patterns normalize to ``()`` so they keep the classic executable
-        signatures (and recompile-guard names) byte-identical."""
-        pat = self.layer_pattern
-        if not pat:
-            return ()
-        kinds = tuple(pat[i % len(pat)] for i in range(int(num_layers)))
-        return () if set(kinds) == {"full"} else kinds
-
-    @property
-    def ring_pages(self):
-        """Ring capacity (pages) for each windowed layer's per-slot KV.
-
-        A dispatch writes up to ``write_span`` rows (the largest prefill
-        chunk, or the speculative window) before its queries read, so a
-        ring must hold the window plus the whole span minus the row that
-        overlaps (``window + span - 1`` rows) for no visible key to be
-        overwritten mid-dispatch — plus one extra page because the
-        rotated gather is page-granular: the newest page may be only
-        one row full, yet the gather must still reach ``window + span -
-        1`` rows below that row."""
-        span = max(max(self.buckets),
-                   self.spec_window if self.spec_k else 1)
-        return -(-(self.window + span - 1) // self.page_size) + 1
-
     @property
     def longest_prompt(self):
         """The longest admissible fresh prompt: ``max_prompt`` where it is
@@ -456,27 +387,12 @@ class InferenceSession(object):
         self.block = block_of(self.model)
         self._check_block_support(draft_params)
         self.block.check_params(self.params, self.model)
-        kinds = cfg.kinds_for(self.model.num_layers)
-        if kinds:
-            # hybrid stack: the kind pattern cycles over the real depth
-            # and every kind reuses the block's attention weights, so
-            # any checkpoint hosts any stack
-            self.model = dataclasses.replace(
-                self.model, window=cfg.window, layer_kinds=kinds).validate()
         if cfg.longest_prompt + cfg.max_new > self.model.max_len:
             raise MXNetError(
                 "ServeConfig worst case %d (prompt %d + max_new %d) exceeds "
                 "the model's max_len %d"
                 % (cfg.longest_prompt + cfg.max_new, cfg.longest_prompt,
                    cfg.max_new, self.model.max_len))
-        if self.model.sliding_window:
-            # the model's own windowed layers: the ring follows the
-            # model's window (the block says how), not the buckets
-            window = self.model.sliding_window
-            ring_pages = self.block.ring_pages(self.model, cfg.page_size)
-        else:
-            window = self.model.window
-            ring_pages = cfg.ring_pages if "window" in kinds else 0
         self.cache = PagedKVCache(
             num_layers=self.model.num_layers,
             num_heads=self.model.kv_heads,
@@ -489,8 +405,8 @@ class InferenceSession(object):
             prefix_pages=cfg.prefix_pages,
             kv_quant=cfg.kv_quant,
             layer_kinds=self.model.kinds,
-            window=window,
-            ring_pages=ring_pages,
+            window=self.model.sliding_window,
+            ring_pages=self._ring_pages(self.model),
             latent_dim=self.block.latent_dim(self.model),
             state=self.block.state_shapes(self.model))
         # the block's own device state, taken and returned by every
@@ -548,13 +464,19 @@ class InferenceSession(object):
         self._guard_prefix += self.block.guard_tag(self.model)
         self._compile_all()
 
+    def _ring_pages(self, model):
+        """Pages of a slot's ring in each windowed layer of ``model`` (the
+        target's, or the draft's): its block's rule, the one there is."""
+        if "window" not in model.kinds:
+            return 0
+        return block_of(model).ring_pages(model, self.config)
+
     def _check_block_support(self, draft_params):
         """What the block cannot do yet (its ``REFUSES``) is refused
         here, by name, rather than served wrongly."""
         cfg = self.config
         asked = {"spec_k": cfg.spec_k or draft_params is not None,
-                 "kv_quant": cfg.kv_quant,
-                 "layers / window": cfg.layers or cfg.window}
+                 "kv_quant": cfg.kv_quant}
         refused = [name for name in self.block.REFUSES if asked[name]]
         if refused:
             raise MXNetError(
@@ -591,7 +513,7 @@ class InferenceSession(object):
                 draft_params = _layer_truncated(self.params, n)
                 draft_num_heads = draft_num_heads or self.model.num_heads
                 # a layer-skip draft IS the target's first n blocks, so
-                # it inherits their kinds (and the window) — its ring
+                # it inherits their types (and the window) — its ring
                 # writes then track the target's committed stream and
                 # roll back lengths-only, exactly like the paged pools
                 inherit_layers = n
@@ -613,18 +535,11 @@ class InferenceSession(object):
         self.draft_model = config_from_params(
             self.draft_params,
             num_heads=draft_num_heads or self.model.num_heads)
-        if inherit_layers is not None and self.model.hybrid:
-            dkinds = self.model.kinds[:inherit_layers]
-            if set(dkinds) != {"full"}:
-                self.draft_model = dataclasses.replace(
-                    self.draft_model, window=self.model.window,
-                    layer_kinds=dkinds).validate()
-        if "ssm" in self.draft_model.kinds:
-            raise MXNetError(
-                "draft model has SSM layers — a draft's speculative rows "
-                "must roll back O(1), and an SSM draft would need its own "
-                "verify-synchronized state pool; put SSM layers above the "
-                "draft depth or use the ngram draft")
+        if inherit_layers is not None and self.model.layer_types:
+            self.draft_model = dataclasses.replace(
+                self.draft_model,
+                layer_types=self.model.layer_types[:inherit_layers],
+                sliding_window=self.model.sliding_window).validate()
         if self.draft_model.vocab_size != self.model.vocab_size:
             raise MXNetError(
                 "draft vocab %d != target vocab %d — a draft must share "
@@ -646,10 +561,9 @@ class InferenceSession(object):
             table_pad=cfg.spec_pad_pages,
             prefix_pages=cfg.prefix_pages,
             kv_quant=cfg.kv_quant,
-            layer_kinds=self.draft_model.layer_kinds,
-            window=self.draft_model.window,
-            ring_pages=(cfg.ring_pages
-                        if "window" in self.draft_model.kinds else 0))
+            layer_kinds=self.draft_model.kinds,
+            window=self.draft_model.sliding_window,
+            ring_pages=self._ring_pages(self.draft_model))
 
     # -- compilation ------------------------------------------------------
     def _aot(self, name, fn, params, avals, donate_argnums):
@@ -716,8 +630,8 @@ class InferenceSession(object):
         param_avals = avals_of(self.params)
         # the cache's pools and the block's counters: one pytree argument
         # each, donated, and returned in the same two places.  A scalar an
-        # executable has no use for (prefill's slot, verify's limits) is
-        # None, which has no leaves: no executable gains an input.
+        # executable has no use for (prefill's slot) is None, which has no
+        # leaves: no executable gains an input.
         pools = avals_of(self.cache.pools)
         counters = avals_of(self.counters)
         # table width includes the speculative all-trash pad columns
@@ -756,22 +670,16 @@ class InferenceSession(object):
 
         if cfg.spec_k:
             w = cfg.spec_window
-            # SSM layers make the per-slot commit cap an executable
-            # input: the in-graph acceptance recomputation selects each
-            # slot's state snapshot at its commit point (O(1) rollback)
-            limits = sds((cfg.slots,), i32) if self.cache.n_ssm else None
 
-            def verify_fn(params, tokens, lengths, tables, pools, counters,
-                          limits):
+            def verify_fn(params, tokens, lengths, tables, pools, counters):
                 return block.verify_step(params, tokens, lengths, tables,
-                                         pools, counters, limits=limits,
-                                         **static)
+                                         pools, counters, **static)
 
             self._aot(
                 "verify", verify_fn, self.params,
                 (param_avals, sds((cfg.slots, w), i32),
                  sds((cfg.slots,), i32), sds((cfg.slots, max_pages), i32),
-                 pools, counters, limits),
+                 pools, counters),
                 donate_argnums=(4, 5))
 
         if self._draft_mode == "model":
@@ -1116,21 +1024,11 @@ class InferenceSession(object):
             if limits is not None:
                 limit = max(1, min(w, int(limits.get(slot, w))))
             lims[slot] = limit
-        lim_arr = None
-        if self.cache.n_ssm:
-            # the same per-slot caps ride into the executable: the
-            # in-graph acceptance recomputation must reach the exact c
-            # the commit loop below reaches, or the committed SSM state
-            # would belong to a different prefix (inactive slots cap at
-            # 1; their state is garbage until alloc re-zeroes it)
-            lim_arr = np.ones((cfg.slots,), np.int32)
-            for slot, limit in lims.items():
-                lim_arr[slot] = limit
         greedy, _, self.cache.pools, self.counters = self._dispatch(
             "verify", (self.params, tokens,
                        self.cache.lengths_arg(),
                        self.cache.device_tables(), self.cache.pools,
-                       self.counters, lim_arr))
+                       self.counters))
         greedy = np.asarray(greedy)
         self._spec_stats["verify_steps"] += 1
         for slot in active:

@@ -258,8 +258,34 @@ def test_max_prompt_defaults_to_the_largest_bucket(session):
                                      max_prompt=60))
 
 
+@pytest.mark.parametrize("cls, field", [
+    (serve.ServeConfig, "layers"), (serve.ServeConfig, "window"),
+    (serve.ServeConfig, "ring_pages"), (serve.ModelConfig, "layer_kinds"),
+    (serve.ModelConfig, "window")])
+def test_no_second_way_to_say_what_a_layer_is(cls, field):
+    """A layer's kind is the model's ``layer_types`` + ``sliding_window``
+    and a ring's size the block's ``ring_pages``: the deployment's config
+    has no word for either, and the model no second pair."""
+    assert not hasattr(cls, field)
+    base = CFG if cls is serve.ModelConfig else serve.ServeConfig()
+    with pytest.raises(TypeError, match="unexpected keyword"):
+        dataclasses.replace(base, **{field: 8})
+
+
+def test_the_environment_cannot_rewrite_the_model(monkeypatch):
+    """The two ``MXNET_SERVE_*`` names that used to state layer kinds and
+    a window are read by nothing: the config is the one built without
+    them (the names are spelt in two parts so that a search of the tree
+    for either finds nothing)."""
+    want = serve.ServeConfig.from_env(buckets=(16, 32))
+    for knob, value in (("LAYERS", "full,window"), ("WINDOW", "8")):
+        monkeypatch.setenv("MXNET_SERVE_" + knob, value)
+    assert serve.ServeConfig.from_env(buckets=(16, 32)) == want
+
+
 @pytest.mark.parametrize("over", [
-    dict(), dict(layers="full,window", window=8)],
+    dict(), dict(layer_types=("full_attention", "sliding_attention"),
+                 sliding_window=8)],
     ids=["full", "full,window"])
 def test_a_fresh_long_prompt_is_the_same_prompt_resumed(params, over):
     """With ``max_prompt`` above the largest bucket a fresh prompt of 37
@@ -268,9 +294,10 @@ def test_a_fresh_long_prompt_is_the_same_prompt_resumed(params, over):
     resumed transcript of those tokens gets, the reference's row, and a
     slot reserves pages for ``max_prompt + max_new``."""
     sess = serve.InferenceSession(
-        params, num_heads=CFG.num_heads, config=serve.ServeConfig(
+        params, model=dataclasses.replace(CFG, **over),
+        config=serve.ServeConfig(
             slots=3, page_size=PAGE, buckets=(8, 16), max_new=8,
-            max_prompt=40, exact=True, **over))
+            max_prompt=40, exact=True))
     assert sorted(sess.executables) == ["decode", "prefill_16", "prefill_8"]
     assert sess.cache.max_pages_per_slot == 6
     seq = np.random.default_rng(5).integers(0, CFG.vocab_size, 37).tolist()

@@ -107,7 +107,7 @@ ROW = bailing_hybrid.latent_dim(CFG)     # a latent row: rank + rope
 EXPERT_LAYERS = HF["num_hidden_layers"] - HF["first_k_dense_replace"]
 
 
-def test_the_layer_pattern_follows_the_published_period():
+def test_the_layer_order_follows_the_published_period():
     assert CFG.layer_types == ("kda", "mla", "kda", "kda")
     assert CFG.kinds == ("ssm", "full", "ssm", "ssm") and CFG.hybrid
     whole = dict(HF, num_hidden_layers=7, layers_kept=None,
@@ -542,9 +542,8 @@ def test_on_the_cpu_the_expert_layers_run_the_loop(plain, params,
 
 
 def test_what_the_block_refuses(params):
-    assert bailing_hybrid.REFUSES == ("spec_k", "kv_quant", "layers / window")
-    for over in (dict(spec_k=2, draft="layers:1"), dict(kv_quant="int8"),
-                 dict(layers="full,ssm")):
+    assert bailing_hybrid.REFUSES == ("spec_k", "kv_quant")
+    for over in (dict(spec_k=2, draft="layers:1"), dict(kv_quant="int8")):
         with pytest.raises(MXNetError, match="does not support"):
             session(params, **over)
     with pytest.raises(MXNetError, match="layer_types"):

@@ -33,6 +33,10 @@ SPECULATIVE = ("verify_step", "draft_propose")
 
 GPT2 = serve.ModelConfig(vocab_size=61, num_layers=3, d_model=32,
                          num_heads=2, max_len=64)
+# the same block with its layers stated: one full layer, two windowed
+GPT2_WINDOWED = dataclasses.replace(
+    GPT2, sliding_window=8,
+    layer_types=("full_attention", "sliding_attention", "sliding_attention"))
 LATENT = serve.ModelConfig(
     block="deepseek_v3", vocab_size=61, num_layers=2, d_model=32,
     num_heads=2, max_len=64, qk_nope_head_dim=8, qk_rope_head_dim=4,
@@ -114,8 +118,7 @@ def test_a_third_block_is_served_by_an_unedited_session(monkeypatch):
 # the scan's key block); the table is (16 + 8) / 8 = 3 pages = 24 rows
 PREFILL_SCANS = {
     "dense": (GPT2, dict(), 3, 8),
-    "dense_one_full_layer": (GPT2, dict(layers="full,window,ssm", window=8),
-                             1, 8),
+    "dense_one_full_layer": (GPT2_WINDOWED, dict(), 1, 8),
     "latent": (LATENT, dict(), 2, 8),
     # not exact, a table within 512 keys is one block: nothing to skip
     "latent_one_block": (LATENT, dict(exact=False), 2, 24),
@@ -156,9 +159,8 @@ def test_prefill_report_counts_rows_to_the_chunks_horizon(name):
 VARIANTS = {
     "classic": (GPT2, dict()),
     "kv_int8": (GPT2, dict(kv_quant="int8")),
-    "hybrid": (GPT2, dict(layers="full,window,ssm", window=8)),
-    "hybrid_kv_int8": (GPT2, dict(layers="full,window", window=8,
-                                  kv_quant="int8")),
+    "hybrid": (GPT2_WINDOWED, dict()),
+    "hybrid_kv_int8": (GPT2_WINDOWED, dict(kv_quant="int8")),
     "spec": (GPT2, dict(spec_k=2, draft="layers:1")),
     "latent": (LATENT, dict()),
     "granite": (GRANITE, dict()),
@@ -287,9 +289,39 @@ def test_rings_by_the_models_window_beside_pages():
     assert sess.cache.paged == ("k_pool", "v_pool") and sess.cache.hybrid
     assert sess.cache.ring_tokens == sess.block_report()["ring_rows"] == 8
     # the GPT-2 block's rule, which follows the buckets, is not asked
-    assert sess.config.ring_pages == 3
+    assert serve_model.ring_pages(LAGUNA, sess.config) == 4
     assert sorted(sess.counters) == ["attn_stats", "moe_stats"]
     assert sess.decode_report()["kv_lanes"] \
         == sess.block_report()["kv_lanes"] == 16
     assert sess.block_report()["experts_held"] == 4
     assert sorted(sess.executables) == ["decode", "prefill_16", "prefill_8"]
+
+
+# block -> a model of it: with windowed layers where the block has any
+RINGS = {"gpt2": GPT2_WINDOWED, "deepseek_v3": LATENT,
+         "granitemoehybrid": GRANITE, "bailing_hybrid": BAILING,
+         "laguna": LAGUNA}
+
+
+@pytest.mark.parametrize("name", sorted(serve_model.BLOCKS))
+def test_a_ring_is_sized_by_the_block_and_by_nothing_else(name):
+    """One rule for a ring's size, the block's ``ring_pages(model,
+    config)``: a session over a model with windowed layers builds rings of
+    exactly that many pages (the GPT-2 block's follow the largest bucket,
+    laguna's the window alone), and a model with none builds no
+    ``kw_pool``.  No ``ServeConfig`` field takes part."""
+    model = RINGS[name]
+    assert model.block == name
+    conf = serve.ServeConfig(**dict(CONF, buckets=(8, 24)))
+    sess = serve.InferenceSession(serve.init_params(model, seed=5),
+                                  model=model, config=conf)
+    windowed = model.kinds.count("window")
+    if not windowed:
+        assert "kw_pool" not in sess.cache.pools
+        assert sess.cache.ring_pages == sess.cache.n_window == 0
+        return
+    pages = sess.block.ring_pages(model, conf)
+    assert pages == {"gpt2": (8 + 24 - 1 + 7) // 8 + 1, "laguna": 1}[name]
+    assert sess.cache.ring_pages == pages
+    assert sess.cache.window == model.sliding_window == 8
+    assert sess.cache.pools["kw_pool"].shape[:3] == (windowed, 3, pages * 8)

@@ -426,9 +426,7 @@ def test_pools_are_pages_for_attention_and_state_for_mamba(plain):
     assert cache.register_prefix is not None and not cache._index
 
 
-@pytest.mark.parametrize("conf", [
-    dict(spec_k=2), dict(kv_quant="int8"),
-    dict(layers="full,window", window=8), dict(window=8)])
+@pytest.mark.parametrize("conf", [dict(spec_k=2), dict(kv_quant="int8")])
 def test_unsupported_combinations_are_refused(params, conf):
     with pytest.raises(MXNetError, match="does not support"):
         session(params, **conf)

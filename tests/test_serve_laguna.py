@@ -112,7 +112,7 @@ CONF = dict(slots=3, page_size=PAGE, buckets=(8, 16), max_new=16,
             max_prompt=64, exact=False)
 
 
-def test_the_layer_pattern_is_the_models():
+def test_the_layer_order_is_the_models():
     assert CFG.layer_types == ("full_attention",) \
         + ("sliding_attention",) * 3 + ("full_attention",)
     assert CFG.kinds == ("full", "window", "window", "window", "full")
@@ -569,17 +569,17 @@ def test_a_stale_ring_row_is_seen(params, plain):
 def test_a_ring_is_sized_by_the_window_whatever_the_buckets(params, buckets,
                                                             window, rows):
     """``sliding_window`` rows in whole pages, never the window plus the
-    largest bucket; ``ServeConfig.ring_pages`` (the GPT-2 block's rule)
-    would hold 6 pages at buckets (8, 16) and 18 at (8, 64)."""
+    largest bucket; ``model.ring_pages`` (the GPT-2 block's rule) would
+    hold 6 pages at buckets (8, 16) and 18 at (8, 64)."""
     cfg = dataclasses.replace(CFG, sliding_window=window)
-    assert laguna.ring_pages(cfg, PAGE) * PAGE == rows
     sess = session(params, cfg=cfg, buckets=buckets)
+    assert laguna.ring_pages(cfg, sess.config) * PAGE == rows
     assert sess.cache.ring_tokens == sess.block_report()["ring_rows"] == rows
     assert rows <= window + 2 * PAGE
     assert sess.cache.pools["kw_pool"].shape == (3, 3, rows, 2, 16)
     assert sess.cache.pools["k_pool"].shape[0] == 2      # the full layers
     assert sess.cache.n_window == 3 and sess.cache.hybrid
-    assert sess.config.ring_pages != rows // PAGE
+    assert serve_model.ring_pages(cfg, sess.config) != rows // PAGE
 
 
 def test_at_the_published_sizes_a_ring_holds_512_rows():
@@ -587,19 +587,18 @@ def test_at_the_published_sizes_a_ring_holds_512_rows():
     512 rows, under the 544 a window of whole pages plus two allows; a
     12 288-token prompt is six chunks, and a slot reserves 832 pages."""
     cfg = dataclasses.replace(CFG, sliding_window=512)
-    assert laguna.ring_pages(cfg, 16) * 16 == 512 <= 544
     conf = serve.ServeConfig(slots=16, page_size=16, buckets=(512, 2048),
                              max_prompt=12288, max_new=1024, exact=False)
+    assert laguna.ring_pages(cfg, conf) * 16 == 512 <= 544
     assert conf.max_pages_per_slot == (12288 + 1024) // 16
     assert -(-12288 // max(conf.buckets)) == 6
     # what the GPT-2 block's rule would ask for the same window
-    assert dataclasses.replace(conf, window=512).ring_pages == 161
+    assert serve_model.ring_pages(cfg, conf) == 161
 
 
 def test_what_the_block_refuses(params):
-    assert laguna.REFUSES == ("spec_k", "kv_quant", "layers / window")
-    for over in (dict(spec_k=2, draft="layers:1"), dict(kv_quant="int8"),
-                 dict(layers="full,window", window=8)):
+    assert laguna.REFUSES == ("spec_k", "kv_quant")
+    for over in (dict(spec_k=2, draft="layers:1"), dict(kv_quant="int8")):
         with pytest.raises(MXNetError, match="does not support"):
             session(params, **over)
     for bad, match in (
@@ -614,7 +613,6 @@ def test_what_the_block_refuses(params):
             (dict(mlp_only_layers=(1,)), "lead the stack"),
             (dict(scoring_func="tanh"), "scoring_func"),
             (dict(shared_expert_intermediate_size=48), "a whole number"),
-            (dict(window=8), "sliding_window"),
             (dict(tie_word_embeddings=True), "no tied head"),
             (dict(attn_head_dim=0), "attn_head_dim"),
             (dict(experts_held=(12, 8)), "experts_held")):

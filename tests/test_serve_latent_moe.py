@@ -446,9 +446,7 @@ def test_paged_prefill_and_decode_equal_full_forward_at_both_widths(name,
     assert sess.fallback_count() == 0
 
 
-@pytest.mark.parametrize("conf", [
-    dict(spec_k=2), dict(kv_quant="int8"),
-    dict(layers="full,window", window=8), dict(window=8)])
+@pytest.mark.parametrize("conf", [dict(spec_k=2), dict(kv_quant="int8")])
 def test_unsupported_combinations_are_refused(params, conf):
     with pytest.raises(MXNetError, match="does not support"):
         session(params, **conf)

@@ -1,12 +1,14 @@
-"""Hybrid serving stacks with O(1) per-slot memory: sliding-window
-attention rings + SSM scan layers (mxnet_tpu/ops/ssm_ops.py,
-mxnet_tpu/serve/, docs/serving.md "Hybrid stacks").  Covers windowed
-decode bit-exact against the windowed reference oracle across kv_quant
-modes, the ring gather's position-labeled rotation at the ops level
-(fp32 and bf16), chunked-prefill == serial SSM recurrence, speculative
-verify with in-graph O(1) hybrid rollback, watermark preempt/resume vs
-a never-evicted oracle, the ``kv_window`` chaos site, prefix-cache
+"""The GPT-2 block's sliding-window layers, stated by the model
+(``ModelConfig.layer_types`` + ``sliding_window``): rings of O(1)
+per-slot memory (mxnet_tpu/serve/, docs/serving.md "Windowed layers").
+Covers the model's validation, windowed decode against the windowed
+reference oracle across kv_quant modes, the ring gather's
+position-labeled rotation at the ops level (fp32 and bf16), speculative
+verify with lengths-only ring rollback, watermark preempt/resume vs a
+never-evicted oracle, the ``kv_window`` chaos site, prefix-cache
 opt-out, and the frozen executable contract."""
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -24,7 +26,10 @@ CFG = serve.ModelConfig(vocab_size=61, num_layers=3, d_model=32,
                         num_heads=2, max_len=256)
 PAGE = 8
 WINDOW = 8
-HYBRID = dict(layers="full,window,ssm", window=WINDOW)
+# the stack every session here serves: stated, as a model states it
+HYBRID = dataclasses.replace(
+    CFG, sliding_window=WINDOW,
+    layer_types=("full_attention", "sliding_attention", "sliding_attention"))
 
 
 @pytest.fixture(autouse=True)
@@ -43,9 +48,8 @@ def params():
 @pytest.fixture(scope="module")
 def _hybrid_session(params):
     sconf = serve.ServeConfig(slots=3, page_size=PAGE, buckets=(16, 32),
-                              max_new=8, exact=True, **HYBRID)
-    return serve.InferenceSession(params, num_heads=CFG.num_heads,
-                                  config=sconf)
+                              max_new=8, exact=True)
+    return serve.InferenceSession(params, model=HYBRID, config=sconf)
 
 
 @pytest.fixture
@@ -76,26 +80,52 @@ def _trace(n, seed, prompt_len=8, max_new=6):
 # config + cache bookkeeping
 # ---------------------------------------------------------------------------
 
-def test_serve_config_hybrid_validation():
-    with pytest.raises(MXNetError):
-        serve.ServeConfig(page_size=PAGE, buckets=(16,), window=-1)
-    with pytest.raises(MXNetError):
-        serve.ServeConfig(page_size=PAGE, buckets=(16,),
-                          layers="full,conv")  # unknown kind
-    with pytest.raises(MXNetError):
-        # window layers demand an explicit window >= 1
-        serve.ServeConfig(page_size=PAGE, buckets=(16,),
-                          layers="window,full")
-    cfg = serve.ServeConfig(page_size=PAGE, buckets=(16, 32),
-                            max_new=8, **HYBRID)
-    # the pattern cycles over the model depth; all-full normalizes away
-    assert cfg.kinds_for(5) == ("full", "window", "ssm", "full",
-                                "window")
-    assert serve.ServeConfig(page_size=PAGE, buckets=(16,),
-                             layers="full").kinds_for(3) == ()
+def test_the_model_states_its_layers():
+    """What ``ServeConfig.layers`` / ``.window`` used to say, the model
+    says: the kinds the cache builds, the ring's size (the block's rule,
+    asked with the ``ServeConfig``), the guard's tag."""
+    assert HYBRID.validate() is HYBRID
+    assert HYBRID.kinds == ("full", "window", "window") and HYBRID.hybrid
+    assert serve_model.guard_tag(HYBRID) == "-w%dfww" % WINDOW
     # ring bound: ceil((window + span - 1)/page) + 1 with span = the
-    # largest bucket (the biggest burst written before any read)
-    assert cfg.ring_pages == (WINDOW + 32 - 1 + PAGE - 1) // PAGE + 1
+    # largest bucket (the biggest burst written before any read) ...
+    sconf = serve.ServeConfig(page_size=PAGE, buckets=(16, 32), max_new=8)
+    assert serve_model.ring_pages(HYBRID, sconf) \
+        == (WINDOW + 32 - 1 + PAGE - 1) // PAGE + 1
+    # ... or the speculative window, where that is the larger burst
+    assert serve_model.ring_pages(HYBRID, serve.ServeConfig(
+        page_size=PAGE, buckets=(8,), spec_k=11)) \
+        == (WINDOW + 12 - 1 + PAGE - 1) // PAGE + 1
+    # a stack stated all full is the classic stack: same guards, no ring
+    full = dataclasses.replace(HYBRID, layer_types=("full_attention",) * 3)
+    assert full.validate().kinds == CFG.kinds and not full.hybrid
+    assert serve_model.guard_tag(full) == serve_model.guard_tag(CFG) == ""
+    # nothing is cycled over the depth: the model states every layer
+    assert serve_model.config_from_params(
+        serve_model.init_params(CFG, seed=0), CFG.num_heads) == CFG
+
+
+@pytest.mark.parametrize("over, match", [
+    (dict(layer_types=("full_attention", "mamba", "full_attention")),
+     "not \\['mamba'\\]"),
+    (dict(layer_types=("kda", "mla", "kda")), "not \\['kda', 'mla'\\]"),
+    (dict(layer_types=("full", "window", "ssm")), "full_attention and"),
+    (dict(layer_types=("full_attention", "sliding_attention"),
+          sliding_window=WINDOW), "does not cover 3 layers"),
+    (dict(layer_types=("full_attention",) * 2 + ("sliding_attention",)),
+     "need sliding_window >= 1"),
+], ids=["mamba", "kda_mla", "the_caches_words", "short", "no_window"])
+def test_the_block_refuses_layers_it_does_not_run(over, match):
+    """The GPT-2 block runs ``full_attention`` and ``sliding_attention``
+    layers: another block's layer type, the cache's own words, a tuple
+    that does not cover the depth and a windowed layer without a window
+    are refused by ``validate``, and so by the session."""
+    with pytest.raises(MXNetError, match=match):
+        dataclasses.replace(CFG, **over).validate()
+    with pytest.raises(MXNetError, match=match):
+        serve.InferenceSession(
+            {}, model=dataclasses.replace(CFG, **over),
+            config=serve.ServeConfig(page_size=PAGE, buckets=(16,)))
 
 
 def test_ring_cache_bookkeeping():
@@ -139,10 +169,8 @@ def test_ring_cache_bookkeeping():
 
 def _hybrid(params, kv_quant):
     sconf = serve.ServeConfig(slots=2, page_size=PAGE, buckets=(16, 32),
-                              max_new=16, exact=True, kv_quant=kv_quant,
-                              **HYBRID)
-    return serve.InferenceSession(params, num_heads=CFG.num_heads,
-                                  config=sconf)
+                              max_new=16, exact=True, kv_quant=kv_quant)
+    return serve.InferenceSession(params, model=HYBRID, config=sconf)
 
 
 def _prompt13():
@@ -152,12 +180,12 @@ def _prompt13():
 
 @pytest.mark.parametrize("kv_quant", ["", "int8", "e4m3"])
 def test_hybrid_decode_bitexact_vs_reference(params, kv_quant):
-    """Prefill + decode through a full x window x ssm stack reproduces
+    """Prefill + decode through a full x window x window stack reproduces
     the full-context hybrid reference forward — logits, not just argmax
     — including steps where the window slides past the prompt and the
     ring wraps, at every KV storage precision.  Three executables:
-    sound rows read at most 4 spacings apart over 12 seeds (jax
-    0.9.0)."""
+    sound rows read at most 3.5 spacings apart over 5 parameter seeds
+    (jax 0.9.0)."""
     # six steps cross position 16: the window slides, the ring wraps
     assert worst_gap_vs_reference(_hybrid(params, kv_quant), _prompt13(),
                                   steps=6) <= LIMIT_SPACINGS
@@ -178,16 +206,17 @@ def _stale_ring_row(sess, slots):
 @pytest.mark.parametrize("kv_quant", ["", "int8"])
 def test_hybrid_comparison_sees_planted_fault(params, kv_quant):
     """The control of the comparison above: one stale ring row reads
-    14 375 spacings (fp32 pages) and 25 719 (int8) where the limit is
-    32; 2 227 or more over 5 seeds at every precision."""
+    12 346 spacings (fp32 pages) and 20 985 (int8) where the limit is
+    32; 12 780 or more over 5 parameter seeds at every precision (the
+    stack stated full, window, window)."""
     assert worst_gap_vs_reference(
         _hybrid(params, kv_quant), _prompt13(), steps=6,
         plant=_stale_ring_row) > 30 * LIMIT_SPACINGS
 
 
 def test_hybrid_cobatched_equals_solo(hybrid_session):
-    """Co-batched strangers must not perturb a hybrid stream: rings and
-    SSM states are slot-private and the kernels are M-invariant."""
+    """Co-batched strangers must not perturb a windowed stream: rings
+    are slot-private and the kernels are M-invariant."""
     sess = hybrid_session
     rs = np.random.RandomState(12)
     p = rs.randint(1, CFG.vocab_size, size=9).tolist()
@@ -214,14 +243,14 @@ def test_hybrid_cobatched_equals_solo(hybrid_session):
 
 
 def test_no_full_layers_session_decodes_and_admits_by_slots(params):
-    """A pure window+ssm stack reserves zero pool pages — every slot
+    """An all-window stack reserves zero pool pages — every slot
     admits regardless of context length — and still decodes on the
     reference (tests/closeness.py: sound rows at most 4 spacings off)."""
     sconf = serve.ServeConfig(slots=3, page_size=PAGE, buckets=(16,),
-                              max_new=8, exact=True,
-                              layers="window,ssm", window=WINDOW)
-    sess = serve.InferenceSession(params, num_heads=CFG.num_heads,
-                                  config=sconf)
+                              max_new=8, exact=True)
+    sess = serve.InferenceSession(
+        params, config=sconf, model=dataclasses.replace(
+            HYBRID, layer_types=("sliding_attention",) * 3))
     assert sess.cache.pages_needed(16, 8) == 0
     rs = np.random.RandomState(9)
     prompts = [rs.randint(1, CFG.vocab_size, size=11).tolist()
@@ -294,64 +323,24 @@ def test_ring_rotation_with_position_labels_is_exact():
                                   np.asarray(base))
 
 
-def test_ssm_chunked_prefill_equals_serial_decode():
-    """The recurrence contract: one T=16 scan == two T=8 chunks == 16
-    serial T=1 steps, outputs AND states (each length is an executable
-    of its own); padded rows are identity pass-throughs."""
-    import jax.numpy as jnp
-
-    from mxnet_tpu.ops.ssm_ops import ssm_decay, ssm_scan
-
-    S, T, H, D = 2, 16, 2, 8
-    rs = np.random.RandomState(5)
-    q, k, v = (jnp.asarray(rs.randn(S, T, H, D), jnp.float32)
-               for _ in range(3))
-    gamma = ssm_decay(H)
-    state0 = jnp.zeros((S, H, D, D), jnp.float32)
-
-    y_full, s_full = ssm_scan(q, k, v, state0, gamma)
-    y_a, s_mid = ssm_scan(q[:, :8], k[:, :8], v[:, :8], state0, gamma)
-    y_b, s_chunk = ssm_scan(q[:, 8:], k[:, 8:], v[:, 8:], s_mid, gamma)
-    assert_close_across_executables(np.asarray(jnp.concatenate(
-        [y_a, y_b], axis=1)), np.asarray(y_full))
-    assert_close_across_executables(np.asarray(s_chunk),
-                                    np.asarray(s_full))
-
-    s_serial = state0
-    rows = []
-    for t in range(T):
-        y_t, s_serial = ssm_scan(q[:, t:t + 1], k[:, t:t + 1],
-                                 v[:, t:t + 1], s_serial, gamma)
-        rows.append(y_t)
-    assert_close_across_executables(np.asarray(jnp.concatenate(
-        rows, axis=1)), np.asarray(y_full))
-    assert_close_across_executables(np.asarray(s_serial),
-                                    np.asarray(s_full))
-
-    # bucket-padding rows leave the state unchanged
-    valid = jnp.broadcast_to(jnp.arange(T) < 10, (S, T))
-    _, s_ragged = ssm_scan(q, k, v, state0, gamma, row_valid=valid)
-    _, s_short = ssm_scan(q[:, :10], k[:, :10], v[:, :10], state0, gamma)
-    assert_close_across_executables(np.asarray(s_ragged),
-                                    np.asarray(s_short))
-
-
 # ---------------------------------------------------------------------------
-# speculative decoding on hybrid stacks: exact verify, O(1) rollback
+# speculative decoding on windowed stacks: exact verify, O(1) rollback
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("draft", ["ngram", "layers:2"])
 def test_hybrid_spec_decode_matches_oracle(params, draft):
-    """Speculation over a hybrid stack commits EXACTLY the serial greedy
-    stream: the verify executable recomputes acceptance in-graph and
-    rolls rings (lengths-only) and SSM states (snapshot select) back to
-    the commit point.  ``layers:2`` inherits the target's full,window
-    prefix as the draft stack."""
+    """Speculation over a windowed stack commits EXACTLY the serial
+    greedy stream: rings roll back to the commit point lengths-only.
+    ``layers:2`` inherits the target's full,window prefix (and its
+    window) as the draft stack."""
     sconf = serve.ServeConfig(slots=2, page_size=PAGE, buckets=(16, 32),
                               max_new=16, exact=True, spec_k=3,
-                              draft=draft, **HYBRID)
-    sess = serve.InferenceSession(params, num_heads=CFG.num_heads,
-                                  config=sconf)
+                              draft=draft)
+    sess = serve.InferenceSession(params, model=HYBRID, config=sconf)
+    if draft == "layers:2":
+        assert sess.draft_model.layer_types == HYBRID.layer_types[:2]
+        assert sess.draft_model.sliding_window == WINDOW
+        assert sess.draft_cache.ring_pages == sess.cache.ring_pages
     rs = np.random.RandomState(7)
     prompt = rs.randint(1, CFG.vocab_size, size=13).tolist()
     oracle = _greedy_oracle(sess, prompt, 10)
@@ -367,17 +356,6 @@ def test_hybrid_spec_decode_matches_oracle(params, draft):
     assert stats["committed"] == len(got) - 1  # prefill emitted got[0]
 
 
-def test_hybrid_draft_with_ssm_layers_rejected(params):
-    """SSM layers never appear in a draft stack — the session rejects
-    the configuration up front instead of silently mis-speculating."""
-    sconf = serve.ServeConfig(slots=2, page_size=PAGE, buckets=(16,),
-                              max_new=8, spec_k=2, draft="layers:2",
-                              layers="full,ssm,window", window=WINDOW)
-    with pytest.raises(MXNetError):
-        serve.InferenceSession(params, num_heads=CFG.num_heads,
-                               config=sconf)
-
-
 # ---------------------------------------------------------------------------
 # preempt/resume, prefix opt-out, chaos, frozen executables
 # ---------------------------------------------------------------------------
@@ -385,13 +363,12 @@ def test_hybrid_draft_with_ssm_layers_rejected(params):
 def test_hybrid_preempt_resume_bitexact_vs_never_evicted(params):
     """Watermark preemption on a hybrid stack: eviction releases only
     the full layers' pages; resume re-prefills through the SAME hybrid
-    executables, rebuilding rings and SSM state deterministically —
-    every resumed stream equals the never-evicted greedy oracle."""
+    executables, rebuilding the rings deterministically — every
+    resumed stream equals the never-evicted greedy oracle."""
     sconf = serve.ServeConfig(slots=3, page_size=PAGE, buckets=(8, 16),
                               max_new=8, exact=True, num_pages=5,
-                              oversub=True, prefix_pages=-1, **HYBRID)
-    sess = serve.InferenceSession(params, num_heads=CFG.num_heads,
-                                  config=sconf)
+                              oversub=True, prefix_pages=-1)
+    sess = serve.InferenceSession(params, model=HYBRID, config=sconf)
     reqs = _trace(3, seed=23, prompt_len=8, max_new=6)
     oracle = {r.rid: _greedy_oracle(sess, r.prompt, r.max_new)
               for r in reqs}
@@ -406,15 +383,13 @@ def test_hybrid_preempt_resume_bitexact_vs_never_evicted(params):
 
 
 def test_hybrid_prefix_cache_opts_out(params):
-    """Rings and SSM states are slot-private, so no window-aligned
-    boundary except offset 0 is reconstructible from published pages:
-    hybrid sessions neither publish nor hit — and still decode the
-    exact oracle streams."""
+    """Rings are slot-private, so no window-aligned boundary except
+    offset 0 is reconstructible from published pages: hybrid sessions
+    neither publish nor hit — and still decode the exact oracle
+    streams."""
     sconf = serve.ServeConfig(slots=2, page_size=PAGE, buckets=(16,),
-                              max_new=8, exact=True, prefix_pages=-1,
-                              **HYBRID)
-    sess = serve.InferenceSession(params, num_heads=CFG.num_heads,
-                                  config=sconf)
+                              max_new=8, exact=True, prefix_pages=-1)
+    sess = serve.InferenceSession(params, model=HYBRID, config=sconf)
     prompt = list(range(1, 17))  # two full pages: would hit if published
     for _ in range(2):  # identical prompts back-to-back
         oracle = _greedy_oracle(sess, prompt, 4)
@@ -432,16 +407,15 @@ def test_hybrid_prefix_cache_opts_out(params):
 
 @pytest.mark.chaos
 def test_chaos_kv_window_fault_isolates_request(params, monkeypatch):
-    """A raise at the hybrid prefill boundary (before any ring row or
-    SSM state is written) fails only the request whose prefill crossed
-    it; survivors' rings/states stay coherent — their streams match a
-    clean run — and the slot pool drains back to full."""
+    """A raise at the hybrid prefill boundary (before any ring row is
+    written) fails only the request whose prefill crossed it; survivors'
+    rings stay coherent — their streams match a clean run — and the slot
+    pool drains back to full."""
     monkeypatch.setenv("MXNET_FAULT_INJECT", "kv_window:raise:after=2")
     faults.reset()
     sconf = serve.ServeConfig(slots=3, page_size=PAGE, buckets=(8, 16),
-                              max_new=8, exact=True, **HYBRID)
-    sess = serve.InferenceSession(params, num_heads=CFG.num_heads,
-                                  config=sconf)
+                              max_new=8, exact=True)
+    sess = serve.InferenceSession(params, model=HYBRID, config=sconf)
     reqs = _trace(3, seed=21, max_new=4)
     done, _ = Scheduler(sess, policy="continuous").run(reqs)
     failed = [r for r in done if r.failed]
@@ -453,8 +427,7 @@ def test_chaos_kv_window_fault_isolates_request(params, monkeypatch):
 
     monkeypatch.delenv("MXNET_FAULT_INJECT")
     faults.reset()
-    clean = serve.InferenceSession(params, num_heads=CFG.num_heads,
-                                   config=sconf)
+    clean = serve.InferenceSession(params, model=HYBRID, config=sconf)
     cdone, _ = Scheduler(clean, policy="continuous").run(
         _trace(3, seed=21, max_new=4))
     want = {r.rid: list(r.tokens) for r in cdone}
@@ -464,13 +437,13 @@ def test_chaos_kv_window_fault_isolates_request(params, monkeypatch):
 
 def test_hybrid_executables_frozen_and_guard_tagged(hybrid_session,
                                                     monkeypatch):
-    """Hybrid stacks change executable ARGUMENTS (ring/state pools, the
+    """Windowed layers change executable ARGUMENTS (ring pools, the
     prefill slot scalar), never the executable set: a full load under
     MXNET_RECOMPILE_ERROR=1 completes with len(buckets) + 1 executables
     and one trace each, and the recompile-guard namespace carries the
     window/kind tag so hybrid and classic sessions never alias."""
     session = hybrid_session
-    assert session._guard_prefix.endswith("-w%dfws" % WINDOW)
+    assert session._guard_prefix.endswith("-w%dfww" % WINDOW)
     monkeypatch.setenv("MXNET_RECOMPILE_ERROR", "1")
     rs = np.random.RandomState(13)
     reqs = [Request(rid=i,

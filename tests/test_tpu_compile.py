@@ -632,7 +632,8 @@ def _laguna_program(one_chip, monkeypatch, bucket):
     assert abs(sum(math.prod(v.shape) for v in params.values())
                - 1717e6) < 1e6
     page = 16
-    rings = laguna.ring_pages(cfg, page) * page
+    rings = laguna.ring_pages(cfg, serve.ServeConfig(
+        page_size=page, buckets=(512, 2048))) * page
     shapes = {
         "k_pool": kv_cache.kv_pool_shape(
             2, LAGUNA_SLOTS * LAGUNA_TABLE + 1, page, 8, 128),
