@@ -55,8 +55,8 @@ __all__ = ["ModelConfig", "BLOCKS", "block_of", "exact_mode", "init_params",
 # a block", names it; tests/test_serve_blocks.py holds the modules to
 # it).  This module is the GPT-2 block.
 BLOCKS = {"gpt2": "model", "deepseek_v3": "latent_moe",
-          "granitemoehybrid": "granite_hybrid",
-          "bailing_hybrid": "bailing_hybrid", "laguna": "laguna"}
+          "granitemoehybrid": "granite_hybrid", "laguna": "laguna",
+          "bailing_hybrid": "bailing_hybrid", "lfm2_moe": "lfm2_moe"}
 
 
 def block_of(cfg):
@@ -106,25 +106,24 @@ class ModelConfig:
     the published ``layer_types`` order, no positions, a shared SwiGLU of
     ``d_ff``, four multipliers, a tied head) is stated the same way, by
     the group of fields from ``num_key_value_heads`` on.
-    ``"bailing_hybrid"`` (``bailing_hybrid.py``: KDA linear-attention
-    layers and gated latent-attention layers, a dense SwiGLU then
-    group-limited routed experts of which this chip may hold a share)
-    takes the latent block's fields, ``layer_types`` of ``"kda"`` |
-    ``"mla"`` (the published stack's layer ``i`` is ``"mla"`` where
-    ``(i + 1) % layer_group_size == 0``; the tuple states the layers
-    kept) and the last group: the router's group limit under its
-    published names, the contiguous range of experts held here, and the
-    KDA mixer's head count, head width, convolution taps, gate bound and
-    prefill chunk.  ``"laguna"`` (``laguna.py``: sliding-window and full
-    grouped-query attention layers with a query-head count a layer, a
-    rotary embedding of two kinds, a sigmoid gate a head, softmax-routed
-    experts of which this chip may hold a share) takes the expert fields,
-    ``num_key_value_heads``, ``layer_types`` of ``"full_attention"`` |
-    ``"sliding_attention"`` and the last group, under the published
-    ``config.json``'s names: the head width, the query heads of each
-    layer, the window, the two ``rope_parameters`` groups (a mapping a
-    kind of layer; kept as sorted tuples, which hash), the leading dense
-    layers, the shared expert's width and the router's score function.
+    ``"bailing_hybrid"`` (``bailing_hybrid.py``: KDA linear-attention and
+    gated latent-attention layers, a dense SwiGLU then group-limited
+    routed experts of which this chip may hold a share) takes the latent
+    block's fields, ``layer_types`` of ``"kda"`` | ``"mla"`` (the tuple
+    states the layers kept) and the group from ``n_group`` on: the
+    router's group limit, the range of experts held here, and the KDA
+    mixer's heads, head width, taps, gate bound and prefill chunk.
+    ``"laguna"`` (``laguna.py``: sliding-window and full grouped-query
+    attention layers, a query-head count a layer, two rotary embeddings,
+    a sigmoid gate a head, softmax-routed experts of which this chip may
+    hold a share) takes the expert fields, ``num_key_value_heads``,
+    ``layer_types`` of ``"full_attention"`` | ``"sliding_attention"`` and
+    the group from ``attn_head_dim`` on (``rope_parameters``: a mapping a
+    kind of layer, kept as sorted tuples, which hash).  ``"lfm2_moe"``
+    (``lfm2_moe.py``: gated short convolutions, QK-normed grouped-query
+    attention layers, sigmoid-routed experts with a selection bias, a
+    tied head) takes the same fields, ``layer_types`` of ``"conv"`` |
+    ``"full_attention"`` and the convolution's taps, ``conv_L_cache``.
     """
     vocab_size: int
     num_layers: int
@@ -135,8 +134,7 @@ class ModelConfig:
     qk_nope_head_dim: int = 0   # per-head query/key width without RoPE
     qk_rope_head_dim: int = 0   # rotated width; one shared key a token
     v_head_dim: int = 0
-    kv_lora_rank: int = 0       # the latent c's width; the cache holds
-    #                             kv_lora_rank + qk_rope_head_dim a token
+    kv_lora_rank: int = 0   # the latent c's width (+ the rope part, cached)
     rope_theta: float = 10000.0
     rms_norm_eps: float = 1e-6
     d_ff: int = 0               # the dense layers' SwiGLU width
@@ -151,8 +149,8 @@ class ModelConfig:
     layer_types: tuple = ()     # per layer "mamba" | "attention", or
     #                             "kda" | "mla" (bailing_hybrid), or
     #                             "full_attention" | "sliding_attention"
-    #                             (laguna, gpt2; gpt2: () = all full)
-    mamba_n_heads: int = 0
+    #                             (laguna, gpt2; gpt2: () = all full), or
+    mamba_n_heads: int = 0      # "conv" | "full_attention" (lfm2_moe)
     mamba_d_head: int = 0
     mamba_d_state: int = 0
     mamba_n_groups: int = 1     # heads that share one B and one C
@@ -184,6 +182,7 @@ class ModelConfig:
     shared_expert_intermediate_size: int = 0    # one shared SwiGLU's width
     scoring_func: str = "sigmoid"   # the router's scores: "sigmoid" (+ a
     #                                 selection bias) | "softmax"
+    conv_L_cache: int = 0       # taps of an lfm2_moe short convolution
 
     def __post_init__(self):
         if isinstance(self.rope_parameters, dict):
@@ -206,7 +205,8 @@ class ModelConfig:
         the slot-private state its block's ``state_shapes`` names."""
         if self.layer_types:
             return tuple({"attention": "full", "mamba": "ssm", "mla": "full",
-                          "kda": "ssm", "full_attention": "full",
+                          "kda": "ssm", "conv": "ssm",
+                          "full_attention": "full",
                           "sliding_attention": "window"}.get(t, t)
                          for t in self.layer_types)
         return ("full",) * self.num_layers

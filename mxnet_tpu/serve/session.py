@@ -1197,6 +1197,15 @@ class InferenceSession(object):
         ``decode_report()``'s ``blocks_visited``); ``ring_rows``, the rows
         a slot's ring holds in a window layer, and ``kv_lanes``.
 
+        The short-convolution / QK-normed grouped-query block
+        (``serve/lfm2_moe.py``): the share's router counts, the six
+        attention counts above (the two window ones stay 0: it has no
+        window layer), and the Mamba-2 block's ``prefills_from_zero``,
+        ``prefills_carried``, ``rows_valid`` and ``rows_padded`` for its
+        convolutions; ``conv_layers``, ``full_layers``, ``window_layers``
+        (0), ``expert_layers``, ``experts_held``, ``state_bytes_per_slot``
+        and ``kv_lanes``.
+
         Every note of the decode executable's trace is copied in, so
         where the paged-attention kernel was traced its
         ``paged_kernel_layers`` shows here as in ``decode_report()``."""

@@ -232,6 +232,39 @@ def test_a_fresh_prompt_in_chunks_says_so_in_its_spans(params):
                 if r.parent == prefill.id]) == 3
 
 
+def test_chunks_that_carry_state_say_so_in_their_spans():
+    """The same on the block whose chunks carry slot-private state (the
+    short convolutions' two rows): a fresh prompt of 37 tokens is one
+    ``session.prefill`` of three chunks and one ``prefill.launch`` a
+    chunk, and the block counts two of them as carried."""
+    cfg = serve.ModelConfig(
+        block="lfm2_moe", vocab_size=61, num_layers=3, d_model=32,
+        num_heads=4, num_key_value_heads=2, max_len=64, attn_head_dim=8,
+        rope_theta=1e6, rms_norm_eps=1e-5,
+        layer_types=("conv", "full_attention", "conv"), conv_L_cache=3,
+        d_ff=48, first_k_dense=1, moe_d_ff=16, n_routed_experts=8,
+        num_experts_per_tok=4, experts_held=(2, 2), tie_word_embeddings=True)
+    sess = serve.InferenceSession(
+        serve.init_params(cfg, seed=3), model=cfg, config=serve.ServeConfig(
+            slots=2, page_size=PAGE, buckets=(8, 16), max_new=8,
+            max_prompt=40, exact=False))
+    prompt = np.random.default_rng(7).integers(0, 61, 37).tolist()
+    profiler.record_spans(True)
+    done, _ = serve.Scheduler(sess).run([serve.Request(
+        rid=0, prompt=prompt, max_new=3, arrival_s=0.0)])
+    profiler.record_spans(False)
+    assert not done[0].failed and len(done[0].tokens) == 3
+    admit, = profiler.spans("serve.admit")
+    prefill, = profiler.spans("session.prefill")
+    assert prefill.parent == admit.id
+    assert prefill.attrs == {"slot": admit.attrs["slot"], "prompt": 37,
+                             "cached": 0, "chunks": 3, "bucket": 8}
+    assert len([r for r in profiler.spans("prefill.launch")
+                if r.parent == prefill.id]) == 3
+    rep = sess.block_report()
+    assert (rep["prefills_from_zero"], rep["prefills_carried"]) == (1, 2)
+
+
 def test_a_step_is_its_four_segments_in_order(session):
     profiler.record_spans(True)
     serve.Scheduler(session).run(requests())

@@ -71,6 +71,13 @@ LAGUNA = serve.ModelConfig(
     mlp_only_layers=(0,), d_ff=48, moe_d_ff=16, n_routed_experts=16,
     num_experts_per_tok=4, shared_expert_intermediate_size=16,
     routed_scaling_factor=2.5, scoring_func="softmax", experts_held=(4, 4))
+LFM2 = serve.ModelConfig(
+    block="lfm2_moe", vocab_size=61, num_layers=4, d_model=32, num_heads=4,
+    num_key_value_heads=2, max_len=64, attn_head_dim=8, rope_theta=1e6,
+    rms_norm_eps=1e-5, layer_types=("conv", "full_attention", "conv", "conv"),
+    conv_L_cache=3, d_ff=48, first_k_dense=1, moe_d_ff=16,
+    n_routed_experts=16, num_experts_per_tok=4, experts_held=(4, 4),
+    tie_word_embeddings=True)
 CONF = dict(slots=3, page_size=8, buckets=(8, 16), max_new=8)
 
 
@@ -124,6 +131,7 @@ PREFILL_SCANS = {
     "latent_one_block": (LATENT, dict(exact=False), 2, 24),
     "granite": (GRANITE, dict(), 1, 8),
     "bailing": (BAILING, dict(), 1, 8),
+    "lfm2": (LFM2, dict(), 1, 8),
 }
 
 
@@ -167,6 +175,8 @@ VARIANTS = {
     "bailing": (BAILING, dict()),
     "laguna": (LAGUNA, dict()),
     "laguna_long_prompts": (LAGUNA, dict(max_prompt=40)),
+    "lfm2": (LFM2, dict()),
+    "lfm2_long_prompts": (LFM2, dict(max_prompt=40)),
 }
 
 
@@ -297,10 +307,41 @@ def test_rings_by_the_models_window_beside_pages():
     assert sorted(sess.executables) == ["decode", "prefill_16", "prefill_8"]
 
 
+def test_a_state_pool_and_nothing_else_in_three_layers_of_four():
+    """The sixth block's cache: K/V pages for its one attention layer, and
+    for its three convolution layers one state pool of two rows a slot and
+    nothing else; three counters, none among the pools; the paged reader's
+    report, since its attention layer runs it; fresh prompts in chunks
+    with buckets + 1 executables."""
+    sess = serve.InferenceSession(
+        serve.init_params(LFM2, seed=5), model=LFM2,
+        config=serve.ServeConfig(**dict(CONF, max_prompt=40)))
+    assert sorted(sess.cache.pools) == ["conv_state", "k_pool", "v_pool"]
+    assert sess.cache.pools["k_pool"].shape \
+        == kv_cache.kv_pool_shape(1, 3 * 6 + 1, 8, 2, 8) == (1, 19, 8, 16)
+    assert sess.cache.pools["conv_state"].shape == (3, 3, 2, 32)
+    assert sess.cache.state == ("conv_state",)
+    assert sess.cache.paged == ("k_pool", "v_pool") and sess.cache.hybrid
+    assert (sess.cache.n_full, sess.cache.n_ssm, sess.cache.n_window) \
+        == (1, 3, 0)
+    assert sorted(sess.counters) == ["attn_stats", "conv_stats", "moe_stats"]
+    assert sess.decode_report()["kv_lanes"] \
+        == sess.block_report()["kv_lanes"] == 16
+    rep = sess.block_report()
+    assert (rep["experts_held"], rep["state_bytes_per_slot"]) \
+        == (4, 3 * 2 * 32 * 4)
+    assert sorted(sess.executables) == ["decode", "prefill_16", "prefill_8"]
+    prompt = list(range(1, 38))
+    slot = sess.try_alloc(len(prompt), 4, tokens=prompt)
+    sess.prefill(slot, prompt)
+    assert sess.block_report()["prefills_carried"] == 2
+    assert sess.prefill_report()["chunks"] == 3
+
+
 # block -> a model of it: with windowed layers where the block has any
 RINGS = {"gpt2": GPT2_WINDOWED, "deepseek_v3": LATENT,
          "granitemoehybrid": GRANITE, "bailing_hybrid": BAILING,
-         "laguna": LAGUNA}
+         "laguna": LAGUNA, "lfm2_moe": LFM2}
 
 
 @pytest.mark.parametrize("name", sorted(serve_model.BLOCKS))

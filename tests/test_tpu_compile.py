@@ -668,6 +668,117 @@ def _laguna_program(one_chip, monkeypatch, bucket):
         compiler_options=laguna.compiler_options("tpu")), shapes, notes
 
 
+# ``lfm2-24b-l13-docqa``'s executables at LFM2-24B-A2B's published widths
+# over the cell's cache: 13 layers (conv | attention conv conv conv x 3; 32
+# query heads over 8 key/value heads of 64; 3 taps), experts 0-7 of 64
+# held, 1/8 of the vocabulary, a tied head; 64 slots x 576 pages of 16 +
+# the trash page in the three attention layers' K/V pools, 8 heads of 64
+# folded into 512 lanes (3.62 GB each), and two convolution rows a slot in
+# ten layers (10.5 MB).  What is compiled is ``lfm2_moe.decode_step`` /
+# ``lfm2_moe.prefill_forward`` with the TPU's branches taken.
+LFM2_SLOTS, LFM2_TABLE = 64, (8192 + 1024) // 16
+
+
+def _lfm2_program(one_chip, monkeypatch, bucket):
+    """-> the compiled decode step (``bucket`` 0) or prefill chunk of
+    ``bucket`` rows, the cache's pool shapes, and the notes of the trace."""
+    from mxnet_tpu import serve
+    from mxnet_tpu.serve import kv_cache, lfm2_moe
+    from mxnet_tpu.serve import model as serve_model
+
+    cfg = serve.ModelConfig(
+        block="lfm2_moe", vocab_size=8192, num_layers=13, d_model=2048,
+        num_heads=32, num_key_value_heads=8, max_len=128000,
+        attn_head_dim=64, rope_theta=1e6, rms_norm_eps=1e-5,
+        layer_types=("conv",) + ("full_attention", "conv", "conv", "conv")
+        * 3, conv_L_cache=3, d_ff=11776, first_k_dense=1, moe_d_ff=1536,
+        n_routed_experts=64, num_experts_per_tok=4,
+        tie_word_embeddings=True, experts_held=(0, 8)).validate()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    i32 = jnp.int32
+    params = {k: sds(v) for k, v in lfm2_moe.param_shapes(cfg).items()}
+    assert abs(sum(math.prod(v.shape) for v in params.values())
+               - 1196.0e6) < 1e6
+    page = 16
+    shapes = {"k_pool": kv_cache.kv_pool_shape(
+        3, LFM2_SLOTS * LFM2_TABLE + 1, page, 8, 64)}
+    shapes["v_pool"] = shapes["k_pool"]
+    shapes["conv_state"] = (10, LFM2_SLOTS, 2, 2048)
+    assert shapes["k_pool"][-1] == 512 and len(shapes["k_pool"]) == 4
+    pools = {name: sds(shape) for name, shape in shapes.items()}
+    counters = {name: sds(leaf.shape, i32) for name, leaf
+                in lfm2_moe.init_counters(cfg).items()}
+    static = dict(cfg=cfg, page_size=page, exact=False, kv_quant="")
+    if bucket:
+        def step(params, tokens, length, offset, table_row, pools, counters,
+                 slot):
+            return lfm2_moe.prefill_forward(
+                params, tokens, length, offset, table_row, pools, counters,
+                slot=slot, **static)
+
+        avals = (params, sds((1, bucket), i32), sds((), i32), sds((), i32),
+                 sds((LFM2_TABLE,), i32), pools, counters, sds((), i32))
+        donate = (5, 6)
+    else:
+        def step(params, tokens, lengths, tables, pools, counters):
+            return lfm2_moe.decode_step(params, tokens, lengths, tables,
+                                        pools, counters, **static)
+
+        avals = (params, sds((LFM2_SLOTS,), i32), sds((LFM2_SLOTS,), i32),
+                 sds((LFM2_SLOTS, LFM2_TABLE), i32), pools, counters)
+        donate = (4, 5)
+    with jax.default_matmul_precision("default"), \
+            serve_model.trace_notes() as notes:
+        lowered = jax.jit(step, donate_argnums=donate).lower(*avals)
+    return lowered.compile(
+        compiler_options=lfm2_moe.compiler_options("tpu")), shapes, notes
+
+
+@pytest.mark.parametrize("bucket, tile, temporaries", [
+    (0, 8, 256 << 20), (2048, 128, 3 << 29)],
+    ids=["decode", "prefill-2048"])
+def test_lfm2_executables_compile_for_v5e_at_the_published_widths(
+        one_chip, monkeypatch, bucket, tile, temporaries):
+    """The whole step fits the chip beside its arguments (12.04 GB: 4.78
+    of weights, 7.25 of pages, 0.01 of convolution rows), Mosaic takes a
+    whole float32 expert of 1536 x 2048 a block in all twelve expert
+    layers, and the donated pools and the state are updated where they
+    lie: the result aliases all three, and no operation copies a whole
+    K/V pool into another layout.  The folded pools keep
+    the loop: one a layer under ``gqa_decode``, no paged-attention
+    kernel."""
+    from mxnet_tpu.ops.grouped_matmul import kernel_name
+
+    compiled, shapes, notes = _lfm2_program(one_chip, monkeypatch, bucket)
+    assert notes.get("expert_kernel_layers") == 12
+    assert not notes.get("paged_kernel_layers")
+    text = compiled.as_text()
+    assert len(_kernel_calls(text, kernel_name(tile))) == 12
+    memory = compiled.memory_analysis()
+    held = 4 * sum(math.prod(shape) for shape in shapes.values())
+    assert memory.alias_size_in_bytes >= held
+    assert 12.0e9 < memory.argument_size_in_bytes < 12.1e9
+    assert memory.temp_size_in_bytes < temporaries
+    copies, _ = _whole_pool_copies(text, shapes["k_pool"])
+    assert not copies, copies
+    # the state (10.5 MB) is at rest a slot's two rows at a time; a decode
+    # step turns it slot-minor and back (three copies of 10.5 MB, under
+    # 0.1 ms of an ~11 ms step: ling's ``conv_state`` likewise, ROADMAP
+    # D15), a prefill chunk touches one slot's rows and copies nothing
+    copies, _ = _whole_pool_copies(text, shapes["conv_state"])
+    assert len(copies) <= (0 if bucket else 3), copies
+    if not bucket:
+        from mxnet_tpu.ops import paged_attention
+
+        assert not _kernel_calls(text, paged_attention.kernel_name(8))
+        assert len(re.findall(r" while\([^\n]*op_name=\"[^\"]*gqa_decode",
+                              text)) == 3
+
+
 def _decode_reads_by_kernel(text, pool_shape, scope, layers):
     """A decode executable's compiled text holds ``layers``
     paged-attention kernels of 8 pages a block, no ``while`` that the
