@@ -511,10 +511,15 @@ def paged_decode_attention(q, k_pool, v_pool, layer, tables, lengths,
 
     That is the ``fori_loop``, on every backend and for every call.  On a
     TPU a call that :func:`~.paged_attention.paged_attention_eligible`
-    accepts (not ``mi``, no scales, float32 pools that keep their heads'
-    axis) is instead ONE Pallas kernel (``ops/paged_attention.py``) that
-    walks each slot's own pages and stops at that slot's length, at the
-    loop's precision; the loop is its fallback and its oracle.  Which ran
+    accepts (not ``mi``, no scales, float32 pools in either layout the
+    cache gives them: heads of 128 on an axis of their own, or heads that
+    divide a lane tile folded into a last axis of whole lane tiles, under
+    a table of at least 2 048 keys) is
+    instead ONE Pallas kernel (``ops/paged_attention.py``) that walks each
+    slot's own pages and stops at that slot's length, at the loop's
+    precision; the loop is its fallback (``mi``, quantized or bfloat16
+    pages, heads of 256, folded pools under short tables, every other
+    backend) and its oracle.  Which ran
     is noted in the trace under way (``paged_kernel_layers``, which
     ``InferenceSession.decode_report()`` hands on).
 
@@ -547,7 +552,8 @@ def paged_decode_attention(q, k_pool, v_pool, layer, tables, lengths,
             % (heads, q.shape, lengths.shape))
     if scale is None:
         scale = 1.0 / (q.shape[-1] ** 0.5)
-    if paged_attention_eligible(q, k_pool, v_pool, mi, k_scale, v_scale):
+    if paged_attention_eligible(q, k_pool, v_pool, mi, k_scale, v_scale,
+                                max_pages * page_size):
         note_traced("paged_kernel_layers", 1)
         return paged_attention(q, k_pool, v_pool, layer, tables, lengths,
                                page_size, scale)

@@ -3,43 +3,75 @@
 ``ops/attention.py:paged_decode_attention`` as a ``fori_loop`` gathers the
 next few pages of EVERY slot up to the longest live context and masks what
 a shorter slot cannot see.  This kernel walks, for each slot, **that
-slot's** pages ``0 .. ceil(lengths[s] / page_size) - 1`` and no others:
+slot's** pages ``0 .. ceil(lengths[s] / page_size) - 1`` and no others,
+over float32 pools in either layout ``serve/kv_cache.py:kv_pool_shape``
+gives them at rest (:func:`paged_attention_eligible`: heads of 128 on an
+axis of their own, or heads that divide a lane tile folded into the last
+axis).  One body; what differs between the layouts is how a page lies in
+the buffer and how a head's rows are taken out of it, both read off the
+buffers' shapes:
 
 * the pools stay where they lie, whole, in HBM (``memory_space=ANY``):
   the caller's ``(layers, pages, page_size, H, D)`` is handed over as
   ``(layers * pages, page_size x H, D)`` (the layers merge into the
   pages, and a page's rows into its heads, which are whole sublane tiles:
-  a bitcast) and the page numbers as ``tables + layer * pages``.  Never
-  ``pool[layer]``: XLA materialises such a slice in front of a custom
-  call, a whole layer of the pool a call;
+  a bitcast), a folded ``(layers, pages, page_size, H x D)`` as
+  ``(layers * pages, page_size, H x D)``, and the page numbers as
+  ``tables + layer * pages``.  Never ``pool[layer]``: XLA materialises
+  such a slice in front of a custom call, a whole layer of the pool a
+  call;
 * ``lengths`` and the tables are scalar-prefetched.  The grid is the
   slots; inside a slot the kernel loops over blocks of ``pages_per_block``
-  pages.  A page, ``(page_size x H, D)`` contiguous, K and V each, is
-  one DMA HBM -> VMEM (64 KB at 16 x 8 x 128 float32); a block's copies
+  pages.  A page, contiguous, K and V each, is one DMA HBM -> VMEM (64 KB
+  at 16 x 8 x 128 float32, 32 KB at 16 x 512 folded); a block's copies
   are all in flight at once and the NEXT block's (the slot's next, or the
   next slot's first) are started before the current block is computed,
   into the other half of a double buffer.  A page past the slot's own
   length is neither copied nor computed: its copy is not started, and its
   rows are masked;
-* in VMEM one key/value head's rows are a strided load of the block seen
-  as ``(rows x H, D)``; scores and the value product go to the MXU with
-  the ``R`` query rows that share the head as the left operand (padded to
-  a sublane tile of 8), operands rounded to bfloat16 where they are read
-  and accumulated in float32: what ``jnp.einsum`` at default precision
-  does with the loop's float32 operands on this chip, **the same
-  precision, not a lower one**.  Where the process asks for full-precision
-  matmuls (``jax_default_matmul_precision`` ``highest`` / ``float32``, as
-  the tests do) the operands stay float32, as the loop's einsum's do.  The
-  online softmax (running maximum, sum, correction) is
+* **heads on their own axis**: in VMEM one key/value head's rows are a
+  strided load of the block seen as ``(rows x H, D)``; scores and the
+  value product go to the MXU with the ``R`` query rows that share the
+  head as the left operand (padded to a sublane tile of 8);
+* **folded heads**: the block is ``(keys, H x D)`` and a head of 64 is
+  half a lane tile.  The kernel never slices narrower than a tile: for
+  lane tile ``t`` (heads ``2t``, ``2t + 1``) the left operand holds both
+  heads' ``R`` query rows one under the other, **block-diagonally inside
+  the tile** (head ``2t``'s rows zero in lanes 64-127, head ``2t + 1``'s
+  zero in lanes 0-63; built once, outside the kernel, from ``q``), so
+  ``Q_t (2R, 128) x K[:, tile t]^T`` is each row's own head's scores and
+  ``P (2R, keys) x V[:, tile t]`` is each row's result in its own head's
+  64 lanes; its neighbour's 64 lanes are dropped outside the kernel.  Two
+  MXU products a tile a block, the same products a byte as the other
+  layout.  A lane slice of 64 a head instead costs twice the products
+  and a lane shift: 346 GB/s on the live rows where this form reads 535
+  at the same 8 pages a block (a v5e at LFM2-24B-A2B's pools; PERF.md,
+  PR 48).  To the kernel a folded pool's "heads" are its lane tiles, of
+  128 lanes each, and a tile's query rows are just rows: any head width
+  that divides a tile takes the same form;
+* operands are rounded to bfloat16 where they are read and accumulated in
+  float32: what ``jnp.einsum`` at default precision does with the loop's
+  float32 operands on this chip, **the same precision, not a lower one**
+  (the block-diagonal zeros are exact).  Where the process asks for
+  full-precision matmuls (``jax_default_matmul_precision`` ``highest`` /
+  ``float32``, as the tests do) the operands stay float32, as the loop's
+  einsum's do.  The online softmax (running maximum, sum, correction) is
   ``online_block_merge``'s in float32, with a large finite negative in
   place of ``-inf`` so that no ``isfinite`` guard is needed; the mask is
   ``k_pos < lengths[s]``.  A slot of length 0 reads nothing and gives 0,
   as the loop does.
 
+What keeps the loop: ``mi`` (``exact=True``), ``kv_quant`` pages and
+their scales, bfloat16 pools, heads of 256, a folded last axis that is
+not whole lane tiles, a folded pool under a table of fewer than 2 048 keys
+(granite-4.0-h-micro's 768: ``_FOLDED_MIN_TABLE_KEYS`` has why), every
+backend but a TPU.
+
 **VMEM** (:func:`_vmem_bytes`): the double buffer, ``2 x 2 x
-pages_per_block x page`` (4 MB at 8 pages of 128 KB, the dense cell's),
-the query and result blocks twice each, the running statistics, and slack
-for Mosaic's own scratch; stated as ``vmem_limit_bytes``.
+pages_per_block x page`` (4 MB at 8 pages of 128 KB, the dense cell's,
+and at 32 folded pages of 32 KB), the query and result blocks twice each,
+the running statistics, and slack for Mosaic's own scratch; stated as
+``vmem_limit_bytes``.
 
 Stale rows (a page of the block that was not copied this time) hold what
 an earlier copy left: finite by the pool's own contract (the loop's
@@ -63,46 +95,73 @@ _VMEM_SLACK = 8 << 20   # Mosaic's own scratch and what the sum leaves out
 _NT = (((1,), (1,)), ((), ()))   # contract the last axis of both operands
 # in place of -inf: exp(_NEG - m) is 0 for any real m and _NEG - _NEG is 0
 _NEG = -0.7 * float(jnp.finfo(jnp.float32).max)
-# keys a block holds: 8 pages of 16 rows.  PERF.md (PR 44) has the sweep
+# keys a block holds: 8 pages of 16 rows where a page is 64-128 KB (heads of
+# 128 on their own axis; PERF.md, PR 44, has the sweep), 32 pages where it
+# is 32 KB (8 heads of 64 folded; PERF.md, PR 48): 0.5-1 MB of keys a block
 _KEYS_PER_BLOCK = 128
+_FOLDED_KEYS_PER_BLOCK = 512
+# a folded pool's table narrower than this keeps the loop: four blocks.  At
+# granite-4.0-h-micro's 768 keys the loop is 1.09 ms of a 23.5 ms decode
+# event and the kernel took 0.45 ms off the event (+1.4 % tokens/s), while a
+# process that had no Pallas kernel took 3.6-4.3 s longer to build its
+# session (1.2 s of it the Pallas import), a tenth of that cell's set-up; at
+# LFM2-24B-A2B's 9 216 keys the loop is 35 ms of a 42 ms event (PERF.md,
+# PR 48)
+_FOLDED_MIN_TABLE_KEYS = 2048
 
 
-def paged_attention_eligible(q, k_pool, v_pool, mi, k_scale, v_scale):
+def paged_attention_eligible(q, k_pool, v_pool, mi, k_scale, v_scale,
+                             table_keys):
     """Whether ``paged_decode_attention`` sends this call to the kernel: a
     decision from what the call shows at trace time, never from whether a
     trial call raised.  The backend is TPU; ``mi`` (the M-invariant reduce
     form) is not asked; the pages are not quantized (the loop dequantises
-    a page at a time); the pools are float32 and keep their heads on an
-    axis of their own (a folded pool keeps the loop), a multiple of 8 of
-    them (whole sublane tiles), each of 128 values: Mosaic's strided load,
-    which takes one head's rows out of a page, wants a last axis of one
-    lane tile (the installed library kernel notes the same), so a head of
-    256 keeps the loop as well."""
+    a page at a time); the pools are float32 and lie in one of the two
+    layouts ``serve/kv_cache.py:kv_pool_shape`` gives them.  **Heads on an
+    axis of their own**: a multiple of 8 of them (whole sublane tiles),
+    each of 128 values: Mosaic's strided load, which takes one head's rows
+    out of a page, wants a last axis of one lane tile (the installed
+    library kernel notes the same), so a head of 256 keeps the loop.
+    **Heads folded into the last axis**: that axis is whole lane tiles and
+    a head divides a tile (64: two heads a tile), so no head straddles
+    two; and the table (``table_keys``: its columns x the page size, the
+    longest context a slot can hold) is at least
+    ``_FOLDED_MIN_TABLE_KEYS`` wide: over a shorter one the loop is too
+    small a part of a step to be worth a kernel's start-up."""
     if jax.default_backend() != "tpu" or mi:
         return False
     if k_scale is not None or v_scale is not None:
         return False
-    if k_pool.ndim != 5 or k_pool.shape != v_pool.shape:
+    if k_pool.ndim not in (4, 5) or k_pool.shape != v_pool.shape:
         return False
     if k_pool.dtype != jnp.float32 or v_pool.dtype != jnp.float32:
         return False
     if q.dtype not in (jnp.float32, jnp.bfloat16):
         return False
+    if k_pool.ndim == 4:
+        return (k_pool.shape[3] % _LANES == 0 and _LANES % q.shape[-1] == 0
+                and table_keys >= _FOLDED_MIN_TABLE_KEYS)
     heads, d = k_pool.shape[3:]
     return d == _LANES and heads % _SUBLANES == 0
 
 
-def pages_per_block(page_size, max_pages):
+def pages_per_block(page_size, max_pages, folded):
     """Pages one block of the kernel holds: ``_KEYS_PER_BLOCK`` keys'
-    worth, at least one and at most the table."""
-    return max(1, min(_KEYS_PER_BLOCK // page_size, max_pages))
+    worth (``_FOLDED_KEYS_PER_BLOCK`` over a folded pool, whose pages are
+    smaller), at least one and at most the table."""
+    keys = _FOLDED_KEYS_PER_BLOCK if folded else _KEYS_PER_BLOCK
+    return max(1, min(keys // page_size, max_pages))
 
 
-def kernel_name(pages):
-    """The ``pallas_call``'s name, which carries its block: what a trace's
+def kernel_name(pages, folded_head):
+    """The ``pallas_call``'s name, which carries its block and, over a
+    folded pool, the width of the heads a lane tile holds (``folded_head``;
+    0 where the heads keep their own axis): what a trace's
     device operations show of this reader (``paged_decode_attention_p8``:
-    8 pages a block)."""
-    return "paged_decode_attention_p%d" % pages
+    8 pages a block, heads on their own axis;
+    ``paged_decode_attention_f64_p32``: 32 pages, heads of 64 folded)."""
+    layout = "f%d_" % folded_head if folded_head else ""
+    return "paged_decode_attention_%sp%d" % (layout, pages)
 
 
 def _full_precision():
@@ -113,7 +172,8 @@ def _full_precision():
 
 def _vmem_bytes(pages, page_size, heads, rows, d):
     """The kernel's VMEM need (the module docstring's reckoning), all of
-    it float32."""
+    it float32; a folded pool's ``heads`` are its lane tiles, of ``d`` =
+    128."""
     buffers = 2 * 2 * pages * page_size * heads * d * 4
     blocks = 2 * 2 * heads * rows * d * 4
     stats = heads * rows * (d + 2 * _LANES) * 4
@@ -128,8 +188,11 @@ def _decode_kernel(lengths_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
 
     slot = pl.program_id(0)
     slots = pl.num_programs(0)
+    # heads, or a folded pool's lane tiles: the buffers' shape says which
     heads, rows, d = acc_ref.shape
     keys = pages * page_size
+    page_rows = k_buf.shape[1] // pages
+    folded = k_buf.shape[2] != d
     f32 = jnp.float32
     operand = f32 if full_precision else jnp.bfloat16
     precision = lax.Precision.HIGHEST if full_precision else None
@@ -141,7 +204,7 @@ def _decode_kernel(lengths_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
         return jnp.maximum(pl.cdiv(live_pages(s), pages), 1)  # masked block
 
     def copies(buf, i, page):
-        at = pl.ds(i * page_size * heads, page_size * heads)
+        at = pl.ds(i * page_rows, page_rows)
         return (pltpu.make_async_copy(k_hbm.at[page], k_buf.at[buf, at],
                                       sems.at[0, buf]),
                 pltpu.make_async_copy(v_hbm.at[page], v_buf.at[buf, at],
@@ -185,8 +248,11 @@ def _decode_kernel(lengths_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
         k_pos = blk * keys + lax.broadcasted_iota(jnp.int32, (rows, keys), 1)
         seen = k_pos < length
         for h in range(heads):
-            k = k_buf[buf, pl.ds(h, keys, stride=heads), :].astype(operand)
-            v = v_buf[buf, pl.ds(h, keys, stride=heads), :].astype(operand)
+            # a head's rows of the block, or a lane tile's columns of it
+            at = (slice(None), pl.ds(h * d, d)) if folded \
+                else (pl.ds(h, keys, stride=heads), slice(None))
+            k = k_buf[(buf,) + at].astype(operand)
+            v = v_buf[(buf,) + at].astype(operand)
             scores = lax.dot_general(
                 q_ref[0, h].astype(operand), k, _NT, precision=precision,
                 preferred_element_type=f32)
@@ -211,9 +277,10 @@ def _decode_kernel(lengths_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
 
 def paged_attention(q, k_pool, v_pool, layer, tables, lengths, page_size,
                     scale, pages=None):
-    """q (S, H, R, D); k_pool, v_pool (layers, pages, page_size, H, D)
-    float32; tables (S, max_pages) int32; lengths (S,) int, the valid
-    rows a slot; ``scale`` multiplies the scores.  -> (S, H, R, D) like q:
+    """q (S, H, R, D); k_pool, v_pool (layers, pages, page_size, H, D) or
+    folded, (layers, pages, page_size, H x D), float32; tables (S,
+    max_pages) int32; lengths (S,) int, the valid rows a slot; ``scale``
+    multiplies the scores.  -> (S, H, R, D) like q:
     softmax attention of each slot's R rows a head over that slot's first
     ``lengths[s]`` rows of layer ``layer``.  ``pages``: pages a block
     (:func:`pages_per_block` unless given).
@@ -224,7 +291,8 @@ def paged_attention(q, k_pool, v_pool, layer, tables, lengths, page_size,
     session's start with every executable already in the compile cache
     (PERF.md, PR 44)."""
     if pages is None:
-        pages = pages_per_block(page_size, tables.shape[1])
+        pages = pages_per_block(page_size, tables.shape[1],
+                                k_pool.ndim == 4)
     return _paged_attention(
         q, k_pool, v_pool, jnp.asarray(layer, jnp.int32), tables, lengths,
         page_size=page_size, scale=float(scale), pages=pages,
@@ -241,19 +309,34 @@ def _paged_attention(q, k_pool, v_pool, layer, tables, lengths, *, page_size,
     s, heads, r, d = q.shape
     layers, pool_pages = k_pool.shape[:2]
     max_pages = tables.shape[1]
-    rows = -(-r // _SUBLANES) * _SUBLANES
+    folded = k_pool.ndim == 4
     # the table's columns past the last whole block are never a block's
     width = -(-max_pages // pages) * pages
     tables = jnp.pad(tables.astype(jnp.int32) + layer * pool_pages,
                      ((0, 0), (0, width - max_pages)))
-    q32 = jnp.pad(q.astype(jnp.float32) * scale,
-                  ((0, 0), (0, 0), (0, rows - r), (0, 0)))
-    # a page as (rows x H, D): only leading axes merge, H whole sublane tiles
-    flat = (layers * pool_pages, page_size * heads, d)
+    q32 = q.astype(jnp.float32) * scale
+    if folded:
+        # a lane tile's heads one under the other, each head's rows zero
+        # outside its own lanes of the tile: (S, tiles, per x R, 128)
+        per = _LANES // d
+        q32 = q32.reshape(s, heads // per, per, r, d)
+        q32 = jnp.concatenate([
+            jnp.pad(q32[:, :, j],
+                    ((0, 0),) * 3 + ((j * d, _LANES - (j + 1) * d),))
+            for j in range(per)], axis=2)
+        # a page as it lies, (rows, H x D): only leading axes merge
+        flat = (layers * pool_pages,) + k_pool.shape[2:]
+    else:
+        # a page as (rows x H, D): only leading axes merge, H whole
+        # sublane tiles
+        flat = (layers * pool_pages, page_size * heads, d)
+    groups, live, lanes = q32.shape[1:]
+    rows = -(-live // _SUBLANES) * _SUBLANES
+    q32 = jnp.pad(q32, ((0, 0), (0, 0), (0, rows - live), (0, 0)))
     kernel = functools.partial(
         _decode_kernel, page_size=page_size, width=width, pages=pages,
         full_precision=full_precision)
-    block = pl.BlockSpec((1, heads, rows, d), lambda i, *_: (i, 0, 0, 0))
+    block = pl.BlockSpec((1, groups, rows, lanes), lambda i, *_: (i, 0, 0, 0))
     out = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct(q32.shape, jnp.float32),
@@ -264,17 +347,21 @@ def _paged_attention(q, k_pool, v_pool, layer, tables, lengths, *, page_size,
                       pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=block,
             scratch_shapes=[
-                pltpu.VMEM((2, pages * flat[1], d), k_pool.dtype),
-                pltpu.VMEM((2, pages * flat[1], d), v_pool.dtype),
+                pltpu.VMEM((2, pages * flat[1], flat[2]), k_pool.dtype),
+                pltpu.VMEM((2, pages * flat[1], flat[2]), v_pool.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
-                pltpu.VMEM((heads, rows, _LANES), jnp.float32),
-                pltpu.VMEM((heads, rows, _LANES), jnp.float32),
-                pltpu.VMEM((heads, rows, d), jnp.float32),
+                pltpu.VMEM((groups, rows, _LANES), jnp.float32),
+                pltpu.VMEM((groups, rows, _LANES), jnp.float32),
+                pltpu.VMEM((groups, rows, lanes), jnp.float32),
                 pltpu.SMEM((1,), jnp.int32)]),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=_vmem_bytes(pages, page_size, heads, rows, d)),
-        name=kernel_name(pages),
+            vmem_limit_bytes=_vmem_bytes(pages, page_size, groups, rows,
+                                         lanes)),
+        name=kernel_name(pages, d if folded else 0),
     )(jnp.minimum(lengths.astype(jnp.int32), max_pages * page_size),
       tables.reshape(-1), q32, k_pool.reshape(flat), v_pool.reshape(flat))
+    if folded:   # each head's rows, out of its own lanes of its tile
+        out = jnp.stack([out[:, :, j * r:(j + 1) * r, j * d:(j + 1) * d]
+                         for j in range(per)], axis=2).reshape(q.shape)
     return out[:, :, :r].astype(q.dtype)

@@ -32,7 +32,11 @@ tables, the trash page, copy-on-write and the scale pools of
 index the trailing axes: they write rows of (..., H, D) through
 :func:`append_rows` and read pages back as (..., H, D) through
 :func:`read_pages` / :func:`read_context`, which reshape what they have
-*gathered* (a few pages a slot), never the pool.
+*gathered* (a few pages a slot), never the pool.  One reader takes
+folded pages in place, without gathering them: the decode step's
+paged-attention kernel (``ops/paged_attention.py``), which copies a slot's
+live pages as they lie, ``(page_size, H * D)``, and works on whole lane
+tiles of them.
 :attr:`PagedKVCache.kv_lanes` says which layout a cache took.
 
 A latent-attention model (``latent_dim > 0``, ``serve/latent_moe.py``)

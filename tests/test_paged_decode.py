@@ -193,13 +193,24 @@ def test_reader_takes_a_groups_query_heads_as_rows_and_no_other_head_count():
 
 KD = 128    # a head of whole lane tiles: the pools keep the heads' axis
 
+# the two layouts the cache gives pools at rest -> (key/value heads, head
+# width): two heads of 128 on an axis of their own, or eight heads of 64
+# folded into 512 lanes, two a lane tile (granite-4.0-h-micro, LFM2)
+LAYOUTS = {"heads": (2, KD), "folded": (8, 64)}
+# (layout, query rows that share a key/value head)
+FORMS = [("heads", 1), ("heads", 6), ("folded", 1), ("folded", 4),
+         ("folded", 5)]
+FORM_IDS = ["%s-R%d" % form for form in FORMS]
 
-def _kernel_case(rs, lengths, rows):
-    """Pools of heads of 128 whose every row is finite garbage where no
+
+def _kernel_case(rs, lengths, rows, layout="heads", head=None):
+    """Pools in ``layout`` whose every row is finite garbage where no
     slot can see it (the pages past a length, the rows of a last page
     past it, the trash page, the other layer): 1e3, so that one such row
     reaching a result moves it by far more than any tolerance."""
-    shape = (LAYERS, TRASH + 1, PAGE, H, KD)
+    heads, d = LAYOUTS[layout]
+    d = head or d
+    shape = (LAYERS, TRASH + 1, PAGE, heads, d)
     k, v = (rs.randn(*shape).astype(np.float32) for _ in "kv")
     tables = np.asarray(_tables(rs, lengths))
     seen = np.zeros(shape[:3], bool)
@@ -207,7 +218,9 @@ def _kernel_case(rs, lengths, rows):
         for pos in range(n):
             seen[LAYER, tables[s, pos // PAGE], pos % PAGE] = True
     k[~seen], v[~seen] = 1e3, -1e3
-    q = jnp.asarray(rs.randn(S, H, rows, KD).astype(np.float32))
+    q = jnp.asarray(rs.randn(S, heads, rows, d).astype(np.float32))
+    if layout == "folded":
+        k, v = (x.reshape(shape[:3] + (heads * d,)) for x in (k, v))
     return q, jnp.asarray(k), jnp.asarray(v), jnp.asarray(tables)
 
 
@@ -217,21 +230,24 @@ def _by_kernel(q, k, v, tables, lengths, pages=2, **more):
     with pltpu.force_tpu_interpret_mode():
         return paged_attention.paged_attention(
             q, k, v, LAYER, tables, lengths, PAGE,
-            more.pop("scale", 1.0 / KD ** 0.5), pages=pages, **more)
+            more.pop("scale", 1.0 / q.shape[-1] ** 0.5), pages=pages, **more)
 
 
-@pytest.mark.parametrize("rows", [1, 6], ids=["R1", "R6"])
+@pytest.mark.parametrize("layout, rows", FORMS, ids=FORM_IDS)
 @pytest.mark.parametrize("case", sorted(LENGTHS))
-def test_kernel_equals_the_loop(case, rows):
+def test_kernel_equals_the_loop(case, layout, rows):
     """Each slot's own pages and no others, two pages a block (so a slot
     is one to three blocks and the next slot's first block is fetched
     behind the last): the loop's result to the tolerance of two
     executables of one computation, one query row a head or a group's
-    six, for every case of ``LENGTHS``; nothing a slot cannot see reaches
-    its result, and nothing is NaN."""
+    four, five or six, for every case of ``LENGTHS``, over pools that keep
+    their heads' axis and over folded ones (where a head's rows are its 64
+    lanes of a lane tile it shares with its neighbour: the neighbour's
+    keys, values and queries reach no result); nothing a slot cannot see
+    reaches its result, and nothing is NaN."""
     rs = np.random.RandomState(12)
     lengths = jnp.asarray(LENGTHS[case], jnp.int32)
-    q, k, v, tables = _kernel_case(rs, LENGTHS[case], rows)
+    q, k, v, tables = _kernel_case(rs, LENGTHS[case], rows, layout)
     want = _paged(q, k, v, None, None, tables, lengths, False)
     got = _by_kernel(q, k, v, tables, lengths)
     assert got.shape == q.shape and got.dtype == q.dtype
@@ -240,24 +256,40 @@ def test_kernel_equals_the_loop(case, rows):
     assert_close_across_executables(got, want)
 
 
+@pytest.mark.parametrize("head", [32, KD])
+def test_folded_kernel_takes_any_head_that_divides_a_lane_tile(head):
+    """Four heads of 32 a lane tile, or one of 128: the same form, since
+    the kernel sees lane tiles and the query rows laid out over them."""
+    rs = np.random.RandomState(19)
+    lengths = jnp.asarray(LENGTHS["idle_beside_full"], jnp.int32)
+    q, k, v, tables = _kernel_case(rs, LENGTHS["idle_beside_full"], 3,
+                                   "folded", head)
+    assert k.shape[-1] == 8 * head
+    want = _paged(q, k, v, None, None, tables, lengths, False)
+    assert_close_across_executables(
+        _by_kernel(q, k, v, tables, lengths), want)
+
+
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
 @pytest.mark.parametrize("pages", [1, 3, 8])
-def test_kernel_pages_a_block_do_not_change_the_result(pages):
+def test_kernel_pages_a_block_do_not_change_the_result(pages, layout):
     """One page a block, three (the table's five columns completed with a
     sixth that no block reads) or the whole table in one."""
     rs = np.random.RandomState(13)
     lengths = jnp.asarray(LENGTHS["one_long"], jnp.int32)
-    q, k, v, tables = _kernel_case(rs, LENGTHS["one_long"], 6)
+    q, k, v, tables = _kernel_case(rs, LENGTHS["one_long"], 6, layout)
     want = _paged(q, k, v, None, None, tables, lengths, False)
     assert_close_across_executables(
         _by_kernel(q, k, v, tables, lengths, pages=pages), want)
 
 
-def test_kernel_gives_a_slot_of_length_zero_zero_and_takes_a_scale():
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_kernel_gives_a_slot_of_length_zero_zero_and_takes_a_scale(layout):
     """No row to see: 0, as the loop gives; ``scale`` multiplies the
     scores; a bfloat16 query gets a bfloat16 answer."""
     rs = np.random.RandomState(14)
     lengths = jnp.asarray((0, CAP, 7), jnp.int32)
-    q, k, v, tables = _kernel_case(rs, (0, CAP, 7), 1)
+    q, k, v, tables = _kernel_case(rs, (0, CAP, 7), 1, layout)
     got = _by_kernel(q, k, v, tables, lengths, scale=0.2)
     want = paged_decode_attention(q, k, v, LAYER, tables, lengths, PAGE,
                                   scale=0.2)
@@ -272,13 +304,14 @@ def test_kernel_gives_a_slot_of_length_zero_zero_and_takes_a_scale():
         limit=2, dtype="bfloat16")
 
 
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
 @pytest.mark.parametrize("pool", ["k", "v"])
-def test_kernel_sees_a_planted_fault_in_a_live_page(pool):
+def test_kernel_sees_a_planted_fault_in_a_live_page(pool, layout):
     """The control: two rows of one live page swapped move that slot's
     result and no other's."""
     rs = np.random.RandomState(15)
     lengths = jnp.asarray(LENGTHS["mid_page"], jnp.int32)
-    q, k, v, tables = _kernel_case(rs, LENGTHS["mid_page"], 6)
+    q, k, v, tables = _kernel_case(rs, LENGTHS["mid_page"], 6, layout)
     sound = np.asarray(_by_kernel(q, k, v, tables, lengths))
     arr = np.array(k if pool == "k" else v)
     live = int(tables[2, 1])     # slot 2 holds 10 rows: its second page
@@ -289,16 +322,20 @@ def test_kernel_sees_a_planted_fault_in_a_live_page(pool):
     np.testing.assert_array_equal(faulty[:2], sound[:2])
 
 
-def test_kernel_rounds_its_operands_as_the_chip_does_at_default_precision():
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_kernel_rounds_its_operands_as_the_chip_does_at_default_precision(
+        layout):
     """What runs on the chip: at the default matmul precision the
     operands are rounded to bfloat16 where they are read and the sums are
     float32, as XLA's einsum does there with the loop's.  The CPU's loop
     multiplies in float32, so the two differ by the roundings: the
     largest read over six seeds was 0.36 % of the result's largest
-    magnitude; the limit is 4 eps of bfloat16, 1.6 %."""
+    magnitude (heads of 128; folded heads of 64, whose neighbour's lanes
+    are exact zeros in the left operand, over seeds 16 and 1-5: 0.36 % as
+    well); the limit is 4 eps of bfloat16, 1.6 %."""
     rs = np.random.RandomState(16)
     lengths = jnp.asarray(LENGTHS["one_long"], jnp.int32)
-    q, k, v, tables = _kernel_case(rs, LENGTHS["one_long"], 6)
+    q, k, v, tables = _kernel_case(rs, LENGTHS["one_long"], 6, layout)
     want = np.asarray(_paged(q, k, v, None, None, tables, lengths, False))
     with jax.default_matmul_precision("default"):
         got = np.asarray(_by_kernel(q, k, v, tables, lengths))
@@ -315,56 +352,99 @@ def _loop_text(q, k, v, ks, vs, tables, lengths, mi):
 def test_only_an_eligible_call_on_a_tpu_takes_the_kernel(monkeypatch):
     """``paged_attention_eligible`` is read off the call while it is
     traced: the backend is a TPU, ``mi`` is not asked, the pages carry no
-    scales, the pools keep their heads' axis in whole sublane tiles of
-    whole lane tiles, in float32.  Every other call lowers to the loop's
-    text, letter for letter what it lowered to with no kernel to ask."""
+    scales, the pools are float32 and either keep their heads' axis in
+    whole sublane tiles of whole lane tiles, or fold heads that divide a
+    lane tile into a last axis of whole lane tiles under a table of at
+    least 2 048 keys.  Every other call lowers to the loop's text, letter
+    for letter what it lowered to with no kernel to ask."""
     rs = np.random.RandomState(17)
     shape = (LAYERS, TRASH + 1, PAGE, 8, KD)
     k = v = jnp.asarray(rs.randn(*shape).astype(np.float32))
     q = jnp.asarray(rs.randn(S, 8, 6, KD).astype(np.float32))
     scales = jnp.ones(shape[:3], jnp.float32)
     lengths = jnp.asarray(LENGTHS["mid_page"], jnp.int32)
-    tables = _tables(rs, LENGTHS["mid_page"])
+    toy = _tables(rs, LENGTHS["mid_page"])
+    # the same pages under a table as wide as the rule for folded pools
+    # asks (512 pages of 4 rows), and under one a page short of it
+    wide = jnp.concatenate(
+        [toy, jnp.full((S, 512 - MAX_PAGES), TRASH, jnp.int32)], axis=1)
+    assert wide.shape[1] * PAGE == paged_attention._FOLDED_MIN_TABLE_KEYS
     eligible = paged_attention.paged_attention_eligible
-    folded = k.reshape(shape[:3] + (-1,))
     two_heads = k[:, :, :, :2]
+
+    def folded(pool, heads, d):
+        """``heads`` heads of ``d`` out of ``pool``, folded as the cache
+        folds them, and a query of as many heads."""
+        pool = pool[:, :, :, :heads, :d].reshape(shape[:3] + (heads * d,))
+        return q[:, :heads, :, :d], pool, pool
+
     refused = {
-        "mi": (q, k, v, True, None, None),
+        # name: (q, K pool, V pool, mi, K scales, V scales, tables)
+        "mi": (q, k, v, True, None, None, toy),
         "scales": (q, k.astype(jnp.int8), v.astype(jnp.int8), False, scales,
-                   scales),
-        "folded": (q, folded, folded, False, None, None),
+                   scales, toy),
+        # three heads of 64: 192 lanes, a tile and a half
+        "folded_into_part_of_a_tile": folded(k, 3, 64) + (False, None, None,
+                                                          wide),
+        # four heads of 96 fill three lane tiles, and two of them straddle
+        "folded_heads_across_tiles": folded(k, 4, 96) + (False, None, None,
+                                                         wide),
+        "folded_bfloat16_pools": folded(k.astype(jnp.bfloat16), 8, 64)
+        + (False, None, None, wide),
+        "folded_scales": folded(k.astype(jnp.int8), 8, 64) + (False, scales,
+                                                              scales, wide),
+        "folded_under_a_short_table": folded(k, 8, 64) + (False, None, None,
+                                                          wide[:, :-1]),
         "heads_of_part_of_a_tile": (q[:, :2], two_heads, two_heads, False,
-                                    None, None),
+                                    None, None, toy),
         "bfloat16_pools": (q, k.astype(jnp.bfloat16), v.astype(jnp.bfloat16),
-                           False, None, None),
+                           False, None, None, toy),
         "heads_of_two_lane_tiles": (
             jnp.tile(q, 2), jnp.tile(k, 2), jnp.tile(v, 2), False, None,
-            None),
+            None, toy),
     }
-    assert not eligible(q, k, v, False, None, None)         # the CPU
+    accepted = {
+        # name: (q, K pool, V pool, tables, the kernel's name: the toy
+        #        table's five pages are under a block, the wide one's 512
+        #        are four blocks of 128 pages of 4 rows)
+        "heads": (q, k, v, toy, "paged_decode_attention_p5"),
+        "bfloat16_query": (q.astype(jnp.bfloat16), k, v, toy,
+                           "paged_decode_attention_p5"),
+        "folded_heads_of_64": folded(k, 8, 64) + (
+            wide, "paged_decode_attention_f64_p128"),
+        "folded_heads_of_32": folded(k, 8, 32) + (
+            wide, "paged_decode_attention_f32_p128"),
+    }
+    for q_, k_, v_, tables, _ in accepted.values():        # the CPU
+        assert not eligible(q_, k_, v_, False, None, None,
+                            tables.shape[1] * PAGE)
     texts = {name: _loop_text(q_, k_, v_, ks, vs, tables, lengths, mi)
-             for name, (q_, k_, v_, mi, ks, vs) in refused.items()}
-    texts["the_cpu"] = _loop_text(q, k, v, None, None, tables, lengths,
-                                  False)
+             for name, (q_, k_, v_, mi, ks, vs, tables) in refused.items()}
+    texts["the_cpu"] = _loop_text(q, k, v, None, None, toy, lengths, False)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert eligible(q, k, v, False, None, None)
-    assert eligible(q.astype(jnp.bfloat16), k, v, False, None, None)
-    for name, (q_, k_, v_, mi, ks, vs) in refused.items():
-        assert not eligible(q_, k_, v_, mi, ks, vs), name
+    for name, (q_, k_, v_, mi, ks, vs, tables) in refused.items():
+        assert not eligible(q_, k_, v_, mi, ks, vs,
+                            tables.shape[1] * PAGE), name
         text = _loop_text(q_, k_, v_, ks, vs, tables, lengths, mi)
         assert text == texts[name] and "while" in text, name
         assert "tpu_custom_call" not in text, name
-    # the eligible call: traced only, this backend cannot lower the kernel
-    with serve_model.trace_notes() as notes:
-        traced = jax.make_jaxpr(lambda *args: _paged(*args, False))(
-            q, k, v, None, None, tables, lengths)
-    assert notes == {"paged_kernel_layers": 1}
-    name = paged_attention.kernel_name(
-        paged_attention.pages_per_block(PAGE, MAX_PAGES))
-    assert name == "paged_decode_attention_p5" and name in str(traced)
-    # the kernel in a jitted body of its own, and no loop beside it
-    steps = [eqn.primitive.name for eqn in traced.jaxpr.eqns]
-    assert str(traced).count("pallas_call") == 1 and "while" not in steps
+    # the eligible calls: traced only, this backend cannot lower the kernel
+    for name, (q_, k_, v_, tables, kernel) in accepted.items():
+        assert eligible(q_, k_, v_, False, None, None,
+                        tables.shape[1] * PAGE), name
+        with serve_model.trace_notes() as notes:
+            traced = jax.make_jaxpr(lambda *args: _paged(*args, False))(
+                q_, k_, v_, None, None, tables, lengths)
+        assert notes == {"paged_kernel_layers": 1}, name
+        assert kernel == paged_attention.kernel_name(
+            paged_attention.pages_per_block(PAGE, tables.shape[1],
+                                            k_.ndim == 4),
+            q_.shape[-1] if k_.ndim == 4 else 0)
+        assert "name=%s\n" % kernel in str(traced), name
+        # the kernel in a jitted body of its own, and no loop beside it
+        steps = [eqn.primitive.name for eqn in traced.jaxpr.eqns]
+        assert str(traced).count("pallas_call") == 1, name
+        assert "while" not in steps, name
 
 
 def test_a_steps_layers_share_one_trace_of_the_kernel(monkeypatch):
@@ -627,6 +707,38 @@ def test_decode_report_counts_for_the_reader_that_was_traced(
     assert type(rep["blocks_visited"]) is (float if kernel_layers else int)
     assert rep["visited_share"] == rep["blocks_visited"] / rep[
         "blocks_capacity"]
+
+
+@pytest.mark.parametrize("head, width, layers_by_kernel", [
+    (16, 256, 0), (64, 255, 0), (64, 256, 2)],
+    ids=["quarter_tile", "short_table", "whole_tile_wide_table"])
+def test_a_folded_cache_notes_the_kernel_where_the_call_is_eligible(
+        monkeypatch, head, width, layers_by_kernel):
+    """What switches the counts above, read off a real trace: the dense
+    decode step over a cache that folds two heads of 64 into one lane tile
+    notes the kernel once a layer when a TPU traces it under a table of
+    2 048 keys (256 pages of 8: the folded reader); under a table a page
+    narrower, and over this file's toy cache (two heads of 16: 32 lanes,
+    a quarter of a tile), it keeps the loop and notes nothing."""
+    cfg = serve.ModelConfig(vocab_size=61, num_layers=2, d_model=2 * head,
+                            num_heads=2, max_len=64)
+    params = jax.eval_shape(lambda: serve_model.init_params(cfg, seed=3))
+    cache = kv_cache.PagedKVCache(2, 2, head, SPAGE, 9, SLOTS, 3)
+    assert cache.kv_lanes == 2 * head and cache.pools["k_pool"].ndim == 4
+    ints = jnp.zeros((SLOTS,), jnp.int32)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with serve_model.trace_notes() as notes:
+        traced = jax.make_jaxpr(
+            lambda params, pools: serve_model.decode_step(
+                params, ints, ints, jnp.zeros((SLOTS, width), jnp.int32),
+                pools, {}, cfg, SPAGE, exact=False))(params, cache.pools)
+    assert notes.get("paged_kernel_layers", 0) == layers_by_kernel
+    name = paged_attention.kernel_name(64, 64)      # 512 keys a block
+    assert name == "paged_decode_attention_f64_p64"
+    # one jitted body for both layers, so the name prints once
+    assert str(traced).count("name=%s\n" % name) == bool(layers_by_kernel)
+    steps = [eqn.primitive.name for eqn in traced.jaxpr.eqns]
+    assert steps.count("while") == 2 - layers_by_kernel
 
 
 def test_decode_report_of_a_fresh_session_is_zero():

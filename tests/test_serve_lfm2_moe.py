@@ -685,6 +685,10 @@ def test_scheduler_serves_and_the_block_counts(params):
             rep["state_bytes_per_slot"], rep["kv_lanes"],
             rep["expert_kernel_layers"]) \
         == (3, 1, 0, 3, 2, 3 * 2 * 64 * 4, 32, 0)
+    # the CPU traced it: the loop.  (A TPU's trace takes the kernel's
+    # folded form where the pools' last axis is whole lane tiles, 3 layers
+    # at the published widths: tests/test_tpu_compile.py; this toy's two
+    # heads of 16 are a quarter of a tile and keep the loop there too.)
     assert sess.decode_report()["paged_kernel_layers"] == 0
     assert sess.fallback_count() == 0 and len(sess.executables) == 3
 

@@ -15,9 +15,11 @@ temporaries, the state updated in place); and the routed-expert layer's
 grouped-matmul kernel over kanana-2's and Ling-3.0-flash's stacks, a
 whole float32 expert a block; and a grouped-query attention layer's
 append to and read of the paged K/V pools at granite-4.0-h-micro's pool
-size (the loop over folded pools) and at the dense cell's and
-Laguna-S-2.1's (the paged-attention kernel over pools that keep their
-heads' axis), which must leave the pools where they lie; and the dense
+size (the loop over folded pools under a short table), at LFM2-24B-A2B's
+(the paged-attention kernel's form for folded pools) and at the dense
+cell's and Laguna-S-2.1's (its form for pools that keep their heads'
+axis, the Mosaic module letter for letter what it was before there were
+two), which must leave the pools where they lie; and the dense
 block's whole decode step at Cerebras-GPT-1.3B's widths with the kernel
 in; and a latent-attention
 layer's append to and read of the latent pool at kanana-2's and
@@ -306,32 +308,37 @@ def test_grouped_swiglu_compiles_for_v5e(one_chip, name, monkeypatch):
 
 # An attention layer's decode half at granite-4.0-h-micro's pool size (4
 # attention layers, 16 slots x 48 pages + the trash page, pages of 16, 8
-# key/value heads of 64, 32 query heads), at the dense cell's heads of 128
-# over as many pages, and at Laguna-S-2.1's two full layers (16 slots x 832
-# pages + 1, 8 key/value heads of 128, 48 query heads): 16 rows appended
-# to the donated K and V pools, then the paged read, with the TPU's
-# branches taken: the ``fori_loop`` over granite's folded pools, the
-# paged-attention kernel (``ops/paged_attention.py``) over the two that
-# keep their heads' axis.  What is compiled is what the blocks'
-# ``decode_step`` runs a layer, without its weights.
+# key/value heads of 64, 32 query heads), at LFM2-24B-A2B's (3 attention
+# layers, 64 slots x 576 pages + 1, the same heads), at the dense cell's
+# heads of 128 over as many pages as granite's, and at Laguna-S-2.1's two
+# full layers (16 slots x 832 pages + 1, 8 key/value heads of 128, 48 query
+# heads): a row a slot appended to the donated K and V pools, then the
+# paged read, with the TPU's branches taken: the ``fori_loop`` over
+# granite's folded pools (a table of 768 keys: too short for the kernel's
+# folded form to be worth its start-up), the paged-attention kernel
+# (``ops/paged_attention.py``) over the other three, in its form for the
+# pool's layout.  What is compiled is what the blocks' ``decode_step``
+# runs a layer, without its weights.
 KV_CASES = {
     # name: (key/value heads, head width, query heads a key/value head,
-    #        layers, pages a slot, the kernel's pages a block or 0: loop)
-    "granite_heads_of_64": (8, 64, 4, 4, 48, 0),
-    "dense_heads_of_128": (16, 128, 1, 4, 48, 8),
-    "laguna_heads_of_128": (8, 128, 6, 2, 832, 8),
+    #        layers, pages a slot, the kernel's pages a block or 0: loop,
+    #        slots)
+    "granite_heads_of_64": (8, 64, 4, 4, 48, 0, 16),
+    "lfm2_heads_of_64": (8, 64, 4, 3, 576, 32, 64),
+    "dense_heads_of_128": (16, 128, 1, 4, 48, 8, 16),
+    "laguna_heads_of_128": (8, 128, 6, 2, 832, 8, 16),
 }
 
 
 def _kv_layer_program(one_chip, monkeypatch, pool_shape, heads, head_dim,
-                      group):
+                      group, slots=16):
     """-> the compiled append + paged read over two donated float32 pools
     of ``pool_shape`` as a TPU traces them, and one pool's logical
     bytes."""
     from mxnet_tpu.ops.attention import paged_decode_attention
     from mxnet_tpu.serve.kv_cache import append_rows
 
-    slots, page = 16, 16
+    page = 16
     max_pages = (pool_shape[1] - 1) // slots
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
@@ -399,44 +406,124 @@ def test_kv_append_and_paged_read_leave_the_pools_where_they_lie(
     aliases them and no operation of the compiled text copies a whole
     pool into another layout.  With nothing else in memory the compiler
     may still park one 100.8 MB pool, as it lies, in the 128 MiB of fast
-    memory for the read loop (the folded V pool here: a ``copy-start`` /
-    ``copy-done`` into ``S(1)``, 101.6 MB of temporaries); the cell's
-    executables, with 12.8 GB of weights to stream, have no such move
-    (read in their text at PR 38: PERF.md), so one is allowed and no
-    more.  **Which reader**: pools that keep their heads' axis are read by
-    the paged-attention kernel, which carries its block in its name, takes
-    the pools whole (no slice of a layer in front of it, no temporaries)
-    and leaves no loop; Mosaic takes its double buffer within the
-    ``vmem_limit_bytes`` it states (the compile is the check), well under
-    the chip's 128 MiB.  Folded pools keep the loop."""
+    memory for the read loop (granite's folded V pool here: a
+    ``copy-start`` / ``copy-done`` into ``S(1)``, 101.6 MB of
+    temporaries); the cell's executables, with 12.8 GB of weights to
+    stream, have no such move (read in their text at PR 38: PERF.md), so
+    one is allowed and no more.  **Which reader**: the paged-attention
+    kernel wherever the call is eligible, named for the layout and for
+    its block (``paged_decode_attention_p8`` over pools that keep their
+    heads' axis, ``paged_decode_attention_f64_p32`` over LFM2's heads of
+    64 folded into 512 lanes), which takes the pools whole (no slice of a
+    layer in front of it, no temporaries) and leaves no loop; Mosaic takes
+    its double buffer within the ``vmem_limit_bytes`` it states (the
+    compile is the check), well under the chip's 128 MiB.  Granite's
+    folded pools under a table of 768 keys keep the loop."""
     from mxnet_tpu.ops import paged_attention
     from mxnet_tpu.serve.kv_cache import kv_pool_shape
 
-    heads, head_dim, group, layers, max_pages, pages = KV_CASES[name]
-    shape = kv_pool_shape(layers, 16 * max_pages + 1, 16, heads, head_dim)
+    heads, head_dim, group, layers, max_pages, pages, slots = KV_CASES[name]
+    shape = kv_pool_shape(layers, slots * max_pages + 1, 16, heads, head_dim)
     compiled, logical = _kv_layer_program(one_chip, monkeypatch, shape,
-                                          heads, head_dim, group)
+                                          heads, head_dim, group, slots)
     memory = compiled.memory_analysis()
-    small = 1 << 20     # q, k, v, tables, lengths and their padding
+    # q, k, v, tables, lengths and their padding: 1 MB at 16 slots
+    small = slots << 16
     assert 2 * logical <= memory.argument_size_in_bytes < 2 * logical + small
     assert memory.alias_size_in_bytes >= 2 * logical
     text = compiled.as_text()
     copies, prefetches = _whole_pool_copies(text, shape)
     assert not copies, copies
     loops = len(re.findall(r" while\(", text))
+    folded = len(shape) == 4
+    assert folded == (head_dim < 128)
     if not pages:
-        assert len(shape) == 4 and loops == 1
+        assert folded and loops == 1
+        assert 16 * max_pages < paged_attention._FOLDED_MIN_TABLE_KEYS
         assert "tpu_custom_call" not in text
         assert len(prefetches) <= 1, prefetches
         assert memory.temp_size_in_bytes < len(prefetches) * logical + small
         return
-    assert paged_attention.pages_per_block(16, max_pages) == pages
-    assert len(_kernel_calls(text, paged_attention.kernel_name(pages))) == 1
+    assert paged_attention.pages_per_block(16, max_pages, folded) == pages
+    kernel = paged_attention.kernel_name(pages, head_dim if folded else 0)
+    assert kernel == ("paged_decode_attention_f64_p32" if folded
+                      else "paged_decode_attention_p8")
+    assert len(_kernel_calls(text, kernel)) == 1
+    assert text.count("tpu_custom_call") == 1
     assert not loops and not prefetches
     assert not _layer_slices(text, shape), _layer_slices(text, shape)
     assert memory.temp_size_in_bytes < small
+    # a folded pool's kernel works on lane tiles: 4 of 128, a tile's two
+    # heads' query rows one under the other
+    per = 128 // head_dim
     assert paged_attention._vmem_bytes(
-        pages, 16, heads, 8, head_dim) < 16 << 20
+        pages, 16, heads // per, -(-per * group // 8) * 8, 128) < 16 << 20
+
+
+# sha256 of the Mosaic module, printed without source locations, that the
+# kernel lowered to at PR 47's commit over the dense cell's pools and over
+# laguna's: before there was a form for folded pools
+UNFOLDED_MODULES = {
+    "dense_heads_of_128": (
+        (24, 16 * 48 + 1, 16, 16, 128), (16, 16, 1, 128),
+        "d4411f054160d4d96f4685e03bdeda1591a3bf36349e7fe811ab845f9fd9fdff"),
+    "laguna_heads_of_128": (
+        (2, 16 * 832 + 1, 16, 8, 128), (16, 8, 6, 128),
+        "2e47e7837f8d930adb28940c3f98e901f52633ca1dd0fd83adcbe6ecdd0df423"),
+}
+
+
+def _mosaic_modules(lowered_text):
+    """The Mosaic modules a lowered text carries (a ``tpu_custom_call``'s
+    ``body``, serialized), each printed without its source locations: the
+    payload itself names this repo's files and lines, which a docstring's
+    edit moves."""
+    import base64
+
+    from jax._src.interpreters import mlir as jax_mlir
+    from jax._src.lib.mlir import ir
+
+    found = []
+    for body in re.findall(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22',
+                           lowered_text):
+        context = jax_mlir.make_ir_context()
+        context.allow_unregistered_dialects = True   # ``stable_mosaic``
+        with context:
+            module = ir.Module.parse(base64.b64decode(body))
+            found.append(module.operation.get_asm(enable_debug_info=False))
+    return found
+
+
+@pytest.mark.parametrize("name", sorted(UNFOLDED_MODULES))
+def test_the_kernel_over_pools_that_keep_their_heads_axis_is_what_it_was(
+        one_chip, name):
+    """The form for folded pools shares the kernel's body with the form
+    ``cgpt1.3b-chat`` and ``laguna-s2.1-l5-code`` run, and must not have
+    moved it: at their shapes the body lowers to the Mosaic module it
+    lowered to before (``paged_decode_attention_p8``, 8 pages a block),
+    letter for letter once the source locations are left out.  A change
+    that means to alter that kernel states the new digest here, and
+    measures both cells."""
+    import hashlib
+
+    from mxnet_tpu.ops import paged_attention
+
+    pool, q, digest = UNFOLDED_MODULES[name]
+    slots, table = q[0], (pool[1] - 1) // q[0]
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    with jax.default_matmul_precision("default"):    # as the cells trace it
+        text = paged_attention._paged_attention.lower(
+            sds(q), sds(pool), sds(pool), sds((), jnp.int32),
+            sds((slots, table), jnp.int32), sds((slots,), jnp.int32),
+            page_size=16, scale=q[-1] ** -0.5,
+            pages=paged_attention.pages_per_block(16, table, False),
+            full_precision=False).as_text()
+    (module,) = _mosaic_modules(text)
+    assert "module @paged_decode_attention_p8 " in module
+    assert hashlib.sha256(module.encode()).hexdigest() == digest
 
 
 def test_unfolded_heads_of_64_cost_the_whole_pool(one_chip, monkeypatch):
@@ -463,8 +550,8 @@ def test_a_layer_sliced_in_front_of_the_kernel_is_found(one_chip,
     from mxnet_tpu.ops import attention
     from mxnet_tpu.serve.kv_cache import kv_pool_shape
 
-    heads, head_dim, group, layers, max_pages, _ = KV_CASES[
-        "dense_heads_of_128"]
+    heads, head_dim, group, layers, max_pages = KV_CASES[
+        "dense_heads_of_128"][:5]
     shape = kv_pool_shape(layers, 16 * max_pages + 1, 16, heads, head_dim)
     whole = attention.paged_attention
 
@@ -739,7 +826,7 @@ def _lfm2_program(one_chip, monkeypatch, bucket):
 
 
 @pytest.mark.parametrize("bucket, tile, temporaries", [
-    (0, 8, 256 << 20), (2048, 128, 3 << 29)],
+    (0, 8, 64 << 20), (2048, 128, 3 << 29)],
     ids=["decode", "prefill-2048"])
 def test_lfm2_executables_compile_for_v5e_at_the_published_widths(
         one_chip, monkeypatch, bucket, tile, temporaries):
@@ -748,14 +835,15 @@ def test_lfm2_executables_compile_for_v5e_at_the_published_widths(
     whole float32 expert of 1536 x 2048 a block in all twelve expert
     layers, and the donated pools and the state are updated where they
     lie: the result aliases all three, and no operation copies a whole
-    K/V pool into another layout.  The folded pools keep
-    the loop: one a layer under ``gqa_decode``, no paged-attention
-    kernel."""
+    K/V pool into another layout.  The decode step's three attention
+    layers read their folded pools through the paged-attention kernel's
+    folded form (one lowering, three calls): no loop under
+    ``gqa_decode``, no slice of a pool's layer."""
     from mxnet_tpu.ops.grouped_matmul import kernel_name
 
     compiled, shapes, notes = _lfm2_program(one_chip, monkeypatch, bucket)
-    assert notes.get("expert_kernel_layers") == 12
-    assert not notes.get("paged_kernel_layers")
+    assert notes == dict({"expert_kernel_layers": 12},
+                         **({} if bucket else {"paged_kernel_layers": 3}))
     text = compiled.as_text()
     assert len(_kernel_calls(text, kernel_name(tile))) == 12
     memory = compiled.memory_analysis()
@@ -772,22 +860,23 @@ def test_lfm2_executables_compile_for_v5e_at_the_published_widths(
     copies, _ = _whole_pool_copies(text, shapes["conv_state"])
     assert len(copies) <= (0 if bucket else 3), copies
     if not bucket:
-        from mxnet_tpu.ops import paged_attention
-
-        assert not _kernel_calls(text, paged_attention.kernel_name(8))
-        assert len(re.findall(r" while\([^\n]*op_name=\"[^\"]*gqa_decode",
-                              text)) == 3
+        _decode_reads_by_kernel(text, shapes["k_pool"], "gqa_decode", 3,
+                                folded_head=64)
 
 
-def _decode_reads_by_kernel(text, pool_shape, scope, layers):
+def _decode_reads_by_kernel(text, pool_shape, scope, layers, folded_head=0):
     """A decode executable's compiled text holds ``layers``
-    paged-attention kernels of 8 pages a block, no ``while`` that the
-    trace put under ``scope`` (the attention's; the expert layers'
-    ``searchsorted`` loops are elsewhere) and no slice of a pool's
-    layer."""
+    paged-attention kernels in the form for its pools' layout (heads on
+    their own axis, 8 pages a block; or heads of ``folded_head`` folded,
+    32) and none in the other, no ``while`` that the trace put under
+    ``scope`` (the attention's; the expert layers' ``searchsorted`` loops
+    are elsewhere) and no slice of a pool's layer."""
     from mxnet_tpu.ops import paged_attention
 
-    assert len(_kernel_calls(text, paged_attention.kernel_name(8))) == layers
+    name = paged_attention.kernel_name(32 if folded_head else 8, folded_head)
+    assert len(_kernel_calls(text, name)) == layers
+    assert len(re.findall(r"%paged_decode_attention_\w+[.\d]* = ", text)) \
+        == layers
     assert not re.findall(r" while\([^\n]*op_name=\"[^\"]*%s[^\"]*\""
                           % scope, text)
     assert not _layer_slices(text, pool_shape), _layer_slices(text,
