@@ -498,8 +498,8 @@ def paged_decode_attention(q, k_pool, v_pool, layer, tables, lengths,
                            scale=None):
     """The decode step's attention, reading KV pages where they lie.
 
-    :func:`decode_attention` over ``pool[layer][tables]`` without the
-    ``(S, Tcap, H, D)`` copy of every slot's whole page table, and without
+    :func:`decode_attention` over ``kv_cache.read_context``'s gather without
+    that ``(S, Tcap, H, D)`` copy of every slot's whole page table, and without
     the blocks past the longest live context: each iteration gathers the
     next few pages of every slot from the pool and merges them, page by
     page in ascending order, with the same :func:`attend_block` and the

@@ -31,8 +31,13 @@ tables, the trash page, copy-on-write and the scale pools of
 ``kv_quant`` do not know the difference.  The step functions never
 index the trailing axes: they write rows of (..., H, D) through
 :func:`append_rows` and read pages back as (..., H, D) through
-:func:`read_pages` / :func:`read_context`, which reshape what they have
-*gathered* (a few pages a slot), never the pool.  One reader takes
+:func:`read_pages` / :func:`read_context`, which gather from the pool
+where it lies (``pool[layer, pages]``, one gather over both leading axes:
+indexing the layer first and the pages second materialises the layer, a
+copy of it written in front of every prefill's gather, 1.2 GB read and
+written again for 19 MB of pages at LFM2's pools: PERF.md, PR 49) and
+reshape what they have *gathered* (a few pages a slot, or a slot's
+table), never the pool.  One reader takes
 folded pages in place, without gathering them: the decode step's
 paged-attention kernel (``ops/paged_attention.py``), which copies a slot's
 live pages as they lie, ``(page_size, H * D)``, and works on whole lane
@@ -67,8 +72,9 @@ axis: they write rows of ``latent_dim`` values through
 zero wherever a row was ever written, and copy-on-write copies them as
 they are) and read a slot's or every slot's table through
 :func:`read_latent_context`, which gathers the table's pages from the
-pool where it lies (``pool[layer, tables]``: a ``pool[layer][tables]``
-first materialises the layer, 85 MB read and written a layer a call)
+pool where it lies (``pool[layer, tables]``: the layer indexed first and
+the tables second materialises the layer, 85 MB read and written a layer
+a call)
 and hands the rows back as wide as the pool keeps them.  The absorbed
 attention gives its query as many zero lanes, which add exactly 0 to a
 score; the materialised one slices what it *gathered*.
@@ -248,11 +254,12 @@ def read_pages(pool, layer, pages, head_dim):
 
 
 def read_context(pool, layer, tables, head_dim):
-    """A slot's whole page table as one context for
-    ``ops.attention.decode_attention``: ``tables`` (max_pages,) or
+    """A slot's whole page table, or every slot's, of layer ``layer`` of
+    a K or V pool as one context for ``ops.attention.decode_attention``,
+    gathered from the pool where it lies: ``tables`` (max_pages,) or
     (S, max_pages) -> (1 or S, H, max_pages * page_size, D)."""
     n = 1 if tables.ndim == 1 else tables.shape[0]
-    return pool[layer][tables].reshape(
+    return pool[layer, tables].reshape(
         n, tables.shape[-1] * pool.shape[2], -1, head_dim
     ).transpose(0, 2, 1, 3)
 

@@ -742,9 +742,9 @@ def prefill_forward(params, tokens, length, offset, table_row, pools,
             ctx_v = read_context(pools["v_pool"], fi, table_row, d)
             ks = vs = None
             if kv_quant:
-                ks = pools["k_scale"][fi][table_row].reshape(
+                ks = pools["k_scale"][fi, table_row].reshape(
                     1, max_pages * page_size)
-                vs = pools["v_scale"][fi][table_row].reshape(
+                vs = pools["v_scale"][fi, table_row].reshape(
                     1, max_pages * page_size)
             att = decode_attention(
                 q.reshape(1, t_b, h, d).transpose(0, 2, 1, 3),
@@ -948,9 +948,9 @@ def verify_step(params, tokens, lengths, tables, pools, counters, cfg,
             ctx_v = read_context(pools["v_pool"], fi, tables, d)
             ks = vs = kp = None
             if kv_quant:
-                ks = pools["k_scale"][fi][tables].reshape(
+                ks = pools["k_scale"][fi, tables].reshape(
                     s, max_pages * page_size)
-                vs = pools["v_scale"][fi][tables].reshape(
+                vs = pools["v_scale"][fi, tables].reshape(
                     s, max_pages * page_size)
             win = 0
             fi += 1

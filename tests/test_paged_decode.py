@@ -562,6 +562,30 @@ def test_append_and_read_over_a_folded_pool_equal_the_heads_layout(
                 new_k.shape), np.asarray(new_k))
 
 
+@pytest.mark.parametrize("rank", [1, 2], ids=["one_slot", "every_slot"])
+@pytest.mark.parametrize("layout", ["heads", "folded"])
+def test_read_context_gathers_what_the_layers_slice_held(layout, rank):
+    """``read_context`` gathers a table's pages from the pool where it
+    lies: over both layouts ``kv_pool_shape`` gives, for one slot's table
+    and for every slot's, bit for bit the rows that a slice of the layer
+    gathered by the table holds, laid out (slots, heads, rows, head)."""
+    rs = np.random.RandomState(7)
+    _, k, _, _, _ = _pools(rs, layout=layout)
+    assert k.ndim == (4 if layout == "folded" else 5)
+    tables = _tables(rs, LENGTHS["mid_page"])
+    if rank == 1:
+        tables = tables[2]
+    n = 1 if rank == 1 else S
+    want = np.asarray(k)[LAYER][np.asarray(tables)].reshape(
+        n, CAP, H, D).transpose(0, 2, 1, 3)
+    got = kv_cache.read_context(k, LAYER, tables, D)
+    assert got.shape == (n, H, CAP, D)
+    np.testing.assert_array_equal(np.asarray(got), want)
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(kv_cache.read_context, static_argnums=3)(
+            k, jnp.int32(LAYER), tables, D)), want)
+
+
 # ---------------------------------------------------------------------------
 # the decode program and the session's counter
 # ---------------------------------------------------------------------------

@@ -19,14 +19,17 @@ size (the loop over folded pools under a short table), at LFM2-24B-A2B's
 (the paged-attention kernel's form for folded pools) and at the dense
 cell's and Laguna-S-2.1's (its form for pools that keep their heads'
 axis, the Mosaic module letter for letter what it was before there were
-two), which must leave the pools where they lie; and the dense
+two), which must leave the pools where they lie; and such a layer's
+prefill half at the four cells' pools and both buckets of each (the
+chunk's rows appended, ``read_context``'s gather of the slot's table, the
+bounded scan), which must hold no copy of a pool's layer; and the dense
 block's whole decode step at Cerebras-GPT-1.3B's widths with the kernel
 in; and a latent-attention
 layer's append to and read of the latent pool at kanana-2's and
 Ling-3.0-flash's pool sizes, likewise; and the window / full
-grouped-query block's whole decode step and its 2048-row prefill chunk at
+grouped-query block's whole decode step and both its prefill chunks at
 Laguna-S-2.1's published widths and the cell's cache, pages and rings
-updated in place.  Nothing runs, so
+updated in place, and LFM2-24B-A2B's three executables likewise.  Nothing runs, so
 nothing here is a result or a time — a compile that passes is not a
 chip run.
 
@@ -386,11 +389,20 @@ def _whole_pool_copies(text, pool_shape):
 
 
 def _layer_slices(text, pool_shape):
-    """The lines of a compiled text whose result is one layer of a pool
-    (what ``pool[layer]`` in front of a custom call materialises)."""
-    layer = ",".join(str(n) for n in pool_shape[1:])
-    return [line.strip()[:140] for line in text.splitlines()
-            if re.search(r"= f32\[(1,)?%s\]" % layer, line)]
+    """The lines of a compiled text whose result holds as many values as
+    one layer of a pool, in whatever type and shape (what ``pool[layer]``
+    in front of a custom call or of a gather materialises: the compiler
+    writes the slice of a float32 pool as ``bf16[...]`` where a matmul will
+    round the gathered rows, and layer 0's, which starts at the pool's
+    first byte, as a plain ``slice`` of the pool laid out as rows,
+    ``f32[589840,512]`` of ``f32[1769520,512]`` at LFM2's pools)."""
+    size = math.prod(pool_shape[1:])
+    found = []
+    for line in text.splitlines():
+        m = re.search(r"= [a-z]\w*\[([\d,]+)\]", line)
+        if m and math.prod(int(n) for n in m.group(1).split(",")) == size:
+            found.append(line.strip()[:140])
+    return found
 
 
 def _kernel_calls(text, name):
@@ -826,8 +838,8 @@ def _lfm2_program(one_chip, monkeypatch, bucket):
 
 
 @pytest.mark.parametrize("bucket, tile, temporaries", [
-    (0, 8, 64 << 20), (2048, 128, 3 << 29)],
-    ids=["decode", "prefill-2048"])
+    (0, 8, 64 << 20), (512, 32, 3 << 25), (2048, 128, 3 << 27)],
+    ids=["decode", "prefill-512", "prefill-2048"])
 def test_lfm2_executables_compile_for_v5e_at_the_published_widths(
         one_chip, monkeypatch, bucket, tile, temporaries):
     """The whole step fits the chip beside its arguments (12.04 GB: 4.78
@@ -838,7 +850,11 @@ def test_lfm2_executables_compile_for_v5e_at_the_published_widths(
     K/V pool into another layout.  The decode step's three attention
     layers read their folded pools through the paged-attention kernel's
     folded form (one lowering, three calls): no loop under
-    ``gqa_decode``, no slice of a pool's layer."""
+    ``gqa_decode``, no slice of a pool's layer.  Nor does a prefill chunk
+    hold one in front of ``read_context``'s gathers (until PR 49 six
+    float32 copies of a 1.21 GB layer a chunk, layer 0's two at bucket
+    2048 as plain slices of the pool laid out as rows, and 1.27-1.32 GB of
+    temporaries where there are now 0.06 and 0.25)."""
     from mxnet_tpu.ops.grouped_matmul import kernel_name
 
     compiled, shapes, notes = _lfm2_program(one_chip, monkeypatch, bucket)
@@ -862,6 +878,8 @@ def test_lfm2_executables_compile_for_v5e_at_the_published_widths(
     if not bucket:
         _decode_reads_by_kernel(text, shapes["k_pool"], "gqa_decode", 3,
                                 folded_head=64)
+    else:
+        assert not _layer_slices(text, shapes["k_pool"])
 
 
 def _decode_reads_by_kernel(text, pool_shape, scope, layers, folded_head=0):
@@ -942,8 +960,8 @@ def _loop_conditions(text, scope):
 
 
 @pytest.mark.parametrize("bucket, tile, temporaries", [
-    (0, 8, 64 << 20), (2048, 128, 3 << 29)],
-    ids=["decode", "prefill-2048"])
+    (0, 8, 64 << 20), (512, 32, 7 << 25), (2048, 128, 25 << 25)],
+    ids=["decode", "prefill-512", "prefill-2048"])
 def test_laguna_executables_compile_for_v5e_at_the_published_widths(
         one_chip, monkeypatch, bucket, tile, temporaries):
     """The whole step fits the chip beside its arguments (10.56 GB: 6.87
@@ -951,7 +969,9 @@ def test_laguna_executables_compile_for_v5e_at_the_published_widths(
     float32 expert of 1024 x 3072 a block in all four expert layers, and
     the donated pools and rings are updated where they lie: the result
     aliases all four, and no operation copies a whole pool or a whole
-    ring into another layout."""
+    ring into another layout, nor (since PR 49) one layer of a pool in
+    front of a prefill chunk's gathers: the temporaries stay under one
+    (0.87 GB)."""
     from mxnet_tpu.ops.grouped_matmul import kernel_name
 
     compiled, shapes, notes = _laguna_program(one_chip, monkeypatch, bucket)
@@ -973,6 +993,7 @@ def test_laguna_executables_compile_for_v5e_at_the_published_widths(
         # the whole pools: no loop under their scope, no layer sliced out
         _decode_reads_by_kernel(text, shapes["k_pool"], "gqa_decode", 2)
     if bucket:
+        assert not _layer_slices(text, shapes["k_pool"])
         # one loop a full layer over the gathered table's 26 blocks of 512
         # keys, and its trip count is data (the chunk's furthest horizon):
         # the condition compares the counter with an element of the carry,
@@ -987,3 +1008,116 @@ def test_laguna_executables_compile_for_v5e_at_the_published_widths(
         assert LAGUNA_TABLE * 16 // 512 == 26
         assert "f32[26,1,8,512,128]" not in text
         assert "f32[1,8,13312,128]" in text
+
+
+# A grouped-query (or dense) attention layer's prefill half at the four
+# cells' pool shapes and both buckets of each: the chunk's rows appended to
+# the donated K and V pools at the slot's pages, ``kv_cache.read_context``
+# of the slot's whole table, ``decode_attention`` over it in the block's
+# key blocks with a horizon a row.  What is compiled is what the blocks'
+# ``prefill_forward`` runs a layer under ``gqa_prefill`` (``serve/model.py``
+# with one query head a key/value head), without its weights, at a layer
+# past the first (layer 0 starts at the pool's first byte: in a program
+# this small its slice is a bitcast, whatever the reader).
+PREFILL_CASES = {
+    # name: (key/value heads, head width, query heads a key/value head,
+    #        layers, pages a slot, slots, the cell's buckets)
+    "dense": (16, 128, 1, 24, 48, 16, (128, 512)),
+    "granite": (8, 64, 4, 4, 48, 16, (128, 512)),
+    "laguna": (8, 128, 6, 2, LAGUNA_TABLE, LAGUNA_SLOTS, (512, 2048)),
+    "lfm2": (8, 64, 4, 3, LFM2_TABLE, LFM2_SLOTS, (512, 2048)),
+}
+
+
+def _layer_first(pool, layer, tables, head_dim):
+    """``kv_cache.read_context`` as it was until PR 49: the layer indexed
+    first, the table second."""
+    return pool[layer][tables].reshape(
+        1, tables.shape[-1] * pool.shape[2], -1, head_dim
+    ).transpose(0, 2, 1, 3)
+
+
+def _prefill_layer_program(one_chip, name, bucket, read=None):
+    """-> the compiled append + whole-table read + scan of one prefill
+    chunk of ``bucket`` rows over two donated float32 pools at the cell's
+    shape, through ``read`` (the cache's own reader unless given), and the
+    pools' shape."""
+    from mxnet_tpu.ops.attention import decode_attention
+    from mxnet_tpu.serve import kv_cache, latent_moe
+    from mxnet_tpu.serve import model as serve_model
+
+    heads, head_dim, group, layers, max_pages, slots, _ = PREFILL_CASES[name]
+    page, layer = 16, 1
+    read = read or kv_cache.read_context
+    block = (serve_model if name == "dense" else latent_moe).prefill_block(
+        max_pages, page, False)
+    shape = kv_cache.kv_pool_shape(layers, slots * max_pages + 1, page,
+                                   heads, head_dim)
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def chunk(pools, q, k, v, table_row, offset):
+        pools = dict(pools)
+        abs_pos = offset + jnp.arange(bucket, dtype=jnp.int32)
+        pages, offsets = table_row[abs_pos // page], abs_pos % page
+        kv_cache.append_rows(pools, "k", layer, pages, offsets, k)
+        kv_cache.append_rows(pools, "v", layer, pages, offsets, v)
+        ctx_k = read(pools["k_pool"], layer, table_row, head_dim)
+        ctx_v = read(pools["v_pool"], layer, table_row, head_dim)
+        att = decode_attention(
+            q.transpose(1, 0, 2, 3).reshape(1, heads, bucket * group,
+                                            head_dim),
+            ctx_k, ctx_v, jnp.repeat(abs_pos + 1, group)[None], block=block)
+        return pools, att
+
+    pool = sds(shape)
+    rows = sds((bucket, heads, head_dim))
+    with jax.default_matmul_precision("default"):    # as the cells trace it
+        compiled = jax.jit(chunk, donate_argnums=0).lower(
+            {"k_pool": pool, "v_pool": pool},
+            sds((bucket, heads, group, head_dim)), rows, rows,
+            sds((max_pages,), jnp.int32), sds((), jnp.int32)).compile()
+    return compiled, shape
+
+
+@pytest.mark.parametrize("large", [False, True], ids=["small", "large"])
+@pytest.mark.parametrize("name", sorted(PREFILL_CASES))
+def test_a_prefill_chunk_gathers_its_table_from_the_pool_where_it_lies(
+        one_chip, name, large):
+    """``read_context`` gathers the slot's pages from the pool itself
+    (``pool[layer, tables]``), so a prefill chunk's compiled text holds no
+    result the size of a pool's layer in any type, no whole-pool copy, and
+    less than a layer of temporaries (counted at the two bytes a value the
+    compiler gave the slice); the donated pools are updated where they
+    lie."""
+    bucket = PREFILL_CASES[name][-1][large]
+    compiled, shape = _prefill_layer_program(one_chip, name, bucket)
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    assert not _layer_slices(text, shape), _layer_slices(text, shape)
+    assert not _whole_pool_copies(text, shape)[0]
+    assert memory.alias_size_in_bytes >= 2 * 4 * math.prod(shape)
+    assert memory.temp_size_in_bytes < 2 * math.prod(shape[1:])
+
+
+@pytest.mark.parametrize("name", sorted(PREFILL_CASES))
+def test_a_layer_sliced_in_front_of_a_prefills_gather_is_found(one_chip,
+                                                               name):
+    """The control of the test above, and what every prefill paid until
+    PR 49: the same chunk with the layer indexed first and the table
+    second holds a copy of each pool's layer in front of its gather, which
+    the compiler writes in bfloat16 (the scan's matmuls round their
+    operands so).  A copy the chip's 128 MiB of fast memory cannot hold
+    (laguna's 436 MB, LFM2's 604 MB; the dense cell's and granite's 50 MB
+    go there, ``S(1)``, with nothing else in this program to stream) is
+    a copy's bytes of temporaries: K's is dead once its pages are
+    gathered."""
+    bucket = PREFILL_CASES[name][-1][1]
+    compiled, shape = _prefill_layer_program(one_chip, name, bucket,
+                                             read=_layer_first)
+    found = _layer_slices(compiled.as_text(), shape)
+    assert len(found) >= 2, found
+    assert any("= bf16[" in line for line in found), found
+    copy = 2 * math.prod(shape[1:])
+    if copy > 128 << 20:
+        assert compiled.memory_analysis().temp_size_in_bytes >= copy
