@@ -25,7 +25,13 @@ temporaries, four whole-pool copies a layer for K and V, 6-7 ms of a
 32 ms decode step for under 1 % of its bytes (PERF.md, PRs 30 and 38).
 So a head narrower than a lane tile is not given the lane axis to
 itself: the heads fold into it, (..., H * D), 512 lanes with no padding,
-and the same append compiles to the in-place update alone.  Axes 0-2
+and the same append compiles to the in-place update alone.  Heads of 128
+that are no whole sublane tile fold likewise (SDAR-30B-A3B's 4 x 128): at
+rest the compiler keeps (4, 128) in tiles of 4, and around a step it
+turns the WHOLE pool to tiles of (page rows, 128) and back, 3 GB a pool
+each way beside 16 GB that are full (read in the text compiled for a
+described v5e: PERF.md, PR 50); folded they are 512 lanes like the
+others'.  Axes 0-2
 (layer, page, row in the page) are the same in both layouts, so pages,
 tables, the trash page, copy-on-write and the scale pools of
 ``kv_quant`` do not know the difference.  The step functions never
@@ -201,14 +207,17 @@ __all__ = ["PagedKVCache", "kv_pool_shape", "append_rows", "pool_heads",
 # a TPU tile's lane count: the last axis of an array at rest is padded to
 # a multiple of it
 _LANES = 128
+# and its sublane count: heads on an axis of their own are its rows
+_SUBLANES = 8
 
 
 def kv_pool_shape(layers, rows, page_size, num_heads, head_dim):
     """Shape at rest of a paged K or V pool of ``rows`` pages (the trash
-    page included): heads of whole lane tiles keep their own axis, narrower
-    heads fold into the last one (the module docstring has why)."""
+    page included): heads of whole lane tiles, a whole number of sublane
+    tiles of them, keep their own axis; narrower or fewer heads fold into
+    the last one (the module docstring has why)."""
     lead = (int(layers), int(rows), int(page_size))
-    if head_dim % _LANES == 0:
+    if head_dim % _LANES == 0 and num_heads % _SUBLANES == 0:
         return lead + (int(num_heads), int(head_dim))
     return lead + (int(num_heads) * int(head_dim),)
 

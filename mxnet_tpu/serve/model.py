@@ -56,7 +56,8 @@ __all__ = ["ModelConfig", "BLOCKS", "block_of", "exact_mode", "init_params",
 # it).  This module is the GPT-2 block.
 BLOCKS = {"gpt2": "model", "deepseek_v3": "latent_moe",
           "granitemoehybrid": "granite_hybrid", "laguna": "laguna",
-          "bailing_hybrid": "bailing_hybrid", "lfm2_moe": "lfm2_moe"}
+          "bailing_hybrid": "bailing_hybrid", "lfm2_moe": "lfm2_moe",
+          "sdar_moe": "sdar_moe"}
 
 
 def block_of(cfg):
@@ -124,6 +125,12 @@ class ModelConfig:
     attention layers, sigmoid-routed experts with a selection bias, a
     tied head) takes the same fields, ``layer_types`` of ``"conv"`` |
     ``"full_attention"`` and the convolution's taps, ``conv_L_cache``.
+    ``"sdar_moe"`` (``sdar_moe.py``: generation by diffusion over blocks of
+    ``block_length`` tokens, QK-normed grouped-query attention that sees
+    both ways inside a block, softmax-routed experts, an untied head)
+    takes the expert fields, ``num_key_value_heads``, ``attn_head_dim`` and
+    the last group: the block's length, the mask token, and the passes and
+    the confidence threshold of its unmasking.
     """
     vocab_size: int
     num_layers: int
@@ -183,6 +190,10 @@ class ModelConfig:
     scoring_func: str = "sigmoid"   # the router's scores: "sigmoid" (+ a
     #                                 selection bias) | "softmax"
     conv_L_cache: int = 0       # taps of an lfm2_moe short convolution
+    block_length: int = 0       # tokens a block of an sdar_moe generation
+    mask_token_id: int = -1     # what a row not yet unmasked holds
+    denoising_steps: int = 0    # denoise passes a block takes at most
+    confidence_threshold: float = 0.0   # a masked row over it is unmasked
 
     def __post_init__(self):
         if isinstance(self.rope_parameters, dict):

@@ -27,6 +27,18 @@ tokens one at a time so EOS / ``max_new`` cut at exactly the token a
 non-speculative run would have stopped at (greedy acceptance is exact,
 so the streams are bit-identical).
 
+A session over a block that generates by diffusion
+(``session.diffusion``: ``serve/sdar_moe.py``) yields no token from prefill
+(``NO_TOKEN``) and 0 to ``block_length`` tokens a slot from a step, each
+with the denoise pass of its block in which it was unmasked and the
+confidence it was unmasked with (``Request.passes``,
+``Request.confidences``): a request's time to first token ends at its first
+block's commit, a commit's tokens are consumed one at a time like a
+verify's (a last block's tail past ``max_new`` or an EOS is dropped), and
+slots in different passes of different blocks share the one step.  Such a
+session refuses ``oversub``, so nothing is parked; a request handed over
+with committed tokens (``submit(parked=True)``) is refused by name.
+
 Preemption and resume (oversubscribed sessions,
 ``session.config.oversub``): before every step the scheduler probes the
 session's page shortfall for the coming boundary; when shortfall plus
@@ -91,6 +103,7 @@ import time
 from ..base import MXNetError
 from ..profiler import span as _span
 from ..testing import faults
+from .session import NO_TOKEN
 
 __all__ = ["Request", "Scheduler", "ServeCancelled", "summarize"]
 
@@ -137,6 +150,10 @@ class Request:
     eos_id: int = -1  # -1: never stops early
     # -- filled in by the scheduler --
     tokens: list = dataclasses.field(default_factory=list)
+    passes: list = dataclasses.field(default_factory=list)  # a diffusion
+    #   block: the denoise pass of its block each token was unmasked in,
+    confidences: list = dataclasses.field(default_factory=list)  # and the
+    #   softmax's value at the token in that pass
     ttft_s: float = -1.0
     done_s: float = -1.0
     failed: bool = False
@@ -248,6 +265,11 @@ class Scheduler(object):
         request that already holds committed tokens (replica failover)
         through the resume path: its transcript re-prefills and the
         replayed token is asserted against the last committed one."""
+        if parked and self.session.diffusion:
+            raise MXNetError(
+                "request %d holds committed tokens and block %r cannot "
+                "resume one (%s)" % (request.rid, self.session.model.block,
+                                     self.session.block.REFUSES_WHY))
         self._queue.append(request)
         if parked:
             self._parked.append(request)
@@ -442,9 +464,11 @@ class Scheduler(object):
                 break  # pool full: stays queued for a later boundary
             if first is None:
                 continue
+            active[slot] = req
+            if first == NO_TOKEN:   # a diffusion block: none from prefill
+                continue
             req.ttft_s = now() - req.arrival_s
             req.tokens.append(first)
-            active[slot] = req
             if len(req.tokens) >= req.max_new or first == req.eos_id:
                 self._finish(req, slot, active, now)
         self.stats["peak_active"] = max(self.stats["peak_active"],
@@ -511,6 +535,21 @@ class Scheduler(object):
                         # EOS inside the speculated window: the
                         # committed tail past it is dropped, exactly
                         # where non-speculative decode would stop
+                        self._finish(req, slot, active, now)
+                        break
+        elif sess.diffusion:
+            committed, _ = sess.step()
+            for slot in sorted(active):
+                req = active[slot]
+                if committed[slot] and req.ttft_s < 0:
+                    req.ttft_s = now() - req.arrival_s
+                for tok, unmasked_at, confidence in committed[slot]:
+                    req.tokens.append(tok)
+                    req.passes.append(unmasked_at)
+                    req.confidences.append(confidence)
+                    if (len(req.tokens) >= req.max_new
+                            or tok == req.eos_id):
+                        # the block's tail past it is dropped
                         self._finish(req, slot, active, now)
                         break
         else:

@@ -135,6 +135,24 @@ the rest at the smallest bucket that fits: the same ``len(buckets) + 1``
 executables), and a slot's page reservation and the ``max_len`` check
 follow ``max_prompt + max_new``.
 
+A block that generates by diffusion (``ModelConfig(block="sdar_moe",
+...)``, ``serve/sdar_moe.py``) has a ``block_pass`` where the others have a
+``decode_step``, and the session compiles that in its place, as
+``"block_pass"``: still ``len(buckets) + 1`` executables.  A slot then
+holds an **open block** of ``block_length`` tokens, some of them still the
+mask token: :meth:`InferenceSession.prefill` writes the K/V of the prompt's
+whole blocks and yields no token (:data:`NO_TOKEN`), the tokens left over
+open the first block, and :meth:`InferenceSession.step` runs ONE pass over
+every live slot's open block, whatever pass of whatever block each is in:
+a slot whose block still held a mask has some rows unmasked (on the
+device; the host reads the block's tokens, which rows were unmasked and
+their confidences, nothing of vocabulary width), a slot whose block held
+none has it committed: ``lengths`` moves by the block's length, the step hands out its
+tokens with the pass each was unmasked in and the confidence it was
+unmasked with, and the next block opens.  A
+step so commits 0 to ``block_length`` tokens a slot.  Such a block refuses
+``spec_k``, ``kv_quant``, ``prefix_pages`` and ``oversub``.
+
 Env knobs (see docs/env_vars.md): ``MXNET_SERVE_SLOTS``,
 ``MXNET_SERVE_PAGE``, ``MXNET_SERVE_BUCKETS``, ``MXNET_SERVE_MAX_NEW``,
 ``MXNET_SERVE_PAGES``, ``MXNET_SERVE_EXACT``, ``MXNET_SERVE_SPEC_K``,
@@ -156,7 +174,11 @@ from .kv_cache import PagedKVCache
 from .model import (ModelConfig, block_of, config_from_params,
                     decode_pages_visited, exact_mode, trace_notes)
 
-__all__ = ["ServeConfig", "InferenceSession"]
+__all__ = ["ServeConfig", "InferenceSession", "NO_TOKEN"]
+
+# what ``prefill`` returns for its first token where the block yields none
+# there (a diffusion block: its first tokens come with a block's commit)
+NO_TOKEN = -1
 
 
 def _parse_buckets(raw):
@@ -332,6 +354,29 @@ class _Executable(object):
         return self.params_sig + rest
 
 
+class _OpenBlock(object):
+    """The block a slot of a diffusion session is generating: its
+    ``tokens`` (the mask token on the rows still masked), the denoise
+    ``passes`` it has had, the pass each row was unmasked ``at`` (-1: it
+    came with the prompt) and the confidence it was unmasked with
+    (``conf``), how many leading rows were ``known`` from the
+    prompt, how many after them are ``fresh`` (generated tokens the request
+    asked for: a last block's tail is not), and the tokens the request may
+    still be handed after this block (``budget``)."""
+
+    __slots__ = ("tokens", "passes", "at", "conf", "known", "fresh",
+                 "budget")
+
+    def __init__(self, known, mask, length, budget):
+        self.tokens = list(known) + [mask] * (length - len(known))
+        self.passes = 0
+        self.at = [-1] * length
+        self.conf = [0.0] * length
+        self.known = len(known)
+        self.fresh = min(length - self.known, budget)
+        self.budget = budget - self.fresh
+
+
 class InferenceSession(object):
     """Compile-once serving session for the built-in transformer LM.
 
@@ -385,6 +430,12 @@ class InferenceSession(object):
         # the block (model.BLOCKS): looked up here, once; everything the
         # session does that depends on the architecture goes through it
         self.block = block_of(self.model)
+        # whether the block generates by diffusion over blocks: it then
+        # has a ``block_pass`` in the place of ``decode_step``, prefill
+        # yields :data:`NO_TOKEN` and a step's tokens are lists of
+        # ``(token, pass, confidence)`` triples
+        self.diffusion = hasattr(self.block, "block_pass")
+        self._step_exe = "block_pass" if self.diffusion else "decode"
         self._check_block_support(draft_params)
         self.block.check_params(self.params, self.model)
         if cfg.longest_prompt + cfg.max_new > self.model.max_len:
@@ -412,8 +463,11 @@ class InferenceSession(object):
         # the block's own device state, taken and returned by every
         # executable beside the cache's pools
         self.counters = self.block.init_counters(self.model)
-        self._slot_tokens = {}  # slot -> next token to feed the decoder
+        # slot -> next token to feed the decoder (a diffusion block: the
+        # slot's _OpenBlock)
+        self._slot_tokens = {}
         self._slot_history = {}  # slot -> prompt + committed tokens
+        self._slot_budget = {}  # diffusion: slot -> max_new, until prefill
         self._spec_stats = {"verify_steps": 0, "slot_steps": 0,
                             "proposed": 0, "accepted": 0, "committed": 0}
         self._decode_stats = {"steps": 0, "pages_visited": 0}
@@ -476,7 +530,8 @@ class InferenceSession(object):
         here, by name, rather than served wrongly."""
         cfg = self.config
         asked = {"spec_k": cfg.spec_k or draft_params is not None,
-                 "kv_quant": cfg.kv_quant}
+                 "kv_quant": cfg.kv_quant, "prefix_pages": cfg.prefix_pages,
+                 "oversub": cfg.oversub}
         refused = [name for name in self.block.REFUSES if asked[name]]
         if refused:
             raise MXNetError(
@@ -645,11 +700,26 @@ class InferenceSession(object):
             return block.decode_step(params, tokens, lengths, tables, pools,
                                      counters, **static)
 
-        self._aot(
-            "decode", decode_fn, self.params,
-            (param_avals, sds((cfg.slots,), i32), sds((cfg.slots,), i32),
-             sds((cfg.slots, max_pages), i32), pools, counters),
-            donate_argnums=(4, 5))
+        if not self.diffusion:
+            self._aot(
+                "decode", decode_fn, self.params,
+                (param_avals, sds((cfg.slots,), i32), sds((cfg.slots,), i32),
+                 sds((cfg.slots, max_pages), i32), pools, counters),
+                donate_argnums=(4, 5))
+        else:
+            def block_pass_fn(params, tokens, quota, fresh, lengths, tables,
+                              pools, counters):
+                return block.block_pass(params, tokens, quota, fresh,
+                                        lengths, tables, pools, counters,
+                                        **static)
+
+            self._aot(
+                "block_pass", block_pass_fn, self.params,
+                (param_avals, sds((cfg.slots, self.model.block_length), i32),
+                 sds((cfg.slots,), i32), sds((cfg.slots,), i32),
+                 sds((cfg.slots,), i32), sds((cfg.slots, max_pages), i32),
+                 pools, counters),
+                donate_argnums=(6, 7))
 
         # hybrid prefill takes a slot scalar (rings and SSM state are
         # slot-indexed, unlike the table-indirected pages)
@@ -782,6 +852,8 @@ class InferenceSession(object):
         oversub = self.config.oversub
         slot = self.cache.alloc(prompt_len, max_new, tokens=toks,
                                 oversub=oversub)
+        if slot is not None and self.diffusion:
+            self._slot_budget[slot] = max_new
         if slot is not None and self.draft_cache is not None:
             # identical geometry + identical alloc/release/publish
             # sequences keep the two caches' deterministic free lists
@@ -821,9 +893,15 @@ class InferenceSession(object):
         prompt, several max-bucket chunks for a resumed transcript or a
         fresh prompt (``config.max_prompt``) longer than the largest
         bucket.  Afterwards the slot's full prompt pages are published
-        into the prefix index for future admissions."""
+        into the prefix index for future admissions.
+
+        A diffusion block (``serve/sdar_moe.py``) prefills the prompt's
+        whole blocks and yields no token: ``(NO_TOKEN, None)``
+        (:meth:`_block_prefill`)."""
         import numpy as np
 
+        if self.diffusion:
+            return self._block_prefill(slot, prompt_tokens)
         with _span("session.prefill", slot=slot) as sp:
             prompt = np.asarray(prompt_tokens, np.int32).reshape(-1)
             p = int(prompt.shape[0])
@@ -847,27 +925,8 @@ class InferenceSession(object):
                 from ..testing import faults
 
                 faults.inject("kv_window")
-            first = last_logits = None
-            off, chunks = cached, 0
-            while off < p:
-                with _span("prefill.launch"):
-                    bucket = self._chunk_bucket(p - off)
-                    n = min(p - off, bucket)
-                    toks = np.zeros((1, bucket), np.int32)
-                    toks[0, :n] = prompt[off:off + n]
-                    self.cache.ensure_writable(slot, off, n)
-                    # host arrays: the launch uploads them, no call of its
-                    # own each
-                    args = (self.params, toks, np.int32(n), np.int32(off),
-                            self.cache.table_row(slot), self.cache.pools,
-                            self.counters,
-                            np.int32(slot) if self.cache.hybrid else None)
-                    self._count_chunk(off, bucket)
-                    first, last_logits, self.cache.pools, self.counters = \
-                        self._dispatch("prefill_%d" % bucket, args)
-                off += n
-                chunks += 1
-                self.cache.lengths[slot] = off
+            first, last_logits, bucket, chunks = self._prefill_chunks(
+                slot, prompt, cached, p)
             sp.set(bucket=bucket, chunks=chunks)
             with _span("prefill.wait"):
                 first = int(first)
@@ -880,6 +939,61 @@ class InferenceSession(object):
                     self._draft_ingest(slot, prompt)
                     self.draft_cache.register_prefix(slot, prompt_list)
         return first, last_logits
+
+    def _prefill_chunks(self, slot, prompt, off, end):
+        """Rows ``off .. end - 1`` of ``prompt`` through the per-bucket
+        executables in page-aligned chunks, a ``prefill.launch`` span each
+        -> (the last chunk's first two results, its bucket, the chunks);
+        ``lengths`` follows."""
+        import numpy as np
+
+        first = last_logits = None
+        bucket = chunks = 0
+        while off < end:
+            with _span("prefill.launch"):
+                bucket = self._chunk_bucket(end - off)
+                n = min(end - off, bucket)
+                toks = np.zeros((1, bucket), np.int32)
+                toks[0, :n] = prompt[off:off + n]
+                self.cache.ensure_writable(slot, off, n)
+                # host arrays: the launch uploads them, no call of its
+                # own each
+                args = (self.params, toks, np.int32(n), np.int32(off),
+                        self.cache.table_row(slot), self.cache.pools,
+                        self.counters,
+                        np.int32(slot) if self.cache.hybrid else None)
+                self._count_chunk(off, bucket)
+                first, last_logits, self.cache.pools, self.counters = \
+                    self._dispatch("prefill_%d" % bucket, args)
+            off += n
+            chunks += 1
+            self.cache.lengths[slot] = off
+        return first, last_logits, bucket, chunks
+
+    def _block_prefill(self, slot, prompt_tokens):
+        """:meth:`prefill` for a diffusion block: the prompt's whole blocks
+        through the chunk loop (none for a prompt shorter than a block),
+        then the slot's first open block, which the tokens left over
+        begin.  -> (:data:`NO_TOKEN`, None)."""
+        import numpy as np
+
+        b = self.model.block_length
+        with _span("session.prefill", slot=slot) as sp:
+            prompt = np.asarray(prompt_tokens, np.int32).reshape(-1)
+            p = int(prompt.shape[0])
+            whole = p - p % b
+            sp.set(prompt=p, cached=0)
+            done, _, bucket, chunks = self._prefill_chunks(slot, prompt, 0,
+                                                           whole)
+            sp.set(bucket=bucket, chunks=chunks)
+            with _span("prefill.wait"):
+                if done is not None:
+                    int(done)
+            with _span("prefill.publish"):
+                self._slot_tokens[slot] = _OpenBlock(
+                    prompt[whole:].tolist(), self.model.mask_token_id, b,
+                    self._slot_budget.pop(slot, self.config.max_new))
+        return NO_TOKEN, None
 
     def _count_chunk(self, offset, bucket):
         """One prefill chunk's share of ``prefill_report()``: in every
@@ -938,9 +1052,15 @@ class InferenceSession(object):
         slot -> emitted token id and ``logits`` is the (slots, vocab)
         array (inactive rows are garbage by design), left on the device:
         the step reads the ``(slots,)`` token vector and nothing else,
-        and a caller that wants numbers converts (``np.asarray``)."""
+        and a caller that wants numbers converts (``np.asarray``).
+
+        A diffusion block's step is one block pass and commits 0 to
+        ``block_length`` tokens a slot: ``tokens`` then maps slot -> a list
+        of ``(token, pass, confidence)`` triples (:meth:`_block_step`)."""
         import numpy as np
 
+        if self.diffusion:
+            return self._block_step()
         cfg = self.config
         with _span("session.step", live=len(self._slot_tokens)):
             with _span("step.prepare"):
@@ -973,6 +1093,72 @@ class InferenceSession(object):
                     if slot in self._slot_history:
                         self._slot_history[slot].append(tok)
                     out[slot] = tok
+        return out, logits
+
+    def _block_step(self):
+        """:meth:`step` for a diffusion block: ONE pass of the block-pass
+        executable over every live slot's open block.  -> (slot -> the
+        ``(token, pass, confidence)`` triples the step committed, the pass
+        being the denoise pass of its block, 0-based, in which the token
+        was unmasked, and the confidence the softmax's value at the token
+        in that pass: none for a slot whose block still held a mask, every
+        row the block generated for one whose block was committed (a last
+        block's tail past the request's ``max_new`` among them: who asked
+        for fewer drops it, as the scheduler does); the (slots,
+        block_length, vocab) logits, left on the device)."""
+        import numpy as np
+
+        cfg, model = self.config, self.model
+        b, mask = model.block_length, model.mask_token_id
+        with _span("session.step", live=len(self._slot_tokens)) as sp:
+            with _span("step.prepare"):
+                self._pre_dispatch(b)
+                tokens = np.zeros((cfg.slots, b), np.int32)
+                quota = np.full((cfg.slots,), -1, np.int32)
+                fresh = np.zeros((cfg.slots,), np.int32)
+                denoise = 0
+                for slot, blk in self._slot_tokens.items():
+                    tokens[slot] = blk.tokens
+                    quota[slot] = self.block.pass_quota(model, blk.passes)
+                    fresh[slot] = blk.fresh
+                    denoise += mask in blk.tokens
+                sp.set(denoise=denoise,
+                       commit=len(self._slot_tokens) - denoise)
+                args = (self.params, tokens, quota, fresh,
+                        self.cache.lengths_arg(), self.cache.device_tables(),
+                        self.cache.pools, self.counters)
+                # the pages this pass's reader visits a layer, the block's
+                # own rows included (decode_pages_visited adds one row)
+                pages = decode_pages_visited(
+                    self.cache.lengths + (b - 1), cfg.page_size,
+                    self.cache.table_width, self._paged_kernel_layers() > 0)
+                self._decode_stats["steps"] += 1
+                self._decode_stats["pages_visited"] += pages
+            with _span("step.launch"):
+                after, unmasked, conf, logits, self.cache.pools, \
+                    self.counters = self._dispatch("block_pass", args)
+            with _span("step.wait"):
+                after, unmasked, conf = (np.asarray(after),
+                                         np.asarray(unmasked),
+                                         np.asarray(conf))
+            with _span("step.commit"):
+                out = {}
+                for slot, blk in self._slot_tokens.items():
+                    if mask in blk.tokens:      # its denoise pass
+                        for row in np.flatnonzero(unmasked[slot]):
+                            blk.tokens[row] = int(after[slot, row])
+                            blk.at[row] = blk.passes
+                            blk.conf[row] = float(conf[slot, row])
+                        blk.passes += 1
+                        out[slot] = []
+                        continue
+                    # its commit pass: the pages hold the block's rows
+                    self.cache.lengths[slot] += b
+                    out[slot] = [
+                        (blk.tokens[row], blk.at[row], blk.conf[row])
+                        for row in range(blk.known, b)]
+                    self._slot_tokens[slot] = _OpenBlock((), mask, b,
+                                                         blk.budget)
         return out, logits
 
     def spec_step(self, limits=None):
@@ -1092,7 +1278,8 @@ class InferenceSession(object):
         """Layers of the decode executable that were traced with the
         paged-attention kernel (``ops/paged_attention.py``); 0 where
         every full-attention layer runs the loop."""
-        return self._exes["decode"].traced.get("paged_kernel_layers", 0)
+        return self._exes[self._step_exe].traced.get("paged_kernel_layers",
+                                                    0)
 
     def decode_report(self):
         """How much of the page tables the decode steps had to read,
@@ -1206,12 +1393,24 @@ class InferenceSession(object):
         (0), ``expert_layers``, ``experts_held``, ``state_bytes_per_slot``
         and ``kv_lanes``.
 
+        The block-diffusion / QK-normed grouped-query block
+        (``serve/sdar_moe.py``): the share's router counts, the six
+        attention counts (a block pass counts as a decode step; the two
+        window ones stay 0) and ``diffusion_stats``: ``slot_passes`` (a
+        live slot's share of one block-pass call) = ``denoise_slot_passes``
+        + ``commit_slot_passes``, ``rows_unmasked_by_threshold`` and
+        ``rows_unmasked_by_quota``, ``blocks_committed`` and
+        ``tokens_committed`` (the generated tokens the requests asked for:
+        a first block's prompt rows and a last block's tail are not);
+        ``full_layers``, ``expert_layers``, ``experts_held``,
+        ``block_length``, ``denoising_steps`` and ``kv_lanes``.
+
         Every note of the decode executable's trace is copied in, so
         where the paged-attention kernel was traced its
         ``paged_kernel_layers`` shows here as in ``decode_report()``."""
         rep = self.block.report(self.counters, self.model)
         if rep is not None:
-            rep.update(self._exes["decode"].traced)
+            rep.update(self._exes[self._step_exe].traced)
             if self.cache.latent_lanes is not None:
                 rep["latent_lanes"] = self.cache.latent_lanes
             if self.cache.n_window:
@@ -1257,6 +1456,7 @@ class InferenceSession(object):
     def release(self, slot):
         self._slot_tokens.pop(slot, None)
         self._slot_history.pop(slot, None)
+        self._slot_budget.pop(slot, None)
         self.cache.release(slot)
         if self.draft_cache is not None:
             self.draft_cache.release(slot)
@@ -1309,8 +1509,9 @@ class InferenceSession(object):
     @property
     def executables(self):
         """name -> compiled executable.  Fixed set for the session's
-        lifetime: prefill per bucket + decode, plus verify (and draft,
-        for a parameterized proposer) when ``spec_k > 0``."""
+        lifetime: prefill per bucket + decode (a diffusion block:
+        ``block_pass`` in its place), plus verify (and draft, for a
+        parameterized proposer) when ``spec_k > 0``."""
         return {name: rec.compiled for name, rec in self._exes.items()}
 
     def memory_analysis(self, name="decode"):

@@ -256,13 +256,15 @@ def test_kernel_equals_the_loop(case, layout, rows):
     assert_close_across_executables(got, want)
 
 
-@pytest.mark.parametrize("head", [32, KD])
-def test_folded_kernel_takes_any_head_that_divides_a_lane_tile(head):
+@pytest.mark.parametrize("head, rows", [(32, 3), (KD, 3), (KD, 32)])
+def test_folded_kernel_takes_any_head_that_divides_a_lane_tile(head, rows):
     """Four heads of 32 a lane tile, or one of 128: the same form, since
-    the kernel sees lane tiles and the query rows laid out over them."""
+    the kernel sees lane tiles and the query rows laid out over them; 32
+    rows a head are a diffusion block's 4 rows x 8 query heads
+    (``serve/sdar_moe.py``)."""
     rs = np.random.RandomState(19)
     lengths = jnp.asarray(LENGTHS["idle_beside_full"], jnp.int32)
-    q, k, v, tables = _kernel_case(rs, LENGTHS["idle_beside_full"], 3,
+    q, k, v, tables = _kernel_case(rs, LENGTHS["idle_beside_full"], rows,
                                    "folded", head)
     assert k.shape[-1] == 8 * head
     want = _paged(q, k, v, None, None, tables, lengths, False)
@@ -474,18 +476,22 @@ def test_a_steps_layers_share_one_trace_of_the_kernel(monkeypatch):
 # the pools' layout at rest: the cache's, and no result knows it
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("head_dim, folds", [(32, True), (64, True),
-                                             (128, False), (256, False)])
-def test_heads_narrower_than_a_lane_tile_fold_into_the_last_axis(head_dim,
-                                                                 folds):
-    shape = kv_cache.kv_pool_shape(4, 769, 16, 8, head_dim)
-    assert shape == ((4, 769, 16, 8 * head_dim) if folds
-                     else (4, 769, 16, 8, head_dim))
-    cache = kv_cache.PagedKVCache(4, 8, head_dim, 16, 6, 2, 3)
+@pytest.mark.parametrize("heads, head_dim, folds", [
+    (8, 32, True), (8, 64, True), (8, 128, False), (8, 256, False),
+    (16, 128, False), (4, 128, True), (12, 128, True), (4, 256, True)])
+def test_heads_narrower_than_a_lane_tile_fold_into_the_last_axis(
+        heads, head_dim, folds):
+    """And heads of whole lane tiles that are no whole sublane tile of
+    them (SDAR-30B-A3B's 4 x 128): at rest those would lie in tiles of 4
+    rows, which a step turns the whole pool out of and back into."""
+    shape = kv_cache.kv_pool_shape(4, 769, 16, heads, head_dim)
+    assert shape == ((4, 769, 16, heads * head_dim) if folds
+                     else (4, 769, 16, heads, head_dim))
+    cache = kv_cache.PagedKVCache(4, heads, head_dim, 16, 6, 2, 3)
     assert cache.pools["k_pool"].shape == cache.pools["v_pool"].shape \
-        == kv_cache.kv_pool_shape(4, 7, 16, 8, head_dim)
-    assert cache.kv_lanes == (8 * head_dim if folds else head_dim)
-    assert kv_cache.pool_heads(cache.pools["k_pool"], head_dim) == 8
+        == kv_cache.kv_pool_shape(4, 7, 16, heads, head_dim)
+    assert cache.kv_lanes == (heads * head_dim if folds else head_dim)
+    assert kv_cache.pool_heads(cache.pools["k_pool"], head_dim) == heads
 
 
 def test_a_cache_without_a_kv_pool_names_no_lane_width():

@@ -30,6 +30,9 @@ SURFACE = ("validate", "check_params", "init_params", "full_forward",
            "REFUSES", "REFUSES_WHY", "compiler_options", "report",
            "decode_report", "prefill_block", "guard_tag")
 SPECULATIVE = ("verify_step", "draft_propose")
+# a block that generates by diffusion has these where the others have
+# their decode_step
+DIFFUSION = ("block_pass", "pass_quota")
 
 GPT2 = serve.ModelConfig(vocab_size=61, num_layers=3, d_model=32,
                          num_heads=2, max_len=64)
@@ -78,13 +81,22 @@ LFM2 = serve.ModelConfig(
     conv_L_cache=3, d_ff=48, first_k_dense=1, moe_d_ff=16,
     n_routed_experts=16, num_experts_per_tok=4, experts_held=(4, 4),
     tie_word_embeddings=True)
+SDAR = serve.ModelConfig(
+    block="sdar_moe", vocab_size=61, num_layers=2, d_model=32, num_heads=4,
+    num_key_value_heads=2, max_len=64, attn_head_dim=8, rope_theta=1e6,
+    moe_d_ff=16, n_routed_experts=16, num_experts_per_tok=4,
+    scoring_func="softmax", experts_held=(4, 4), block_length=4,
+    mask_token_id=60, denoising_steps=4, confidence_threshold=0.9)
 CONF = dict(slots=3, page_size=8, buckets=(8, 16), max_new=8)
 
 
 @pytest.mark.parametrize("name", sorted(serve_model.BLOCKS))
 def test_every_block_provides_the_surface(name):
     block = serve_model.block_of(dataclasses.replace(GPT2, block=name))
-    assert [n for n in SURFACE if not hasattr(block, n)] == []
+    step = DIFFUSION if hasattr(block, "block_pass") else ("decode_step",)
+    assert (step == DIFFUSION) == (name == "sdar_moe")
+    assert [n for n in set(SURFACE) - {"decode_step"} | set(step)
+            if not hasattr(block, n)] == []
     # the speculative steps, unless the block says it refuses spec_k
     if "spec_k" not in block.REFUSES:
         assert [n for n in SPECULATIVE if not hasattr(block, n)] == []
@@ -132,6 +144,7 @@ PREFILL_SCANS = {
     "granite": (GRANITE, dict(), 1, 8),
     "bailing": (BAILING, dict(), 1, 8),
     "lfm2": (LFM2, dict(), 1, 8),
+    "sdar": (SDAR, dict(), 2, 8),
 }
 
 
@@ -177,6 +190,8 @@ VARIANTS = {
     "laguna_long_prompts": (LAGUNA, dict(max_prompt=40)),
     "lfm2": (LFM2, dict()),
     "lfm2_long_prompts": (LFM2, dict(max_prompt=40)),
+    "sdar": (SDAR, dict()),
+    "sdar_long_prompts": (SDAR, dict(max_prompt=40)),
 }
 
 
@@ -338,10 +353,43 @@ def test_a_state_pool_and_nothing_else_in_three_layers_of_four():
     assert sess.prefill_report()["chunks"] == 3
 
 
+def test_pages_alone_and_a_block_pass_in_the_place_of_decode():
+    """The seventh block's cache: K/V pages in every layer and nothing
+    else (a slot's open block is the session's, a few integers); three
+    counters, none among the pools; the paged reader's report, since its
+    passes run it; fresh prompts in chunks, and a block-pass executable
+    where the others have decode: buckets + 1."""
+    sess = serve.InferenceSession(
+        serve.init_params(SDAR, seed=5), model=SDAR,
+        config=serve.ServeConfig(**dict(CONF, max_prompt=40)))
+    assert sorted(sess.cache.pools) == ["k_pool", "v_pool"]
+    assert sess.cache.pools["k_pool"].shape \
+        == kv_cache.kv_pool_shape(2, 3 * 6 + 1, 8, 2, 8) == (2, 19, 8, 16)
+    assert sess.cache.paged == ("k_pool", "v_pool")
+    assert not sess.cache.hybrid and not sess.cache.state
+    assert sorted(sess.counters) == ["attn_stats", "diffusion_stats",
+                                     "moe_stats"]
+    assert sess.diffusion
+    assert sorted(sess.executables) == ["block_pass", "prefill_16",
+                                        "prefill_8"]
+    prompt = list(range(1, 38))
+    slot = sess.try_alloc(len(prompt), 4, tokens=prompt)
+    assert sess.prefill(slot, prompt) == (-1, None)
+    assert sess.prefill_report()["chunks"] == 3      # 16 + 16 + 4 of 37
+    assert int(sess.cache.lengths[slot]) == 36
+    out, logits = sess.step()
+    assert out == {slot: []} and logits.shape == (3, 4, 61)
+    rep = sess.block_report()
+    assert (rep["experts_held"], rep["block_length"], rep["slot_passes"],
+            rep["prefill_chunks_continued"]) == (4, 4, 1, 2)
+    assert sess.decode_report()["kv_lanes"] == rep["kv_lanes"] == 16
+    assert sess.decode_report()["steps"] == 1
+
+
 # block -> a model of it: with windowed layers where the block has any
 RINGS = {"gpt2": GPT2_WINDOWED, "deepseek_v3": LATENT,
          "granitemoehybrid": GRANITE, "bailing_hybrid": BAILING,
-         "laguna": LAGUNA, "lfm2_moe": LFM2}
+         "laguna": LAGUNA, "lfm2_moe": LFM2, "sdar_moe": SDAR}
 
 
 @pytest.mark.parametrize("name", sorted(serve_model.BLOCKS))

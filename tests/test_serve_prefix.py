@@ -280,7 +280,8 @@ def test_cow_copies_a_page_whatever_the_pools_layout(head_dim, lanes):
     one, and the shared one is untouched."""
     import jax.numpy as jnp
 
-    heads, layers = 2, 2
+    # heads of 128 keep their axis in whole sublane tiles of them
+    heads, layers = (8 if lanes == head_dim else 2), 2
     cache = PagedKVCache(layers, heads, head_dim, PAGE, 6, 2, 3,
                          prefix_pages=-1)
     assert cache.kv_lanes == lanes

@@ -882,6 +882,121 @@ def test_lfm2_executables_compile_for_v5e_at_the_published_widths(
         assert not _layer_slices(text, shapes["k_pool"])
 
 
+# ``sdar-30b-l12-chat``'s executables at SDAR-30B-A3B-Chat's published
+# widths over the cell's cache: 12 layers (32 query heads over 4 key/value
+# heads of 128, a norm a head), experts 0-15 of 128 held, 1/8 of the
+# vocabulary, an untied head; 32 slots x 256 pages of 16 + the trash page
+# in twelve layers' K/V pools, 4 heads of 128 folded into 512 lanes (3.22
+# GB each).  What is compiled is ``sdar_moe.block_pass`` /
+# ``sdar_moe.prefill_forward`` with the TPU's branches taken.
+SDAR_SLOTS, SDAR_TABLE, SDAR_BLOCK = 32, (3072 + 1024) // 16, 4
+
+
+def _sdar_program(one_chip, monkeypatch, bucket):
+    """-> the compiled block pass (``bucket`` 0) or prefill chunk of
+    ``bucket`` rows, the cache's pool shapes, and the notes of the trace."""
+    from mxnet_tpu import serve
+    from mxnet_tpu.serve import kv_cache, sdar_moe
+    from mxnet_tpu.serve import model as serve_model
+
+    cfg = serve.ModelConfig(
+        block="sdar_moe", vocab_size=18992, num_layers=12, d_model=2048,
+        num_heads=32, num_key_value_heads=4, max_len=32768,
+        attn_head_dim=128, rope_theta=1e6, rms_norm_eps=1e-6, moe_d_ff=768,
+        n_routed_experts=128, num_experts_per_tok=8, scoring_func="softmax",
+        experts_held=(0, 16), block_length=SDAR_BLOCK, mask_token_id=18991,
+        denoising_steps=4, confidence_threshold=0.9).validate()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    i32 = jnp.int32
+    params = {k: sds(v) for k, v in sdar_moe.param_shapes(cfg).items()}
+    assert abs(sum(math.prod(v.shape) for v in params.values())
+               - 1213.5e6) < 1e6
+    page = 16
+    shapes = {"k_pool": kv_cache.kv_pool_shape(
+        12, SDAR_SLOTS * SDAR_TABLE + 1, page, 4, 128)}
+    shapes["v_pool"] = shapes["k_pool"]
+    assert shapes["k_pool"][-1] == 512 and len(shapes["k_pool"]) == 4
+    pools = {name: sds(shape) for name, shape in shapes.items()}
+    counters = {name: sds(leaf.shape, i32) for name, leaf
+                in sdar_moe.init_counters(cfg).items()}
+    static = dict(cfg=cfg, page_size=page, exact=False, kv_quant="")
+    if bucket:
+        def step(params, tokens, length, offset, table_row, pools, counters):
+            return sdar_moe.prefill_forward(
+                params, tokens, length, offset, table_row, pools, counters,
+                **static)
+
+        avals = (params, sds((1, bucket), i32), sds((), i32), sds((), i32),
+                 sds((SDAR_TABLE,), i32), pools, counters)
+        donate = (5, 6)
+    else:
+        def step(params, tokens, quota, fresh, lengths, tables, pools,
+                 counters):
+            return sdar_moe.block_pass(params, tokens, quota, fresh, lengths,
+                                       tables, pools, counters, **static)
+
+        avals = (params, sds((SDAR_SLOTS, SDAR_BLOCK), i32),
+                 sds((SDAR_SLOTS,), i32), sds((SDAR_SLOTS,), i32),
+                 sds((SDAR_SLOTS,), i32), sds((SDAR_SLOTS, SDAR_TABLE), i32),
+                 pools, counters)
+        donate = (6, 7)
+    with jax.default_matmul_precision("default"), \
+            serve_model.trace_notes() as notes:
+        lowered = jax.jit(step, donate_argnums=donate).lower(*avals)
+    return lowered.compile(
+        compiler_options=sdar_moe.compiler_options("tpu")), shapes, notes
+
+
+@pytest.mark.parametrize("bucket, tile, temporaries", [
+    (0, 8, 512 << 20), (2048, 128, 3 << 29)],
+    ids=["block_pass", "prefill-2048"])
+def test_sdar_executables_compile_for_v5e_at_the_published_widths(
+        one_chip, monkeypatch, bucket, tile, temporaries):
+    """The whole step fits the chip beside its arguments (11.3 GB: 4.85 of
+    weights, 6.44 of pages), Mosaic takes a whole float32 expert of 768 x
+    2048 a block in all twelve layers (a pass's 128 rows x 8 experts over
+    128 is a tile of 8, a chunk of 2048 rows one of 128), and the donated
+    pools are updated where they lie: the result aliases both, and no
+    operation copies a whole K/V pool into another layout (four heads of
+    128 on an axis of their own lie at rest in tiles of 4 rows, and the
+    step then turns both pools whole to tiles of page rows and back: 6 GB
+    of temporaries, more than the chip has left: PERF.md, PR 50).  Folded,
+    the pools are the paged-attention kernel's to read, one head a lane
+    tile with the block's 4 rows x 8 query heads as its 32 rows: twelve
+    kernels in a pass and no loop under ``bdiff_pass``."""
+    from mxnet_tpu.ops import paged_attention
+    from mxnet_tpu.ops.grouped_matmul import kernel_name
+
+    compiled, shapes, notes = _sdar_program(one_chip, monkeypatch, bucket)
+    assert notes.get("expert_kernel_layers") == 12
+    assert notes.get("paged_kernel_layers", 0) == (0 if bucket else 12)
+    text = compiled.as_text()
+    # a prefill yields no token: what the last layer's attention and
+    # experts would add to x nobody reads, and the compiler drops both
+    assert len(_kernel_calls(text, kernel_name(tile))) == (11 if bucket
+                                                           else 12)
+    memory = compiled.memory_analysis()
+    held = 4 * sum(math.prod(shape) for shape in shapes.values())
+    assert memory.alias_size_in_bytes >= held
+    # (the head, the final norm and the last layer's q, o and experts are
+    # no arguments of a prefill: 0.47 GB)
+    assert (10.8e9 if bucket else 11.2e9) < memory.argument_size_in_bytes \
+        < (10.9e9 if bucket else 11.4e9)
+    assert memory.temp_size_in_bytes < temporaries
+    copies, _ = _whole_pool_copies(text, shapes["k_pool"])
+    assert not copies, copies
+    if not bucket:
+        # ONE trace of the kernel for the twelve layers
+        assert len(_kernel_calls(
+            text, paged_attention.kernel_name(32, 128))) >= 1
+        assert not re.findall(r" while\([^\n]*op_name=\"[^\"]*bdiff_pass",
+                              text)
+
+
 def _decode_reads_by_kernel(text, pool_shape, scope, layers, folded_head=0):
     """A decode executable's compiled text holds ``layers``
     paged-attention kernels in the form for its pools' layout (heads on
