@@ -47,12 +47,15 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..base import MXNetError, get_env
-from .paged_attention import paged_attention, paged_attention_eligible
+from .paged_attention import (paged_attention, paged_attention_eligible,
+                              paged_prefill, paged_prefill_eligible,
+                              prefill_tiling)
 
 __all__ = ["attention_impl", "attention_block_size", "dot_product_attention",
            "flash_attention", "reference_attention", "attend_block",
            "online_block_merge", "finalize_attention", "decode_attention",
-           "paged_decode_attention", "pallas_eligible"]
+           "paged_decode_attention", "paged_prefill_attention",
+           "pallas_eligible"]
 
 _IMPLS = ("auto", "flash", "reference")
 
@@ -595,6 +598,81 @@ def paged_decode_attention(q, k_pool, v_pool, layer, tables, lengths,
     acc, _, l = lax.fori_loop(0, (live_pages + group - 1) // group, body,
                               (acc0, m0, l0))
     return finalize_attention(acc, l).astype(q.dtype)
+
+
+def paged_prefill_attention(q, k_pool, v_pool, layer, table_row, positions,
+                            page_size, block, mi=False, k_scale=None,
+                            v_scale=None, scale=None, horizons=None):
+    """A prefill chunk's attention over the slot's pages, the chunk's own
+    rows among them (the caller has appended them).
+
+    On every backend, and for every call the kernel refuses, that is
+    ``kv_cache.read_context``'s gather of the slot's whole table and
+    :func:`decode_attention` over it in key blocks of ``block`` with a
+    horizon a query row: the bounded scan, which ends at the chunk's
+    furthest horizon.  On a TPU a call that
+    :func:`~.paged_attention.paged_prefill_eligible` accepts (the rules of
+    :func:`paged_decode_attention`'s kernel: not ``mi``, no scales,
+    float32 pools in either layout the cache gives them, a folded pool
+    under a table of at least 2 048 keys) is instead ONE Pallas kernel
+    (``ops/paged_attention.py:paged_prefill``) that reads the pages where
+    they lie, each tile of query rows up to its own furthest horizon, and
+    writes no score to HBM, at the scan's precision; the scan is its
+    fallback and its oracle.  Which ran is noted in the trace under way
+    (``prefill_kernel_layers``, with the kernel's tile in tokens and its
+    key block summed over those layers beside it, from which
+    ``InferenceSession.prefill_report()`` counts the rows visited).
+
+    q: (T, H, G, D), the chunk's T tokens' query heads, the G that share
+    key/value head H side by side; k_pool / v_pool, ``layer``,
+    ``k_scale`` / ``v_scale`` (scale pools of quantized pages) and
+    ``scale`` as :func:`paged_decode_attention` takes them; table_row
+    (max_pages,) int32, the slot's pages; positions (T,) int, each
+    token's row in the slot: a token's query heads see the rows up to
+    and including its own, ``positions + 1`` keys, unless ``horizons``
+    (T,) gives each token the keys it sees (a diffusion block's rows see
+    each other).  A horizon is data: nothing assumes causality.
+    -> (T, H, G x D): each token's results, a key/value head's query
+    heads side by side.  Traced bound, so not differentiable."""
+    # called from a traced step, long after both packages are loaded
+    from ..serve.kv_cache import read_context
+    from ..serve.model import note_traced
+
+    t, heads, group, d = q.shape
+    max_pages = table_row.shape[0]
+    if paged_prefill_eligible(q, k_pool, v_pool, mi, k_scale, v_scale,
+                              max_pages * page_size):
+        if scale is None:
+            scale = 1.0 / (d ** 0.5)
+        if horizons is None:
+            horizons = positions + 1
+        tile, pages = prefill_tiling(t * group, d, k_pool.ndim == 4, heads,
+                                     page_size, max_pages)
+        note_traced("prefill_kernel_layers", 1)
+        # summed over those layers: what ``prefill_report()`` counts from
+        note_traced("prefill_kernel_tile_rows", tile)
+        note_traced("prefill_kernel_query_heads", group)
+        note_traced("prefill_kernel_block_keys", pages * page_size)
+        att = paged_prefill(
+            q.transpose(1, 0, 2, 3).reshape(heads, t * group, d), k_pool,
+            v_pool, layer, table_row, jnp.repeat(horizons, group), page_size,
+            scale, tile, pages)
+        return att.reshape(heads, t, group * d).transpose(1, 0, 2)
+    # the gathers first, then the query's rows and the horizons: the order
+    # the blocks wrote them in, which the lowered text keeps
+    ctx_k = read_context(k_pool, layer, table_row, d)
+    ctx_v = read_context(v_pool, layer, table_row, d)
+    if k_scale is not None:
+        k_scale = k_scale[layer, table_row].reshape(1, max_pages * page_size)
+        v_scale = v_scale[layer, table_row].reshape(1, max_pages * page_size)
+    # a key/value head's query heads are its rows: row t * group + g sees
+    # the keys token t sees
+    att = decode_attention(
+        q.transpose(1, 0, 2, 3).reshape(1, heads, t * group, d), ctx_k,
+        ctx_v, jnp.repeat(positions + 1 if horizons is None else horizons,
+                          group)[None],
+        scale=scale, block=block, mi=mi, k_scale=k_scale, v_scale=v_scale)
+    return att.reshape(heads, t, group * d).transpose(1, 0, 2)
 
 
 # ---------------------------------------------------------------------------

@@ -1,4 +1,7 @@
-"""The decode step's paged attention as one kernel (Pallas, TPU).
+"""Paged attention as one kernel (Pallas, TPU): the decode step's, and (at
+the end of the file) a prefill chunk's.
+
+**The decode step's.**
 
 ``ops/attention.py:paged_decode_attention`` as a ``fori_loop`` gathers the
 next few pages of EVERY slot up to the longest live context and masks what
@@ -77,6 +80,33 @@ Stale rows (a page of the block that was not copied this time) hold what
 an earlier copy left: finite by the pool's own contract (the loop's
 ``0 x garbage`` needs the same).  The value buffer is zeroed once, at the
 first slot, because memory never written may hold anything.
+
+**A prefill chunk's** (:func:`paged_prefill`; what
+``ops/attention.py:paged_prefill_attention`` runs in the place of
+``kv_cache.read_context``'s gather of the slot's whole table and
+``decode_attention``'s bounded scan over it, for the calls
+:func:`paged_prefill_eligible` accepts: the rules above).  One slot, many
+query rows (a key/value head's query heads are its rows), and a horizon a
+row, which is data: ``k_pos < horizons[row]``, so nothing assumes
+causality.  The same pools whole in HBM, the same pages copied as they
+lie into the same double buffer, the same two layouts and the same
+block-diagonal left operand over a folded pool, the same precision.  What
+differs: the grid runs over **tiles of query rows**, and inside a tile the
+loop runs over key blocks up to **that tile's furthest horizon** (a scalar
+a tile, prefetched), so a chunk at offset 0 pays its triangle in whole
+blocks and a chunk at offset 6 144 pays 6 144 keys and its own triangle,
+where the scan walked every row to the chunk's last horizon; the blocks
+wholly under a tile's nearest horizon skip the mask; a (rows, keys) block
+of scores lives and dies in VMEM, where the scan wrote every block's to
+HBM and read it back.  Every column of the table names a page in bounds
+(rows past a slot's reservation name the trash page), so a block's pages
+are all copied, whatever the horizon: nothing stale is ever read.  And
+the loops over a block's pages and over the heads stay loops in the
+kernel (a page's and a head's number are data there): a head's work on a
+block of 1 024 keys is thousands of vector operations, and written out a
+head, a form (masked or not) and a page they made megabytes of code a
+layer and seconds of lowering a bucket, which a session pays at every
+start, compile cache or not.
 """
 from __future__ import annotations
 
@@ -87,7 +117,8 @@ import jax.numpy as jnp
 from jax import lax
 
 __all__ = ["paged_attention", "paged_attention_eligible", "kernel_name",
-           "pages_per_block"]
+           "pages_per_block", "paged_prefill", "paged_prefill_eligible",
+           "prefill_kernel_name", "prefill_tiling"]
 
 _LANES = 128        # a head's width the MXU takes whole
 _SUBLANES = 8       # rows of a float32 tile
@@ -180,35 +211,90 @@ def _vmem_bytes(pages, page_size, heads, rows, d):
     return buffers + blocks + stats + _VMEM_SLACK
 
 
-def _decode_kernel(lengths_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
-                   k_buf, v_buf, sems, m_ref, l_ref, acc_ref, buf_ref, *,
-                   page_size, width, pages, full_precision):
+def _page_copies(k_hbm, v_hbm, k_buf, v_buf, sems, page_rows, buf, i, page):
+    """The two copies, K's and V's, of pool page ``page`` into row ``i`` of
+    half ``buf`` of the double buffer."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    slot = pl.program_id(0)
-    slots = pl.num_programs(0)
-    # heads, or a folded pool's lane tiles: the buffers' shape says which
-    heads, rows, d = acc_ref.shape
-    keys = pages * page_size
-    page_rows = k_buf.shape[1] // pages
+    at = pl.ds(i * page_rows, page_rows)
+    return (pltpu.make_async_copy(k_hbm.at[page], k_buf.at[buf, at],
+                                  sems.at[0, buf]),
+            pltpu.make_async_copy(v_hbm.at[page], v_buf.at[buf, at],
+                                  sems.at[1, buf]))
+
+
+def _merge_block(q_of, k_buf, v_buf, buf, seen, m_ref, l_ref, acc_ref, keys,
+                 full_precision, rolled):
+    """Merge the key block in half ``buf`` of the double buffer into every
+    head's running maximum, sum and accumulator (``online_block_merge``'s
+    arithmetic, ``_NEG`` in place of ``-inf``).  ``q_of(h)``: head ``h``'s
+    query rows (a folded pool's ``h`` is a lane tile); ``seen`` (rows,
+    keys) bool, or None where every row sees the whole block.  ``rolled``:
+    the heads are a loop of the kernel's and ``h`` is data (the prefill
+    kernel, whose one head's work on a block is thousands of vector
+    operations: written out sixteen times in two forms they were most of
+    3 MB of code a layer and of the seconds a lowering took, PERF.md,
+    PR 51), or the loop is written out (decode's few rows a head)."""
+    from jax.experimental import pallas as pl
+
+    heads, _, d = acc_ref.shape
     folded = k_buf.shape[2] != d
     f32 = jnp.float32
     operand = f32 if full_precision else jnp.bfloat16
     precision = lax.Precision.HIGHEST if full_precision else None
+
+    def head(h, _):
+        # a head's rows of the block, or a lane tile's columns of it
+        at = (slice(None), pl.ds(pl.multiple_of(h * d, d) if rolled
+                                 else h * d, d)) if folded \
+            else (pl.ds(h, keys, stride=heads), slice(None))
+        k = k_buf[(buf,) + at].astype(operand)
+        v = v_buf[(buf,) + at].astype(operand)
+        scores = lax.dot_general(
+            q_of(h).astype(operand), k, _NT, precision=precision,
+            preferred_element_type=f32)
+        if seen is not None:
+            scores = jnp.where(seen, scores, _NEG)
+        m = m_ref[h]
+        new_m = jnp.maximum(m, jnp.max(scores, axis=1, keepdims=True))
+        correction = jnp.exp(m - new_m)
+        p = jnp.exp(scores - new_m[:, :1])
+        if seen is not None:
+            p = jnp.where(seen, p, 0.0)
+        l_ref[h] = l_ref[h] * correction \
+            + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[h] = acc_ref[h] * correction[:, :1] + lax.dot_general(
+            p.astype(operand), v, (((1,), (0,)), ((), ())),
+            precision=precision, preferred_element_type=f32)
+        m_ref[h] = new_m
+        return _
+
+    if rolled:
+        lax.fori_loop(0, heads, head, 0)
+    else:
+        for h in range(heads):
+            head(h, 0)
+
+
+def _decode_kernel(lengths_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
+                   k_buf, v_buf, sems, m_ref, l_ref, acc_ref, buf_ref, *,
+                   page_size, width, pages, full_precision):
+    from jax.experimental import pallas as pl
+
+    slot = pl.program_id(0)
+    slots = pl.num_programs(0)
+    # heads, or a folded pool's lane tiles: the buffers' shape says which
+    heads, rows, _ = acc_ref.shape
+    keys = pages * page_size
+    copies = functools.partial(_page_copies, k_hbm, v_hbm, k_buf, v_buf, sems,
+                               k_buf.shape[1] // pages)
 
     def live_pages(s):   # the lengths come clamped to the table
         return pl.cdiv(lengths_ref[s], page_size)
 
     def blocks_of(s):   # a slot of length 0 still takes its turn: one
         return jnp.maximum(pl.cdiv(live_pages(s), pages), 1)  # masked block
-
-    def copies(buf, i, page):
-        at = pl.ds(i * page_rows, page_rows)
-        return (pltpu.make_async_copy(k_hbm.at[page], k_buf.at[buf, at],
-                                      sems.at[0, buf]),
-                pltpu.make_async_copy(v_hbm.at[page], v_buf.at[buf, at],
-                                      sems.at[1, buf]))
 
     def live_copies(s, blk, buf, start):
         """Start, or wait for, the copies of block ``blk`` of slot ``s``:
@@ -246,27 +332,9 @@ def _decode_kernel(lengths_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
 
         live_copies(slot, blk, buf, start=False)
         k_pos = blk * keys + lax.broadcasted_iota(jnp.int32, (rows, keys), 1)
-        seen = k_pos < length
-        for h in range(heads):
-            # a head's rows of the block, or a lane tile's columns of it
-            at = (slice(None), pl.ds(h * d, d)) if folded \
-                else (pl.ds(h, keys, stride=heads), slice(None))
-            k = k_buf[(buf,) + at].astype(operand)
-            v = v_buf[(buf,) + at].astype(operand)
-            scores = lax.dot_general(
-                q_ref[0, h].astype(operand), k, _NT, precision=precision,
-                preferred_element_type=f32)
-            scores = jnp.where(seen, scores, _NEG)
-            m = m_ref[h]
-            new_m = jnp.maximum(m, jnp.max(scores, axis=1, keepdims=True))
-            correction = jnp.exp(m - new_m)
-            p = jnp.where(seen, jnp.exp(scores - new_m[:, :1]), 0.0)
-            l_ref[h] = l_ref[h] * correction \
-                + jnp.sum(p, axis=1, keepdims=True)
-            acc_ref[h] = acc_ref[h] * correction[:, :1] + lax.dot_general(
-                p.astype(operand), v, (((1,), (0,)), ((), ())),
-                precision=precision, preferred_element_type=f32)
-            m_ref[h] = new_m
+        _merge_block(lambda h: q_ref[0, h], k_buf, v_buf, buf,
+                     k_pos < length, m_ref, l_ref, acc_ref, keys,
+                     full_precision, rolled=False)
         return 1 - buf
 
     buf_ref[0] = lax.fori_loop(0, n_blocks, block, buf_ref[0])
@@ -365,3 +433,252 @@ def _paged_attention(q, k_pool, v_pool, layer, tables, lengths, *, page_size,
         out = jnp.stack([out[:, :, j * r:(j + 1) * r, j * d:(j + 1) * d]
                          for j in range(per)], axis=2).reshape(q.shape)
     return out[:, :, :r].astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# a prefill chunk's attention over the slot's pages
+# ---------------------------------------------------------------------------
+
+# What one step of the prefill kernel's grid takes and what one iteration
+# of its loop takes, at most: the left operand's rows (a key/value head's
+# query rows; over a folded pool the rows of a lane tile's heads one under
+# the other) and the keys a block holds.  Every row of a tile pays for
+# its running maximum, sum and accumulator once a key block, so few large
+# blocks win (a v5e, one LFM2-24B-A2B layer at the deepest offset: 4.04 ms
+# at 1 024 rows x 512 keys, 2.43 at 1 024 x 1 024 (2.52 since its loops are
+# rolled), 3.24 at 1 024 x 2 048, where whole blocks past a tile's horizon
+# are computed and masked; the scan 13.1; PERF.md, PR 51, has the sweep).
+_PREFILL_TILE_ROWS = 1024
+_PREFILL_KEYS_PER_BLOCK = 1024
+# and what bounds both under more heads: rows of a lane tile over all the
+# kernel's groups (heads of 128, or a folded pool's lane tiles).  The
+# query, result and statistics blocks hold 7 x groups x tile rows, the K
+# and V double buffer 4 x groups x keys: 14.7 MB and up to 16.8 MB at
+# these, beside ~25 MB of one group's scores.  Laguna's 8 heads at
+# 1 024 x 1 024 asked 78 MB and XLA had no room for it beside what it
+# keeps in VMEM itself; at 512 x 1 024 (52 MB) it runs (PR 51).
+_PREFILL_TILE_ROWS_ALL_GROUPS = 4096
+_PREFILL_KEYS_ALL_GROUPS = 8192
+
+
+def paged_prefill_eligible(q, k_pool, v_pool, mi, k_scale, v_scale,
+                           table_keys):
+    """Whether ``ops/attention.py:paged_prefill_attention`` sends a chunk
+    to :func:`paged_prefill`: :func:`paged_attention_eligible`'s rules,
+    read off the same facts of the call (``q`` any array of the chunk's
+    query heads: its type and head width are asked)."""
+    return paged_attention_eligible(q, k_pool, v_pool, mi, k_scale, v_scale,
+                                    table_keys)
+
+
+def prefill_tiling(rows, head_dim, folded, heads, page_size, max_pages):
+    """-> (query rows of one key/value head a grid step takes, pages a key
+    block holds) for a chunk of ``rows`` query rows a head, ``heads``
+    key/value heads of ``head_dim`` and a table of ``max_pages`` pages.
+    The kernel's left operand is the step's rows of every head a lane tile
+    holds (one where the heads keep their own axis): at most
+    ``_PREFILL_TILE_ROWS``, fewer under more than four groups (the VMEM
+    the blocks of all groups take), and the chunk is shared out evenly
+    over the fewest steps in whole sublane tiles; a key block likewise
+    ``_PREFILL_KEYS_PER_BLOCK`` keys at most, and the table's."""
+    per = _LANES // head_dim if folded else 1
+    groups = heads // per
+    operand = min(_PREFILL_TILE_ROWS, _PREFILL_TILE_ROWS_ALL_GROUPS // groups)
+    steps = -(-rows * per // operand)
+    tile = -(-rows // (steps * _SUBLANES)) * _SUBLANES
+    keys = min(_PREFILL_KEYS_PER_BLOCK, _PREFILL_KEYS_ALL_GROUPS // groups)
+    return tile, max(1, min(keys // page_size, max_pages))
+
+
+def prefill_kernel_name(tile, pages, folded_head):
+    """The prefill ``pallas_call``'s name: its layout as
+    :func:`kernel_name` writes it, a head's query rows a grid step and
+    the pages a key block (``paged_prefill_attention_f64_r512_p32``)."""
+    layout = "f%d_" % folded_head if folded_head else ""
+    return "paged_prefill_attention_%sr%d_p%d" % (layout, tile, pages)
+
+
+def _prefill_vmem_bytes(pages, page_row_bytes, groups, rows, keys):
+    """The prefill kernel's VMEM need: the double buffer of K and V
+    blocks, the query and result blocks twice each, the running maximum,
+    sum and accumulator, a few (rows, keys) float32 temporaries of one
+    head's scores, and slack."""
+    buffers = 2 * 2 * pages * page_row_bytes
+    blocks = (2 * 2 + 3) * groups * rows * _LANES * 4
+    scores = 6 * rows * keys * 4
+    return buffers + blocks + scores + _VMEM_SLACK
+
+
+def _prefill_kernel(most_ref, least_ref, table_ref, q_ref, seen_ref, k_hbm,
+                    v_hbm, o_ref, k_buf, v_buf, sems, m_ref, l_ref, acc_ref,
+                    buf_ref, *, page_size, pages, full_precision):
+    from jax.experimental import pallas as pl
+
+    tile = pl.program_id(0)
+    tiles = pl.num_programs(0)
+    # heads, or a folded pool's lane tiles, each with the rows of the
+    # heads it holds one under the other
+    groups, rows, _ = acc_ref.shape
+    keys = pages * page_size
+    copies = functools.partial(_page_copies, k_hbm, v_hbm, k_buf, v_buf, sems,
+                               k_buf.shape[1] // pages)
+
+    def block_copies(blk, buf, start):
+        """Start, or wait for, the copies of key block ``blk``: every
+        page of it (the table names a page in bounds in every column).
+        A loop the kernel keeps rolled: written out, the five places that
+        call this were 640 copies of a block of 64 pages, most of the
+        kernel's text, and lowering it took a session's start 3 s a
+        bucket with every executable in the compile cache (PERF.md,
+        PR 51)."""
+        def page_copies(i, _):
+            # a wait needs the copy's shape and semaphore, not its page
+            page = table_ref[blk * pages + i] if start else 0
+            for copy in copies(buf, i, page):
+                copy.start() if start else copy.wait()
+            return _
+
+        lax.fori_loop(0, pages, page_copies, 0)
+
+    @pl.when(tile == 0)
+    def _():
+        buf_ref[0] = 0
+        block_copies(0, 0, start=True)
+
+    m_ref[...] = jnp.full_like(m_ref, _NEG)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    horizon = seen_ref[...]                                  # (rows, 1)
+    # a tile walks the key blocks up to its furthest horizon (one at
+    # least: it takes its turn in the double buffer), and those wholly
+    # under its nearest one need no mask
+    n_blocks = jnp.maximum(pl.cdiv(most_ref[tile], keys), 1)
+    n_plain = jnp.minimum(least_ref[tile] // keys, n_blocks)
+
+    def block(blk, buf, masked):
+        last = blk + 1 >= n_blocks
+
+        @pl.when(jnp.logical_or(jnp.logical_not(last), tile + 1 < tiles))
+        def _():   # this tile's next block, or the next tile's first
+            block_copies(jnp.where(last, 0, blk + 1), 1 - buf, start=True)
+
+        block_copies(blk, buf, start=False)
+        seen = None
+        if masked:
+            k_pos = blk * keys + lax.broadcasted_iota(jnp.int32,
+                                                      (rows, keys), 1)
+            seen = k_pos < horizon
+        _merge_block(lambda g: q_ref[g], k_buf, v_buf, buf, seen, m_ref,
+                     l_ref, acc_ref, keys, full_precision, rolled=True)
+        return 1 - buf
+
+    buf = lax.fori_loop(0, n_plain,
+                        functools.partial(block, masked=False), buf_ref[0])
+    buf_ref[0] = lax.fori_loop(n_plain, n_blocks,
+                               functools.partial(block, masked=True), buf)
+    o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...][:, :, :1], 1e-20)
+                  ).astype(o_ref.dtype)
+
+
+def paged_prefill(q, k_pool, v_pool, layer, table_row, horizons, page_size,
+                  scale, tile, pages):
+    """q (H, rows, D), a key/value head's query rows (a chunk's tokens x
+    the query heads that share the head); k_pool, v_pool as
+    :func:`paged_attention` takes them; table_row (max_pages,) int32, the
+    slot's pages (every column a page in bounds); horizons (rows,) int,
+    the key rows each query row sees: its own token's and every earlier
+    one's, whatever the caller's rule (no causality is assumed).  ->
+    (H, rows, D) like q: softmax attention of each row over the slot's
+    first ``horizons[row]`` rows of layer ``layer``, clipped to the table.
+    ``tile`` and ``pages``: a head's query rows a grid step and the pages
+    a key block (:func:`prefill_tiling`'s).
+
+    The layer's number is data to the jitted body, as in
+    :func:`paged_attention`: a chunk's layers share one trace and one
+    lowering of the kernel."""
+    return _paged_prefill(
+        q, k_pool, v_pool, jnp.asarray(layer, jnp.int32), table_row,
+        horizons, page_size=page_size, scale=float(scale), tile=tile,
+        pages=pages, full_precision=_full_precision())
+
+
+@functools.partial(jax.jit, static_argnames=("page_size", "scale", "tile",
+                                             "pages", "full_precision"))
+def _paged_prefill(q, k_pool, v_pool, layer, table_row, horizons, *,
+                   page_size, scale, tile, pages, full_precision):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    heads, live, d = q.shape
+    layers, pool_pages = k_pool.shape[:2]
+    max_pages = table_row.shape[0]
+    folded = k_pool.ndim == 4
+    per = _LANES // d if folded else 1
+    steps = -(-live // tile)
+    rows = steps * tile
+    # the columns that complete the last block lie past every horizon:
+    # the layer's trash page, which is in bounds and finite
+    width = -(-max_pages // pages) * pages
+    table = jnp.pad(table_row.astype(jnp.int32), (0, width - max_pages),
+                    constant_values=pool_pages - 1) + layer * pool_pages
+    # rows that complete the last tile see what the last row sees
+    seen = jnp.pad(jnp.clip(horizons.astype(jnp.int32), 0,
+                            max_pages * page_size),
+                   (0, rows - live), mode="edge").reshape(steps, tile)
+    q32 = jnp.pad(q.astype(jnp.float32) * scale,
+                  ((0, 0), (0, rows - live), (0, 0)))
+    if folded:
+        # a step's left operand for a lane tile: its heads' rows of the
+        # step one under the other, each head's zero outside its own
+        # lanes of the tile: (tiles, steps x per x tile, 128)
+        q32 = q32.reshape(heads // per, per, steps, tile, d)
+        q32 = jnp.stack([
+            jnp.pad(q32[:, j],
+                    ((0, 0),) * 3 + ((j * d, _LANES - (j + 1) * d),))
+            for j in range(per)], axis=2)
+        q32 = q32.reshape(heads // per, rows * per, _LANES)
+        # a page as it lies, (rows, H x D): only leading axes merge
+        flat = (layers * pool_pages,) + k_pool.shape[2:]
+    else:
+        # a page as (rows x H, D): only leading axes merge, H whole
+        # sublane tiles
+        flat = (layers * pool_pages, page_size * heads, d)
+    groups = q32.shape[0]
+    operand = per * tile
+    kernel = functools.partial(
+        _prefill_kernel, page_size=page_size, pages=pages,
+        full_precision=full_precision)
+    block = pl.BlockSpec((groups, operand, _LANES), lambda i, *_: (0, i, 0))
+    out = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct(q32.shape, jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(steps,),
+            in_specs=[block,
+                      pl.BlockSpec((operand, 1), lambda i, *_: (i, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=block,
+            scratch_shapes=[
+                pltpu.VMEM((2, pages * flat[1], flat[2]), k_pool.dtype),
+                pltpu.VMEM((2, pages * flat[1], flat[2]), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((groups, operand, _LANES), jnp.float32),
+                pltpu.VMEM((groups, operand, _LANES), jnp.float32),
+                pltpu.VMEM((groups, operand, _LANES), jnp.float32),
+                pltpu.SMEM((1,), jnp.int32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_prefill_vmem_bytes(
+                pages, flat[1] * flat[2] * 4, groups, operand,
+                pages * page_size)),
+        name=prefill_kernel_name(tile, pages, d if folded else 0),
+    )(jnp.max(seen, axis=1), jnp.min(seen, axis=1), table, q32,
+      jnp.tile(seen[:, None], (1, per, 1)).reshape(-1, 1),
+      k_pool.reshape(flat), v_pool.reshape(flat))
+    if folded:   # each head's rows, out of its own lanes of its tile
+        out = out.reshape(heads // per, steps, per, tile, _LANES)
+        out = jnp.stack([out[:, :, j, :, j * d:(j + 1) * d]
+                         for j in range(per)], axis=1).reshape(heads, rows, d)
+    return out[:, :live].astype(q.dtype)

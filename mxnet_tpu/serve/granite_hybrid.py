@@ -46,10 +46,10 @@ reads it.
 from __future__ import annotations
 
 from ..base import MXNetError
-from ..ops.attention import (decode_attention, flash_attention,
-                             paged_decode_attention)
+from ..ops.attention import (flash_attention, paged_decode_attention,
+                             paged_prefill_attention)
 from ..ops.mamba2 import causal_conv, conv_step, ssd_chunked_scan, ssd_step
-from .kv_cache import append_rows, read_context
+from .kv_cache import append_rows
 from .latent_moe import _rms_norm, fold_named, prefill_block, read_named
 from .model import _mm, _resolve_params, check_param_shapes
 # the attention layers run the GPT-2 block's paged reader: its report
@@ -403,8 +403,6 @@ def prefill_forward(params, tokens, length, offset, table_row, pools,
     pages = jnp.where(idx < max_pages,
                       table_row[jnp.clip(idx, 0, max_pages - 1)], trash)
     offsets = abs_pos % page_size
-    kv, hd = cfg.kv_heads, cfg.head_dim
-    group = cfg.num_heads // kv
     block = prefill_block(max_pages, page_size, exact)
     x = _embed(params, tokens[0], cfg)
     ai = mi = 0
@@ -424,15 +422,10 @@ def prefill_forward(params, tokens, length, offset, table_row, pools,
                 q, k, v = _qkv(params, pre, u, cfg, exact)
                 append_rows(pools, "k", ai, pages, offsets, k, "")
                 append_rows(pools, "v", ai, pages, offsets, v, "")
-                ctx_k = read_context(pools["k_pool"], ai, table_row, hd)
-                ctx_v = read_context(pools["v_pool"], ai, table_row, hd)
-                # a key/value head's query heads are its rows: row
-                # t * group + g sees the keys row t sees
-                att = decode_attention(
-                    q.transpose(1, 0, 2, 3).reshape(1, kv, t_b * group, hd),
-                    ctx_k, ctx_v, jnp.repeat(abs_pos + 1, group)[None],
-                    scale=cfg.attention_multiplier, block=block, mi=exact)
-                att = att.reshape(kv, t_b, group * hd).transpose(1, 0, 2)
+                att = paged_prefill_attention(
+                    q, pools["k_pool"], pools["v_pool"], ai, table_row,
+                    abs_pos, page_size, block, mi=exact,
+                    scale=cfg.attention_multiplier)
             out = _mm(att.reshape(t_b, -1), params[pre + "o_weight"], exact)
             ai += 1
         x = x + cfg.residual_multiplier * out

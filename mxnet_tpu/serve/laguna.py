@@ -30,8 +30,8 @@ layer ``l`` has ``H_l = num_attention_heads_per_layer[l]`` query heads over
 
 **What the cache holds.**  A full layer keeps its key/value heads in the
 K/V pools' pages, as the Mamba-2 block's attention layers do: append
-through ``append_rows``, prefill over ``read_context`` with per-row
-horizons, decode through ``paged_decode_attention``.  A window layer keeps,
+through ``append_rows``, prefill through ``paged_prefill_attention`` with
+per-row horizons, decode through ``paged_decode_attention``.  A window layer keeps,
 a slot, a ring of :func:`ring_pages` pages: ``sliding_window`` rows rounded
 up to whole pages, whatever the buckets are (``kv_cache.py`` owns the
 ring's layout; this module writes through ``append_rows`` /
@@ -60,12 +60,12 @@ import functools
 import math
 
 from ..base import MXNetError
-from ..ops.attention import (attend_block, decode_attention,
-                             finalize_attention, flash_attention,
-                             paged_decode_attention)
+from ..ops.attention import (attend_block, finalize_attention,
+                             flash_attention, paged_decode_attention,
+                             paged_prefill_attention)
 from . import latent_moe
 from .kv_cache import (append_rows, fold_into_ring, kv_pool_shape,
-                       read_context, ring_positions)
+                       ring_positions)
 from .latent_moe import (_ffn_held, _head, _head_gate, _resolve, _rms_norm,
                          fold_named, held_range, prefill_block, read_named)
 # the expert layer is the latent block's, and so is what it asks of XLA
@@ -479,7 +479,6 @@ def prefill_forward(params, tokens, length, offset, table_row, pools,
                       table_row[jnp.clip(idx, 0, max_pages - 1)], trash)
     offsets = abs_pos % page_size
     valid = offs < length
-    kv, hd = cfg.kv_heads, cfg.attn_head_dim
     block = prefill_block(max_pages, page_size, exact)
     x = jnp.take(params["tok_embed_weight"], tokens[0].astype(jnp.int32),
                  axis=0)
@@ -488,7 +487,6 @@ def prefill_forward(params, tokens, length, offset, table_row, pools,
     for i, (kind, heads) in enumerate(zip(cfg.layer_types,
                                           layer_heads(cfg))):
         pre = "blk%d_" % i
-        group = heads // kv
         u = _rms_norm(x, params[pre + "attn_norm_gamma"], cfg.rms_norm_eps)
         q, k, v = _qkv(params, pre, u, abs_pos, heads, kind, cfg, exact)
         if kind == "sliding_attention":
@@ -507,15 +505,9 @@ def prefill_forward(params, tokens, length, offset, table_row, pools,
             with jax.named_scope("gqa_prefill"):
                 append_rows(pools, "k", fi, pages, offsets, k, "")
                 append_rows(pools, "v", fi, pages, offsets, v, "")
-                ctx_k = read_context(pools["k_pool"], fi, table_row, hd)
-                ctx_v = read_context(pools["v_pool"], fi, table_row, hd)
-                # a key/value head's query heads are its rows: row
-                # t * group + g sees the keys row t sees
-                att = decode_attention(
-                    q.transpose(1, 0, 2, 3).reshape(1, kv, t_b * group, hd),
-                    ctx_k, ctx_v, jnp.repeat(abs_pos + 1, group)[None],
-                    block=block, mi=exact)
-                att = att.reshape(kv, t_b, group * hd).transpose(1, 0, 2)
+                att = paged_prefill_attention(
+                    q, pools["k_pool"], pools["v_pool"], fi, table_row,
+                    abs_pos, page_size, block, mi=exact)
             fi += 1
         att = _head_gate(params, pre, att.reshape(t_b, -1), u, heads, exact,
                          scope="attn_gate")

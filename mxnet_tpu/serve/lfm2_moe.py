@@ -63,11 +63,11 @@ its convolutions scanned into ``counters["conv_stats"]``
 from __future__ import annotations
 
 from ..base import MXNetError
-from ..ops.attention import (decode_attention, flash_attention,
-                             paged_decode_attention)
+from ..ops.attention import (flash_attention, paged_decode_attention,
+                             paged_prefill_attention)
 from ..ops.mamba2 import causal_conv, conv_step
 from . import latent_moe
-from .kv_cache import append_rows, kv_pool_shape, read_context
+from .kv_cache import append_rows, kv_pool_shape
 from .laguna import ATTN_COLUMNS, MOE_COLUMNS, _rope
 from .latent_moe import (_ffn_held, _resolve, _rms_norm, fold_named,
                          held_range, prefill_block, read_named)
@@ -378,8 +378,6 @@ def prefill_forward(params, tokens, length, offset, table_row, pools,
                       table_row[jnp.clip(idx, 0, max_pages - 1)], trash)
     offsets = abs_pos % page_size
     valid = offs < length
-    kv, hd = cfg.kv_heads, cfg.head_dim
-    group = cfg.num_heads // kv
     block = prefill_block(max_pages, page_size, exact)
     x = _embed(params, tokens[0])
     incs = []
@@ -400,15 +398,9 @@ def prefill_forward(params, tokens, length, offset, table_row, pools,
             with jax.named_scope("gqa_prefill"):
                 append_rows(pools, "k", ai, pages, offsets, k, "")
                 append_rows(pools, "v", ai, pages, offsets, v, "")
-                ctx_k = read_context(pools["k_pool"], ai, table_row, hd)
-                ctx_v = read_context(pools["v_pool"], ai, table_row, hd)
-                # a key/value head's query heads are its rows: row
-                # t * group + g sees the keys row t sees
-                att = decode_attention(
-                    q.transpose(1, 0, 2, 3).reshape(1, kv, t_b * group, hd),
-                    ctx_k, ctx_v, jnp.repeat(abs_pos + 1, group)[None],
-                    scale=_scale(cfg), block=block, mi=exact)
-                att = att.reshape(kv, t_b, group * hd).transpose(1, 0, 2)
+                att = paged_prefill_attention(
+                    q, pools["k_pool"], pools["v_pool"], ai, table_row,
+                    abs_pos, page_size, block, mi=exact, scale=_scale(cfg))
             out = _mm(att.reshape(t_b, -1), params[pre + "o_weight"], exact)
             ai += 1
         x, inc = _ffn_held(params, i, x + out, cfg, exact, valid,

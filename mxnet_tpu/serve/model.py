@@ -42,7 +42,7 @@ import importlib
 
 from ..base import MXNetError, get_env
 from ..ops.attention import (decode_attention, flash_attention,
-                             paged_decode_attention)
+                             paged_decode_attention, paged_prefill_attention)
 from .kv_cache import append_rows, read_context
 
 __all__ = ["ModelConfig", "BLOCKS", "block_of", "exact_mode", "init_params",
@@ -741,6 +741,7 @@ def prefill_forward(params, tokens, length, offset, table_row, pools,
                 ctx_k.transpose(0, 2, 1, 3), ctx_v.transpose(0, 2, 1, 3),
                 row_valid, block=page_size, mi=exact, k_scale=ks,
                 v_scale=vs, window=cfg.sliding_window, k_positions=kp)
+            ctx = att.transpose(0, 2, 1, 3).reshape(1, t_b, cfg.d_model)
             wi += 1
         else:
             # append the chunk's KV at its absolute rows (one vectorized
@@ -749,20 +750,14 @@ def prefill_forward(params, tokens, length, offset, table_row, pools,
                         kv_quant)
             append_rows(pools, "v", fi, pages, offsets, v.reshape(t_b, h, d),
                         kv_quant)
-            ctx_k = read_context(pools["k_pool"], fi, table_row, d)
-            ctx_v = read_context(pools["v_pool"], fi, table_row, d)
-            ks = vs = None
-            if kv_quant:
-                ks = pools["k_scale"][fi, table_row].reshape(
-                    1, max_pages * page_size)
-                vs = pools["v_scale"][fi, table_row].reshape(
-                    1, max_pages * page_size)
-            att = decode_attention(
-                q.reshape(1, t_b, h, d).transpose(0, 2, 1, 3),
-                ctx_k, ctx_v, row_valid, block=page_size, mi=exact,
-                k_scale=ks, v_scale=vs)
+            # one query head a key/value head
+            ctx = paged_prefill_attention(
+                q.reshape(t_b, h, 1, d), pools["k_pool"], pools["v_pool"],
+                fi, table_row, abs_pos, page_size, page_size, mi=exact,
+                k_scale=pools["k_scale"] if kv_quant else None,
+                v_scale=pools["v_scale"] if kv_quant else None,
+            ).reshape(1, t_b, cfg.d_model)
             fi += 1
-        ctx = att.transpose(0, 2, 1, 3).reshape(1, t_b, cfg.d_model)
         out = _mm(ctx, params["blk%d_attn_out_weight" % i], exact) \
             + params["blk%d_attn_out_bias" % i]
         x = x + out

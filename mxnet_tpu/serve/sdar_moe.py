@@ -86,9 +86,10 @@ three.
 from __future__ import annotations
 
 from ..base import MXNetError
-from ..ops.attention import decode_attention, paged_decode_attention
+from ..ops.attention import (decode_attention, paged_decode_attention,
+                             paged_prefill_attention)
 from . import latent_moe
-from .kv_cache import append_rows, kv_pool_shape, read_context
+from .kv_cache import append_rows, kv_pool_shape
 from .laguna import ATTN_COLUMNS, MOE_COLUMNS
 from .latent_moe import (_ffn_held, _head, _resolve, _rms_norm, fold_named,
                          held_range, prefill_block, read_named)
@@ -333,10 +334,8 @@ def prefill_forward(params, tokens, length, offset, table_row, pools,
                       table_row[jnp.clip(idx, 0, max_pages - 1)], trash)
     offsets = abs_pos % page_size
     valid = offs < length
-    kv, hd = cfg.kv_heads, cfg.head_dim
-    group = cfg.num_heads // kv
     scan_block = prefill_block(max_pages, page_size, exact)
-    seen = jnp.repeat(_horizons(abs_pos, cfg), group)[None]
+    seen = _horizons(abs_pos, cfg)
     x = _embed(params, tokens[0])
     incs = []
     for i in range(cfg.num_layers):
@@ -346,15 +345,10 @@ def prefill_forward(params, tokens, length, offset, table_row, pools,
         with jax.named_scope("bdiff_prefill"):
             append_rows(pools, "k", i, pages, offsets, k, "")
             append_rows(pools, "v", i, pages, offsets, v, "")
-            ctx_k = read_context(pools["k_pool"], i, table_row, hd)
-            ctx_v = read_context(pools["v_pool"], i, table_row, hd)
-            # a key/value head's query heads are its rows: row t * group
-            # + g sees the keys row t sees
-            att = decode_attention(
-                q.transpose(1, 0, 2, 3).reshape(1, kv, t_b * group, hd),
-                ctx_k, ctx_v, seen, scale=_scale(cfg), block=scan_block,
-                mi=exact)
-            att = att.reshape(kv, t_b, group * hd).transpose(1, 0, 2)
+            att = paged_prefill_attention(
+                q, pools["k_pool"], pools["v_pool"], i, table_row, abs_pos,
+                page_size, scan_block, mi=exact, scale=_scale(cfg),
+                horizons=seen)
         out = _mm(att.reshape(t_b, -1), params[pre + "o_weight"], exact)
         x, inc = _ffn_held(params, i, x + out, cfg, exact, valid,
                            dequantized)

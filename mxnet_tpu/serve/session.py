@@ -996,17 +996,34 @@ class InferenceSession(object):
         return NO_TOKEN, None
 
     def _count_chunk(self, offset, bucket):
-        """One prefill chunk's share of ``prefill_report()``: in every
-        layer that reads the slot's page table the attention scan
-        (:func:`~mxnet_tpu.ops.attention.decode_attention`) visits whole
-        key blocks up to the chunk's furthest horizon, bucket padding
-        included, of the table's capacity."""
+        """One prefill chunk's share of ``prefill_report()``, for the
+        reader its executable was traced with
+        (:func:`~mxnet_tpu.ops.attention.paged_prefill_attention`).  The
+        scan: in every layer that reads the slot's page table it visits
+        whole key blocks up to the chunk's furthest horizon, bucket
+        padding included, of the table's capacity.  The kernel: each tile
+        of query rows walks whole key blocks up to its own last row's
+        horizon (the row's token's position + 1); a layer counts the mean
+        over the chunk's tiles."""
         stats, block = self._prefill_stats, self._scan_block
         capacity = self.cache.table_width * self.config.page_size
+        traced = self._exes["prefill_%d" % bucket].traced
+        by_kernel = traced.get("prefill_kernel_layers", 0)
         stats["chunks"] += 1
-        stats["rows_visited"] += self.cache.n_full * min(
-            -(-(offset + bucket) // block) * block, capacity)
         stats["rows_capacity"] += self.cache.n_full * capacity
+        stats["rows_visited"] += (self.cache.n_full - by_kernel) * min(
+            -(-(offset + bucket) // block) * block, capacity)
+        if by_kernel:
+            # the notes are sums over the layers traced with the kernel
+            tile, heads, keys = (traced["prefill_kernel_" + name] // by_kernel
+                                 for name in ("tile_rows", "query_heads",
+                                              "block_keys"))
+            rows = bucket * heads
+            # a tile's last row r belongs to token r // heads
+            walked = [min(-(-min(offset + (min(end, rows) - 1) // heads + 1,
+                                 capacity) // keys) * keys, capacity)
+                      for end in range(tile, rows + tile, tile)]
+            stats["rows_visited"] += by_kernel * sum(walked) / len(walked)
 
     def _draft_ingest(self, slot, prompt):
         """Teacher-force the prompt through the draft executable in
@@ -1321,22 +1338,37 @@ class InferenceSession(object):
     def prefill_report(self):
         """How much of the slots' page tables the prefill chunks' attention
         had to read, counted on the host where a chunk is launched (no
-        device read, no chunk pays for it): ``chunks`` since the session
+        device read, no chunk pays for it), for the reader that was
+        traced: ``prefill_kernel_layers`` says which, the layers of the
+        largest bucket's executable whose
+        :func:`~mxnet_tpu.ops.attention.paged_prefill_attention` is the
+        Pallas kernel (every full-attention layer on a TPU where the call
+        is eligible, ``ops/paged_attention.py:paged_prefill_eligible``, and
+        the same in every bucket's executable; 0 on the CPU, under
+        ``exact`` / ``kv_quant``, and for folded pools under a short
+        table, where the bounded scan runs).  ``chunks`` since the session
         was built; ``rows_visited`` the sum, over them and over the layers
         that read a page table (full attention, latent), of the key rows
-        up to the chunk's furthest horizon ``offset + bucket``, rounded up
-        to the block's scan block (its ``prefill_block``) and clipped to
-        the table, which is where the loop of
-        :func:`~mxnet_tpu.ops.attention.decode_attention` ends;
+        the reader visits.  The scan
+        (:func:`~mxnet_tpu.ops.attention.decode_attention`): up to the
+        chunk's furthest horizon ``offset + bucket``, rounded up to the
+        block's scan block (its ``prefill_block``) and clipped to the
+        table, an ``int``.  The kernel: the mean over the chunk's tiles of
+        query rows of what a tile walks, whole key blocks up to its own
+        furthest horizon, a ``float``: a chunk at offset 0 counts about
+        half its bucket, where the scan counts the bucket.
         ``rows_capacity`` = chunks x layers x the table's rows, what a
         scan of the whole table visits, and ``visited_share`` their
-        ratio.  (The whole table is still *gathered* in front of the
-        scan; a window layer's ring and a recurrent layer's state are
-        not tables and count nothing.)"""
+        ratio.  (Under the scan the whole table is still *gathered* in
+        front of it; a window layer's ring and a recurrent layer's state
+        are not tables and count nothing.)"""
         rep = dict(self._prefill_stats)
         rep["visited_share"] = (
             rep["rows_visited"] / float(rep["rows_capacity"])
             if rep["rows_capacity"] else 0.0)
+        rep["prefill_kernel_layers"] = self._exes[
+            "prefill_%d" % max(self.config.buckets)].traced.get(
+                "prefill_kernel_layers", 0)
         return rep
 
     def block_report(self):

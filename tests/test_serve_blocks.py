@@ -162,7 +162,7 @@ def test_prefill_report_counts_rows_to_the_chunks_horizon(name):
     assert sess.block.prefill_block(3, 8, sess.config.exact) == block
     assert sess.prefill_report() == {
         "chunks": 0, "rows_visited": 0, "rows_capacity": 0,
-        "visited_share": 0.0}
+        "visited_share": 0.0, "prefill_kernel_layers": 0}
     for seed, n in enumerate((5, 13)):
         slot = sess.try_alloc(n, 4)
         sess.prefill(slot, np.random.default_rng(seed).integers(
@@ -171,10 +171,66 @@ def test_prefill_report_counts_rows_to_the_chunks_horizon(name):
     assert sess.prefill_report() == {
         "chunks": 2, "rows_visited": visited,
         "rows_capacity": layers * 2 * 24,
-        "visited_share": visited / (layers * 2 * 24.0)}
+        "visited_share": visited / (layers * 2 * 24.0),
+        "prefill_kernel_layers": 0}     # the CPU runs the scan
     assert visited == (layers * 24 if block == 8 else layers * 48)
+    assert type(sess.prefill_report()["rows_visited"]) is int
     sess.step()         # a decode step is no chunk
     assert sess.prefill_report()["chunks"] == 2
+
+
+@pytest.mark.parametrize("name, by_kernel, tile, heads", [
+    ("dense", 3, 8, 1), ("dense_one_full_layer", 1, 4, 1),
+    ("lfm2", 1, 8, 2), ("sdar", 2, 24, 4), ("sdar", 1, 8, 4)],
+    ids=["dense", "one_full_layer", "lfm2", "sdar", "sdar_one_of_two"])
+def test_prefill_report_counts_for_the_reader_that_was_traced(
+        monkeypatch, name, by_kernel, tile, heads):
+    """``prefill_kernel_layers`` and the kernel's tiling are what a
+    bucket's executable noted while it was traced
+    (``ops/attention.py:paged_prefill_attention``; nothing on the CPU).
+    With them the session counts, for each of those layers, the mean over
+    the chunk's tiles of query rows of the key blocks a tile walks to its
+    own last row's horizon, a float; the other layers count the scan's.
+    The counts are host arithmetic: the notes alone switch them, the
+    executables are the CPU's scan.  Here a key block of 8 rows under a
+    table of 24, a prompt of 5 in bucket 8 and one of 13 in bucket 16."""
+    model, settings, layers, block = PREFILL_SCANS[name]
+    sess = serve.InferenceSession(
+        serve.init_params(model, seed=5), model=model,
+        config=serve.ServeConfig(**dict(CONF, **settings)))
+    notes = {"prefill_kernel_layers": by_kernel,
+             "prefill_kernel_tile_rows": by_kernel * tile,
+             "prefill_kernel_query_heads": by_kernel * heads,
+             "prefill_kernel_block_keys": by_kernel * 8}
+    for bucket in (8, 16):
+        exe = sess._exes["prefill_%d" % bucket]
+        monkeypatch.setattr(exe, "traced", dict(exe.traced, **notes))
+    for seed, n in enumerate((5, 13)):
+        slot = sess.try_alloc(n, 4)
+        sess.prefill(slot, np.random.default_rng(seed).integers(
+            0, model.vocab_size, n).tolist())
+    rep = sess.prefill_report()
+    assert rep["prefill_kernel_layers"] == by_kernel and rep["chunks"] == 2
+
+    def walked(bucket):
+        """The mean over a chunk's tiles, from offset 0, of the rows up to
+        the tile's last token's horizon in whole blocks of 8."""
+        ends = [min(end, bucket * heads) for end in
+                range(tile, bucket * heads + tile, tile)]
+        return sum(-(-((end - 1) // heads + 1) // 8) * 8
+                   for end in ends) / len(ends)
+
+    # bucket 8 is one block whatever the tile; bucket 16 in tiles of 8
+    # rows of one head walks 8 then 16, of 4 tokens x 2 heads 8, 8, 16, 16
+    assert walked(8) == 8 and walked(16) == {
+        (8, 1): 12, (4, 1): 12, (8, 2): 12, (24, 4): 40 / 3,
+        (8, 4): 12}[tile, heads]
+    assert rep["rows_visited"] == pytest.approx(
+        by_kernel * (walked(8) + walked(16))
+        + (layers - by_kernel) * (8 + 16), rel=1e-12)
+    assert type(rep["rows_visited"]) is float
+    assert rep["rows_visited"] < layers * (8 + 16)     # the scan's count
+    assert rep["visited_share"] == rep["rows_visited"] / rep["rows_capacity"]
 
 
 VARIANTS = {

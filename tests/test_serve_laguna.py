@@ -40,6 +40,7 @@ import pytest
 
 from mxnet_tpu import serve
 from mxnet_tpu.base import MXNetError
+from mxnet_tpu.ops import attention as ops_attention
 from mxnet_tpu.serve import kv_cache, laguna, latent_moe
 from mxnet_tpu.serve import model as serve_model
 from mxnet_tpu.serve.scheduler import Request, Scheduler
@@ -406,14 +407,15 @@ def test_a_fresh_prompt_in_chunks_is_the_prompt_in_one_bucket(params, plain):
         plain.try_alloc(65, 3)
 
 
+_bounded_scan = ops_attention.decode_attention
+
+
 def _whole_walk(q, k_ctx, v_ctx, lengths, **kw):
     """``decode_attention`` as it was before the scan had a bound: rows
     labelled with their own index carry the same mask, and a call with
     ``k_positions`` walks every block of the table."""
-    from mxnet_tpu.ops.attention import decode_attention
-
     rows = jnp.arange(k_ctx.shape[-2], dtype=jnp.int32)
-    return decode_attention(
+    return _bounded_scan(
         q, k_ctx, v_ctx, lengths,
         k_positions=jnp.broadcast_to(rows, (q.shape[0], rows.shape[0])),
         **kw)
@@ -446,7 +448,8 @@ def test_a_chunks_scan_ends_at_its_horizon_and_changes_no_bit(
     seq = tokens(77, n)
     sess = session(params, cfg, **conf)
     with monkeypatch.context() as patch:
-        patch.setattr(laguna, "decode_attention", _whole_walk)
+        # the scan ``paged_prefill_attention`` falls back to on the CPU
+        patch.setattr(ops_attention, "decode_attention", _whole_walk)
         whole = session(params, cfg, **conf)
     got, want = [], []
     for s, out in ((sess, got), (whole, want)):
@@ -470,7 +473,8 @@ def test_a_chunks_scan_ends_at_its_horizon_and_changes_no_bit(
     # 2 full layers; the 3 window layers' rings are no tables
     assert rep == {"chunks": 3, "rows_visited": 2 * sum(visited),
                    "rows_capacity": 2 * 3 * rows,
-                   "visited_share": sum(visited) / (3.0 * rows)}
+                   "visited_share": sum(visited) / (3.0 * rows),
+                   "prefill_kernel_layers": 0}     # the CPU runs the scan
     assert rep["rows_visited"] < rep["rows_capacity"]
     assert whole.prefill_report() == rep    # the host counts the same
     assert sorted(sess.executables) == sorted(whole.executables)

@@ -22,7 +22,10 @@ axis, the Mosaic module letter for letter what it was before there were
 two), which must leave the pools where they lie; and such a layer's
 prefill half at the four cells' pools and both buckets of each (the
 chunk's rows appended, ``read_context``'s gather of the slot's table, the
-bounded scan), which must hold no copy of a pool's layer; and the dense
+bounded scan), which must hold no copy of a pool's layer, and through the
+reader the blocks call (``paged_prefill_attention``: the prefill kernel
+where the call is eligible, which holds no gathered context either, the
+gather and the scan letter for letter where it is not); and the dense
 block's whole decode step at Cerebras-GPT-1.3B's widths with the kernel
 in; and a latent-attention
 layer's append to and read of the latent pool at kanana-2's and
@@ -851,15 +854,18 @@ def test_lfm2_executables_compile_for_v5e_at_the_published_widths(
     layers read their folded pools through the paged-attention kernel's
     folded form (one lowering, three calls): no loop under
     ``gqa_decode``, no slice of a pool's layer.  Nor does a prefill chunk
-    hold one in front of ``read_context``'s gathers (until PR 49 six
-    float32 copies of a 1.21 GB layer a chunk, layer 0's two at bucket
-    2048 as plain slices of the pool laid out as rows, and 1.27-1.32 GB of
-    temporaries where there are now 0.06 and 0.25)."""
+    hold one (until PR 49 six float32 copies of a 1.21 GB layer a chunk in
+    front of ``read_context``'s gathers, layer 0's two at bucket 2048 as
+    plain slices of the pool laid out as rows, and 1.27-1.32 GB of
+    temporaries where there were then 0.06 and 0.25), nor since PR 51 the
+    gathers or the scan: its three attention layers read their pages
+    through the prefill kernel (one lowering, three calls)."""
     from mxnet_tpu.ops.grouped_matmul import kernel_name
 
     compiled, shapes, notes = _lfm2_program(one_chip, monkeypatch, bucket)
-    assert notes == dict({"expert_kernel_layers": 12},
-                         **({} if bucket else {"paged_kernel_layers": 3}))
+    assert notes == dict({"expert_kernel_layers": 12}, **(
+        _prefill_notes(3, 512, 4) if bucket
+        else {"paged_kernel_layers": 3}))
     text = compiled.as_text()
     assert len(_kernel_calls(text, kernel_name(tile))) == 12
     memory = compiled.memory_analysis()
@@ -879,7 +885,11 @@ def test_lfm2_executables_compile_for_v5e_at_the_published_widths(
         _decode_reads_by_kernel(text, shapes["k_pool"], "gqa_decode", 3,
                                 folded_head=64)
     else:
-        assert not _layer_slices(text, shapes["k_pool"])
+        # two heads of 64 a lane tile, 512 rows each: a left operand of
+        # 1 024 rows, 64 pages a key block
+        _prefill_reads_by_kernel(text, shapes["k_pool"], "gqa_prefill", 3,
+                                 (1, 8, LFM2_TABLE * 16, 64), 512,
+                                 folded_head=64)
 
 
 # ``sdar-30b-l12-chat``'s executables at SDAR-30B-A3B-Chat's published
@@ -972,8 +982,9 @@ def test_sdar_executables_compile_for_v5e_at_the_published_widths(
     from mxnet_tpu.ops.grouped_matmul import kernel_name
 
     compiled, shapes, notes = _sdar_program(one_chip, monkeypatch, bucket)
-    assert notes.get("expert_kernel_layers") == 12
-    assert notes.get("paged_kernel_layers", 0) == (0 if bucket else 12)
+    assert notes == dict({"expert_kernel_layers": 12}, **(
+        _prefill_notes(12, 1024, 8) if bucket
+        else {"paged_kernel_layers": 12}))
     text = compiled.as_text()
     # a prefill yields no token: what the last layer's attention and
     # experts would add to x nobody reads, and the compiler drops both
@@ -995,6 +1006,13 @@ def test_sdar_executables_compile_for_v5e_at_the_published_widths(
             text, paged_attention.kernel_name(32, 128))) >= 1
         assert not re.findall(r" while\([^\n]*op_name=\"[^\"]*bdiff_pass",
                               text)
+    else:
+        # a chunk's layers read their pages through the prefill kernel,
+        # one head of 128 a lane tile with 1 024 of its 16 384 rows a step
+        # (the last layer's too: its router's counts read what it gives)
+        _prefill_reads_by_kernel(text, shapes["k_pool"], "bdiff_prefill",
+                                 12, (1, 4, SDAR_TABLE * 16, 128), 1024,
+                                 folded_head=128)
 
 
 def _decode_reads_by_kernel(text, pool_shape, scope, layers, folded_head=0):
@@ -1014,6 +1032,46 @@ def _decode_reads_by_kernel(text, pool_shape, scope, layers, folded_head=0):
                           % scope, text)
     assert not _layer_slices(text, pool_shape), _layer_slices(text,
                                                               pool_shape)
+
+
+def _prefill_notes(layers, tile, group, pages=64):
+    """What ``paged_prefill_attention`` notes in a trace whose ``layers``
+    full-attention layers all took the kernel at ``tile`` query rows of a
+    head a step, ``group`` query heads a key/value head and ``pages``
+    pages of 16 keys a block: the sums ``prefill_report()`` counts
+    from."""
+    return {"prefill_kernel_layers": layers,
+            "prefill_kernel_tile_rows": layers * tile,
+            "prefill_kernel_query_heads": layers * group,
+            "prefill_kernel_block_keys": layers * pages * 16}
+
+
+def _prefill_reads_by_kernel(text, pool_shape, scope, layers, context, tile,
+                             folded_head=0, pages=64):
+    """A prefill executable's compiled text holds ``layers`` prefill
+    kernels in the form for its pools' layout at ``tile`` query rows a
+    step and ``pages`` pages a key block, no ``while`` that the trace put under
+    ``scope`` (the bounded scan's), no slice of a pool's layer and no
+    gathered context (``context``: the slot's whole table as
+    ``read_context`` hands it to the scan, (1, H, rows, D), or as its
+    gather and reshape leave it on the way there)."""
+    from mxnet_tpu.ops import paged_attention
+
+    name = paged_attention.prefill_kernel_name(tile, pages, folded_head)
+    assert len(_kernel_calls(text, name)) == layers
+    assert len(re.findall(r"%paged_prefill_attention_\w+[.\d]* = ", text)) \
+        == layers
+    assert not re.findall(r" while\([^\n]*op_name=\"[^\"]*%s[^\"]*\""
+                          % scope, text)
+    assert not _layer_slices(text, pool_shape), _layer_slices(text,
+                                                              pool_shape)
+    _, heads, rows, d = context
+    shapes = ("1,%d,%d,%d" % (heads, rows, d), "1,%d,%d,%d" % (rows, heads, d),
+              "%d,16,%d,%d" % (rows // 16, heads, d),
+              "%d,16,%d" % (rows // 16, heads * d))
+    found = [line.strip()[:140] for line in text.splitlines()
+             if re.search(r"= f32\[(%s)\]" % "|".join(shapes), line)]
+    assert not found, found
 
 
 def test_dense_decode_step_compiles_for_v5e_with_the_kernel_in(one_chip,
@@ -1085,13 +1143,13 @@ def test_laguna_executables_compile_for_v5e_at_the_published_widths(
     the donated pools and rings are updated where they lie: the result
     aliases all four, and no operation copies a whole pool or a whole
     ring into another layout, nor (since PR 49) one layer of a pool in
-    front of a prefill chunk's gathers: the temporaries stay under one
-    (0.87 GB)."""
+    a prefill chunk: the temporaries stay under one (0.87 GB)."""
     from mxnet_tpu.ops.grouped_matmul import kernel_name
 
     compiled, shapes, notes = _laguna_program(one_chip, monkeypatch, bucket)
-    assert notes == dict({"expert_kernel_layers": 4},
-                         **({} if bucket else {"paged_kernel_layers": 2}))
+    assert notes == dict({"expert_kernel_layers": 4}, **(
+        _prefill_notes(2, 512, 6) if bucket
+        else {"paged_kernel_layers": 2}))
     text = compiled.as_text()
     assert len(_kernel_calls(text, kernel_name(tile))) == 4
     memory = compiled.memory_analysis()
@@ -1108,21 +1166,12 @@ def test_laguna_executables_compile_for_v5e_at_the_published_widths(
         # the whole pools: no loop under their scope, no layer sliced out
         _decode_reads_by_kernel(text, shapes["k_pool"], "gqa_decode", 2)
     if bucket:
-        assert not _layer_slices(text, shapes["k_pool"])
-        # one loop a full layer over the gathered table's 26 blocks of 512
-        # keys, and its trip count is data (the chunk's furthest horizon):
-        # the condition compares the counter with an element of the carry,
-        # where a walk of every block compared it with constant(26); and
-        # the blocks are sliced out of the context, not stacked beside it
-        conditions = _loop_conditions(text, "gqa_prefill")
-        assert len(conditions) == 2
-        for cond in conditions:
-            assert "constant(" not in cond, cond
-            assert re.search(r"ROOT \S+ = pred\S* compare\(%get-tuple-element"
-                             r"\S+ %get-tuple-element", cond), cond
-        assert LAGUNA_TABLE * 16 // 512 == 26
-        assert "f32[26,1,8,512,128]" not in text
-        assert "f32[1,8,13312,128]" in text
+        # the two full layers read their pages through the prefill kernel,
+        # 512 of a head's 12 288 (or 3 072) rows a step: no loop under
+        # their scope (until PR 51 one a layer over the gathered table's
+        # 26 blocks of 512 keys), no gathered table
+        _prefill_reads_by_kernel(text, shapes["k_pool"], "gqa_prefill", 2,
+                                 (1, 8, LAGUNA_TABLE * 16, 128), 512)
 
 
 # A grouped-query (or dense) attention layer's prefill half at the four
@@ -1130,7 +1179,8 @@ def test_laguna_executables_compile_for_v5e_at_the_published_widths(
 # the donated K and V pools at the slot's pages, ``kv_cache.read_context``
 # of the slot's whole table, ``decode_attention`` over it in the block's
 # key blocks with a horizon a row.  What is compiled is what the blocks'
-# ``prefill_forward`` runs a layer under ``gqa_prefill`` (``serve/model.py``
+# ``prefill_forward`` ran a layer under ``gqa_prefill`` until PR 51, and
+# what their reader still runs where its kernel does not (``serve/model.py``
 # with one query head a key/value head), without its weights, at a layer
 # past the first (layer 0 starts at the pool's first byte: in a program
 # this small its slice is a bitcast, whatever the reader).
@@ -1152,22 +1202,36 @@ def _layer_first(pool, layer, tables, head_dim):
     ).transpose(0, 2, 1, 3)
 
 
-def _prefill_layer_program(one_chip, name, bucket, read=None):
-    """-> the compiled append + whole-table read + scan of one prefill
-    chunk of ``bucket`` rows over two donated float32 pools at the cell's
-    shape, through ``read`` (the cache's own reader unless given), and the
-    pools' shape."""
-    from mxnet_tpu.ops.attention import decode_attention
+# The same layer through the reader the blocks call since PR 51
+# (``ops/attention.py:paged_prefill_attention``) as a TPU traces it: the
+# prefill kernel where the call is eligible, the gather and the scan above
+# where it is not.
+READER_CASES = dict(PREFILL_CASES,
+                    sdar=(4, 128, 8, 12, SDAR_TABLE, SDAR_SLOTS, (512, 2048)))
+
+
+def _prefill_reader_lowered(one_chip, monkeypatch, name, bucket, by_reader,
+                            exact=False, kv_quant=False, read=None):
+    """-> the lowered append + read of one prefill chunk of ``bucket``
+    rows at the cell's pools: through the reader as a TPU traces it
+    (``by_reader``), or as the blocks wrote the gather and the scan out
+    until PR 51, the gather through ``read`` (the cache's own
+    ``read_context`` unless given); the pools' shape; the notes of the
+    trace."""
+    from mxnet_tpu.ops.attention import (decode_attention,
+                                         paged_prefill_attention)
     from mxnet_tpu.serve import kv_cache, latent_moe
     from mxnet_tpu.serve import model as serve_model
 
-    heads, head_dim, group, layers, max_pages, slots, _ = PREFILL_CASES[name]
+    heads, head_dim, group, layers, max_pages, slots, _ = READER_CASES[name]
     page, layer = 16, 1
-    read = read or kv_cache.read_context
     block = (serve_model if name == "dense" else latent_moe).prefill_block(
-        max_pages, page, False)
+        max_pages, page, exact)
     shape = kv_cache.kv_pool_shape(layers, slots * max_pages + 1, page,
                                    heads, head_dim)
+    read = read or kv_cache.read_context
+    if by_reader:
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
     def sds(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -1176,24 +1240,50 @@ def _prefill_layer_program(one_chip, name, bucket, read=None):
         pools = dict(pools)
         abs_pos = offset + jnp.arange(bucket, dtype=jnp.int32)
         pages, offsets = table_row[abs_pos // page], abs_pos % page
-        kv_cache.append_rows(pools, "k", layer, pages, offsets, k)
-        kv_cache.append_rows(pools, "v", layer, pages, offsets, v)
+        quant = "int8" if kv_quant else ""
+        kv_cache.append_rows(pools, "k", layer, pages, offsets, k, quant)
+        kv_cache.append_rows(pools, "v", layer, pages, offsets, v, quant)
+        with jax.named_scope("gqa_prefill"):
+            return pools, attend(pools, q, table_row, abs_pos)
+
+    def attend(pools, q, table_row, abs_pos):
+        ks, vs = pools.get("k_scale"), pools.get("v_scale")
+        if by_reader:
+            return paged_prefill_attention(
+                q, pools["k_pool"], pools["v_pool"], layer, table_row,
+                abs_pos, page, block, mi=exact, k_scale=ks, v_scale=vs)
         ctx_k = read(pools["k_pool"], layer, table_row, head_dim)
         ctx_v = read(pools["v_pool"], layer, table_row, head_dim)
+        if kv_quant:
+            ks = ks[layer, table_row].reshape(1, max_pages * page)
+            vs = vs[layer, table_row].reshape(1, max_pages * page)
         att = decode_attention(
             q.transpose(1, 0, 2, 3).reshape(1, heads, bucket * group,
                                             head_dim),
-            ctx_k, ctx_v, jnp.repeat(abs_pos + 1, group)[None], block=block)
-        return pools, att
+            ctx_k, ctx_v, jnp.repeat(abs_pos + 1, group)[None], block=block,
+            mi=exact, k_scale=ks, v_scale=vs)
+        return att.reshape(heads, bucket, group * head_dim).transpose(1, 0, 2)
 
-    pool = sds(shape)
+    pool = sds(shape, jnp.int8 if kv_quant else jnp.float32)
+    pools = {"k_pool": pool, "v_pool": pool}
+    if kv_quant:
+        pools.update(k_scale=sds(shape[:3]), v_scale=sds(shape[:3]))
     rows = sds((bucket, heads, head_dim))
-    with jax.default_matmul_precision("default"):    # as the cells trace it
-        compiled = jax.jit(chunk, donate_argnums=0).lower(
-            {"k_pool": pool, "v_pool": pool},
-            sds((bucket, heads, group, head_dim)), rows, rows,
-            sds((max_pages,), jnp.int32), sds((), jnp.int32)).compile()
-    return compiled, shape
+    with jax.default_matmul_precision("default"), \
+            serve_model.trace_notes() as notes:
+        lowered = jax.jit(chunk, donate_argnums=0).lower(
+            pools, sds((bucket, heads, group, head_dim)), rows, rows,
+            sds((max_pages,), jnp.int32), sds((), jnp.int32))
+    return lowered, shape, notes
+
+
+def _prefill_layer_program(one_chip, name, bucket, read=None):
+    """-> the compiled append + whole-table read + scan of one prefill
+    chunk of ``bucket`` rows over two donated float32 pools at the cell's
+    shape, through ``read``, and the pools' shape."""
+    lowered, shape, _ = _prefill_reader_lowered(one_chip, None, name, bucket,
+                                                False, read=read)
+    return lowered.compile(), shape
 
 
 @pytest.mark.parametrize("large", [False, True], ids=["small", "large"])
@@ -1236,3 +1326,104 @@ def test_a_layer_sliced_in_front_of_a_prefills_gather_is_found(one_chip,
     copy = 2 * math.prod(shape[1:])
     if copy > 128 << 20:
         assert compiled.memory_analysis().temp_size_in_bytes >= copy
+
+
+@pytest.mark.parametrize("name, large, tile, pages", [
+    ("dense", False, 128, 32), ("dense", True, 256, 32),
+    ("sdar", False, 1024, 64), ("laguna", False, 512, 64),
+    ("lfm2", True, 512, 64)],
+    ids=["dense-128", "dense-512", "sdar-512", "laguna-512", "lfm2-2048"])
+def test_a_prefill_chunk_reads_its_pages_through_the_kernel(
+        one_chip, monkeypatch, name, large, tile, pages):
+    """Where the call is eligible a chunk's attention is ONE kernel, named
+    for the pools' layout, its tile of query rows and its key block, over
+    the pools whole: the compiled text holds no loop, no slice of a pool's
+    layer, no gathered context and next to no temporaries, and Mosaic
+    takes the kernel's blocks within the ``vmem_limit_bytes`` it states
+    (the compile is the check), under the chip's 128 MiB.  The whole
+    prefill executables of laguna, LFM2 and SDAR above hold the other
+    buckets of those cells."""
+    from mxnet_tpu.ops import paged_attention
+
+    heads, head_dim, group, _, max_pages, _, buckets = READER_CASES[name]
+    bucket = buckets[large]
+    lowered, shape, notes = _prefill_reader_lowered(
+        one_chip, monkeypatch, name, bucket, by_reader=True)
+    assert notes == _prefill_notes(1, tile, group, pages)
+    folded = len(shape) == 4
+    assert (tile, pages) == paged_attention.prefill_tiling(
+        bucket * group, head_dim, folded, heads, 16, max_pages)
+    (module,) = _mosaic_modules(lowered.as_text())
+    kernel = paged_attention.prefill_kernel_name(
+        tile, pages, head_dim if folded else 0)
+    assert "module @%s " % kernel in module
+    # the kernel keeps its loops over a block's pages and over the heads
+    # rolled: one head's work on a block in two forms (masked or not), a
+    # copy's start in three places and its wait in two, whatever the
+    # pages a block and the heads (written out, a block of 64 pages was
+    # 640 copies and sixteen heads 64 matmuls: seconds of a session's
+    # start a bucket and megabytes of code a layer, PERF.md, PR 51)
+    assert module.count("tpu.matmul") == 4
+    assert module.count("tpu.enqueue_dma") == 6
+    assert module.count("tpu.wait_dma") == 4
+    compiled = lowered.compile()
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    assert memory.generated_code_size_in_bytes < 3 << 20
+    assert text.count("tpu_custom_call") == 1
+    assert not re.findall(r" while\(", text)
+    _prefill_reads_by_kernel(text, shape, "", 1,
+                             (1, heads, max_pages * 16, head_dim), tile,
+                             folded_head=head_dim if folded else 0,
+                             pages=pages)
+    assert not _whole_pool_copies(text, shape)[0]
+    assert memory.alias_size_in_bytes >= 2 * 4 * math.prod(shape)
+    # the query's and the result's copies in the kernel's layout (a folded
+    # pool's take a lane tile a head): a few times the query's size
+    query = 4 * bucket * heads * group * 128
+    assert memory.temp_size_in_bytes < 6 * query + (1 << 20)
+    per = 128 // head_dim if folded else 1
+    assert paged_attention._prefill_vmem_bytes(
+        pages, math.prod(shape[2:]) * 4, heads // per, per * tile,
+        pages * 16) < 64 << 20
+
+
+@pytest.mark.parametrize("name, exact, kv_quant", [
+    ("granite", False, False), ("lfm2", True, False), ("dense", True, False),
+    ("dense", False, True), ("laguna", True, False)],
+    ids=["granite", "lfm2-exact", "dense-exact", "dense-kv_int8",
+         "laguna-exact"])
+def test_a_refused_prefill_lowers_to_the_gather_and_the_scan_letter_for_letter(
+        one_chip, monkeypatch, name, exact, kv_quant):
+    """What keeps the scan on a TPU: granite-4.0-h-micro's folded pools
+    under a table of 768 keys, and every call under ``exact`` or
+    ``kv_quant``.  Through the reader such a chunk lowers to the text it
+    lowered to when the blocks wrote the gather and the scan out
+    themselves, letter for letter, so the executable is the one the
+    compile cache holds; it notes no kernel, and the scan's trip count is
+    still data (PR 43)."""
+    bucket = READER_CASES[name][-1][1]
+    lowered, _, notes = _prefill_reader_lowered(
+        one_chip, monkeypatch, name, bucket, True, exact, kv_quant)
+    was, _, _ = _prefill_reader_lowered(
+        one_chip, monkeypatch, name, bucket, False, exact, kv_quant)
+    assert notes == {}
+    text = lowered.as_text()
+    assert text == was.as_text()
+    assert "tpu_custom_call" not in text and "stablehlo.while" in text
+    if name == "granite":
+        compiled = lowered.compile().as_text()
+        (cond,) = _loop_conditions(compiled, "gqa_prefill")
+        assert "constant(" not in cond, cond
+
+
+def test_the_latent_blocks_prefill_keeps_calling_the_scan():
+    """kanana-2's and Ling-3.0-flash's prefill read a latent context, not
+    K/V pages: ``latent_moe.py`` calls ``decode_attention`` itself and
+    knows nothing of the reader, so its executables cannot have moved."""
+    import inspect
+
+    from mxnet_tpu.serve import bailing_hybrid, latent_moe
+
+    assert "decode_attention(" in inspect.getsource(latent_moe)
+    for module in (latent_moe, bailing_hybrid):
+        assert "paged_prefill_attention" not in inspect.getsource(module)
