@@ -27,8 +27,11 @@ batch), under the names ``docs/performance.md`` ("Spans") lists.  A span
 is live while a profiler session runs, whoever started it, or after
 ``record_spans(True)``; it is then written into the running trace as
 ``mx:<name>`` (on the device trace's clock) and kept in memory on
-``time.perf_counter()`` for :func:`spans`.  Otherwise a span site costs
-one ``is_enabled()`` check.
+``time.perf_counter()`` for :func:`spans`.  A :func:`cpu_span` keeps the
+calling thread's CPU time over its stretch beside the wall time
+(``cpu_s``: the thread *ran* that long and *waited*, off the core, for
+the rest).  Otherwise a span site costs one ``is_enabled()`` check and
+reads no clock.
 """
 from __future__ import annotations
 
@@ -49,7 +52,7 @@ from .base import MXNetError, get_env
 __all__ = ["profiler_set_config", "profiler_set_state", "set_config",
            "set_state", "dump", "dump_profile", "state",
            "compile_event", "compile_events", "total_compile_s",
-           "span", "spans", "clear_spans", "spans_dropped",
+           "span", "cpu_span", "spans", "clear_spans", "spans_dropped",
            "record_spans", "SpanRecord", "SPAN_PREFIX", "SPAN_CAPACITY"]
 
 _config = {"filename": "profile.json", "profile_all": False}
@@ -159,9 +162,12 @@ SPAN_PREFIX = "mx:"
 SPAN_CAPACITY = 1 << 18
 
 SpanRecord = collections.namedtuple(
-    "SpanRecord", "id parent name start_s end_s attrs")
+    "SpanRecord", "id parent name start_s end_s attrs cpu_s",
+    defaults=(None,))
 
 _perf_counter = _time.perf_counter   # the clock of Scheduler.now
+# CPU seconds of the calling thread; None on a platform without the clock
+_thread_time = getattr(_time, "thread_time", lambda: None)
 _span_records = collections.deque(maxlen=SPAN_CAPACITY)
 _span_lock = threading.Lock()
 _span_ids = itertools.count(1)
@@ -189,13 +195,15 @@ _NO_SPAN = _NoSpan()
 
 
 class _Span(object):
-    __slots__ = ("id", "parent", "name", "start_s", "attrs", "_annotation")
+    __slots__ = ("id", "parent", "name", "start_s", "attrs", "_annotation",
+                 "_cpu", "_cpu0")
     on = True
 
     def __init__(self, name, attrs, annotation):
         self.name = name
         self.attrs = attrs
         self._annotation = annotation
+        self._cpu = False     # cpu_span: read the thread's CPU clock too
 
     def __enter__(self):
         try:
@@ -208,15 +216,18 @@ class _Span(object):
         if self._annotation is not None:
             self._annotation.__enter__()
         self.start_s = _perf_counter()
+        self._cpu0 = _thread_time() if self._cpu else None
         return self
 
     def __exit__(self, *exc):
+        # the CPU clock inside the wall clock's stretch: cpu_s <= wall
+        cpu_s = None if self._cpu0 is None else _thread_time() - self._cpu0
         end_s = _perf_counter()
         if self._annotation is not None:
             self._annotation.__exit__(*exc)
         _span_local.stack.pop()
         record = (self.id, self.parent, self.name, self.start_s, end_s,
-                  self.attrs)
+                  self.attrs, cpu_s)
         with _span_lock:
             if len(_span_records) == SPAN_CAPACITY:
                 _span_state["dropped"] += 1
@@ -241,6 +252,17 @@ def span(name, **attrs):
     return _NO_SPAN
 
 
+def cpu_span(name, **attrs):
+    """:func:`span`, whose record also says how long the calling thread
+    was on a core (``cpu_s``, by ``time.thread_time()``): for the spans a
+    metric reads it of, since a read of that clock is a system call of
+    6 us on some hosts (docs/performance.md, "What they cost")."""
+    sp = span(name, **attrs)
+    if sp.on:
+        sp._cpu = True
+    return sp
+
+
 def record_spans(on=True):
     """Keep spans in memory with no profiler session running (about two
     microseconds a span); returns what the switch was."""
@@ -255,8 +277,8 @@ def spans(name=None, since=None, until=None):
     (``time.perf_counter()`` values)."""
     with _span_lock:
         records = list(_span_records)
-    return [SpanRecord(i, parent, n, start_s, end_s, dict(attrs))
-            for i, parent, n, start_s, end_s, attrs in records
+    return [SpanRecord(i, parent, n, start_s, end_s, dict(attrs), cpu_s)
+            for i, parent, n, start_s, end_s, attrs, cpu_s in records
             if (name is None or n == name)
             and (since is None or start_s >= since)
             and (until is None or end_s <= until)]

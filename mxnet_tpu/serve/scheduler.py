@@ -565,8 +565,7 @@ class Scheduler(object):
 
     def _finish(self, req, slot, active, now):
         self.stats["finished"] += 1
-        with _span("serve.finish", rid=req.rid, slot=slot,
-                   tokens=len(req.tokens)):
+        with _span("serve.finish", rid=req.rid, slot=slot):
             active.pop(slot, None)
             if self._boundary(req, slot, "serve_respond"):
                 req.done_s = now()

@@ -168,7 +168,7 @@ import json
 import time
 
 from ..base import MXNetError, get_env
-from ..profiler import span as _span
+from ..profiler import cpu_span as _cpu_span, span as _span
 from ..quantize import quant_mode
 from .kv_cache import PagedKVCache
 from .model import (ModelConfig, block_of, config_from_params,
@@ -943,6 +943,7 @@ class InferenceSession(object):
     def _prefill_chunks(self, slot, prompt, off, end):
         """Rows ``off .. end - 1`` of ``prompt`` through the per-bucket
         executables in page-aligned chunks, a ``prefill.launch`` span each
+        (its ``bucket``, and the ``largest`` there is)
         -> (the last chunk's first two results, its bucket, the chunks);
         ``lengths`` follows."""
         import numpy as np
@@ -950,9 +951,10 @@ class InferenceSession(object):
         first = last_logits = None
         bucket = chunks = 0
         while off < end:
-            with _span("prefill.launch"):
+            with _span("prefill.launch") as sp:
                 bucket = self._chunk_bucket(end - off)
                 n = min(end - off, bucket)
+                sp.set(bucket=bucket, largest=max(self.config.buckets))
                 toks = np.zeros((1, bucket), np.int32)
                 toks[0, :n] = prompt[off:off + n]
                 self.cache.ensure_writable(slot, off, n)
@@ -1079,7 +1081,7 @@ class InferenceSession(object):
         if self.diffusion:
             return self._block_step()
         cfg = self.config
-        with _span("session.step", live=len(self._slot_tokens)):
+        with _cpu_span("session.step", live=len(self._slot_tokens)):
             with _span("step.prepare"):
                 self._pre_dispatch(1)
                 tokens = np.zeros((cfg.slots,), np.int32)
@@ -1099,7 +1101,7 @@ class InferenceSession(object):
             with _span("step.launch"):
                 next_toks, logits, self.cache.pools, self.counters = \
                     self._dispatch("decode", args)
-            with _span("step.wait"):
+            with _cpu_span("step.wait"):
                 next_np = np.asarray(next_toks)
             with _span("step.commit"):
                 out = {}
@@ -1127,7 +1129,7 @@ class InferenceSession(object):
 
         cfg, model = self.config, self.model
         b, mask = model.block_length, model.mask_token_id
-        with _span("session.step", live=len(self._slot_tokens)) as sp:
+        with _cpu_span("session.step", live=len(self._slot_tokens)) as sp:
             with _span("step.prepare"):
                 self._pre_dispatch(b)
                 tokens = np.zeros((cfg.slots, b), np.int32)
@@ -1154,7 +1156,7 @@ class InferenceSession(object):
             with _span("step.launch"):
                 after, unmasked, conf, logits, self.cache.pools, \
                     self.counters = self._dispatch("block_pass", args)
-            with _span("step.wait"):
+            with _cpu_span("step.wait"):
                 after, unmasked, conf = (np.asarray(after),
                                          np.asarray(unmasked),
                                          np.asarray(conf))

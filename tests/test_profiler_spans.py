@@ -5,6 +5,7 @@ in the profiler's trace while a session runs, bounded."""
 import collections
 import glob
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -146,6 +147,27 @@ def test_off_the_sites_read_no_clock_and_make_no_annotation(
     assert profiler.spans() == [] and profiler.spans_dropped() == 0
 
 
+def test_off_a_tick_and_a_step_read_neither_clock(session, monkeypatch):
+    """Off, a site hands out the shared handle: no ``_Span`` is made and
+    neither the wall clock nor the thread's CPU clock is read, by a
+    ``Scheduler`` tick or by a bare ``session.step``."""
+    def never(*args, **kwargs):
+        raise AssertionError("a span site that is off touched this")
+
+    for name in ("_perf_counter", "_thread_time", "_Span"):
+        monkeypatch.setattr(profiler, name, never)
+    sched = serve.Scheduler(session)
+    sched.begin(requests(2))
+    while sched.tick():
+        pass
+    assert sched.stats["finished"] == 2
+    slot = session.try_alloc(5, 2)
+    session.prefill(slot, [1, 2, 3, 4, 5])
+    tokens, _ = session.step()
+    assert slot in tokens
+    assert profiler.spans() == []
+
+
 def test_off_the_handle_is_shared_and_takes_what_it_is_given():
     with profiler.span("a", x=1) as first:
         first.set(slot=3)
@@ -190,7 +212,8 @@ def test_serve_spans_are_the_vocabulary_whole_and_nested(session):
         assert admit.attrs["slot"] == finish.attrs["slot"] >= 0
         assert admit.attrs["resume"] == 0
         assert admit.attrs["prompt"] == len(req.prompt)
-        assert finish.attrs["tokens"] == len(req.tokens) == req.max_new
+        assert set(finish.attrs) == {"rid", "slot"}
+        assert len(req.tokens) == req.max_new
         prefill = prefills[admit.id]
         assert prefill.attrs["slot"] == admit.attrs["slot"]
         assert prefill.attrs == {
@@ -228,8 +251,34 @@ def test_a_fresh_prompt_in_chunks_says_so_in_its_spans(params):
     assert prefill.parent == admit.id
     assert prefill.attrs == {"slot": admit.attrs["slot"], "prompt": 37,
                              "cached": 0, "chunks": 3, "bucket": 8}
-    assert len([r for r in profiler.spans("prefill.launch")
-                if r.parent == prefill.id]) == 3
+    launches = [r for r in profiler.spans("prefill.launch")
+                if r.parent == prefill.id]
+    assert [r.attrs for r in launches] == [
+        {"bucket": 16, "largest": 16}, {"bucket": 16, "largest": 16},
+        {"bucket": 8, "largest": 16}]
+
+
+def test_each_chunk_of_a_two_chunk_prompt_says_which_it_is(session, params):
+    """A ``prefill.launch`` span carries its chunk's ``bucket`` beside
+    the ``largest`` the session has; the prompt within a bucket is one
+    launch of its bucket."""
+    sess = serve.InferenceSession(
+        params, num_heads=CFG.num_heads, config=serve.ServeConfig(
+            slots=2, page_size=PAGE, buckets=(8, 16), max_new=8,
+            max_prompt=40, exact=True))
+    prompt = np.random.default_rng(11).integers(0, CFG.vocab_size,
+                                                28).tolist()
+    profiler.record_spans(True)
+    sess.prefill(sess.try_alloc(28, 2), prompt)
+    session.prefill(session.try_alloc(6, 2), prompt[:6])
+    profiler.record_spans(False)
+    long, short = profiler.spans("session.prefill")
+    assert (long.attrs["bucket"], long.attrs["chunks"]) == (16, 2)
+    by_parent = collections.defaultdict(list)
+    for r in profiler.spans("prefill.launch"):
+        by_parent[r.parent].append(r.attrs)
+    assert by_parent[long.id] == [{"bucket": 16, "largest": 16}] * 2
+    assert by_parent[short.id] == [{"bucket": 8, "largest": 16}]
 
 
 def test_chunks_that_carry_state_say_so_in_their_spans():
@@ -365,6 +414,128 @@ def test_spans_filters_by_name_and_window_and_hands_out_copies():
     assert profiler.spans() == []
 
 
+# -- on: a cpu_span's CPU time beside its wall time --------------------------
+
+def _spin(cpu_seconds):
+    """Compute until this thread has had ``cpu_seconds`` of a core,
+    however long the machine takes to give them."""
+    end = time.thread_time() + cpu_seconds
+    while time.thread_time() < end:
+        pass
+
+
+# what two reads of a CPU clock may disagree by
+GRAIN_S = 1e-3
+
+
+@pytest.mark.parametrize("work, ran_at_least, ran_at_most", [
+    (time.sleep, 0.0, 0.02), (_spin, 0.2, float("inf"))],
+    ids=["a_sleep_waited", "a_busy_loop_ran"])
+def test_a_cpu_span_says_whether_its_thread_ran_or_waited(
+        work, ran_at_least, ran_at_most):
+    profiler.record_spans(True)
+    with profiler.cpu_span("stretch", k=1) as sp:
+        work(0.2)
+        sp.set(n=2)
+    profiler.record_spans(False)
+    r, = profiler.spans("stretch")
+    wall = r.end_s - r.start_s
+    # ran = cpu_s, waited = wall - cpu_s; the CPU clock is read inside the
+    # wall clock's stretch
+    assert wall >= 0.2 and ran_at_least <= r.cpu_s <= ran_at_most
+    assert r.cpu_s <= wall + GRAIN_S
+    assert r.attrs == {"k": 1, "n": 2}
+
+
+def test_what_another_thread_ran_is_not_this_threads():
+    """Another thread computes while this one sleeps inside a span:
+    ``cpu_s`` stays this thread's own."""
+    done = threading.Event()
+
+    def other():
+        _spin(0.2)
+        done.set()
+
+    worker = threading.Thread(target=other)
+    profiler.record_spans(True)
+    with profiler.cpu_span("asleep"):
+        worker.start()
+        assert done.wait(60)
+    profiler.record_spans(False)
+    worker.join(30)
+    assert not worker.is_alive()
+    r, = profiler.spans("asleep")
+    assert r.cpu_s <= 0.05 and r.end_s - r.start_s >= 0.2 - GRAIN_S
+
+
+def test_a_parents_cpu_time_covers_its_childrens():
+    profiler.record_spans(True)
+    with profiler.cpu_span("outer"):
+        with profiler.cpu_span("first"):
+            _spin(0.05)
+        time.sleep(0.05)
+        with profiler.cpu_span("second"):
+            _spin(0.05)
+    profiler.record_spans(False)
+    outer, = profiler.spans("outer")
+    first, = profiler.spans("first")
+    second, = profiler.spans("second")
+    assert min(first.cpu_s, second.cpu_s) >= 0.05
+    assert outer.cpu_s >= first.cpu_s + second.cpu_s
+    # the self values: the span's less its children's, as for wall time
+    self_wall = (outer.end_s - outer.start_s) - sum(
+        c.end_s - c.start_s for c in (first, second))
+    self_ran = outer.cpu_s - first.cpu_s - second.cpu_s
+    assert self_wall >= 0.05 and 0 <= self_ran <= 0.02
+
+
+def test_on_a_plain_span_reads_no_cpu_clock(session, monkeypatch):
+    """A read of the thread's CPU clock is a slow system call on some
+    hosts, so only a ``cpu_span`` pays it: of the serving spans a decode
+    call's ``session.step`` and its ``step.wait``, which
+    ``step_host_cpu_ms.serve`` reads."""
+    reads = []
+    monkeypatch.setattr(profiler, "_thread_time",
+                        lambda: reads.append(1) or 0.25 * len(reads))
+    profiler.record_spans(True)
+    with profiler.span("plain", k=1):
+        pass
+    assert reads == []
+    sched = serve.Scheduler(session)
+    sched.begin(requests(2))
+    while sched.tick():
+        pass
+    profiler.record_spans(False)
+    plain, = profiler.spans("plain")
+    assert plain.cpu_s is None and plain.attrs == {"k": 1}
+    with_cpu = {r.name for r in profiler.spans() if r.cpu_s is not None}
+    assert with_cpu == {"session.step", "step.wait"}
+    steps = profiler.spans("session.step")
+    assert len(reads) == 4 * len(steps) > 0
+    # a step's reads enclose its wait's: 0.75 against 0.25 by this clock
+    assert {r.cpu_s for r in steps} == {0.75}
+    assert {r.cpu_s for r in profiler.spans("step.wait")} == {0.25}
+
+
+def test_a_record_of_six_fields_still_reads_and_has_no_cpu_time():
+    r = profiler.SpanRecord(1, None, "session.step", 0.5, 0.75, {"live": 2})
+    assert r.cpu_s is None
+    assert (r.name, r.end_s - r.start_s, r.attrs) \
+        == ("session.step", 0.25, {"live": 2})
+    assert r._fields == ("id", "parent", "name", "start_s", "end_s",
+                         "attrs", "cpu_s")
+
+
+def test_a_platform_without_the_cpu_clock_records_none(monkeypatch):
+    monkeypatch.setattr(profiler, "_thread_time", lambda: None)
+    profiler.record_spans(True)
+    with profiler.cpu_span("s", k=1):
+        pass
+    profiler.record_spans(False)
+    r, = profiler.spans("s")
+    assert r.cpu_s is None and r.end_s >= r.start_s
+
+
 def test_a_span_entered_while_off_stays_off():
     with profiler.span("outer") as outer:
         profiler.record_spans(True)
@@ -461,3 +632,5 @@ def test_a_profiler_session_switches_the_sites_on_and_off(session, tmp_path):
                for _, stats in found["mx:serve.admit"])
     assert all({"bucket", "chunks", "prompt"} <= set(stats)
                for _, stats in found["mx:session.prefill"])
+    assert all(0 < stats["bucket"] <= stats["largest"]
+               for _, stats in found["mx:prefill.launch"])

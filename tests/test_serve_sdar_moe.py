@@ -441,6 +441,29 @@ def test_slots_in_different_passes_of_different_blocks(params, plain,
         assert all(0 < c <= 1 for _, _, c in got[slot])
 
 
+def test_the_chunks_of_a_long_prompt_say_which_they_are(plain):
+    """A prompt of 30 tokens prefills its 28 whole-block rows in two
+    chunks of the largest bucket, and each ``prefill.launch`` span carries
+    its ``bucket`` beside the ``largest`` there is, as the autoregressive
+    blocks' do (one loop: ``_prefill_chunks``)."""
+    import time
+
+    mx.profiler.record_spans(True)
+    t0 = time.perf_counter()
+    try:
+        slot = plain.try_alloc(30, 4)
+        plain.prefill(slot, tokens(42, 30))
+        prefill, = mx.profiler.spans("session.prefill", since=t0)
+        launches = [s.attrs for s in mx.profiler.spans("prefill.launch",
+                                                       since=t0)]
+    finally:
+        mx.profiler.record_spans(False)
+        mx.profiler.clear_spans()
+    assert prefill.attrs == {"slot": slot, "prompt": 30, "cached": 0,
+                             "bucket": 16, "chunks": 2}
+    assert launches == [{"bucket": 16, "largest": 16}] * 2
+
+
 def test_a_length_that_is_no_multiple_of_the_block(params, plain):
     """(d) the last block's tail is dropped: the request ends at its asked
     length, and the session counts the tokens it asked for."""
