@@ -39,6 +39,22 @@ slots in different passes of different blocks share the one step.  Such a
 session refuses ``oversub``, so nothing is parked; a request handed over
 with committed tokens (``submit(parked=True)``) is refused by name.
 
+A step one ahead: a continuous run's decode step is told it may launch
+the next step before its own tokens are read
+(:meth:`InferenceSession.step`, ``ahead=True``), so the host's work of a
+tick lies under the running step.  The scheduler says so only where it
+can see that nobody needs the chip at the next boundary: no active
+request's returned token is its last (``max_new - len(tokens)``: its
+follow-up's prefill then finds an idle chip, as before), no arrival is
+waiting that the next boundary could admit, and under ``oversub`` the pool
+covers both steps above the watermark.  An end nobody can foresee (an
+``eos_id``, a :meth:`Scheduler.cancel`, a fault, a drain, a watermark
+eviction) finds a step in flight that carried the slot: its row is
+dropped, and an arrival admitted into the slot waits for that step, one
+decode step at most, as in any engine that schedules a step behind.
+``spec_step`` and a diffusion block's step keep their order: their next
+input is computed on the host from the read.
+
 Preemption and resume (oversubscribed sessions,
 ``session.config.oversub``): before every step the scheduler probes the
 session's page shortfall for the coming boundary; when shortfall plus
@@ -438,6 +454,7 @@ class Scheduler(object):
             admit_cap = config.slots if not active else 0
         else:
             admit_cap = config.slots - len(active)
+        blocked = False   # an arrival found no room: none until a release
         for req in arrived[:max(admit_cap, 0)]:
             if not self._boundary(req, None, "serve_admit"):
                 pending.remove(req)
@@ -461,6 +478,7 @@ class Scheduler(object):
                            cache.reclaimable_pages,
                            cache.pages_needed(len(req.prompt),
                                               req.max_new)))
+                blocked = True
                 break  # pool full: stays queued for a later boundary
             if first is None:
                 continue
@@ -553,8 +571,10 @@ class Scheduler(object):
                         self._finish(req, slot, active, now)
                         break
         else:
-            step_tokens, _ = sess.step()
+            step_tokens, _ = sess.step(ahead=self._foresees_no_end(blocked))
             for slot in sorted(active):
+                if slot not in step_tokens:
+                    continue    # admitted behind the step that was read
                 req = active[slot]
                 req.tokens.append(step_tokens[slot])
                 if (len(req.tokens) >= req.max_new
@@ -562,6 +582,27 @@ class Scheduler(object):
                     self._finish(req, slot, active, now)
 
         return self.outstanding
+
+    def _foresees_no_end(self, blocked):
+        """Whether the coming decode step may launch its successor before
+        it is read: nothing this scheduler can see will want the chip at
+        the next boundary.  ``blocked``: an arrival found no room at this
+        one, so none is admitted before a release."""
+        sess, active = self.session, self._active
+        config = sess.config
+        if any(req.max_new - len(req.tokens) <= 1
+               for req in active.values()):
+            # a returned token is a request's last: its slot frees, and
+            # whoever takes it should find the chip idle
+            return False
+        if (config.oversub and sess.pages_short(2) + config.watermark
+                > sess.cache.reclaimable_pages):
+            return False    # the second step's pages might evict someone
+        if (blocked or self.policy != "continuous"
+                or len(active) >= config.slots):
+            return True     # no admission before a finish, which is foreseen
+        t = self.now()
+        return not any(r.arrival_s <= t for r in self._pending)
 
     def _finish(self, req, slot, active, now):
         self.stats["finished"] += 1

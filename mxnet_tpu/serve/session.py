@@ -153,6 +153,22 @@ unmasked with, and the next block opens.  A
 step so commits 0 to ``block_length`` tokens a slot.  Such a block refuses
 ``spec_k``, ``kv_quant``, ``prefix_pages`` and ``oversub``.
 
+A decode step one ahead (``InferenceSession.step(ahead=True)``): of what a
+decode launch takes, only the ``(slots,)`` token vector depends on the
+launch before it, and that launch's own first result holds it on the
+device.  The decode executable therefore takes three token arguments (the
+host's vector, the launch before's ``next_tokens`` as the device array it
+is, and a mask that says which to feed a slot) and selects inside: a
+call told it may run ahead launches the next step *before* it reads this
+one's tokens, and leaves it in flight when it returns.  The host's state
+moves where it belongs: ``lengths``, page upkeep and the page count at a
+launch, ``_slot_tokens`` / ``_slot_history`` and the returned tokens at
+the read.  A launch remembers the slots it carried and the epoch of each
+(:meth:`InferenceSession.prefill` starts a new one); a row whose slot was
+released, or released and filled again, since the launch is dropped at
+the read.  A speculating session and a diffusion block compute their next
+input on the host from the read, so they never run ahead.
+
 Env knobs (see docs/env_vars.md): ``MXNET_SERVE_SLOTS``,
 ``MXNET_SERVE_PAGE``, ``MXNET_SERVE_BUCKETS``, ``MXNET_SERVE_MAX_NEW``,
 ``MXNET_SERVE_PAGES``, ``MXNET_SERVE_EXACT``, ``MXNET_SERVE_SPEC_K``,
@@ -377,6 +393,18 @@ class _OpenBlock(object):
         self.budget = budget - self.fresh
 
 
+class _Flight(object):
+    """A decode step launched and not read yet: its ``tokens`` and
+    ``logits`` as the launch returned them (device arrays that may not be
+    ready), and the slots it ``carried``, slot -> the epoch the slot was
+    in then."""
+
+    __slots__ = ("tokens", "logits", "carried")
+
+    def __init__(self, tokens, logits, carried):
+        self.tokens, self.logits, self.carried = tokens, logits, carried
+
+
 class InferenceSession(object):
     """Compile-once serving session for the built-in transformer LM.
 
@@ -467,10 +495,22 @@ class InferenceSession(object):
         # slot's _OpenBlock)
         self._slot_tokens = {}
         self._slot_history = {}  # slot -> prompt + committed tokens
+        # a slot's epoch: every prefill into it starts a new one, so a
+        # decode step in flight can tell the request it carried from the
+        # next one in the same slot
+        self._slot_epoch = [0] * cfg.slots
+        self._flight = None  # the decode step launched and not read yet
+        # what a decode launch passes where every slot's token comes from
+        # one side (nearly always): on the device once, so that a launch
+        # uploads neither a mask nor a vector that nothing reads
+        self._no_tokens = jnp.zeros((cfg.slots,), jnp.int32)
+        self._all_host = jnp.ones((cfg.slots,), jnp.bool_)
+        self._none_host = jnp.zeros((cfg.slots,), jnp.bool_)
         self._slot_budget = {}  # diffusion: slot -> max_new, until prefill
         self._spec_stats = {"verify_steps": 0, "slot_steps": 0,
                             "proposed": 0, "accepted": 0, "committed": 0}
-        self._decode_stats = {"steps": 0, "pages_visited": 0}
+        self._decode_stats = {"steps": 0, "steps_ahead": 0,
+                              "pages_visited": 0}
         self._prefill_stats = {"chunks": 0, "rows_visited": 0,
                                "rows_capacity": 0}
         # the key block of a prefill chunk's attention scan over the table
@@ -696,7 +736,11 @@ class InferenceSession(object):
         static = dict(cfg=self.model, page_size=cfg.page_size,
                       exact=bool(cfg.exact), kv_quant=cfg.kv_quant)
 
-        def decode_fn(params, tokens, lengths, tables, pools, counters):
+        def decode_fn(params, tokens, before, from_host, lengths, tables,
+                      pools, counters):
+            # a slot's token: the host's, or the launch before's own result
+            # where that has not been read yet (``step(ahead=True)``)
+            tokens = jax.numpy.where(from_host, tokens, before)
             return block.decode_step(params, tokens, lengths, tables, pools,
                                      counters, **static)
 
@@ -704,8 +748,9 @@ class InferenceSession(object):
             self._aot(
                 "decode", decode_fn, self.params,
                 (param_avals, sds((cfg.slots,), i32), sds((cfg.slots,), i32),
+                 sds((cfg.slots,), jax.numpy.bool_), sds((cfg.slots,), i32),
                  sds((cfg.slots, max_pages), i32), pools, counters),
-                donate_argnums=(4, 5))
+                donate_argnums=(6, 7))
         else:
             def block_pass_fn(params, tokens, quota, fresh, lengths, tables,
                               pools, counters):
@@ -931,6 +976,7 @@ class InferenceSession(object):
             with _span("prefill.wait"):
                 first = int(first)
             with _span("prefill.publish"):
+                self._slot_epoch[slot] += 1
                 self._slot_tokens[slot] = first
                 self._slot_history[slot] = [int(t) for t in prompt] + [first]
                 prompt_list = [int(t) for t in prompt]
@@ -1065,54 +1111,115 @@ class InferenceSession(object):
             self.draft_cache.device_tables(), self.draft_cache.pools, {}))
         return outs
 
-    def step(self):
-        """Advance every active slot one token with the single decode
+    def step(self, ahead=False):
+        """One token for every live slot from the single decode
         executable; returns ``(tokens, logits)`` where ``tokens`` maps
         slot -> emitted token id and ``logits`` is the (slots, vocab)
         array (inactive rows are garbage by design), left on the device:
         the step reads the ``(slots,)`` token vector and nothing else,
         and a caller that wants numbers converts (``np.asarray``).
 
+        ``ahead=True`` lets the call launch the *next* step before it
+        reads this one's tokens, and return with that step in flight:
+        the following call reads it (and launches another, if it may
+        too), so the host's work of a call runs beside the device's and
+        not between two of its steps.  The next step takes its tokens
+        from this one's result on the device.  What is in flight when a
+        slot is released is dropped at its read: a call returns tokens
+        only for the slots that the step it read carried and that have
+        not been released since, so a slot prefilled while a step was in
+        flight gets its first decode token one call later.  Without
+        ``ahead`` the call leaves nothing in flight (it reads what an
+        earlier call left, or launches and reads), which is what a caller
+        that prefills and releases between steps as it pleases wants.  A
+        session that speculates (``spec_k``) does not run ahead: its next
+        input comes from the host.
+
         A diffusion block's step is one block pass and commits 0 to
         ``block_length`` tokens a slot: ``tokens`` then maps slot -> a list
-        of ``(token, pass, confidence)`` triples (:meth:`_block_step`)."""
+        of ``(token, pass, confidence)`` triples (:meth:`_block_step`);
+        its next pass's input is computed on the host from the read, so it
+        does not run ahead either."""
         import numpy as np
 
         if self.diffusion:
             return self._block_step()
-        cfg = self.config
-        with _cpu_span("session.step", live=len(self._slot_tokens)):
-            with _span("step.prepare"):
-                self._pre_dispatch(1)
-                tokens = np.zeros((cfg.slots,), np.int32)
-                for slot, tok in self._slot_tokens.items():
-                    tokens[slot] = tok
-                args = (self.params, tokens,
-                        self.cache.lengths_arg(), self.cache.device_tables(),
-                        self.cache.pools, self.counters)
-                # the pages this step's reader visits a full layer, each
-                # context's new row included: the kernel every slot's own,
-                # the loop the longest context's for every slot
-                pages = decode_pages_visited(
-                    self.cache.lengths, cfg.page_size,
-                    self.cache.table_width, self._paged_kernel_layers() > 0)
-                self._decode_stats["steps"] += 1
-                self._decode_stats["pages_visited"] += pages
-            with _span("step.launch"):
-                next_toks, logits, self.cache.pools, self.counters = \
-                    self._dispatch("decode", args)
+        ahead = bool(ahead) and not self.config.spec_k
+        with _cpu_span("session.step", live=len(self._slot_tokens),
+                       ahead=int(ahead)):
+            flight, self._flight = self._flight, None
+            if flight is None or not any(
+                    self._carries(flight, slot) for slot in flight.carried):
+                # nothing in flight, or only rows nobody waits for
+                flight = self._launch(None)
+            if ahead:
+                self._flight = self._launch(flight)
+                self._decode_stats["steps_ahead"] += 1
             with _cpu_span("step.wait"):
-                next_np = np.asarray(next_toks)
+                next_np = np.asarray(flight.tokens)
             with _span("step.commit"):
                 out = {}
-                for slot in list(self._slot_tokens):
-                    self.cache.lengths[slot] += 1
+                for slot in flight.carried:
+                    if not self._carries(flight, slot):
+                        continue    # released since the launch: dropped
                     tok = int(next_np[slot])
                     self._slot_tokens[slot] = tok
                     if slot in self._slot_history:
                         self._slot_history[slot].append(tok)
                     out[slot] = tok
-        return out, logits
+        return out, flight.logits
+
+    def _carries(self, flight, slot):
+        """Whether ``slot`` still holds the request ``flight`` carried in
+        it: not released since, nor released and prefilled again."""
+        return (slot in self._slot_tokens
+                and flight.carried.get(slot) == self._slot_epoch[slot])
+
+    def _launch(self, before):
+        """Launch one decode step for every live slot -> its
+        :class:`_Flight`.  ``before`` is the step in flight whose result
+        this one follows (``None``: every token is on the host): a slot it
+        carried takes its token from that result on the device, any other
+        (one a prefill has filled since) the host's.  What never depended
+        on a read moves here: page upkeep, the page count, ``lengths``."""
+        import numpy as np
+
+        cfg = self.config
+        with _span("step.prepare"):
+            self._pre_dispatch(1)
+            tokens = np.zeros((cfg.slots,), np.int32)
+            carried, host = {}, []
+            for slot, tok in self._slot_tokens.items():
+                carried[slot] = self._slot_epoch[slot]
+                if before is None or not self._carries(before, slot):
+                    tokens[slot] = tok
+                    host.append(slot)
+            if before is None:
+                feed = (tokens, self._no_tokens, self._all_host)
+            elif not host:
+                # an idle slot's row is garbage either way
+                feed = (self._no_tokens, before.tokens, self._none_host)
+            else:
+                from_host = np.zeros((cfg.slots,), np.bool_)
+                from_host[host] = True
+                feed = (tokens, before.tokens, from_host)
+            args = (self.params,) + feed + (
+                self.cache.lengths_arg(), self.cache.device_tables(),
+                self.cache.pools, self.counters)
+            # the pages this step's reader visits a full layer, each
+            # context's new row included: the kernel every slot's own,
+            # the loop the longest context's for every slot
+            pages = decode_pages_visited(
+                self.cache.lengths, cfg.page_size,
+                self.cache.table_width, self._paged_kernel_layers() > 0)
+            self._decode_stats["steps"] += 1
+            self._decode_stats["pages_visited"] += pages
+        with _span("step.launch"):
+            next_toks, logits, self.cache.pools, self.counters = \
+                self._dispatch("decode", args)
+            for slot in carried:
+                self.cache.lengths[slot] += 1
+        return _Flight(next_toks, logits, carried)
 
     def _block_step(self):
         """:meth:`step` for a diffusion block: ONE pass of the block-pass
@@ -1311,8 +1418,10 @@ class InferenceSession(object):
         is eligible: ``ops/paged_attention.py:paged_attention_eligible``;
         0 on the CPU, under ``exact`` / ``kv_quant`` and for pools that
         fold their heads, where the ``fori_loop`` runs).  ``steps`` decode
-        steps since the session was built; ``pages_visited`` the sum over
-        them of the pages a full layer's reader visits, each context's new
+        steps launched since the session was built, ``steps_ahead`` of
+        them before the step in front of them had been read
+        (``step(ahead=True)``); ``pages_visited`` the sum over
+        the steps of the pages a full layer's reader visits, each context's new
         row included: with the kernel every slot's own
         ``ceil((length + 1) / page_size)``, an idle slot's one; with the
         loop the longest live context's pages for every slot (where the
@@ -1488,6 +1597,11 @@ class InferenceSession(object):
         return short
 
     def release(self, slot):
+        """Give the slot and its pages back.  A decode step in flight
+        that carried the slot still writes its one row, inside the
+        request's own reservation; the row's token is dropped at the
+        read, and whatever takes the pages or the slot next is ordered
+        behind that step by the donated pools it takes."""
         self._slot_tokens.pop(slot, None)
         self._slot_history.pop(slot, None)
         self._slot_budget.pop(slot, None)
@@ -1506,6 +1620,9 @@ class InferenceSession(object):
         would do, minus the recompile (the executables are immutable
         and carry no request state, so reusing them in-process models
         only the state a real restart loses)."""
+        # a decode step in flight carried slots that are all released
+        # here: nobody reads it
+        self._flight = None
         # allocated-but-never-prefilled slots too (their holder died
         # between ``try_alloc`` and ``prefill``): the cache knows them
         for slot in sorted(set(self._slot_tokens)

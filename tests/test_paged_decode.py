@@ -777,7 +777,8 @@ def test_decode_report_of_a_fresh_session_is_zero():
     sess = serve.InferenceSession(serve_model.init_params(CFG, seed=3),
                                   num_heads=CFG.num_heads, config=sconf)
     assert sess.decode_report() == {
-        "steps": 0, "blocks_visited": 0, "pages_visited": 0,
+        "steps": 0, "steps_ahead": 0, "blocks_visited": 0,
+        "pages_visited": 0,
         "blocks_capacity": 0, "visited_share": 0.0,
         "paged_kernel_layers": 0,
         # two heads of 16 fold into the pools' last axis

@@ -314,27 +314,60 @@ def test_chunks_that_carry_state_say_so_in_their_spans():
     assert (rep["prefills_from_zero"], rep["prefills_carried"]) == (1, 2)
 
 
-def test_a_step_is_its_four_segments_in_order(session):
+def test_a_step_is_its_launches_then_its_read_in_order(session):
+    """A call is the launches it makes (a ``step.prepare`` and a
+    ``step.launch`` each: its own step's unless an earlier call left that
+    in flight, and the next step's where ``ahead`` is 1), then the read of
+    one step and its commit."""
+    before = session.decode_report()
     profiler.record_spans(True)
     serve.Scheduler(session).run(requests())
     profiler.record_spans(False)
     children = collections.defaultdict(list)
     for r in sorted(profiler.spans(), key=lambda r: r.start_s):
         children[r.parent].append(r)
-    steps = profiler.spans("session.step")
+    steps = sorted(profiler.spans("session.step"), key=lambda r: r.start_s)
     assert steps
-    shares = []
+    shares, in_flight, launches = [], 0, 0
     for step in steps:
         parts = children[step.id]
-        assert [p.name for p in parts] == ["step.prepare", "step.launch",
-                                           "step.wait", "step.commit"]
-        for before, after in zip(parts, parts[1:]):
-            assert before.end_s <= after.start_s
+        made = (1 - in_flight) + step.attrs["ahead"]
+        assert [p.name for p in parts] == (
+            ["step.prepare", "step.launch"] * made
+            + ["step.wait", "step.commit"])
+        for first, then in zip(parts, parts[1:]):
+            assert first.end_s <= then.start_s
         assert 1 <= step.attrs["live"] <= 3
         shares.append(sum(p.end_s - p.start_s for p in parts)
                       / (step.end_s - step.start_s))
-    # what lies between the segments is four `with` statements
-    assert 0.95 <= float(np.median(shares)) <= 1.0
+        in_flight = step.attrs["ahead"]
+        launches += made
+    # every request ends by max_new, which the scheduler foresees: the
+    # last call leaves nothing in flight, and some call ran ahead
+    assert in_flight == 0
+    ahead = sum(step.attrs["ahead"] for step in steps)
+    assert 0 < ahead < len(steps)
+    after = session.decode_report()
+    assert after["steps"] - before["steps"] == launches == len(steps)
+    assert after["steps_ahead"] - before["steps_ahead"] == ahead
+    # what lies between the segments is a few `with` statements
+    assert 0.9 <= float(np.median(shares)) <= 1.0
+
+
+def test_a_bare_step_says_whether_it_ran_ahead(session):
+    slot = session.try_alloc(5, 6)
+    session.prefill(slot, [1, 2, 3, 4, 5])
+    profiler.record_spans(True)
+    for ahead in (False, True, True, False):
+        tokens, _ = session.step(ahead=True) if ahead else session.step()
+        assert list(tokens) == [slot]
+    profiler.record_spans(False)
+    steps = sorted(profiler.spans("session.step"), key=lambda r: r.start_s)
+    assert [s.attrs["ahead"] for s in steps] == [0, 1, 1, 0]
+    launched = collections.Counter(
+        r.parent for r in profiler.spans("step.launch"))
+    # the last call reads what the third left in flight: it launches none
+    assert [launched[s.id] for s in steps] == [1, 2, 1, 0]
 
 
 def test_a_resumed_request_is_admitted_again_with_resume_set(params):

@@ -152,6 +152,8 @@ def test_a_drifted_argument_still_goes_through_the_lazy_jit(sess, drift):
     if drift == "dtype":
         name = "decode"
         args = (sess.params, jnp.zeros((CONF["slots"],), jnp.int16),
+                jnp.zeros((CONF["slots"],), jnp.int32),
+                jnp.ones((CONF["slots"],), jnp.bool_),
                 sess.cache.lengths_arg(), sess.cache.device_tables(),
                 sess.cache.pools, sess.counters)
     else:
@@ -215,8 +217,10 @@ def test_every_executable_returns_its_logits_and_donates_the_cache(sess):
         donated = [leaf.donated for leaf in
                    jax.tree.leaves(compiled.args_info)]
         assert not any(donated[:-n_state]) and all(donated[-n_state:])
+        # a decode step's tokens are three: the host's, the launch
+        # before's, and the mask between them
         assert len(donated) - n_params - n_state == \
-            (3 if name == "decode" else 4)
+            (5 if name == "decode" else 4)
 
 
 def test_logits_stay_on_the_device_until_read(sess):

@@ -379,7 +379,8 @@ def test_sixteen_slots_turn_over_under_the_scheduler(params):
             for i in range(40)]
     steps = []
     inner = sess.step
-    sess.step = lambda: steps.append(1) or inner()
+    # every step launched is read by one call, a step ahead or not
+    sess.step = lambda **how: steps.append(1) or inner(**how)
     done, _ = Scheduler(sess, policy="continuous").run(reqs)
     assert len(done) == 40
     for r in done:

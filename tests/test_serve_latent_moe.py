@@ -536,8 +536,9 @@ def _latent_session(params):
 @pytest.mark.parametrize("block", ["gpt2", "deepseek_v3"])
 def test_flattened_executable_inputs_are_pinned(block, params):
     """What an executable is compiled over, leaf by leaf in the order the
-    device sees them: the parameters, the step's tokens, lengths and
-    tables, then the cache's pools and the block's counters, and nothing
+    device sees them: the parameters, the step's tokens (a decode step's
+    three: the host's, the launch before's, the mask between them),
+    lengths and tables, then the cache's pools and the block's counters, and nothing
     else.  The GPT-2 call infers as before (two pools, no counters); the
     latent call has its one pool and its routers' counts.  How the
     session groups them into arguments is free to change; this is not."""
@@ -551,8 +552,8 @@ def test_flattened_executable_inputs_are_pinned(block, params):
         return [leaf for _, leaf in sig]
 
     assert leaves(sess._exes["decode"].aval_sig) == leaves(signature_of(
-        (param_avals, sds((3,), i32), sds((3,), i32), sds((3, width), i32))
-        + state))
+        (param_avals, sds((3,), i32), sds((3,), i32), sds((3,), jnp.bool_),
+         sds((3,), i32), sds((3, width), i32)) + state))
     assert leaves(sess._exes["prefill_16"].aval_sig) == leaves(signature_of(
         (param_avals, sds((1, 16), i32), sds((), i32), sds((), i32),
          sds((width,), i32)) + state))
