@@ -49,8 +49,9 @@ from . import latent_moe
 from .kv_cache import append_latent_rows, read_latent_context
 from .latent_moe import (_attend_absorbed, _attend_materialised,
                          _ffn_held as _ffn, _head, _head_gate,
-                         _query_and_row, _resolve, _rms_norm, fold_named,
-                         held_range, prefill_block, read_named)
+                         _query_and_row, _resolve, fold_named, held_range,
+                         prefill_block, read_named)
+from .layers import rms_norm
 # the expert layer is the latent block's, and so is what it asks of XLA
 from .latent_moe import compiler_options  # noqa: F401
 from .model import _mm, check_param_shapes
@@ -308,7 +309,7 @@ def _kda_out(params, pre, o, gate, cfg, exact):
     import jax
 
     with jax.named_scope("kda_out_norm"):
-        o = _rms_norm(o, params[pre + "kda_o_norm_gamma"], cfg.rms_norm_eps)
+        o = rms_norm(o, params[pre + "kda_o_norm_gamma"], cfg.rms_norm_eps)
         y = o.reshape(o.shape[0], -1).astype(gate.dtype) \
             * jax.nn.sigmoid(gate)
     return _mm(y, params[pre + "kda_o_weight"], exact)
@@ -359,8 +360,8 @@ def full_forward(params, tokens, cfg, exact, block=None):
                      axis=0)
         for i, kind in enumerate(cfg.layer_types):
             pre = "blk%d_" % i
-            u = _rms_norm(x, params[pre + "attn_norm_gamma"],
-                          cfg.rms_norm_eps)
+            u = rms_norm(x, params[pre + "attn_norm_gamma"],
+                         cfg.rms_norm_eps)
             if kind == "kda":
                 out, _, _ = _kda_rows(
                     params, pre, u,
@@ -420,7 +421,7 @@ def prefill_forward(params, tokens, length, offset, table_row, pools,
     ki = li = 0
     for i, kind in enumerate(cfg.layer_types):
         pre = "blk%d_" % i
-        u = _rms_norm(x, params[pre + "attn_norm_gamma"], cfg.rms_norm_eps)
+        u = rms_norm(x, params[pre + "attn_norm_gamma"], cfg.rms_norm_eps)
         if kind == "kda":
             out, state, context = _kda_rows(
                 params, pre, u, pools["kda_state"][ki, slot],
@@ -476,7 +477,7 @@ def decode_step(params, tokens, lengths, tables, pools, counters, cfg,
     ki = li = 0
     for i, kind in enumerate(cfg.layer_types):
         pre = "blk%d_" % i
-        u = _rms_norm(x, params[pre + "attn_norm_gamma"], cfg.rms_norm_eps)
+        u = rms_norm(x, params[pre + "attn_norm_gamma"], cfg.rms_norm_eps)
         if kind == "kda":
             rows, g, beta, gate = _kda_inputs(params, pre, u, cfg, exact)
             with jax.named_scope("kda_conv"):

@@ -50,7 +50,8 @@ from ..ops.attention import (flash_attention, paged_decode_attention,
                              paged_prefill_attention)
 from ..ops.mamba2 import causal_conv, conv_step, ssd_chunked_scan, ssd_step
 from .kv_cache import append_rows
-from .latent_moe import _rms_norm, fold_named, prefill_block, read_named
+from .latent_moe import fold_named, prefill_block, read_named
+from .layers import rms_norm
 from .model import _mm, _resolve_params, check_param_shapes
 # the attention layers run the GPT-2 block's paged reader: its report
 from .model import decode_report  # noqa: F401
@@ -237,7 +238,7 @@ def _mlp(params, pre, x, cfg, exact):
     import jax
     import jax.numpy as jnp
 
-    u = _rms_norm(x, params[pre + "ffn_norm_gamma"], cfg.rms_norm_eps)
+    u = rms_norm(x, params[pre + "ffn_norm_gamma"], cfg.rms_norm_eps)
     gate, value = jnp.split(_mm(u, params[pre + "ffn_in_weight"], exact), 2,
                             axis=-1)
     return x + cfg.residual_multiplier * _mm(
@@ -281,9 +282,9 @@ def _mamba_out(params, pre, y, x, z, cfg, exact):
         n, g = y.shape[0], cfg.mamba_n_groups
         y = (y + params[pre + "D"][:, None] * x).astype(z.dtype)
         y = y.reshape(n, -1) * jax.nn.silu(z)
-        y = _rms_norm(y.reshape(n, g, -1),
-                      params[pre + "gate_norm_gamma"].reshape(g, -1),
-                      cfg.rms_norm_eps).reshape(n, -1)
+        y = rms_norm(y.reshape(n, g, -1),
+                     params[pre + "gate_norm_gamma"].reshape(g, -1),
+                     cfg.rms_norm_eps).reshape(n, -1)
     return _mm(y, params[pre + "out_weight"], exact)
 
 
@@ -318,7 +319,7 @@ def _qkv(params, pre, u, cfg, exact):
 
 
 def _head(params, x, cfg, exact):
-    x = _rms_norm(x, params["final_norm_gamma"], cfg.rms_norm_eps)
+    x = rms_norm(x, params["final_norm_gamma"], cfg.rms_norm_eps)
     return _mm(x, params["tok_embed_weight"], exact) / cfg.logits_scaling
 
 
@@ -348,8 +349,8 @@ def full_forward(params, tokens, cfg, exact, block=None):
         x = _embed(params, seq, cfg)
         for i, kind in enumerate(cfg.layer_types):
             pre = "blk%d_" % i
-            u = _rms_norm(x, params[pre + "mixer_norm_gamma"],
-                          cfg.rms_norm_eps)
+            u = rms_norm(x, params[pre + "mixer_norm_gamma"],
+                         cfg.rms_norm_eps)
             if kind == "mamba":
                 out, _, _ = _mamba_rows(
                     params, pre, u,
@@ -408,7 +409,7 @@ def prefill_forward(params, tokens, length, offset, table_row, pools,
     ai = mi = 0
     for i, kind in enumerate(cfg.layer_types):
         pre = "blk%d_" % i
-        u = _rms_norm(x, params[pre + "mixer_norm_gamma"], cfg.rms_norm_eps)
+        u = rms_norm(x, params[pre + "mixer_norm_gamma"], cfg.rms_norm_eps)
         if kind == "mamba":
             out, state, context = _mamba_rows(
                 params, pre, u, pools["ssm_state"][mi, slot],
@@ -461,7 +462,7 @@ def decode_step(params, tokens, lengths, tables, pools, counters, cfg,
     ai = mi = 0
     for i, kind in enumerate(cfg.layer_types):
         pre = "blk%d_" % i
-        u = _rms_norm(x, params[pre + "mixer_norm_gamma"], cfg.rms_norm_eps)
+        u = rms_norm(x, params[pre + "mixer_norm_gamma"], cfg.rms_norm_eps)
         if kind == "mamba":
             z, xbc, dt = _mamba_inputs(params, pre, u, cfg, exact)
             with jax.named_scope("ssm_conv"):

@@ -114,7 +114,10 @@ head count.
 **A windowed layer's ring** (``kw_pool`` / ``vw_pool``; THIS file is the
 only place that knows its layout):
 
-    kw_pool, vw_pool : (window layers, slots, ring rows, H, D)
+    kw_pool, vw_pool : (window layers, slots, ring rows, H, D), or
+                       (window layers, slots, ring rows, H * D) where
+                       the page pools fold: one rule,
+                       :func:`kv_pool_shape`'s, with a slot for a page
 
 No page indexes it: every slot owns ``ring_pages * page_size`` rows a
 windowed layer for the session's life, and position ``p`` of a slot's
@@ -128,8 +131,9 @@ chunk, attends over them and the chunk's own rows, and only then folds
 the chunk's last rows in.  The step functions never compute a row's index
 or a row's position themselves: one token a slot is written through
 :func:`append_rows` at ``lengths % rows``, a chunk's last rows through
-:func:`fold_into_ring` (one dense rewrite of the slot's ring: bucket
-padding writes nothing), and what a row holds is read off
+:func:`fold_into_ring` (a scatter of the slot's ring rows that the chunk
+reaches: bucket padding writes nothing), a layer's rings are read through
+:func:`read_ring`, and what a row holds is read off
 :func:`ring_positions`, the latest position at most ``newest`` that maps
 to the row, negative where the request has not written it.  A row that is
 stale (the request before, an idle slot's junk token in row 0) therefore
@@ -202,7 +206,7 @@ from ..base import MXNetError
 __all__ = ["PagedKVCache", "kv_pool_shape", "append_rows", "pool_heads",
            "read_pages", "read_context", "latent_pool_shape",
            "append_latent_rows", "read_latent_context", "ring_positions",
-           "fold_into_ring"]
+           "fold_into_ring", "read_ring"]
 
 # a TPU tile's lane count: the last axis of an array at rest is padded to
 # a multiple of it
@@ -213,13 +217,37 @@ _SUBLANES = 8
 
 def kv_pool_shape(layers, rows, page_size, num_heads, head_dim):
     """Shape at rest of a paged K or V pool of ``rows`` pages (the trash
-    page included): heads of whole lane tiles, a whole number of sublane
+    page included), or of a ring pool of ``rows`` slots of ``page_size``
+    ring rows: heads of whole lane tiles, a whole number of sublane
     tiles of them, keep their own axis; narrower or fewer heads fold into
-    the last one (the module docstring has why)."""
+    the last one (the module docstring has why; ten heads of 128 on an
+    axis of their own in a ring lay at rest in tiles a step cannot write
+    a row into, and every decode step turned both ring pools WHOLE into
+    another layout and back, twelve copies of 503 MB each way at
+    Phi-4-mini-flash's six window layers: read in the text compiled for a
+    described v5e, PERF.md, PR 54)."""
     lead = (int(layers), int(rows), int(page_size))
     if head_dim % _LANES == 0 and num_heads % _SUBLANES == 0:
         return lead + (int(num_heads), int(head_dim))
     return lead + (int(num_heads) * int(head_dim),)
+
+
+def read_ring(pool, layer, head_dim, slot=None):
+    """Layer ``layer`` of a ring pool, every slot's or ``slot``'s, as
+    (..., ring rows, H, D) whatever the pool's layout at rest.  One slot's
+    ring is GATHERED (``pool[layer, [slot]]``), as pages are: sliced out,
+    the layout its reader wants (the keys transposed for the scores)
+    travels back through the slice to the pool, and the compiler turns the
+    WHOLE pool into it in front of the chunk and back behind it, 503 MB
+    each way a pool at Phi-4-mini-flash's rings (read in the text compiled
+    for a described v5e: PERF.md, PR 54)."""
+    import jax.numpy as jnp
+
+    if slot is None:
+        ring = pool[layer]
+        return ring.reshape(ring.shape[:2] + (-1, head_dim))
+    ring = pool[layer, jnp.reshape(slot, (1,))]
+    return ring.reshape(ring.shape[1], -1, head_dim)
 
 
 def append_rows(pools, which, layer, major, minor, rows, kv_quant=""):
@@ -333,9 +361,16 @@ def fold_into_ring(pools, which, layer, slot, rows, first, count):
     held = ring_positions(pool.shape[2], first + count - 1)
     from_chunk = held >= first
     taken = rows[jnp.clip(held - first, 0, rows.shape[0] - 1)]
-    pools[name] = pool.at[layer, slot].set(jnp.where(
-        from_chunk[:, None, None], taken.astype(pool.dtype),
-        pool[layer, slot]))
+    # the rows are SCATTERED, a row that keeps what it held to an index
+    # past the ring, which drops it.  Rewritten densely (a select between
+    # the chunk's row and the ring's), the layout the chunk's keys were
+    # made in for their scores (transposed) travels through the select to
+    # the update of a folded pool, and the compiler turns the whole pool
+    # into it and back (PERF.md, PR 54)
+    where = jnp.where(from_chunk, jnp.arange(pool.shape[2]), pool.shape[2])
+    pools[name] = pool.at[layer, slot, where].set(
+        taken.reshape((taken.shape[0],) + pool.shape[3:]).astype(pool.dtype),
+        mode="drop")
 
 
 def _chain_key(prev_key, page_tokens):
@@ -390,8 +425,8 @@ class PagedKVCache:
         self.slots = int(slots)
         self.max_pages_per_slot = int(max_pages_per_slot)
         # -- hybrid-stack layout ------------------------------------------
-        # layer_kinds: per-layer "full" | "window" | "ssm" (empty = all
-        # full-attention).  Only FULL layers occupy the paged pools —
+        # layer_kinds: per-layer "full" | "window" | "ssm" | "shared" (empty
+        # = all full-attention).  Only FULL layers occupy the paged pools —
         # the pool's layer axis is the full-layer count, so a hybrid
         # stack's page costs proportionally less and a fixed pool budget
         # admits proportionally more slots.  Windowed layers get a fixed
@@ -400,19 +435,23 @@ class PagedKVCache:
         # place, and the attention mask saturates visibility at the
         # window).  SSM layers own neither: what they keep a slot is in
         # ``state`` (below), the per-layer state pools beside the KV
-        # pools.
+        # pools.  SHARED layers own nothing at all: they read what
+        # another layer of the stack owns (its pages, or a value of the
+        # same step), so no pool has an entry for them and
+        # ``pool_bytes()`` counts nothing.
         self.layer_kinds = tuple(layer_kinds) or ("full",) * self.num_layers
         if len(self.layer_kinds) != self.num_layers:
             raise MXNetError(
                 "PagedKVCache: layer_kinds %r does not cover %d layers"
                 % (self.layer_kinds, self.num_layers))
-        bad = set(self.layer_kinds) - {"full", "window", "ssm"}
+        bad = set(self.layer_kinds) - {"full", "window", "ssm", "shared"}
         if bad:
             raise MXNetError("PagedKVCache: unknown layer kinds %r"
                              % sorted(bad))
         self.n_full = self.layer_kinds.count("full")
         self.n_window = self.layer_kinds.count("window")
         self.n_ssm = self.layer_kinds.count("ssm")
+        self.n_shared = self.layer_kinds.count("shared")
         self.window = int(window)
         self.ring_pages = int(ring_pages)
         if self.n_window and (self.window < 1 or self.ring_pages < 1):
@@ -469,8 +508,9 @@ class PagedKVCache:
         # owns exactly ring_pages pages for each windowed layer, for the
         # session's whole lifetime (that is the O(1)-per-slot story)
         if self.n_window:
-            ring_shape = (self.n_window, self.slots, self.ring_tokens,
-                          self.num_heads, self.head_dim)
+            ring_shape = kv_pool_shape(
+                self.n_window, self.slots, self.ring_tokens, self.num_heads,
+                self.head_dim)
             self.pools["kw_pool"] = jnp.zeros(ring_shape, dtype)
             self.pools["vw_pool"] = jnp.zeros(ring_shape, dtype)
             if self.kv_quant:

@@ -60,14 +60,14 @@ import functools
 import math
 
 from ..base import MXNetError
-from ..ops.attention import (attend_block, finalize_attention,
-                             flash_attention, paged_decode_attention,
+from ..ops.attention import (flash_attention, paged_decode_attention,
                              paged_prefill_attention)
 from . import latent_moe
 from .kv_cache import (append_rows, fold_into_ring, kv_pool_shape,
-                       ring_positions)
-from .latent_moe import (_ffn_held, _head, _head_gate, _resolve, _rms_norm,
-                         fold_named, held_range, prefill_block, read_named)
+                       read_ring, ring_positions)
+from .latent_moe import (_ffn_held, _head, _head_gate, _resolve, fold_named,
+                         held_range, prefill_block, read_named)
+from .layers import rms_norm, window_decode, window_prefill
 # the expert layer is the latent block's, and so is what it asks of XLA
 # (the same pass would carry the K/V pools and the rings as bfloat16)
 from .latent_moe import compiler_options  # noqa: F401
@@ -331,79 +331,6 @@ def _qkv(params, pre, u, positions, heads, kind, cfg, exact):
             _mm(u, params[pre + "v_weight"], exact).reshape(n, kv, hd))
 
 
-def _attend_once(q, k, v, seen, exact):
-    """Softmax attention over one block of keys.  q (..., Q, D) float32,
-    scaled; k, v (..., K, D); ``seen`` broadcastable to (..., Q, K)."""
-    import jax.numpy as jnp
-
-    acc = jnp.zeros(q.shape[:-1] + (v.shape[-1],), jnp.float32)
-    m = jnp.full(q.shape[:-1] + (1,), -jnp.inf, jnp.float32)
-    acc, _, l = attend_block(q, k, v, acc, m, jnp.zeros_like(m),
-                             kv_valid=seen, mi=exact)
-    return finalize_attention(acc, l)
-
-
-def _band(q_pos, k_pos, window):
-    """(Q,), (K,) positions -> (Q, K) bool: a key that was written (its
-    position is not negative) inside the query's band."""
-    behind = q_pos[:, None] - k_pos[None, :]
-    return (k_pos[None, :] >= 0) & (behind >= 0) & (behind < window)
-
-
-def _window_prefill(q, k, v, ring_k, ring_v, ring_pos, abs_pos, window,
-                    exact):
-    """A chunk's window attention.  q (T, KV, G, D), k and v (T, KV, D) the
-    chunk's own rows at positions ``abs_pos`` (T,); ``ring_k``, ``ring_v``
-    (R, KV, D) the slot's ring as the chunks before left it, its rows at
-    positions ``ring_pos`` (R,).  A block of R queries at a time where the
-    chunk is whole blocks: the first sees [ring | its own rows], a later
-    one the block before it and its own rows (every key further back is
-    outside its band, R >= window).  -> (T, KV, G * D)."""
-    import jax.numpy as jnp
-
-    t, kv, g, d = q.shape
-    ring = ring_k.shape[0]
-    block = ring if t > ring and t % ring == 0 else t
-    q32 = q.astype(jnp.float32) * d ** -0.5
-    outs = []
-    for start in range(0, t, block):
-        if start == 0:
-            keys = jnp.concatenate([ring_k.astype(k.dtype), k[:block]])
-            values = jnp.concatenate([ring_v.astype(v.dtype), v[:block]])
-            k_pos = jnp.concatenate([ring_pos, abs_pos[:block]])
-        else:
-            keys, values = k[start - ring:start + block], \
-                v[start - ring:start + block]
-            k_pos = abs_pos[start - ring:start + block]
-        q_pos = jnp.repeat(abs_pos[start:start + block], g)
-        # a key/value head's query heads are its rows: row t * G + g sees
-        # what row t sees
-        out = _attend_once(
-            q32[start:start + block].transpose(1, 0, 2, 3).reshape(
-                kv, block * g, d),
-            keys.transpose(1, 0, 2), values.transpose(1, 0, 2),
-            _band(q_pos, k_pos, window)[None], exact)
-        outs.append(out.reshape(kv, block, g * d).transpose(1, 0, 2))
-    return jnp.concatenate(outs).astype(q.dtype)
-
-
-def _window_decode(q, ring_k, ring_v, lengths, window, exact):
-    """One token a slot over its ring.  q (S, KV, G, D); rings
-    (S, R, KV, D), this token's row appended; ``lengths`` (S,) its
-    position.  -> (att (S, KV, G * D), ring rows inside the band (S,))."""
-    import jax.numpy as jnp
-
-    s, kv, g, d = q.shape
-    k_pos = ring_positions(ring_k.shape[1], lengths)            # (S, R)
-    behind = lengths[:, None] - k_pos
-    seen = (k_pos >= 0) & (behind < window)
-    att = _attend_once(q.astype(jnp.float32) * d ** -0.5,
-                       ring_k.transpose(0, 2, 1, 3),
-                       ring_v.transpose(0, 2, 1, 3),
-                       seen[:, None, None, :], exact)
-    return att.reshape(s, kv, g * d).astype(q.dtype), seen.sum(axis=1)
-
-
 def full_forward(params, tokens, cfg, exact, block=None):
     """(n, T) int tokens -> (n, T, V) logits: the forward the cached paths
     are held against.  ``block`` is the attention's key block (T by
@@ -426,8 +353,8 @@ def full_forward(params, tokens, cfg, exact, block=None):
         for i, (kind, heads) in enumerate(zip(cfg.layer_types,
                                               layer_heads(cfg))):
             pre = "blk%d_" % i
-            u = _rms_norm(x, params[pre + "attn_norm_gamma"],
-                          cfg.rms_norm_eps)
+            u = rms_norm(x, params[pre + "attn_norm_gamma"],
+                         cfg.rms_norm_eps)
             q, k, v = _qkv(params, pre, u, positions, heads, kind, cfg,
                            exact)
             k, v = (jnp.repeat(a, heads // cfg.kv_heads, axis=1
@@ -487,14 +414,15 @@ def prefill_forward(params, tokens, length, offset, table_row, pools,
     for i, (kind, heads) in enumerate(zip(cfg.layer_types,
                                           layer_heads(cfg))):
         pre = "blk%d_" % i
-        u = _rms_norm(x, params[pre + "attn_norm_gamma"], cfg.rms_norm_eps)
+        u = rms_norm(x, params[pre + "attn_norm_gamma"], cfg.rms_norm_eps)
         q, k, v = _qkv(params, pre, u, abs_pos, heads, kind, cfg, exact)
         if kind == "sliding_attention":
             with jax.named_scope("swa_prefill"):
                 rows = pools["kw_pool"].shape[2]
-                att = _window_prefill(
-                    q, k, v, pools["kw_pool"][wi, slot],
-                    pools["vw_pool"][wi, slot],
+                att = window_prefill(
+                    q, k, v,
+                    read_ring(pools["kw_pool"], wi, cfg.attn_head_dim, slot),
+                    read_ring(pools["vw_pool"], wi, cfg.attn_head_dim, slot),
                     ring_positions(rows, offset - 1), abs_pos,
                     cfg.sliding_window, exact)
             with jax.named_scope("swa_append"):
@@ -554,7 +482,7 @@ def decode_step(params, tokens, lengths, tables, pools, counters, cfg,
     for i, (kind, heads) in enumerate(zip(cfg.layer_types,
                                           layer_heads(cfg))):
         pre = "blk%d_" % i
-        u = _rms_norm(x, params[pre + "attn_norm_gamma"], cfg.rms_norm_eps)
+        u = rms_norm(x, params[pre + "attn_norm_gamma"], cfg.rms_norm_eps)
         q, k, v = _qkv(params, pre, u, lengths, heads, kind, cfg, exact)
         if kind == "sliding_attention":
             with jax.named_scope("swa_append"):
@@ -562,9 +490,10 @@ def decode_step(params, tokens, lengths, tables, pools, counters, cfg,
                 append_rows(pools, "kw", wi, slot_ids, row, k, "")
                 append_rows(pools, "vw", wi, slot_ids, row, v, "")
             with jax.named_scope("swa_decode"):
-                att, seen = _window_decode(
-                    q, pools["kw_pool"][wi], pools["vw_pool"][wi], lengths,
-                    cfg.sliding_window, exact)
+                att, seen = window_decode(
+                    q, read_ring(pools["kw_pool"], wi, cfg.attn_head_dim),
+                    read_ring(pools["vw_pool"], wi, cfg.attn_head_dim),
+                    lengths, cfg.sliding_window, exact)
             in_band = in_band + jnp.where(live, seen, 0).sum().astype(
                 jnp.int32)
             wi += 1

@@ -81,6 +81,7 @@ from ..base import MXNetError
 from ..ops.attention import decode_attention
 from ..ops.grouped_matmul import grouped_swiglu, grouped_swiglu_eligible
 from .kv_cache import append_latent_rows, read_latent_context
+from .layers import rms_norm
 from .model import _mm, _resolve_params, check_param_shapes, note_traced
 
 BLOCK = "deepseek_v3"
@@ -343,14 +344,6 @@ def report(counters, cfg):
     }
 
 
-def _rms_norm(x, gamma, eps):
-    import jax.numpy as jnp
-    from jax import lax
-
-    var = jnp.mean(x * x, axis=-1, keepdims=True)
-    return x * lax.rsqrt(var + eps) * gamma
-
-
 def _rope(x, positions, theta):
     """Rotate the interleaved pairs (2j, 2j + 1) of ``x`` (N, ..., rope)
     by ``positions`` (N,) x theta ** (-2j / rope)."""
@@ -385,8 +378,8 @@ def _query_and_row(params, pre, u, positions, cfg, exact):
         [q[..., :nope], _rope(q[..., nope:], positions, cfg.rope_theta)],
         axis=-1)
     kva = _mm(u, params[pre + "kv_a_weight"], exact)
-    c = _rms_norm(kva[:, :rank], params[pre + "kv_norm_gamma"],
-                  cfg.rms_norm_eps)
+    c = rms_norm(kva[:, :rank], params[pre + "kv_norm_gamma"],
+                 cfg.rms_norm_eps)
     r = _rope(kva[:, rank:], positions, cfg.rope_theta)
     return q, jnp.concatenate([c, r], axis=-1)
 
@@ -568,7 +561,7 @@ def _ffn_out(params, i, x, cfg, exact, dequantized):
     import jax
 
     pre = "blk%d_" % i
-    u = _rms_norm(x, params[pre + "ffn_norm_gamma"], cfg.rms_norm_eps)
+    u = rms_norm(x, params[pre + "ffn_norm_gamma"], cfg.rms_norm_eps)
     if i < cfg.first_k_dense:
         return _swiglu(u, params[pre + "gate_weight"],
                        params[pre + "up_weight"],
@@ -657,7 +650,7 @@ def _stats_after(counters, incs, decode):
 
 
 def _head(params, x, cfg, exact):
-    x = _rms_norm(x, params["final_norm_gamma"], cfg.rms_norm_eps)
+    x = rms_norm(x, params["final_norm_gamma"], cfg.rms_norm_eps)
     return _mm(x, params["lm_head_weight"], exact)
 
 
@@ -681,8 +674,8 @@ def full_forward(params, tokens, cfg, exact, block=None):
                      axis=0)
         for i in range(cfg.num_layers):
             pre = "blk%d_" % i
-            u = _rms_norm(x, params[pre + "attn_norm_gamma"],
-                          cfg.rms_norm_eps)
+            u = rms_norm(x, params[pre + "attn_norm_gamma"],
+                         cfg.rms_norm_eps)
             q, rows = _query_and_row(params, pre, u, positions, cfg, exact)
             att = _attend_materialised(params, pre, q, rows, positions + 1,
                                        cfg, exact, block or t)
@@ -742,8 +735,8 @@ def prefill_forward(params, tokens, length, offset, table_row, pools,
     for i in range(cfg.num_layers):
         pre = "blk%d_" % i
         with jax.named_scope("mla_prefill"):
-            u = _rms_norm(x, params[pre + "attn_norm_gamma"],
-                          cfg.rms_norm_eps)
+            u = rms_norm(x, params[pre + "attn_norm_gamma"],
+                         cfg.rms_norm_eps)
             q, rows = _query_and_row(params, pre, u, abs_pos, cfg, exact)
             append_latent_rows(pools, i, pages, offsets, rows)
             ctx = read_latent_context(pools["latent_pool"], i, table_row)
@@ -784,8 +777,8 @@ def decode_step(params, tokens, lengths, tables, pools, counters, cfg,
     for i in range(cfg.num_layers):
         pre = "blk%d_" % i
         with jax.named_scope("mla_decode"):
-            u = _rms_norm(x, params[pre + "attn_norm_gamma"],
-                          cfg.rms_norm_eps)
+            u = rms_norm(x, params[pre + "attn_norm_gamma"],
+                         cfg.rms_norm_eps)
             q, rows = _query_and_row(params, pre, u, lengths, cfg, exact)
             append_latent_rows(pools, i, page, offset, rows)
             ctx = read_latent_context(pools["latent_pool"], i, tables)

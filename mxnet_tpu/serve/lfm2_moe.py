@@ -69,8 +69,9 @@ from ..ops.mamba2 import causal_conv, conv_step
 from . import latent_moe
 from .kv_cache import append_rows, kv_pool_shape
 from .laguna import ATTN_COLUMNS, MOE_COLUMNS, _rope
-from .latent_moe import (_ffn_held, _resolve, _rms_norm, fold_named,
-                         held_range, prefill_block, read_named)
+from .latent_moe import (_ffn_held, _resolve, fold_named, held_range,
+                         prefill_block, read_named)
+from .layers import rms_norm
 # the expert layer is the latent block's and the K/V pools the Mamba-2
 # block's, and so is what both ask of XLA (the same pass would carry the
 # expert stacks and the folded pools through a step as bfloat16)
@@ -278,8 +279,8 @@ def _qkv(params, pre, u, positions, cfg, exact):
     q = _mm(u, params[pre + "q_weight"], exact).reshape(n, cfg.num_heads, hd)
     k = _mm(u, params[pre + "k_weight"], exact).reshape(n, kv, hd)
     with jax.named_scope("gqa_qknorm"):
-        q = _rms_norm(q, params[pre + "q_norm_gamma"], cfg.rms_norm_eps)
-        k = _rms_norm(k, params[pre + "k_norm_gamma"], cfg.rms_norm_eps)
+        q = rms_norm(q, params[pre + "q_norm_gamma"], cfg.rms_norm_eps)
+        k = rms_norm(k, params[pre + "k_norm_gamma"], cfg.rms_norm_eps)
     with jax.named_scope("gqa_rope"):
         group = {"rope_theta": cfg.rope_theta}      # plain, the whole head
         q, k = _rope(q, positions, group), _rope(k, positions, group)
@@ -293,7 +294,7 @@ def _scale(cfg):
 
 
 def _head(params, x, cfg, exact):
-    x = _rms_norm(x, params["final_norm_gamma"], cfg.rms_norm_eps)
+    x = rms_norm(x, params["final_norm_gamma"], cfg.rms_norm_eps)
     return _mm(x, params["tok_embed_weight"], exact)
 
 
@@ -325,8 +326,8 @@ def full_forward(params, tokens, cfg, exact, block=None):
         x = _embed(params, seq)
         for i, kind in enumerate(cfg.layer_types):
             pre = "blk%d_" % i
-            u = _rms_norm(x, params[pre + "operator_norm_gamma"],
-                          cfg.rms_norm_eps)
+            u = rms_norm(x, params[pre + "operator_norm_gamma"],
+                         cfg.rms_norm_eps)
             if kind == "conv":
                 out, _ = _conv_rows(params, pre, u,
                                     jnp.zeros(zeros, u.dtype), t, cfg, exact)
@@ -384,8 +385,8 @@ def prefill_forward(params, tokens, length, offset, table_row, pools,
     ai = ci = 0
     for i, kind in enumerate(cfg.layer_types):
         pre = "blk%d_" % i
-        u = _rms_norm(x, params[pre + "operator_norm_gamma"],
-                      cfg.rms_norm_eps)
+        u = rms_norm(x, params[pre + "operator_norm_gamma"],
+                     cfg.rms_norm_eps)
         if kind == "conv":
             out, context = _conv_rows(
                 params, pre, u, pools["conv_state"][ci, slot], length, cfg,
@@ -441,8 +442,8 @@ def decode_step(params, tokens, lengths, tables, pools, counters, cfg,
     ai = ci = 0
     for i, kind in enumerate(cfg.layer_types):
         pre = "blk%d_" % i
-        u = _rms_norm(x, params[pre + "operator_norm_gamma"],
-                      cfg.rms_norm_eps)
+        u = rms_norm(x, params[pre + "operator_norm_gamma"],
+                     cfg.rms_norm_eps)
         if kind == "conv":
             g, c = _gates(params, pre, u, cfg, exact)
             with jax.named_scope("sconv_mix"):

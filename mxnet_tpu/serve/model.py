@@ -43,7 +43,7 @@ import importlib
 from ..base import MXNetError, get_env
 from ..ops.attention import (decode_attention, flash_attention,
                              paged_decode_attention, paged_prefill_attention)
-from .kv_cache import append_rows, read_context
+from .kv_cache import append_rows, read_context, read_ring
 
 __all__ = ["ModelConfig", "BLOCKS", "block_of", "exact_mode", "init_params",
            "config_from_params", "full_forward", "prefill_forward",
@@ -57,7 +57,7 @@ __all__ = ["ModelConfig", "BLOCKS", "block_of", "exact_mode", "init_params",
 BLOCKS = {"gpt2": "model", "deepseek_v3": "latent_moe",
           "granitemoehybrid": "granite_hybrid", "laguna": "laguna",
           "bailing_hybrid": "bailing_hybrid", "lfm2_moe": "lfm2_moe",
-          "sdar_moe": "sdar_moe"}
+          "sdar_moe": "sdar_moe", "phi4flash": "phi4flash"}
 
 
 def block_of(cfg):
@@ -86,8 +86,9 @@ class ModelConfig:
     A layer's kind is stated once, here: ``layer_types`` names every
     layer in the block's own words and :attr:`kinds` maps them to the
     cache's (``"full"`` owns pages, ``"window"`` a ring, ``"ssm"`` only
-    slot-private state).  No ``ServeConfig`` field and no environment
-    variable says what a layer is.
+    slot-private state, ``"shared"`` nothing: it reads what another layer
+    owns).  No ``ServeConfig`` field and no environment variable says
+    what a layer is.
 
     ``block`` names the architecture, a key of :data:`BLOCKS`.  ``"gpt2"``
     (learned positions, LayerNorm, GELU, biased fused-QKV heads) is what
@@ -130,7 +131,16 @@ class ModelConfig:
     both ways inside a block, softmax-routed experts, an untied head)
     takes the expert fields, ``num_key_value_heads``, ``attn_head_dim`` and
     the last group: the block's length, the mask token, and the passes and
-    the confidence threshold of its unmasking.
+    the confidence threshold of its unmasking.  ``"phi4flash"``
+    (``phi4flash.py``: Mamba-1 and window differential attention in the
+    first half, one full-attention layer whose pages every later
+    cross-attention layer reads, gated memory units on one Mamba layer's
+    scan output, LayerNorm, a tied head, no positions) counts
+    DIFFERENTIAL heads, pairs of the published ones: ``num_heads`` 20 of
+    2 x 64 over ``num_key_value_heads`` 10; ``layer_types`` of ``"mamba"``
+    | ``"sliding_attention"`` | ``"full_attention"`` | ``"gmu"`` |
+    ``"cross_attention"``, ``sliding_window``, ``d_ff``, ``mamba_d_state``,
+    ``mamba_d_conv`` and the last three fields.
     """
     vocab_size: int
     num_layers: int
@@ -194,6 +204,10 @@ class ModelConfig:
     mask_token_id: int = -1     # what a row not yet unmasked holds
     denoising_steps: int = 0    # denoise passes a block takes at most
     confidence_threshold: float = 0.0   # a masked row over it is unmasked
+    mamba_expand: int = 2       # a Mamba-1 mixer's d_inner over d_model
+    mamba_dt_rank: int = 0      # the rank of its step's projection; 0:
+    #                             ceil(d_model / 16)
+    layer_norm_eps: float = 1e-5    # of a LayerNorm block's norms
 
     def __post_init__(self):
         if isinstance(self.rope_parameters, dict):
@@ -213,12 +227,17 @@ class ModelConfig:
     def kinds(self):
         """Per-layer kinds in the cache's words, ``num_layers`` entries:
         ``"full"`` owns pages, ``"window"`` a ring, ``"ssm"`` nothing but
-        the slot-private state its block's ``state_shapes`` names."""
+        the slot-private state its block's ``state_shapes`` names,
+        ``"shared"`` nothing at all (a gated memory unit reads a scan's
+        output of the same step, a cross-attention layer another layer's
+        pages)."""
         if self.layer_types:
             return tuple({"attention": "full", "mamba": "ssm", "mla": "full",
                           "kda": "ssm", "conv": "ssm",
                           "full_attention": "full",
-                          "sliding_attention": "window"}.get(t, t)
+                          "sliding_attention": "window",
+                          "gmu": "shared",
+                          "cross_attention": "shared"}.get(t, t)
                          for t in self.layer_types)
         return ("full",) * self.num_layers
 
@@ -558,7 +577,7 @@ def _block_attention(params, i, x, cfg, exact, block, kv_quant="",
     return x + out, (k, v)
 
 
-def _ring_gather(pools, which, i, pb_max, page_size, slot=None):
+def _ring_gather(pools, which, i, pb_max, page_size, head_dim, slot=None):
     """Gather layer ``i`` of the ring ``pools[which + "_pool"]`` (and its
     scales, where the mapping holds them) in ascending-absolute-position
     order.
@@ -575,8 +594,8 @@ def _ring_gather(pools, which, i, pb_max, page_size, slot=None):
     import jax.numpy as jnp
 
     pool, scale_pool = pools[which + "_pool"], pools.get(which + "_scale")
-    ring = pool[i] if slot is None else \
-        jnp.take(pool[i], slot, axis=0)[None]
+    ring = read_ring(pool, i, head_dim) if slot is None else \
+        read_ring(pool, i, head_dim, slot)[None]
     s, ring_tokens = ring.shape[0], ring.shape[1]
     ring_pages = ring_tokens // page_size
     pb = pb_max.reshape(-1, 1)                              # (S, 1)
@@ -733,9 +752,9 @@ def prefill_forward(params, tokens, length, offset, table_row, pools,
                         kv_quant)
             pb_max = jnp.atleast_1d((offset + t_b - 1) // page_size)
             ctx_k, ks, kp = _ring_gather(pools, "kw", wi, pb_max,
-                                         page_size, slot=slot)
+                                         page_size, d, slot=slot)
             ctx_v, vs, _ = _ring_gather(pools, "vw", wi, pb_max,
-                                        page_size, slot=slot)
+                                        page_size, d, slot=slot)
             att = decode_attention(
                 q.reshape(1, t_b, h, d).transpose(0, 2, 1, 3),
                 ctx_k.transpose(0, 2, 1, 3), ctx_v.transpose(0, 2, 1, 3),
@@ -829,8 +848,9 @@ def decode_step(params, tokens, lengths, tables, pools, counters, cfg,
                         v.reshape(s, h, d), kv_quant)
             pb_max = lengths // page_size
             ctx_k, ks, kp = _ring_gather(pools, "kw", wi, pb_max,
-                                         page_size)
-            ctx_v, vs, _ = _ring_gather(pools, "vw", wi, pb_max, page_size)
+                                         page_size, d)
+            ctx_v, vs, _ = _ring_gather(pools, "vw", wi, pb_max,
+                                        page_size, d)
             att = decode_attention(q.reshape(s, h, 1, d),
                                    ctx_k.transpose(0, 2, 1, 3),
                                    ctx_v.transpose(0, 2, 1, 3),
@@ -938,8 +958,9 @@ def verify_step(params, tokens, lengths, tables, pools, counters, cfg,
                 append_rows(pools, "vw", wi, slot_ids, rr, v[:, j], kv_quant)
             pb_max = (lengths + w - 1) // page_size
             ctx_k, ks, kp = _ring_gather(pools, "kw", wi, pb_max,
-                                         page_size)
-            ctx_v, vs, _ = _ring_gather(pools, "vw", wi, pb_max, page_size)
+                                         page_size, d)
+            ctx_v, vs, _ = _ring_gather(pools, "vw", wi, pb_max,
+                                        page_size, d)
             ctx_k = ctx_k.transpose(0, 2, 1, 3)
             ctx_v = ctx_v.transpose(0, 2, 1, 3)
             win = cfg.sliding_window

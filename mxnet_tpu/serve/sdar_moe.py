@@ -91,10 +91,11 @@ from ..ops.attention import (decode_attention, paged_decode_attention,
 from . import latent_moe
 from .kv_cache import append_rows, kv_pool_shape
 from .laguna import ATTN_COLUMNS, MOE_COLUMNS
-from .latent_moe import (_ffn_held, _head, _resolve, _rms_norm, fold_named,
-                         held_range, prefill_block, read_named)
+from .latent_moe import (_ffn_held, _head, _resolve, fold_named, held_range,
+                         prefill_block, read_named)
 # the expert layer is the latent block's, and so is what it asks of XLA
 from .latent_moe import compiler_options  # noqa: F401
+from .layers import rms_norm
 from .lfm2_moe import _embed, _qkv, _scale
 from .model import _mm, check_param_shapes
 # the passes run the GPT-2 block's paged reader: its report
@@ -286,8 +287,8 @@ def full_forward(params, tokens, cfg, exact, block=None):
         x = _embed(params, seq)
         for i in range(cfg.num_layers):
             pre = "blk%d_" % i
-            u = _rms_norm(x, params[pre + "attn_norm_gamma"],
-                          cfg.rms_norm_eps)
+            u = rms_norm(x, params[pre + "attn_norm_gamma"],
+                         cfg.rms_norm_eps)
             q, k, v = _qkv(params, pre, u, positions, cfg, exact)
             k, v = (jnp.pad(a, ((0, pad), (0, 0), (0, 0))
                             ).transpose(1, 0, 2)[None] for a in (k, v))
@@ -340,7 +341,7 @@ def prefill_forward(params, tokens, length, offset, table_row, pools,
     incs = []
     for i in range(cfg.num_layers):
         pre = "blk%d_" % i
-        u = _rms_norm(x, params[pre + "attn_norm_gamma"], cfg.rms_norm_eps)
+        u = rms_norm(x, params[pre + "attn_norm_gamma"], cfg.rms_norm_eps)
         q, k, v = _qkv(params, pre, u, abs_pos, cfg, exact)
         with jax.named_scope("bdiff_prefill"):
             append_rows(pools, "k", i, pages, offsets, k, "")
@@ -427,7 +428,7 @@ def block_pass(params, tokens, quota, fresh, lengths, tables, pools,
     incs = []
     for i in range(cfg.num_layers):
         pre = "blk%d_" % i
-        u = _rms_norm(x, params[pre + "attn_norm_gamma"], cfg.rms_norm_eps)
+        u = rms_norm(x, params[pre + "attn_norm_gamma"], cfg.rms_norm_eps)
         q, k, v = _qkv(params, pre, u, abs_pos.reshape(n), cfg, exact)
         with jax.named_scope("bdiff_pass"):
             append_rows(pools, "k", i, pages, offsets, k, "")

@@ -25,7 +25,7 @@ from mxnet_tpu.testing import faults
 
 from serve_util import lend
 from test_serve_blocks import (BAILING, GPT2, GRANITE, LAGUNA, LATENT, LFM2,
-                               SDAR)
+                               PHI4, SDAR)
 
 # granite's multipliers at 1: at the published 12 a toy model's tied head
 # repeats the prompt's last token for ever, and no stream tells a step
@@ -33,7 +33,8 @@ from test_serve_blocks import (BAILING, GPT2, GRANITE, LAGUNA, LATENT, LFM2,
 BLOCKS = {"gpt2": GPT2, "deepseek_v3": LATENT,
           "granitemoehybrid": dataclasses.replace(
               GRANITE, embedding_multiplier=1.0, residual_multiplier=1.0),
-          "bailing_hybrid": BAILING, "laguna": LAGUNA, "lfm2_moe": LFM2}
+          "bailing_hybrid": BAILING, "laguna": LAGUNA, "lfm2_moe": LFM2,
+          "phi4flash": PHI4}
 # a prompt of 37 tokens goes in three chunks, one of 22 in two
 CONF = dict(slots=3, page_size=8, buckets=(8, 16), max_new=8, max_prompt=40)
 every_block = pytest.mark.parametrize("sess", sorted(BLOCKS), indirect=True)
@@ -367,7 +368,8 @@ def test_a_drain_under_a_step_in_flight_replays_bit_exact(sess):
     assert second.stats["resumes"] == len(resumable)
 
 
-@pytest.mark.parametrize("name", ["gpt2", "granitemoehybrid", "lfm2_moe"])
+@pytest.mark.parametrize("name", ["gpt2", "granitemoehybrid", "lfm2_moe",
+                                  "phi4flash"])
 def test_a_watermark_eviction_under_a_step_in_flight(name):
     sess = build(name, num_pages=7, oversub=True, watermark=1,
                  max_prompt=0)

@@ -87,6 +87,13 @@ SDAR = serve.ModelConfig(
     moe_d_ff=16, n_routed_experts=16, num_experts_per_tok=4,
     scoring_func="softmax", experts_held=(4, 4), block_length=4,
     mask_token_id=60, denoising_steps=4, confidence_threshold=0.9)
+PHI4 = serve.ModelConfig(
+    block="phi4flash", vocab_size=61, num_layers=8, d_model=32, num_heads=2,
+    num_key_value_heads=1, max_len=64, d_ff=48, sliding_window=8,
+    layer_types=("mamba", "sliding_attention") * 2 + (
+        "mamba", "full_attention", "gmu", "cross_attention"),
+    mamba_d_state=4, mamba_dt_rank=4, rms_norm_eps=1e-5,
+    tie_word_embeddings=True)
 CONF = dict(slots=3, page_size=8, buckets=(8, 16), max_new=8)
 
 
@@ -366,7 +373,7 @@ def test_rings_by_the_models_window_beside_pages():
                                         "vw_pool"]
     assert sess.cache.pools["k_pool"].shape \
         == kv_cache.kv_pool_shape(1, 3 * 6 + 1, 8, 2, 8) == (1, 19, 8, 16)
-    assert sess.cache.pools["kw_pool"].shape == (2, 3, 8, 2, 8)
+    assert sess.cache.pools["kw_pool"].shape == (2, 3, 8, 2 * 8)
     assert sess.cache.paged == ("k_pool", "v_pool") and sess.cache.hybrid
     assert sess.cache.ring_tokens == sess.block_report()["ring_rows"] == 8
     # the GPT-2 block's rule, which follows the buckets, is not asked
@@ -442,10 +449,36 @@ def test_pages_alone_and_a_block_pass_in_the_place_of_decode():
     assert sess.decode_report()["steps"] == 1
 
 
+def test_a_block_whose_layers_share_a_cache_is_served_by_an_unedited_session():
+    """The eighth block, whose second half owns no cache: buckets + 1
+    executables, four streams through three slots, a step ahead, pools for
+    one full layer, two rings and three states of eight layers."""
+    sess = serve.InferenceSession(
+        serve.init_params(PHI4, seed=5, scale=0.3), model=PHI4,
+        config=serve.ServeConfig(**CONF))
+    assert sess.block is serve_model.block_of(PHI4)
+    assert sorted(sess.executables) == ["decode", "prefill_16", "prefill_8"]
+    assert PHI4.kinds.count("shared") == 2
+    assert (sess.cache.n_full, sess.cache.n_window, sess.cache.n_ssm,
+            sess.cache.n_shared) == (1, 2, 3, 2)
+    assert sess.cache.pools["k_pool"].shape[0] == 1
+    tokens = served(sess)
+    assert all(len(toks) == 6 for toks in tokens.values())
+    rep = sess.block_report()
+    assert (rep["prefill_chunks"], rep["cross_rows"], rep["shared_readers"]) \
+        == (4, 4, 2)
+    assert sess.decode_report()["steps_ahead"] > 0
+    with pytest.raises(MXNetError, match="does not support.*spec_k"):
+        serve.InferenceSession(
+            serve.init_params(PHI4, seed=5), model=PHI4,
+            config=serve.ServeConfig(spec_k=2, **CONF))
+
+
 # block -> a model of it: with windowed layers where the block has any
 RINGS = {"gpt2": GPT2_WINDOWED, "deepseek_v3": LATENT,
          "granitemoehybrid": GRANITE, "bailing_hybrid": BAILING,
-         "laguna": LAGUNA, "lfm2_moe": LFM2, "sdar_moe": SDAR}
+         "laguna": LAGUNA, "lfm2_moe": LFM2, "sdar_moe": SDAR,
+         "phi4flash": PHI4}
 
 
 @pytest.mark.parametrize("name", sorted(serve_model.BLOCKS))
@@ -466,7 +499,8 @@ def test_a_ring_is_sized_by_the_block_and_by_nothing_else(name):
         assert sess.cache.ring_pages == sess.cache.n_window == 0
         return
     pages = sess.block.ring_pages(model, conf)
-    assert pages == {"gpt2": (8 + 24 - 1 + 7) // 8 + 1, "laguna": 1}[name]
+    assert pages == {"gpt2": (8 + 24 - 1 + 7) // 8 + 1, "laguna": 1,
+                     "phi4flash": 1}[name]
     assert sess.cache.ring_pages == pages
     assert sess.cache.window == model.sliding_window == 8
     assert sess.cache.pools["kw_pool"].shape[:3] == (windowed, 3, pages * 8)

@@ -513,7 +513,7 @@ def _another_rotation(x, positions, group):
 
 
 def _ring_read_after_the_write(q, k, v, ring_k, ring_v, ring_pos, abs_pos,
-                               window, exact, real=laguna._window_prefill):
+                               window, exact, real=laguna.window_prefill):
     """A chunk that folds its rows into the ring first and attends over
     the ring then."""
     pools = {"kw_pool": ring_k[None, None], "vw_pool": ring_v[None, None]}
@@ -533,7 +533,7 @@ FAULTS = {
     "the gate left out": dict(patch=("_head_gate", lambda params, pre, att,
                                      u, heads, exact, scope="": att)),
     "interleaved rotation": dict(patch=("_rope", _another_rotation)),
-    "ring read after the write": dict(patch=("_window_prefill",
+    "ring read after the write": dict(patch=("window_prefill",
                                              _ring_read_after_the_write)),
 }
 
@@ -580,7 +580,7 @@ def test_a_ring_is_sized_by_the_window_whatever_the_buckets(params, buckets,
     assert laguna.ring_pages(cfg, sess.config) * PAGE == rows
     assert sess.cache.ring_tokens == sess.block_report()["ring_rows"] == rows
     assert rows <= window + 2 * PAGE
-    assert sess.cache.pools["kw_pool"].shape == (3, 3, rows, 2, 16)
+    assert sess.cache.pools["kw_pool"].shape == (3, 3, rows, 2 * 16)
     assert sess.cache.pools["k_pool"].shape[0] == 2      # the full layers
     assert sess.cache.n_window == 3 and sess.cache.hybrid
     assert serve_model.ring_pages(cfg, sess.config) != rows // PAGE

@@ -304,7 +304,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
     p = _ffn_layer(7, UNCUT)
     x = jnp.asarray(np.random.RandomState(17).randn(40, 64)
                     .astype(np.float32))
-    u = latent_moe._rms_norm(x, jnp.ones((64,)), 1e-5)
+    u = latent_moe.rms_norm(x, jnp.ones((64,)), 1e-5)
     want = np.asarray(reference.routed(u, p, "blk1_", hf_config(UNCUT)))
     total = np.zeros_like(want)
     computed = np.zeros((40, 4), int)
@@ -558,7 +558,7 @@ def _conv_on_z(params, pre, u, cfg, exact):
     return bcz[:, 2 * d:], bcz[:, d:2 * d]
 
 
-def _no_head_norm(x, gamma, eps, real=lfm2_moe._rms_norm):
+def _no_head_norm(x, gamma, eps, real=lfm2_moe.rms_norm):
     """Heads (N, heads, D) pass as they are; the rows' norms stay."""
     return x if x.ndim == 3 else real(x, gamma, eps)
 
@@ -579,7 +579,7 @@ FAULTS = {
     "taps reversed": [(lfm2_moe, "causal_conv", _taps_reversed),
                       (lfm2_moe, "conv_step", _step_reversed)],
     "convolution on z alone": [(lfm2_moe, "_gates", _conv_on_z)],
-    "qk norm left out": [(lfm2_moe, "_rms_norm", _no_head_norm)],
+    "qk norm left out": [(lfm2_moe, "rms_norm", _no_head_norm)],
     "interleaved rotation": [(lfm2_moe, "_rope", _another_rotation)],
     "bias used as a weight": [(latent_moe, "_route", _bias_as_weight)],
 }

@@ -525,7 +525,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
     rs = np.random.RandomState(7)
     p = {k: jnp.asarray((0.3 * rs.randn(*s)).astype(np.float32))
          for k, s in sorted(shapes.items())}
-    u = latent_moe._rms_norm(jnp.asarray(
+    u = latent_moe.rms_norm(jnp.asarray(
         rs.randn(40, 64).astype(np.float32)), jnp.ones((64,)), 1e-6)
     want = np.asarray(reference.routed(u, p, "blk1_", UNCUT))
     taken, w = latent_moe._route(u, p, "blk1_", cfg)

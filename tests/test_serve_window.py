@@ -140,7 +140,8 @@ def test_ring_cache_bookkeeping():
     # pools only carry FULL layers; rings and state live beside them
     pools = cache.pools
     assert pools["k_pool"].shape[0] == 1
-    assert pools["kw_pool"].shape == (1, 2, 24, 2, 4)
+    # two heads of 4 fold, in a ring as in the pages
+    assert pools["kw_pool"].shape == (1, 2, 24, 8)
     assert pools["ssm_state"].shape == (1, 2, 2, 4, 4)
     assert cache.pool_bytes() > 2 * pools["k_pool"].nbytes
     # alloc re-zeroes the slot's recurrence state (rings need no zeroing:

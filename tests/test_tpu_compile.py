@@ -1174,6 +1174,168 @@ def test_laguna_executables_compile_for_v5e_at_the_published_widths(
                                  (1, 8, LAGUNA_TABLE * 16, 128), 512)
 
 
+# ``phi4-flash-l24-reason``'s executables at Phi-4-mini-flash-reasoning's
+# published widths over the cell's cache: 24 layers by the rule (7 Mamba-1,
+# 6 window and 1 full differential attention, 5 gated memory units, 5
+# cross-attention), the whole vocabulary, a tied head; 24 slots x 256 pages
+# of 16 (the larger bucket, 2048, and ``max_new`` 2048) + the trash page
+# in ONE layer's K/V pools, 10 key/value pairs of 128 folded into 1280
+# lanes (0.50 GB each); six rings of 512 rows, folded
+# likewise (0.38 GB each); seven layers' state as (16, 5120) a slot.  What
+# is compiled is ``phi4flash.decode_step`` / ``phi4flash.prefill_forward``
+# with the TPU's branches taken.
+PHI4_SLOTS, PHI4_TABLE = 24, (2048 + 2048) // 16
+
+
+def _phi4flash_program(one_chip, monkeypatch, bucket, slots=PHI4_SLOTS):
+    """-> the compiled decode step (``bucket`` 0) or prefill chunk of
+    ``bucket`` rows, the cache's pool shapes, and the notes of the trace."""
+    from mxnet_tpu import serve
+    from mxnet_tpu.serve import kv_cache, phi4flash
+    from mxnet_tpu.serve import model as serve_model
+
+    cfg = serve.ModelConfig(
+        block="phi4flash", vocab_size=200064, num_layers=24, d_model=2560,
+        num_heads=20, num_key_value_heads=10, max_len=262144, d_ff=10240,
+        layer_types=phi4flash.layer_rule(24), sliding_window=512,
+        mamba_d_state=16, mamba_d_conv=4, mamba_expand=2, mamba_dt_rank=160,
+        rms_norm_eps=1e-5, layer_norm_eps=1e-5,
+        tie_word_embeddings=True).validate()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    i32 = jnp.int32
+    params = {k: sds(v) for k, v in phi4flash.param_shapes(cfg).items()}
+    assert abs(sum(math.prod(v.shape) for v in params.values())
+               - 3022.86e6) < 1e4          # 12.09 GB in float32
+    page = 16
+    shapes = {"k_pool": kv_cache.kv_pool_shape(
+        1, slots * PHI4_TABLE + 1, page, 10, 128)}
+    shapes["v_pool"] = shapes["k_pool"]
+    shapes["kw_pool"] = shapes["vw_pool"] = kv_cache.kv_pool_shape(
+        6, slots, 512, 10, 128)
+    assert shapes["k_pool"][-1] == shapes["kw_pool"][-1] == 1280
+    for name, (layers, shape, _) in phi4flash.state_shapes(cfg).items():
+        shapes[name] = (layers, slots) + tuple(shape)
+    pools = {name: sds(shape) for name, shape in shapes.items()}
+    counters = {name: sds(leaf.shape, i32) for name, leaf
+                in phi4flash.init_counters(cfg).items()}
+    static = dict(cfg=cfg, page_size=page, exact=False, kv_quant="")
+    if bucket:
+        def step(params, tokens, length, offset, table_row, pools, counters,
+                 slot):
+            return phi4flash.prefill_forward(
+                params, tokens, length, offset, table_row, pools, counters,
+                slot=slot, **static)
+
+        avals = (params, sds((1, bucket), i32), sds((), i32), sds((), i32),
+                 sds((PHI4_TABLE,), i32), pools, counters, sds((), i32))
+        donate = (5, 6)
+    else:
+        def step(params, tokens, lengths, tables, pools, counters):
+            return phi4flash.decode_step(params, tokens, lengths, tables,
+                                         pools, counters, **static)
+
+        avals = (params, sds((slots,), i32), sds((slots,), i32),
+                 sds((slots, PHI4_TABLE), i32), pools, counters)
+        donate = (4, 5)
+    with jax.default_matmul_precision("default"), \
+            serve_model.trace_notes() as notes:
+        lowered = jax.jit(step, donate_argnums=donate).lower(*avals)
+    return lowered.compile(
+        compiler_options=phi4flash.compiler_options("tpu")), shapes, notes
+
+
+@pytest.mark.parametrize("bucket, temporaries", [
+    (0, 1 << 28), (512, 1 << 28), (2048, 2 << 28)],
+    ids=["decode", "prefill-512", "prefill-2048"])
+def test_phi4flash_executables_compile_for_v5e_at_the_published_widths(
+        one_chip, monkeypatch, bucket, temporaries):
+    """The whole step fits the chip beside its arguments (13.92 GB: 12.09 of
+    weights, 1.01 of one layer's pages, 0.75 of rings, 0.07 of state):
+    under 0.27 GB of temporaries in a decode step and 0.54 GB in a chunk,
+    and the compiler rematerializes nothing (at 32 slots it does: the
+    test below).  The owner and the five cross-attention layers
+    read the ONE layer of pages through the paged-attention kernel (six
+    calls: in a chunk for its last row only), a chunk's seven scans are
+    the selective-scan kernel, and the donated pools, rings and states are
+    updated where they lie: the result aliases all six, and no operation
+    copies a whole page pool or a whole ring pool into another layout (ten
+    heads of 128 on an axis of their own in a ring did: twelve copies of
+    503 MB each way a decode step and 1.35 GB of temporaries, over the
+    chip: PERF.md, PR 54)."""
+    from mxnet_tpu.ops import mamba1, paged_attention
+
+    compiled, shapes, notes = _phi4flash_program(one_chip, monkeypatch,
+                                                 bucket)
+    assert notes == dict({"paged_kernel_layers": 6}, **(
+        {"sscan_kernel_layers": 7} if bucket else {}))
+    text = compiled.as_text()
+    assert len(_kernel_calls(text, mamba1.SCAN_KERNEL_NAME)) == (
+        7 if bucket else 0)
+    assert len(_kernel_calls(
+        text, paged_attention.kernel_name(32, 128))) == 6
+    memory = compiled.memory_analysis()
+    held = 4 * sum(math.prod(shape) for shape in shapes.values())
+    assert 1.82e9 < held < 1.84e9
+    assert memory.alias_size_in_bytes >= held
+    assert 13.91e9 < memory.argument_size_in_bytes < 13.93e9
+    assert memory.temp_size_in_bytes < temporaries
+    assert not _rematerialized(text)
+    for shape in (shapes["k_pool"], shapes["kw_pool"]):
+        copies, _ = _whole_pool_copies(text, shape)
+        assert not copies, copies
+
+
+def _rematerialized(text):
+    """Shapes of the operations the compiler computes a second time to
+    stay inside its memory limit (``<name>.remat``)."""
+    return re.findall(r"%\S+\.remat\S* = (\w+\[[\d,]*\])", text)
+
+
+def test_at_32_slots_the_state_updates_are_rematerialized(one_chip,
+                                                          monkeypatch):
+    """Why the cell has 24 slots (ISSUE 54's fallback).  At 32 slots and
+    tables of 256 pages the arguments are 14.53 GB and the compiler stays
+    inside its limit by computing three in-place updates of donated pools
+    twice in every decode step: a ring's append, which written twice is
+    written once, and the Mamba-1 state's recurrence and the convolution
+    rows' shift of one layer, which are not (which of them goes wrong was
+    not isolated on the chip).  What such executables served
+    failed the cell's comparison on the chip on 8 seeds of 8 (mean gap
+    3.3e-3 to 4.6e-3 where 3e-4 is sound, beside an int8 control that
+    holds 9 GB less and read what it always reads); at tables of 192 pages
+    (buckets 512 and 1024) nothing was rematerialized and 9 seeds of 9
+    were sound (PERF.md, PR 54)."""
+    compiled, shapes, _ = _phi4flash_program(one_chip, monkeypatch, 0,
+                                             slots=32)
+    assert 14.52e9 < compiled.memory_analysis().argument_size_in_bytes \
+        < 14.54e9
+    twice = _rematerialized(compiled.as_text())
+    for name in ("ssm_state", "conv_state", "vw_pool"):
+        assert "f32[%s]" % ",".join(map(str, shapes[name])) in twice, twice
+
+
+def test_the_selective_scan_kernel_compiles_for_v5e(one_chip):
+    """A chunk of 2 048 rows at d_inner 5120 and 16 states: Mosaic takes
+    the kernel's aligned (8, 512) loads, the (16, 128) B and C tiles
+    repeated across a channel tile and the state carried in VMEM across
+    the row blocks."""
+    from mxnet_tpu.ops import mamba1
+
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    t, di, n = 2048, 5120, 16
+    compiled = _compile(mamba1._scan_kernel, sds(t, di), sds(t, di),
+                        sds(n, di), sds(t, n), sds(t, n), sds(di),
+                        sds(n, di))
+    assert len(_kernel_calls(compiled.as_text(),
+                             mamba1.SCAN_KERNEL_NAME)) == 1
+
+
 # A grouped-query (or dense) attention layer's prefill half at the four
 # cells' pool shapes and both buckets of each: the chunk's rows appended to
 # the donated K and V pools at the slot's pages, ``kv_cache.read_context``
