@@ -39,21 +39,25 @@ slots in different passes of different blocks share the one step.  Such a
 session refuses ``oversub``, so nothing is parked; a request handed over
 with committed tokens (``submit(parked=True)``) is refused by name.
 
-A step one ahead: a continuous run's decode step is told it may launch
-the next step before its own tokens are read
-(:meth:`InferenceSession.step`, ``ahead=True``), so the host's work of a
-tick lies under the running step.  The scheduler says so only where it
-can see that nobody needs the chip at the next boundary: no active
-request's returned token is its last (``max_new - len(tokens)``: its
-follow-up's prefill then finds an idle chip, as before), no arrival is
-waiting that the next boundary could admit, and under ``oversub`` the pool
-covers both steps above the watermark.  An end nobody can foresee (an
-``eos_id``, a :meth:`Scheduler.cancel`, a fault, a drain, a watermark
-eviction) finds a step in flight that carried the slot: its row is
-dropped, and an arrival admitted into the slot waits for that step, one
-decode step at most, as in any engine that schedules a step behind.
-``spec_step`` and a diffusion block's step keep their order: their next
-input is computed on the host from the read.
+A step one ahead: a run's decode step is told it may launch the next
+step before its own tokens are read (:meth:`InferenceSession.step`,
+``ahead=True``), so the host's work of a tick lies under the running
+step.  The scheduler says so only where it can see that nobody needs the
+chip at the next boundary: no active request's returned token is its last
+(``max_new - len(tokens)``: its follow-up's prefill then finds an idle
+chip, as before), no arrival is waiting that the next boundary could
+admit, and under ``oversub`` the pool covers both steps above the
+watermark.  A diffusion block's pass runs ahead by the same rule, and
+there a request's end is foreseen exactly: the host holds every token of a
+block before the pass that commits it is read
+(:meth:`InferenceSession.committing`), so the step is held back where
+that block spends a request's ``max_new`` or holds its ``eos_id``.  An end
+nobody can foresee (a decode step's ``eos_id``, a :meth:`Scheduler.cancel`,
+a fault, a drain, a watermark eviction) finds a step in flight that carried
+the slot: its row is dropped, and an arrival admitted into the slot waits
+for that step, one step at most, as in any engine that schedules a step
+behind.  ``spec_step`` keeps its order: its next input is computed on the
+host from the read.
 
 Preemption and resume (oversubscribed sessions,
 ``session.config.oversub``): before every step the scheduler probes the
@@ -556,8 +560,10 @@ class Scheduler(object):
                         self._finish(req, slot, active, now)
                         break
         elif sess.diffusion:
-            committed, _ = sess.step()
+            committed, _ = sess.step(ahead=self._foresees_no_end(blocked))
             for slot in sorted(active):
+                if slot not in committed:
+                    continue    # admitted behind the pass that was read
                 req = active[slot]
                 if committed[slot] and req.ttft_s < 0:
                     req.ttft_s = now() - req.arrival_s
@@ -584,18 +590,31 @@ class Scheduler(object):
         return self.outstanding
 
     def _foresees_no_end(self, blocked):
-        """Whether the coming decode step may launch its successor before
-        it is read: nothing this scheduler can see will want the chip at
-        the next boundary.  ``blocked``: an arrival found no room at this
+        """Whether the coming step may launch its successor before it is
+        read: nothing this scheduler can see will want the chip at the
+        next boundary.  ``blocked``: an arrival found no room at this
         one, so none is admitted before a release."""
         sess, active = self.session, self._active
         config = sess.config
-        if any(req.max_new - len(req.tokens) <= 1
-               for req in active.values()):
+        if sess.diffusion:
+            # the blocks the coming read commits, token for token: one
+            # that spends a request's ``max_new`` or holds its ``eos_id``
+            # ends it
+            committing = sess.committing()
+            ends = any(
+                len(committing[slot]) >= req.max_new - len(req.tokens)
+                or req.eos_id in committing[slot]
+                for slot, req in active.items() if slot in committing)
+            rows = 2 * sess.model.block_length
+        else:
+            ends = any(req.max_new - len(req.tokens) <= 1
+                       for req in active.values())
+            rows = 2
+        if ends:
             # a returned token is a request's last: its slot frees, and
             # whoever takes it should find the chip idle
             return False
-        if (config.oversub and sess.pages_short(2) + config.watermark
+        if (config.oversub and sess.pages_short(rows) + config.watermark
                 > sess.cache.reclaimable_pages):
             return False    # the second step's pages might evict someone
         if (blocked or self.policy != "continuous"

@@ -395,7 +395,10 @@ def block_pass(params, tokens, quota, fresh, lengths, tables, pools,
     their ``decode_step``.
 
     tokens: (S, B) int32, each slot's open block, ``mask_token_id`` on the
-    rows still masked (an idle slot: anything else); quota: (S,) int32,
+    rows still masked (an idle slot: anything else; the session's
+    executable selects a slot's rows in front of this call, the host's or
+    those the pass before left on the device: ``after`` below, which is
+    why a pass can be launched before the last one is read); quota: (S,) int32,
     rows this pass unmasks at least in a slot whose block has a mask
     (:func:`pass_quota`), -1 for an idle slot; fresh: (S,) int32, the
     tokens a slot's block delivers when it is committed, for the count

@@ -153,7 +153,7 @@ unmasked with, and the next block opens.  A
 step so commits 0 to ``block_length`` tokens a slot.  Such a block refuses
 ``spec_k``, ``kv_quant``, ``prefix_pages`` and ``oversub``.
 
-A decode step one ahead (``InferenceSession.step(ahead=True)``): of what a
+A step one ahead (``InferenceSession.step(ahead=True)``): of what a
 decode launch takes, only the ``(slots,)`` token vector depends on the
 launch before it, and that launch's own first result holds it on the
 device.  The decode executable therefore takes three token arguments (the
@@ -166,8 +166,17 @@ launch, ``_slot_tokens`` / ``_slot_history`` and the returned tokens at
 the read.  A launch remembers the slots it carried and the epoch of each
 (:meth:`InferenceSession.prefill` starts a new one); a row whose slot was
 released, or released and filled again, since the launch is dropped at
-the read.  A speculating session and a diffusion block compute their next
-input on the host from the read, so they never run ahead.
+the read.  A diffusion block's pass runs ahead the same way, over a token
+array of ``block_length`` columns: the host has read the pass before the
+unread one, which is the unread one's input, so it knows of every slot
+whether the unread pass is a denoise pass of its block (the next pass
+takes that pass's ``after`` rows from the device, and one more pass counts
+towards its quota) or its commit pass (the next pass opens the next block,
+all masks, ``block_length`` rows further on: every part of it is the
+host's); which rows a pass unmasks stays the device's to decide.  There
+``lengths`` and the open blocks move at the read, as the rule that commits
+a block does.  A speculating session computes its next input on the host
+from the read, so it never runs ahead.
 
 Env knobs (see docs/env_vars.md): ``MXNET_SERVE_SLOTS``,
 ``MXNET_SERVE_PAGE``, ``MXNET_SERVE_BUCKETS``, ``MXNET_SERVE_MAX_NEW``,
@@ -392,17 +401,32 @@ class _OpenBlock(object):
         self.fresh = min(length - self.known, budget)
         self.budget = budget - self.fresh
 
+    def following(self, mask):
+        """The block that opens when this one is committed: all masks."""
+        return _OpenBlock((), mask, len(self.tokens), self.budget)
+
 
 class _Flight(object):
-    """A decode step launched and not read yet: its ``tokens`` and
-    ``logits`` as the launch returned them (device arrays that may not be
-    ready), and the slots it ``carried``, slot -> the epoch the slot was
-    in then."""
+    """A step launched and not read yet: what the launch returned, device
+    arrays that may not be ready (``tokens``, the next launch's input: a
+    decode step's ``(slots,)`` next tokens, a block pass's ``(slots,
+    block_length)`` rows after it, beside which the host reads a pass's
+    ``unmasked`` flags and ``conf``; ``logits``), and the slots it
+    ``carried``, slot -> the epoch the slot was in then."""
 
-    __slots__ = ("tokens", "logits", "carried")
+    __slots__ = ("tokens", "unmasked", "conf", "logits", "carried")
 
-    def __init__(self, tokens, logits, carried):
+    def __init__(self, tokens, logits, carried, unmasked=None, conf=None):
         self.tokens, self.logits, self.carried = tokens, logits, carried
+        self.unmasked, self.conf = unmasked, conf
+
+    def read(self):
+        """What the host reads of it, as numpy arrays (the wait for the
+        launch); the logits stay where they are."""
+        import numpy as np
+
+        return [np.asarray(a) for a in (self.tokens, self.unmasked,
+                                        self.conf) if a is not None]
 
 
 class InferenceSession(object):
@@ -499,11 +523,13 @@ class InferenceSession(object):
         # decode step in flight can tell the request it carried from the
         # next one in the same slot
         self._slot_epoch = [0] * cfg.slots
-        self._flight = None  # the decode step launched and not read yet
-        # what a decode launch passes where every slot's token comes from
-        # one side (nearly always): on the device once, so that a launch
-        # uploads neither a mask nor a vector that nothing reads
-        self._no_tokens = jnp.zeros((cfg.slots,), jnp.int32)
+        self._flight = None  # the step launched and not read yet
+        # what a launch passes where every slot's tokens come from one
+        # side (nearly always): on the device once, so that a launch
+        # uploads neither a mask nor an array that nothing reads
+        self._no_tokens = jnp.zeros(
+            (cfg.slots, self.model.block_length) if self.diffusion
+            else (cfg.slots,), jnp.int32)
         self._all_host = jnp.ones((cfg.slots,), jnp.bool_)
         self._none_host = jnp.zeros((cfg.slots,), jnp.bool_)
         self._slot_budget = {}  # diffusion: slot -> max_new, until prefill
@@ -752,19 +778,23 @@ class InferenceSession(object):
                  sds((cfg.slots, max_pages), i32), pools, counters),
                 donate_argnums=(6, 7))
         else:
-            def block_pass_fn(params, tokens, quota, fresh, lengths, tables,
-                              pools, counters):
+            def block_pass_fn(params, tokens, before, from_host, quota,
+                              fresh, lengths, tables, pools, counters):
+                # a slot's open block: the host's, or the rows the pass
+                # before left where that has not been read yet
+                tokens = jax.numpy.where(from_host[:, None], tokens, before)
                 return block.block_pass(params, tokens, quota, fresh,
                                         lengths, tables, pools, counters,
                                         **static)
 
+            rows = sds((cfg.slots, self.model.block_length), i32)
             self._aot(
                 "block_pass", block_pass_fn, self.params,
-                (param_avals, sds((cfg.slots, self.model.block_length), i32),
+                (param_avals, rows, rows,
+                 sds((cfg.slots,), jax.numpy.bool_), sds((cfg.slots,), i32),
                  sds((cfg.slots,), i32), sds((cfg.slots,), i32),
-                 sds((cfg.slots,), i32), sds((cfg.slots, max_pages), i32),
-                 pools, counters),
-                donate_argnums=(6, 7))
+                 sds((cfg.slots, max_pages), i32), pools, counters),
+                donate_argnums=(8, 9))
 
         # hybrid prefill takes a slot scalar (rings and SSM state are
         # slot-indexed, unlike the table-indirected pages)
@@ -1038,6 +1068,7 @@ class InferenceSession(object):
                 if done is not None:
                     int(done)
             with _span("prefill.publish"):
+                self._slot_epoch[slot] += 1
                 self._slot_tokens[slot] = _OpenBlock(
                     prompt[whole:].tolist(), self.model.mask_token_id, b,
                     self._slot_budget.pop(slot, self.config.max_new))
@@ -1137,36 +1168,40 @@ class InferenceSession(object):
 
         A diffusion block's step is one block pass and commits 0 to
         ``block_length`` tokens a slot: ``tokens`` then maps slot -> a list
-        of ``(token, pass, confidence)`` triples (:meth:`_block_step`);
-        its next pass's input is computed on the host from the read, so it
-        does not run ahead either."""
-        import numpy as np
-
-        if self.diffusion:
-            return self._block_step()
+        of ``(token, pass, confidence)`` triples, none for a slot whose
+        block still held a mask when the pass began, every row the block
+        generated for one whose block the pass committed (a last block's
+        tail past the request's ``max_new`` among them: who asked for
+        fewer drops it, as the scheduler does): the pass being the denoise
+        pass of its block, 0-based, in which the token was unmasked, and
+        the confidence the softmax's value at the token in that pass; the
+        logits are (slots, block_length, vocab).  It runs ahead like a
+        decode step (:meth:`_launch_pass`): the pass launched ahead takes
+        the rows still masked from the unread pass's result on the device,
+        and a slot prefilled while a pass was in flight gets its first
+        pass one call later."""
         ahead = bool(ahead) and not self.config.spec_k
+        launch = self._launch_pass if self.diffusion else self._launch
         with _cpu_span("session.step", live=len(self._slot_tokens),
-                       ahead=int(ahead)):
+                       ahead=int(ahead)) as sp:
             flight, self._flight = self._flight, None
             if flight is None or not any(
                     self._carries(flight, slot) for slot in flight.carried):
                 # nothing in flight, or only rows nobody waits for
-                flight = self._launch(None)
+                flight = launch(None)
             if ahead:
-                self._flight = self._launch(flight)
+                self._flight = launch(flight)
                 self._decode_stats["steps_ahead"] += 1
             with _cpu_span("step.wait"):
-                next_np = np.asarray(flight.tokens)
+                read = flight.read()
             with _span("step.commit"):
-                out = {}
-                for slot in flight.carried:
-                    if not self._carries(flight, slot):
-                        continue    # released since the launch: dropped
-                    tok = int(next_np[slot])
-                    self._slot_tokens[slot] = tok
-                    if slot in self._slot_history:
-                        self._slot_history[slot].append(tok)
-                    out[slot] = tok
+                # a slot released since the launch: dropped
+                held = [slot for slot in flight.carried
+                        if self._carries(flight, slot)]
+                if self.diffusion:
+                    out = self._commit_pass(held, sp, *read)
+                else:
+                    out = self._commit(held, *read)
         return out, flight.logits
 
     def _carries(self, flight, slot):
@@ -1174,6 +1209,21 @@ class InferenceSession(object):
         it: not released since, nor released and prefilled again."""
         return (slot in self._slot_tokens
                 and flight.carried.get(slot) == self._slot_epoch[slot])
+
+    def _feed(self, tokens, before, host):
+        """The three token arguments of a launch: the host's array, the
+        unread launch's result (``before.tokens``) and the mask that says
+        which a slot takes; ``host`` lists the slots whose ``tokens`` are
+        the host's.  An idle slot's row is garbage either way."""
+        import numpy as np
+
+        if before is None:
+            return tokens, self._no_tokens, self._all_host
+        if not host:
+            return self._no_tokens, before.tokens, self._none_host
+        from_host = np.zeros((self.config.slots,), np.bool_)
+        from_host[host] = True
+        return tokens, before.tokens, from_host
 
     def _launch(self, before):
         """Launch one decode step for every live slot -> its
@@ -1194,16 +1244,7 @@ class InferenceSession(object):
                 if before is None or not self._carries(before, slot):
                     tokens[slot] = tok
                     host.append(slot)
-            if before is None:
-                feed = (tokens, self._no_tokens, self._all_host)
-            elif not host:
-                # an idle slot's row is garbage either way
-                feed = (self._no_tokens, before.tokens, self._none_host)
-            else:
-                from_host = np.zeros((cfg.slots,), np.bool_)
-                from_host[host] = True
-                feed = (tokens, before.tokens, from_host)
-            args = (self.params,) + feed + (
+            args = (self.params,) + self._feed(tokens, before, host) + (
                 self.cache.lengths_arg(), self.cache.device_tables(),
                 self.cache.pools, self.counters)
             # the pages this step's reader visits a full layer, each
@@ -1221,71 +1262,116 @@ class InferenceSession(object):
                 self.cache.lengths[slot] += 1
         return _Flight(next_toks, logits, carried)
 
-    def _block_step(self):
-        """:meth:`step` for a diffusion block: ONE pass of the block-pass
-        executable over every live slot's open block.  -> (slot -> the
-        ``(token, pass, confidence)`` triples the step committed, the pass
-        being the denoise pass of its block, 0-based, in which the token
-        was unmasked, and the confidence the softmax's value at the token
-        in that pass: none for a slot whose block still held a mask, every
-        row the block generated for one whose block was committed (a last
-        block's tail past the request's ``max_new`` among them: who asked
-        for fewer drops it, as the scheduler does); the (slots,
-        block_length, vocab) logits, left on the device)."""
+    def _commit(self, held, next_np):
+        """The read of a decode step: the token of every slot it carried
+        and that is still ``held`` becomes the slot's next input.
+        -> slot -> token."""
+        out = {}
+        for slot in held:
+            tok = int(next_np[slot])
+            self._slot_tokens[slot] = tok
+            if slot in self._slot_history:
+                self._slot_history[slot].append(tok)
+            out[slot] = tok
+        return out
+
+    def _launch_pass(self, before):
+        """:meth:`_launch` for a diffusion block: ONE pass of the
+        block-pass executable over every live slot's open block -> its
+        :class:`_Flight`.  ``before`` is the pass in flight whose result
+        this one follows (``None``: every block is on the host).  The
+        host's blocks are that pass's *input*, so of a slot it carried
+        they say which pass it is: a denoise pass (the block holds a
+        mask), and this one takes the rows it leaves from the device, its
+        quota one pass further on; or the block's commit pass, and this
+        one opens the block that follows, all masks, ``block_length`` rows
+        further on (the read moves ``lengths`` there: here a copy is).
+        Any other slot (one a prefill has filled since) feeds the host's
+        block.  Page upkeep and the page count are the launch's."""
         import numpy as np
 
         cfg, model = self.config, self.model
         b, mask = model.block_length, model.mask_token_id
-        with _cpu_span("session.step", live=len(self._slot_tokens)) as sp:
-            with _span("step.prepare"):
-                self._pre_dispatch(b)
-                tokens = np.zeros((cfg.slots, b), np.int32)
-                quota = np.full((cfg.slots,), -1, np.int32)
-                fresh = np.zeros((cfg.slots,), np.int32)
-                denoise = 0
-                for slot, blk in self._slot_tokens.items():
+        with _span("step.prepare"):
+            tokens = np.zeros((cfg.slots, b), np.int32)
+            quota = np.full((cfg.slots,), -1, np.int32)
+            fresh = np.zeros((cfg.slots,), np.int32)
+            lengths = self.cache.lengths_arg()
+            carried, host = {}, []
+            for slot, blk in self._slot_tokens.items():
+                carried[slot] = self._slot_epoch[slot]
+                passes = blk.passes
+                if before is None or not self._carries(before, slot):
                     tokens[slot] = blk.tokens
-                    quota[slot] = self.block.pass_quota(model, blk.passes)
-                    fresh[slot] = blk.fresh
-                    denoise += mask in blk.tokens
-                sp.set(denoise=denoise,
-                       commit=len(self._slot_tokens) - denoise)
-                args = (self.params, tokens, quota, fresh,
-                        self.cache.lengths_arg(), self.cache.device_tables(),
-                        self.cache.pools, self.counters)
-                # the pages this pass's reader visits a layer, the block's
-                # own rows included (decode_pages_visited adds one row)
-                pages = decode_pages_visited(
-                    self.cache.lengths + (b - 1), cfg.page_size,
-                    self.cache.table_width, self._paged_kernel_layers() > 0)
-                self._decode_stats["steps"] += 1
-                self._decode_stats["pages_visited"] += pages
-            with _span("step.launch"):
-                after, unmasked, conf, logits, self.cache.pools, \
-                    self.counters = self._dispatch("block_pass", args)
-            with _cpu_span("step.wait"):
-                after, unmasked, conf = (np.asarray(after),
-                                         np.asarray(unmasked),
-                                         np.asarray(conf))
-            with _span("step.commit"):
-                out = {}
-                for slot, blk in self._slot_tokens.items():
-                    if mask in blk.tokens:      # its denoise pass
-                        for row in np.flatnonzero(unmasked[slot]):
-                            blk.tokens[row] = int(after[slot, row])
-                            blk.at[row] = blk.passes
-                            blk.conf[row] = float(conf[slot, row])
-                        blk.passes += 1
-                        out[slot] = []
-                        continue
-                    # its commit pass: the pages hold the block's rows
-                    self.cache.lengths[slot] += b
-                    out[slot] = [
-                        (blk.tokens[row], blk.at[row], blk.conf[row])
-                        for row in range(blk.known, b)]
-                    self._slot_tokens[slot] = _OpenBlock((), mask, b,
-                                                         blk.budget)
-        return out, logits
+                    host.append(slot)
+                elif mask in blk.tokens:
+                    passes += 1     # the unread pass is one of its block's
+                else:
+                    blk = blk.following(mask)
+                    tokens[slot], passes = blk.tokens, blk.passes
+                    host.append(slot)
+                    lengths[slot] += b
+                quota[slot] = self.block.pass_quota(model, passes)
+                fresh[slot] = blk.fresh
+            self._pre_dispatch(b, lengths)
+            args = (self.params,) + self._feed(tokens, before, host) + (
+                quota, fresh, lengths, self.cache.device_tables(),
+                self.cache.pools, self.counters)
+            # the pages this pass's reader visits a layer, the block's
+            # own rows included (decode_pages_visited adds one row)
+            pages = decode_pages_visited(
+                lengths + (b - 1), cfg.page_size,
+                self.cache.table_width, self._paged_kernel_layers() > 0)
+            self._decode_stats["steps"] += 1
+            self._decode_stats["pages_visited"] += pages
+        with _span("step.launch"):
+            after, unmasked, conf, logits, self.cache.pools, \
+                self.counters = self._dispatch("block_pass", args)
+        return _Flight(after, logits, carried, unmasked, conf)
+
+    def _commit_pass(self, held, sp, after, unmasked, conf):
+        """The read of a block pass, for every slot it carried that is
+        still ``held``: a block that went in with a mask takes the rows
+        the pass unmasked (its denoise pass); one that went in with none
+        is committed: ``lengths`` moves over it, its generated rows are
+        handed out and the next block opens.  The step's span ``sp`` gets
+        how many of each the pass held.
+        -> slot -> its ``(token, pass, confidence)`` triples."""
+        import numpy as np
+
+        b, mask = self.model.block_length, self.model.mask_token_id
+        out, denoise = {}, 0
+        for slot in held:
+            blk = self._slot_tokens[slot]
+            if mask in blk.tokens:      # its denoise pass
+                for row in np.flatnonzero(unmasked[slot]):
+                    blk.tokens[row] = int(after[slot, row])
+                    blk.at[row] = blk.passes
+                    blk.conf[row] = float(conf[slot, row])
+                blk.passes += 1
+                denoise += 1
+                out[slot] = []
+                continue
+            # its commit pass: the pages hold the block's rows
+            self.cache.lengths[slot] += b
+            out[slot] = [(blk.tokens[row], blk.at[row], blk.conf[row])
+                         for row in range(blk.known, b)]
+            self._slot_tokens[slot] = blk.following(mask)
+        sp.set(denoise=denoise, commit=len(out) - denoise)
+        return out
+
+    def committing(self):
+        """A diffusion block: slot -> the tokens the next call of
+        :meth:`step` hands out for it, for every slot whose block the pass
+        that call reads commits (the block holds no mask any more: the
+        host has read them all).  What the host holds is the input of the
+        one pass in flight, or of the pass the call launches where none
+        is; a slot a prefill has filled since opens with a mask, so it is
+        never among them."""
+        mask = self.model.mask_token_id
+        return {slot: blk.tokens[blk.known:]
+                for slot, blk in self._slot_tokens.items()
+                if mask not in blk.tokens}
 
     def spec_step(self, limits=None):
         """One speculative step for every active slot: draft proposes K
@@ -1418,9 +1504,10 @@ class InferenceSession(object):
         is eligible: ``ops/paged_attention.py:paged_attention_eligible``;
         0 on the CPU, under ``exact`` / ``kv_quant`` and for pools that
         fold their heads, where the ``fori_loop`` runs).  ``steps`` decode
-        steps launched since the session was built, ``steps_ahead`` of
-        them before the step in front of them had been read
-        (``step(ahead=True)``); ``pages_visited`` the sum over
+        steps (a diffusion block: block passes) launched since the session
+        was built, ``steps_ahead`` of them before the step in front of
+        them had been read (``step(ahead=True)``); ``pages_visited`` the
+        sum over
         the steps of the pages a full layer's reader visits, each context's new
         row included: with the kernel every slot's own
         ``ceil((length + 1) / page_size)``, an idle slot's one; with the
@@ -1562,17 +1649,21 @@ class InferenceSession(object):
 
     moe_report = block_report   # the name it had while only routers counted
 
-    def _pre_dispatch(self, rows):
+    def _pre_dispatch(self, rows, lengths=None):
         """Per-boundary page upkeep before a decode/verify/draft
         dispatch writes ``rows`` KV rows per active slot: grow
         oversubscribed slots to cover their next rows (a no-op under
         reservation admission — the pages are already mapped) and cross
         the copy-on-write guard so no write can land in a shared or
         published page.  The scheduler preempts on the watermark BEFORE
-        stepping, so growth here never finds an empty pool."""
+        stepping, so growth here never finds an empty pool.  ``lengths``:
+        where the rows begin, a slot; the cache's own unless a launch
+        ahead of a read says otherwise (:meth:`_launch_pass`)."""
         cfg = self.config
+        if lengths is None:
+            lengths = self.cache.lengths
         for slot in sorted(self._slot_tokens):
-            n = int(self.cache.lengths[slot])
+            n = int(lengths[slot])
             if cfg.oversub:
                 self.cache.append_pages(slot, n + rows)
             self.cache.ensure_writable(slot, n, rows)
@@ -1597,11 +1688,12 @@ class InferenceSession(object):
         return short
 
     def release(self, slot):
-        """Give the slot and its pages back.  A decode step in flight
-        that carried the slot still writes its one row, inside the
-        request's own reservation; the row's token is dropped at the
-        read, and whatever takes the pages or the slot next is ordered
-        behind that step by the donated pools it takes."""
+        """Give the slot and its pages back.  A step in flight that
+        carried the slot still writes its one row (a block pass its
+        block's), inside the request's own reservation; what it returns
+        for the slot is dropped at the read, and whatever takes the pages
+        or the slot next is ordered behind that step by the donated pools
+        it takes."""
         self._slot_tokens.pop(slot, None)
         self._slot_history.pop(slot, None)
         self._slot_budget.pop(slot, None)
@@ -1620,8 +1712,8 @@ class InferenceSession(object):
         would do, minus the recompile (the executables are immutable
         and carry no request state, so reusing them in-process models
         only the state a real restart loses)."""
-        # a decode step in flight carried slots that are all released
-        # here: nobody reads it
+        # a step in flight carried slots that are all released here:
+        # nobody reads it
         self._flight = None
         # allocated-but-never-prefilled slots too (their holder died
         # between ``try_alloc`` and ``prefill``): the cache knows them

@@ -15,6 +15,7 @@ from mxnet_tpu import profiler, serve
 from mxnet_tpu.serve import model as serve_model
 
 from serve_util import lend
+from test_serve_blocks import SDAR
 
 CFG = serve.ModelConfig(vocab_size=61, num_layers=2, d_model=32,
                         num_heads=2, max_len=64)
@@ -67,9 +68,32 @@ def session(_session):
     yield from lend(_session)
 
 
-def requests(n=6, seed=0, spread_s=0.0):
+@pytest.fixture(scope="module")
+def _block_session():
+    """A diffusion block's session: its step is a block pass."""
+    sconf = serve.ServeConfig(slots=3, page_size=PAGE, buckets=(8, 16),
+                              max_new=8)
+    return serve.InferenceSession(serve.init_params(SDAR, seed=3, scale=0.3),
+                                  model=SDAR, config=sconf)
+
+
+@pytest.fixture
+def stepped(request):
+    """The session of a decode step, or of a block pass: both step by
+    the one ``InferenceSession.step``."""
+    yield from lend(request.getfixturevalue(
+        {"decode": "_session", "block_pass": "_block_session"}[
+            request.param]))
+
+
+both_steps = pytest.mark.parametrize("stepped", ["decode", "block_pass"],
+                                     indirect=True)
+
+
+def requests(n=6, seed=0, spread_s=0.0, below=61):
     rng = np.random.default_rng(seed)
-    return [serve.Request(rid=i, prompt=rng.integers(0, 61, 5 + i).tolist(),
+    return [serve.Request(rid=i,
+                          prompt=rng.integers(0, below, 5 + i).tolist(),
                           max_new=3 + i % 4, arrival_s=spread_s * i)
             for i in range(n)]
 
@@ -314,14 +338,18 @@ def test_chunks_that_carry_state_say_so_in_their_spans():
     assert (rep["prefills_from_zero"], rep["prefills_carried"]) == (1, 2)
 
 
-def test_a_step_is_its_launches_then_its_read_in_order(session):
+@both_steps
+def test_a_step_is_its_launches_then_its_read_in_order(stepped):
     """A call is the launches it makes (a ``step.prepare`` and a
     ``step.launch`` each: its own step's unless an earlier call left that
     in flight, and the next step's where ``ahead`` is 1), then the read of
-    one step and its commit."""
+    one step and its commit; a block pass's call likewise, and its span
+    says what the pass it read held."""
+    session = stepped
     before = session.decode_report()
     profiler.record_spans(True)
-    serve.Scheduler(session).run(requests())
+    # a diffusion block's prompt holds no mask token (60)
+    serve.Scheduler(session).run(requests(below=60))
     profiler.record_spans(False)
     children = collections.defaultdict(list)
     for r in sorted(profiler.spans(), key=lambda r: r.start_s):
@@ -338,6 +366,12 @@ def test_a_step_is_its_launches_then_its_read_in_order(session):
         for first, then in zip(parts, parts[1:]):
             assert first.end_s <= then.start_s
         assert 1 <= step.attrs["live"] <= 3
+        if session.diffusion:
+            # every end is foreseen, so the pass read carried every slot
+            assert step.attrs["denoise"] + step.attrs["commit"] \
+                == step.attrs["live"]
+        else:
+            assert set(step.attrs) == {"live", "ahead"}
         shares.append(sum(p.end_s - p.start_s for p in parts)
                       / (step.end_s - step.start_s))
         in_flight = step.attrs["ahead"]
@@ -354,7 +388,9 @@ def test_a_step_is_its_launches_then_its_read_in_order(session):
     assert 0.9 <= float(np.median(shares)) <= 1.0
 
 
-def test_a_bare_step_says_whether_it_ran_ahead(session):
+@both_steps
+def test_a_bare_step_says_whether_it_ran_ahead(stepped):
+    session = stepped
     slot = session.try_alloc(5, 6)
     session.prefill(slot, [1, 2, 3, 4, 5])
     profiler.record_spans(True)
@@ -368,6 +404,9 @@ def test_a_bare_step_says_whether_it_ran_ahead(session):
         r.parent for r in profiler.spans("step.launch"))
     # the last call reads what the third left in flight: it launches none
     assert [launched[s.id] for s in steps] == [1, 2, 1, 0]
+    prepared = collections.Counter(
+        r.parent for r in profiler.spans("step.prepare"))
+    assert prepared == launched
 
 
 def test_a_resumed_request_is_admitted_again_with_resume_set(params):

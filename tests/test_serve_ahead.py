@@ -9,8 +9,15 @@ it was in flight (an ``eos_id``, a cancel, a fault, a drain, a watermark
 eviction) gets no token from that step and leaks nothing, and the request
 admitted into the same slot before that step is read gets its own tokens,
 recurrent state included; the executables stay ``buckets + 1`` with no
-drift and no fallback; a speculating session and a diffusion block never
-run ahead.
+drift and no fallback; a speculating session never runs ahead.
+
+A diffusion block's pass runs ahead too (the file's second half): there a
+request hands back ``(token, pass, confidence)`` triples, all three held to
+the run in series; the scheduler foresees every end but a cancel, so no
+pass is launched for a request that is gone (the device's own
+``diffusion_stats`` equal the serial run's); what a release leaves in
+flight is dropped, and a slot prefilled under a pass gets its first pass
+one call later, its known rows from the host.
 """
 import contextlib
 import dataclasses
@@ -20,7 +27,9 @@ import pytest
 
 from mxnet_tpu import serve
 from mxnet_tpu.base import MXNetError
+from mxnet_tpu.serve import sdar_moe
 from mxnet_tpu.serve.scheduler import Request, Scheduler
+from mxnet_tpu.serve.session import NO_TOKEN
 from mxnet_tpu.testing import faults
 
 from serve_util import lend
@@ -37,7 +46,6 @@ BLOCKS = {"gpt2": GPT2, "deepseek_v3": LATENT,
           "phi4flash": PHI4}
 # a prompt of 37 tokens goes in three chunks, one of 22 in two
 CONF = dict(slots=3, page_size=8, buckets=(8, 16), max_new=8, max_prompt=40)
-every_block = pytest.mark.parametrize("sess", sorted(BLOCKS), indirect=True)
 
 
 @pytest.fixture(autouse=True)
@@ -48,13 +56,35 @@ def _clean_faults(monkeypatch):
     faults.reset()
 
 
+# the toy diffusion block at three thresholds: at 0.2 some passes' rows
+# clear it, and a block is committed after one to four denoise passes; at
+# 0.9 none does (the quota's four passes a block), at 0 all do (one)
+DIFFUSION = {"some_rows_clear_it": 0.2, "quota_only": 0.9,
+             "all_at_once": 0.0}
+BLOCKS.update({"sdar_moe:" + name: dataclasses.replace(
+    SDAR, confidence_threshold=at) for name, at in DIFFUSION.items()})
+AUTOREGRESSIVE = sorted(name for name in BLOCKS if ":" not in name)
+# four blocks of four tokens a request at most
+DIFFUSION_CONF = dict(max_new=16)
+
+
 def build(name, **conf):
-    cfg = BLOCKS.get(name, SDAR)
+    cfg = BLOCKS[name]
+    if name.startswith("sdar_moe"):
+        conf = dict(DIFFUSION_CONF, **conf)
     return serve.InferenceSession(
         # drawn wide: at the default 0.02 a toy model with a tied head
         # repeats itself too
         serve.init_params(cfg, seed=3, scale=0.3), model=cfg,
         config=serve.ServeConfig(**dict(CONF, **conf)))
+
+
+every_block = pytest.mark.parametrize("sess", AUTOREGRESSIVE, indirect=True)
+every_threshold = pytest.mark.parametrize(
+    "sess", ["sdar_moe:" + name for name in sorted(DIFFUSION)],
+    indirect=True)
+some_rows_clear_it = pytest.mark.parametrize(
+    "sess", ["sdar_moe:some_rows_clear_it"], indirect=True)
 
 
 @pytest.fixture(scope="module")
@@ -417,22 +447,6 @@ def test_a_speculating_session_never_runs_ahead():
     assert got == want
 
 
-def test_a_diffusion_block_never_runs_ahead():
-    sess = build("sdar_moe", max_prompt=0)
-    calls = []
-    with watched(sess, calls):
-        done, _ = Scheduler(sess).run(trace(4, longest=16))
-    assert calls and not any(a for a, _, _ in calls)
-    assert all(len(r.tokens) == r.max_new for r in done)
-    slot = sess.try_alloc(6, 8)
-    sess.prefill(slot, [1, 2, 3, 4, 5, 6])
-    steps = sess._decode_stats["steps"]
-    for _ in range(3):
-        sess.step(ahead=True)               # a block pass, read as it was
-    assert sess._decode_stats["steps"] == steps + 3
-    assert sess._decode_stats["steps_ahead"] == 0
-
-
 def test_a_bare_step_is_the_synchronous_call():
     """No argument: what ``prefill`` and ``release`` do between two steps
     is seen by the next one, as the tests' and ``bench_serve.py``'s loops
@@ -450,3 +464,297 @@ def test_a_bare_step_is_the_synchronous_call():
     with pytest.raises(MXNetError):
         sess.cache.release(slot_a)          # released once, and only once
     assert sess.decode_report()["steps_ahead"] == 0
+
+
+# -- a diffusion block's pass one ahead ----------------------------------------
+
+def block_trace(n=8, **more):
+    """Prompts that end at every place in a block of four, lengths that
+    are no multiples of it; a prompt holds no mask token (60)."""
+    rng = np.random.default_rng(11)
+    sizes = [(5, 14), (37, 11), (9, 16), (16, 9), (3, 13), (22, 10),
+             (12, 7), (7, 4)][:n]
+    return [Request(rid=i, prompt=rng.integers(0, 60, p).tolist(), max_new=m,
+                    arrival_s=0.0, **more) for i, (p, m) in enumerate(sizes)]
+
+
+def triples(requests):
+    return {r.rid: list(zip(r.tokens, r.passes, r.confidences))
+            for r in requests}
+
+
+def counted(sess):
+    """What the block-pass executable counted of its passes, on the
+    device."""
+    report = sess.block_report()
+    return {name: report[name] for name in sdar_moe.DIFFUSION_COLUMNS}
+
+
+def since(sess, before):
+    return {name: n - before[name] for name, n in counted(sess).items()}
+
+
+def blocks_in_series(sess, requests):
+    """-> (every request's triples, the device's counts) of the run in
+    series."""
+    before = counted(sess)
+    with in_series(sess):
+        done, _ = Scheduler(sess).run(requests)
+    assert not any(r.failed for r in done), [r.error for r in done]
+    return triples(done), since(sess, before)
+
+
+def denoise_passes(request):
+    """The denoise passes each block took that the request generated
+    whole (a first block whose leading rows the prompt gave takes
+    fewer)."""
+    at = list(request.passes)[-len(request.prompt) % 4:]
+    return [max(at[i:i + 4]) + 1 for i in range(0, len(at) - 3, 4)]
+
+
+@every_threshold
+def test_a_block_run_ahead_serves_the_triples_of_the_run_in_series(sess):
+    want, want_counts = blocks_in_series(sess, block_trace())
+    guards = sess.guard_report()
+    before, calls = counted(sess), []
+    with watched(sess, calls):
+        done, _ = Scheduler(sess).run(block_trace())
+    assert not any(r.failed for r in done), [r.error for r in done]
+    # the tokens, the pass each was unmasked in, the confidence it was
+    # unmasked with: the same executable on the same inputs
+    assert triples(done) == want
+    assert all(len(r.tokens) == r.max_new for r in done)
+    taken = {n for r in done for n in denoise_passes(r)}
+    assert taken == {0.0: {1}, 0.9: {4}, 0.2: {1, 2, 3, 4}}[
+        sess.model.confidence_threshold]
+    # every end was foreseen: no pass was launched for a request that was
+    # gone, so the device counted the passes of the run in series
+    assert since(sess, before) == want_counts
+    ahead = [a for a, _, _ in calls]
+    assert any(ahead) and not all(ahead) and not ahead[-1]
+    assert sum(ahead) > len(ahead) // 2
+    assert all(live == got for _, live, got in calls)
+    assert len(sess.executables) == len(CONF["buckets"]) + 1
+    assert sess.fallback_count() == 0
+    for name, guard in sess.guard_report().items():
+        assert guard["signatures"] == guards[name]["signatures"], name
+        assert guard["traces"] == guards[name]["traces"], name
+    assert sess.guard_report()["block_pass"]["calls"] \
+        == guards["block_pass"]["calls"] + len(calls)
+
+
+@pytest.mark.parametrize("policy", ["serial", "static"])
+@some_rows_clear_it
+def test_the_other_policies_run_a_pass_ahead_too(sess, policy):
+    want, want_counts = blocks_in_series(sess, block_trace(5))
+    before, calls = counted(sess), []
+    with watched(sess, calls):
+        done, _ = Scheduler(sess, policy=policy).run(block_trace(5))
+    assert triples(done) == want and since(sess, before) == want_counts
+    assert any(a for a, _, _ in calls)
+
+
+@pytest.mark.parametrize("row", [0, 1, 2, 3])
+@some_rows_clear_it
+def test_an_end_of_sequence_inside_a_block_is_foreseen(sess, row):
+    """The host holds a block's every token before its commit pass is
+    read, so an ``eos_id`` at any place of a block ends the request as the
+    run in series ends it, and no pass runs ahead over that commit."""
+    whole, _ = blocks_in_series(sess, block_trace(5))
+    lengths = {r.rid: len(r.prompt) for r in block_trace(5)}
+    # a token at that place of a later block than the request's first,
+    # which the request has not delivered before
+    rid, at = next((rid, i) for rid in sorted(whole)
+                   for i, (tok, _, _) in enumerate(whole[rid])
+                   if i >= 4 and (lengths[rid] + i) % 4 == row
+                   and tok not in [t for t, _, _ in whole[rid][:i]])
+
+    def requests():
+        reqs = block_trace(5)
+        reqs[rid].eos_id = whole[rid][at][0]
+        return reqs
+
+    want, want_counts = blocks_in_series(sess, requests())
+    assert want == {**whole, rid: whole[rid][:at + 1]}
+    before, calls = counted(sess), []
+    with watched(sess, calls):
+        done, _ = Scheduler(sess).run(requests())
+    assert triples(done) == want
+    assert since(sess, before) == want_counts
+    assert any(a for a, _, _ in calls)
+    assert all(live == got for _, live, got in calls)
+
+
+def block_steady():
+    """Three requests of sixteen tokens fill the slots; two more wait for
+    a slot, and take the one a cancel frees, behind the pass in flight."""
+    rng = np.random.default_rng(13)
+    sizes = [(5, 16), (22, 16), (9, 16), (12, 10), (18, 7)]
+    return [Request(rid=i, prompt=rng.integers(0, 60, p).tolist(), max_new=m,
+                    arrival_s=0.0) for i, (p, m) in enumerate(sizes)]
+
+
+@every_threshold
+def test_a_cancel_under_a_pass_in_flight(sess):
+    want, _ = blocks_in_series(sess, block_steady())
+
+    def between(sched, n, calls):
+        if n == 3:
+            assert calls[-1][0]             # the last call ran ahead
+            assert sched.cancel(1)
+
+    sched, calls = ticks(sess, block_steady(), between)
+    got = triples(sched._queue)
+    assert sched._queue[1].cancelled and len(got[1]) < 16
+    assert got[1] == want[1][:len(got[1])]
+    assert but(got, 1) == but(want, 1)
+    # the pass in flight carried the slot: nothing of it reached the
+    # request that took the slot, which then got its own blocks
+    assert filled_behind_a_step(calls)
+    assert not any(r.failed for r in sched._queue if r.rid != 1)
+
+
+def admit_block(sess, prompt, max_new):
+    slot = sess.try_alloc(len(prompt), max_new, tokens=prompt)
+    assert sess.prefill(slot, prompt) == (NO_TOKEN, None)
+    return slot
+
+
+def alone_blocks(sess, prompt, n):
+    """The first ``n`` triples of ``prompt`` served by itself, in series."""
+    slot, got = admit_block(sess, prompt, n), []
+    while len(got) < n:
+        got += sess.step()[0][slot]
+    sess.release(slot)
+    return got[:n]
+
+
+@every_threshold
+def test_a_slot_released_and_filled_again_under_a_pass_in_flight(sess):
+    rng = np.random.default_rng(5)
+    a, b, c = (rng.integers(0, 60, n).tolist() for n in (6, 11, 19))
+    want_b, want_c = alone_blocks(sess, b, 12), alone_blocks(sess, c, 8)
+    baseline = sess.state_report()
+    slot_a, slot_b = admit_block(sess, a, 16), admit_block(sess, b, 12)
+    out, _ = sess.step(ahead=True)          # the next pass is in flight
+    assert sorted(out) == sorted([slot_a, slot_b])
+    got_b, got_c = list(out[slot_b]), []
+    sess.release(slot_a)                    # ... and carries a's slot
+    slot_c = admit_block(sess, c, 8)
+    assert slot_c == slot_a
+    out, _ = sess.step(ahead=True)          # reads the pass that carried a
+    assert sorted(out) == [slot_b]          # a's rows are dropped, not c's
+    got_b += out[slot_b]
+    while len(got_c) < 8:
+        out, _ = sess.step(ahead=True)
+        got_b += out[slot_b]
+        got_c += out[slot_c]
+    # c began from its own known rows and its own pages
+    assert got_c[:8] == want_c
+    assert got_b[:12] == want_b[:len(got_b)]
+    sess.release(slot_c)
+    out, _ = sess.step()                    # reads what was in flight
+    assert sorted(out) == [slot_b]
+    assert sess._flight is None
+    sess.release(slot_b)
+    assert sess.state_report() == baseline
+    assert sess.fallback_count() == 0
+
+
+@every_threshold
+def test_a_slot_prefilled_under_a_pass_gets_its_first_pass_one_call_later(
+        sess):
+    rng = np.random.default_rng(8)
+    a, b = (rng.integers(0, 60, n).tolist() for n in (9, 14))
+    want_b = alone_blocks(sess, b, 8)
+    slot_a = admit_block(sess, a, 16)
+    sess.step(ahead=True)                   # a's second pass is in flight
+    slot_b = admit_block(sess, b, 8)
+    passes = sess.decode_report()["steps"]
+    out, _ = sess.step(ahead=True)          # reads it; launches b's first
+    assert sorted(out) == [slot_a]
+    assert sess.decode_report()["steps"] == passes + 1
+    blk = sess._slot_tokens[slot_b]         # the host's rows, as prefilled
+    assert (blk.passes, blk.tokens) == (0, b[12:] + [60, 60])
+    out, _ = sess.step()                    # b's first pass, its rows the
+    assert sorted(out) == [slot_a, slot_b]  # host's; nothing is launched
+    assert sess.decode_report()["steps"] == passes + 1
+    assert sess._slot_tokens[slot_b].passes == 1 and out[slot_b] == []
+    got_b = []
+    while len(got_b) < 8:
+        got_b += sess.step(ahead=len(got_b) < 4)[0][slot_b]
+    assert got_b[:8] == want_b
+
+
+@some_rows_clear_it
+def test_a_pass_nobody_waits_for_is_not_read(sess):
+    rng = np.random.default_rng(6)
+    a, b = (rng.integers(0, 60, n).tolist() for n in (7, 10))
+    want_b = alone_blocks(sess, b, 6)
+    slot_a = admit_block(sess, a, 16)
+    sess.step(ahead=True)
+    sess.release(slot_a)                    # the pass in flight carried a
+    passes = sess.guard_report()["block_pass"]["calls"]
+    slot_b, got_b, calls = admit_block(sess, b, 6), [], 0
+    while len(got_b) < 6:
+        out, _ = sess.step()                # launched anew and read: b's
+        assert sorted(out) == [slot_b]
+        got_b += out[slot_b]
+        calls += 1
+    assert got_b[:6] == want_b
+    assert sess.guard_report()["block_pass"]["calls"] == passes + calls
+
+
+@pytest.mark.parametrize("ends_by, flags", [
+    ("max_new", [True, True, True, False]),
+    ("eos_in_its_last_block", [True, True, True, False]),
+    ("eos_in_its_first_block", [True, False])])
+def test_no_pass_runs_ahead_over_a_requests_last_commit(ends_by, flags):
+    """At threshold 0 a block is one denoise pass and its commit.  A
+    prompt of 6 leaves 2 known rows: the first commit delivers 2 tokens,
+    the second 4, which spend ``max_new`` 6; the call that reads that
+    commit, or one that delivers the ``eos_id``, is told to keep the chip
+    free, every other to run ahead."""
+    sess = build("sdar_moe:all_at_once")
+    prompt = np.random.default_rng(9).integers(0, 60, 6).tolist()
+    whole = [tok for tok, _, _ in alone_blocks(sess, prompt, 6)]
+    eos = {"max_new": -1, "eos_in_its_first_block": whole[1],
+           "eos_in_its_last_block": next(
+               t for t in whole[2:] if t not in whole[:2])}[ends_by]
+    req = Request(rid=0, prompt=prompt, max_new=6, eos_id=eos)
+    before, calls = counted(sess), []
+    with watched(sess, calls):
+        Scheduler(sess).run([req])
+    assert [a for a, _, _ in calls] == flags
+    assert list(req.tokens) == whole[:len(req.tokens)] and not req.failed
+    assert req.tokens[-1] == eos or len(req.tokens) == 6
+    # one pass a call: none was launched behind the request's end
+    assert since(sess, before)["slot_passes"] == len(flags)
+    assert sess._flight is None and sess.active_slots() == []
+
+
+def test_a_bare_block_step_is_the_synchronous_call():
+    """No argument: what ``prefill`` and ``release`` do between two passes
+    is seen by the next one; told it may, a call leaves one pass in
+    flight and every later call launches one."""
+    sess = build("sdar_moe:quota_only")
+    slot_a = admit_block(sess, [5, 6, 7], 8)
+    out, _ = sess.step()
+    assert sorted(out) == [slot_a]
+    slot_b = admit_block(sess, [8, 9], 8)
+    out, _ = sess.step()
+    assert sorted(out) == sorted([slot_a, slot_b])
+    sess.release(slot_a)
+    out, _ = sess.step()
+    assert sorted(out) == [slot_b]
+    report = sess.decode_report()
+    assert (report["steps"], report["steps_ahead"]) == (3, 0)
+    for _ in range(3):
+        out, _ = sess.step(ahead=True)
+        assert sorted(out) == [slot_b]
+    report = sess.decode_report()
+    assert (report["steps"], report["steps_ahead"]) == (7, 3)
+    assert sess._flight is not None
+    sess.step()                             # reads it, launches nothing
+    assert sess._flight is None
+    assert sess.decode_report()["steps"] == 7

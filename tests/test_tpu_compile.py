@@ -900,6 +900,9 @@ def test_lfm2_executables_compile_for_v5e_at_the_published_widths(
 # GB each).  What is compiled is ``sdar_moe.block_pass`` /
 # ``sdar_moe.prefill_forward`` with the TPU's branches taken.
 SDAR_SLOTS, SDAR_TABLE, SDAR_BLOCK = 32, (3072 + 1024) // 16, 4
+# ``_sdar_program``'s "bucket" for the block pass as the session's
+# executable takes its tokens since PR 55 (0: ``sdar_moe.block_pass`` alone)
+AHEAD = -1
 
 
 def _sdar_program(one_chip, monkeypatch, bucket):
@@ -934,7 +937,7 @@ def _sdar_program(one_chip, monkeypatch, bucket):
     counters = {name: sds(leaf.shape, i32) for name, leaf
                 in sdar_moe.init_counters(cfg).items()}
     static = dict(cfg=cfg, page_size=page, exact=False, kv_quant="")
-    if bucket:
+    if bucket > 0:
         def step(params, tokens, length, offset, table_row, pools, counters):
             return sdar_moe.prefill_forward(
                 params, tokens, length, offset, table_row, pools, counters,
@@ -943,6 +946,21 @@ def _sdar_program(one_chip, monkeypatch, bucket):
         avals = (params, sds((1, bucket), i32), sds((), i32), sds((), i32),
                  sds((SDAR_TABLE,), i32), pools, counters)
         donate = (5, 6)
+    elif bucket == AHEAD:
+        # the executable as the session builds it (``_compile_all``): a
+        # slot's rows are the host's or the unread pass's, selected inside
+        def step(params, tokens, before, from_host, quota, fresh, lengths,
+                 tables, pools, counters):
+            tokens = jnp.where(from_host[:, None], tokens, before)
+            return sdar_moe.block_pass(params, tokens, quota, fresh, lengths,
+                                       tables, pools, counters, **static)
+
+        rows = sds((SDAR_SLOTS, SDAR_BLOCK), i32)
+        avals = (params, rows, rows, sds((SDAR_SLOTS,), jnp.bool_),
+                 sds((SDAR_SLOTS,), i32), sds((SDAR_SLOTS,), i32),
+                 sds((SDAR_SLOTS,), i32), sds((SDAR_SLOTS, SDAR_TABLE), i32),
+                 pools, counters)
+        donate = (8, 9)
     else:
         def step(params, tokens, quota, fresh, lengths, tables, pools,
                  counters):
@@ -962,8 +980,8 @@ def _sdar_program(one_chip, monkeypatch, bucket):
 
 
 @pytest.mark.parametrize("bucket, tile, temporaries", [
-    (0, 8, 512 << 20), (2048, 128, 3 << 29)],
-    ids=["block_pass", "prefill-2048"])
+    (0, 8, 512 << 20), (AHEAD, 8, 512 << 20), (2048, 128, 3 << 29)],
+    ids=["block_pass", "block_pass-ahead", "prefill-2048"])
 def test_sdar_executables_compile_for_v5e_at_the_published_widths(
         one_chip, monkeypatch, bucket, tile, temporaries):
     """The whole step fits the chip beside its arguments (11.3 GB: 4.85 of
@@ -977,11 +995,22 @@ def test_sdar_executables_compile_for_v5e_at_the_published_widths(
     of temporaries, more than the chip has left: PERF.md, PR 50).  Folded,
     the pools are the paged-attention kernel's to read, one head a lane
     tile with the block's 4 rows x 8 query heads as its 32 rows: twelve
-    kernels in a pass and no loop under ``bdiff_pass``."""
+    kernels in a pass and no loop under ``bdiff_pass``.  The pass as the
+    session's executable takes its tokens (``block_pass-ahead``: the
+    host's rows, the unread pass's, a mask) is all of that too, and its
+    temporaries are the plain pass's to within the selected rows."""
     from mxnet_tpu.ops import paged_attention
     from mxnet_tpu.ops.grouped_matmul import kernel_name
 
     compiled, shapes, notes = _sdar_program(one_chip, monkeypatch, bucket)
+    if bucket == AHEAD:
+        # the select in front of the pass costs the selected rows a buffer
+        # of their own (two tiles' worth: 30 687 744 bytes of temporaries
+        # against 30 655 488 without it) and nothing of a pool's size
+        plain, _, _ = _sdar_program(one_chip, monkeypatch, 0)
+        assert compiled.memory_analysis().temp_size_in_bytes \
+            <= plain.memory_analysis().temp_size_in_bytes + (64 << 10)
+        bucket = 0      # from here on it is held to all a block pass is
     assert notes == dict({"expert_kernel_layers": 12}, **(
         _prefill_notes(12, 1024, 8) if bucket
         else {"paged_kernel_layers": 12}))
