@@ -516,13 +516,13 @@ def paged_decode_attention(q, k_pool, v_pool, layer, tables, lengths,
     TPU a call that :func:`~.paged_attention.paged_attention_eligible`
     accepts (not ``mi``, no scales, float32 pools in either layout the
     cache gives them: heads of 128 on an axis of their own, or heads that
-    divide a lane tile folded into a last axis of whole lane tiles, under
-    a table of at least 2 048 keys) is
+    divide a lane tile or are whole lane tiles folded into a last axis of
+    whole lane tiles, under a table of at least 2 048 keys) is
     instead ONE Pallas kernel (``ops/paged_attention.py``) that walks each
     slot's own pages and stops at that slot's length, at the loop's
     precision; the loop is its fallback (``mi``, quantized or bfloat16
-    pages, heads of 256, folded pools under short tables, every other
-    backend) and its oracle.  Which ran
+    pages, heads of 256 on an axis of their own, folded pools under short
+    tables, every other backend) and its oracle.  Which ran
     is noted in the trace under way (``paged_kernel_layers``, which
     ``InferenceSession.decode_report()`` hands on).
 

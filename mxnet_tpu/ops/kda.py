@@ -47,7 +47,7 @@ from jax.scipy.linalg import solve_triangular
 
 from ..base import MXNetError
 
-__all__ = ["kda_step", "kda_chunked", "MAX_EXPONENT"]
+__all__ = ["kda_step", "kda_chunked", "chunk_pass", "MAX_EXPONENT"]
 
 # the largest |exponent| a factor of the chunked form may reach: e^80 is
 # 5.5e34, inside float32 (and bfloat16, which has its exponent range)
@@ -118,16 +118,26 @@ def kda_chunked(q, k, v, g, beta, state0, chunk=32, lower_bound=-5.0):
     q_start = q * from_start
     k_end = k * jnp.exp(total[:, :, None] - cum)
 
-    # the pass between chunks, from the state ENTERING each
+    state, o = chunk_pass(w, u_free, q_start, b, k_end, jnp.exp(total),
+                          state0)
+    return o.transpose(0, 2, 1, 3).reshape(nc * c, h, -1)[:t], state
+
+
+def chunk_pass(w, u_free, q_start, b, k_end, keep, state0):
+    """The pass between chunks, from the state ENTERING each: the one
+    sequential part of a chunked delta rule (this file's, and
+    ``ops/gdn.py``'s).  Per chunk and head: w, q_start, k_end (C, K);
+    u_free (C, V); b (C, C); keep (K,) or (1,), what the chunk's decays
+    leave of a state row.  -> (state after the last chunk, o (chunks, H,
+    C, V))."""
     def one_chunk(state, xs):
-        w_c, u_c, q_c, b_c, k_c, keep = xs
+        w_c, u_c, q_c, b_c, k_c, keep_c = xs
         u = u_c - jnp.einsum("htk,hkv->htv", w_c, state)
         o = jnp.einsum("htk,hkv->htv", q_c, state) \
             + jnp.einsum("hts,hsv->htv", b_c, u)
-        state = state * keep[..., None] \
+        state = state * keep_c[..., None] \
             + jnp.einsum("htk,htv->hkv", k_c, u)
         return state, o
 
-    state, o = lax.scan(one_chunk, state0.astype(f32),
-                        (w, u_free, q_start, b, k_end, jnp.exp(total)))
-    return o.transpose(0, 2, 1, 3).reshape(nc * c, h, -1)[:t], state
+    return lax.scan(one_chunk, state0.astype(jnp.float32),
+                    (w, u_free, q_start, b, k_end, keep))

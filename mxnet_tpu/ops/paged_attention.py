@@ -9,8 +9,8 @@ a shorter slot cannot see.  This kernel walks, for each slot, **that
 slot's** pages ``0 .. ceil(lengths[s] / page_size) - 1`` and no others,
 over float32 pools in either layout ``serve/kv_cache.py:kv_pool_shape``
 gives them at rest (:func:`paged_attention_eligible`: heads of 128 on an
-axis of their own, or heads that divide a lane tile folded into the last
-axis).  One body; what differs between the layouts is how a page lies in
+axis of their own, or heads that divide a lane tile, or are whole lane
+tiles, folded into the last axis).  One body; what differs between the layouts is how a page lies in
 the buffer and how a head's rows are taken out of it, both read off the
 buffers' shapes:
 
@@ -52,6 +52,13 @@ buffers' shapes:
   PR 48).  To the kernel a folded pool's "heads" are its lane tiles, of
   128 lanes each, and a tile's query rows are just rows: any head width
   that divides a tile takes the same form;
+* **folded heads of whole lane tiles** (Qwen3-Next's 2 heads of 256 in 512
+  lanes: two heads are no sublane tile, so the cache folds them): a head
+  is a group of its own whose lanes are its two tiles, ``(keys, 256)`` of
+  the block; the score product contracts over both tiles at once and the
+  value product fills both, nothing is block-diagonal, and a key/value
+  head's 8 query heads are exactly one sublane tile of rows
+  (``paged_decode_attention_f256_p32``);
 * operands are rounded to bfloat16 where they are read and accumulated in
   float32: what ``jnp.einsum`` at default precision does with the loop's
   float32 operands on this chip, **the same precision, not a lower one**
@@ -65,8 +72,8 @@ buffers' shapes:
   as the loop does.
 
 What keeps the loop: ``mi`` (``exact=True``), ``kv_quant`` pages and
-their scales, bfloat16 pools, heads of 256, a folded last axis that is
-not whole lane tiles, a folded pool under a table of fewer than 2 048 keys
+their scales, bfloat16 pools, heads of 256 on an axis of their own, a
+folded last axis that is not whole lane tiles, a folded pool under a table of fewer than 2 048 keys
 (granite-4.0-h-micro's 768: ``_FOLDED_MIN_TABLE_KEYS`` has why), every
 backend but a TPU.
 
@@ -154,8 +161,9 @@ def paged_attention_eligible(q, k_pool, v_pool, mi, k_scale, v_scale,
     out of a page, wants a last axis of one lane tile (the installed
     library kernel notes the same), so a head of 256 keeps the loop.
     **Heads folded into the last axis**: that axis is whole lane tiles and
-    a head divides a tile (64: two heads a tile), so no head straddles
-    two; and the table (``table_keys``: its columns x the page size, the
+    a head divides a tile (64: two heads a tile) or is whole tiles (256:
+    two tiles a head), so no head shares a tile with part of another; and
+    the table (``table_keys``: its columns x the page size, the
     longest context a slot can hold) is at least
     ``_FOLDED_MIN_TABLE_KEYS`` wide: over a shorter one the loop is too
     small a part of a step to be worth a kernel's start-up."""
@@ -170,7 +178,9 @@ def paged_attention_eligible(q, k_pool, v_pool, mi, k_scale, v_scale,
     if q.dtype not in (jnp.float32, jnp.bfloat16):
         return False
     if k_pool.ndim == 4:
-        return (k_pool.shape[3] % _LANES == 0 and _LANES % q.shape[-1] == 0
+        d = q.shape[-1]
+        return (k_pool.shape[3] % _LANES == 0
+                and (_LANES % d == 0 or d % _LANES == 0)
                 and table_keys >= _FOLDED_MIN_TABLE_KEYS)
     heads, d = k_pool.shape[3:]
     return d == _LANES and heads % _SUBLANES == 0
@@ -385,12 +395,14 @@ def _paged_attention(q, k_pool, v_pool, layer, tables, lengths, *, page_size,
     q32 = q.astype(jnp.float32) * scale
     if folded:
         # a lane tile's heads one under the other, each head's rows zero
-        # outside its own lanes of the tile: (S, tiles, per x R, 128)
-        per = _LANES // d
+        # outside its own lanes of the tile: (S, tiles, per x R, 128); a
+        # head of whole tiles is a group of its own, (S, H, R, D)
+        wide = max(_LANES, d)
+        per = wide // d
         q32 = q32.reshape(s, heads // per, per, r, d)
         q32 = jnp.concatenate([
             jnp.pad(q32[:, :, j],
-                    ((0, 0),) * 3 + ((j * d, _LANES - (j + 1) * d),))
+                    ((0, 0),) * 3 + ((j * d, wide - (j + 1) * d),))
             for j in range(per)], axis=2)
         # a page as it lies, (rows, H x D): only leading axes merge
         flat = (layers * pool_pages,) + k_pool.shape[2:]
@@ -481,8 +493,8 @@ def prefill_tiling(rows, head_dim, folded, heads, page_size, max_pages):
     the blocks of all groups take), and the chunk is shared out evenly
     over the fewest steps in whole sublane tiles; a key block likewise
     ``_PREFILL_KEYS_PER_BLOCK`` keys at most, and the table's."""
-    per = _LANES // head_dim if folded else 1
-    groups = heads // per
+    per = max(_LANES // head_dim, 1) if folded else 1
+    groups = heads * head_dim // _LANES if folded else heads    # lane tiles
     operand = min(_PREFILL_TILE_ROWS, _PREFILL_TILE_ROWS_ALL_GROUPS // groups)
     steps = -(-rows * per // operand)
     tile = -(-rows // (steps * _SUBLANES)) * _SUBLANES
@@ -613,7 +625,9 @@ def _paged_prefill(q, k_pool, v_pool, layer, table_row, horizons, *,
     layers, pool_pages = k_pool.shape[:2]
     max_pages = table_row.shape[0]
     folded = k_pool.ndim == 4
-    per = _LANES // d if folded else 1
+    # a group's lanes: a tile, or a folded head of whole tiles
+    lanes = max(_LANES, d)
+    per = lanes // d if folded else 1
     steps = -(-live // tile)
     rows = steps * tile
     # the columns that complete the last block lie past every horizon:
@@ -634,9 +648,9 @@ def _paged_prefill(q, k_pool, v_pool, layer, table_row, horizons, *,
         q32 = q32.reshape(heads // per, per, steps, tile, d)
         q32 = jnp.stack([
             jnp.pad(q32[:, j],
-                    ((0, 0),) * 3 + ((j * d, _LANES - (j + 1) * d),))
+                    ((0, 0),) * 3 + ((j * d, lanes - (j + 1) * d),))
             for j in range(per)], axis=2)
-        q32 = q32.reshape(heads // per, rows * per, _LANES)
+        q32 = q32.reshape(heads // per, rows * per, lanes)
         # a page as it lies, (rows, H x D): only leading axes merge
         flat = (layers * pool_pages,) + k_pool.shape[2:]
     else:
@@ -648,7 +662,7 @@ def _paged_prefill(q, k_pool, v_pool, layer, table_row, horizons, *,
     kernel = functools.partial(
         _prefill_kernel, page_size=page_size, pages=pages,
         full_precision=full_precision)
-    block = pl.BlockSpec((groups, operand, _LANES), lambda i, *_: (0, i, 0))
+    block = pl.BlockSpec((groups, operand, lanes), lambda i, *_: (0, i, 0))
     out = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct(q32.shape, jnp.float32),
@@ -666,19 +680,19 @@ def _paged_prefill(q, k_pool, v_pool, layer, table_row, horizons, *,
                 pltpu.SemaphoreType.DMA((2, 2)),
                 pltpu.VMEM((groups, operand, _LANES), jnp.float32),
                 pltpu.VMEM((groups, operand, _LANES), jnp.float32),
-                pltpu.VMEM((groups, operand, _LANES), jnp.float32),
+                pltpu.VMEM((groups, operand, lanes), jnp.float32),
                 pltpu.SMEM((1,), jnp.int32)]),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_prefill_vmem_bytes(
-                pages, flat[1] * flat[2] * 4, groups, operand,
-                pages * page_size)),
+                pages, flat[1] * flat[2] * 4, groups * lanes // _LANES,
+                operand, pages * page_size)),
         name=prefill_kernel_name(tile, pages, d if folded else 0),
     )(jnp.max(seen, axis=1), jnp.min(seen, axis=1), table, q32,
       jnp.tile(seen[:, None], (1, per, 1)).reshape(-1, 1),
       k_pool.reshape(flat), v_pool.reshape(flat))
     if folded:   # each head's rows, out of its own lanes of its tile
-        out = out.reshape(heads // per, steps, per, tile, _LANES)
+        out = out.reshape(heads // per, steps, per, tile, lanes)
         out = jnp.stack([out[:, :, j, :, j * d:(j + 1) * d]
                          for j in range(per)], axis=1).reshape(heads, rows, d)
     return out[:, :live].astype(q.dtype)

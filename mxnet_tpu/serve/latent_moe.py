@@ -38,7 +38,8 @@ form, and the tests hold this module to it):
   the choice is group-limited (``noaux_tc``): the experts lie in
   ``n_group`` equal groups, a group's score is the sum of its two largest
   ``s + b``, and the experts are taken from the ``topk_group`` best groups
-  only.
+  only.  With ``shared_expert_gate`` (the Qwen3-Next block) the shared
+  expert's result is multiplied by ``sigmoid(w_s . u)``, one number a row.
 
 The expert layer sorts the tokens x k assignments by expert, pads each
 expert's group to whole tiles and computes the tiles in use with the
@@ -179,6 +180,8 @@ def ffn_param_shapes(cfg, i):
         out.update({p + "shared_gate_weight": (fs, d),
                     p + "shared_up_weight": (fs, d),
                     p + "shared_down_weight": (d, fs)})
+        if cfg.shared_expert_gate:
+            out[p + "shared_expert_gate_weight"] = (1, d)
     return out
 
 
@@ -571,9 +574,13 @@ def _ffn_out(params, i, x, cfg, exact, dequantized):
                                     dequantized)
     if cfg.n_shared_experts:
         with jax.named_scope("moe_shared"):
-            out = out + _swiglu(u, params[pre + "shared_gate_weight"],
-                                params[pre + "shared_up_weight"],
-                                params[pre + "shared_down_weight"], exact)
+            shared = _swiglu(u, params[pre + "shared_gate_weight"],
+                             params[pre + "shared_up_weight"],
+                             params[pre + "shared_down_weight"], exact)
+            if cfg.shared_expert_gate:   # one sigmoid a row on the result
+                shared = shared * jax.nn.sigmoid(_mm(
+                    u, params[pre + "shared_expert_gate_weight"], exact))
+            out = out + shared
     return out, taken, computed
 
 

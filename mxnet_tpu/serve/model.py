@@ -57,7 +57,8 @@ __all__ = ["ModelConfig", "BLOCKS", "block_of", "exact_mode", "init_params",
 BLOCKS = {"gpt2": "model", "deepseek_v3": "latent_moe",
           "granitemoehybrid": "granite_hybrid", "laguna": "laguna",
           "bailing_hybrid": "bailing_hybrid", "lfm2_moe": "lfm2_moe",
-          "sdar_moe": "sdar_moe", "phi4flash": "phi4flash"}
+          "sdar_moe": "sdar_moe", "phi4flash": "phi4flash",
+          "qwen3_next": "qwen3_next"}
 
 
 def block_of(cfg):
@@ -140,7 +141,16 @@ class ModelConfig:
     2 x 64 over ``num_key_value_heads`` 10; ``layer_types`` of ``"mamba"``
     | ``"sliding_attention"`` | ``"full_attention"`` | ``"gmu"`` |
     ``"cross_attention"``, ``sliding_window``, ``d_ff``, ``mamba_d_state``,
-    ``mamba_d_conv`` and the last three fields.
+    ``mamba_d_conv`` and the three fields from ``mamba_expand`` on.
+    ``"qwen3_next"`` (``qwen3_next.py``: Gated DeltaNet layers with one
+    decay a head, gated grouped-query attention with a zero-centred norm a
+    query and key head and a partial rotation, softmax-routed experts of
+    which this chip may hold a share beside a shared expert behind a
+    sigmoid gate, an untied head) takes the expert fields (softmax
+    scores, ``shared_expert_gate``), ``num_key_value_heads``,
+    ``attn_head_dim``, ``partial_rotary_factor``, ``layer_types`` of
+    ``"linear_attention"`` | ``"full_attention"`` and the ``linear_*``
+    group with ``gdn_chunk_size``.
     """
     vocab_size: int
     num_layers: int
@@ -208,6 +218,16 @@ class ModelConfig:
     mamba_dt_rank: int = 0      # the rank of its step's projection; 0:
     #                             ceil(d_model / 16)
     layer_norm_eps: float = 1e-5    # of a LayerNorm block's norms
+    partial_rotary_factor: float = 1.0  # the share of a head's values that
+    #                                     a qwen3_next layer rotates
+    shared_expert_gate: bool = False    # the shared expert's result times
+    #                                     sigmoid(w_s . u)
+    linear_num_key_heads: int = 0       # a Gated DeltaNet mixer's query /
+    linear_num_value_heads: int = 0     # key heads and its value heads,
+    linear_key_head_dim: int = 0        # their widths,
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 4     # the taps of its convolution
+    gdn_chunk_size: int = 64            # and the rows a chunk of its prefill
 
     def __post_init__(self):
         if isinstance(self.rope_parameters, dict):
@@ -234,6 +254,7 @@ class ModelConfig:
         if self.layer_types:
             return tuple({"attention": "full", "mamba": "ssm", "mla": "full",
                           "kda": "ssm", "conv": "ssm",
+                          "linear_attention": "ssm",
                           "full_attention": "full",
                           "sliding_attention": "window",
                           "gmu": "shared",

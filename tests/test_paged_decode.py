@@ -256,12 +256,15 @@ def test_kernel_equals_the_loop(case, layout, rows):
     assert_close_across_executables(got, want)
 
 
-@pytest.mark.parametrize("head, rows", [(32, 3), (KD, 3), (KD, 32)])
+@pytest.mark.parametrize("head, rows", [(32, 3), (KD, 3), (KD, 32),
+                                        (2 * KD, 8), (2 * KD, 3)])
 def test_folded_kernel_takes_any_head_that_divides_a_lane_tile(head, rows):
     """Four heads of 32 a lane tile, or one of 128: the same form, since
     the kernel sees lane tiles and the query rows laid out over them; 32
     rows a head are a diffusion block's 4 rows x 8 query heads
-    (``serve/sdar_moe.py``)."""
+    (``serve/sdar_moe.py``).  And a head of 256, two lane tiles whole: a
+    group of its own, whose 8 query heads are one sublane tile of rows
+    (``serve/qwen3_next.py``)."""
     rs = np.random.RandomState(19)
     lengths = jnp.asarray(LENGTHS["idle_beside_full"], jnp.int32)
     q, k, v, tables = _kernel_case(rs, LENGTHS["idle_beside_full"], rows,
@@ -416,6 +419,12 @@ def test_only_an_eligible_call_on_a_tpu_takes_the_kernel(monkeypatch):
             wide, "paged_decode_attention_f64_p128"),
         "folded_heads_of_32": folded(k, 8, 32) + (
             wide, "paged_decode_attention_f32_p128"),
+        # two heads of 256 in 512 lanes: a head is two lane tiles whole
+        "folded_heads_of_256": (
+            jnp.tile(q[:, :2], 2),
+            jnp.tile(two_heads, 2).reshape(shape[:3] + (4 * KD,)),
+            jnp.tile(two_heads, 2).reshape(shape[:3] + (4 * KD,)), wide,
+            "paged_decode_attention_f256_p128"),
     }
     for q_, k_, v_, tables, _ in accepted.values():        # the CPU
         assert not eligible(q_, k_, v_, False, None, None,

@@ -29,12 +29,14 @@ KD = 128
 
 # the two layouts the cache gives pools at rest -> (key/value heads, head
 # width): heads of 128 on an axis of their own (dense chat, laguna), heads
-# of 64 folded two a lane tile (LFM2), heads of 128 folded one a tile (SDAR)
-LAYOUTS = {"heads": (2, KD), "folded": (4, 64), "folded128": (2, KD)}
+# of 64 folded two a lane tile (LFM2), heads of 128 folded one a tile
+# (SDAR), heads of 256 folded two tiles a head (Qwen3-Next)
+LAYOUTS = {"heads": (2, KD), "folded": (4, 64), "folded128": (2, KD),
+           "folded256": (2, 2 * KD)}
 # (layout, query heads that share a key/value head): one (dense chat),
 # four (LFM2), six (laguna), eight (SDAR)
 FORMS = [("heads", 1), ("heads", 6), ("folded", 4), ("folded", 1),
-         ("folded128", 8)]
+         ("folded128", 8), ("folded256", 8)]
 FORM_IDS = ["%s-G%d" % form for form in FORMS]
 
 
@@ -199,6 +201,10 @@ def test_the_tiling_shares_a_chunk_out_in_whole_sublane_tiles():
     assert tiling(8192, 64, True, 8, 16, 576) == (512, 64)
     # SDAR: 2048 x 8, 4 heads of 128 in four lane tiles
     assert tiling(16384, 128, True, 4, 16, 256) == (1024, 64)
+    # Qwen3-Next: 2048 x 8, 2 heads of 256, each two of the four lane tiles
+    assert tiling(16384, 256, True, 2, 16, 1088) == (1024, 64)
+    assert paged_attention.prefill_kernel_name(1024, 64, 256) \
+        == "paged_prefill_attention_f256_r1024_p64"
     # laguna: 2048 x 6, 8 heads of 128 on their own axis: half the rows
     assert tiling(12288, 128, False, 8, 16, 832) == (512, 64)
     # dense chat: 512 and 128 rows a head, 16 heads, a table of 48 pages:

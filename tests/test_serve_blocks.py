@@ -94,6 +94,15 @@ PHI4 = serve.ModelConfig(
         "mamba", "full_attention", "gmu", "cross_attention"),
     mamba_d_state=4, mamba_dt_rank=4, rms_norm_eps=1e-5,
     tie_word_embeddings=True)
+Q3N = serve.ModelConfig(
+    block="qwen3_next", vocab_size=61, num_layers=4, d_model=32, num_heads=4,
+    num_key_value_heads=2, max_len=64, attn_head_dim=8,
+    partial_rotary_factor=0.5, layer_types=("linear_attention",) * 3 + (
+        "full_attention",), linear_num_key_heads=2, linear_num_value_heads=4,
+    linear_key_head_dim=8, linear_value_head_dim=8, gdn_chunk_size=4,
+    moe_d_ff=16, n_routed_experts=16, num_experts_per_tok=4,
+    n_shared_experts=1, shared_expert_gate=True, scoring_func="softmax",
+    experts_held=(4, 4))
 CONF = dict(slots=3, page_size=8, buckets=(8, 16), max_new=8)
 
 
@@ -474,11 +483,42 @@ def test_a_block_whose_layers_share_a_cache_is_served_by_an_unedited_session():
             config=serve.ServeConfig(spec_k=2, **CONF))
 
 
+def test_a_block_with_a_matrix_state_a_head_is_served_by_an_unedited_session():
+    """The ninth block: buckets + 1 executables, four streams through three
+    slots, a step ahead, pages for the one attention layer beside two
+    state pools for the three DeltaNet layers, a prompt of three chunks
+    that carries its state, and the shared expert's gate as an option of
+    the latent block's expert layer."""
+    params = serve.init_params(Q3N, seed=5, scale=0.3)
+    sess = serve.InferenceSession(
+        params, model=Q3N, config=serve.ServeConfig(max_prompt=40, **CONF))
+    assert sess.block is serve_model.block_of(Q3N)
+    assert sorted(sess.executables) == ["decode", "prefill_16", "prefill_8"]
+    assert (sess.cache.n_full, sess.cache.n_ssm) == (1, 3)
+    assert sorted(sess.cache.state) == ["conv_state", "gdn_state"]
+    assert sess.cache.pools["gdn_state"].shape == (3, 3, 4, 8, 8)
+    assert sess.cache.pools["conv_state"].shape == (3, 3, 3, 64)
+    assert "blk0_shared_expert_gate_weight" in params
+    tokens = served(sess)
+    assert all(len(toks) == 6 for toks in tokens.values())
+    long = Request(rid=9, prompt=np.random.default_rng(9).integers(
+        0, 61, 37).tolist(), max_new=3, arrival_s=0.0)
+    done, _ = Scheduler(sess, policy="continuous").run([long])
+    assert not done[0].failed and len(done[0].tokens) == 3
+    rep = sess.block_report()
+    assert (rep["prefill_chunks"], rep["prefills_from_zero"],
+            rep["prefills_carried"]) == (7, 5, 2)
+    assert sess.decode_report()["steps_ahead"] > 0
+    with pytest.raises(MXNetError, match="does not support.*kv_quant"):
+        serve.InferenceSession(params, model=Q3N, config=serve.ServeConfig(
+            kv_quant="int8", **CONF))
+
+
 # block -> a model of it: with windowed layers where the block has any
 RINGS = {"gpt2": GPT2_WINDOWED, "deepseek_v3": LATENT,
          "granitemoehybrid": GRANITE, "bailing_hybrid": BAILING,
          "laguna": LAGUNA, "lfm2_moe": LFM2, "sdar_moe": SDAR,
-         "phi4flash": PHI4}
+         "phi4flash": PHI4, "qwen3_next": Q3N}
 
 
 @pytest.mark.parametrize("name", sorted(serve_model.BLOCKS))

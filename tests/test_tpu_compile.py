@@ -1618,3 +1618,131 @@ def test_the_latent_blocks_prefill_keeps_calling_the_scan():
     assert "decode_attention(" in inspect.getsource(latent_moe)
     for module in (latent_moe, bailing_hybrid):
         assert "paged_prefill_attention" not in inspect.getsource(module)
+
+
+# ``qwen3next-l8-longdoc``'s executables at Qwen3-Next-80B-A3B-Instruct's
+# published widths over the cell's cache: 8 layers (published 0-7: three
+# Gated DeltaNet layers, one gated attention layer, twice), experts 0-63
+# of 512 held, 1/8 of the vocabulary, an untied head; 32 slots x 1088 pages
+# of 16 + the trash page in two layers' K/V pools, 2 heads of 256 folded
+# into 512 lanes (2.28 GB each); six layers' state as (32, 128, 128) and
+# (3, 8192) a slot.  What is compiled is ``qwen3_next.decode_step`` /
+# ``qwen3_next.prefill_forward`` with the TPU's branches taken.
+Q3N_SLOTS, Q3N_TABLE = 32, (16384 + 1024) // 16
+
+
+def _qwen3next_program(one_chip, monkeypatch, bucket):
+    """-> the compiled decode step (``bucket`` 0) or prefill chunk of
+    ``bucket`` rows, the cache's pool shapes, and the notes of the trace."""
+    from mxnet_tpu import serve
+    from mxnet_tpu.serve import kv_cache, qwen3_next
+    from mxnet_tpu.serve import model as serve_model
+
+    cfg = serve.ModelConfig(
+        block="qwen3_next", vocab_size=18992, num_layers=8, d_model=2048,
+        num_heads=16, num_key_value_heads=2, max_len=262144,
+        attn_head_dim=256, partial_rotary_factor=0.25, rope_theta=1e7,
+        rms_norm_eps=1e-6,
+        layer_types=(("linear_attention",) * 3 + ("full_attention",)) * 2,
+        linear_num_key_heads=16, linear_num_value_heads=32,
+        linear_key_head_dim=128, linear_value_head_dim=128,
+        linear_conv_kernel_dim=4, gdn_chunk_size=64, moe_d_ff=512,
+        n_routed_experts=512, num_experts_per_tok=10, n_shared_experts=1,
+        shared_expert_gate=True, scoring_func="softmax",
+        experts_held=(0, 64)).validate()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def sds(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    i32 = jnp.int32
+    params = {k: sds(v) for k, v in qwen3_next.param_shapes(cfg).items()}
+    assert abs(sum(math.prod(v.shape) for v in params.values())
+               - 1978.8e6) < 1e5           # 7.92 GB in float32
+    page = 16
+    shapes = {"k_pool": kv_cache.kv_pool_shape(
+        2, Q3N_SLOTS * Q3N_TABLE + 1, page, 2, 256)}
+    shapes["v_pool"] = shapes["k_pool"]
+    assert shapes["k_pool"][-1] == 512 and len(shapes["k_pool"]) == 4
+    for name, (layers, shape, _) in qwen3_next.state_shapes(cfg).items():
+        shapes[name] = (layers, Q3N_SLOTS) + tuple(shape)
+    pools = {name: sds(shape) for name, shape in shapes.items()}
+    counters = {name: sds(leaf.shape, i32) for name, leaf
+                in qwen3_next.init_counters(cfg).items()}
+    static = dict(cfg=cfg, page_size=page, exact=False, kv_quant="")
+    if bucket:
+        def step(params, tokens, length, offset, table_row, pools, counters,
+                 slot):
+            return qwen3_next.prefill_forward(
+                params, tokens, length, offset, table_row, pools, counters,
+                slot=slot, **static)
+
+        avals = (params, sds((1, bucket), i32), sds((), i32), sds((), i32),
+                 sds((Q3N_TABLE,), i32), pools, counters, sds((), i32))
+        donate = (5, 6)
+    else:
+        def step(params, tokens, lengths, tables, pools, counters):
+            return qwen3_next.decode_step(params, tokens, lengths, tables,
+                                          pools, counters, **static)
+
+        avals = (params, sds((Q3N_SLOTS,), i32), sds((Q3N_SLOTS,), i32),
+                 sds((Q3N_SLOTS, Q3N_TABLE), i32), pools, counters)
+        donate = (4, 5)
+    with jax.default_matmul_precision("default"), \
+            serve_model.trace_notes() as notes:
+        lowered = jax.jit(step, donate_argnums=donate).lower(*avals)
+    return lowered.compile(
+        compiler_options=qwen3_next.compiler_options("tpu")), shapes, notes
+
+
+@pytest.mark.parametrize("bucket, tile, temporaries", [
+    (0, 8, 1 << 28), (512, 16, 3 << 27), (2048, 64, 3 << 28)],
+    ids=["decode", "prefill-512", "prefill-2048"])
+def test_qwen3next_executables_compile_for_v5e_at_the_published_widths(
+        one_chip, monkeypatch, bucket, tile, temporaries):
+    """The whole step fits the chip beside its arguments (12.9 GB: 7.92 of
+    weights, 4.56 of pages, 0.42 of state, under the 13.6 GB past which
+    PR 54 found state updates computed twice), Mosaic takes a whole float32
+    expert of 512 x 2048 a block in all eight expert layers, and the
+    donated pools and the states are updated where they lie: the result
+    aliases all four, no operation copies a whole K/V pool into another
+    layout, and a DeltaNet state's update is applied once and never
+    rematerialized (ROADMAP M4 (f)).  The two attention layers read their
+    folded pools of 2 heads of 256 through the paged kernels' form for a head of two lane
+    tiles (one lowering, two calls): no loop under ``gattn_decode`` or
+    ``gattn_prefill``, no slice of a pool's layer, no gathered context."""
+    from mxnet_tpu.ops.grouped_matmul import kernel_name
+
+    compiled, shapes, notes = _qwen3next_program(one_chip, monkeypatch,
+                                                 bucket)
+    assert notes == dict({"expert_kernel_layers": 8}, **(
+        _prefill_notes(2, min(1024, bucket * 8), 8) if bucket
+        else {"paged_kernel_layers": 2}))
+    text = compiled.as_text()
+    assert len(_kernel_calls(text, kernel_name(tile))) == 8
+    memory = compiled.memory_analysis()
+    held = 4 * sum(math.prod(shape) for shape in shapes.values())
+    assert 4.97e9 < held < 4.99e9
+    assert memory.alias_size_in_bytes >= held
+    assert 12.89e9 < memory.argument_size_in_bytes < 12.92e9
+    assert memory.temp_size_in_bytes < temporaries
+    # what the compiler computes a second time (a chunk's activations;
+    # layer 0's two appends of a decode step are clones whose originals are
+    # gone) is no update of a state pool, and each is applied once
+    state = "f32[%s]" % ",".join(map(str, shapes["gdn_state"]))
+    assert state not in _rematerialized(text)
+    assert len(re.findall(
+        r"= %s\S* dynamic-update-slice\(" % re.escape(state), text)) == 6
+    copies, _ = _whole_pool_copies(text, shapes["k_pool"])
+    assert not copies, copies
+    if not bucket:
+        pool = "f32[%s]" % ",".join(map(str, shapes["k_pool"]))
+        assert len(re.findall(
+            r"\n\s*%%\S+ = %s\S* fusion\(" % re.escape(pool),
+            text)) == 4          # K's and V's append, in two layers
+        _decode_reads_by_kernel(text, shapes["k_pool"], "gattn_decode", 2,
+                                folded_head=256)
+    else:
+        _prefill_reads_by_kernel(text, shapes["k_pool"], "gattn_prefill", 2,
+                                 (1, 2, Q3N_TABLE * 16, 256),
+                                 min(1024, bucket * 8), folded_head=256)
