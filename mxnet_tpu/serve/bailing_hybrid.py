@@ -71,11 +71,12 @@ REFUSES_WHY = ("a rejected draft would need the state before it, and "
 # the sum over DECODE steps and expert layers of the held experts at
 # least one row reached; rows_without_held_expert: real rows a layer that
 # reached none; state_slot_layers: (slot, KDA layer) states read and
-# written.
+# written; dispatch_rows: padded rows the expert layers laid out, and
+# dispatch_held the held assignments they are read against.
 COLUMNS = ("decode_steps", "prefill_chunks", "assignments_asked",
            "assignments_held", "assignments_computed",
            "distinct_held_experts", "rows_without_held_expert",
-           "state_slot_layers")
+           "state_slot_layers", "dispatch_rows", "dispatch_held")
 
 _L2_EPS = 1e-6      # under the square root of a query's or key's length
 

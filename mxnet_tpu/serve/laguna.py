@@ -87,7 +87,7 @@ REFUSES_WHY = ("a draft's rejected rows would already have overwritten "
 # moe_stats columns: latent_moe._ffn_held's counts
 MOE_COLUMNS = ("assignments_asked", "assignments_held",
                "assignments_computed", "distinct_held_experts",
-               "rows_without_held_expert")
+               "rows_without_held_expert", "dispatch_rows", "dispatch_held")
 # attn_stats columns.  prefill_chunks_continued: chunks at an offset past
 # 0 (a prompt longer than the largest bucket, or a resumed transcript).
 # Of the DECODE steps, summed over the window layers: window_rows_visited,

@@ -64,7 +64,12 @@ normalised over everything a token took, the stacked matrices hold the
 assignments that fall on them.  The others add nothing here (their
 chips would add them) and are told apart from dropped ones: ``computed``
 is false for both, and :func:`held` says which were asked of this chip.
-Nothing stands in for the absent chips or their exchange.
+Nothing stands in for the absent chips or their exchange.  A share's
+prefill chunk lays out rows for the assignments it holds, twice its
+balanced share at a time (:func:`_held_in_rounds`), not for every
+assignment the chunk could send it: its padded arrays are a third of
+the worst case's, and routing that sends more here takes a second round
+of the same loop, so nothing is dropped on any input.
 
 ``exact`` selects the M-invariant ``_mm`` as for the GPT-2 block, but
 the bit-identity contract does not extend here: the absorbed and the
@@ -484,15 +489,122 @@ def _tile_rows(assignments, experts):
     return min(128, max(8, 1 << (mean - 1).bit_length()))
 
 
+# A share lays out rows for what it holds when that takes this many bytes
+# off every padded array a layer writes and reads (about ten microseconds
+# of a v5e's HBM: what a loop's own start costs).  A decode step's worst
+# case is under it in every block served, and keeps the one-pass layout.
+_WORTH_BYTES = 8 << 20
+
+
+def _held_layout(assignments, held_experts, experts, tile):
+    """-> (the assignments one round of a share's layout takes: twice what
+    balanced routing sends to the experts held, from the call's shapes
+    alone; the tiles they can fill, and one more that stays zero)."""
+    bound = min(assignments, -(-2 * assignments * held_experts // experts))
+    return bound, bound // tile + min(held_experts, bound) + 1
+
+
+def _expert_tiles(x, expert_of_tile, in_use, stacks, tile, exact, by_kernel):
+    """x (tiles * tile, d) padded rows -> y like x: tile ``t < in_use`` is
+    ``SwiGLU_e(x_t)`` with ``e = expert_of_tile[t]``, every other row
+    zero."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    gate, up, down = stacks
+    if by_kernel:
+        return grouped_swiglu(x, expert_of_tile, in_use, gate, up, down,
+                              tile=tile)
+
+    # the fallback, and what the tests hold the kernel to
+    def one_tile(t, y):
+        idx = expert_of_tile[t]
+        xt = lax.dynamic_slice_in_dim(x, t * tile, tile)
+        yt = _swiglu(
+            xt, lax.dynamic_index_in_dim(gate, idx, 0, False),
+            lax.dynamic_index_in_dim(up, idx, 0, False),
+            lax.dynamic_index_in_dim(down, idx, 0, False), exact)
+        return lax.dynamic_update_slice_in_dim(y, yt, t * tile, 0)
+
+    return lax.fori_loop(0, in_use, one_tile, jnp.zeros_like(x))
+
+
+def _held_in_rounds(u, taken, w, cfg, tile, bound, tiles, on_tiles):
+    """:func:`_routed_experts` for a share, its rows laid out for the
+    assignments held: ``tiles`` tiles, what ``bound`` assignments can fill
+    on ``e`` experts and one more that stays zero, not the tiles all ``n x
+    k`` could.  The held assignments, sorted by expert, are taken ``bound``
+    at a time: one round under ordinary routing, as many as it takes when
+    the routing sends more here, so nothing is dropped on any input.
+    An assignment is ``j * n + token``, so the combine reads its ``(k, n,
+    d)`` as it lies for any ``k``.
+    -> (out (n, d), computed (n, k) bool, rows laid out () int32)."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    n, d = u.shape
+    k = taken.shape[1]
+    first, e = held_range(cfg)
+    a = n * k
+    rows = tiles * tile
+    flat = taken.T.reshape(a)
+    local = jnp.where(held(flat, cfg), flat - first, e)
+    order = jnp.argsort(local, stable=True).astype(jnp.int32)
+    position = jnp.zeros((a,), jnp.int32).at[order].set(
+        jnp.arange(a, dtype=jnp.int32), unique_indices=True)
+    # sorted position of each expert's first assignment; [e]: all held
+    group_start = (local < jnp.arange(e + 1, dtype=jnp.int32)[:, None]
+                   ).sum(axis=1, dtype=jnp.int32)
+    group = jnp.minimum(local, e - 1)
+    weight = w.T.reshape(k, n, 1).astype(u.dtype)
+    t = jnp.arange(tiles, dtype=jnp.int32)
+    in_tile = jnp.arange(tile, dtype=jnp.int32)
+
+    def one_round(carry):
+        lo, out, computed = carry
+        start = jnp.clip(group_start, lo, lo + bound)
+        counts = start[1:] - start[:-1]
+        tiles_of = (counts + tile - 1) // tile
+        tile_end = jnp.cumsum(tiles_of)
+        first_tile = tile_end - tiles_of
+        in_use = tile_end[-1]
+        expert_of_tile = jnp.minimum(
+            (tile_end <= t[:, None]).sum(axis=1, dtype=jnp.int32), e - 1)
+        # a row's rank in its expert's group; past the group: padding,
+        # which reads row 0 and is read by nothing
+        rank = ((t - first_tile[expert_of_tile]) * tile)[:, None] + in_tile
+        filled = rank < counts[expert_of_tile][:, None]
+        source = order[jnp.minimum(
+            start[expert_of_tile][:, None] + rank, a - 1).reshape(rows)]
+        x = u[jnp.where(filled.reshape(rows), source % n, 0)]
+        y = on_tiles(x, expert_of_tile, in_use)
+        # every assignment's row: this round's in their tiles, the others
+        # in the last tile, which is never in use and so zero
+        row_of = jnp.where(
+            (position >= lo) & (position < start[e]),
+            first_tile[group] * tile + position - start[group], rows - tile)
+        out = out + (y[row_of].reshape(k, n, d) * weight).sum(axis=0)
+        return lo + bound, out, computed | (row_of < in_use * tile)
+
+    done, out, computed = lax.while_loop(
+        lambda carry: carry[0] < group_start[e], one_round,
+        (jnp.int32(0), jnp.zeros((n, d), u.dtype), jnp.zeros((a,), bool)))
+    return out, computed.reshape(k, n).T, done // bound * rows
+
+
 def _routed_experts(u, taken, w, params, pre, cfg, exact, dequantized=False):
     """sum_k w[:, k] * SwiGLU_{taken[:, k]}(u) over the experts held
     here, dropless.  ``dequantized``: the stacks in ``params`` were made
     inside this trace from a weight-only-quantized tree (:func:`_resolve`).
+    A block that holds every expert, or a share whose call is small (a
+    decode step), lays out ``max_tiles`` tiles, what all ``n x k``
+    assignments could fill here; a share's prefill chunk lays out rows
+    for what it holds (:func:`_held_in_rounds`).
     -> (out (N, d), assignments whose tile was computed (N, k) bool: false
-    for one that belongs to an expert held elsewhere, see :func:`held`)."""
+    for one that belongs to an expert held elsewhere, see :func:`held`,
+    padded rows laid out () int32)."""
     import jax
     import jax.numpy as jnp
-    from jax import lax
 
     n, d = u.shape
     k = taken.shape[1]
@@ -501,7 +613,23 @@ def _routed_experts(u, taken, w, params, pre, cfg, exact, dequantized=False):
     a = n * k
     tile = _tile_rows(a, cfg.n_routed_experts)
     max_tiles = a // tile + min(e, a)
+    stacks = tuple(params[pre + "experts_%s_weight" % m]
+                   for m in ("gate", "up", "down"))
+    by_kernel = grouped_swiglu_eligible(u, *stacks, tile, exact, dequantized)
+    note_traced("expert_kernel_layers", int(by_kernel))
+
+    def on_tiles(x, expert_of_tile, in_use):
+        return _expert_tiles(x, expert_of_tile, in_use, stacks, tile, exact,
+                             by_kernel)
+
     with jax.named_scope("moe_experts"):
+        if share:
+            bound, held_tiles = _held_layout(a, e, cfg.n_routed_experts,
+                                             tile)
+            if (max_tiles - held_tiles) * tile * d * u.dtype.itemsize \
+                    >= _WORTH_BYTES:
+                return _held_in_rounds(u, taken, w, cfg, tile, bound,
+                                       held_tiles, on_tiles)
         flat = taken.reshape(a)               # assignment = token * k + j
         if share:   # held elsewhere: group e, behind every group computed
             flat = jnp.where(held(flat, cfg), flat - first, e)
@@ -527,40 +655,20 @@ def _routed_experts(u, taken, w, params, pre, cfg, exact, dequantized=False):
             tile_end, jnp.arange(max_tiles, dtype=jnp.int32), side="right"),
             0, e - 1)
         in_use = tile_end[-1]
-        gate, up, down = (params[pre + "experts_%s_weight" % m]
-                          for m in ("gate", "up", "down"))
-
-        by_kernel = grouped_swiglu_eligible(x, gate, up, down, tile, exact,
-                                            dequantized)
-        note_traced("expert_kernel_layers", int(by_kernel))
-        if by_kernel:
-            y = grouped_swiglu(x, expert_of_tile, in_use, gate, up, down,
-                               tile=tile)
-        else:   # the fallback, and what the tests hold the kernel to
-            def one_tile(t, y):
-                idx = expert_of_tile[t]
-                xt = lax.dynamic_slice_in_dim(x, t * tile, tile)
-                yt = _swiglu(
-                    xt, lax.dynamic_index_in_dim(gate, idx, 0, False),
-                    lax.dynamic_index_in_dim(up, idx, 0, False),
-                    lax.dynamic_index_in_dim(down, idx, 0, False), exact)
-                return lax.dynamic_update_slice_in_dim(y, yt, t * tile, 0)
-
-            y = lax.fori_loop(0, in_use, one_tile,
-                              jnp.zeros((max_tiles * tile, d), u.dtype))
+        y = on_tiles(x, expert_of_tile, in_use)
         row_of = jnp.zeros((a,), jnp.int32).at[order].set(row)
         if share:   # what is held elsewhere adds nothing here
             y = jnp.concatenate([y, jnp.zeros((1, d), y.dtype)])
         out = (y[row_of].reshape(n, k, d) * w[..., None].astype(u.dtype)
                ).sum(axis=1)
         computed = (row_of < in_use * tile).reshape(n, k)
-    return out, computed
+    return out, computed, jnp.int32(max_tiles * tile)
 
 
 def _ffn_out(params, i, x, cfg, exact, dequantized):
     """FFN(RMSNorm(x)) on (N, d), what layer ``i`` adds to ``x``.
-    -> (out, taken (N, k) expert ids, computed (N, k) bool); the last two
-    ``None`` in a dense layer."""
+    -> (out, taken (N, k) expert ids, computed (N, k) bool, padded rows
+    laid out () int32); all but the first ``None`` in a dense layer."""
     import jax
 
     pre = "blk%d_" % i
@@ -568,10 +676,10 @@ def _ffn_out(params, i, x, cfg, exact, dequantized):
     if i < cfg.first_k_dense:
         return _swiglu(u, params[pre + "gate_weight"],
                        params[pre + "up_weight"],
-                       params[pre + "down_weight"], exact), None, None
+                       params[pre + "down_weight"], exact), None, None, None
     taken, w = _route(u, params, pre, cfg)
-    out, computed = _routed_experts(u, taken, w, params, pre, cfg, exact,
-                                    dequantized)
+    out, computed, rows = _routed_experts(u, taken, w, params, pre, cfg,
+                                          exact, dequantized)
     if cfg.n_shared_experts:
         with jax.named_scope("moe_shared"):
             shared = _swiglu(u, params[pre + "shared_gate_weight"],
@@ -581,7 +689,7 @@ def _ffn_out(params, i, x, cfg, exact, dequantized):
                 shared = shared * jax.nn.sigmoid(_mm(
                     u, params[pre + "shared_expert_gate_weight"], exact))
             out = out + shared
-    return out, taken, computed
+    return out, taken, computed, rows
 
 
 def _ffn(params, i, x, cfg, exact, valid, dequantized):
@@ -590,7 +698,7 @@ def _ffn(params, i, x, cfg, exact, valid, dequantized):
     any row, and not counted).  -> (x + FFN, counter increments or None)."""
     import jax.numpy as jnp
 
-    out, taken, computed = _ffn_out(params, i, x, cfg, exact, dequantized)
+    out, taken, computed, _ = _ffn_out(params, i, x, cfg, exact, dequantized)
     if taken is None:
         return x + out, None
     real = jnp.broadcast_to(valid[:, None], taken.shape)
@@ -607,18 +715,23 @@ def _ffn_held(params, i, x, cfg, exact, valid, dequantized):
     ``assignments_held`` those that fell on experts held here,
     ``assignments_computed`` those of them whose tile the loop reached,
     ``distinct_held_experts`` the held experts at least one real row
-    reached, ``rows_without_held_expert`` real rows that reached none.
+    reached, ``rows_without_held_expert`` real rows that reached none,
+    ``dispatch_rows`` the padded rows the layer laid out for its tiles
+    (:func:`_routed_experts`) and ``dispatch_held`` the count they are
+    read against: ``assignments_held`` again, under a name of its own so
+    that whoever differences the one differences the other.
     -> (x + FFN, {name: count} or None in a dense layer)."""
     import jax.numpy as jnp
 
-    out, taken, computed = _ffn_out(params, i, x, cfg, exact, dequantized)
+    out, taken, computed, rows = _ffn_out(params, i, x, cfg, exact,
+                                          dequantized)
     if taken is None:
         return x + out, None
     first, count = held_range(cfg)
     here = held(taken, cfg) & valid[:, None]
     reached = jnp.zeros((count + 1,), bool).at[
         jnp.where(here, taken - first, count).reshape(-1)].set(True)
-    return x + out, {
+    counts = {
         name: mask.sum().astype(jnp.int32) for name, mask in (
             ("assignments_asked", jnp.broadcast_to(valid[:, None],
                                                    taken.shape)),
@@ -626,6 +739,8 @@ def _ffn_held(params, i, x, cfg, exact, valid, dequantized):
             ("assignments_computed", here & computed),
             ("distinct_held_experts", reached[:count]),
             ("rows_without_held_expert", valid & ~here.any(axis=1)))}
+    return x + out, dict(counts, dispatch_rows=rows,
+                         dispatch_held=counts["assignments_held"])
 
 
 def _head_gate(params, pre, att, u, heads, exact, scope="mla_gate"):

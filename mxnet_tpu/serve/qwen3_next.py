@@ -94,8 +94,8 @@ REFUSES_WHY = ("a rejected draft would need the state and the convolution "
                "row to scale: ROADMAP M3, M4")
 
 # moe_stats columns.  assignments_*, distinct_held_experts,
-# rows_without_held_expert: latent_moe._ffn_held's counts (distinct_held_
-# experts over DECODE steps only).  state_slot_layers: (slot, DeltaNet
+# rows_without_held_expert, dispatch_*: latent_moe._ffn_held's counts
+# (distinct_held_experts over DECODE steps only).  state_slot_layers: (slot, DeltaNet
 # layer) states read and written.  prefills_from_zero / prefills_carried:
 # the prefill chunks that began on the zeros ``alloc`` left and those that
 # took up the state an earlier chunk wrote.  full_rows_live: summed over
@@ -106,7 +106,7 @@ COLUMNS = ("decode_steps", "prefill_chunks", "assignments_asked",
            "assignments_held", "assignments_computed",
            "distinct_held_experts", "rows_without_held_expert",
            "state_slot_layers", "prefills_from_zero", "prefills_carried",
-           "full_rows_live")
+           "full_rows_live", "dispatch_rows", "dispatch_held")
 
 _L2_EPS = 1e-6      # under the square root of a query's or key's length
 

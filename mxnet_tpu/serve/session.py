@@ -1635,6 +1635,16 @@ class InferenceSession(object):
         ``full_layers``, ``expert_layers``, ``experts_held``,
         ``block_length``, ``denoising_steps`` and ``kv_lanes``.
 
+        Every block that holds a share of its experts (the KDA block and
+        the four after it) also counts ``dispatch_rows``, the padded rows
+        its expert layers laid out for their tiles (a share's prefill
+        chunk lays out rows for what it holds, a round at a time:
+        ``latent_moe._held_in_rounds``; a call that took a second round
+        counts its rows twice), and ``dispatch_held``, the held
+        assignments those rows served (``assignments_held`` under a name
+        of its own, so that a reader who differences one count over a
+        window differences both or neither).
+
         Every note of the decode executable's trace is copied in, so
         where the paged-attention kernel was traced its
         ``paged_kernel_layers`` shows here as in ``decode_report()``."""

@@ -81,7 +81,7 @@ def both_ways(name, monkeypatch, seed=0):
 
     def run():
         with jax.default_matmul_precision("default"):
-            out, computed = latent_moe._routed_experts(
+            out, computed, _ = latent_moe._routed_experts(
                 u, taken, w, params, "blk1_", cfg, False)
         return np.asarray(out), np.asarray(computed)
 

@@ -308,7 +308,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
         mine = {k: (v[first:first + 4] if "experts_" in k else v)
                 for k, v in p.items()}
         taken, w = latent_moe._route(u, mine, "blk1_", cfg)
-        out, done = latent_moe._routed_experts(u, taken, w, mine, "blk1_",
+        out, done, _ = latent_moe._routed_experts(u, taken, w, mine, "blk1_",
                                                cfg, False)
         here = np.asarray(latent_moe.held(taken, cfg))
         assert (np.asarray(done) == here).all()      # none dropped
@@ -329,7 +329,7 @@ def test_an_uncut_layer_is_its_own_whole_share():
     u = jnp.asarray(np.random.RandomState(4).randn(24, 64)
                     .astype(np.float32))
     taken, w = latent_moe._route(u, p, "blk1_", cfg)
-    out, done = latent_moe._routed_experts(u, taken, w, p, "blk1_", cfg,
+    out, done, _ = latent_moe._routed_experts(u, taken, w, p, "blk1_", cfg,
                                            False)
     assert bool(done.all())
     _near(out, reference.routed(u, p, "blk1_", UNCUT), "the whole layer",

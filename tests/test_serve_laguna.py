@@ -297,7 +297,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
         mine = {k: (v[first:first + 2] if "experts_" in k else v)
                 for k, v in p.items()}
         taken, w = latent_moe._route(u, mine, "blk1_", cfg)
-        out, done = latent_moe._routed_experts(u, taken, w, mine, "blk1_",
+        out, done, _ = latent_moe._routed_experts(u, taken, w, mine, "blk1_",
                                                cfg, False)
         here = np.asarray(latent_moe.held(taken, cfg))
         assert (np.asarray(done) == here).all()      # none dropped

@@ -311,6 +311,196 @@ def test_skewed_routing_is_dropless(top_k, hot):
     assert load[:, list(hot)].sum() == asked and load.sum() == asked
 
 
+# A share's expert layer alone, at toy widths: 16 experts of which 4..7
+# are held, 4 a token, 64 rows: 256 assignments in tiles of 16.  One round
+# of the layout for what is held takes 2 x 256 x 4 / 16 = 128 of them, in
+# 128 / 16 + 4 tiles and the one that stays zero: 208 rows, where all 256
+# could fill 16 + 4 tiles, 320 rows.
+SHARE = dict(d=32, f=16, experts=16, top_k=4, held=(4, 4), rows=64)
+SHARE_ROUND_ROWS, SHARE_ALL_ROWS = 208, 320
+# name: (the experts the crafted router takes from, rounds)
+SHARE_ROUTINGS = {"balanced": (range(16), 1), "every-one-held": (range(4, 8), 2),
+                  "three-in-four-held": ((3, 4, 5, 6), 2),
+                  "none-held": ((0, 1, 2, 3, 8, 9, 12, 15), 0)}
+
+
+def _share_layer(routing, seed=0):
+    """-> (cfg, the layer's arguments, the plain reference's result and
+    the held assignments): every row takes ``top_k`` distinct experts of
+    ``routing``'s."""
+    from serve_util import expert_layer_config
+
+    c = SHARE
+    cfg = expert_layer_config(c["d"], c["f"], c["experts"], c["top_k"],
+                              c["held"])
+    rng = np.random.default_rng(seed)
+    first, e = c["held"]
+    params = {"blk1_experts_%s_weight" % m: jnp.asarray(
+        rng.standard_normal(shape) / 4, jnp.float32) for m, shape in (
+            ("gate", (e, c["f"], c["d"])), ("up", (e, c["f"], c["d"])),
+            ("down", (e, c["d"], c["f"])))}
+    u = rng.standard_normal((c["rows"], c["d"])).astype(np.float32)
+    pool = np.asarray(list(SHARE_ROUTINGS[routing][0]))
+    taken = np.stack([rng.permutation(pool)[:c["top_k"]]
+                      for _ in range(c["rows"])]).astype(np.int32)
+    w = rng.uniform(0.1, 1.0, taken.shape).astype(np.float32)
+    here = (taken >= first) & (taken < first + e)
+    want = np.zeros_like(u, dtype=np.float64)
+    gate, up, down = (np.asarray(params["blk1_experts_%s_weight" % m],
+                                 np.float64) for m in ("gate", "up", "down"))
+    for t, j in zip(*np.nonzero(here)):
+        g = taken[t, j] - first
+        a = gate[g] @ u[t]
+        want[t] += w[t, j] * (down[g] @ (a / (1 + np.exp(-a))
+                                         * (up[g] @ u[t])))
+    return cfg, (jnp.asarray(u), jnp.asarray(taken), jnp.asarray(w),
+                 params), want, here
+
+
+def _share_layouts(monkeypatch, cfg, args):
+    """-> the layer's three results under the layout for what is held,
+    then under the one for every assignment (today's at these sizes)."""
+    out = []
+    for worth in (0, latent_moe._WORTH_BYTES):
+        monkeypatch.setattr(latent_moe, "_WORTH_BYTES", worth)
+        out.append(jax.jit(lambda *a: latent_moe._routed_experts(
+            *a, "blk1_", cfg, False))(*args))
+    return out
+
+
+@pytest.mark.parametrize("routing", sorted(SHARE_ROUTINGS))
+def test_a_share_lays_out_rows_for_what_it_holds(routing, monkeypatch):
+    """Under balanced routing one round of the bounded layout; with every
+    assignment (or three in four) on the held experts the bound overflows
+    and a second round computes the rest; with none held no round runs.
+    Each time the result is the one-pass layout's and the plain
+    reference's, ``computed`` is true for exactly the held assignments,
+    and the rows laid out are the count from the shapes."""
+    cfg, args, want, here = _share_layer(routing)
+    (out, computed, rows), (old, old_computed, old_rows) = _share_layouts(
+        monkeypatch, cfg, args)
+    assert_close_across_executables(np.asarray(out), np.asarray(old))
+    assert_close_across_executables(np.asarray(out), want.astype(np.float32))
+    assert (np.asarray(computed) == here).all()
+    assert (np.asarray(old_computed) == here).all()
+    rounds = SHARE_ROUTINGS[routing][1]
+    assert -(-here.sum() // 128) == rounds
+    assert int(rows) == rounds * SHARE_ROUND_ROWS
+    assert int(old_rows) == SHARE_ALL_ROWS
+
+
+def test_a_bound_that_truncates_is_caught(monkeypatch):
+    """The planted fault: the loop over rounds runs its body once, so what
+    lies past the bound is silently left out.  The comparison reads it in
+    the thousands of spacings and ``computed`` says which assignments no
+    tile reached (the benchmark's ``moe_assignments_dropped``)."""
+    from jax import lax
+
+    cfg, args, want, here = _share_layer("every-one-held")
+    monkeypatch.setattr(lax, "while_loop",
+                        lambda cond, body, init: body(init))
+    (out, computed, rows), (old, old_computed, _) = _share_layouts(
+        monkeypatch, cfg, args)
+    assert int(rows) == SHARE_ROUND_ROWS
+    assert spacings_apart(np.asarray(out), want.astype(np.float32)) > 1e3
+    assert np.asarray(computed).sum() == 128 < here.sum() == 256
+    # the one-pass layout has no such loop
+    assert_close_across_executables(np.asarray(old), want.astype(np.float32))
+    assert bool(np.asarray(old_computed).all())
+
+
+# (rows, d, f, experts, top_k, held): a call whose layout has to be the
+# one-pass text, primitive for primitive as ``jax.make_jaxpr`` shows it
+# (recorded at the parent of PR 57: the count of primitives, nested
+# computations included, and the first 12 digits of the SHA-1 of their
+# names joined by spaces), and the share's prefill chunks, which lay out
+# rows for what they hold.
+ONE_PASS = {
+    "qwen3next-decode": ((32, 2048, 512, 512, 10, (0, 64)),
+                         (184, "d9477e8e2cdb")),
+    "sdar-block-pass": ((128, 2048, 768, 128, 8, (0, 16)),
+                        (184, "d9477e8e2cdb")),
+    "lfm2-decode": ((64, 2048, 1536, 64, 4, (0, 8)), (184, "d9477e8e2cdb")),
+    "kanana-bucket-2048": ((2048, 2048, 768, 128, 6, ()),
+                           (166, "be2f39dfbc88")),
+}
+# name: (sizes, rows a round lays out, rows every assignment could fill)
+IN_ROUNDS = {
+    "qwen3next-bucket-2048": ((2048, 2048, 512, 512, 10, (0, 64)),
+                              145 * 64, 384 * 64),
+    "qwen3next-bucket-512": ((512, 2048, 512, 512, 10, (0, 64)),
+                             145 * 16, 384 * 16),
+    "laguna-bucket-2048": ((2048, 3072, 512, 256, 10, (0, 32)),
+                           73 * 128, 192 * 128),
+    "lfm2-bucket-512": ((512, 2048, 1536, 64, 4, (0, 8)), 25 * 32, 72 * 32),
+    "sdar-bucket-2048": ((2048, 2048, 768, 128, 8, (0, 16)),
+                         49 * 128, 144 * 128),
+    "ling-bucket-1024": ((1024, 2560, 768, 512, 8, (0, 64)),
+                         193 * 16, 576 * 16),
+}
+
+
+def _traced_layer(sizes):
+    """-> (the names of the primitives of ``_routed_experts`` traced at
+    ``sizes`` on abstract values, nested computations included; the
+    leading sizes of every array it makes)."""
+    from serve_util import expert_layer_config
+
+    n, d, f, experts, top_k, held = sizes
+    cfg = expert_layer_config(d, f, experts, top_k, held)
+    e = latent_moe.held_range(cfg)[1]
+    sds = jax.ShapeDtypeStruct
+    params = {"blk1_experts_gate_weight": sds((e, f, d), jnp.float32),
+              "blk1_experts_up_weight": sds((e, f, d), jnp.float32),
+              "blk1_experts_down_weight": sds((e, d, f), jnp.float32)}
+    jaxpr = jax.make_jaxpr(lambda u, t, w, p: latent_moe._routed_experts(
+        u, t, w, p, "blk1_", cfg, False)[:2])(
+            sds((n, d), jnp.float32), sds((n, top_k), jnp.int32),
+            sds((n, top_k), jnp.float32), params)
+    names, heights = [], set()
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            names.append(eqn.primitive.name)
+            heights.update(v.aval.shape[0] for v in eqn.outvars
+                           if getattr(v.aval, "shape", ()))
+            for value in eqn.params.values():
+                for sub in (value if isinstance(value, (list, tuple))
+                            else [value]):
+                    if hasattr(sub, "jaxpr"):
+                        walk(sub.jaxpr)
+                    elif hasattr(sub, "eqns"):
+                        walk(sub)
+
+    walk(jaxpr.jaxpr)
+    return names, heights
+
+
+@pytest.mark.parametrize("name", sorted(ONE_PASS))
+def test_a_small_call_and_a_whole_block_keep_the_one_pass_text(name):
+    """A share's decode step (the worst case is a few megabytes) and a
+    block that holds every expert (its worst case is what it computes)
+    trace what they traced before there was a second layout."""
+    import hashlib
+
+    sizes, (count, digest) = ONE_PASS[name]
+    names, _ = _traced_layer(sizes)
+    assert (len(names), hashlib.sha1(" ".join(names).encode())
+            .hexdigest()[:12]) == (count, digest)
+
+
+@pytest.mark.parametrize("name", sorted(IN_ROUNDS))
+def test_a_shares_prefill_chunk_holds_no_worst_case_array(name):
+    """At the served blocks' widths a share's prefill chunk lays out the
+    bounded rows: no array of the height all ``n x k`` assignments could
+    fill is left in its text, nor one of that height and the zero row."""
+    sizes, round_rows, all_rows = IN_ROUNDS[name]
+    names, heights = _traced_layer(sizes)
+    assert round_rows in heights
+    assert not {all_rows, all_rows + 1} & heights, sorted(heights)
+    assert names.count("while") == 2    # the rounds, and the tiles' loop
+
+
 def test_moe_report_counts_every_router(plain):
     seq = tokens(41, 11)
     slot = plain.try_alloc(len(seq), 4, tokens=seq)

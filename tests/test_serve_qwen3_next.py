@@ -211,7 +211,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
         mine = {k: (v[first:first + 2] if "experts_" in k else v)
                 for k, v in p.items()}
         taken, w = latent_moe._route(u, mine, "blk1_", cfg)
-        out, done = latent_moe._routed_experts(u, taken, w, mine, "blk1_",
+        out, done, _ = latent_moe._routed_experts(u, taken, w, mine, "blk1_",
                                                cfg, False)
         here = np.asarray(latent_moe.held(taken, cfg))
         assert (np.asarray(done) == here).all()      # none dropped
@@ -235,11 +235,11 @@ def test_the_shared_expert_is_gated_a_row():
     u = x / jnp.sqrt(jnp.mean(x * x, axis=-1, keepdims=True) + 1e-6)
     want = reference.routed(u, p, "blk1_", HF) + reference.shared(u, p,
                                                                   "blk1_")
-    got, _, _ = latent_moe._ffn_out(p, 1, x, CFG, False, False)
+    got = latent_moe._ffn_out(p, 1, x, CFG, False, False)[0]
     _near(got, want, "the gated layer", rel=1e-5)
-    whole, _, _ = latent_moe._ffn_out(
+    whole = latent_moe._ffn_out(
         p, 1, x, dataclasses.replace(CFG, shared_expert_gate=False), False,
-        False)
+        False)[0]
     gate = jax.nn.sigmoid(u @ p["blk1_shared_expert_gate_weight"].T)
     assert float(gate.min()) < 0.1 and float(gate.max()) > 0.9
     _near(whole - got, (1 - gate) * reference.shared(u, p, "blk1_") / gate,

@@ -259,17 +259,20 @@ def test_kda_decode_step_updates_the_state_in_place_on_v5e(one_chip):
 
 
 # The routed-expert layer of the two expert cells at their real shapes
-# (rows of the call, d, routed experts, experts a token, experts held):
-# kanana-2's decode step over 16 slots (108 tiles of 8 rows) and its
-# bucket of 2048 (224 tiles of 128) over (128, 768, 2048) stacks;
-# Ling-3.0-flash's decode step over 64 slots (128 tiles of 8) and its
-# bucket of 1024 (576 tiles of 16) over the (64, 768, 2560) stacks of the
-# share this chip holds.
+# (rows of the call, d, routed experts, experts a token, experts held, the
+# tile, the tiles the layout holds, the tiles every assignment of the call
+# could fill here): kanana-2's decode step over 16 slots (108 tiles of 8
+# rows) and its bucket of 2048 (224 tiles of 128) over (128, 768, 2048)
+# stacks; Ling-3.0-flash's decode step over 64 slots (128 tiles of 8) and
+# its bucket of 1024 over the (64, 768, 2560) stacks of the share this
+# chip holds: since PR 57 the 193 tiles of 16 that twice its balanced
+# share of the 8 192 assignments (2 048) can fill on 64 experts, and one
+# that stays zero, where all 8 192 could fill 576.
 EXPERT_CASES = {
-    "kanana-decode": (16, 2048, 128, 6, (), 8, 108),
-    "kanana-bucket-2048": (2048, 2048, 128, 6, (), 128, 224),
-    "ling-decode": (64, 2560, 512, 8, (0, 64), 8, 128),
-    "ling-bucket-1024": (1024, 2560, 512, 8, (0, 64), 16, 576),
+    "kanana-decode": (16, 2048, 128, 6, (), 8, 108, 108),
+    "kanana-bucket-2048": (2048, 2048, 128, 6, (), 128, 224, 224),
+    "ling-decode": (64, 2560, 512, 8, (0, 64), 8, 128, 128),
+    "ling-bucket-1024": (1024, 2560, 512, 8, (0, 64), 16, 193, 576),
 }
 
 
@@ -279,16 +282,19 @@ def test_grouped_swiglu_compiles_for_v5e(one_chip, name, monkeypatch):
     predicate's own answer for a TPU: Mosaic takes a whole expert a block
     (``vmem_limit_bytes``), the kernel carries its tile in its name, and
     no stack is copied or converted on its way in: the kernel reads the
-    float32 matrices where the parameters lie."""
+    float32 matrices where the parameters lie.  The kernel's rows are the
+    layout's: every assignment's worst case where the block holds all its
+    experts or the call is a decode step, the bounded height in a share's
+    prefill chunk, whose text holds no array of the worst case's rows."""
     from mxnet_tpu.ops.grouped_matmul import kernel_name
     from mxnet_tpu.serve import latent_moe
     from serve_util import expert_layer_config
 
-    rows, d, experts, top_k, held, tile, tiles = EXPERT_CASES[name]
+    rows, d, experts, top_k, held, tile, tiles, worst = EXPERT_CASES[name]
     cfg = expert_layer_config(d, 768, experts, top_k, held)
     e = latent_moe.held_range(cfg)[1]
     assert latent_moe._tile_rows(rows * top_k, experts) == tile
-    assert rows * top_k // tile + min(e, rows * top_k) == tiles
+    assert rows * top_k // tile + min(e, rows * top_k) == worst
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
     def sds(shape, dtype=jnp.float32):
@@ -305,6 +311,12 @@ def test_grouped_swiglu_compiles_for_v5e(one_chip, name, monkeypatch):
                     sds((rows, top_k)), sds((e, 768, d)), sds((e, 768, d)),
                     sds((e, d, 768))).as_text()
     assert len(_kernel_calls(text, kernel_name(tile))) == 1
+    assert re.search(r"%%%s[.\d]* = f32\[%d,%d\]"
+                     % (kernel_name(tile), tiles * tile, d), text)
+    if tiles < worst:
+        tall = re.findall(r"f32\[(%d|%d),%d\]"
+                          % (worst * tile, worst * tile + 1, d), text)
+        assert not tall, tall
     stack = r"(f32|bf16)\[%d,(768,%d|%d,768)\]" % (e, d, d)
     moved = [line.strip()[:160] for line in text.splitlines()
              if re.search(r"= %s\S* (copy|convert|fusion|transpose)\("
@@ -406,6 +418,22 @@ def _layer_slices(text, pool_shape):
         if m and math.prod(int(n) for n in m.group(1).split(",")) == size:
             found.append(line.strip()[:140])
     return found
+
+
+def _worst_case_rows(text, rows, top_k, experts, held, d):
+    """What a compiled text keeps of a share's expert layout as tall as if
+    every assignment of a chunk of ``rows`` fell on the ``held`` experts
+    here: the expert kernel's results of that height, and the arrays of
+    that height and the appended zero row.  A share's prefill chunk held
+    both a layer until PR 57 and holds neither since."""
+    from mxnet_tpu.ops.grouped_matmul import kernel_name
+    from mxnet_tpu.serve import latent_moe
+
+    a = rows * top_k
+    tile = latent_moe._tile_rows(a, experts)
+    worst = (a // tile + min(held, a)) * tile
+    return re.findall(r"%%%s[.\d]* = f32\[%d,%d\]|f32\[%d,%d\]"
+                      % (kernel_name(tile), worst, d, worst + 1, d), text)
 
 
 def _kernel_calls(text, name):
@@ -841,7 +869,7 @@ def _lfm2_program(one_chip, monkeypatch, bucket):
 
 
 @pytest.mark.parametrize("bucket, tile, temporaries", [
-    (0, 8, 64 << 20), (512, 32, 3 << 25), (2048, 128, 3 << 27)],
+    (0, 8, 64 << 20), (512, 32, 7 << 23), (2048, 128, 3 << 26)],
     ids=["decode", "prefill-512", "prefill-2048"])
 def test_lfm2_executables_compile_for_v5e_at_the_published_widths(
         one_chip, monkeypatch, bucket, tile, temporaries):
@@ -859,7 +887,11 @@ def test_lfm2_executables_compile_for_v5e_at_the_published_widths(
     plain slices of the pool laid out as rows, and 1.27-1.32 GB of
     temporaries where there were then 0.06 and 0.25), nor since PR 51 the
     gathers or the scan: its three attention layers read their pages
-    through the prefill kernel (one lowering, three calls)."""
+    through the prefill kernel (one lowering, three calls).  Since PR 57
+    a chunk's expert layers lay out rows for what they hold (3 200 where
+    every assignment could fill 9 216 at bucket 2048, 800 for 2 304 at
+    512): 50.0 and 175.9 MB of temporaries where there were 65.3 and
+    228.8, and no array of the worst case's rows."""
     from mxnet_tpu.ops.grouped_matmul import kernel_name
 
     compiled, shapes, notes = _lfm2_program(one_chip, monkeypatch, bucket)
@@ -873,6 +905,8 @@ def test_lfm2_executables_compile_for_v5e_at_the_published_widths(
     assert memory.alias_size_in_bytes >= held
     assert 12.0e9 < memory.argument_size_in_bytes < 12.1e9
     assert memory.temp_size_in_bytes < temporaries
+    if bucket:
+        assert not _worst_case_rows(text, bucket, 4, 64, 8, 2048)
     copies, _ = _whole_pool_copies(text, shapes["k_pool"])
     assert not copies, copies
     # the state (10.5 MB) is at rest a slot's two rows at a time; a decode
@@ -980,7 +1014,7 @@ def _sdar_program(one_chip, monkeypatch, bucket):
 
 
 @pytest.mark.parametrize("bucket, tile, temporaries", [
-    (0, 8, 512 << 20), (AHEAD, 8, 512 << 20), (2048, 128, 3 << 29)],
+    (0, 8, 512 << 20), (AHEAD, 8, 512 << 20), (2048, 128, 5 << 26)],
     ids=["block_pass", "block_pass-ahead", "prefill-2048"])
 def test_sdar_executables_compile_for_v5e_at_the_published_widths(
         one_chip, monkeypatch, bucket, tile, temporaries):
@@ -998,7 +1032,10 @@ def test_sdar_executables_compile_for_v5e_at_the_published_widths(
     kernels in a pass and no loop under ``bdiff_pass``.  The pass as the
     session's executable takes its tokens (``block_pass-ahead``: the
     host's rows, the unread pass's, a mask) is all of that too, and its
-    temporaries are the plain pass's to within the selected rows."""
+    temporaries are the plain pass's to within the selected rows.  Since
+    PR 57 a chunk's expert layers lay out 6 272 rows where every
+    assignment could fill 18 432: 268.9 MB of temporaries where there were
+    385.0."""
     from mxnet_tpu.ops import paged_attention
     from mxnet_tpu.ops.grouped_matmul import kernel_name
 
@@ -1022,11 +1059,18 @@ def test_sdar_executables_compile_for_v5e_at_the_published_widths(
     memory = compiled.memory_analysis()
     held = 4 * sum(math.prod(shape) for shape in shapes.values())
     assert memory.alias_size_in_bytes >= held
-    # (the head, the final norm and the last layer's q, o and experts are
-    # no arguments of a prefill: 0.47 GB)
-    assert (10.8e9 if bucket else 11.2e9) < memory.argument_size_in_bytes \
-        < (10.9e9 if bucket else 11.4e9)
+    # (the head, the final norm and the last layer's q and o are no
+    # arguments of a prefill: 0.17 GB.  Until PR 57 its experts were none
+    # either, 0.30 GB: the loop over the layout's rounds stays for what it
+    # counts, the compiler drops the kernel and the combine out of its
+    # body, and the stacks stay in the signature of a loop that no longer
+    # reads them.  They lie on the device as the session's parameters
+    # whether an executable names them or not.)
+    assert (11.1e9 if bucket else 11.2e9) < memory.argument_size_in_bytes \
+        < (11.2e9 if bucket else 11.4e9)
     assert memory.temp_size_in_bytes < temporaries
+    if bucket:
+        assert not _worst_case_rows(text, bucket, 8, 128, 16, 2048)
     copies, _ = _whole_pool_copies(text, shapes["k_pool"])
     assert not copies, copies
     if not bucket:
@@ -1162,7 +1206,7 @@ def _loop_conditions(text, scope):
 
 
 @pytest.mark.parametrize("bucket, tile, temporaries", [
-    (0, 8, 64 << 20), (512, 32, 7 << 25), (2048, 128, 25 << 25)],
+    (0, 8, 64 << 20), (512, 32, 7 << 25), (2048, 128, 20 << 25)],
     ids=["decode", "prefill-512", "prefill-2048"])
 def test_laguna_executables_compile_for_v5e_at_the_published_widths(
         one_chip, monkeypatch, bucket, tile, temporaries):
@@ -1172,7 +1216,11 @@ def test_laguna_executables_compile_for_v5e_at_the_published_widths(
     the donated pools and rings are updated where they lie: the result
     aliases all four, and no operation copies a whole pool or a whole
     ring into another layout, nor (since PR 49) one layer of a pool in
-    a prefill chunk: the temporaries stay under one (0.87 GB)."""
+    a prefill chunk: the temporaries stay under one (0.87 GB).  Since
+    PR 57 a chunk's expert layers lay out 9 344 rows of 3 072 where every
+    assignment could fill 24 576 (2 336 for 6 144 at bucket 512): 608.2 MB
+    of temporaries at bucket 2048 where there were 798.0 (190.6 for 196.2
+    at 512, where the window layers' attention sets the peak)."""
     from mxnet_tpu.ops.grouped_matmul import kernel_name
 
     compiled, shapes, notes = _laguna_program(one_chip, monkeypatch, bucket)
@@ -1187,6 +1235,8 @@ def test_laguna_executables_compile_for_v5e_at_the_published_widths(
     assert memory.alias_size_in_bytes >= held
     assert 10.5e9 < memory.argument_size_in_bytes < 10.6e9
     assert memory.temp_size_in_bytes < temporaries
+    if bucket:
+        assert not _worst_case_rows(text, bucket, 10, 256, 32, 3072)
     for shape in (shapes["k_pool"], shapes["kw_pool"]):
         copies, _ = _whole_pool_copies(text, shape)
         assert not copies, copies
@@ -1696,7 +1746,7 @@ def _qwen3next_program(one_chip, monkeypatch, bucket):
 
 
 @pytest.mark.parametrize("bucket, tile, temporaries", [
-    (0, 8, 1 << 28), (512, 16, 3 << 27), (2048, 64, 3 << 28)],
+    (0, 8, 1 << 28), (512, 16, 5 << 25), (2048, 64, 21 << 25)],
     ids=["decode", "prefill-512", "prefill-2048"])
 def test_qwen3next_executables_compile_for_v5e_at_the_published_widths(
         one_chip, monkeypatch, bucket, tile, temporaries):
@@ -1710,7 +1760,12 @@ def test_qwen3next_executables_compile_for_v5e_at_the_published_widths(
     rematerialized (ROADMAP M4 (f)).  The two attention layers read their
     folded pools of 2 heads of 256 through the paged kernels' form for a head of two lane
     tiles (one lowering, two calls): no loop under ``gattn_decode`` or
-    ``gattn_prefill``, no slice of a pool's layer, no gathered context."""
+    ``gattn_prefill``, no slice of a pool's layer, no gathered context.
+    Since PR 57 a chunk's expert layers lay out 9 280 rows where every
+    assignment could fill 24 576 (2 320 for 6 144 at bucket 512): 667.1
+    and 137.5 MB of temporaries where there were 723.8 and 206.4 (the
+    DeltaNet scan sets the peak of a 2 048-row chunk), and no array of the
+    worst case's rows is left in a chunk's text."""
     from mxnet_tpu.ops.grouped_matmul import kernel_name
 
     compiled, shapes, notes = _qwen3next_program(one_chip, monkeypatch,
@@ -1726,6 +1781,8 @@ def test_qwen3next_executables_compile_for_v5e_at_the_published_widths(
     assert memory.alias_size_in_bytes >= held
     assert 12.89e9 < memory.argument_size_in_bytes < 12.92e9
     assert memory.temp_size_in_bytes < temporaries
+    if bucket:
+        assert not _worst_case_rows(text, bucket, 10, 512, 64, 2048)
     # what the compiler computes a second time (a chunk's activations;
     # layer 0's two appends of a decode step are clones whose originals are
     # gone) is no update of a state pool, and each is applied once
